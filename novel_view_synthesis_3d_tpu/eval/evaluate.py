@@ -87,7 +87,7 @@ def make_cond_sensitivity_fn(model, logsnr: float = 0.0):
     Cross-frame attention is the ONLY path from the conditioning image to
     the target-frame output (convs are per-frame, models/layers.py), so an
     inert-attention config — the r2/r3 postmortem class
-    (results/RESULTS_r03.md) — yields EXACTLY 0.0 here while its seen-pose
+    (record deleted in PR 21) — yields EXACTLY 0.0 here while its seen-pose
     PSNR curve still looks healthy. A healthy conditioned model yields
     O(0.1–1). One forward pair per call: cheap enough for the in-loop
     probe at every eval point.

@@ -23,6 +23,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 import numpy as np
 
+from novel_view_synthesis_3d_tpu.ops._pallas import over_data_axis
 from novel_view_synthesis_3d_tpu.ops.fused_epilogue import (
     fits_vmem as epilogue_fits_vmem,
     fused_film_epilogue,
@@ -257,7 +258,11 @@ class AttnLayer(nn.Module):
     out_proj: bool = False
     use_flash: bool = False
     use_serving: bool = False  # forward-only Pallas serving kernel
-    mesh: Optional[object] = None  # jax Mesh → ring attention over 'seq'
+    # jax Mesh the program is partitioned over: the Pallas kernels run
+    # per 'data' shard (ops/_pallas.over_data_axis); with `ring`, exact
+    # ring attention over its 'seq' axis instead.
+    mesh: Optional[object] = None
+    ring: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -269,7 +274,7 @@ class AttnLayer(nn.Module):
         qh = nn.DenseGeneral((self.attn_heads, head_dim), **kw)(q)
         kh = nn.DenseGeneral((self.attn_heads, head_dim), **kw)(kv)
         vh = nn.DenseGeneral((self.attn_heads, head_dim), **kw)(kv)
-        if self.mesh is not None:
+        if self.ring:
             # Sequence-parallel exact attention: tokens sharded over 'seq',
             # batch riding the 'data' axis, k/v blocks rotating via ppermute.
             from novel_view_synthesis_3d_tpu.parallel.mesh import DATA_AXIS
@@ -285,11 +290,11 @@ class AttnLayer(nn.Module):
             # for forward-only step programs.
             from novel_view_synthesis_3d_tpu.ops.serving_attention import (
                 serving_attention)
-            out = serving_attention(qh, kh, vh)
+            out = over_data_axis(serving_attention, self.mesh)(qh, kh, vh)
         elif self.use_flash:
             from novel_view_synthesis_3d_tpu.ops.flash_attention import (
                 flash_attention)
-            out = flash_attention(qh, kh, vh)
+            out = over_data_axis(flash_attention, self.mesh)(qh, kh, vh)
         else:
             out = nn.dot_product_attention(qh, kh, vh)  # (B, L, heads, hd)
         if self.out_proj:
@@ -314,6 +319,7 @@ class AttnBlock(nn.Module):
     use_flash: bool = False
     use_serving: bool = False
     mesh: Optional[object] = None
+    ring: bool = False
     per_frame_gn: bool = True
     fused_gn: bool = False
     dtype: jnp.dtype = jnp.float32
@@ -328,6 +334,7 @@ class AttnBlock(nn.Module):
         layer = AttnLayer(attn_heads=self.attn_heads, out_proj=self.out_proj,
                           use_flash=self.use_flash,
                           use_serving=self.use_serving, mesh=self.mesh,
+                          ring=self.ring,
                           dtype=self.dtype, param_dtype=self.param_dtype)
         if self.attn_type == "self":
             out = layer(q=tokens.reshape(B * F, H * W, C),
@@ -361,6 +368,7 @@ class XUNetBlock(nn.Module):
     attn_use_flash: bool = False
     attn_use_serving: bool = False
     attn_mesh: Optional[object] = None
+    attn_ring: bool = False
     dropout: float = 0.0
     train: bool = False  # attribute (not call arg) so nn.remat needs no statics
     per_frame_gn: bool = True
@@ -376,6 +384,7 @@ class XUNetBlock(nn.Module):
         attn_kw = dict(attn_heads=self.attn_heads, out_proj=self.attn_out_proj,
                        use_flash=self.attn_use_flash,
                        use_serving=self.attn_use_serving, mesh=self.attn_mesh,
+                       ring=self.attn_ring,
                        **kw)
         h = ResnetBlock(features=self.features, dropout=self.dropout,
                         fused_epilogue=self.fused_epilogue,

@@ -346,9 +346,11 @@ def op_groups(cfg: ModelConfig):
 class XUNet(nn.Module):
     """The X-UNet (reference model/xunet.py:205-280), config-driven.
 
-    `mesh` activates sequence-parallel ring attention when
-    config.sequence_parallel is set (tokens sharded over the mesh 'seq'
-    axis; parallel/ring_attention.py).
+    `mesh` is the device mesh the model's programs are partitioned
+    over: the Pallas attention kernels then run per 'data' shard (GSPMD
+    cannot partition them — ops/_pallas.over_data_axis), and with
+    config.sequence_parallel attention is the exact ring over the 'seq'
+    axis (parallel/ring_attention.py). None = one device.
 
     The body is an ordered list of ops (pipeline_op_specs): the default
     call runs all of them — numerically and param-tree identical to the
@@ -393,7 +395,9 @@ class XUNet(nn.Module):
                 attn_use_flash=resolve_flash(cfg.use_flash_attention),
                 attn_use_serving=resolve_serving_attention(
                     cfg.use_serving_attention),
-                attn_mesh=(self.mesh if cfg.sequence_parallel else None),
+                attn_mesh=self.mesh,
+                attn_ring=(cfg.sequence_parallel
+                           and self.mesh is not None),
                 dropout=cfg.dropout,
                 train=train,
                 name=name,

@@ -27,47 +27,52 @@ from __future__ import annotations
 import threading
 from typing import Callable, List, Optional
 
-# Dense bf16 peak FLOPs per chip, public spec sheets. v5e/v5litepod:
-# 197 TF (394 is its int8 TOPS figure, not bf16); v4: 275 TF;
-# v6e/trillium: 918 TF. Unknown kinds return None — an absent MFU beats
-# one silently computed against the wrong peak.
-_PEAK_FLOPS_BY_KIND = (("v5lite", 197e12), ("v5e", 197e12),
-                       ("v6", 918e12), ("v4", 275e12))
-
-# HBM bandwidth per chip, same spec sheets and keying: v5e/v5litepod
-# 819 GB/s, v4 1228 GB/s, v6e/trillium 1640 GB/s. The roofline join
-# (obs/roofline.py) divides by this to classify memory-bound groups —
-# this table is its one home, next to the FLOPs peaks it pairs with.
-_PEAK_BYTES_BY_KIND = (("v5lite", 819e9), ("v5e", 819e9),
-                       ("v6", 1640e9), ("v4", 1228e9))
+# Per-chip peaks from public spec sheets, keyed on `device_kind`: dense
+# bf16 FLOP/s (v5e/v5litepod: 197 TF — 394 is its int8 TOPS figure; v4:
+# 275 TF; v6e/trillium: 918 TF) and HBM bytes/s (819 / 1228 / 1640 GB/s).
+# The one home of these numbers: bench.py, the trainer's MFU gauge and the
+# roofline join (obs/roofline.py) all read them here. An accelerator kind
+# that is not in the table is an error — a utilization silently computed
+# against the wrong peak, or silently absent, is worse than none.
+_PEAKS_BY_KIND = (("v5lite", 197e12, 819e9), ("v5e", 197e12, 819e9),
+                  ("v6", 918e12, 1640e9), ("v4", 275e12, 1228e9))
 
 
-def _peak_by_kind(table, device) -> Optional[float]:
+def _peaks(device) -> Optional[tuple]:
+    """(peak FLOP/s, peak bytes/s) of one chip; None on CPU."""
     import jax
 
     if device is None:
-        devices = jax.devices()
-        if not devices:
-            return None
-        device = devices[0]
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
     kind = device.device_kind.lower().replace(" ", "")
-    return next((v for k, v in table if k in kind), None)
+    for key, flops, byts in _PEAKS_BY_KIND:
+        if key in kind:
+            return flops, byts
+    raise KeyError(
+        f"device_kind {device.device_kind!r} ({device.platform}) is not "
+        "in the peak table (obs/devmon._PEAKS_BY_KIND): add its spec-"
+        "sheet peaks before reporting utilization on it")
 
 
 def device_peak_flops(device=None) -> Optional[float]:
-    """Dense bf16 peak FLOPs/s for one chip, or None if unknown."""
-    return _peak_by_kind(_PEAK_FLOPS_BY_KIND, device)
+    """Dense bf16 peak FLOPs/s for one chip; None on CPU, KeyError for
+    an accelerator kind the table does not know."""
+    peaks = _peaks(device)
+    return peaks and peaks[0]
 
 
 def device_peak_bytes_per_s(device=None) -> Optional[float]:
-    """Peak HBM bytes/s for one chip, or None if unknown (CPU)."""
-    return _peak_by_kind(_PEAK_BYTES_BY_KIND, device)
+    """Peak HBM bytes/s for one chip; None on CPU, KeyError for an
+    accelerator kind the table does not know."""
+    peaks = _peaks(device)
+    return peaks and peaks[1]
 
 
 def mfu(flops_per_step: float, steps_per_sec: float,
         n_chips: Optional[int] = None) -> Optional[float]:
-    """Model-FLOPs utilization in [0, 1], or None when the chip's peak is
-    unknown (CPU, unrecognized TPU generation)."""
+    """Model-FLOPs utilization in [0, 1], or None on CPU (no peak)."""
     import jax
 
     peak = device_peak_flops()

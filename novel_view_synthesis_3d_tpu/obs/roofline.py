@@ -151,21 +151,18 @@ def analyze_run(run_dir: str, *, peak_flops: Optional[float] = None,
                      "time; run with obs.profile.enabled to measure)")
     group_seconds = dict((window or {}).get("groups") or {})
     if peak_flops is None or peak_bytes_per_s is None:
-        try:
-            from novel_view_synthesis_3d_tpu.obs.devmon import (
-                device_peak_bytes_per_s,
-                device_peak_flops,
-            )
+        from novel_view_synthesis_3d_tpu.obs.devmon import (
+            device_peak_bytes_per_s,
+            device_peak_flops,
+        )
 
-            if peak_flops is None:
-                peak_flops = device_peak_flops()
-            if peak_bytes_per_s is None:
-                peak_bytes_per_s = device_peak_bytes_per_s()
-        except Exception:
-            pass
+        if peak_flops is None:
+            peak_flops = device_peak_flops()
+        if peak_bytes_per_s is None:
+            peak_bytes_per_s = device_peak_bytes_per_s()
     if not peak_flops and not peak_bytes_per_s:
-        notes.append("chip peaks unknown (CPU or untabulated kind) — "
-                     "bound classification degraded to 'unknown'")
+        notes.append("no chip peaks on CPU — bound classification "
+                     "degraded to 'unknown'")
     if window and window.get("other_s", 0.0) > 0.5 * max(
             window.get("total_s") or 1e-12, 1e-12):
         notes.append(

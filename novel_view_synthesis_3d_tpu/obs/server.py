@@ -4,7 +4,7 @@ Stdlib ``http.server`` only — nothing to install on a TPU VM. OFF by
 default: the server starts only when ``obs.metrics_port`` is set, and it
 binds 127.0.0.1 unless ``obs.metrics_host`` says otherwise (a training
 host should not expose an unauthenticated scrape target to the network;
-reach it remotely over an SSH tunnel — docs/TPU_VM_SETUP.md).
+reach it remotely over an SSH port forward — docs/TPU_VM_SETUP.md).
 
 ``/metrics`` renders the shared registry in Prometheus format 0.0.4;
 ``/healthz`` answers ``ok`` (livenesss for the supervisor or an external

@@ -13,14 +13,29 @@ ops/fused_groupnorm.py; this module is their one home, and new kernels
 from __future__ import annotations
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-# Conservative per-program VMEM budget for a kernel's resident input
-# slab(s). v5e has ~16 MB VMEM/core and a kernel typically also holds an
-# f32 working copy (2-4x the slab), f32 intermediates, and the output —
-# a 3 MiB input slab bounds the worst case at ~12 MiB. Strict `<` in
-# fits_vmem so power-of-two slab sizes (every UNet level is one) can't
-# sit on a zero-headroom boundary.
+from novel_view_synthesis_3d_tpu.parallel.dist import answered_platform
+from novel_view_synthesis_3d_tpu.parallel.mesh import DATA_AXIS
+
+VMEM = pltpu.VMEM
+
+# Per-program budget for a kernel's resident input slab(s). A slab
+# kernel holds its in/out blocks double-buffered plus f32 working
+# copies (2× a bf16 slab each): a 2 MiB bf16 slab already measures
+# 16.25 MiB of scoped VMEM in the chip's compiler, past its 16 MiB
+# default, so the slab kernels ask for VMEM_LIMIT_BYTES (v5e has
+# 128 MiB of VMEM per core). Strict `<` in fits_vmem so power-of-two
+# slab sizes (every UNet level is one) can't sit on a zero-headroom
+# boundary.
 SLAB_LIMIT_BYTES = 3 * 1024 * 1024
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
+def slab_compiler_params() -> pltpu.CompilerParams:
+    """Compiler parameters of the kernels guarded by `fits_vmem`."""
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def use_interpret() -> bool:
@@ -28,8 +43,31 @@ def use_interpret() -> bool:
 
     This is how tier-1 (JAX_PLATFORMS=cpu) executes the exact same
     kernel code path the TPU compiles — correctness is proven on the
-    bits that ship, not on an XLA stand-in."""
-    return jax.default_backend() != "tpu"
+    bits that ship, not on an XLA stand-in. On platform 'tpu' the
+    kernels are compiled: the platform is the one that answered AND was
+    asked for (parallel/dist.answered_platform raises otherwise), never
+    a guess from the backend's name."""
+    return answered_platform() != "tpu"
+
+
+def over_data_axis(fn, mesh):
+    """`fn` as a per-shard call over `mesh`'s 'data' axis.
+
+    GSPMD cannot partition a compiled Pallas kernel ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map") — on a multi-chip mesh the program does not lower. Every
+    kernel here treats the rows of its leading (batch) dimension
+    independently, and the batch is what the mesh shards over 'data', so
+    the call runs inside a shard_map over that axis; the other axes see
+    replicated operands. `fn` takes and returns arrays whose leading
+    dimension is the batch. No-op without a mesh, on a one-shard data
+    axis, and inside a shard_map that already holds the axis (the
+    pipeline-staged step, parallel/pipeline.py)."""
+    if (mesh is None or mesh.shape[DATA_AXIS] == 1
+            or DATA_AXIS in jax.sharding.get_abstract_mesh().manual_axes):
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(DATA_AXIS),
+                         out_specs=P(DATA_AXIS), check_vma=False)
 
 
 def resolve_flag(flag, field: str) -> bool:

@@ -42,13 +42,6 @@ from jax.experimental import pallas as pl
 
 from novel_view_synthesis_3d_tpu.ops import _pallas
 
-try:  # pltpu only imports on TPU-capable jaxlibs; interpret mode needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
 _NEG_INF = -1e30
 
 
@@ -98,7 +91,7 @@ def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
     Lk = k.shape[1]
     grid = (N, Lq // block_q)
     kernel = functools.partial(_attn_kernel, scale=scale, kv_len=kv_len)
-    mem = {} if _VMEM is None or interpret else {"memory_space": _VMEM}
+    mem = {} if interpret else {"memory_space": _pallas.VMEM}
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -258,7 +251,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, scale: float, block_q: int):
         vt = _pad_to(vt, 2, 128)
         dot = _pad_to(dot, 2, 128)
     N, _, Dp = qt.shape
-    mem = {} if _VMEM is None or interpret else {"memory_space": _VMEM}
+    mem = {} if interpret else {"memory_space": _pallas.VMEM}
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, kv_len=Lk),

@@ -33,6 +33,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from novel_view_synthesis_3d_tpu.ops import _pallas
+from novel_view_synthesis_3d_tpu.ops.fused_groupnorm import (
+    affine_row,
+    group_moments,
+    round_to,
+    slab_blocks,
+    swish_in,
+)
 
 
 def resolve_fused_epilogue(flag) -> bool:
@@ -53,23 +60,17 @@ def fits_vmem(hw: int, c: int, dtype) -> bool:
 def _epilogue_kernel(x_ref, g_ref, b_ref, s_ref, t_ref, y_ref, mean_ref,
                      rstd_ref, *, groups: int, eps: float):
     x = x_ref[0].astype(jnp.float32)            # (HW, C)
-    hw, c = x.shape
-    cg = c // groups
-    xg = x.reshape(hw, groups, cg)
-    mean = jnp.mean(xg, axis=(0, 2))            # (G,)
-    # Two-pass variance over the VMEM-resident slab (ops/fused_groupnorm
-    # rationale: no E[x²]−E[x]² cancellation, no extra HBM traffic).
-    var = jnp.mean(jnp.square(xg - mean[None, :, None]), axis=(0, 2))
-    rstd = jax.lax.rsqrt(var + eps)
-    xhat = ((xg - mean[None, :, None]) * rstd[None, :, None]).reshape(hw, c)
-    gn = xhat * g_ref[...].astype(jnp.float32) + b_ref[...].astype(
-        jnp.float32)
-    # Cast BEFORE modulate+activate to mirror the XLA ordering:
-    # nn.GroupNorm casts its output to the module dtype, then FiLM's
-    # h·(1+s)+t and the swish run in that dtype.
-    gn = gn.astype(y_ref.dtype)
-    z = gn * (jnp.ones((), y_ref.dtype) + s_ref[0]) + t_ref[0]
-    y_ref[0] = z * jax.nn.sigmoid(z)
+    xc, rstd_c, mean, rstd = group_moments(x, groups, eps)
+    # Round BEFORE modulate+activate, and after each op of that chain,
+    # to mirror the XLA ordering: nn.GroupNorm casts its output to the
+    # module dtype, then FiLM's h·(1+s)+t and the swish run in that
+    # dtype (ops/fused_groupnorm.round_to: f32 arithmetic, same bits).
+    dt = y_ref.dtype
+    gn = round_to(xc * rstd_c * g_ref[...] + b_ref[...], dt)
+    one_plus_s = round_to(1.0 + s_ref[0].astype(jnp.float32), dt)
+    z = round_to(round_to(gn * one_plus_s, dt)
+                 + t_ref[0].astype(jnp.float32), dt)
+    y_ref[0] = swish_in(z, dt)
     mean_ref[0] = mean
     rstd_ref[0] = rstd
 
@@ -78,29 +79,21 @@ def _forward(x, gscale, gbias, fscale, fshift, groups: int, eps: float,
              out_dtype):
     n, hw, c = x.shape
     kernel = functools.partial(_epilogue_kernel, groups=groups, eps=eps)
+    row, slab, stat = slab_blocks(hw, c, groups)
     y, mean, rstd = pl.pallas_call(
         kernel,
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, hw, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((1, hw, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, hw, c), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, hw, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, groups), lambda i: (i, 0)),
-            pl.BlockSpec((1, groups), lambda i: (i, 0)),
-        ],
+        in_specs=[slab, row, row, slab, slab],
+        out_specs=[slab, stat, stat],
         out_shape=[
             jax.ShapeDtypeStruct((n, hw, c), out_dtype or x.dtype),
-            jax.ShapeDtypeStruct((n, groups), jnp.float32),
-            jax.ShapeDtypeStruct((n, groups), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, groups), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, groups), jnp.float32),
         ],
+        compiler_params=_pallas.slab_compiler_params(),
         interpret=_pallas.use_interpret(),
-    )(x, gscale, gbias, fscale, fshift)
-    return y, mean, rstd
+    )(x, affine_row(gscale), affine_row(gbias), fscale, fshift)
+    return y, mean.reshape(n, groups), rstd.reshape(n, groups)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))

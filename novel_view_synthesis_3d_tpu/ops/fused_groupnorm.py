@@ -57,26 +57,79 @@ def fits_vmem(hw: int, c: int, dtype) -> bool:
     return _pallas.fits_vmem(hw * c * jnp.dtype(dtype).itemsize)
 
 
+def round_to(a, dtype):
+    """Round an f32 value to `dtype`'s grid, keeping the f32 container.
+
+    The chip's compiler takes no bf16 vector arithmetic (and no bf16
+    operand broadcast against f32 statistics), so the kernels compute in
+    f32 and round after each op the XLA path runs in the module dtype —
+    the same bits, op for op. A no-op at float32."""
+    return a.astype(dtype).astype(jnp.float32)
+
+
+def swish_in(y, dtype):
+    """y·σ(y) with the XLA path's rounding: XLA expands the logistic to
+    1/(1+exp(−y)) and rounds each op to the module dtype."""
+    sig = round_to(1.0 / round_to(1.0 + round_to(jnp.exp(-y), dtype), dtype),
+                   dtype)
+    return (y * sig).astype(dtype)
+
+
+def group_moments(x, groups: int, eps: float):
+    """Two-pass GroupNorm statistics of one (HW, C) f32 slab.
+
+    Returns (x − μ, rstd as a (1, C) row, μ and rstd as (1, G) rows).
+    Group sums contract the channel axis against a (G, C) one-hot
+    membership matrix, and the same matrix broadcasts group values back
+    to channels: the per-group (HW, C) → (HW, G, C/G) reshape is a shape
+    cast the chip's compiler refuses. E[(x−μ)²] is free of the
+    E[x²]−E[x]² cancellation and costs no extra HBM traffic on a
+    VMEM-resident slab."""
+    hw, c = x.shape
+    cg = c // groups
+    ch = jax.lax.broadcasted_iota(jnp.int32, (groups, c), 1)
+    lo = jax.lax.broadcasted_iota(jnp.int32, (groups, c), 0) * cg
+    member = ((ch >= lo) & (ch < lo + cg)).astype(jnp.float32)   # (G, C)
+
+    def dot(a, contract_member_axis):
+        return jax.lax.dot_general(
+            a, member, (((1,), (contract_member_axis,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    inv_n = 1.0 / float(hw * cg)
+    mean = dot(jnp.sum(x, axis=0, keepdims=True), 1) * inv_n      # (1, G)
+    xc = x - dot(mean, 0)
+    var = dot(jnp.sum(jnp.square(xc), axis=0, keepdims=True), 1) * inv_n
+    rstd = jax.lax.rsqrt(var + eps)
+    return xc, dot(rstd, 0), mean, rstd
+
+
+def slab_blocks(hw: int, c: int, groups: int):
+    """(affine row, slab, statistics) BlockSpecs of a grid over N rows:
+    affine rows as (1, C) and statistics as (N, 1, G), so that every
+    block's last two dims equal the array's, as the TPU block rule
+    requires (a (1, G) block over (N, G) is refused for N > 1)."""
+    return (pl.BlockSpec((1, c), lambda i: (0, 0)),
+            pl.BlockSpec((1, hw, c), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, groups), lambda i: (i, 0, 0)))
+
+
+def affine_row(a):
+    """A (C,) GroupNorm parameter as the kernel's (1, C) f32 row."""
+    return a.astype(jnp.float32).reshape(1, -1)
+
+
 def _gn_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref,
                *, groups: int, eps: float, act: Optional[str]):
     x = x_ref[0].astype(jnp.float32)            # (HW, C)
-    hw, c = x.shape
-    cg = c // groups
-    xg = x.reshape(hw, groups, cg)
-    mean = jnp.mean(xg, axis=(0, 2))            # (G,)
-    # Two-pass variance over the VMEM-resident slab: E[(x-μ)²] is free of
-    # the E[x²]-E[x]² cancellation and costs no extra HBM traffic here.
-    var = jnp.mean(jnp.square(xg - mean[None, :, None]), axis=(0, 2))
-    rstd = jax.lax.rsqrt(var + eps)
-    xhat = ((xg - mean[None, :, None]) * rstd[None, :, None]).reshape(hw, c)
-    y = xhat * g_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    # Cast BEFORE the activation to mirror the XLA path's ordering
+    xc, rstd_c, mean, rstd = group_moments(x, groups, eps)
+    # Round BEFORE the activation to mirror the XLA path's ordering
     # (nn.GroupNorm casts its output to the module dtype, then swish runs
     # in that dtype) — keeps the two paths interchangeable at bf16 too.
-    y = y.astype(y_ref.dtype)
-    if act == "swish":
-        y = y * jax.nn.sigmoid(y)
-    y_ref[0] = y
+    y = round_to(xc * rstd_c * g_ref[...] + b_ref[...], y_ref.dtype)
+    y_ref[0] = (swish_in(y, y_ref.dtype) if act == "swish"
+                else y.astype(y_ref.dtype))
     mean_ref[0] = mean
     rstd_ref[0] = rstd
 
@@ -85,27 +138,21 @@ def _forward(x, scale, bias, groups: int, eps: float, act: Optional[str],
              out_dtype):
     n, hw, c = x.shape
     kernel = functools.partial(_gn_kernel, groups=groups, eps=eps, act=act)
+    row, slab, stat = slab_blocks(hw, c, groups)
     y, mean, rstd = pl.pallas_call(
         kernel,
         grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, hw, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, hw, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, groups), lambda i: (i, 0)),
-            pl.BlockSpec((1, groups), lambda i: (i, 0)),
-        ],
+        in_specs=[slab, row, row],
+        out_specs=[slab, stat, stat],
         out_shape=[
             jax.ShapeDtypeStruct((n, hw, c), out_dtype or x.dtype),
-            jax.ShapeDtypeStruct((n, groups), jnp.float32),
-            jax.ShapeDtypeStruct((n, groups), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, groups), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1, groups), jnp.float32),
         ],
+        compiler_params=_pallas.slab_compiler_params(),
         interpret=_use_interpret(),
-    )(x, scale, bias)
-    return y, mean, rstd
+    )(x, affine_row(scale), affine_row(bias))
+    return y, mean.reshape(n, groups), rstd.reshape(n, groups)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -49,13 +49,6 @@ from jax.experimental import pallas as pl
 
 from novel_view_synthesis_3d_tpu.ops import _pallas
 
-try:  # pltpu only imports on TPU-capable jaxlibs; interpret needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
 _LANES = 128
 
 # Row-parameter columns: STEP_COEF_KEYS order (sample/ddpm.py), then the
@@ -152,12 +145,12 @@ def _step_kernel(z_ref, ec_ref, eu_ref, nz_ref, rp_ref, o_ref, *,
                  clip_denoised: bool, n_valid: int):
     """One batch row's fused update, entirely in VMEM.
 
-    z/ec/eu/nz/o refs are (1, M, 128) slabs; rp_ref is the (1, 128)
+    z/ec/eu/nz/o refs are (1, M, 128) slabs; rp_ref is the (1, 1, 128)
     row-parameter vector (_COEF_COLS + w). `n_valid` is the true
     (unpadded) element count — static; only the cfg-rescale row-std
     reduction needs it (all other math is elementwise, and padded
     lanes are sliced off by the wrapper)."""
-    rp = rp_ref[0]
+    rp = rp_ref[0, 0]
 
     def c(name):
         return rp[_COEF_COLS[name]]
@@ -260,7 +253,7 @@ def fused_denoise_step(z: jnp.ndarray, eps_cond: jnp.ndarray,
         _step_kernel, sampler=sampler, objective=objective,
         eta=float(eta), phi=float(cfg_rescale),
         clip_denoised=bool(clip_denoised), n_valid=L)
-    mem = {} if _VMEM is None or interpret else {"memory_space": _VMEM}
+    mem = {} if interpret else {"memory_space": _pallas.VMEM}
     out = pl.pallas_call(
         kernel,
         grid=(B,),
@@ -269,10 +262,14 @@ def fused_denoise_step(z: jnp.ndarray, eps_cond: jnp.ndarray,
             pl.BlockSpec((1, M, _LANES), lambda i: (i, 0, 0), **mem),
             pl.BlockSpec((1, M, _LANES), lambda i: (i, 0, 0), **mem),
             pl.BlockSpec((1, M, _LANES), lambda i: (i, 0, 0), **mem),
-            pl.BlockSpec((1, _LANES), lambda i: (i, 0), **mem),
+            # (B, 1, 128) view: the trailing (1, 128) block equals the
+            # array's last two dims, which the TPU block rule requires
+            # (a (1, 128) block over (B, 128) is refused for B > 1).
+            pl.BlockSpec((1, 1, _LANES), lambda i: (i, 0, 0), **mem),
         ],
         out_specs=pl.BlockSpec((1, M, _LANES), lambda i: (i, 0, 0), **mem),
         out_shape=jax.ShapeDtypeStruct((B, M, _LANES), z.dtype),
         interpret=interpret,
-    )(slab(z), slab(eps_cond), slab(eps_uncond), slab(noise), rp)
+    )(slab(z), slab(eps_cond), slab(eps_uncond), slab(noise),
+      rp.reshape(B, 1, _LANES))
     return out.reshape(B, M * _LANES)[:, :L].reshape(z.shape)

@@ -38,13 +38,6 @@ from jax.experimental import pallas as pl
 
 from novel_view_synthesis_3d_tpu.ops import _pallas
 
-try:  # pltpu only imports on TPU-capable jaxlibs; interpret needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
 _NEG_INF = -1e30
 _LANES = 128
 
@@ -158,7 +151,7 @@ def serving_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         vt = _pad_to(vt, 2, _LANES)
     N, Lq_p, Dp = qt.shape
     Lk_pad = kt.shape[1]
-    mem = {} if _VMEM is None or interpret else {"memory_space": _VMEM}
+    mem = {} if interpret else {"memory_space": _pallas.VMEM}
     out = pl.pallas_call(
         functools.partial(_serving_kernel, scale=scale, kv_len=Lk),
         grid=(N, Lq_p // bq),

@@ -28,16 +28,10 @@ _NEG_INF = -1e30
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map with a fallback for jax builds that predate its
-    top-level promotion (< 0.6): jax.experimental.shard_map is the same
-    transform with the replication check under its older name."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """jax.shard_map without the replication (vma) check — shared by the
+    ring, ZeRO and pipeline shard_maps."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _block_update(q, k, v, m_prev, l_prev, o_prev, scale):

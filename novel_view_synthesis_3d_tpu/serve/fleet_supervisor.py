@@ -33,11 +33,20 @@ crash-looping spec needs a human, not a hotter loop.
 Everything external is injectable (spawn, probe, heartbeat age, clock,
 sleep) so tier-1 tests drill every detector with fakes; the defaults
 drive real subprocesses for serve_bench --fleet's chaos phases.
+
+One process per chip: a chip belongs to one process at a time, so the
+LAUNCHER never brings up an accelerator backend (it counts chips from
+device nodes, `host_chips`) and hands each replica process exactly one
+chip through its environment BEFORE the child imports JAX
+(`assign_chips`; the overlay is stored under "env" in the replica's spec
+file, so a respawn gets its predecessor's chip). More replica processes
+than chips is refused loudly, not left to hang.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import glob
 import json
 import os
 import subprocess
@@ -76,15 +85,61 @@ class _Slot:
         self.resurrections = 0
 
 
+def host_chips() -> int:
+    """TPU chips this host exposes, counted from their device nodes —
+    asking JAX would take the chips the replicas need."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def assign_chips(n_replicas: int, *, chips: Optional[int] = None,
+                 environ=None) -> List[Dict[str, str]]:
+    """Environment overlay for each of `n_replicas` replica processes.
+
+    On the CPU lane (JAX_PLATFORMS=cpu asked for) the overlays are empty
+    and any number of replicas may share the host. Otherwise replica i
+    is confined to chip i as a one-chip slice of its own; asking for
+    more replica processes than the host has chips raises — the extra
+    process would fail or hang on a chip another process holds.
+    `chips` defaults to `host_chips()`."""
+    environ = os.environ if environ is None else environ
+    if environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu":
+        return [{} for _ in range(n_replicas)]
+    if chips is None:
+        chips = host_chips()
+    if n_replicas > chips:
+        raise RuntimeError(
+            f"{n_replicas} replica processes asked for on a host with "
+            f"{chips} chip(s): a chip belongs to one process at a time "
+            "(fewer replicas, or JAX_PLATFORMS=cpu for the CPU lane)")
+    return [{
+        "TPU_VISIBLE_CHIPS": str(i),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{8476 + i}",
+        "TPU_PROCESS_PORT": str(8476 + i),
+        "CLOUD_TPU_TASK_ID": "0",
+    } for i in range(n_replicas)]
+
+
+def spec_env(spec_path: str) -> Dict[str, str]:
+    """This process's environment plus the chip overlay the launcher
+    stored in the replica's spec file."""
+    with open(spec_path) as fh:
+        overlay = json.load(fh).get("env") or {}
+    return dict(os.environ, **{str(k): str(v) for k, v in overlay.items()})
+
+
 def _default_spawn(spec: ReplicaSpec):
     cmd = [sys.executable, "-m",
            "novel_view_synthesis_3d_tpu.serve.replica_main",
            spec.spec_path]
+    env = spec_env(spec.spec_path)
     if spec.log_path:
         with open(spec.log_path, "ab") as log:
             return subprocess.Popen(cmd, stdout=log,
-                                    stderr=subprocess.STDOUT)
-    return subprocess.Popen(cmd)
+                                    stderr=subprocess.STDOUT, env=env)
+    return subprocess.Popen(cmd, env=env)
 
 
 def _default_probe(spec: ReplicaSpec) -> dict:

@@ -27,8 +27,12 @@ Spec keys:
     steps           diffusion.sample_timesteps (default 4)
     overrides       {dotted.key: value} extra config overrides
     port            bind port (default 0 = ephemeral)
-    jax_cache_dir   shared persistent compile cache (optional; fleet
-                    benches share one so N replicas pay one compile)
+    jax_cache_dir   shared persistent compile cache, used when
+                    JAX_COMPILATION_CACHE_DIR is unset (optional;
+                    default <checkout>/.jax_cache, which the replicas of
+                    one checkout share anyway: N pay one compile)
+    env             chip overlay the launcher applies to this process's
+                    environment (serve/fleet_supervisor.assign_chips)
     registry        {"dir": ..., "channel": ..., "poll_s": ...} —
                     subscribe a RegistryWatcher; initial weights load
                     from the channel head when it points at a version
@@ -104,12 +108,11 @@ def main(argv=None) -> int:
     with open(argv[0]) as fh:
         spec = json.load(fh)
 
-    if spec.get("jax_cache_dir"):
-        from novel_view_synthesis_3d_tpu.utils.xla_cache import (
-            setup_compilation_cache)
+    from novel_view_synthesis_3d_tpu.utils.xla_cache import (
+        setup_compilation_cache)
 
-        setup_compilation_cache(default_dir=spec["jax_cache_dir"],
-                                min_entry_bytes=0)
+    setup_compilation_cache(default_dir=spec.get("jax_cache_dir"),
+                            min_entry_bytes=0)
 
     from novel_view_synthesis_3d_tpu import obs
     from novel_view_synthesis_3d_tpu.config import get_preset
@@ -176,8 +179,13 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGTERM, lambda *_: stop.set())
     signal.signal(signal.SIGINT, lambda *_: stop.set())
 
+    import jax
+
+    device = jax.devices()[0]
     ready = {"port": server.port, "pid": os.getpid(),
-             "url": server.url(), "name": name}
+             "url": server.url(), "name": name,
+             "device": {"platform": device.platform,
+                        "kind": device.device_kind, "id": device.id}}
     tmp = spec["ready_file"] + ".tmp"
     with open(tmp, "w") as fh:
         json.dump(ready, fh)

@@ -112,12 +112,13 @@ def create_train_state(cfg: TrainConfig, model, sample_batch: dict,
     each device differently, train.py:122-123) and build the state.
 
     `on_cpu` (default: automatically True off the CPU backend) runs the init
-    forward on the host: flax init dispatches thousands of small eager ops,
-    which over a remote-accelerator link takes minutes for large models,
-    while the threefry PRNG makes the resulting params bitwise identical on
-    every backend. The init pass swaps in a dense-attention model (Pallas
-    kernels can't lower on CPU, shard_map can't use remote device meshes) —
-    neither feature has parameters, so the tree is unchanged.
+    forward on the host (the accelerator run's platform list must include
+    'cpu'): the state is then built in host memory and reaches the
+    accelerator only through the caller's sharded device_put, and the
+    threefry PRNG makes the resulting params bitwise identical on every
+    backend. The init pass swaps in a dense-attention model (Pallas kernels
+    can't lower on CPU, a shard_map over the accelerator mesh can't run
+    there) — neither feature has parameters, so the tree is unchanged.
     """
     seed = cfg.seed if seed is None else seed
     root = jax.random.PRNGKey(seed)

@@ -496,7 +496,7 @@ def make_train_step(config: Config, model, schedule: DiffusionSchedule,
     # dispatches (state.step advances inside the scan, so fold_in-derived
     # noise/dropout/CFG keys match the sequential run exactly); what
     # disappears is K-1 host dispatch round trips, the dominant cost for
-    # small models and remote-device runtimes. loss/grad_norm come back as
+    # small models. loss/grad_norm come back as
     # the window mean (per-step values inside the window are unobservable
     # to the logger anyway); lr is the LAST step's value — a schedule
     # position, where a window mean would misreport the logged step.

@@ -304,7 +304,12 @@ class Trainer:
                         max_record_retries=config.data.max_record_retries)
                     self.data_iter = iter(self._native_loader)
                 else:
-                    backend = "grain"  # graceful fallback
+                    # The documented fallback, said out loud: a run that
+                    # quietly lost its C++ loader looks like a slow chip.
+                    print("note: native IO library unavailable (`make -C "
+                          "native` failed or ABI mismatch): data.loader="
+                          "'native' falls back to the Grain loader")
+                    backend = "grain"
             if self._packed_loader is not None:
                 pass  # data_iter already set above
             elif backend == "grain" and config.data.num_workers > 0:
@@ -331,9 +336,7 @@ class Trainer:
         if config.train.remat != "":
             import dataclasses as _dc
             model_cfg = _dc.replace(model_cfg, remat=config.train.remat)
-        self.model = XUNet(
-            model_cfg,
-            mesh=self.mesh if config.model.sequence_parallel else None)
+        self.model = XUNet(model_cfg, mesh=self.mesh)
         first_batch = next(self.data_iter)
         self._held_batch = first_batch
         self._device_batch = None  # staged batch for the NEXT dispatch

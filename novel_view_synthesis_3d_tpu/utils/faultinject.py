@@ -35,10 +35,6 @@ Injection points:
                             drill for utils/watchdog.py. Exact-step match,
                             so a supervised restart that resumes PAST the
                             armed step does not re-stall.
-  NVS3D_FI_PROBE_HANG       "1": parallel/dist.probe_backend's disposable
-                            child sleeps forever (wedged-tunnel drill);
-  NVS3D_FI_PROBE_FAIL       "1": the probe child exits non-zero instead
-                            (dead-backend drill, no timeout burn).
   NVS3D_FI_CORRUPT_SHARD_AT comma list of packed-shard ordinals; the
                             packed-record reader (data/records.py) sees a
                             FLIPPED BYTE in those shards' streams at open

@@ -20,7 +20,7 @@ separate from steady-state step budgets). On expiry it:
      cross-host-agreed checkpoint-and-exit or degrades, per phase);
   3. optionally HARD-EXITS: if the phase is still stuck `hard_exit_s`
      seconds past its budget — the main thread never returned to observe
-     the soft flag, i.e. a true wedge such as uninterruptible tunnel IO —
+     the soft flag, i.e. a true wedge such as uninterruptible IO —
      the monitor dumps a final bundle and `os._exit(EXIT_STALL)` so a
      supervisor (train/supervisor.py) can restart the host. One stuck
      host exiting beats one stuck host wedging the whole slice.
@@ -42,7 +42,7 @@ from typing import Callable, Dict, Optional
 # Process exit code for a watchdog-declared stall (soft checkpoint-and-exit
 # in cli.cmd_train, or the monitor's hard exit). Distinct from
 # parallel/dist.EXIT_BACKEND_UNREACHABLE (3): a stall mid-run is a
-# different diagnosis than a backend that never answered at all.
+# different diagnosis than a platform that did not answer at start-up.
 EXIT_STALL = 74
 
 # Canonical phase name -> config.WatchdogConfig budget field.

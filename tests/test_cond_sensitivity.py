@@ -1,6 +1,6 @@
 """Conditioning-sensitivity standing metric (VERDICT r3 item 3).
 
-The r2/r3 quality postmortem (results/RESULTS_r03.md): an attn_resolutions
+The r2/r3 quality postmortem (record deleted in PR 21): an attn_resolutions
 set matching no UNet level cut the ONLY path from the conditioning image to
 the target frame, and the model trained as an unconditional pose-memorizer
 whose seen-pose PSNR looked healthy. The diagnostic that caught it — output

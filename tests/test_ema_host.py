@@ -1,8 +1,8 @@
 """Host-side EMA (train.ema_host): HBM-free EMA buffer in host RAM.
 
 Motivated by hardware: the paper256 state (708M params) with a device f32
-EMA copy measured 17.94G of 15.75G v5e HBM (results/tpu_r04/
-analyze_paper256.out) — the EMA copy (2.64G) IS the OOM margin. bf16 EMA
+EMA copy measured 17.94G of 15.75G v5e HBM (record deleted in PR 21) —
+the EMA copy (2.64G) IS the OOM margin. bf16 EMA
 would silently never update (decay 0.9999 increments round to zero in 8
 mantissa bits), so the buffer moves to host RAM instead, folded in every
 ema_host_every steps with the decay^k correction.

@@ -122,3 +122,31 @@ def test_model_flag_wires_kernel():
                     train=False)
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1),
                                atol=1e-5, rtol=1e-5)
+
+
+def test_attention_layer_runs_the_kernel_per_data_shard():
+    """AttnLayer with a mesh wraps the kernel in a shard_map over 'data'
+    (ops/_pallas.over_data_axis — the compiled kernel cannot be
+    partitioned by GSPMD): same values as the unwrapped layer, output
+    still batch-sharded."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from novel_view_synthesis_3d_tpu.config import MeshConfig
+    from novel_view_synthesis_3d_tpu.models.layers import AttnLayer
+    from novel_view_synthesis_3d_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(MeshConfig(data=4))
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, 24, 32))
+    plain = AttnLayer(attn_heads=4, use_flash=True)
+    params = plain.init(jax.random.PRNGKey(1), q=x, kv=x)
+    want = plain.apply(params, q=x, kv=x)
+    sharded = jax.device_put(x, NamedSharding(mesh, P("data")))
+    layer = AttnLayer(attn_heads=4, use_flash=True, mesh=mesh)
+    got = jax.jit(lambda p, x: layer.apply(p, q=x, kv=x))(params, sharded)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert got.sharding.spec[0] == "data"
+    text = jax.jit(lambda p, x: layer.apply(p, q=x, kv=x)).lower(
+        params, sharded).as_text()
+    assert "shard_map" in text or "manual" in text
+

@@ -128,7 +128,7 @@ def test_sequence_parallel_forward_matches_dense():
 
 
 def test_host_side_init_matches_default():
-    """create_train_state(on_cpu=True) — the remote-accelerator startup path
+    """create_train_state(on_cpu=True) — the accelerator start-up path
     — must produce the identical param tree (structure AND values; threefry
     is backend-deterministic) as the default init, including under the
     flash/sequence-parallel model variants it swaps out during init."""

@@ -3,7 +3,7 @@ one XLA program must be SEMANTICALLY identical to K single dispatches — same
 per-step fold_in(rng, step) keys, same optimizer trajectory — with only the
 host dispatch count changing. (The reference has one dispatch per step plus
 a host round trip per batch, train.py:130-155; this is the TPU-native lever
-that amortizes that overhead for small models and remote-device runtimes.)
+that amortizes that overhead for small models.)
 """
 
 import dataclasses

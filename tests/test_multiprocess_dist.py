@@ -25,7 +25,9 @@ import sys
 pid = int(sys.argv[1]); port = sys.argv[2]
 import jax
 jax.config.update("jax_platforms", "cpu")  # before any backend query
-jax.config.update("jax_compilation_cache_dir", "/tmp/nvs3d_jax_cache")
+from novel_view_synthesis_3d_tpu.utils.xla_cache import (
+    setup_compilation_cache)
+setup_compilation_cache()
 
 from novel_view_synthesis_3d_tpu.parallel.dist import (
     initialize_distributed, local_batch_size, process_shard)

@@ -1,10 +1,9 @@
 """Sampling-service tests: bucketing/padding correctness vs single-request
 reference images, request ordering, flush-timeout and backpressure paths,
 zero-recompile-after-warmup (jit cache-size counters), shard-aware
-dispatch over the 8-device test mesh, the trainer's device prefetcher,
-and the shared compile-cache helper."""
+dispatch over the 8-device test mesh, and the trainer's device
+prefetcher."""
 
-import os
 import time
 
 import jax
@@ -355,30 +354,8 @@ def test_trainer_honors_prefetch_depth_and_completes(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# shared compile-cache helper + fused-GN fallback logging satellites
+# fused-GN fallback logging satellites
 # ---------------------------------------------------------------------------
-def test_setup_compilation_cache_helper(tmp_path, monkeypatch):
-    from novel_view_synthesis_3d_tpu.utils import xla_cache
-
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                           str(tmp_path / "cache"))
-        got = xla_cache.setup_compilation_cache(default_dir=None)
-        assert got == str(tmp_path / "cache")
-        assert os.path.isdir(got)
-        assert jax.config.jax_compilation_cache_dir == got
-
-        monkeypatch.setenv("NVS3D_NO_COMPILE_CACHE", "1")
-        assert xla_cache.setup_compilation_cache() is None
-
-        monkeypatch.delenv("NVS3D_NO_COMPILE_CACHE")
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-        assert xla_cache.setup_compilation_cache(default_dir=None) is None
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-
-
 def test_log_once_dedups():
     from novel_view_synthesis_3d_tpu.utils.profiling import log_once
 

@@ -1,0 +1,166 @@
+"""Every Pallas kernel compiles for the chip, checked without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is
+described, not attached (`jax.experimental.topologies`). Interpret mode —
+how the rest of tier-1 runs these kernels — accepts block shapes, shape
+casts and VMEM footprints the chip's compiler refuses, so each kernel of
+the main path is compiled at the shapes base128 and paper256 produce.
+Nothing runs: a pass says the kernel lowers, not that it is right (the
+interpret-mode tests and chip_smoke.py's on-chip twins say that).
+
+The test steers `ops/_pallas.use_interpret` itself (code that asks JAX
+for its platform still sees the CPU); the program has no option for it.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+# Describing a chip takes libtpu's one-process lockfile although no chip
+# is held; another test process doing the same would skip this file.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from novel_view_synthesis_3d_tpu.ops import (
+    _pallas,
+    flash_attention,
+    fused_epilogue,
+    fused_groupnorm,
+    fused_step,
+    serving_attention,
+)
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """The four described chips of a v5e:2x2 host; skips where they cannot
+    be described. The persistent compile cache is off around these
+    compiles: an entry written for a described chip cannot be read back
+    without one, and the next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def v5e(v5e_devices):
+    return jax.sharding.SingleDeviceSharding(v5e_devices[0])
+
+
+def _attn(fn, L, hd, grad):
+    shape = (2, L, 4, hd)
+    if grad:
+        def loss(q, k, v):
+            return jnp.sum(fn(q, k, v).astype(F32))
+        return jax.grad(loss, argnums=(0, 1, 2)), [(shape, BF16)] * 3
+    return fn, [(shape, BF16)] * 3
+
+
+def _gn(hw, c):
+    return (lambda x, s, b: fused_groupnorm.fused_group_norm(
+        x, s, b, 32, 1e-6, "swish", BF16),
+        [((16, hw, c), BF16), ((c,), F32), ((c,), F32)])
+
+
+def _epilogue(hw, c):
+    return (lambda x, gs, gb, s, t: fused_epilogue.fused_film_epilogue(
+        x, gs, gb, s, t, 32, 1e-6, BF16),
+        [((16, hw, c), BF16), ((c,), F32), ((c,), F32),
+         ((16, hw, c), BF16), ((16, hw, c), BF16)])
+
+
+def _step(sampler, B, px):
+    img = ((B, px, px, 3), F32)
+    return (lambda z, ec, eu, nz, coefs, w: fused_step.fused_denoise_step(
+        z, ec, eu, nz, coefs, w, sampler=sampler, objective="eps",
+        eta=0.5 if sampler == "ddim" else 0.0),
+        [img] * 4 + [((B, 11), F32), ((B,), F32)])
+
+
+# base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
+# head dim 256. GroupNorm and epilogue cases are UNet level slabs (H·W, C)
+# that `fits_vmem` admits, the largest included.
+CASES = {
+    **{f"flash_{'fwdbwd' if g else 'fwd'}_L{L}_d{hd}":
+       _attn(flash_attention.flash_attention, L, hd, g)
+       for g in (False, True)
+       for L, hd in ((1024, 64), (256, 128), (1024, 256))},
+    **{f"serving_attention_L{L}_d{hd}":
+       _attn(serving_attention.serving_attention, L, hd, False)
+       for L, hd in ((1024, 64), (1024, 256))},
+    **{f"fused_groupnorm_{hw}x{c}": _gn(hw, c)
+       for hw, c in ((4096, 256), (1024, 1024), (256, 512))},
+    **{f"fused_epilogue_{hw}x{c}": _epilogue(hw, c)
+       for hw, c in ((1024, 256), (256, 512), (256, 1024))},
+    **{f"fused_step_{s}_B{B}_{px}px": _step(s, B, px)
+       for s in ("ddpm", "ddim") for B in (1, 2, 16) for px in (128, 256)},
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, v5e, monkeypatch):
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    fn, arg_specs = CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in arg_specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{name}: compiled without a Pallas kernel in it")
+
+
+def test_flash_compiles_under_a_four_chip_data_mesh(v5e_devices,
+                                                    monkeypatch):
+    """GSPMD refuses to partition a Mosaic kernel, so a batch-sharded
+    program with the bare kernel in it does not lower on a multi-chip
+    mesh; through `over_data_axis` (how models/layers.AttnLayer calls it
+    when the model holds a mesh) it compiles, with no collective added."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from novel_view_synthesis_3d_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    mesh = make_mesh(devices=v5e_devices)
+    assert dict(mesh.shape) == {"data": 4, "model": 1, "seq": 1}
+    qkv = [jax.ShapeDtypeStruct((8, 1024, 4, 64), BF16,
+                                sharding=NamedSharding(mesh, P("data")))] * 3
+
+    def grads_of(attn):
+        return jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v).astype(F32)),
+            argnums=(0, 1, 2)))
+
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        grads_of(flash_attention.flash_attention).lower(*qkv)
+    text = grads_of(_pallas.over_data_axis(
+        flash_attention.flash_attention, mesh)).lower(*qkv).compile(
+        ).as_text()
+    assert text.count("tpu_custom_call") == 3  # fwd, dq, dk/dv
+    assert "all-gather(" not in text and "all-reduce(" not in text
+
+
+def test_admitted_slabs_are_cases():
+    """The GroupNorm/epilogue cases above sit inside the VMEM guards the
+    model applies (a case the guard rejects would never reach the kernel
+    and would prove nothing)."""
+    for name in CASES:
+        if name.startswith(("fused_groupnorm_", "fused_epilogue_")):
+            hw, c = map(int, name.rsplit("_", 1)[1].split("x"))
+            mod = (fused_groupnorm if "groupnorm" in name
+                   else fused_epilogue)
+            assert mod.fits_vmem(hw, c, BF16), name

@@ -1,13 +1,12 @@
-"""Hang-and-stall robustness drills (utils/watchdog.py, parallel/dist
-probe, train/supervisor.py) — tier-1, CPU, deterministic.
+"""Hang-and-stall robustness drills (utils/watchdog.py,
+train/supervisor.py) — tier-1, CPU, deterministic.
 
 Every stall-shaped recovery path is driven by an injected hang
-(utils/faultinject.py NVS3D_FI_STALL_*_AT / NVS3D_FI_PROBE_*):
+(utils/faultinject.py NVS3D_FI_STALL_*_AT):
 
   data stall   → watchdog fires, diagnosis bundle, checkpoint-and-exit
   step stall   → cross-host-agreed checkpoint-and-exit, resumable
   save stall   → degrade with diagnosis; the run still completes
-  wedged probe → bench/cli exit with the structured code in seconds
   supervised   → crash/stall child restarted with backoff, resumes from
                  the last intact checkpoint, bounded by max_restarts
 """
@@ -25,7 +24,6 @@ from novel_view_synthesis_3d_tpu.config import (
     TrainConfig, WatchdogConfig,
 )
 from novel_view_synthesis_3d_tpu.data.synthetic import write_synthetic_srn
-from novel_view_synthesis_3d_tpu.parallel import dist
 from novel_view_synthesis_3d_tpu.train import supervisor
 from novel_view_synthesis_3d_tpu.utils import faultinject, watchdog
 
@@ -108,7 +106,7 @@ def test_null_watchdog_surface():
 
 def test_hard_exit_kills_a_truly_wedged_process(tmp_path):
     # The monitor thread must end a process whose main thread never comes
-    # back (the uninterruptible-tunnel-IO case): run one in a subprocess
+    # back (the uninterruptible-IO case): run one in a subprocess
     # and assert it dies with EXIT_STALL, fast, with the bundle on stderr.
     code = (
         "import time\n"
@@ -268,90 +266,6 @@ def test_clean_run_records_no_stall(srn_root, tmp_path):
     assert not any(",stall," in ln for ln in _events(tmp_path))
     assert tr.watchdog.stall_count == 0
     tr.ckpt.close()
-
-
-# ---------------------------------------------------------------------------
-# Backend probe: structured fail-fast instead of silent hang
-# ---------------------------------------------------------------------------
-def test_probe_backend_ok_on_cpu(monkeypatch):
-    ok, reason = dist.probe_backend(timeout_s=120.0)
-    assert ok, reason
-    # The watcher semantics: a CPU answer is not accelerator evidence.
-    ok, reason = dist.probe_backend(timeout_s=120.0,
-                                    require_accelerator=True)
-    assert not ok and "CPU" in reason
-
-
-def test_probe_backend_wedged_child_times_out(monkeypatch):
-    monkeypatch.setenv("NVS3D_FI_PROBE_HANG", "1")
-    t0 = time.monotonic()
-    ok, reason = dist.probe_backend(timeout_s=1.0)
-    assert not ok and "timed out" in reason
-    assert time.monotonic() - t0 < 30
-
-
-def test_probe_backend_dead_child_fails_fast(monkeypatch):
-    monkeypatch.setenv("NVS3D_FI_PROBE_FAIL", "1")
-    ok, reason = dist.probe_backend(timeout_s=30.0)
-    assert not ok and "rc=1" in reason
-
-
-def test_require_backend_exits_structured(monkeypatch, capsys):
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.setenv("NVS3D_FI_PROBE_FAIL", "1")
-    monkeypatch.setenv("NVS3D_PROBE_BUDGET_S", "1")
-    monkeypatch.setenv("NVS3D_PROBE_TRY_S", "1")
-    with pytest.raises(SystemExit) as exc:
-        dist.require_backend()
-    assert exc.value.code == dist.EXIT_BACKEND_UNREACHABLE
-    assert "unreachable" in capsys.readouterr().err
-
-
-def test_require_backend_skips_on_cpu_pin(monkeypatch):
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.setenv("NVS3D_FI_PROBE_HANG", "1")  # would hang if probed
-    dist.require_backend()  # returns immediately
-
-
-def _unreachable_env(tmp_path):
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    env.update(NVS3D_FI_PROBE_HANG="1", NVS3D_PROBE_BUDGET_S="3",
-               NVS3D_PROBE_TRY_S="3",
-               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-    return env
-
-
-def test_cli_train_unreachable_backend_structured_exit(tmp_path):
-    # The acceptance drill: `nvs3d train` against a wedged backend must be
-    # a structured sub-60s diagnosis, not a silent hang.
-    t0 = time.monotonic()
-    proc = subprocess.run(
-        [sys.executable, "-m", "novel_view_synthesis_3d_tpu", "train",
-         "--no-grain"],
-        cwd=REPO, env=_unreachable_env(tmp_path), capture_output=True,
-        text=True, timeout=120)
-    assert proc.returncode == dist.EXIT_BACKEND_UNREACHABLE, proc.stderr
-    assert "unreachable" in proc.stderr
-    assert time.monotonic() - t0 < 60
-
-
-def test_bench_unreachable_backend_structured_exit(tmp_path):
-    # With NVS3D_BENCH_REQUIRE_DEVICE=1 the bench keeps the PR 2
-    # contract this drill exists for: a wedged backend is a structured
-    # sub-60s rc=3 diagnosis. (Without the flag it now drops to the
-    # labeled CPU benchmark lane instead — tests/test_bench.py covers
-    # both sides of that fork; here we pin the hard-fail path because
-    # the probe fault injection is this file's machinery.)
-    env = _unreachable_env(tmp_path)
-    env["NVS3D_BENCH_REQUIRE_DEVICE"] = "1"
-    t0 = time.monotonic()
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "tiny64", "1"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
-    assert proc.returncode == dist.EXIT_BACKEND_UNREACHABLE, proc.stderr
-    assert "unreachable" in proc.stderr
-    assert time.monotonic() - t0 < 60
 
 
 # ---------------------------------------------------------------------------

@@ -34,8 +34,7 @@ Usage:
 
 bench.py runs this in-process after emitting its judged line (exits 4
 only under NVS3D_BENCH_SENTRY=1 so archived trajectories keep their rc
-semantics), and tools/tpu_bench_watch.py prints the verdict after a
-matrix completes. tests/test_bench_sentry.py pins the rc contract
+semantics). tests/test_bench_sentry.py pins the rc contract
 against synthetic trajectories and the real r01–r09 archive.
 """
 

@@ -57,19 +57,10 @@ from tools._common import init_jax_env  # noqa: E402
 
 init_jax_env()
 
-# Like bench.py, the persistent compile cache is ON by default at the
-# repo-local path (env wins): it keeps bench re-runs warm AND gives the
-# one-shot baseline the same compile-cache benefit the CLI now has —
-# the reported vs_baseline is program-reuse + batching, not cold compiles.
-from novel_view_synthesis_3d_tpu.utils.xla_cache import (  # noqa: E402
-    setup_compilation_cache)
-
-setup_compilation_cache(
-    default_dir=os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache"),
-    min_entry_bytes=0)
-
+# init_jax_env wires the persistent compile cache (env wins, else
+# <checkout>/.jax_cache): it keeps bench re-runs warm AND gives the
+# one-shot baseline the same compile-cache benefit the CLI has — the
+# reported vs_baseline is program-reuse + batching, not cold compiles.
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
@@ -2005,11 +1996,13 @@ def _await_ready(path: str, timeout_s: float) -> dict:
 
 
 def _spawn_replica(name: str, base: str, registry_dir: str, args,
-                   jax_cache: str, extra_env=None):
+                   chip_env: dict, extra_env=None):
     """One fleet replica as a real OS process (serve/replica_main.py):
     own JAX runtime, own telemetry dir (<base>/replica_<name>/), own
     registry watcher on the 'stable' channel with poke-driven polling
     (poll_s is huge on purpose — the deploy driver owns swap timing).
+    `chip_env` (serve/fleet_supervisor.assign_chips) gives the process
+    its one chip before it imports JAX, and rides in the spec file.
 
     serve.step_floor_ms paces each denoise dispatch to a wall-clock
     floor (the sleep releases the GIL/core), emulating the device-bound
@@ -2037,7 +2030,7 @@ def _spawn_replica(name: str, base: str, registry_dir: str, args,
         "sidelength": args.sidelength,
         "steps": args.steps,
         "port": port,
-        "jax_cache_dir": jax_cache,
+        "env": chip_env,
         "registry": {"dir": registry_dir, "channel": "stable",
                      "poll_s": 3600.0},
         "overrides": {
@@ -2056,7 +2049,7 @@ def _spawn_replica(name: str, base: str, registry_dir: str, args,
     with open(spec_path, "w") as fh:
         json.dump(spec, fh)
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
+    env = dict(os.environ, **chip_env)
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
     if extra_env:
         env.update(extra_env)
@@ -2258,8 +2251,22 @@ def fleet_bench(args) -> dict:
     from novel_view_synthesis_3d_tpu.serve.fleet_supervisor import (
         FleetSupervisor,
         ReplicaSpec,
+        assign_chips,
     )
     from novel_view_synthesis_3d_tpu.utils.geometry import orbit_poses
+
+    # One process per chip, settled before anything is built: more
+    # replica processes than chips raises here, in seconds. The launcher
+    # itself never brings up an accelerator backend — the replicas need
+    # the chips — so its own JAX work (model.init for the params it
+    # publishes, as host arrays) asks for the CPU platform: in code, not
+    # in the environment the replicas inherit.
+    n = args.fleet_replicas
+    try:
+        chip_envs = dict(zip([f"r{i}" for i in range(n)], assign_chips(n)))
+    except RuntimeError as e:
+        raise SystemExit(f"error: {e}") from e
+    jax.config.update("jax_platforms", "cpu")
 
     base = args.fleet_dir or "/tmp/nvs3d_fleet_bench"
     if os.path.isdir(base):
@@ -2267,10 +2274,6 @@ def fleet_bench(args) -> dict:
 
         shutil.rmtree(base)
     os.makedirs(base, exist_ok=True)
-    jax_cache = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache")
-
     # Parent-side build: conds for the load + the params the fleet
     # serves (published as v1; every replica loads the channel head, so
     # the whole fleet starts byte-identical).
@@ -2283,8 +2286,7 @@ def fleet_bench(args) -> dict:
     v1 = store.publish_params(params, step=1, ema=False,
                               channel="stable", notes="fleet v1").version
 
-    n = args.fleet_replicas
-    names = [f"r{i}" for i in range(n)]
+    names = list(chip_envs)
     procs = {}
     handles = []
     supervisor = None
@@ -2294,9 +2296,10 @@ def fleet_bench(args) -> dict:
         # into the shared persistent cache; r1..rN then spawn into a
         # warm cache instead of compiling 4x concurrently on one core.
         procs[names[0]] = _spawn_replica(names[0], base, registry_dir,
-                                         args, jax_cache)
+                                         args, chip_envs[names[0]])
         ready = _await_ready(os.path.join(base, f"{names[0]}.ready"),
                              args.fleet_spawn_timeout_s)
+        replica_device = ready["device"]  # where the fleet ran, not us
         handles.append(HttpReplica(
             names[0], ready["url"],
             run_dir=os.path.join(base, f"replica_{names[0]}")))
@@ -2304,7 +2307,7 @@ def fleet_bench(args) -> dict:
                           trace_id="warm-r0").result(timeout=600)
         for name in names[1:]:
             procs[name] = _spawn_replica(name, base, registry_dir, args,
-                                         jax_cache)
+                                         chip_envs[name])
         for name in names[1:]:
             ready = _await_ready(os.path.join(base, f"{name}.ready"),
                                  args.fleet_spawn_timeout_s)
@@ -2344,7 +2347,7 @@ def fleet_bench(args) -> dict:
 
         def respawn(spec):
             return _spawn_replica(spec.name, base, registry_dir, args,
-                                  jax_cache,
+                                  chip_envs[spec.name],
                                   extra_env=slow_env.get(spec.name))
 
         sup_rcfg = RouterConfig(
@@ -2755,7 +2758,7 @@ def fleet_bench(args) -> dict:
         return {"scaling": scaling, "chaos": chaos, "deploy": deploy,
                 "restart": restart, "gray": gray,
                 "recompiles": recompiles, "trace": trace,
-                "fleet_dir": base}
+                "fleet_dir": base, "replica_device": replica_device}
     finally:
         import signal as _signal
 
@@ -3210,7 +3213,7 @@ def main() -> int:
     if args.fleet:
         # Its own light-backbone build happens inside (the parent only
         # supplies conds + the published v1 params; the replicas are
-        # separate processes with their own JAX runtimes).
+        # separate processes with their own JAX runtimes and chips).
         fleet = fleet_bench(args)
         result = {
             "metric": f"serve_fleet_rps_{args.preset}",
@@ -3222,7 +3225,7 @@ def main() -> int:
                          "replica in rotation (quiesced fleet)"),
             "sidelength": args.sidelength,
             "fleet": fleet,
-            "platform": jax.default_backend(),
+            "platform": fleet["replica_device"]["platform"],
         }
         print(json.dumps(result))
         return check_fleet(fleet)
