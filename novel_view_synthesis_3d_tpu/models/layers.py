@@ -13,6 +13,12 @@ two deliberate layout changes for TPU:
      set `per_frame=False` for bit-faithful reference behavior.
 
 Frame count F is a free dimension (the reference hardcodes F=2).
+
+Each leaf (FrameConv, GroupNorm, FiLM, an AttnBlock's attention) runs
+inside one `jax.named_scope("lk.<kind>")`, and so do a block's few own
+ops: the layer kind its instructions are booked under when device time is
+read back from a profiler capture (models/xunet.layer_of holds the
+vocabulary and the precedence). Metadata only, like `og.<label>`.
 """
 
 from __future__ import annotations
@@ -20,6 +26,7 @@ from __future__ import annotations
 from typing import Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -59,17 +66,19 @@ class FrameConv(nn.Module):
 
     @nn.compact
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
-        B, F = h.shape[:2]
-        h = h.reshape((B * F,) + h.shape[2:])
-        h = nn.Conv(
-            self.features,
-            kernel_size=(self.kernel, self.kernel),
-            strides=(self.stride, self.stride),
-            kernel_init=out_init_scale() if self.zero_init else nn.linear.default_kernel_init,
-            dtype=self.dtype,
-            param_dtype=self.param_dtype,
-        )(h)
-        return h.reshape((B, F) + h.shape[1:])
+        with jax.named_scope("lk.conv"):
+            B, F = h.shape[:2]
+            h = h.reshape((B * F,) + h.shape[2:])
+            h = nn.Conv(
+                self.features,
+                kernel_size=(self.kernel, self.kernel),
+                strides=(self.stride, self.stride),
+                kernel_init=(out_init_scale() if self.zero_init
+                             else nn.linear.default_kernel_init),
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+            )(h)
+            return h.reshape((B, F) + h.shape[1:])
 
 
 class _GNParams(nn.Module):
@@ -106,36 +115,38 @@ class GroupNorm(nn.Module):
 
     @nn.compact
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
-        B, F, H, W, C = h.shape
-        if self.fused and self.per_frame:
-            if fits_vmem(H * W, C, h.dtype):
-                scale, bias = _GNParams(features=C, name="GroupNorm_0")()
-                # out_dtype=self.dtype matches the XLA branch's semantics:
-                # nn.GroupNorm casts to the module dtype, THEN swish runs
-                # in that dtype.
-                y = fused_group_norm(h.reshape(B * F, H * W, C), scale,
-                                     bias, 32, 1e-6, self.act, self.dtype)
-                return y.reshape(B, F, H, W, C)
-            # Silent fallbacks hide perf cliffs: paper256's top level
-            # loses the fused kernel here and the byte budget regresses
-            # with no trace. One line per (H·W, C, dtype) per process —
-            # fired at trace time, so steady-state steps stay clean.
-            from novel_view_synthesis_3d_tpu.utils.profiling import log_once
+        with jax.named_scope("lk.gn"):
+            B, F, H, W, C = h.shape
+            if self.fused and self.per_frame:
+                if fits_vmem(H * W, C, h.dtype):
+                    scale, bias = _GNParams(features=C, name="GroupNorm_0")()
+                    # out_dtype=self.dtype matches the XLA branch's semantics:
+                    # nn.GroupNorm casts to the module dtype, THEN swish runs
+                    # in that dtype.
+                    y = fused_group_norm(h.reshape(B * F, H * W, C), scale,
+                                         bias, 32, 1e-6, self.act, self.dtype)
+                    return y.reshape(B, F, H, W, C)
+                # Silent fallbacks hide perf cliffs: paper256's top level
+                # loses the fused kernel here and the byte budget regresses
+                # with no trace. One line per (H·W, C, dtype) per process —
+                # fired at trace time, so steady-state steps stay clean.
+                from novel_view_synthesis_3d_tpu.utils.profiling import (
+                    log_once)
 
-            log_once(
-                ("fused_gn_fallback", H * W, C, str(h.dtype)),
-                f"note: fused GroupNorm falling back to XLA for slab "
-                f"(H·W={H * W}, C={C}, {h.dtype}): "
-                f"{H * W * C * jnp.dtype(h.dtype).itemsize} bytes exceeds "
-                "the kernel's VMEM budget (ops/fused_groupnorm.py) — this "
-                "level pays ~3 HBM passes per GN instead of 2")
-        norm = nn.GroupNorm(num_groups=32, dtype=self.dtype)
-        if self.per_frame:
-            y = norm(h.reshape(B * F, H, W, C)).reshape(B, F, H, W, C)
-        else:
-            # Reference-compat: statistics reduce over (F, H, W) jointly.
-            y = norm(h)
-        return nonlinearity(y) if self.act == "swish" else y
+                log_once(
+                    ("fused_gn_fallback", H * W, C, str(h.dtype)),
+                    f"note: fused GroupNorm falling back to XLA for slab "
+                    f"(H·W={H * W}, C={C}, {h.dtype}): "
+                    f"{H * W * C * jnp.dtype(h.dtype).itemsize} bytes exceeds "
+                    "the kernel's VMEM budget (ops/fused_groupnorm.py) — "
+                    "this level pays ~3 HBM passes per GN instead of 2")
+            norm = nn.GroupNorm(num_groups=32, dtype=self.dtype)
+            if self.per_frame:
+                y = norm(h.reshape(B * F, H, W, C)).reshape(B, F, H, W, C)
+            else:
+                # Reference-compat: statistics reduce over (F, H, W) jointly.
+                y = norm(h)
+            return nonlinearity(y) if self.act == "swish" else y
 
 
 class FiLM(nn.Module):
@@ -152,12 +163,13 @@ class FiLM(nn.Module):
 
     @nn.compact
     def __call__(self, h: Optional[jnp.ndarray], emb: jnp.ndarray):
-        emb = nn.Dense(2 * self.features, dtype=self.dtype,
-                       param_dtype=self.param_dtype)(nonlinearity(emb))
-        scale, shift = jnp.split(emb, 2, axis=-1)
-        if h is None:
-            return scale, shift
-        return h * (1.0 + scale) + shift
+        with jax.named_scope("lk.emb"):
+            emb = nn.Dense(2 * self.features, dtype=self.dtype,
+                           param_dtype=self.param_dtype)(nonlinearity(emb))
+            scale, shift = jnp.split(emb, 2, axis=-1)
+            if h is None:
+                return scale, shift
+            return h * (1.0 + scale) + shift
 
 
 class _GNParamsNested(nn.Module):
@@ -191,8 +203,12 @@ class ResnetBlock(nn.Module):
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
+    # The convolutions, the norms and the FiLM stamp their own kind; the
+    # block's own few ops (resampling, dropout, skip projection, residual
+    # sum) are booked as `conv`, the activation after the FiLM as `gn`.
     @nn.compact
-    def __call__(self, h_in: jnp.ndarray, emb: jnp.ndarray, *, train: bool) -> jnp.ndarray:
+    def __call__(self, h_in: jnp.ndarray, emb: jnp.ndarray, *,
+                 train: bool) -> jnp.ndarray:
         C = h_in.shape[-1]
         features = C if self.features is None else self.features
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
@@ -205,8 +221,9 @@ class ResnetBlock(nn.Module):
                 "up": nearest_neighbor_upsample,
                 "down": avgpool_downsample,
             }[self.resample]
-            h = updown(h)
-            h_in = updown(h_in)
+            with jax.named_scope("lk.conv"):
+                h = updown(h)
+                h_in = updown(h_in)
         h = FrameConv(features, **kw)(h)
         B, F, H, W, _ = h.shape
         if (self.fused_epilogue and self.per_frame_gn
@@ -218,12 +235,13 @@ class ResnetBlock(nn.Module):
                                             name="GroupNorm_1")()
             fscale, fshift = FiLM(features=features, **kw)(None, emb)
             flat = (B * F, H * W, features)
-            h = fused_film_epilogue(
-                h.reshape(flat),
-                gscale, gbias,
-                jnp.broadcast_to(fscale, h.shape).reshape(flat),
-                jnp.broadcast_to(fshift, h.shape).reshape(flat),
-                32, 1e-6, self.dtype).reshape(B, F, H, W, features)
+            with jax.named_scope("lk.gn"):
+                h = fused_film_epilogue(
+                    h.reshape(flat),
+                    gscale, gbias,
+                    jnp.broadcast_to(fscale, h.shape).reshape(flat),
+                    jnp.broadcast_to(fshift, h.shape).reshape(flat),
+                    32, 1e-6, self.dtype).reshape(B, F, H, W, features)
         else:
             if self.fused_epilogue and self.per_frame_gn:
                 from novel_view_synthesis_3d_tpu.utils.profiling import (
@@ -238,12 +256,15 @@ class ResnetBlock(nn.Module):
                     "(ops/fused_epilogue.py) — this level pays the "
                     "three-pass GN→FiLM→swish tail")
             h = FiLM(features=features, **kw)(GroupNorm(**gn_kw)(h), emb)
-            h = nonlinearity(h)
-        h = nn.Dropout(rate=self.dropout)(h, deterministic=not train)
+            with jax.named_scope("lk.gn"):
+                h = nonlinearity(h)
+        with jax.named_scope("lk.conv"):
+            h = nn.Dropout(rate=self.dropout)(h, deterministic=not train)
         h = FrameConv(features, zero_init=True, **kw)(h)
-        if C != features:
-            h_in = nn.Dense(features, **kw)(h_in)
-        return (h + h_in) * INV_SQRT2
+        with jax.named_scope("lk.conv"):
+            if C != features:
+                h_in = nn.Dense(features, **kw)(h_in)
+            return (h + h_in) * INV_SQRT2
 
 
 class AttnLayer(nn.Module):
@@ -330,29 +351,34 @@ class AttnBlock(nn.Module):
         B, F, H, W, C = h_in.shape
         h = GroupNorm(per_frame=self.per_frame_gn, fused=self.fused_gn,
                       dtype=self.dtype)(h_in)
-        tokens = h.reshape(B, F, H * W, C)
-        layer = AttnLayer(attn_heads=self.attn_heads, out_proj=self.out_proj,
-                          use_flash=self.use_flash,
-                          use_serving=self.use_serving, mesh=self.mesh,
-                          ring=self.ring,
-                          dtype=self.dtype, param_dtype=self.param_dtype)
-        if self.attn_type == "self":
-            out = layer(q=tokens.reshape(B * F, H * W, C),
-                        kv=tokens.reshape(B * F, H * W, C))
-            out = out.reshape(B, F, H * W, C)
-        elif self.attn_type == "cross":
-            if F < 2:
-                raise ValueError("cross-frame attention needs F >= 2")
-            outs = []
-            for i in range(F):
-                others = [tokens[:, j] for j in range(F) if j != i]
-                kv = jnp.concatenate(others, axis=1)  # (B, (F-1)·HW, C)
-                outs.append(layer(q=tokens[:, i], kv=kv))
-            out = jnp.stack(outs, axis=1)
-        else:
-            raise NotImplementedError(self.attn_type)
-        out = out.reshape(B, F, H, W, C)
-        return (out + h_in) * INV_SQRT2
+        # Everything after the norm is `attn`: the one stamp covers the
+        # AttnLayer (this block is its only caller) and the block's own
+        # token shuffling and residual.
+        with jax.named_scope("lk.attn"):
+            tokens = h.reshape(B, F, H * W, C)
+            layer = AttnLayer(attn_heads=self.attn_heads,
+                              out_proj=self.out_proj,
+                              use_flash=self.use_flash,
+                              use_serving=self.use_serving, mesh=self.mesh,
+                              ring=self.ring,
+                              dtype=self.dtype, param_dtype=self.param_dtype)
+            if self.attn_type == "self":
+                out = layer(q=tokens.reshape(B * F, H * W, C),
+                            kv=tokens.reshape(B * F, H * W, C))
+                out = out.reshape(B, F, H * W, C)
+            elif self.attn_type == "cross":
+                if F < 2:
+                    raise ValueError("cross-frame attention needs F >= 2")
+                outs = []
+                for i in range(F):
+                    others = [tokens[:, j] for j in range(F) if j != i]
+                    kv = jnp.concatenate(others, axis=1)  # (B, (F-1)·HW, C)
+                    outs.append(layer(q=tokens[:, i], kv=kv))
+                out = jnp.stack(outs, axis=1)
+            else:
+                raise NotImplementedError(self.attn_type)
+            out = out.reshape(B, F, H, W, C)
+            return (out + h_in) * INV_SQRT2
 
 
 class XUNetBlock(nn.Module):

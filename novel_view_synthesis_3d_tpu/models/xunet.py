@@ -23,6 +23,8 @@ Batch contract (canonical keys, reference train.py:23-34):
 
 from __future__ import annotations
 
+import re
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -122,15 +124,18 @@ class ConditioningProcessor(nn.Module):
         B, H, W, _ = z.shape
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
 
-        # --- logsnr embedding (reference xunet.py:152-157) ---
-        # clip ±20, squash to (0,1) via 2·atan(e^{−λ/2})/π, DDPM sinusoid
-        # (max_time=1 ⇒ internal ×1000), then Dense → Dense∘swish.
-        logsnr = jnp.clip(batch["logsnr"], -20.0, 20.0)
-        logsnr = 2.0 * jnp.arctan(jnp.exp(-logsnr / 2.0)) / np.pi
-        logsnr_emb = posenc_ddpm(logsnr, emb_ch=self.emb_ch, max_time=1.0,
-                                 dtype=self.dtype)
-        logsnr_emb = nn.Dense(self.emb_ch, **kw)(logsnr_emb)
-        logsnr_emb = nn.Dense(self.emb_ch, **kw)(nonlinearity(logsnr_emb))
+        with jax.named_scope("lk.emb"):
+            # --- logsnr embedding (reference xunet.py:152-157) ---
+            # clip ±20, squash to (0,1) via 2·atan(e^{−λ/2})/π, DDPM
+            # sinusoid (max_time=1 ⇒ internal ×1000), then Dense →
+            # Dense∘swish.
+            logsnr = jnp.clip(batch["logsnr"], -20.0, 20.0)
+            logsnr = 2.0 * jnp.arctan(jnp.exp(-logsnr / 2.0)) / np.pi
+            logsnr_emb = posenc_ddpm(logsnr, emb_ch=self.emb_ch,
+                                     max_time=1.0, dtype=self.dtype)
+            logsnr_emb = nn.Dense(self.emb_ch, **kw)(logsnr_emb)
+            logsnr_emb = nn.Dense(self.emb_ch, **kw)(
+                nonlinearity(logsnr_emb))
 
         # --- scene-category embedding (data/corpus.py mixed batches) ---
         # Rides the logsnr channel so it reaches every FiLM site without
@@ -165,57 +170,60 @@ class ConditioningProcessor(nn.Module):
         if "pose_embs" in batch:
             return logsnr_emb, list(batch["pose_embs"])
 
-        # --- pose embeddings (reference xunet.py:158-173) ---
-        # Stack cond + target cameras on the frame axis, generate world rays,
-        # NeRF-posenc origins (deg 15 → 93) and directions (deg 8 → 51),
-        # concat → (B, F, H, W, 144).
-        R1 = _as_frames(batch["R1"], 3)   # (B, Fc, 3, 3)
-        t1 = _as_frames(batch["t1"], 2)   # (B, Fc, 3)
-        R = jnp.concatenate([R1, batch["R2"][:, None]], axis=1)
-        t = jnp.concatenate([t1, batch["t2"][:, None]], axis=1)
-        F = R.shape[1]
-        K = jnp.broadcast_to(batch["K"][:, None], (B, F, 3, 3))
-        pos, dirs = camera_rays(R, t, K, resolution=(H, W))
-        pose_emb = jnp.concatenate(
-            [
-                posenc_nerf(pos, min_deg=0, max_deg=15),
-                posenc_nerf(dirs, min_deg=0, max_deg=8),
-            ],
-            axis=-1,
-        ).astype(self.dtype)
-        D = pose_emb.shape[-1]
+        with jax.named_scope("lk.pose"):
+            # --- pose embeddings (reference xunet.py:158-173) ---
+            # Stack cond + target cameras on the frame axis, generate world
+            # rays, NeRF-posenc origins (deg 15 → 93) and directions
+            # (deg 8 → 51), concat → (B, F, H, W, 144).
+            R1 = _as_frames(batch["R1"], 3)   # (B, Fc, 3, 3)
+            t1 = _as_frames(batch["t1"], 2)   # (B, Fc, 3)
+            R = jnp.concatenate([R1, batch["R2"][:, None]], axis=1)
+            t = jnp.concatenate([t1, batch["t2"][:, None]], axis=1)
+            F = R.shape[1]
+            K = jnp.broadcast_to(batch["K"][:, None], (B, F, 3, 3))
+            pos, dirs = camera_rays(R, t, K, resolution=(H, W))
+            pose_emb = jnp.concatenate(
+                [
+                    posenc_nerf(pos, min_deg=0, max_deg=15),
+                    posenc_nerf(dirs, min_deg=0, max_deg=8),
+                ],
+                axis=-1,
+            ).astype(self.dtype)
+            D = pose_emb.shape[-1]
 
-        # Classifier-free guidance: zero the whole pose embedding per sample
-        # where cond_mask == 0 (reference xunet.py:174-179).
-        assert cond_mask.shape == (B,), cond_mask.shape
-        mask = cond_mask[:, None, None, None, None]
-        pose_emb = jnp.where(mask, pose_emb, jnp.zeros_like(pose_emb))
+            # Classifier-free guidance: zero the whole pose embedding per
+            # sample where cond_mask == 0 (reference xunet.py:174-179).
+            assert cond_mask.shape == (B,), cond_mask.shape
+            mask = cond_mask[:, None, None, None, None]
+            pose_emb = jnp.where(mask, pose_emb, jnp.zeros_like(pose_emb))
 
-        if self.use_pos_emb:
-            pos_emb = self.param(
-                "pos_emb", nn.initializers.normal(stddev=1.0 / np.sqrt(D)),
-                (H, W, D), self.param_dtype)
-            pose_emb += pos_emb[None, None].astype(self.dtype)
+            if self.use_pos_emb:
+                pos_emb = self.param(
+                    "pos_emb",
+                    nn.initializers.normal(stddev=1.0 / np.sqrt(D)),
+                    (H, W, D), self.param_dtype)
+                pose_emb += pos_emb[None, None].astype(self.dtype)
 
-        if self.use_ref_pose_emb:
-            # Binary frame-identity embedding: 'first' on frame 0, 'other' on
-            # the rest (reference xunet.py:186-194, generalized to F frames).
-            first = self.param(
-                "ref_pose_emb_first", nn.initializers.normal(stddev=1.0 / np.sqrt(D)),
-                (D,), self.param_dtype)
-            other = self.param(
-                "ref_pose_emb_other", nn.initializers.normal(stddev=1.0 / np.sqrt(D)),
-                (D,), self.param_dtype)
-            frame_emb = jnp.stack([first] + [other] * (F - 1), axis=0)
-            pose_emb += frame_emb[None, :, None, None, :].astype(self.dtype)
+            if self.use_ref_pose_emb:
+                # Binary frame-identity embedding: 'first' on frame 0,
+                # 'other' on the rest (reference xunet.py:186-194,
+                # generalized to F frames).
+                init = nn.initializers.normal(stddev=1.0 / np.sqrt(D))
+                first = self.param("ref_pose_emb_first", init, (D,),
+                                   self.param_dtype)
+                other = self.param("ref_pose_emb_other", init, (D,),
+                                   self.param_dtype)
+                frame_emb = jnp.stack([first] + [other] * (F - 1), axis=0)
+                pose_emb += frame_emb[None, :, None, None, :].astype(
+                    self.dtype)
 
-        # Per-resolution strided downsampling of the full-res embedding
-        # (reference xunet.py:197-202): one conv per level, stride 2ˡ.
-        pose_embs = []
-        for i_level in range(self.num_resolutions):
-            pose_embs.append(
-                FrameConv(self.emb_ch, kernel=3, stride=2 ** i_level, **kw)(pose_emb)
-            )
+            # Per-resolution strided downsampling of the full-res embedding
+            # (reference xunet.py:197-202): one conv per level, stride 2ˡ.
+            pose_embs = []
+            for i_level in range(self.num_resolutions):
+                pose_embs.append(FrameConv(
+                    self.emb_ch, kernel=3, stride=2 ** i_level,
+                    **kw)(pose_emb))
         return logsnr_emb, pose_embs
 
 
@@ -343,6 +351,44 @@ def op_groups(cfg: ModelConfig):
     return groups
 
 
+# The layer kinds a `jax.named_scope("lk.<kind>")` may stamp. The stamps
+# sit where the work happens (models/layers.py, ConditioningProcessor and
+# level_emb here, sample/ddpm.py); this tuple and layer_of are the only
+# other place a kind is spelled.
+LAYER_KINDS = ("conv", "gn", "attn", "emb", "pose", "update")
+
+
+def layer_of(path: str):
+    """(block, kind) of a scope path — an HLO `op_name`, which a profiler
+    capture carries as the `tf_op` of a device event's metadata.
+
+    `block` is the `og.<label>` of op_groups ('' outside the model's op
+    loop). `kind` is the innermost `lk.<kind>` stamp (LAYER_KINDS), with
+    two exceptions: a stamp from outside a block does not reach into it
+    (the sampler's `update` encloses the model call, whose unstamped
+    instructions are the model's `other`, not the sampler's), and `pose`
+    anywhere on the path wins, because the pose path is one thing to a
+    reader whichever convolutions and norms it is made of. A path inside
+    a program scope with no kind is `other`; a path with no program
+    scope at all (the compiler's own instructions, an RNG helper called
+    outside every stamp) is `unattributed`. Transform wrappers
+    (`transpose(jvp(XUNet))/og.final/...`) are split like slashes; of the
+    `;`-joined paths of instructions XLA merged, the first holds.
+    """
+    segs = [s for s in re.split(r"[/()]", path.split(";", 1)[0]) if s]
+    blocks = [i for i, s in enumerate(segs) if s.startswith("og.")]
+    block = segs[blocks[-1]][3:] if blocks else ""
+    kinds = [(i, s[3:]) for i, s in enumerate(segs)
+             if s.startswith("lk.") and s[3:] in LAYER_KINDS]
+    if any(k == "pose" for _, k in kinds):
+        return block, "pose"
+    if blocks:
+        kinds = [(i, k) for i, k in kinds if i > blocks[-1]]
+    if kinds:
+        return block, kinds[-1][1]
+    return block, "other" if blocks else "unattributed"
+
+
 class XUNet(nn.Module):
     """The X-UNet (reference model/xunet.py:205-280), config-driven.
 
@@ -442,7 +488,9 @@ class XUNet(nn.Module):
 
             def level_emb(i_level):
                 # (B, 1, 1, 1, emb) + (B, F, H/2ˡ, W/2ˡ, emb) broadcast add.
-                return logsnr_emb[:, None, None, None, :] + pose_embs[i_level]
+                with jax.named_scope("lk.emb"):
+                    return (logsnr_emb[:, None, None, None, :]
+                            + pose_embs[i_level])
 
             if kind == "down_block":
                 use_attn = h.shape[3] in cfg.attn_resolutions

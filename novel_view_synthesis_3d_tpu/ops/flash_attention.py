@@ -108,6 +108,7 @@ def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
             jax.ShapeDtypeStruct((N, Lq, D), q.dtype),
             jax.ShapeDtypeStruct((N, Lq, 128), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse[:, :, 0]
@@ -266,6 +267,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, scale: float, block_q: int):
         ],
         out_specs=pl.BlockSpec((1, bq, Dp), lambda n, i: (n, i, 0), **mem),
         out_shape=jax.ShapeDtypeStruct((N, Lq_p, Dp), q.dtype),
+        name="flash_dq",
         interpret=interpret,
     )(qt, kt, vt, dot, lse_b, dlt_b)
 
@@ -288,6 +290,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, scale: float, block_q: int):
             jax.ShapeDtypeStruct((N, Lk_p, Dp), k.dtype),
             jax.ShapeDtypeStruct((N, Lk_p, Dp), v.dtype),
         ],
+        name="flash_dkv",
         interpret=interpret,
     )(qt, kt, vt, dot, lse_b, dlt_b)
 

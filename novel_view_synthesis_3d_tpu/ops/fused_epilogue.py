@@ -91,6 +91,7 @@ def _forward(x, gscale, gbias, fscale, fshift, groups: int, eps: float,
             jax.ShapeDtypeStruct((n, 1, groups), jnp.float32),
         ],
         compiler_params=_pallas.slab_compiler_params(),
+        name="fused_epilogue",
         interpret=_pallas.use_interpret(),
     )(x, affine_row(gscale), affine_row(gbias), fscale, fshift)
     return y, mean.reshape(n, groups), rstd.reshape(n, groups)

@@ -150,6 +150,7 @@ def _forward(x, scale, bias, groups: int, eps: float, act: Optional[str],
             jax.ShapeDtypeStruct((n, 1, groups), jnp.float32),
         ],
         compiler_params=_pallas.slab_compiler_params(),
+        name="fused_groupnorm",
         interpret=_use_interpret(),
     )(x, affine_row(scale), affine_row(bias))
     return y, mean.reshape(n, groups), rstd.reshape(n, groups)

@@ -269,6 +269,7 @@ def fused_denoise_step(z: jnp.ndarray, eps_cond: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, M, _LANES), lambda i: (i, 0, 0), **mem),
         out_shape=jax.ShapeDtypeStruct((B, M, _LANES), z.dtype),
+        name="fused_step",
         interpret=interpret,
     )(slab(z), slab(eps_cond), slab(eps_uncond), slab(noise),
       rp.reshape(B, 1, _LANES))

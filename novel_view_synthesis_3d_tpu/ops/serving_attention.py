@@ -162,6 +162,7 @@ def serving_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, bq, Dp), lambda n, i: (n, i, 0), **mem),
         out_shape=jax.ShapeDtypeStruct((N, Lq_p, Dp), q.dtype),
+        name="serving_attention",
         interpret=interpret,
     )(qt, kt, vt)
     return out[:, :Lq, :D].reshape(B, H, Lq, D).transpose(0, 2, 1, 3)

@@ -332,6 +332,7 @@ def make_sampler(model, schedule: DiffusionSchedule, config: DiffusionConfig,
         return (z, key, aux), None
 
     @jax.jit
+    @jax.named_scope("lk.update")
     def sample(params, key, cond: dict) -> jnp.ndarray:
         z_shape = cond["x"].shape[:1] + cond["x"].shape[-3:]  # (B, H, W, 3)
         key, k_init = jax.random.split(key)
@@ -466,6 +467,7 @@ def make_request_sampler(model, schedule: DiffusionSchedule,
         impl_eta = 0.0
 
     @jax.jit
+    @jax.named_scope("lk.update")
     def sample(params, keys, cond: dict) -> jnp.ndarray:
         if param_transform is not None:
             params = param_transform(params)
@@ -638,6 +640,7 @@ def make_slot_step_fn(model, config: DiffusionConfig, *,
     logsnr_col = STEP_COEF_KEYS.index("logsnr")
 
     @jax.jit
+    @jax.named_scope("lk.update")
     def step(params, z, keys, first, cond, coefs, w):
         if param_transform is not None:
             params = param_transform(params)
@@ -692,6 +695,7 @@ def make_slot_step_fn(model, config: DiffusionConfig, *,
         return z_next, keys_next, finite
 
     @jax.jit
+    @jax.named_scope("lk.update")
     def step_cached(params, z, keys, first, cond, coefs, w, cc):
         # Cached-conditioning twin (see docstring): identical body
         # except the cond branch arrives as device arguments.
@@ -811,6 +815,7 @@ def make_bank_step_fn(model, config: DiffusionConfig, k_max: int, *,
     logsnr_col = STEP_COEF_KEYS.index("logsnr")
 
     @jax.jit
+    @jax.named_scope("lk.update")
     def step(params, z, keys, first, cond, coefs, w, R2, t2,
              bank_x, bank_R, bank_t, bank_state):
         if param_transform is not None:
@@ -894,6 +899,7 @@ def make_bank_step_fn(model, config: DiffusionConfig, k_max: int, *,
         return z_next, keys_next, finite
 
     @jax.jit
+    @jax.named_scope("lk.update")
     def step_cached(params, z, keys, first, cond, coefs, w, R2, t2,
                     bank_x, bank_R, bank_t, bank_state, cc):
         # Cached-conditioning twin (see docstring): identical RNG head
@@ -976,6 +982,7 @@ def make_bank_commit_fn():
     every ring bucket, and every sliding-window position."""
 
     @jax.jit
+    @jax.named_scope("lk.update")
     def commit(bank_x, bank_R, bank_t, frame, pos, R2, t2):
         bank_x = jax.lax.dynamic_update_slice(
             bank_x, frame[None].astype(bank_x.dtype), (pos, 0, 0, 0))
@@ -1014,6 +1021,7 @@ def make_stochastic_sampler(model, schedule: DiffusionSchedule,
     update, init_aux = _make_update(schedule, config, memoryless=True)
 
     @partial(jax.jit, static_argnames=())
+    @jax.named_scope("lk.update")
     def sample(params, key, pool: dict, target_pose: dict,
                num_views: jnp.ndarray) -> jnp.ndarray:
         B, P, H, W, C = pool["x"].shape

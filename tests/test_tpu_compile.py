@@ -123,6 +123,48 @@ def test_kernel_compiles_for_v5e(name, v5e, monkeypatch):
         f"{name}: compiled without a Pallas kernel in it")
 
 
+# The name each `pl.pallas_call` passes: a kernel is then an instruction
+# of that name in a compiled program and in a profiler capture, where an
+# unnamed one is told from the next only by its number.
+KERNEL_NAMES = {
+    "flash_fwd": "flash_fwdbwd_L256_d128",
+    "flash_dq": "flash_fwdbwd_L256_d128",
+    "flash_dkv": "flash_fwdbwd_L256_d128",
+    "serving_attention": "serving_attention_L1024_d64",
+    "fused_groupnorm": "fused_groupnorm_256x512",
+    "fused_epilogue": "fused_epilogue_256x512",
+    "fused_step": "fused_step_ddpm_B2_128px",
+}
+
+
+def _lowered(case, sharding):
+    fn, arg_specs = CASES[case]
+    return jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in arg_specs])
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_NAMES))
+def test_kernel_lowers_under_its_name(kernel, v5e, monkeypatch):
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    text = _lowered(KERNEL_NAMES[kernel], v5e).as_text()
+    assert f'kernel_name = "{kernel}"' in text
+
+
+def test_flash_kernels_are_distinct_instructions(v5e, monkeypatch):
+    """Forward and both backward kernels of one attention, compiled: three
+    custom calls, each named after its kernel (under a transform the name
+    is wrapped, `transpose_jvp_flash_dq__`)."""
+    import re
+
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    text = _lowered("flash_fwdbwd_L256_d128", v5e).compile().as_text()
+    calls = re.findall(r"^\s*%(\S+) = .* custom-call\(", text, re.M)
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert sum(kernel in c for c in calls) == 1, (kernel, calls)
+    assert len(set(calls)) == len(calls) == 3
+
+
 def test_flash_compiles_under_a_four_chip_data_mesh(v5e_devices,
                                                     monkeypatch):
     """GSPMD refuses to partition a Mosaic kernel, so a batch-sharded
