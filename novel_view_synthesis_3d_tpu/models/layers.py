@@ -23,7 +23,7 @@ vocabulary and the precedence). Metadata only, like `og.<label>`.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -47,6 +47,9 @@ from novel_view_synthesis_3d_tpu.ops.resample import (
 nonlinearity = nn.swish
 
 INV_SQRT2 = float(1.0 / np.sqrt(2.0))
+
+# What FiLM reads: one array over all rows, or a (full extent, 1 × 1) pair.
+Emb = Union[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]
 
 
 def out_init_scale():
@@ -155,18 +158,49 @@ class FiLM(nn.Module):
     `h=None` returns the raw (scale, shift) pair instead of applying the
     modulation — the fused-epilogue path (ops/fused_epilogue.py) feeds
     them to the Pallas kernel while this module keeps sole ownership of
-    the Dense projection (same param tree either way)."""
+    the Dense projection (same param tree either way).
+
+    `emb` is one array over all of `h`'s rows, or a pair (leading rows at
+    `h`'s spatial extent, the remaining rows at 1 × 1): what
+    models/xunet.precompute_guidance_pose_embs gives for a guidance pair
+    whose unconditional rows' embedding is one vector per frame. The one
+    Dense then projects each part at its own extent — a 1 × 1 part once a
+    frame, not once a pixel — and the two projections are summed with
+    zero rows standing in for each other's: an exact sum, an array over
+    all rows to everything after it, and the 1 × 1 part's broadcast left
+    to whichever fusion consumes it."""
 
     features: int
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, h: Optional[jnp.ndarray], emb: jnp.ndarray):
+    def __call__(self, h: Optional[jnp.ndarray], emb: Emb):
         with jax.named_scope("lk.emb"):
-            emb = nn.Dense(2 * self.features, dtype=self.dtype,
-                           param_dtype=self.param_dtype)(nonlinearity(emb))
-            scale, shift = jnp.split(emb, 2, axis=-1)
+            dense = nn.Dense(2 * self.features, dtype=self.dtype,
+                             param_dtype=self.param_dtype)
+            if isinstance(emb, tuple):
+                full, per_frame = (dense(nonlinearity(e)) for e in emb)
+                n, m = full.shape[0], per_frame.shape[0]
+
+                def rows(x, before, after):
+                    return jax.lax.pad(
+                        x, jnp.zeros((), x.dtype),
+                        [(before, after, 0)] + [(0, 0, 0)] * (x.ndim - 1))
+
+                # Padded before the split and summed after it: in that
+                # order XLA:TPU fuses pad, split and sum into the
+                # modulation's one pass over `h`. Summed before the
+                # split, it writes the sum out at 2C channels for every
+                # row first; split before the pad, the split becomes a
+                # copy of its own (PERF.md §6, PR 25).
+                scale, shift = (
+                    f + rows(p, n, 0) for f, p in
+                    zip(jnp.split(rows(full, 0, m), 2, axis=-1),
+                        jnp.split(per_frame, 2, axis=-1)))
+            else:
+                scale, shift = jnp.split(dense(nonlinearity(emb)), 2,
+                                         axis=-1)
             if h is None:
                 return scale, shift
             return h * (1.0 + scale) + shift
@@ -207,7 +241,7 @@ class ResnetBlock(nn.Module):
     # block's own few ops (resampling, dropout, skip projection, residual
     # sum) are booked as `conv`, the activation after the FiLM as `gn`.
     @nn.compact
-    def __call__(self, h_in: jnp.ndarray, emb: jnp.ndarray, *,
+    def __call__(self, h_in: jnp.ndarray, emb: Emb, *,
                  train: bool) -> jnp.ndarray:
         C = h_in.shape[-1]
         features = C if self.features is None else self.features
@@ -404,7 +438,7 @@ class XUNetBlock(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, emb: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray, emb: Emb) -> jnp.ndarray:
         kw = dict(per_frame_gn=self.per_frame_gn, fused_gn=self.fused_gn,
                   dtype=self.dtype, param_dtype=self.param_dtype)
         attn_kw = dict(attn_heads=self.attn_heads, out_proj=self.attn_out_proj,

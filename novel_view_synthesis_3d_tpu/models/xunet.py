@@ -46,6 +46,7 @@ from novel_view_synthesis_3d_tpu.ops.fused_groupnorm import resolve_fused_gn
 from novel_view_synthesis_3d_tpu.ops.serving_attention import (
     resolve_serving_attention)
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
+from novel_view_synthesis_3d_tpu.utils.profiling import log_once
 
 
 def _as_frames(arr: jnp.ndarray, frame_rank: int) -> jnp.ndarray:
@@ -237,6 +238,10 @@ def precompute_pose_embs(model: "XUNet", params, cond: dict,
     per-level downsampling convs inside every scan step. `cond_mask` is
     baked in (CFG zeroing happens at this stage). `cond` needs x/R1/t1/
     R2/t2/K; z/logsnr are synthesized for shape purposes only.
+
+    Every level comes back at full extent, (B, F, H/2ˡ, W/2ˡ, emb), masked
+    rows too; a guidance pair's unconditional rows at 1 × 1 extent are
+    precompute_guidance_pose_embs's to give.
     """
     cfg = model.config
     x = cond["x"]
@@ -257,6 +262,53 @@ def precompute_pose_embs(model: "XUNet", params, cond: dict,
     _, pose_embs = proc.apply({"params": params["ConditioningProcessor_0"]},
                               batch, cond_mask)
     return tuple(pose_embs)
+
+
+def precompute_guidance_pose_embs(model: "XUNet", params, cond: dict):
+    """Per-level pose embeddings for a guidance pair, rows [cond…, uncond…],
+    with the unconditional rows at 1 × 1 extent — or None where the
+    configuration does not admit that.
+
+    The mask zeroes an unconditional row's whole ray encoding, and a
+    zero-padded convolution of zeros is its bias at every pixel, borders
+    included. With `use_pos_emb` and `use_ref_pose_emb` both off such a
+    row's embedding is therefore ONE vector per frame, repeated over
+    H × W, and each level comes back as the pair
+
+      (conditional (B, F, H/2ˡ, W/2ˡ, emb), unconditional (B, F, 1, 1, emb))
+
+    where a 1 × 1 extent says "this value at every pixel of the frame".
+    The model carries it as such through `level_emb` and FiLM's Dense,
+    which projects those rows once a frame instead of once a pixel, and
+    broadcasts only where the modulation is applied: the same numbers,
+    computed once. The unconditional part is the processor run on masked
+    rows of the smallest image every level still has a pixel of.
+
+    With either flag on, an unconditional row is not constant over the
+    frame (a learned (H, W, D) table; a non-zero constant that zero
+    padding changes at the borders): None, and the caller keeps
+    `precompute_pose_embs` of its doubled layout. The choice is a static
+    property of the configuration and shows in the shapes.
+    """
+    cfg = model.config
+    blocked = [f for f in ("use_pos_emb", "use_ref_pose_emb")
+               if getattr(cfg, f)]
+    if blocked:
+        log_once(
+            ("film_collapse_forbidden",) + tuple(blocked),
+            "note: guidance pair keeps full-extent pose embeddings: with "
+            f"{' and '.join(blocked)} on, an unconditional row's embedding "
+            "is not constant over the frame — every FiLM site projects "
+            "both halves per pixel")
+        return None
+    x = cond["x"]
+    B = x.shape[0]
+    pose_c = precompute_pose_embs(model, params, cond, jnp.ones((B,)))
+    side = 2 ** (len(cfg.ch_mult) - 1)
+    small = dict(cond, x=jnp.zeros(
+        x.shape[:-3] + (side, side, x.shape[-1]), x.dtype))
+    pose_u = precompute_pose_embs(model, params, small, jnp.zeros((B,)))
+    return tuple((c, u[:, :, :1, :1]) for c, u in zip(pose_c, pose_u))
 
 
 def precompute_cond_feats(model: "XUNet", params, cond: dict) -> jnp.ndarray:
@@ -488,9 +540,21 @@ class XUNet(nn.Module):
 
             def level_emb(i_level):
                 # (B, 1, 1, 1, emb) + (B, F, H/2ˡ, W/2ˡ, emb) broadcast add.
+                # A level given as a pair (precompute_guidance_pose_embs:
+                # leading rows at full extent, the rest at 1 × 1) stays a
+                # pair, each part with its own rows of logsnr_emb.
+                pose = pose_embs[i_level]
                 with jax.named_scope("lk.emb"):
-                    return (logsnr_emb[:, None, None, None, :]
-                            + pose_embs[i_level])
+                    lemb = logsnr_emb[:, None, None, None, :]
+                    if not isinstance(pose, tuple):
+                        return lemb + pose
+                    full, per_frame = pose
+                    n = full.shape[0]
+                    assert n + per_frame.shape[0] == lemb.shape[0], (
+                        full.shape, per_frame.shape, lemb.shape)
+                    film_rows.append((int(np.prod(full.shape[:-1])),
+                                      int(np.prod(per_frame.shape[:-1]))))
+                    return lemb[:n] + full, lemb[n:] + per_frame
 
             if kind == "down_block":
                 use_attn = h.shape[3] in cfg.attn_resolutions
@@ -538,6 +602,7 @@ class XUNet(nn.Module):
         specs = pipeline_op_specs(cfg)
         a, b = (0, len(specs)) if ops is None else ops
         state = carry
+        film_rows = []  # (rows at full extent, rows at 1 × 1) per FiLM site
         for kind, info in specs[a:b]:
             # og.<label> named scope: stamps each op's HLO with its
             # op-group label (the op_groups vocabulary) so profiler
@@ -547,4 +612,12 @@ class XUNet(nn.Module):
             label = kind if kind in ("prelude", "final") else info["name"]
             with jax.named_scope(f"og.{label}"):
                 state = run_op(kind, info, state)
+        if film_rows:
+            per_pixel, per_frame = map(sum, zip(*film_rows))
+            log_once(
+                ("film_collapse", len(film_rows), per_pixel, per_frame),
+                f"note: guidance pair with the unconditional half's pose "
+                f"embedding at 1 × 1 extent: {len(film_rows)} FiLM sites "
+                f"project {per_pixel} rows per pixel and {per_frame} rows "
+                f"per frame (at full extent: {2 * per_pixel} per pixel)")
         return state

@@ -8,7 +8,13 @@ host-side numpy steps, each dispatching TWO un-jitted Flax forward passes
     round-trips, no per-step dispatch overhead;
   - CFG computed in a single forward pass on a doubled batch (2B) with
     cond_mask = [1…1, 0…0] instead of two applies — keeps the MXU fed with
-    one large matmul stream per step;
+    one large matmul stream per step. The unconditional half's pose
+    embedding is one vector per frame (the mask zeroes the rays; what is
+    left is the pose convolutions' biases), so the samplers that compute
+    it once a trajectory (`make_sampler`, `make_request_sampler`) hand it
+    to the model at 1 × 1 extent and every FiLM site projects it once a
+    frame instead of once a pixel (models/xunet.
+    precompute_guidance_pose_embs says when that holds);
   - guidance weight w, respacing (e.g. 256 of 1000 steps) and x̂₀ clipping
     are config fields (reference hardcodes w=3 at sampling.py:134);
   - k>1 stochastic conditioning (3DiM paper §3.2): each denoise step picks a
@@ -42,6 +48,7 @@ from novel_view_synthesis_3d_tpu.config import DiffusionConfig
 from novel_view_synthesis_3d_tpu.diffusion.schedules import DiffusionSchedule
 from novel_view_synthesis_3d_tpu.models.xunet import (
     precompute_cond_feats,
+    precompute_guidance_pose_embs,
     precompute_pose_embs,
 )
 from novel_view_synthesis_3d_tpu.ops import fused_step as fused_step_lib
@@ -53,7 +60,12 @@ def _raw_eps(model, params, model_batch: dict, pose_embs=None,
 
     `pose_embs`: per-level pose embeddings already computed for the
     DOUBLED (cond+uncond) layout — injected after the doubling so they are
-    not concatenated twice. See models/xunet.precompute_pose_embs.
+    not concatenated twice. See models/xunet.precompute_pose_embs. A
+    level is one (2B, F, H/2ˡ, W/2ˡ, emb) array or, rows in the same
+    order, the pair (cond (B, F, H/2ˡ, W/2ˡ, emb), uncond (B, F, 1, 1,
+    emb)): a 1 × 1 extent stands for one value at every pixel of the
+    frame, which a caller may pass only where that is true of the rows
+    (models/xunet.precompute_guidance_pose_embs decides it).
     `cond_feats`: stem features of the conditioning frame(s) for the
     doubled layout (models/xunet.precompute_cond_feats) — with them the
     step program convolves only the noised target frame."""
@@ -81,7 +93,17 @@ def _cfg_eps(model, params, model_batch: dict, w: float,
 def _doubled_pose_embs(model, params, cond: dict):
     """Pose embeddings for _cfg_eps's doubled layout, computed once per
     trajectory: conditional half with the mask on, unconditional half with
-    the pose embedding zeroed — exactly what the in-loop mask produced."""
+    the pose embedding zeroed — exactly what the in-loop mask produced.
+
+    Wherever the configuration admits it the unconditional half comes at
+    1 × 1 extent — one vector per frame, which is all the mask leaves of
+    it: each level is then a (cond, uncond) pair, and the model projects
+    the unconditional rows once a frame at each FiLM site
+    (models/xunet.precompute_guidance_pose_embs, which also says when it
+    does not hold; each level is then one array over the doubled rows)."""
+    pairs = precompute_guidance_pose_embs(model, params, cond)
+    if pairs is not None:
+        return pairs
     B = cond["x"].shape[0]
     doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0), cond)
     mask = jnp.concatenate([jnp.ones((B,)), jnp.zeros((B,))])

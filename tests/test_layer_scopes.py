@@ -16,6 +16,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from novel_view_synthesis_3d_tpu.config import DiffusionConfig, ModelConfig
@@ -141,11 +142,11 @@ def _tiny_sampler():
 
 
 @pytest.fixture(scope="module")
-def sampler_paths():
-    """Every `op_name` of the compiled trajectory sampler (the benchmark
-    cell's program at rehearsal size). The persistent cache's key leaves
-    metadata out, so it would hand back an executable compiled under an
-    older stamping: for this compile the metadata is part of the key."""
+def compiled_sampler():
+    """The compiled trajectory sampler's text (the benchmark cell's program
+    at rehearsal size). The persistent cache's key leaves metadata out,
+    so it would hand back an executable compiled under an older
+    stamping: for this compile the metadata is part of the key."""
     cfg, sampler, params, cond = _tiny_sampler()
     flag = "jax_compilation_cache_include_metadata_in_key"
     before = getattr(jax.config, flag)
@@ -156,6 +157,13 @@ def sampler_paths():
             cond).compile().as_text()
     finally:
         jax.config.update(flag, before)
+    return cfg, text
+
+
+@pytest.fixture(scope="module")
+def sampler_paths(compiled_sampler):
+    """Every `op_name` of the compiled trajectory sampler."""
+    cfg, text = compiled_sampler
     paths = sorted(set(re.findall(r'op_name="([^"]*)"', text)))
     assert len(paths) > 200
     return cfg, paths
@@ -216,6 +224,56 @@ def test_each_module_call_is_stamped_once(sampler_paths):
                       if s.startswith("lk.")]
             assert len(stamps) == len(set(stamps)) <= 3, part
             assert len(stamps) <= 2 or "lk.pose" in stamps, part
+
+
+def test_film_projects_the_unconditional_rows_once_a_frame(
+        compiled_sampler):
+    """In the compiled sampler the rows entering FiLM's matmuls are, per
+    site, the conditional half's B·F·H·W pixels plus the unconditional
+    half's B·F frames — not 2·B·F·H·W. (XLA may share one `swish(level
+    embedding)` between the sites of a level; each site keeps its own
+    matmuls, whose result's leading dimensions are the rows.)"""
+    cfg, text = compiled_sampler
+    B, F, side = 2, 2, 16
+    rows = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"(?:dot|convolution)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not (m and name and name.group(1).endswith(
+                "FiLM_0/lk.emb/Dense_0/dot_general")):
+            continue
+        block, kind = layer_of(name.group(1))
+        assert kind == "emb"
+        dims = [int(d) for d in m.group(1).split(",")]
+        rows.setdefault(block, []).append(int(np.prod(dims[:-1])))
+    assert set(rows) == {label for label, _ in op_groups(cfg)} - {
+        "prelude", "final"}
+    per_level = [side // 2 ** lvl for lvl in range(len(cfg.ch_mult))]
+    for block, parts in rows.items():
+        assert min(parts) == B * F, (block, parts)
+        assert sum(parts) in {B * F * p * p + B * F for p in per_level}, (
+            block, parts)
+
+
+def test_film_collapse_is_said_once_with_its_counts(capfd):
+    """Tracing the sampler says once, on stderr, how many FiLM sites
+    project how many rows per pixel and how many per frame."""
+    from novel_view_synthesis_3d_tpu.utils.profiling import reset_log_once
+
+    cfg, sampler, params, cond = _tiny_sampler()
+    reset_log_once()
+    capfd.readouterr()
+    for _ in range(2):
+        sampler.lower(params, jax.ShapeDtypeStruct((2,), jnp.uint32), cond)
+    said = [line for line in capfd.readouterr().err.splitlines()
+            if "FiLM sites" in line]
+    # 9 ResnetBlocks; B·F·H·W = 2·2·16² at level 0 (4 sites) and 2·2·8²
+    # at level 1 (5 sites); B·F = 4 at each.
+    sites, per_pixel, per_frame = 9, 4 * 1024 + 5 * 256, 9 * 4
+    assert len(said) == 1, said
+    assert (f"{sites} FiLM sites project {per_pixel} rows per pixel and "
+            f"{per_frame} rows per frame") in said[0]
 
 
 _NO_PROTOBUF = """

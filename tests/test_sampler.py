@@ -494,6 +494,155 @@ def test_precomputed_pose_embs_match_inline():
                                rtol=1e-6, atol=1e-6)
 
 
+def _perturbed(params):
+    """The output head is zero-init and every bias starts at zero:
+    perturb, so that the unconditional half's embedding (the pose
+    convolutions' biases) and the output are non-trivial."""
+    return jax.tree.map(
+        lambda p: p + 0.01 * jnp.arange(p.size, dtype=p.dtype
+                                        ).reshape(p.shape) / p.size, params)
+
+
+def test_split_pose_embs_match_full_extent_and_inline():
+    """A guidance pair given as (conditional rows at full extent,
+    unconditional rows at 1 × 1) gives the output of the same rows at
+    full extent and of the in-loop path, mask [1, 0]."""
+    from novel_view_synthesis_3d_tpu.models.xunet import precompute_pose_embs
+    from novel_view_synthesis_3d_tpu.sample.ddpm import _doubled_pose_embs
+
+    model, params, cond = _model_and_params(B=1)
+    params = _perturbed(params)
+    pair = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0), cond)
+    batch = dict(pair, z=jnp.asarray(
+        np.random.default_rng(0).normal(size=(2, 16, 16, 3))
+    ).astype(jnp.float32), logsnr=jnp.linspace(-4.0, 7.0, 2))
+    mask = jnp.asarray([1.0, 0.0])
+
+    full = precompute_pose_embs(model, params, pair, mask)
+    split = _doubled_pose_embs(model, params, cond)
+    for lvl, (f, (c, u)) in enumerate(zip(full, split)):
+        side = 16 // 2 ** lvl
+        assert f.shape == (2, 2, side, side, TINY.emb_ch)
+        assert c.shape == (1, 2, side, side, TINY.emb_ch)
+        assert u.shape == (1, 2, 1, 1, TINY.emb_ch)
+        # one vector per frame, and not a zero one
+        np.testing.assert_array_equal(
+            np.asarray(f[1:]), np.broadcast_to(np.asarray(u), f[1:].shape))
+        assert float(jnp.abs(u).max()) > 1e-3
+
+    outs = {name: model.apply({"params": params},
+                              dict(batch, **extra), cond_mask=mask,
+                              train=False)
+            for name, extra in (("inline", {}),
+                                ("full", {"pose_embs": full}),
+                                ("split", {"pose_embs": split}))}
+    assert float(jnp.abs(outs["inline"]).max()) > 0.1
+    for name in ("full", "split"):
+        np.testing.assert_allclose(np.asarray(outs[name]),
+                                   np.asarray(outs["inline"]),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("flags", [(), ("use_pos_emb",),
+                                   ("use_ref_pose_emb",)],
+                         ids=lambda f: "+".join(f) or "neither")
+def test_sampler_matches_inline_loop_whatever_the_extent(flags, capsys):
+    """`make_sampler` hands the model the unconditional half at 1 × 1
+    extent only where it is one vector per frame: with a learned
+    position table or a frame-identity embedding it keeps the full
+    extent (and says why, once). Either way its images are those of a
+    loop that recomputes the pose path inside every step."""
+    import dataclasses
+
+    from novel_view_synthesis_3d_tpu.sample import ddpm
+    from novel_view_synthesis_3d_tpu.utils.profiling import reset_log_once
+
+    cfg = dataclasses.replace(TINY, **{f: True for f in flags})
+    model = XUNet(cfg)
+    raw = make_example_batch(batch_size=2, sidelength=16)
+    cond = {k: jnp.asarray(raw[k]) for k in ("x", "R1", "t1", "R2", "t2",
+                                             "K")}
+    batch = dict(cond, z=jnp.asarray(raw["target"]), logsnr=jnp.zeros((2,)))
+    params = _perturbed(model.init(
+        {"params": jax.random.PRNGKey(0)}, batch, cond_mask=jnp.ones((2,)),
+        train=False)["params"])
+
+    embs = ddpm._doubled_pose_embs(model, params, cond)
+    assert all(isinstance(e, tuple) != bool(flags) for e in embs)
+
+    dcfg = DiffusionConfig(timesteps=8, sample_timesteps=2,
+                           guidance_weight=3.0)
+    sched = respace(dcfg, 2)
+    reset_log_once()
+    capsys.readouterr()
+    imgs = make_sampler(model, sched, dcfg)(params, jax.random.PRNGKey(3),
+                                            cond)
+    err = capsys.readouterr().err
+    assert ("FiLM sites project" in err) != bool(flags), err
+    assert ("keeps full-extent pose embeddings" in err) == bool(flags), err
+    for f in flags:
+        assert f in err
+
+    update, init_aux = ddpm._make_update(sched, dcfg)
+    key, k_init = jax.random.split(jax.random.PRNGKey(3))
+    z = jax.random.normal(k_init, imgs.shape)
+    aux = init_aux(z)
+    for t in (jnp.asarray(1), jnp.asarray(0)):
+        key, k_step = jax.random.split(key)
+        outs = ddpm._cfg_eps(
+            model, params,
+            dict(cond, z=z, logsnr=jnp.full((2,), sched.logsnr(t))),
+            dcfg.guidance_weight)
+        z, aux = update(z, t, outs, k_step, aux)
+    # One jitted scan against eager steps, guidance amplifying both: they
+    # sit 6e-5 to 1.2e-4 apart on either path; the unconditional half
+    # scaled by 1.5 moves an image by 3e-2.
+    assert float(jnp.mean(jnp.abs(z) < 1.0)) > 0.2  # not all clipped
+    np.testing.assert_allclose(np.asarray(imgs), np.asarray(z),
+                               rtol=0, atol=1e-3)
+
+
+def test_split_pose_embs_leave_the_param_tree_alone(capsys):
+    """Checkpoint layout: the tree `XUNet.init` builds is the one it built
+    before a pair could be passed (paths and shapes pinned), `init` does
+    not take the split path, and a call on the split path creates no
+    parameter that tree lacks."""
+    import hashlib
+
+    from novel_view_synthesis_3d_tpu.models.xunet import (
+        precompute_guidance_pose_embs)
+    from novel_view_synthesis_3d_tpu.utils.profiling import reset_log_once
+
+    def layout(tree):
+        return sorted(("/".join(str(k.key) for k in path), tuple(leaf.shape))
+                      for path, leaf in
+                      jax.tree_util.tree_flatten_with_path(tree)[0])
+
+    reset_log_once()
+    capsys.readouterr()
+    model, params, cond = _model_and_params(B=2)
+    assert "FiLM sites" not in capsys.readouterr().err
+    flat = layout(params)
+    assert len(flat) == 178
+    assert hashlib.sha256(repr(flat).encode()).hexdigest()[:16] == (
+        "6f875995620f797a")
+    film = [p for p, _ in flat if "/FiLM_0/" in p]
+    assert film and all(p.split("/FiLM_0/")[1] in ("Dense_0/kernel",
+                                                  "Dense_0/bias")
+                        for p in film)
+
+    split = precompute_guidance_pose_embs(model, params, cond)
+    doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0), cond)
+    batch = dict(doubled, z=jnp.zeros((4, 16, 16, 3)),
+                 logsnr=jnp.zeros((4,)), pose_embs=split)
+    mask = jnp.concatenate([jnp.ones((2,)), jnp.zeros((2,))])
+    out, grown = model.apply({"params": params}, batch, cond_mask=mask,
+                             train=False, mutable=["params"])
+    assert "FiLM sites" in capsys.readouterr().err
+    assert out.shape == (4, 16, 16, 3)
+    assert layout(grown.get("params", params)) == flat
+
+
 @pytest.mark.slow
 def test_stochastic_precompute_matches_inline_path():
     """The stochastic sampler's hoisted pose path (precompute_pose=True)

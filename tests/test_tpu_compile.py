@@ -21,6 +21,7 @@ os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from novel_view_synthesis_3d_tpu.ops import (
@@ -206,3 +207,73 @@ def test_admitted_slabs_are_cases():
             mod = (fused_groupnorm if "groupnorm" in name
                    else fused_epilogue)
             assert mod.fits_vmem(hw, c, BF16), name
+
+
+_HLO_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+
+
+def _bytes_written_by_kind(text):
+    """Bytes each top-level instruction of a compiled program's entry
+    writes, summed by the layer kind its `op_name` is stamped with
+    (models/xunet.layer_of); what a fusion keeps inside is not written."""
+    import re
+
+    from novel_view_synthesis_3d_tpu.models.xunet import layer_of
+
+    written = {}
+    for line in text[text.index("ENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not (m and name) or m.group(2) in (
+                "parameter", "get-tuple-element", "tuple", "constant",
+                "bitcast"):
+            continue
+        size = sum(
+            _HLO_BYTES[dt] * int(np.prod([int(d) for d in dims.split(",")]))
+            for dt, dims in re.findall(r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]",
+                                       m.group(1)))
+        kind = layer_of(name.group(1))[1]
+        written[kind] = written.get(kind, 0) + size
+    return written
+
+
+def test_film_pair_buys_no_pass_over_h_on_v5e(v5e):
+    """A ResnetBlock at paper256's level-0 shape, compiled for the chip
+    with a guidance pair's embedding (conditional rows at full extent,
+    unconditional rows at 1 × 1) and with the same rows at full extent.
+    With the pair, what is written under `lk.emb` is the conditional
+    rows' projection and nothing else of its size — no (scale, shift) at
+    full extent for every row, no copy, slice or concatenate of `h` — and
+    the norms and convolutions write what they write at full extent. It
+    holds only while XLA:TPU fuses FiLM's pad → split → sum into the
+    modulation's one pass over `h` (models/layers.FiLM says which other
+    orders cost which passes); the chip would show a loss as `gn` time."""
+    from novel_view_synthesis_3d_tpu.models.layers import ResnetBlock
+
+    n, F, side, C, E = 2, 2, 256, 256, 1024
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=v5e)
+
+    block = ResnetBlock(dtype=BF16)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e),
+        jax.eval_shape(lambda: block.init(
+            jax.random.PRNGKey(0), jnp.zeros((2 * n, F, 8, 8, C), BF16),
+            jnp.zeros((2 * n, F, 8, 8, E), BF16), train=False)))
+    h = S(2 * n, F, side, side, C)
+    written = {
+        name: _bytes_written_by_kind(jax.jit(
+            lambda p, h, e: block.apply(p, h, e, train=False)
+        ).lower(params, h, emb).compile().as_text())
+        for name, emb in (("pair", (S(n, F, side, side, E),
+                                    S(n, F, 1, 1, E))),
+                          ("full", S(2 * n, F, side, side, E)))}
+    cond_projection = n * F * side * side * 2 * C * 2  # bf16 (scale, shift)
+    assert written["full"]["emb"] >= 2 * cond_projection, written
+    assert written["pair"]["emb"] <= 1.02 * cond_projection, written
+    for kind in written["full"]:
+        if kind != "emb":
+            assert written["pair"].get(kind, 0) <= 1.01 * written["full"][
+                kind], (kind, written)
+    assert set(written["pair"]) <= set(written["full"]), written
