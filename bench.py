@@ -38,7 +38,7 @@ def build(preset_name: str, overrides=()):
     from novel_view_synthesis_3d_tpu.config import get_preset, MeshConfig
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
     from novel_view_synthesis_3d_tpu.diffusion import make_schedule
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.parallel import mesh as mesh_lib
     from novel_view_synthesis_3d_tpu.train.state import (
         create_train_state, pack_train_state)
@@ -75,7 +75,7 @@ def build(preset_name: str, overrides=()):
     batch = make_example_batch(batch_size=cfg.train.batch_size,
                                sidelength=cfg.data.img_sidelength)
     schedule = make_schedule(cfg.diffusion)
-    model = XUNet(cfg.model, mesh=mesh)
+    model = build_denoiser(cfg.model, mesh=mesh)
     state = create_train_state(cfg.train, model, _sample_model_batch(batch))
     if cfg.train.update_sharding == "zero":
         # ZeRO lane: opt_state/EMA live lane-packed and row-sharded over
@@ -302,7 +302,7 @@ def _sampling_setup(preset_name: str, sample_steps: int, overrides):
     device-committed params. Returns (cfg, model, params, raw batch)."""
     from novel_view_synthesis_3d_tpu.config import get_preset
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.train.state import create_train_state
     from novel_view_synthesis_3d_tpu.train.trainer import _sample_model_batch
 
@@ -313,7 +313,7 @@ def _sampling_setup(preset_name: str, sample_steps: int, overrides):
     cfg.validate()
     raw = make_example_batch(batch_size=1,
                              sidelength=cfg.data.img_sidelength, seed=0)
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     state = create_train_state(cfg.train, model, _sample_model_batch(raw))
     # Commit params to the default device: host-side init leaves them on
     # CPU, and timing with uncommitted params would re-upload per rep.

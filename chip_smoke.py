@@ -271,7 +271,7 @@ def custom_call_counts(cfg) -> dict:
     shapes only: nothing is compiled or run."""
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
     from novel_view_synthesis_3d_tpu.diffusion import make_schedule
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.parallel import mesh as mesh_lib
     from novel_view_synthesis_3d_tpu.sample.ddpm import (
         STEP_COEF_KEYS, make_slot_step_fn)
@@ -283,7 +283,7 @@ def custom_call_counts(cfg) -> dict:
         return jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     side = cfg.data.img_sidelength
     batch = make_example_batch(batch_size=cfg.train.batch_size,
                                sidelength=side)
@@ -418,7 +418,7 @@ def check_data_parallel(preset, seed, overrides, n_chips=4) -> None:
     from novel_view_synthesis_3d_tpu.config import MeshConfig
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
     from novel_view_synthesis_3d_tpu.diffusion import make_schedule
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.parallel import mesh as mesh_lib
     from novel_view_synthesis_3d_tpu.train.state import create_train_state
     from novel_view_synthesis_3d_tpu.train.step import make_train_step
@@ -435,7 +435,7 @@ def check_data_parallel(preset, seed, overrides, n_chips=4) -> None:
 
     def two_steps(data: int) -> dict:
         mesh = mesh_lib.make_mesh(MeshConfig(data=data))
-        model = XUNet(cfg.model, mesh=mesh)  # as train/trainer.py builds it
+        model = build_denoiser(cfg.model, mesh=mesh)  # as train/trainer.py builds it
         state = mesh_lib.replicate(mesh, create_train_state(
             cfg.train, model, _sample_model_batch(batch)))
         step = make_train_step(cfg, model, schedule, mesh)

@@ -185,7 +185,7 @@ def cmd_sample(args, overrides: List[str]) -> int:
 
     from novel_view_synthesis_3d_tpu.data.srn import SRNDataset
     from novel_view_synthesis_3d_tpu.diffusion.schedules import sampling_schedule
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.sample.ddpm import (
         autoregressive_generate, make_sampler)
     from novel_view_synthesis_3d_tpu.train.trainer import _sample_model_batch
@@ -235,7 +235,7 @@ def cmd_sample(args, overrides: List[str]) -> int:
         poses2 = orbit_poses(args.num_views, radius=radius,
                              elevation=args.elevation)
 
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     first_view = {
         "x": jnp.asarray(x)[None],
         "R1": jnp.asarray(pose1[:3, :3])[None],
@@ -399,7 +399,7 @@ def cmd_serve(args, overrides: List[str]) -> int:
     import jax
 
     from novel_view_synthesis_3d_tpu.data.srn import SRNDataset
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.sample.service import (
         Rejected, SamplingService)
     from novel_view_synthesis_3d_tpu.train.trainer import _sample_model_batch
@@ -408,7 +408,7 @@ def cmd_serve(args, overrides: List[str]) -> int:
     cfg = build_config(args, overrides)
     ds = SRNDataset(args.folder or cfg.data.root_dir,
                     img_sidelength=cfg.data.img_sidelength)
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     inst0 = ds.instances[0]
     x0, pose0 = inst0.view(0)
     sample_batch = _sample_model_batch({
@@ -681,13 +681,13 @@ def cmd_eval(args, overrides: List[str]) -> int:
 
     from novel_view_synthesis_3d_tpu.data.srn import SRNDataset
     from novel_view_synthesis_3d_tpu.eval.evaluate import evaluate_dataset
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.train.trainer import _sample_model_batch
 
     cfg = build_config(args, overrides)
     ds = SRNDataset(args.folder or cfg.data.root_dir,
                     img_sidelength=cfg.data.img_sidelength)
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
 
     rec = ds.pair(0, np.random.default_rng(0))
     sample_batch = _sample_model_batch(
@@ -865,7 +865,7 @@ def cmd_export(args, overrides: List[str]) -> int:
     from novel_view_synthesis_3d_tpu.compat.reference_ckpt import (
         export_reference_params)
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.train.trainer import _sample_model_batch
 
     cfg = build_config(args, overrides)
@@ -873,7 +873,7 @@ def cmd_export(args, overrides: List[str]) -> int:
         raise SystemExit(
             "export: the reference format is strictly two-frame (k=1); "
             f"model.num_cond_frames={cfg.model.num_cond_frames}")
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     sample_batch = _sample_model_batch(make_example_batch(
         batch_size=1, sidelength=cfg.data.img_sidelength))
     params, step = _restore_params(cfg, model, sample_batch, args.step)
@@ -926,7 +926,7 @@ def cmd_distill(args, overrides: List[str]) -> int:
 
     import jax
 
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.registry import (
         RegistryError, RegistryStore, promote)
     from novel_view_synthesis_3d_tpu.train.distill import run_distill
@@ -943,7 +943,7 @@ def cmd_distill(args, overrides: List[str]) -> int:
     teacher_params = store.load_params(vid, verify=False)
     print(f"teacher: {vid} (step {manifest.step}, channel "
           f"{args.teacher_channel})")
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     event_cb = _registry_event_cb(args.registry)
 
     data_iter = None
@@ -1208,14 +1208,14 @@ def cmd_registry(args, overrides: List[str]) -> int:
     if sub == "publish":
         from novel_view_synthesis_3d_tpu.data.synthetic import (
             make_example_batch)
-        from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+        from novel_view_synthesis_3d_tpu.models import build_denoiser
         from novel_view_synthesis_3d_tpu.registry.manifest import (
             config_digest)
         from novel_view_synthesis_3d_tpu.train.trainer import (
             _sample_model_batch)
 
         cfg = build_config(args, overrides)
-        model = XUNet(cfg.model)
+        model = build_denoiser(cfg.model)
         sample_batch = _sample_model_batch(make_example_batch(
             batch_size=1, sidelength=cfg.data.img_sidelength))
         # step=None rides the checkpoint integrity walk-back: a torn
@@ -1243,7 +1243,7 @@ def cmd_registry(args, overrides: List[str]) -> int:
                 "empty and no --version was given")
         gate_result = None
         if not args.force:
-            from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+            from novel_view_synthesis_3d_tpu.models import build_denoiser
 
             # Probe AT the serving precision (serve.precision): a
             # version promoted into a bf16/int8 deployment is gated on
@@ -1252,7 +1252,7 @@ def cmd_registry(args, overrides: List[str]) -> int:
             # consistency gate runs too (same margin).
             try:
                 passed, gate_result = _run_gates(
-                    cfg, XUNet(cfg.model), store, vid, channel,
+                    cfg, build_denoiser(cfg.model), store, vid, channel,
                     _gate_probe_batch(cfg, args.folder),
                     psnr_sample_steps=cfg.registry.gate_sample_steps,
                     event_cb=event_cb)

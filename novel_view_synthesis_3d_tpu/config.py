@@ -18,6 +18,61 @@ from typing import Any, Optional, Sequence, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeParameters:
+    """`rope_parameters` of a `mistral4` config.json (yarn), key for key."""
+
+    beta_fast: float = 32
+    beta_slow: float = 1
+    factor: float = 128
+    llama_4_scaling_beta: float = 0.1
+    mscale: float = 1
+    mscale_all_dim: float = 1
+    original_max_position_embeddings: int = 8192
+    rope_theta: float = 10000
+    rope_type: str = "yarn"
+
+
+@dataclasses.dataclass(frozen=True)
+class TokenTrunkConfig:
+    """The token denoiser's trunk (models/token_denoiser.py): a decoder
+    layer with latent attention and sparse experts, under the key names of
+    the `config.json` it is read from. The defaults are
+    Mistral-Small-4-119B-2603's published values; a preset sets the depth
+    and the experts this chip holds."""
+
+    hidden_size: int = 4096
+    num_hidden_layers: int = 36
+    num_attention_heads: int = 32
+    q_lora_rank: int = 1024
+    kv_lora_rank: int = 256
+    qk_nope_head_dim: int = 64
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # The router's width: it always scores all of these and takes
+    # `num_experts_per_tok` of them, whichever experts live here.
+    n_routed_experts: int = 128
+    num_experts_per_tok: int = 4
+    n_shared_experts: int = 1
+    moe_intermediate_size: int = 2048
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    rms_norm_eps: float = 1e-6
+    rope_interleave: bool = True
+    rope_parameters: RopeParameters = dataclasses.field(
+        default_factory=RopeParameters)
+    # (first, count): the routed experts this chip holds of each layer —
+    # its share of an expert-parallel deployment. The layer computes the
+    # part of the result these give and nothing of the others'.
+    held_experts: Tuple[int, int] = (0, 128)
+    # The patch adapter (this repo's): patch × patch pixels make a token.
+    patch_size: int = 4
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """X-UNet hyperparameters (reference: model/xunet.py:205-215)."""
 
@@ -109,6 +164,12 @@ class ModelConfig:
     # (train/ladder.restore_with_growth). 0 = off (no table, param tree
     # unchanged).
     num_classes: int = 0
+    # Which denoiser `models.build_denoiser` builds: "xunet" (everything
+    # above) or "tokens" (models/token_denoiser.py: patch tokens through
+    # the trunk that `tokens` describes; of the fields above it reads
+    # dtype, param_dtype, num_cond_frames and use_flash_attention).
+    family: str = "xunet"
+    tokens: TokenTrunkConfig = None
 
     @property
     def num_frames(self) -> int:
@@ -965,77 +1026,19 @@ class Config:
         """
         m, d, t = self.model, self.data, self.train
         errors = []
-        if m.ch <= 0 or not m.ch_mult:
-            errors.append("model.ch must be positive and model.ch_mult "
-                          "non-empty")
-        for level, mult in enumerate(m.ch_mult):
-            c = m.ch * mult
-            if c % 32 != 0:
+        if m.family == "tokens":
+            errors.extend(_token_family_errors(m, d))
+        elif m.family != "xunet":
+            errors.append(f"model.family={m.family!r}: 'xunet' or 'tokens'")
+        else:
+            if m.tokens is not None:
                 errors.append(
-                    f"model.ch×mult = {c} is not divisible by 32 "
-                    "(GroupNorm runs with 32 groups at every level)")
-            # Heads only matter at levels where attention actually runs.
-            if (d.img_sidelength // (2 ** level) in m.attn_resolutions
-                    and c % m.attn_heads != 0):
-                errors.append(
-                    f"model.ch×mult = {c} (level {level}, attention "
-                    f"resolution {d.img_sidelength // (2 ** level)}) is "
-                    f"not divisible by attn_heads={m.attn_heads}")
-        # Cross-frame attention is the ONLY path from the conditioning
-        # image to the target frame (convs are per-frame). A non-empty
-        # attn_resolutions that matches NO UNet level silently trains an
-        # unconditional pose-memorizer: seen-pose metrics look great,
-        # held-out eval sits at the mean-image floor (r2/r3 quality-run
-        # postmortem — the r2 tool used size//4 on a 2-level UNet).
-        level_res = {d.img_sidelength // (2 ** lv)
-                     for lv in range(len(m.ch_mult))}
-        stray = set(m.attn_resolutions) - level_res
-        if m.attn_resolutions and stray == set(m.attn_resolutions):
-            errors.append(
-                f"model.attn_resolutions={tuple(m.attn_resolutions)} "
-                f"matches NO UNet level (levels run at "
-                f"{tuple(sorted(level_res, reverse=True))} for "
-                f"data.img_sidelength={d.img_sidelength}, "
-                f"{len(m.ch_mult)} levels): cross-frame attention would "
-                "never fire and the conditioning image could not influence "
-                "the generated view. Pick resolutions from the level set, "
-                "or set attn_resolutions=() explicitly for an attention-free "
-                "model")
-        elif stray:
-            # Partial match: attention fires somewhere, but stray entries
-            # are silently inert (advisor r3 — a sub-lethal recurrence of
-            # the r2/r3 postmortem class). Entries related to the
-            # sidelength by a power of two are a deliberate DDPM-style
-            # superset list (the presets keep one attn list across depths
-            # and image sizes; e.g. 8 on a 3-level 64px UNet) — allowed.
-            # Anything else (e.g. 5 at sidelength 16) can never name a
-            # UNet level at any depth or power-of-two rescale of this
-            # config: error.
-            def _pow2_related(e: int) -> bool:
-                if e <= 0:
-                    return False
-                a, b = max(e, d.img_sidelength), min(e, d.img_sidelength)
-                q, r = divmod(a, b)
-                return r == 0 and (q & (q - 1)) == 0
-            bogus = {e for e in stray if not _pow2_related(e)}
-            if bogus:
-                errors.append(
-                    f"model.attn_resolutions entries "
-                    f"{tuple(sorted(bogus))} match no UNet level and never "
-                    f"could (level resolutions are "
-                    f"data.img_sidelength={d.img_sidelength} divided by "
-                    "powers of 2): each would be silently inert. Remove "
-                    "them or pick resolutions from the level set")
+                    "model.tokens is set but model.family is 'xunet'")
+            errors.extend(_xunet_family_errors(m, d))
         if not 0.0 <= m.dropout < 1.0:
             errors.append(f"model.dropout={m.dropout} outside [0, 1)")
         if m.num_cond_frames < 1:
             errors.append("model.num_cond_frames must be >= 1")
-        down = 2 ** (len(m.ch_mult) - 1)
-        if d.img_sidelength % down != 0:
-            errors.append(
-                f"data.img_sidelength={d.img_sidelength} is not divisible "
-                f"by 2^{len(m.ch_mult) - 1} (the UNet downsamples "
-                f"{len(m.ch_mult) - 1} times)")
         if self.diffusion.timesteps < 1:
             errors.append("diffusion.timesteps must be >= 1")
         if not 1 <= self.diffusion.sample_timesteps <= self.diffusion.timesteps:
@@ -1746,10 +1749,114 @@ class Config:
         return self.override(**overrides)
 
 
+def _xunet_family_errors(m: ModelConfig, d: DataConfig) -> list:
+    """The X-UNet's shape checks (GroupNorm groups, heads, attention
+    levels, downsampling)."""
+    errors = []
+    if m.ch <= 0 or not m.ch_mult:
+        errors.append("model.ch must be positive and model.ch_mult "
+                      "non-empty")
+    for level, mult in enumerate(m.ch_mult):
+        c = m.ch * mult
+        if c % 32 != 0:
+            errors.append(
+                f"model.ch×mult = {c} is not divisible by 32 "
+                "(GroupNorm runs with 32 groups at every level)")
+        # Heads only matter at levels where attention actually runs.
+        if (d.img_sidelength // (2 ** level) in m.attn_resolutions
+                and c % m.attn_heads != 0):
+            errors.append(
+                f"model.ch×mult = {c} (level {level}, attention "
+                f"resolution {d.img_sidelength // (2 ** level)}) is "
+                f"not divisible by attn_heads={m.attn_heads}")
+    # Cross-frame attention is the ONLY path from the conditioning
+    # image to the target frame (convs are per-frame). A non-empty
+    # attn_resolutions that matches NO UNet level silently trains an
+    # unconditional pose-memorizer: seen-pose metrics look great,
+    # held-out eval sits at the mean-image floor (r2/r3 quality-run
+    # postmortem — the r2 tool used size//4 on a 2-level UNet).
+    level_res = {d.img_sidelength // (2 ** lv)
+                 for lv in range(len(m.ch_mult))}
+    stray = set(m.attn_resolutions) - level_res
+    if m.attn_resolutions and stray == set(m.attn_resolutions):
+        errors.append(
+            f"model.attn_resolutions={tuple(m.attn_resolutions)} "
+            f"matches NO UNet level (levels run at "
+            f"{tuple(sorted(level_res, reverse=True))} for "
+            f"data.img_sidelength={d.img_sidelength}, "
+            f"{len(m.ch_mult)} levels): cross-frame attention would "
+            "never fire and the conditioning image could not influence "
+            "the generated view. Pick resolutions from the level set, "
+            "or set attn_resolutions=() explicitly for an attention-free "
+            "model")
+    elif stray:
+        # Partial match: attention fires somewhere, but stray entries
+        # are silently inert (advisor r3 — a sub-lethal recurrence of
+        # the r2/r3 postmortem class). Entries related to the
+        # sidelength by a power of two are a deliberate DDPM-style
+        # superset list (the presets keep one attn list across depths
+        # and image sizes; e.g. 8 on a 3-level 64px UNet) — allowed.
+        # Anything else (e.g. 5 at sidelength 16) can never name a
+        # UNet level at any depth or power-of-two rescale of this
+        # config: error.
+        def _pow2_related(e: int) -> bool:
+            if e <= 0:
+                return False
+            a, b = max(e, d.img_sidelength), min(e, d.img_sidelength)
+            q, r = divmod(a, b)
+            return r == 0 and (q & (q - 1)) == 0
+        bogus = {e for e in stray if not _pow2_related(e)}
+        if bogus:
+            errors.append(
+                f"model.attn_resolutions entries "
+                f"{tuple(sorted(bogus))} match no UNet level and never "
+                f"could (level resolutions are "
+                f"data.img_sidelength={d.img_sidelength} divided by "
+                "powers of 2): each would be silently inert. Remove "
+                "them or pick resolutions from the level set")
+    down = 2 ** (len(m.ch_mult) - 1)
+    if d.img_sidelength % down != 0:
+        errors.append(
+            f"data.img_sidelength={d.img_sidelength} is not divisible "
+            f"by 2^{len(m.ch_mult) - 1} (the UNet downsamples "
+            f"{len(m.ch_mult) - 1} times)")
+    return errors
+
+
+def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
+    """What a `family: tokens` model needs of its settings."""
+    k = m.tokens
+    if k is None:
+        return ["model.family='tokens' needs model.tokens (the trunk)"]
+    errors = []
+    first, count = k.held_experts
+    if not (0 <= first and count >= 1
+            and first + count <= k.n_routed_experts):
+        errors.append(
+            f"model.tokens.held_experts={tuple(k.held_experts)} is not a "
+            f"(first, count) range inside the router's "
+            f"{k.n_routed_experts} experts")
+    if not 1 <= k.num_experts_per_tok <= k.n_routed_experts:
+        errors.append("model.tokens.num_experts_per_tok must be in "
+                      "[1, n_routed_experts]")
+    if d.img_sidelength % k.patch_size:
+        errors.append(
+            f"data.img_sidelength={d.img_sidelength} is not a multiple of "
+            f"model.tokens.patch_size={k.patch_size}")
+    if k.qk_rope_head_dim % 2:
+        errors.append("model.tokens.qk_rope_head_dim must be even (rotary "
+                      "pairs)")
+    if m.num_cond_frames != 1:
+        errors.append("model.family='tokens' carries one conditioning "
+                      "frame (model.num_cond_frames=1)")
+    return errors
+
+
 # ----------------------------------------------------------------------
 # Config ladder presets (BASELINE.json "configs")
 # ----------------------------------------------------------------------
-PRESET_NAMES = ("reference", "tiny64", "base128", "paper256", "pod64")
+PRESET_NAMES = ("reference", "tiny64", "base128", "paper256", "pod64",
+                "ms4_denoiser128")
 
 
 def get_preset(name: str) -> Config:
@@ -1826,4 +1933,20 @@ def get_preset(name: str) -> Config:
             # update across the pod instead.
             "train.ema_host": False,
         })
+    if name == "ms4_denoiser128":
+        # A token denoiser whose trunk is Mistral-Small-4-119B-2603's
+        # decoder layer at its published widths (TokenTrunkConfig's
+        # defaults), cut to chip 0's share of a deployment in which four
+        # chips divide each layer by expert parallelism: 6 of 36 layers,
+        # experts 0-31 of 128 held (the router keeps 128 and top-4).
+        # bfloat16 parameters: 1.72 GB a layer, 10.3 GB in all.
+        return Config(
+            model=ModelConfig(
+                family="tokens", dtype="bfloat16", param_dtype="bfloat16",
+                dropout=0.0,
+                tokens=TokenTrunkConfig(num_hidden_layers=6,
+                                        held_experts=(0, 32))),
+            data=DataConfig(img_sidelength=128),
+            diffusion=DiffusionConfig(sample_timesteps=256),
+        )
     raise KeyError(f"unknown preset {name!r}")

@@ -1,5 +1,50 @@
+"""The denoisers, and the one place a model is built.
+
+**The denoiser contract.** Whatever `build_denoiser` returns has
+
+  - `config` (the ModelConfig) and `mesh`;
+  - `init(rngs, batch, cond_mask=, train=)` → `{"params": tree}` and
+    `apply({"params": tree}, batch, cond_mask=, train=)` → ε̂ (B, H, W, 3)
+    of the target frame, flax's calling convention;
+  - `precompute(params, cond)` → a dict of batch entries that do not
+    change over a sampling call, laid out for the samplers' doubled
+    guidance batch (rows [conditional…, unconditional…]); `apply` takes
+    them in `batch`. What is in it is the model's own affair
+    (sample/ddpm.make_sampler hands it through and names no model);
+  - a scope vocabulary: `lk.<kind>` stamps from models/vocab.LAYER_KINDS
+    and `og.<label>` blocks named by `op_groups(config)` of the family's
+    module.
+
+`model.family` selects: "xunet" (models/xunet.XUNet, the default) or
+"tokens" (models/token_denoiser.TokenDenoiser). Entry points that carry
+only the X-UNet say so through `require_family`.
+"""
+
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays  # noqa: F401
 from novel_view_synthesis_3d_tpu.models.xunet import (  # noqa: F401
     ConditioningProcessor,
     XUNet,
 )
+
+
+def build_denoiser(model_config, mesh=None):
+    """The denoiser `model_config.family` names."""
+    if model_config.family == "xunet":
+        return XUNet(model_config, mesh=mesh)
+    if model_config.family == "tokens":
+        from novel_view_synthesis_3d_tpu.models.token_denoiser import (
+            TokenDenoiser)
+
+        return TokenDenoiser(model_config, mesh=mesh)
+    raise ValueError(f"model.family={model_config.family!r}: 'xunet' or "
+                     "'tokens'")
+
+
+def require_family(model_config, family: str, who: str, missing: str):
+    """Refuse, at construction, a family that `who` does not carry yet:
+    one sentence naming the missing piece, and no fallback."""
+    if model_config.family != family:
+        raise NotImplementedError(
+            f"{who} does not carry model.family={model_config.family!r} "
+            f"yet: {missing}")
+

@@ -1,0 +1,494 @@
+"""A token denoiser: patch tokens of both frames through a decoder trunk
+with latent attention (MLA) and sparse experts, ε̂ of the target frame out.
+
+The trunk's layer is Mistral-Small-4-119B-2603's (`mistral4` config.json;
+config.TokenTrunkConfig holds its keys): RMSNorm → low-rank queries and a
+compressed key/value latent with one shared rotary key head → softmax
+attention → RMSNorm → a router over ALL `n_routed_experts`, top-k,
+renormalised → gated-SiLU experts plus one shared expert. What is this
+repo's and not the source's is the frame around it:
+
+  - both frames are cut into `patch_size`² patches, one token each:
+    token = Dense(patch) + Dense(posenc of the patch's rays)·cond_mask +
+    the logsnr embedding (the X-UNet's two-layer MLP on `posenc_ddpm`);
+    the conditioning frame's tokens take it at logsnr 20, the clean frame;
+  - the sequence is [conditioning frame, target frame], a token's rotary
+    position its index in it, and a token sees its own frame and the
+    frames before it — so the conditioning frame's tokens never depend on
+    z_t or the step;
+  - the last RMSNorm is followed by Dense(hidden → patch pixels) on the
+    target's tokens, un-patched to ε̂ (B, H, W, 3). No vocabulary.
+
+**The once-a-call pass.** Because of that mask, everything a step needs of
+the conditioning frame is its per-layer latent cache — the normalised
+c_kv (kv_lora_rank) and the rotated shared key (qk_rope_head_dim) of each
+of its tokens. `precompute` runs the conditioning frame through all layers
+once (prefill) and every denoise step runs the target's tokens alone
+against [cache ; own] (decode through the latent cache). `apply` without a
+cache does exactly the two in a row, so there is one set of equations.
+
+**The expert layer is told which experts it holds** (`held_experts`, a
+(first, count) range: this chip's share of an expert-parallel deployment).
+It routes over all experts, computes the part of the result its own give
+and nothing of the others'. Assignments to held experts are sorted by
+expert and multiplied as ONE grouped product over stacked weights
+(ops/grouped_matmul.py); there is no capacity and no token is dropped,
+whatever the imbalance. On one chip it runs without its exchange.
+
+Not a flax module: parameters are a plain nested dict, `init`/`apply`
+keep flax's calling convention so that every caller of the X-UNet's can
+call this one's (the denoiser contract, models/__init__.py).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from novel_view_synthesis_3d_tpu.config import ModelConfig, TokenTrunkConfig
+from novel_view_synthesis_3d_tpu.models.rays import camera_rays
+from novel_view_synthesis_3d_tpu.ops.flash_attention import (
+    flash_attention, resolve_flash)
+from novel_view_synthesis_3d_tpu.ops.grouped_matmul import grouped_matmul
+from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
+
+LOGSNR_CLEAN = 20.0   # the conditioning frame's logsnr: 3DiM's clean frame
+RAY_CHANNELS = 144    # posenc_nerf(origin, 15) 93 + posenc_nerf(dir, 8) 51
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+# ---------------------------------------------------------------------------
+# Rotary embedding (yarn) and the scales that go with it — static numpy.
+# ---------------------------------------------------------------------------
+def yarn_inv_freq(rope, dim: int) -> np.ndarray:
+    """(dim/2,) rotary frequencies: θ^(−2i/dim) where a pair turns often
+    inside the original context (extrapolated as it is), the same ÷ factor
+    where it turns seldom (interpolated), blended by a linear ramp between
+    the two correction dimensions."""
+    base, factor = float(rope.rope_theta), float(rope.factor)
+    orig = float(rope.original_max_position_embeddings)
+    freq = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return freq * (1.0 - ramp) + freq / factor * ramp
+
+
+def rope_tables(positions, k: TokenTrunkConfig):
+    """cos, sin (L, rope/2) float32 of the given positions, and the
+    queries' position scale (L,) float32:
+    1 + β·ln(1 + ⌊pos / original_max_position_embeddings⌋)."""
+    rope = k.rope_parameters
+    pos = np.asarray(positions, np.float64)
+    ang = pos[:, None] * yarn_inv_freq(rope, k.qk_rope_head_dim)[None]
+    qscale = 1.0 + float(rope.llama_4_scaling_beta) * np.log1p(
+        np.floor(pos / float(rope.original_max_position_embeddings)))
+    return (np.cos(ang).astype(np.float32), np.sin(ang).astype(np.float32),
+            qscale.astype(np.float32))
+
+
+def apply_rope(x, cos, sin, interleave: bool):
+    """Rotate the pairs of x (..., L, [heads,] rope) by the tables (L,
+    rope/2): interleaved pairs (2i, 2i+1) as `rope_interleave` says,
+    otherwise halves (i, i + rope/2). float32 inside."""
+    x32 = x.astype(jnp.float32)
+    if x.ndim == 4:  # (B, L, heads, rope)
+        cos, sin = cos[:, None], sin[:, None]
+    if interleave:
+        a, b = x32[..., 0::2], x32[..., 1::2]
+        out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
+    a, b = jnp.split(x32, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def softmax_scale(k: TokenTrunkConfig) -> float:
+    """qk_head_dim^(−1/2)·m², m = 0.1·mscale_all_dim·ln(factor) + 1 (the
+    DeepSeek-V2 convention for a yarn-scaled model)."""
+    rope = k.rope_parameters
+    m = 1.0
+    if float(rope.factor) > 1.0 and rope.mscale_all_dim:
+        m = 0.1 * float(rope.mscale_all_dim) * math.log(float(rope.factor)) \
+            + 1.0
+    return k.qk_head_dim ** -0.5 * m * m
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+def layer_label(i: int) -> str:
+    return f"layer_{i}"
+
+
+def op_groups(cfg: ModelConfig):
+    """Ordered (label, top-level param names) groups: the `og.<label>`
+    blocks of this family, as models/xunet.op_groups gives the X-UNet's."""
+    layers = [(layer_label(i), (layer_label(i),))
+              for i in range(cfg.tokens.num_hidden_layers)]
+    return [("prelude", ("patch_in", "ray_in", "emb"))] + layers + [
+        ("final", ("final_norm", "out"))]
+
+
+def param_shapes(cfg: ModelConfig) -> dict:
+    """The parameter tree as ShapeDtypeStructs. 2-D kernels are (in, out);
+    an expert stack is (held, in, out). No biases in the trunk."""
+    k = cfg.tokens
+    dt = jnp.dtype(cfg.param_dtype)
+    H, NH = k.hidden_size, k.num_attention_heads
+    held, inter = k.held_experts[1], k.moe_intermediate_size
+    pix = 3 * k.patch_size ** 2
+
+    def w(*shape):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    def mlp(width, *lead):
+        return {"gate": {"kernel": w(*lead, H, width)},
+                "up": {"kernel": w(*lead, H, width)},
+                "down": {"kernel": w(*lead, width, H)}}
+
+    layer = {
+        "attn_norm": {"scale": w(H)},
+        "q_a": {"kernel": w(H, k.q_lora_rank)},
+        "q_norm": {"scale": w(k.q_lora_rank)},
+        "q_b": {"kernel": w(k.q_lora_rank, NH * k.qk_head_dim)},
+        "kv_a": {"kernel": w(H, k.kv_lora_rank + k.qk_rope_head_dim)},
+        "kv_norm": {"scale": w(k.kv_lora_rank)},
+        "kv_b": {"kernel": w(k.kv_lora_rank,
+                             NH * (k.qk_nope_head_dim + k.v_head_dim))},
+        "o": {"kernel": w(NH * k.v_head_dim, H)},
+        "mlp_norm": {"scale": w(H)},
+        "router": {"kernel": w(H, k.n_routed_experts)},
+        "shared": mlp(k.moe_intermediate_size * k.n_shared_experts),
+        "experts": mlp(inter, held),
+    }
+    tree = {
+        "patch_in": {"kernel": w(pix, H)},
+        "ray_in": {"kernel": w(RAY_CHANNELS * k.patch_size ** 2, H)},
+        "emb": {"dense_0": {"kernel": w(H, H), "bias": w(H)},
+                "dense_1": {"kernel": w(H, H), "bias": w(H)}},
+        "final_norm": {"scale": w(H)},
+        "out": {"kernel": w(H, pix)},
+    }
+    for i in range(k.num_hidden_layers):
+        tree[layer_label(i)] = layer
+    return tree
+
+
+def _init_leaf(key, path, s):
+    name = path[-1]
+    if name == "scale":
+        return jnp.ones(s.shape, s.dtype)
+    if name == "bias" or path[0] == "out":
+        return jnp.zeros(s.shape, s.dtype)  # ε̂ = 0 at init, as the X-UNet
+    fan_in = s.shape[-2]
+    return (jax.random.normal(key, s.shape, jnp.float32)
+            / math.sqrt(fan_in)).astype(s.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The layer
+# ---------------------------------------------------------------------------
+def rms_norm(x, scale, eps):
+    """float32 in, float32 out."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
+
+
+def _dense(x, p):
+    return jnp.dot(x, p["kernel"].astype(x.dtype))
+
+
+def _attention(q, k, v, scale, use_flash):
+    """softmax(q·kᵀ·scale)·v, softmax in float32, no mask (the caller
+    hands each frame's queries the keys they may see). q (B, Lq, N, D),
+    k/v (B, Lk, N, D)."""
+    if use_flash:
+        return flash_attention(q, k, v, scale=scale)
+    s = jnp.einsum("bqnd,bknd->bnqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bnqk,bknd->bqnd", p, v)
+
+
+def route(b32, p_router, k: TokenTrunkConfig):
+    """(top-k probabilities (T, k) float32, expert ids (T, k) int32) of
+    normalised tokens b32 (T, H) float32: softmax over ALL experts' logits
+    in float32, top-k, renormalised to sum 1 where the config says so."""
+    logits = jnp.dot(b32, p_router["kernel"].astype(jnp.float32),
+                     precision=HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, k.num_experts_per_tok)
+    if k.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p * float(k.routed_scaling_factor), top_i.astype(jnp.int32)
+
+
+def held_expert_part(b, top_p, top_i, p_experts, k: TokenTrunkConfig):
+    """Σ over a token's top-k experts THAT ARE HELD HERE of p_e·expert_e(b),
+    and the tokens each held expert was given (held,) int32.
+
+    The (T·k) assignments are sorted by expert, those to absent experts
+    last; the held ones are one grouped product each for gate, up and
+    down. Every assignment to a held expert is computed (no capacity);
+    assignments to absent experts lie past the last group, where the
+    product does no work."""
+    with jax.named_scope("lk.moe_route"):
+        first, count = k.held_experts
+        T, K = top_i.shape
+        local = top_i.reshape(-1) - first
+        is_held = (local >= 0) & (local < count)
+        slot = jnp.where(is_held, local, count)       # absent: a last group
+        order = jnp.argsort(slot, stable=True)
+        group_sizes = jnp.bincount(slot, length=count + 1)[:count].astype(
+            jnp.int32)
+        x = jnp.take(b, order // K, axis=0)           # (T·K, H) by expert
+    with jax.named_scope("lk.moe_experts"):
+        g = grouped_matmul(x, p_experts["gate"]["kernel"], group_sizes)
+        u = grouped_matmul(x, p_experts["up"]["kernel"], group_sizes)
+        y = grouped_matmul(jax.nn.silu(g) * u, p_experts["down"]["kernel"],
+                           group_sizes)
+        # Back to token order and summed over a token's choices, one
+        # gather per choice (no (token, choice, hidden) relayout). A choice
+        # that is not held has weight 0 and points past the last group,
+        # at rows the product never wrote: masked, not multiplied.
+        back = jnp.argsort(order).reshape(T, K)
+        w = jnp.where(is_held.reshape(T, K), top_p, 0.0)
+        out = jnp.zeros((T, y.shape[-1]), jnp.float32)
+        for c in range(K):
+            yc = jnp.take(y, back[:, c], axis=0).astype(jnp.float32)
+            wc = w[:, c:c + 1]
+            out = out + jnp.where(wc > 0, yc * wc, 0.0)
+    return out.astype(b.dtype), group_sizes
+
+
+def gated_mlp(x, p):
+    return _dense(jax.nn.silu(_dense(x, p["gate"])) * _dense(x, p["up"]),
+                  p["down"])
+
+
+class TokenDenoiser:
+    """The denoiser contract (models/__init__.py) for `family: tokens`."""
+
+    family = "tokens"
+
+    def __init__(self, config: ModelConfig, mesh=None):
+        if config.tokens is None:
+            raise ValueError("TokenDenoiser needs config.tokens (the trunk)")
+        if mesh is not None and math.prod(dict(mesh.shape).values()) > 1:
+            raise NotImplementedError(
+                "the token denoiser runs on one chip: the all-to-all that "
+                "exchanges tokens between the chips of an expert-parallel "
+                "layer is not in parallel/ yet")
+        self.config = config
+        self.mesh = mesh
+
+    # -- parameters --------------------------------------------------------
+    def init(self, rngs, batch=None, *, cond_mask=None, train=False):
+        """{"params": tree}. `batch` is not read (the tree does not depend
+        on the image size); it is there for flax's calling convention."""
+        key = rngs["params"] if isinstance(rngs, dict) else rngs
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(
+            param_shapes(self.config))
+        out = [_init_leaf(jax.random.fold_in(key, i),
+                          tuple(str(getattr(q, "key", q)) for q in path), s)
+               for i, (path, s) in enumerate(leaves)]
+        return {"params": jax.tree_util.tree_unflatten(treedef, out)}
+
+    # -- pieces --------------------------------------------------------------
+    def _patches(self, img):
+        """(B, H, W, C) → (B, L, p·p·C), patches in raster order."""
+        p = self.config.tokens.patch_size
+        B, H, W, C = img.shape
+        x = img.reshape(B, H // p, p, W // p, p, C).transpose(0, 1, 3, 2, 4, 5)
+        return x.reshape(B, (H // p) * (W // p), p * p * C)
+
+    def _unpatch(self, tok, side_h, side_w):
+        p = self.config.tokens.patch_size
+        B = tok.shape[0]
+        x = tok.reshape(B, side_h // p, side_w // p, p, p, -1)
+        return x.transpose(0, 1, 3, 2, 4, 5).reshape(B, side_h, side_w, -1)
+
+    def _logsnr_emb(self, params, logsnr):
+        """(B,) → (B, hidden): the X-UNet's logsnr embedding (clip ±20,
+        squash to (0, 1), DDPM sinusoid, Dense → swish → Dense). The
+        sinusoid is taken in float32: 1000·u needs more than bfloat16's 8
+        bits near 1000."""
+        dt = jnp.dtype(self.config.dtype)
+        with jax.named_scope("lk.emb"):
+            lam = jnp.clip(logsnr.astype(jnp.float32), -20.0, 20.0)
+            u = 2.0 * jnp.arctan(jnp.exp(-lam / 2.0)) / np.pi
+            e = posenc_ddpm(u, emb_ch=self.config.tokens.hidden_size,
+                            max_time=1.0).astype(dt)
+            p = params["emb"]
+            e = _dense(e, p["dense_0"]) + p["dense_0"]["bias"].astype(dt)
+            return _dense(jax.nn.swish(e), p["dense_1"]) \
+                + p["dense_1"]["bias"].astype(dt)
+
+    def _frame_tokens(self, params, img, R, t, K, logsnr, cond_mask):
+        """One frame's tokens (B, L, hidden) in the compute type."""
+        dt = jnp.dtype(self.config.dtype)
+        H, W = img.shape[1:3]
+        with jax.named_scope("lk.pose"):
+            pos, dirs = camera_rays(R.astype(jnp.float32),
+                                    t.astype(jnp.float32),
+                                    K.astype(jnp.float32), resolution=(H, W))
+            rays = jnp.concatenate([posenc_nerf(pos, 0, 15),
+                                    posenc_nerf(dirs, 0, 8)], axis=-1)
+            rays = self._patches(rays).astype(dt)
+        with jax.named_scope("lk.patch"):
+            tok = _dense(self._patches(img).astype(dt), params["patch_in"])
+            ray_tok = _dense(rays, params["ray_in"])
+            if cond_mask is not None:
+                # Classifier-free guidance: the ray term drops per row, as
+                # the X-UNet's pose embedding does.
+                ray_tok = ray_tok * cond_mask.astype(dt)[:, None, None]
+            tok = tok + ray_tok
+        return tok + self._logsnr_emb(params, logsnr)[:, None, :]
+
+    def _layer(self, p, h, tables, cache):
+        """One trunk layer over one frame's tokens h (B, L, hidden), whose
+        rotary tables are `tables`; `cache` is the (c_kv, k_rope) of the
+        frames before it, or None for the first frame. → (h, this frame's
+        (c_kv, k_rope), tokens per held expert)."""
+        cfg, k = self.config, self.config.tokens
+        dt = jnp.dtype(cfg.dtype)
+        eps = k.rms_norm_eps
+        B, L, _ = h.shape
+        NH, dn, dr, dv = (k.num_attention_heads, k.qk_nope_head_dim,
+                          k.qk_rope_head_dim, k.v_head_dim)
+        cos, sin, qscale = tables
+        with jax.named_scope("lk.mla_proj"):
+            a = rms_norm(h, p["attn_norm"]["scale"], eps).astype(dt)
+            c_q = rms_norm(_dense(a, p["q_a"]), p["q_norm"]["scale"],
+                           eps).astype(dt)
+            q = _dense(c_q, p["q_b"]).reshape(B, L, NH, dn + dr)
+            q = jnp.concatenate(
+                [q[..., :dn],
+                 apply_rope(q[..., dn:], cos, sin, k.rope_interleave)],
+                axis=-1)
+            if np.any(qscale != 1.0):
+                q = q * jnp.asarray(qscale, dt)[None, :, None, None]
+            kv_a = _dense(a, p["kv_a"])
+            c_kv = rms_norm(kv_a[..., :k.kv_lora_rank],
+                            p["kv_norm"]["scale"], eps).astype(dt)
+            k_rope = apply_rope(kv_a[..., k.kv_lora_rank:], cos, sin,
+                                k.rope_interleave)
+            own = (c_kv, k_rope)
+            if cache is not None:
+                c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
+                k_rope = jnp.concatenate([cache[1].astype(dt), k_rope],
+                                         axis=1)
+            # Keys and values up-projected from the latent (the form a
+            # chip run chose over absorbed weights; PERF.md, PR 26).
+            Lk = c_kv.shape[1]
+            kv = _dense(c_kv, p["kv_b"]).reshape(B, Lk, NH, dn + dv)
+            keys = jnp.concatenate(
+                [kv[..., :dn],
+                 jnp.broadcast_to(k_rope[:, :, None, :], (B, Lk, NH, dr))],
+                axis=-1)
+            values = kv[..., dn:]
+        with jax.named_scope("lk.mla_core"):
+            o = _attention(q, keys, values, softmax_scale(k),
+                           resolve_flash(cfg.use_flash_attention))
+        with jax.named_scope("lk.mla_proj"):
+            h = h + _dense(o.reshape(B, L, NH * dv), p["o"])
+        with jax.named_scope("lk.moe_route"):
+            b32 = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(B * L, -1)
+            top_p, top_i = route(b32, p["router"], k)
+            b = b32.astype(dt)
+        routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
+        with jax.named_scope("lk.moe_shared"):
+            shared = gated_mlp(b, p["shared"])
+        h = h + (shared + routed).reshape(B, L, -1)
+        return h, own, counts
+
+    def _frame(self, params, tok, frame_index, caches):
+        """One frame's tokens through every layer. → (h, per-layer own
+        (c_kv, k_rope), per-layer tokens per held expert)."""
+        L = tok.shape[1]
+        tables = rope_tables(np.arange(L) + frame_index * L,
+                             self.config.tokens)
+        h, owns, counts = tok, [], []
+        for i in range(self.config.tokens.num_hidden_layers):
+            label = layer_label(i)
+            with jax.named_scope(f"og.{label}"):
+                h, own, c = self._layer(
+                    params[label], h, tables,
+                    None if caches is None else caches[i])
+            owns.append(own)
+            counts.append(c)
+        return h, tuple(owns), jnp.stack(counts)
+
+    def _cond_frame(self, params, cond, cond_mask):
+        """The conditioning frame through the trunk: its latent cache."""
+        x, R1, t1 = cond["x"], cond["R1"], cond["t1"]
+        if x.ndim == 5:       # (B, 1, H, W, 3): one conditioning frame
+            x, R1, t1 = x[:, 0], R1[:, 0], t1[:, 0]
+        B = x.shape[0]
+        with jax.named_scope("og.prelude"):
+            tok = self._frame_tokens(
+                params, x, R1, t1, cond["K"],
+                jnp.full((B,), LOGSNR_CLEAN, jnp.float32), cond_mask)
+        _, cache, counts = self._frame(params, tok, 0, None)
+        return cache, counts
+
+    # -- the contract --------------------------------------------------------
+    def precompute(self, params, cond: dict):
+        """What does not change over a call, for `_raw_eps`'s doubled
+        guidance layout (rows [conditional…, unconditional…]): the
+        conditioning frame's per-layer latent cache, as batch entries."""
+        B = cond["x"].shape[0]
+        doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0),
+                               dict(cond))
+        mask = jnp.concatenate([jnp.ones((B,)), jnp.zeros((B,))])
+        with jax.named_scope("precompute"):
+            cache, _ = self._cond_frame(params, doubled, mask)
+        return {"latent_cache": cache}
+
+    def _forward(self, params, batch, cond_mask):
+        cache = batch.get("latent_cache")
+        if cache is None:
+            cache, _ = self._cond_frame(params, batch, cond_mask)
+        z = batch["z"]
+        with jax.named_scope("og.prelude"):
+            tok = self._frame_tokens(params, z, batch["R2"], batch["t2"],
+                                     batch["K"], batch["logsnr"], cond_mask)
+        h, _, counts = self._frame(params, tok, 1, cache)
+        with jax.named_scope("og.final"):
+            with jax.named_scope("lk.patch"):
+                k = self.config.tokens
+                hn = rms_norm(h, params["final_norm"]["scale"],
+                              k.rms_norm_eps).astype(h.dtype)
+                out = jnp.dot(hn, params["out"]["kernel"].astype(h.dtype),
+                              preferred_element_type=jnp.float32)
+                eps = self._unpatch(out, z.shape[1], z.shape[2])
+        return eps, counts
+
+    def apply(self, variables, batch, *, cond_mask=None, train=False,
+              **unsupported):
+        """ε̂ (B, H, W, 3) float32 of the target frame. With
+        `batch["latent_cache"]` (from `precompute`) only the target's
+        tokens run; without it the conditioning frame runs first."""
+        if unsupported:
+            raise NotImplementedError(
+                "the token denoiser has no "
+                + ", ".join(sorted(unsupported)) + " path (pipeline stages "
+                "and op slices are the X-UNet's)")
+        return self._forward(variables["params"], batch, cond_mask)[0]
+
+    def routing_counts(self, params, batch, cond_mask=None):
+        """Tokens each held expert is given, per layer, in the pass that
+        `apply` makes over the target's tokens: (layers, held) int32. Every
+        assignment to a held expert is in it — nothing is dropped."""
+        return self._forward(params, batch, cond_mask)[1]

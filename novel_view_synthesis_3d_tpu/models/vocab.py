@@ -1,0 +1,55 @@
+"""The scope vocabulary of tracing and staging, for every denoiser family.
+
+The program stamps `jax.named_scope("lk.<kind>")` where the work happens
+and `og.<label>` around each op group of a model's op loop; `layer_of`
+reads a scope path back into (block, kind). One vocabulary serves both
+families, so one reader (benchmarks/layer_metrics/layer_ms_per_call.py)
+serves every cell; `models/xunet.py` re-exports these names.
+"""
+
+from __future__ import annotations
+
+import re
+
+# The kinds each family stamps. `emb`, `pose` and `update` are shared: the
+# logsnr MLP, the rays and their encoding, the sampler's update.
+XUNET_LAYER_KINDS = ("conv", "gn", "attn", "emb", "pose", "update")
+TOKEN_LAYER_KINDS = ("mla_proj", "mla_core", "moe_route", "moe_experts",
+                     "moe_shared", "patch", "emb", "pose", "update")
+# Every kind a `jax.named_scope("lk.<kind>")` may stamp. The stamps sit
+# where the work happens (models/layers.py, models/xunet.py,
+# models/token_denoiser.py, sample/ddpm.py); these tuples and layer_of are
+# the only other place a kind is spelled.
+LAYER_KINDS = XUNET_LAYER_KINDS + tuple(
+    k for k in TOKEN_LAYER_KINDS if k not in XUNET_LAYER_KINDS)
+
+
+def layer_of(path: str):
+    """(block, kind) of a scope path — an HLO `op_name`, which a profiler
+    capture carries as the `tf_op` of a device event's metadata.
+
+    `block` is the `og.<label>` of op_groups ('' outside the model's op
+    loop). `kind` is the innermost `lk.<kind>` stamp (LAYER_KINDS), with
+    two exceptions: a stamp from outside a block does not reach into it
+    (the sampler's `update` encloses the model call, whose unstamped
+    instructions are the model's `other`, not the sampler's), and `pose`
+    anywhere on the path wins, because the pose path is one thing to a
+    reader whichever convolutions and norms it is made of. A path inside
+    a program scope with no kind is `other`; a path with no program
+    scope at all (the compiler's own instructions, an RNG helper called
+    outside every stamp) is `unattributed`. Transform wrappers
+    (`transpose(jvp(XUNet))/og.final/...`) are split like slashes; of the
+    `;`-joined paths of instructions XLA merged, the first holds.
+    """
+    segs = [s for s in re.split(r"[/()]", path.split(";", 1)[0]) if s]
+    blocks = [i for i, s in enumerate(segs) if s.startswith("og.")]
+    block = segs[blocks[-1]][3:] if blocks else ""
+    kinds = [(i, s[3:]) for i, s in enumerate(segs)
+             if s.startswith("lk.") and s[3:] in LAYER_KINDS]
+    if any(k == "pose" for _, k in kinds):
+        return block, "pose"
+    if blocks:
+        kinds = [(i, k) for i, k in kinds if i > blocks[-1]]
+    if kinds:
+        return block, kinds[-1][1]
+    return block, "other" if blocks else "unattributed"

@@ -23,8 +23,6 @@ Batch contract (canonical keys, reference train.py:23-34):
 
 from __future__ import annotations
 
-import re
-
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -39,6 +37,13 @@ from novel_view_synthesis_3d_tpu.models.layers import (
     nonlinearity,
 )
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
+# The scope vocabulary lives in models/vocab.py for every family; these
+# names stay importable here (benchmarks/layer_metrics reads them here).
+from novel_view_synthesis_3d_tpu.models.vocab import (  # noqa: F401
+    LAYER_KINDS,
+    XUNET_LAYER_KINDS,
+    layer_of,
+)
 from novel_view_synthesis_3d_tpu.ops.flash_attention import resolve_flash
 from novel_view_synthesis_3d_tpu.ops.fused_epilogue import (
     resolve_fused_epilogue)
@@ -403,44 +408,6 @@ def op_groups(cfg: ModelConfig):
     return groups
 
 
-# The layer kinds a `jax.named_scope("lk.<kind>")` may stamp. The stamps
-# sit where the work happens (models/layers.py, ConditioningProcessor and
-# level_emb here, sample/ddpm.py); this tuple and layer_of are the only
-# other place a kind is spelled.
-LAYER_KINDS = ("conv", "gn", "attn", "emb", "pose", "update")
-
-
-def layer_of(path: str):
-    """(block, kind) of a scope path — an HLO `op_name`, which a profiler
-    capture carries as the `tf_op` of a device event's metadata.
-
-    `block` is the `og.<label>` of op_groups ('' outside the model's op
-    loop). `kind` is the innermost `lk.<kind>` stamp (LAYER_KINDS), with
-    two exceptions: a stamp from outside a block does not reach into it
-    (the sampler's `update` encloses the model call, whose unstamped
-    instructions are the model's `other`, not the sampler's), and `pose`
-    anywhere on the path wins, because the pose path is one thing to a
-    reader whichever convolutions and norms it is made of. A path inside
-    a program scope with no kind is `other`; a path with no program
-    scope at all (the compiler's own instructions, an RNG helper called
-    outside every stamp) is `unattributed`. Transform wrappers
-    (`transpose(jvp(XUNet))/og.final/...`) are split like slashes; of the
-    `;`-joined paths of instructions XLA merged, the first holds.
-    """
-    segs = [s for s in re.split(r"[/()]", path.split(";", 1)[0]) if s]
-    blocks = [i for i, s in enumerate(segs) if s.startswith("og.")]
-    block = segs[blocks[-1]][3:] if blocks else ""
-    kinds = [(i, s[3:]) for i, s in enumerate(segs)
-             if s.startswith("lk.") and s[3:] in LAYER_KINDS]
-    if any(k == "pose" for _, k in kinds):
-        return block, "pose"
-    if blocks:
-        kinds = [(i, k) for i, k in kinds if i > blocks[-1]]
-    if kinds:
-        return block, kinds[-1][1]
-    return block, "other" if blocks else "unattributed"
-
-
 class XUNet(nn.Module):
     """The X-UNet (reference model/xunet.py:205-280), config-driven.
 
@@ -463,6 +430,31 @@ class XUNet(nn.Module):
 
     config: ModelConfig = ModelConfig()
     mesh: object = None
+    family = "xunet"
+
+    @nn.nowrap
+    def precompute(self, params, cond: dict) -> dict:
+        """The denoiser contract's once-a-call part (models/__init__.py):
+        pose embeddings for the samplers' doubled guidance layout —
+        conditional half with the mask on, unconditional half with the
+        pose embedding zeroed, exactly what the in-loop mask produced.
+        Cameras are fixed for a whole reverse process, so the rays →
+        posenc → per-level convs run once here instead of every step.
+
+        Wherever the configuration admits it the unconditional half comes
+        at 1 × 1 extent — one vector per frame, which is all the mask
+        leaves of it: each level is then a (cond, uncond) pair, and the
+        model projects the unconditional rows once a frame at each FiLM
+        site (precompute_guidance_pose_embs, which also says when it does
+        not hold; each level is then one array over the doubled rows)."""
+        pairs = precompute_guidance_pose_embs(self, params, cond)
+        if pairs is None:
+            B = cond["x"].shape[0]
+            doubled = jax.tree.map(
+                lambda a: jnp.concatenate([a, a], axis=0), cond)
+            mask = jnp.concatenate([jnp.ones((B,)), jnp.zeros((B,))])
+            pairs = precompute_pose_embs(self, params, doubled, mask)
+        return {"pose_embs": pairs}
 
     @nn.compact
     def __call__(self, batch: dict, *, cond_mask: jnp.ndarray = None,

@@ -207,10 +207,15 @@ def xunet_costmap(config, model_batch) -> List[dict]:
     """
     import jax
 
+    from novel_view_synthesis_3d_tpu.models import (
+        build_denoiser, require_family)
     from novel_view_synthesis_3d_tpu.models.xunet import (
-        XUNet, op_groups, pipeline_op_specs)
+        op_groups, pipeline_op_specs)
 
-    model = XUNet(config.model)
+    require_family(
+        config.model, "xunet", "obs.compiles' per-op cost table",
+        "an op-sliced call (`ops=(a, b)`) of the token trunk's layers")
+    model = build_denoiser(config.model)
     specs = pipeline_op_specs(config.model)
     labels = [label for label, _ in op_groups(config.model)]
 

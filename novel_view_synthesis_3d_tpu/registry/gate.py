@@ -88,6 +88,13 @@ def make_psnr_probe(model, diffusion, batch: dict, *,
     counts against the gate margin — a candidate that only looks good
     in f32 cannot be promoted into a bf16/int8 deployment. Pass the
     deployment's `serve.precision` here (the CLI promote path does)."""
+    from novel_view_synthesis_3d_tpu.models import require_family
+
+    require_family(
+        model.config, "xunet", "registry.gate.make_psnr_probe",
+        "precision staging (sample/precision.py) of a token denoiser's "
+        "tree: which leaves of an expert stack quantize, and a gate set "
+        "scored at that precision")
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -145,6 +152,13 @@ def make_trajectory_probe(model, diffusion, batch: dict, *,
     from the probe batch), identical noise for candidate and incumbent.
     `precision` stages weights exactly like the serving path, as in
     `make_psnr_probe`."""
+    from novel_view_synthesis_3d_tpu.models import require_family
+
+    require_family(
+        model.config, "xunet", "registry.gate.make_trajectory_probe",
+        "precision staging (sample/precision.py) of a token denoiser's "
+        "tree: which leaves of an expert stack quantize, and a gate set "
+        "scored at that precision")
     import jax
     import numpy as np
 

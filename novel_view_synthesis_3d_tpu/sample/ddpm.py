@@ -46,16 +46,16 @@ import numpy as np
 
 from novel_view_synthesis_3d_tpu.config import DiffusionConfig
 from novel_view_synthesis_3d_tpu.diffusion.schedules import DiffusionSchedule
+from novel_view_synthesis_3d_tpu.models import require_family
 from novel_view_synthesis_3d_tpu.models.xunet import (
     precompute_cond_feats,
-    precompute_guidance_pose_embs,
     precompute_pose_embs,
 )
 from novel_view_synthesis_3d_tpu.ops import fused_step as fused_step_lib
 
 
 def _raw_eps(model, params, model_batch: dict, pose_embs=None,
-             cond_feats=None):
+             cond_feats=None, precomputed=None):
     """(ε̂_cond, ε̂_uncond) network outputs via one doubled-batch forward.
 
     `pose_embs`: per-level pose embeddings already computed for the
@@ -68,7 +68,10 @@ def _raw_eps(model, params, model_batch: dict, pose_embs=None,
     (models/xunet.precompute_guidance_pose_embs decides it).
     `cond_feats`: stem features of the conditioning frame(s) for the
     doubled layout (models/xunet.precompute_cond_feats) — with them the
-    step program convolves only the noised target frame."""
+    step program convolves only the noised target frame.
+    `precomputed`: what the model's own `precompute(params, cond)` gave
+    for the doubled layout, batch entries handed through unread (the
+    denoiser contract, models/__init__.py)."""
     B = model_batch["z"].shape[0]
     doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0), model_batch)
     mask = jnp.concatenate([jnp.ones((B,)), jnp.zeros((B,))])
@@ -76,38 +79,27 @@ def _raw_eps(model, params, model_batch: dict, pose_embs=None,
         doubled["pose_embs"] = pose_embs
     if cond_feats is not None:
         doubled["cond_feats"] = cond_feats
+    if precomputed is not None:
+        doubled.update(precomputed)
     eps = model.apply({"params": params}, doubled, cond_mask=mask, train=False)
     eps_cond, eps_uncond = jnp.split(eps, 2, axis=0)
     return eps_cond, eps_uncond
 
 
 def _cfg_eps(model, params, model_batch: dict, w: float,
-             pose_embs=None):
+             pose_embs=None, precomputed=None):
     """(guided, conditional) network outputs; CFG combine applied here.
     The conditional output rides along for cfg_rescale."""
     eps_cond, eps_uncond = _raw_eps(model, params, model_batch,
-                                    pose_embs=pose_embs)
+                                    pose_embs=pose_embs,
+                                    precomputed=precomputed)
     return (1.0 + w) * eps_cond - w * eps_uncond, eps_cond
 
 
 def _doubled_pose_embs(model, params, cond: dict):
-    """Pose embeddings for _cfg_eps's doubled layout, computed once per
-    trajectory: conditional half with the mask on, unconditional half with
-    the pose embedding zeroed — exactly what the in-loop mask produced.
-
-    Wherever the configuration admits it the unconditional half comes at
-    1 × 1 extent — one vector per frame, which is all the mask leaves of
-    it: each level is then a (cond, uncond) pair, and the model projects
-    the unconditional rows once a frame at each FiLM site
-    (models/xunet.precompute_guidance_pose_embs, which also says when it
-    does not hold; each level is then one array over the doubled rows)."""
-    pairs = precompute_guidance_pose_embs(model, params, cond)
-    if pairs is not None:
-        return pairs
-    B = cond["x"].shape[0]
-    doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0), cond)
-    mask = jnp.concatenate([jnp.ones((B,)), jnp.zeros((B,))])
-    return precompute_pose_embs(model, params, doubled, mask)
+    """The X-UNet's pose embeddings for _cfg_eps's doubled layout, computed
+    once per trajectory (models/xunet.XUNet.precompute says how)."""
+    return model.precompute(params, cond)["pose_embs"]
 
 
 def _per_row_encode(model, params, cond: dict, mask):
@@ -167,6 +159,9 @@ def make_cond_encode_fn(model, *, param_transform=None):
     `param_transform` must match the step program's (the int8 path
     dequantizes in-jit) so cached activations are computed from exactly
     the weights the step program would have used."""
+    require_family(
+        model.config, "xunet", "sample.ddpm.make_cond_encode_fn (the serving cond cache)",
+        "a per-slot latent cache of the conditioning frame in place of pose embeddings and stem features")
 
     @jax.jit
     def encode(params, cond, mask):
@@ -344,12 +339,12 @@ def make_sampler(model, schedule: DiffusionSchedule, config: DiffusionConfig,
         raise ValueError(
             f"trajectory_every must be in [0, {T}]; got {trajectory_every}")
 
-    def body(cond, params, pose_embs, carry, t):
+    def body(cond, params, precomputed, carry, t):
         z, key, aux = carry
         key, k_step = jax.random.split(key)
         batch = dict(cond, z=z,
                      logsnr=jnp.full((z.shape[0],), schedule.logsnr(t)))
-        outs = _cfg_eps(model, params, batch, w, pose_embs=pose_embs)
+        outs = _cfg_eps(model, params, batch, w, precomputed=precomputed)
         z, aux = update(z, t, outs, k_step, aux)
         return (z, key, aux), None
 
@@ -360,11 +355,12 @@ def make_sampler(model, schedule: DiffusionSchedule, config: DiffusionConfig,
         key, k_init = jax.random.split(key)
         z0 = jax.random.normal(k_init, z_shape)
         ts = jnp.arange(T - 1, -1, -1)
-        # Cameras are fixed for the whole reverse process: compute the
-        # pose-conditioning path (rays → posenc → per-level convs) ONCE
-        # here instead of every scan step — pure win, identical math.
-        pose_embs = _doubled_pose_embs(model, params, cond)
-        step = partial(body, cond, params, pose_embs)
+        # The conditioning is fixed for the whole reverse process: what
+        # the model can compute of it ONCE (the X-UNet's pose embeddings,
+        # the token denoiser's latent cache of the conditioning frame)
+        # comes through this one seam instead of every scan step.
+        precomputed = model.precompute(params, cond)
+        step = partial(body, cond, params, precomputed)
         carry0 = (z0, key, init_aux(z0))
 
         if not trajectory_every:
@@ -473,6 +469,9 @@ def make_request_sampler(model, schedule: DiffusionSchedule,
     the int8 serving path passes the dequantizer here so weights rest in
     HBM quantized (sample/precision.py).
     """
+    require_family(
+        model.config, "xunet", "sample.ddpm.make_request_sampler",
+        "the per-sample-keyed sampler hoists the X-UNet's pose embeddings by name; it has to call the model's precompute seam as make_sampler does")
     w = config.guidance_weight
     T = schedule.num_timesteps
     use_fused = _resolve_request_fused(config)
@@ -642,6 +641,9 @@ def make_slot_step_fn(model, config: DiffusionConfig, *,
     update math, anomaly mask — is byte-for-byte the uncached body, and
     the two programs produce BIT-identical rows
     (tests/test_cond_cache.py)."""
+    require_family(
+        model.config, "xunet", "sample.ddpm.make_slot_step_fn (the step ring)",
+        "a latent cache per ring slot, written when a request is admitted and read by every step of its rows")
     phi = config.cfg_rescale
     if not 0.0 <= phi <= 1.0:
         raise ValueError(f"cfg_rescale must be in [0, 1], got {phi}")
@@ -811,6 +813,9 @@ def make_bank_step_fn(model, config: DiffusionConfig, k_max: int, *,
     never reads them, so XLA drops the gathers. RNG stream and update
     math are byte-for-byte the uncached body.
     """
+    require_family(
+        model.config, "xunet", "sample.ddpm.make_bank_step_fn (the trajectory ring)",
+        "a latent cache per slot and per frame of the bank, re-made when the step's conditioning frame changes")
     if k_max < 1:
         raise ValueError(
             f"make_bank_step_fn: k_max={k_max} must be >= 1 (a bank-less "
@@ -1037,6 +1042,9 @@ def make_stochastic_sampler(model, schedule: DiffusionSchedule,
     ~512 MB (e.g. 256px paper-scale pools) and falls back to in-loop
     computation.
     """
+    require_family(
+        model.config, "xunet", "sample.ddpm.make_stochastic_sampler",
+        "a latent cache per pool view, gathered by the step's drawn view index")
     w = config.guidance_weight
     # memoryless: the conditioning view is re-drawn every denoise step, so
     # multistep solver history is invalid here (see _make_update).

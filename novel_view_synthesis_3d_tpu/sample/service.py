@@ -590,6 +590,12 @@ class SamplingService:
                  mesh=None, results_folder: Optional[str] = None,
                  start: bool = True, tracer=None, flight=None,
                  profiler=None, model_version: str = ""):
+        from novel_view_synthesis_3d_tpu.models import require_family
+
+        require_family(
+            model.config, "xunet", "sample.service.SamplingService",
+            "samplers and ring step functions that take the model's "
+            "precompute seam (a latent cache per request or ring slot)")
         self.model = model
         self.diffusion = diffusion
         self.serve = serve or ServeConfig()

@@ -64,9 +64,9 @@ def _build_synthetic(cfg):
 
     from novel_view_synthesis_3d_tpu.data.synthetic import (
         make_example_batch)
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
 
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     batch = make_example_batch(
         batch_size=8, sidelength=cfg.data.img_sidelength, seed=0)
     mb = {

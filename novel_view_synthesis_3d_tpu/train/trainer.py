@@ -33,7 +33,7 @@ from novel_view_synthesis_3d_tpu.diffusion.schedules import (
     make_schedule,
     sampling_schedule,
 )
-from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+from novel_view_synthesis_3d_tpu.models import build_denoiser, require_family
 from novel_view_synthesis_3d_tpu.parallel import dist, mesh as mesh_lib
 from novel_view_synthesis_3d_tpu.parallel import pipeline as pipeline_lib
 from novel_view_synthesis_3d_tpu.sample.ddpm import make_sampler
@@ -199,6 +199,11 @@ class Trainer:
                 "train.results_folder": results_folder,
             })
         self.config = config.validate()
+        require_family(
+            config.model, "xunet", "train.Trainer",
+            "a router balance loss, expert gradients through the grouped "
+            "product and the optimizer's share of an expert-parallel layer "
+            "(train/step.py)")
         tcfg = config.train
 
         dist.initialize_distributed()
@@ -336,7 +341,7 @@ class Trainer:
         if config.train.remat != "":
             import dataclasses as _dc
             model_cfg = _dc.replace(model_cfg, remat=config.train.remat)
-        self.model = XUNet(model_cfg, mesh=self.mesh)
+        self.model = build_denoiser(model_cfg, mesh=self.mesh)
         first_batch = next(self.data_iter)
         self._held_batch = first_batch
         self._device_batch = None  # staged batch for the NEXT dispatch
@@ -1463,14 +1468,14 @@ class Trainer:
         self.metrics.log_eval(step, logged)
         return logged
 
-    def _probe_model(self) -> XUNet:
+    def _probe_model(self):
         """The model the in-loop probes run: dense (non-sequence-parallel)
         attention — identical math and identical params, but free of the
         batch/'data'-axis divisibility constraint the ring path imposes (a
         4-view probe need not divide the mesh)."""
         if self.config.model.sequence_parallel:
             import dataclasses
-            return XUNet(dataclasses.replace(
+            return build_denoiser(dataclasses.replace(
                 self.config.model, sequence_parallel=False))
         return self.model
 

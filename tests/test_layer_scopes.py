@@ -23,7 +23,7 @@ from novel_view_synthesis_3d_tpu.config import DiffusionConfig, ModelConfig
 from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
 from novel_view_synthesis_3d_tpu.diffusion.schedules import sampling_schedule
 from novel_view_synthesis_3d_tpu.models.xunet import (
-    LAYER_KINDS, XUNet, layer_of, op_groups)
+    XUNET_LAYER_KINDS as LAYER_KINDS, XUNet, layer_of, op_groups)
 from novel_view_synthesis_3d_tpu.sample.ddpm import make_sampler
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

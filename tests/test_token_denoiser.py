@@ -1,0 +1,494 @@
+"""The token denoiser (models/token_denoiser.py) against the benchmark's
+plain reference (benchmarks/reference/ms4_ref.py) at a small size on the
+CPU, in float32 on both sides: hidden 64, 2 layers, 8 experts top-2, 4
+held (32 experts in the share test). Weights are the benchmark's seeded
+ones (benchmarks/token_weights.py), so no output adapter is zero.
+
+Tolerances. Both sides compute in float32 here and differ by the order of
+their sums (a grouped product over sorted rows against a dense masked
+loop; a cached prefix against one masked softmax): TOL = 2e-5 relative
+rms is ~50× the 4e-7 they read, and the same reference with its matmul
+inputs rounded to bfloat16 reads ~1e-2, so a lower precision in the
+reference's place fails every one of these (asserted once, below).
+"""
+
+import dataclasses
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for _p in (ROOT, os.path.join(ROOT, "benchmarks")):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import harness  # noqa: E402
+import synth_data  # noqa: E402
+import token_check  # noqa: E402
+import token_weights  # noqa: E402
+from novel_view_synthesis_3d_tpu.config import get_preset  # noqa: E402
+from novel_view_synthesis_3d_tpu.diffusion.schedules import (  # noqa: E402
+    sampling_schedule)
+from novel_view_synthesis_3d_tpu.models import (  # noqa: E402
+    build_denoiser, token_denoiser)
+from novel_view_synthesis_3d_tpu.models.vocab import (  # noqa: E402
+    TOKEN_LAYER_KINDS, layer_of)
+from novel_view_synthesis_3d_tpu.sample.ddpm import make_sampler  # noqa: E402
+
+TOL = 2e-5
+SIDE = 16
+SMALL = {
+    "model.tokens.hidden_size": 64, "model.tokens.num_hidden_layers": 2,
+    "model.tokens.num_attention_heads": 4, "model.tokens.q_lora_rank": 32,
+    "model.tokens.kv_lora_rank": 16, "model.tokens.qk_nope_head_dim": 8,
+    "model.tokens.qk_rope_head_dim": 8, "model.tokens.v_head_dim": 16,
+    "model.tokens.n_routed_experts": 8,
+    "model.tokens.num_experts_per_tok": 2,
+    "model.tokens.moe_intermediate_size": 32,
+    "model.tokens.held_experts": [0, 4], "data.img_sidelength": SIDE,
+    "model.dtype": "float32", "model.param_dtype": "float32",
+    "diffusion.sample_timesteps": 4,
+}
+ref = harness.load_module(os.path.join(
+    ROOT, "benchmarks", "reference", "ms4_ref.py"), "ms4_ref")
+
+
+def small_cfg(**over):
+    return get_preset("ms4_denoiser128").override(
+        **dict(SMALL, **over)).validate()
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+
+
+def seeded(cfg, seed=5):
+    model = build_denoiser(cfg.model)
+    shapes = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}))["params"]
+    return model, token_weights.make_weights(seed, shapes)
+
+
+def doubled_batch(seed=3, rows=2):
+    """One view twice (conditional row, unconditional row)."""
+    cond = {k: jnp.asarray(np.repeat(v, rows, axis=0))
+            for k, v in synth_data.cond_views(1, SIDE, seed).items()}
+    key = jax.random.PRNGKey(seed)
+    z = jnp.repeat(jax.random.normal(key, (1, SIDE, SIDE, 3)), rows, axis=0)
+    return dict(cond, z=z, logsnr=jnp.full((rows,), 0.7)), \
+        jnp.asarray([1.0, 0.0] * (rows // 2))
+
+
+@pytest.fixture(scope="module")
+def small():
+    cfg = small_cfg()
+    model, params = seeded(cfg)
+    batch, mask = doubled_batch()
+    return cfg, model, params, batch, mask, token_check.model_sizes(cfg)
+
+
+def test_full_forward_matches_the_reference(small):
+    cfg, model, params, batch, mask, m = small
+    eps = model.apply({"params": params}, batch, cond_mask=mask, train=False)
+    want = ref.forward(params, m, batch, mask)
+    assert eps.shape == (2, SIDE, SIDE, 3) and eps.dtype == jnp.float32
+    assert rel(eps, want) < TOL
+    # the two guidance rows differ (the ray term is masked in one)
+    assert rel(eps[0], eps[1]) > 1e-2
+
+
+def test_a_lower_precision_in_the_references_place_fails(small):
+    cfg, model, params, batch, mask, m = small
+    want = ref.forward(params, m, batch, mask)
+    assert rel(ref.forward(params, m, batch, mask, "bf16"), want) > 100 * TOL
+    assert rel(ref.forward(params, m, batch, mask, "fp8"), want) > 1000 * TOL
+
+
+def test_precompute_then_step_matches_the_full_forward(small):
+    """Prefill of the conditioning frame into the latent cache, then the
+    target's tokens alone against [cache ; own], is the reference's ONE
+    masked forward over both frames."""
+    cfg, model, params, batch, mask, m = small
+    cond = {k: v[:1] for k, v in batch.items() if k not in ("z", "logsnr")}
+    pre = model.precompute(params, cond)
+    k = cfg.model.tokens
+    assert len(pre["latent_cache"]) == k.num_hidden_layers
+    c_kv, k_rope = pre["latent_cache"][0]
+    L = (SIDE // k.patch_size) ** 2
+    assert c_kv.shape == (2, L, k.kv_lora_rank)
+    assert k_rope.shape == (2, L, k.qk_rope_head_dim)
+    eps = model.apply({"params": params}, dict(batch, **pre), cond_mask=mask,
+                      train=False)
+    assert rel(eps, ref.forward(params, m, batch, mask)) < TOL
+    # and a second step with another state reuses the same cache
+    batch2 = dict(batch, z=batch["z"] * 0.5 + 0.1,
+                  logsnr=jnp.full((2,), -2.0))
+    eps2 = model.apply({"params": params}, dict(batch2, **pre),
+                       cond_mask=mask, train=False)
+    assert rel(eps2, ref.forward(params, m, batch2, mask)) < TOL
+
+
+def test_shipped_attention_form_against_the_other(small):
+    """The program up-projects keys and values from the latent; the
+    reference also writes the absorbed form. All three agree."""
+    cfg, model, params, batch, mask, m = small
+    eps = model.apply({"params": params}, batch, cond_mask=mask, train=False)
+    absorbed = ref.forward(params, m, batch, mask, "f32", "absorbed")
+    assert rel(eps, absorbed) < TOL
+    assert rel(ref.forward(params, m, batch, mask), absorbed) < TOL
+
+
+def test_guided_eps_through_make_sampler(small):
+    """Every step of `make_sampler(trajectory_every=1)`: the program's
+    guided ε̂, read back from the returned states by inverting the update,
+    against the reference's guided ε̂ from the program's input state."""
+    cfg, model, params, _, _, m = small
+    n, views = cfg.diffusion.sample_timesteps, 2
+    sampler = make_sampler(model, sampling_schedule(cfg.diffusion, n),
+                           cfg.diffusion, trajectory_every=1)
+    cond = {k: jnp.asarray(v) for k, v in synth_data.cond_views(
+        views, SIDE, 9).items()}
+    key = jax.random.PRNGKey(4)
+    final, traj = sampler(params, key, cond)
+    assert float(jnp.max(jnp.abs(final - traj[-1]))) == 0.0
+    tables = harness.load_module(os.path.join(
+        ROOT, "benchmarks", "reference", "xunet_ref.py"), "xunet_ref")
+    T, w = cfg.diffusion.timesteps, cfg.diffusion.guidance_weight
+    tab = tables.cosine_tables(T, n)
+    sample = {"traj": np.asarray(traj[:, 1]), "key": key, "row": 1,
+              "cond": {k: np.asarray(a[1]) for k, a in cond.items()},
+              "draw_shape": (views, SIDE, SIDE, 3)}
+    steps = list(range(n))
+    batch, mask, z_ins, noises = token_check.step_inputs(
+        tables, tab, T, sample, steps)
+    eps = np.asarray(ref.forward(params, m, batch, mask), np.float64)
+    got = {"eps": {"f32": eps}, "layer_margin": np.full(
+        (1, 2 * n, (SIDE // m["patch_size"]) ** 2), np.inf)}
+    rows = token_check.step_rows(m, tab, w, sample, steps, z_ins, noises,
+                                 got, 0.0)
+    assert sum(r["pixels"] for r in rows) > 100
+    # (1 + w) amplifies the rows' 4e-7 by up to 7, the inversion divides
+    # by c1: still float32 rounding, three orders under bfloat16's.
+    assert token_check.sampling_check.pooled(rows, "program") < 10 * TOL
+
+
+# ---------------------------------------------------------------------------
+# Rotary embedding against hand values
+# ---------------------------------------------------------------------------
+def test_rope_interleaved_pairs_by_hand():
+    k = small_cfg().model.tokens
+    x = jnp.asarray([[[1.0, 0.0, 0.0, 2.0, 3.0, 4.0, 0.5, -1.0]]])
+    cos, sin, _ = token_denoiser.rope_tables(np.array([3]), k)
+    out = np.asarray(token_denoiser.apply_rope(x, cos, sin, True))[0, 0]
+    inv = token_denoiser.yarn_inv_freq(k.rope_parameters, 8)
+    for i in range(4):
+        a, b = float(x[0, 0, 2 * i]), float(x[0, 0, 2 * i + 1])
+        c, s = math.cos(3 * inv[i]), math.sin(3 * inv[i])
+        assert out[2 * i] == pytest.approx(a * c - b * s, abs=1e-6)
+        assert out[2 * i + 1] == pytest.approx(b * c + a * s, abs=1e-6)
+    # the half-split form pairs (i, i + dim/2) instead
+    half = np.asarray(token_denoiser.apply_rope(x, cos, sin, False))[0, 0]
+    c, s = math.cos(3 * inv[0]), math.sin(3 * inv[0])
+    assert half[0] == pytest.approx(1.0 * c - 3.0 * s, abs=1e-6)
+    assert half[4] == pytest.approx(3.0 * c + 1.0 * s, abs=1e-6)
+
+
+def test_yarn_blend_at_the_published_sizes_by_hand():
+    """dim 64, θ 10000, factor 128, original length 8192, β 32/1: the
+    correction dimensions are ⌊64·ln(8192/(32·2π))/(2·ln 10000)⌋ = 12 and
+    ⌈64·ln(8192/(2π))/(2·ln 10000)⌉ = 25. Pairs below 12 keep θ^(−2i/64),
+    pairs from 25 on are ÷ 128, between them the linear ramp."""
+    rope = get_preset("ms4_denoiser128").model.tokens.rope_parameters
+    inv = token_denoiser.yarn_inv_freq(rope, 64)
+    base = 10000.0 ** (-np.arange(32) / 32.0)
+    assert math.floor(64 * math.log(8192 / (32 * 2 * math.pi))
+                      / (2 * math.log(10000))) == 12
+    assert math.ceil(64 * math.log(8192 / (2 * math.pi))
+                     / (2 * math.log(10000))) == 25
+    np.testing.assert_allclose(inv[:13], base[:13], rtol=1e-12)
+    np.testing.assert_allclose(inv[25:], base[25:] / 128, rtol=1e-12)
+    r = (18 - 12) / (25 - 12)
+    assert inv[18] == pytest.approx(base[18] * (1 - r) + base[18] / 128 * r,
+                                    rel=1e-12)
+    np.testing.assert_allclose(ref.yarn_inv_freq(
+        dataclasses.asdict(rope), 64), inv, rtol=1e-12)
+
+
+def test_query_position_scale_past_the_original_length():
+    k = get_preset("ms4_denoiser128").model.tokens
+    _, _, scale = token_denoiser.rope_tables(
+        np.array([0, 2047, 8191, 8192, 20000]), k)
+    want = [1.0, 1.0, 1.0, 1 + 0.1 * math.log(2), 1 + 0.1 * math.log(3)]
+    np.testing.assert_allclose(scale, want, rtol=1e-6)
+    m = {"rope_parameters": dataclasses.asdict(k.rope_parameters)}
+    np.testing.assert_allclose(ref.query_position_scale(
+        np.array([0, 2047, 8191, 8192, 20000]), m), want, rtol=1e-12)
+    assert token_denoiser.softmax_scale(k) == pytest.approx(
+        128 ** -0.5 * (0.1 * math.log(128) + 1) ** 2)
+
+
+def test_a_sequence_past_8192_scales_its_queries():
+    """With the original length shrunk to 8 the second frame's tokens lie
+    past it: program and reference still agree, and the scale matters."""
+    over = {"model.tokens.rope_parameters": dict(dataclasses.asdict(
+        small_cfg().model.tokens.rope_parameters),
+        original_max_position_embeddings=8, factor=4.0)}
+    cfg = small_cfg(**over)
+    model, params = seeded(cfg)
+    batch, mask = doubled_batch()
+    m = token_check.model_sizes(cfg)
+    eps = model.apply({"params": params}, batch, cond_mask=mask, train=False)
+    assert rel(eps, ref.forward(params, m, batch, mask)) < TOL
+    flat = dict(m, rope_parameters=dict(m["rope_parameters"],
+                                        llama_4_scaling_beta=0.0))
+    assert rel(eps, ref.forward(params, flat, batch, mask)) > 100 * TOL
+
+
+# ---------------------------------------------------------------------------
+# The expert layer is told which experts it holds
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def layer32():
+    """One layer with 32 routed experts, all weights held, and a token
+    block: what the four shares of 8 divide."""
+    cfg = small_cfg(**{"model.tokens.n_routed_experts": 32,
+                       "model.tokens.num_experts_per_tok": 4,
+                       "model.tokens.held_experts": [0, 32],
+                       "model.tokens.num_hidden_layers": 1})
+    model, params = seeded(cfg, seed=8)
+    h = jax.random.normal(jax.random.PRNGKey(2), (2, 32, 64))
+    return cfg, params["layer_0"], h, token_check.model_sizes(cfg)
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer(layer32):
+    """Routed parts of experts 0–7, 8–15, 16–23, 24–31, each from a layer
+    that holds only those, plus the shared expert and attention counted
+    once, are the uncut reference layer's output."""
+    cfg, p, h, m = layer32
+    k = cfg.model.tokens
+    want, aux = ref.layer(p, m, h, parts=True)
+    b32 = token_denoiser.rms_norm(h + aux["attn"], p["mlp_norm"]["scale"],
+                                  k.rms_norm_eps).reshape(-1, 64)
+    top_p, top_i = token_denoiser.route(b32, p["router"], k)
+    total, counted = 0.0, 0
+    for first in (0, 8, 16, 24):
+        share = dataclasses.replace(k, held_experts=(first, 8))
+        held = jax.tree.map(lambda a: a[first:first + 8], p["experts"])
+        part, counts = token_denoiser.held_expert_part(
+            b32, top_p, top_i, held, share)
+        # the same share, written plainly
+        plain, _ = ref.experts_part(
+            held, dict(m, held_experts=[first, 8]), b32, top_p, top_i, "f32")
+        assert rel(part, plain) < TOL
+        assert 0 < float(jnp.max(jnp.abs(part)))
+        total = total + part
+        counted += int(counts.sum())
+    assert counted == b32.shape[0] * k.num_experts_per_tok  # every choice
+    assert rel(total.reshape(h.shape), aux["routed"]) < TOL
+    out = h + aux["attn"] + aux["shared"] + total.reshape(h.shape)
+    assert rel(out, want) < TOL
+
+
+def test_every_token_on_one_held_expert_loses_none(layer32):
+    """A router driven so that every token's first choice is held expert 3
+    (of experts 0–7 held): that expert is given every token, nothing is
+    dropped, and the output is the reference's."""
+    cfg, p, h, m = layer32
+    k = dataclasses.replace(cfg.model.tokens, held_experts=(0, 8))
+    b32 = token_denoiser.rms_norm(h, p["mlp_norm"]["scale"],
+                                  k.rms_norm_eps).reshape(-1, 64)
+    # a constant feature whose router row is +50 on expert 3's logit
+    b_aug = jnp.concatenate([b32, jnp.ones((b32.shape[0], 1))], axis=1)
+    kernel = jnp.concatenate(
+        [p["router"]["kernel"], 50.0 * jax.nn.one_hot(3, 32)[None]], axis=0)
+    top_p, top_i = token_denoiser.route(b_aug, {"kernel": kernel}, k)
+    assert bool(jnp.all(top_i[:, 0] == 3))
+    held = jax.tree.map(lambda a: a[:8], p["experts"])
+    part, counts = token_denoiser.held_expert_part(b32, top_p, top_i, held, k)
+    assert int(counts[3]) == b32.shape[0]
+    assert int(counts.sum()) == int(jnp.sum(top_i < 8))
+    plain, ref_counts = ref.experts_part(
+        held, dict(m, held_experts=[0, 8]), b32, top_p, top_i, "f32")
+    assert rel(part, plain) < TOL
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(ref_counts))
+
+
+def test_routing_counts_are_the_programs_own(small):
+    cfg, model, params, batch, mask, m = small
+    counts = np.asarray(model.routing_counts(params, batch, mask))
+    k = cfg.model.tokens
+    assert counts.shape == (k.num_hidden_layers, k.held_experts[1])
+    _, auxes = ref.forward(params, m, batch, mask, aux=True)
+    L = (SIDE // k.patch_size) ** 2
+    for layer, aux in zip(counts, auxes):
+        # the program counts its pass over the target's tokens
+        assert int(layer.sum()) == int(np.asarray(
+            aux["held_hits"])[:, L:].sum())
+
+
+@pytest.mark.parametrize("rows, sizes", [
+    (64, [5, 0, 30, 13]),        # one row tile, an empty group, a tail
+    (640, [300, 0, 7, 249]),     # groups that straddle the 256-row tiles
+    (512, [0, 0, 0, 0]),         # nothing held: the product does no work
+])
+def test_grouped_matmul_is_the_shipped_kernel(rows, sizes):
+    """ops/grouped_matmul.py through the Pallas interpreter (the kernel the
+    chip compiles, with its tile clamp) against a product per group; the
+    rows past the last group are unspecified and not compared."""
+    from novel_view_synthesis_3d_tpu.ops.grouped_matmul import grouped_matmul
+
+    k1, k2 = jax.random.split(jax.random.PRNGKey(rows))
+    lhs = jax.random.normal(k1, (rows, 48), jnp.float32)
+    rhs = jax.random.normal(k2, (len(sizes), 48, 40), jnp.float32)
+    got = np.asarray(grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32)))
+    start = 0
+    for g, n in enumerate(sizes):
+        if n:
+            assert rel(got[start:start + n],
+                       np.asarray(lhs[start:start + n]) @ np.asarray(rhs[g])
+                       ) < TOL
+        start += n
+    assert got.shape == (rows, 40)
+
+
+# ---------------------------------------------------------------------------
+# Scopes
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("path, want", [
+    ("jit(sample)/lk.update/while/body/closed_call/og.layer_1/lk.moe_experts"
+     "/gmm", ("layer_1", "moe_experts")),
+    ("jit(sample)/lk.update/precompute/og.layer_0/lk.mla_core/dot_general",
+     ("layer_0", "mla_core")),
+    ("jit(sample)/lk.update/precompute/og.prelude/lk.pose/lk.patch/sin",
+     ("prelude", "pose")),
+    ("jit(sample)/lk.update/while/body/closed_call/og.final/lk.patch/dot",
+     ("final", "patch")),
+    ("jit(sample)/lk.update/while/body/closed_call/og.layer_3/add",
+     ("layer_3", "other")),
+    ("jit(sample)/lk.update/while/body/mul", ("", "update")),
+])
+def test_layer_of_reads_the_trunks_paths(path, want):
+    assert layer_of(path) == want
+
+
+def test_compiled_sampler_stamps_are_the_token_vocabulary(small):
+    import re
+
+    cfg, model, params, _, _, _ = small
+    sampler = make_sampler(model, sampling_schedule(cfg.diffusion, 4),
+                           cfg.diffusion, trajectory_every=1)
+    cond = {k: jnp.asarray(v) for k, v in synth_data.cond_views(
+        2, SIDE, 1).items()}
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        text = sampler.lower(params, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                             cond).compile().as_text()
+    finally:
+        jax.config.update(flag, before)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    stamps = {s for p in paths for s in re.split(r"[/();]", p)
+              if s.startswith("lk.")}
+    assert stamps == {"lk." + k for k in TOKEN_LAYER_KINDS}
+    labels = {label for label, _ in token_denoiser.op_groups(cfg.model)}
+    blocks = {layer_of(p)[0] for p in paths} - {""}
+    assert blocks == labels
+    assert any("/precompute/" in p for p in paths)
+    top = set(params)
+    assert {n for _, names in token_denoiser.op_groups(cfg.model)
+            for n in names} == top
+
+
+# ---------------------------------------------------------------------------
+# Configuration
+# ---------------------------------------------------------------------------
+def test_preset_is_the_published_config_cut_as_the_file_says():
+    import json
+
+    cfg = get_preset("ms4_denoiser128").validate()
+    k = cfg.model.tokens
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "ms4_denoiser128.json")) as fh:
+        doc = json.load(fh)
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    for name, value in doc.items():
+        if hasattr(k, name) and name not in ("rope_parameters",
+                                             "n_routed_experts"):
+            assert getattr(k, name) == value, name
+    rope = dataclasses.asdict(k.rope_parameters)
+    assert {n: doc["rope_parameters"][n] for n in rope} == rope
+    # the router keeps the published width; the file counts what is held
+    assert k.n_routed_experts == doc["published"]["n_routed_experts"] == 128
+    assert k.held_experts == (0, doc["n_routed_experts"]) == (0, 32)
+    assert k.num_hidden_layers == 6 and k.num_experts_per_tok == 4
+    assert cfg.model.dtype == cfg.model.param_dtype == "bfloat16"
+    assert k.qk_head_dim == doc["qk_head_dim"] == doc["head_dim"]
+    if os.path.exists(catalog):
+        with open(catalog) as fh:
+            rows = [json.loads(line) for line in fh]
+        row = next(r for r in rows if r["name"] == "Mistral-Small-4-119B-2603")
+        assert doc["source"] == row["source_url"]
+        for name, value in row["config"].items():
+            if name not in doc["reduced"]:
+                assert doc[name] == value, name
+    # 2 bytes a parameter: the arithmetic of the cut
+    shapes = token_denoiser.param_shapes(cfg.model)
+    layer = sum(math.prod(s.shape) for s in jax.tree.leaves(
+        shapes["layer_0"]))
+    assert 855e6 < layer < 865e6            # 859 M: 1.72 GB in bfloat16
+
+
+def test_config_round_trip_and_refusals():
+    from novel_view_synthesis_3d_tpu.config import Config
+
+    cfg = small_cfg()
+    assert Config.from_json(cfg.to_json()) == cfg
+    assert get_preset("paper256").model.tokens is None
+    for over, word in [
+        ({"model.tokens.held_experts": [6, 4]}, "held_experts"),
+        ({"data.img_sidelength": 18}, "patch_size"),
+        ({"model.num_cond_frames": 2}, "conditioning"),
+        ({"model.family": "unet"}, "family"),
+    ]:
+        with pytest.raises(ValueError, match=word):
+            small_cfg(**over)
+    with pytest.raises(ValueError, match="tokens"):
+        get_preset("tiny64").override(**{"model.family": "tokens"}).validate()
+
+
+def test_replicated_router_gives_each_share_one_assignment_a_token():
+    """The cell's seeded router (benchmarks/token_weights.py,
+    `router_replicas`): 32 columns as 8 prototypes at 4 experts each. A
+    token's top-4 are the replicas of its best prototype, one in each
+    share of 8, so the held share is given exactly one assignment a
+    token in every layer — and program and reference still agree."""
+    cfg = small_cfg(**{"model.tokens.n_routed_experts": 32,
+                       "model.tokens.num_experts_per_tok": 4,
+                       "model.tokens.held_experts": [8, 8]})
+    model = build_denoiser(cfg.model)
+    shapes = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}))["params"]
+    params = token_weights.make_weights(6, shapes, router_replicas=4)
+    kernel = np.asarray(params["layer_0"]["router"]["kernel"])
+    for j in range(1, 4):
+        np.testing.assert_array_equal(kernel[:, :8], kernel[:, 8 * j:8 * j + 8])
+    one = token_weights.make_group(6, shapes, "layer_1", router_replicas=4)
+    np.testing.assert_array_equal(
+        np.asarray(one["router"]["kernel"]),
+        np.asarray(params["layer_1"]["router"]["kernel"]))
+    batch, mask = doubled_batch()
+    counts = np.asarray(model.routing_counts(params, batch, mask))
+    tokens = 2 * (SIDE // cfg.model.tokens.patch_size) ** 2
+    assert counts.sum(axis=1).tolist() == [tokens] * 2
+    m = token_check.model_sizes(cfg)
+    eps = model.apply({"params": params}, batch, cond_mask=mask, train=False)
+    assert rel(eps, ref.forward(params, m, batch, mask)) < TOL
+    with pytest.raises(ValueError, match="replicas"):
+        token_weights.make_weights(6, shapes, router_replicas=5)

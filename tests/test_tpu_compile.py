@@ -30,6 +30,7 @@ from novel_view_synthesis_3d_tpu.ops import (
     fused_epilogue,
     fused_groupnorm,
     fused_step,
+    grouped_matmul,
     serving_attention,
 )
 
@@ -93,6 +94,22 @@ def _step(sampler, B, px):
         [img] * 4 + [((B, 11), F32), ((B,), F32)])
 
 
+def _rect_attn(Lq, Lk, heads, hd):
+    """The token trunk's core: one frame's queries against two frames of
+    keys, no mask (models/token_denoiser.py)."""
+    return (lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, scale=0.2),
+        [((2, Lq, heads, hd), BF16)] + [((2, Lk, heads, hd), BF16)] * 2)
+
+
+def _grouped(rows, experts, k, n):
+    """The expert layer's grouped product at the published widths: a
+    static row count for the worst case, 32 held experts' stacked weights."""
+    return (grouped_matmul.grouped_matmul,
+            [((rows, k), BF16), ((experts, k, n), BF16),
+             ((experts,), jnp.int32)])
+
+
 # base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
 # head dim 256. GroupNorm and epilogue cases are UNet level slabs (H·W, C)
 # that `fits_vmem` admits, the largest included.
@@ -101,6 +118,9 @@ CASES = {
        _attn(flash_attention.flash_attention, L, hd, g)
        for g in (False, True)
        for L, hd in ((1024, 64), (256, 128), (1024, 256))},
+    "flash_fwd_Lq1024_Lk2048_d128": _rect_attn(1024, 2048, 32, 128),
+    "grouped_matmul_up_4096x2048": _grouped(32768, 32, 4096, 2048),
+    "grouped_matmul_down_2048x4096": _grouped(32768, 32, 2048, 4096),
     **{f"serving_attention_L{L}_d{hd}":
        _attn(serving_attention.serving_attention, L, hd, False)
        for L, hd in ((1024, 64), (1024, 256))},

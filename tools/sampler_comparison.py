@@ -77,7 +77,7 @@ def main() -> int:
     from novel_view_synthesis_3d_tpu.config import Config, get_preset
     from novel_view_synthesis_3d_tpu.data.srn import SRNDataset
     from novel_view_synthesis_3d_tpu.eval.evaluate import evaluate_dataset
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.train.checkpoint import CheckpointManager
     from novel_view_synthesis_3d_tpu.train.state import create_train_state
     from novel_view_synthesis_3d_tpu.train.trainer import _sample_model_batch
@@ -98,7 +98,7 @@ def main() -> int:
     cfg.validate()
 
     ds = SRNDataset(args.folder, img_sidelength=cfg.data.img_sidelength)
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     rec = ds.pair(0, np.random.default_rng(0))
     template = create_train_state(
         cfg.train, model, _sample_model_batch({k: v[None]

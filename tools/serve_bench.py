@@ -98,7 +98,7 @@ def get_default_timesteps(preset: str) -> int:
 def build(preset: str, sidelength: int, steps: int, extra_overrides=()):
     from novel_view_synthesis_3d_tpu.config import get_preset
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
 
     cfg = get_preset(preset).override(**{
         "data.img_sidelength": sidelength,
@@ -107,7 +107,7 @@ def build(preset: str, sidelength: int, steps: int, extra_overrides=()):
     if extra_overrides:
         cfg = cfg.override(**dict(extra_overrides))
     cfg = cfg.validate()
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     batch = make_example_batch(batch_size=8, sidelength=sidelength, seed=0)
     mb = {
         "x": jnp.asarray(batch["x"]), "z": jnp.asarray(batch["target"]),
@@ -948,14 +948,14 @@ def _attention_coverage_probe(cfg, sidelength: int) -> dict:
     import dataclasses as _dc
 
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.ops.serving_attention import (
         attention_coverage, reset_attention_coverage)
 
     bottleneck = sidelength // (2 ** (len(cfg.model.ch_mult) - 1))
     mcfg = _dc.replace(cfg.model, attn_resolutions=(bottleneck,),
                        use_serving_attention=True)
-    model = XUNet(mcfg)
+    model = build_denoiser(mcfg)
     raw = make_example_batch(batch_size=2, sidelength=sidelength, seed=1)
     mb = {
         "x": jnp.asarray(raw["x"]), "z": jnp.asarray(raw["target"]),

@@ -52,7 +52,7 @@ def main() -> None:
     from novel_view_synthesis_3d_tpu.config import get_preset
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
     from novel_view_synthesis_3d_tpu.diffusion import make_schedule
-    from novel_view_synthesis_3d_tpu.models.xunet import XUNet
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.parallel import mesh as mesh_lib
     from novel_view_synthesis_3d_tpu.train.state import create_train_state
     from novel_view_synthesis_3d_tpu.train.step import make_train_step
@@ -71,7 +71,7 @@ def main() -> None:
     mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(data=1, model=1, seq=1),
                               devices=jax.devices()[:1])
     schedule = make_schedule(cfg.diffusion)
-    model = XUNet(cfg.model)
+    model = build_denoiser(cfg.model)
     batch = make_example_batch(batch_size=cfg.train.batch_size,
                                sidelength=16, seed=0)
     state = create_train_state(cfg.train, model, _sample_model_batch(batch))
