@@ -31,9 +31,12 @@ cache does exactly the two in a row, so there is one set of equations.
 (first, count) range: this chip's share of an expert-parallel deployment).
 It routes over all experts, computes the part of the result its own give
 and nothing of the others'. Assignments to held experts are sorted by
-expert and multiplied as ONE grouped product over stacked weights
-(ops/grouped_matmul.py); there is no capacity and no token is dropped,
-whatever the imbalance. On one chip it runs without its exchange.
+expert, each expert's rows laid into a span of whole row tiles (pad rows
+at the span's head, live rows at its end, the spans end to end in a static
+buffer sized for every assignment landing here) and multiplied as ONE
+grouped product over stacked weights (ops/grouped_matmul.py, whose
+contract the aligned spans are); there is no capacity and no token is
+dropped, whatever the imbalance. On one chip it runs without its exchange.
 
 Not a flax module: parameters are a plain nested dict, `init`/`apply`
 keep flax's calling convention so that every caller of the X-UNet's can
@@ -52,7 +55,8 @@ from novel_view_synthesis_3d_tpu.config import ModelConfig, TokenTrunkConfig
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
     flash_attention, resolve_flash)
-from novel_view_synthesis_3d_tpu.ops.grouped_matmul import grouped_matmul
+from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
+    ROW_TILE, buffer_rows, grouped_matmul, span_sizes)
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
 
 LOGSNR_CLEAN = 20.0   # the conditioning frame's logsnr: 3DiM's clean frame
@@ -236,33 +240,51 @@ def route(b32, p_router, k: TokenTrunkConfig):
 
 def held_expert_part(b, top_p, top_i, p_experts, k: TokenTrunkConfig):
     """Σ over a token's top-k experts THAT ARE HELD HERE of p_e·expert_e(b),
-    and the tokens each held expert was given (held,) int32.
+    and the tokens each held expert was given (held,) int32 — the true
+    counts, not the spans'.
 
-    The (T·k) assignments are sorted by expert, those to absent experts
-    last; the held ones are one grouped product each for gate, up and
-    down. Every assignment to a held expert is computed (no capacity);
-    assignments to absent experts lie past the last group, where the
-    product does no work."""
+    Each held expert's rows get a span of whole row tiles in a static
+    buffer (ops/grouped_matmul.py's contract: no tile belongs to two
+    experts), the spans end to end, a span's pad rows at its head and its
+    live rows at its end; the span sizes are what the products are handed,
+    one grouped product each for gate, up and down. ONE stable sort lays
+    it out: the (T·k) assignments keyed by expert (absent experts last)
+    behind the buffer's spare rows, of which each held expert's first
+    span − size are keyed to it as pad and the rest to the absent. Every
+    assignment to a held expert has exactly one row (no capacity: the
+    buffer covers all T·k landing here); assignments to absent experts lie
+    past the last span. Pad rows hold token 0's copy, rows past the last
+    span are never written; the combine reads neither."""
     with jax.named_scope("lk.moe_route"):
         first, count = k.held_experts
         T, K = top_i.shape
         local = top_i.reshape(-1) - first
         is_held = (local >= 0) & (local < count)
         slot = jnp.where(is_held, local, count)       # absent: a last group
-        order = jnp.argsort(slot, stable=True)
         group_sizes = jnp.bincount(slot, length=count + 1)[:count].astype(
             jnp.int32)
-        x = jnp.take(b, order // K, axis=0)           # (T·K, H) by expert
+        spans = span_sizes(group_sizes)
+        # The buffer's spare rows: a row tile a group (and T·k's remainder
+        # to a whole tile), a group's as far as its span pads.
+        spare = buffer_rows(T * K, count) - T * K
+        i = jnp.arange(spare)
+        pad_slot = jnp.where(i % ROW_TILE < jnp.repeat(
+            spans - group_sizes, ROW_TILE, total_repeat_length=spare),
+            jnp.minimum(i // ROW_TILE, count), count)
+        # Stable, the spare rows first: a span's pad lies before its rows.
+        order = jnp.argsort(jnp.concatenate([pad_slot, slot]), stable=True)
+        x = jnp.take(b, jnp.maximum(order - spare, 0) // K,
+                     axis=0, mode="clip")             # (rows, H) by expert
     with jax.named_scope("lk.moe_experts"):
-        g = grouped_matmul(x, p_experts["gate"]["kernel"], group_sizes)
-        u = grouped_matmul(x, p_experts["up"]["kernel"], group_sizes)
+        g = grouped_matmul(x, p_experts["gate"]["kernel"], spans)
+        u = grouped_matmul(x, p_experts["up"]["kernel"], spans)
         y = grouped_matmul(jax.nn.silu(g) * u, p_experts["down"]["kernel"],
-                           group_sizes)
+                           spans)
         # Back to token order and summed over a token's choices, one
         # gather per choice (no (token, choice, hidden) relayout). A choice
-        # that is not held has weight 0 and points past the last group,
+        # that is not held has weight 0 and points past the last span,
         # at rows the product never wrote: masked, not multiplied.
-        back = jnp.argsort(order).reshape(T, K)
+        back = jnp.argsort(order)[spare:].reshape(T, K)
         w = jnp.where(is_held.reshape(T, K), top_p, 0.0)
         out = jnp.zeros((T, y.shape[-1]), jnp.float32)
         for c in range(K):

@@ -332,29 +332,222 @@ def test_routing_counts_are_the_programs_own(small):
             aux["held_hits"])[:, L:].sum())
 
 
-@pytest.mark.parametrize("rows, sizes", [
-    (64, [5, 0, 30, 13]),        # one row tile, an empty group, a tail
-    (640, [300, 0, 7, 249]),     # groups that straddle the 256-row tiles
-    (512, [0, 0, 0, 0]),         # nothing held: the product does no work
-])
-def test_grouped_matmul_is_the_shipped_kernel(rows, sizes):
-    """ops/grouped_matmul.py through the Pallas interpreter (the kernel the
-    chip compiles, with its tile clamp) against a product per group; the
-    rows past the last group are unspecified and not compared."""
-    from novel_view_synthesis_3d_tpu.ops.grouped_matmul import grouped_matmul
+# Group sizes against the 128-row tile: what the layout and the product
+# have to get right whatever the router does.
+RAGGED = {
+    "an_empty_group_and_a_tail": [5, 0, 30, 13],
+    "groups_past_one_tile": [300, 0, 7, 249],
+    "nothing_held": [0, 0, 0, 0],
+    "exactly_one_tile": [128, 0, 128, 0],
+    "one_row": [0, 1, 0, 0],
+    "a_row_past_a_tile": [129, 1, 127, 256],
+}
 
-    k1, k2 = jax.random.split(jax.random.PRNGKey(rows))
+
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_grouped_matmul_is_the_shipped_kernel(case):
+    """ops/grouped_matmul.py through the Pallas interpreter (the kernel the
+    chip compiles) against a product per group over its whole span — pad
+    rows are multiplied like any other; the rows past the last span are
+    unspecified and not compared."""
+    from novel_view_synthesis_3d_tpu.ops import grouped_matmul as gm
+
+    sizes = np.asarray(RAGGED[case], np.int32)
+    spans = gm.span_sizes(sizes)
+    assert np.all(spans % gm.ROW_TILE == 0) and np.all(spans >= sizes)
+    assert np.all(spans - sizes < gm.ROW_TILE)
+    assert gm.rows_visited(sizes) == sum(
+        math.ceil(n / gm.ROW_TILE) * gm.ROW_TILE for n in sizes)
+    rows = gm.buffer_rows(int(sizes.sum()), len(sizes))
+    assert rows >= spans.sum()
+    k1, k2 = jax.random.split(jax.random.PRNGKey(int(sizes.sum())))
     lhs = jax.random.normal(k1, (rows, 48), jnp.float32)
     rhs = jax.random.normal(k2, (len(sizes), 48, 40), jnp.float32)
-    got = np.asarray(grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32)))
+    got = np.asarray(gm.grouped_matmul(lhs, rhs, jnp.asarray(spans)))
     start = 0
-    for g, n in enumerate(sizes):
+    for g, n in enumerate(spans):
         if n:
             assert rel(got[start:start + n],
                        np.asarray(lhs[start:start + n]) @ np.asarray(rhs[g])
                        ) < TOL
         start += n
     assert got.shape == (rows, 40)
+
+
+def test_column_blocks_and_the_worst_case_buffer():
+    """The weights' column block adapts to K and N alone (all of N where
+    an expert's block fits a VMEM slot, else the widest divisor that
+    does), and the static buffer holds every way T·k rows can fall into
+    `groups` aligned spans."""
+    from novel_view_synthesis_3d_tpu.ops import grouped_matmul as gm
+
+    assert gm._column_block(4096, 2048, 2) == 2048    # the cell's gate, up
+    assert gm._column_block(2048, 4096, 2) == 4096    # and down
+    assert gm._column_block(8192, 4096, 2) == 1024    # a wider expert
+    assert gm._column_block(48, 40, 4) == 40
+    rng = np.random.default_rng(0)
+    for assignments, groups in ((32768, 32), (100, 3), (128, 1), (1, 4)):
+        rows = gm.buffer_rows(assignments, groups)
+        assert rows % gm.ROW_TILE == 0
+        # every group one row past whole tiles: the most pad there can be
+        worst = np.ones(min(groups, assignments), np.int64)
+        worst[0] += assignments - worst.size
+        assert gm.rows_visited(worst) <= rows
+        for _ in range(20):
+            sizes = rng.multinomial(assignments, rng.dirichlet(
+                np.full(groups, 0.3)))
+            assert gm.rows_visited(sizes) <= rows
+
+
+def _spy_on_the_products(monkeypatch):
+    """Every (lhs, group_sizes) that held_expert_part hands the seam the
+    benchmark's control replaces, the product itself untouched."""
+    calls = []
+    real = token_denoiser.grouped_matmul
+
+    def spy(lhs, rhs, group_sizes):
+        calls.append((np.asarray(lhs), np.asarray(group_sizes)))
+        return real(lhs, rhs, group_sizes)
+
+    monkeypatch.setattr(token_denoiser, "grouped_matmul", spy)
+    return calls
+
+
+def _choices(case, T, K, first, count, n_experts, rng):
+    """top_i (T, K), distinct experts a token, by the case's name."""
+    held = np.arange(first, first + count)
+    absent = np.setdiff1d(np.arange(n_experts), held)
+    if case == "nothing_held":
+        return np.stack([rng.choice(absent, K, replace=False)
+                         for _ in range(T)])
+    if case == "everything_held":
+        return np.stack([rng.choice(held, K, replace=False)
+                         for _ in range(T)])
+    top_i = np.stack([rng.choice(n_experts, K, replace=False)
+                      for _ in range(T)])
+    if case == "independent":
+        return top_i
+    # one held expert given exactly `n` tokens' first choice, the other
+    # held experts nothing
+    n = {"exactly_one_tile": 128, "one_row": 1, "a_row_past_a_tile": 129}[
+        case]
+    top_i = np.stack([rng.choice(absent, K, replace=False)
+                      for _ in range(T)])
+    top_i[rng.choice(T, n, replace=False), 0] = held[1]
+    return top_i
+
+
+@pytest.mark.parametrize("case", [
+    "independent", "nothing_held", "everything_held", "exactly_one_tile",
+    "one_row", "a_row_past_a_tile"])
+def test_layout_gives_every_held_assignment_one_row_in_whole_tiles(
+        layer32, monkeypatch, case):
+    """What held_expert_part hands the grouped product, and what comes
+    back: spans of whole row tiles laid end to end in the worst case's
+    static buffer, a span's live rows at its END holding exactly the
+    tokens assigned to that expert (in assignment order), the returned
+    counts the unpadded ones, and the result the dense per-expert loop's —
+    so the combine read each of those rows and no other. Tokens have 0 to
+    k held choices at unequal gates ("independent"), none, or all."""
+    from novel_view_synthesis_3d_tpu.ops import grouped_matmul as gm
+
+    cfg, p, _, m = layer32
+    first, count, K, T = 8, 5, 4, 160
+    k = dataclasses.replace(cfg.model.tokens, held_experts=(first, count))
+    rng = np.random.default_rng(len(case))
+    top_i = _choices(case, T, K, first, count, 32, rng)
+    top_p = rng.random((T, K)).astype(np.float32) + 0.05
+    top_p /= top_p.sum(-1, keepdims=True)
+    b = jax.random.normal(jax.random.PRNGKey(3), (T, 64))
+    held = jax.tree.map(lambda a: a[first:first + count], p["experts"])
+    calls = _spy_on_the_products(monkeypatch)
+    part, counts = token_denoiser.held_expert_part(
+        b, jnp.asarray(top_p), jnp.asarray(top_i, jnp.int32), held, k)
+
+    is_held = (top_i >= first) & (top_i < first + count)
+    want_counts = np.bincount(top_i[is_held] - first, minlength=count)
+    np.testing.assert_array_equal(np.asarray(counts), want_counts)
+    if case == "independent":
+        assert set(is_held.sum(-1)) >= {0, 1, 2}      # ragged in the token
+    assert len(calls) == 3                            # gate, up, down
+    x, spans = calls[0]
+    for _, again in calls[1:]:
+        np.testing.assert_array_equal(again, spans)
+    np.testing.assert_array_equal(spans, gm.span_sizes(want_counts))
+    assert x.shape[0] == gm.buffer_rows(T * K, count) >= spans.sum()
+    assert gm.rows_visited(want_counts) == spans.sum()
+    flat_expert = top_i.reshape(-1)
+    token_of = np.arange(T * K) // K
+    end = 0
+    for g in range(count):
+        end += spans[g]
+        tokens = token_of[flat_expert == first + g]   # assignment order
+        np.testing.assert_array_equal(
+            x[end - want_counts[g]:end], np.asarray(b)[tokens])
+    plain, ref_counts = ref.experts_part(
+        held, dict(m, held_experts=[first, count]), b, jnp.asarray(top_p),
+        jnp.asarray(top_i), "f32")
+    np.testing.assert_array_equal(np.asarray(ref_counts), want_counts)
+    if is_held.any():
+        assert rel(part, plain) < TOL
+    else:
+        assert float(jnp.max(jnp.abs(part))) == 0.0
+
+
+@pytest.mark.parametrize("which", ["group", "row"])
+def test_the_benchmarks_planted_fault_hits_live_rows(layer32, which):
+    """benchmarks/token_check.rows_lost finds a group's rows by the running
+    sum of the sizes the seam is handed and zeroes the span or its LAST
+    row: with the live rows at a span's end, the tokens of the expert with
+    the longest span lose their part (all of them, or exactly one token)."""
+    from novel_view_synthesis_3d_tpu.ops.grouped_matmul import span_sizes
+
+    cfg, p, _, _ = layer32
+    first, count, K, T = 0, 8, 4, 96
+    k = dataclasses.replace(cfg.model.tokens, held_experts=(first, count))
+    rng = np.random.default_rng(4)
+    top_i = np.stack([rng.choice(32, K, replace=False) for _ in range(T)])
+    top_p = np.full((T, K), 0.25, np.float32)
+    b = jax.random.normal(jax.random.PRNGKey(4), (T, 64))
+    held = jax.tree.map(lambda a: a[:count], p["experts"])
+    args = (b, jnp.asarray(top_p), jnp.asarray(top_i, jnp.int32), held, k)
+    sound, counts = token_denoiser.held_expert_part(*args)
+    with token_check.rows_lost(which):
+        faulty, _ = token_denoiser.held_expert_part(*args)
+    changed = np.flatnonzero(np.any(np.asarray(sound != faulty), axis=-1))
+    fullest = int(np.argmax(span_sizes(np.asarray(counts))))
+    given = np.flatnonzero(np.any(top_i == first + fullest, axis=-1))
+    if which == "group":
+        np.testing.assert_array_equal(changed, given)
+    else:
+        assert len(changed) == 1 and changed[0] == given[-1]
+
+
+@pytest.mark.parametrize("first, count", [(0, 32), (8, 16), (5, 3), (31, 1)])
+def test_held_part_is_the_dense_loop_on_independent_router_columns(
+        layer32, first, count):
+    """The layer's own router (independent columns: tokens with 0–4 held
+    choices, unequal renormalised gates) through the sort, the aligned
+    spans, the three products and the combine, against the reference's
+    loop over the held experts under a dense mask. (0, 32) holds every
+    assignment: the static buffer's worst case."""
+    cfg, p, h, m = layer32
+    k = dataclasses.replace(cfg.model.tokens, held_experts=(first, count))
+    b32 = token_denoiser.rms_norm(h, p["mlp_norm"]["scale"],
+                                  k.rms_norm_eps).reshape(-1, 64)
+    top_p, top_i = token_denoiser.route(b32, p["router"], k)
+    held = jax.tree.map(lambda a: a[first:first + count], p["experts"])
+    part, counts = jax.jit(
+        lambda *a: token_denoiser.held_expert_part(*a, k))(
+            b32, top_p, top_i, held)
+    plain, ref_counts = ref.experts_part(
+        held, dict(m, held_experts=[first, count]), b32, top_p, top_i, "f32")
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(ref_counts))
+    assert int(counts.sum()) == int(jnp.sum(
+        (top_i >= first) & (top_i < first + count)))
+    assert rel(part, plain) < TOL
+    if count == 32:
+        assert int(counts.sum()) == top_i.size
 
 
 # ---------------------------------------------------------------------------

@@ -102,12 +102,15 @@ def _rect_attn(Lq, Lk, heads, hd):
         [((2, Lq, heads, hd), BF16)] + [((2, Lk, heads, hd), BF16)] * 2)
 
 
-def _grouped(rows, experts, k, n):
-    """The expert layer's grouped product at the published widths: a
-    static row count for the worst case, 32 held experts' stacked weights."""
+def _grouped(assignments, experts, k, n):
+    """The expert layer's grouped product at the published widths: the
+    static row count of the worst case (a step's 8192 tokens × top-4, all
+    landing here, each of the 32 held experts' spans with a tile's
+    remainder), the held experts' stacked weights, and the shipped column
+    block — both VMEM slots of a whole expert's (K, N) weights."""
     return (grouped_matmul.grouped_matmul,
-            [((rows, k), BF16), ((experts, k, n), BF16),
-             ((experts,), jnp.int32)])
+            [((grouped_matmul.buffer_rows(assignments, experts), k), BF16),
+             ((experts, k, n), BF16), ((experts,), jnp.int32)])
 
 
 # base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
@@ -155,6 +158,7 @@ KERNEL_NAMES = {
     "fused_groupnorm": "fused_groupnorm_256x512",
     "fused_epilogue": "fused_epilogue_256x512",
     "fused_step": "fused_step_ddpm_B2_128px",
+    "gmm": "grouped_matmul_up_4096x2048",
 }
 
 
