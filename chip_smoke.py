@@ -267,14 +267,14 @@ def check_kernels(seed: int) -> dict:
 def custom_call_counts(cfg) -> dict:
     """`tpu_custom_call` occurrences in the lowered train step and ring
     step, built by the factories the trainer (train/step.make_train_step)
-    and the service (sample/ddpm.make_slot_step_fn) use. Traced on
+    and the service (sample/ddpm.make_ring_step_fn) use. Traced on
     shapes only: nothing is compiled or run."""
     from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
     from novel_view_synthesis_3d_tpu.diffusion import make_schedule
     from novel_view_synthesis_3d_tpu.models import build_denoiser
     from novel_view_synthesis_3d_tpu.parallel import mesh as mesh_lib
     from novel_view_synthesis_3d_tpu.sample.ddpm import (
-        STEP_COEF_KEYS, make_slot_step_fn)
+        STEP_COEF_KEYS, make_ring_step_fn)
     from novel_view_synthesis_3d_tpu.train.state import create_train_state
     from novel_view_synthesis_3d_tpu.train.step import make_train_step
     from novel_view_synthesis_3d_tpu.train.trainer import _sample_model_batch
@@ -299,7 +299,7 @@ def custom_call_counts(cfg) -> dict:
     f32 = jnp.float32
     cond = {k: jax.ShapeDtypeStruct((B,) + np.shape(batch[k])[1:], f32)
             for k in ("x", "R1", "t1", "R2", "t2", "K")}
-    ring_step = make_slot_step_fn(model, cfg.diffusion)
+    ring_step = make_ring_step_fn(model, cfg.diffusion)
     ring_text = ring_step.lower(
         state.params,
         jax.ShapeDtypeStruct((B, side, side, 3), f32),

@@ -220,7 +220,7 @@ class DiffusionConfig:
     # step instead of ~a dozen elementwise HLOs, consuming the per-row
     # (B, K) schedule-coefficient matrix as device arguments. Honored by
     # the serving samplers (sample/ddpm.make_request_sampler and
-    # make_slot_step_fn — both serve.scheduler values share it). "auto"
+    # make_ring_step_fn — both serve.scheduler values share it). "auto"
     # enables it on TPU backends only; True forces it (interpret mode
     # off-TPU: exact, slow — the tier-1 parity path); False keeps the
     # unfused chain. dpm++ 2M cannot fuse (multistep history): True

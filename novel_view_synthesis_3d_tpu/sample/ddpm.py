@@ -33,6 +33,12 @@ ancestral-variance at `ddim_eta=1`; `diffusion.sampler='dpm++'` uses the
 DPM-Solver++(2M) second-order multistep solver (Lu et al. 2022) for
 comparable quality at ~8× fewer steps. The reference has only the
 1000-step ancestral loop.
+
+The file, top down:
+  scan samplers (offline)  make_sampler, make_stochastic_sampler
+  request sampler          make_request_sampler: one scan a request
+  ring step                make_ring_step_fn: one step, any requests' rows
+  the pieces they share    _raw_eps, _step_noise; the serving two: _guided_step
 """
 
 from __future__ import annotations
@@ -54,31 +60,26 @@ from novel_view_synthesis_3d_tpu.models.xunet import (
 from novel_view_synthesis_3d_tpu.ops import fused_step as fused_step_lib
 
 
-def _raw_eps(model, params, model_batch: dict, pose_embs=None,
-             cond_feats=None, precomputed=None):
+def _raw_eps(model, params, model_batch: dict, precomputed=None):
     """(ε̂_cond, ε̂_uncond) network outputs via one doubled-batch forward.
 
-    `pose_embs`: per-level pose embeddings already computed for the
-    DOUBLED (cond+uncond) layout — injected after the doubling so they are
-    not concatenated twice. See models/xunet.precompute_pose_embs. A
-    level is one (2B, F, H/2ˡ, W/2ˡ, emb) array or, rows in the same
-    order, the pair (cond (B, F, H/2ˡ, W/2ˡ, emb), uncond (B, F, 1, 1,
-    emb)): a 1 × 1 extent stands for one value at every pixel of the
-    frame, which a caller may pass only where that is true of the rows
-    (models/xunet.precompute_guidance_pose_embs decides it).
-    `cond_feats`: stem features of the conditioning frame(s) for the
-    doubled layout (models/xunet.precompute_cond_feats) — with them the
-    step program convolves only the noised target frame.
-    `precomputed`: what the model's own `precompute(params, cond)` gave
-    for the doubled layout, batch entries handed through unread (the
-    denoiser contract, models/__init__.py)."""
+    `precomputed`: what the model computed of the conditioning outside
+    the step, for the DOUBLED (cond+uncond) layout — the mapping of the
+    denoiser contract (models/__init__.py), its entries written into the
+    batch after the doubling (so they are not concatenated twice) and
+    handed through unread. The model's own `precompute(params, cond)`
+    gives one; for the X-UNet the samplers may build it themselves:
+    `pose_embs`, per level one (2B, F, H/2ˡ, W/2ˡ, emb) array or, rows in
+    the same order, the pair (cond (B, F, H/2ˡ, W/2ˡ, emb), uncond (B, F,
+    1, 1, emb)) — a 1 × 1 extent stands for one value at every pixel of
+    the frame, which a caller may pass only where that is true of the
+    rows (models/xunet.precompute_guidance_pose_embs decides it) — and
+    `cond_feats`, stem features of the conditioning frame(s)
+    (models/xunet.precompute_cond_feats): with them the step program
+    convolves only the noised target frame."""
     B = model_batch["z"].shape[0]
     doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0), model_batch)
     mask = jnp.concatenate([jnp.ones((B,)), jnp.zeros((B,))])
-    if pose_embs is not None:
-        doubled["pose_embs"] = pose_embs
-    if cond_feats is not None:
-        doubled["cond_feats"] = cond_feats
     if precomputed is not None:
         doubled.update(precomputed)
     eps = model.apply({"params": params}, doubled, cond_mask=mask, train=False)
@@ -86,20 +87,11 @@ def _raw_eps(model, params, model_batch: dict, pose_embs=None,
     return eps_cond, eps_uncond
 
 
-def _cfg_eps(model, params, model_batch: dict, w: float,
-             pose_embs=None, precomputed=None):
+def _cfg_eps(model, params, model_batch: dict, w: float, precomputed=None):
     """(guided, conditional) network outputs; CFG combine applied here.
     The conditional output rides along for cfg_rescale."""
-    eps_cond, eps_uncond = _raw_eps(model, params, model_batch,
-                                    pose_embs=pose_embs,
-                                    precomputed=precomputed)
+    eps_cond, eps_uncond = _raw_eps(model, params, model_batch, precomputed)
     return (1.0 + w) * eps_cond - w * eps_uncond, eps_cond
-
-
-def _doubled_pose_embs(model, params, cond: dict):
-    """The X-UNet's pose embeddings for _cfg_eps's doubled layout, computed
-    once per trajectory (models/xunet.XUNet.precompute says how)."""
-    return model.precompute(params, cond)["pose_embs"]
 
 
 def _per_row_encode(model, params, cond: dict, mask):
@@ -145,11 +137,11 @@ def make_cond_encode_fn(model, *, param_transform=None):
     frame(s). The service (sample/service.py) calls this ONCE at
     admission — or once per frame-bank encode for trajectories, with B
     = k_max and the current target pose broadcast — and the results
-    live device-resident on the ring slot; `make_slot_step_fn` /
-    `make_bank_step_fn` built with `cond_cache=True` consume them as
-    device arguments instead of re-running rays → posenc → convs every
-    denoise step. A separate jitted callable (like make_bank_commit_fn)
-    so the step-program cache's entry accounting is untouched; compiles
+    live device-resident on the ring slot; `make_ring_step_fn` built
+    with `cond_cache=True` consumes them as device arguments instead of
+    re-running rays → posenc → convs every denoise step. A separate
+    jitted callable (like make_bank_commit_fn) so the step-program
+    cache's entry accounting is untouched; compiles
     once per (B, H, W) admission shape, never on the warm step path.
 
     Internally row-unrolled (_per_row_encode) so a k_max-batched bank
@@ -173,22 +165,25 @@ def make_cond_encode_fn(model, *, param_transform=None):
 
 
 def _assemble_cached_cond(cc3):
-    """Doubled (cond ‖ uncond) pose embeddings + stem features from the
-    cached halves: `cc3 = (pose_c, pose_u, feats_c)` with pose_c per-level
-    (B, …), pose_u per-level (1, …) — the shared uncond half, broadcast
-    here IN-program so guidance pairs store one encode — and feats_c
+    """`_raw_eps`'s `precomputed` mapping — doubled (cond ‖ uncond) pose
+    embeddings + stem features — from the cached halves:
+    `cc3 = (pose_c, pose_u, feats_c)` with pose_c per-level (B, …),
+    pose_u per-level (1, …) — the shared uncond half, broadcast here
+    IN-program so guidance pairs store one encode — and feats_c
     (B, Fc, H, W, ch), which is CFG-mask-independent (only the pose
     embedding is zeroed) so the same tensor serves both halves. Pinned
     with optimization_barrier: the forward must see materialized inputs,
     exactly like the uncached program's in-jit conv outputs, so XLA
     cannot fuse the assembly into the UNet and drift the two programs a
-    ulp apart (the barrier note above _resolve_request_fused)."""
+    ulp apart (the barrier note above _ring_update_spec)."""
     pose_c, pose_u, feats_c = cc3
     pose_embs = tuple(
         jnp.concatenate([pc, jnp.broadcast_to(pu, pc.shape)], axis=0)
         for pc, pu in zip(pose_c, pose_u))
     cond_feats = jnp.concatenate([feats_c, feats_c], axis=0)
-    return jax.lax.optimization_barrier((pose_embs, cond_feats))
+    pose_embs, cond_feats = jax.lax.optimization_barrier(
+        (pose_embs, cond_feats))
+    return {"pose_embs": pose_embs, "cond_feats": cond_feats}
 
 
 def _step_noise(key, z):
@@ -402,23 +397,65 @@ def make_sampler(model, schedule: DiffusionSchedule, config: DiffusionConfig,
 # training-side `make_sampler` is untouched (golden bit-compat).
 
 
-def _resolve_request_fused(config: DiffusionConfig) -> bool:
-    """Resolve diffusion.fused_step for the whole-request sampler.
+def _refuse_fused_multistep(config: DiffusionConfig):
+    """dpm++ 2M needs cross-step x̂₀ history, which a single fused step
+    cannot express: an explicit diffusion.fused_step=True is a loud error
+    (config.validate catches it earlier with the same message class),
+    while 'auto' silently keeps the unfused multistep scan."""
+    fused_step_lib.resolve_fused_step(config.fused_step)  # a typo'd flag
+    if config.sampler == "dpm++" and config.fused_step is True:
+        raise ValueError(
+            "diffusion.fused_step=True requires sampler 'ddpm' or "
+            "'ddim' — the dpm++ 2M multistep update carries x̂₀ "
+            "history across steps and is not expressible as one "
+            "fused step (use 'auto' to fuse where possible)")
 
-    dpm++ 2M needs cross-step x̂₀ history, which a single fused step
-    cannot express: an explicit True is a loud error (config.validate
-    catches it earlier with the same message class), while 'auto'
-    silently keeps the unfused multistep scan."""
-    use = fused_step_lib.resolve_fused_step(config.fused_step)
-    if use and config.sampler == "dpm++":
-        if config.fused_step is True:
-            raise ValueError(
-                "diffusion.fused_step=True requires sampler 'ddpm' or "
-                "'ddim' — the dpm++ 2M multistep update carries x̂₀ "
-                "history across steps and is not expressible as one "
-                "fused step (use 'auto' to fuse where possible)")
-        return False
-    return use
+
+def _ring_update_spec(config: DiffusionConfig):
+    """`(use_fused, kwargs)` of the serving samplers' shared per-step
+    update (ops/fused_step.py), validated: whether diffusion.fused_step
+    asks for the kernel, and the keywords either twin is called with.
+
+    `sampler='dpm++'` resolves to its first-order (history-free) update,
+    = η=0 ddim, which the kernel serves like any ddim: ring membership
+    changes between steps, so multistep history is invalid there, the
+    same rule `_make_update` applies to stochastic conditioning (serve
+    with serve.scheduler='request' for exact 2M, which never comes
+    here)."""
+    phi = config.cfg_rescale
+    if not 0.0 <= phi <= 1.0:
+        raise ValueError(f"cfg_rescale must be in [0, 1], got {phi}")
+    if config.objective not in ("eps", "x0", "v"):
+        raise ValueError(f"unknown objective {config.objective!r}")
+    sampler = config.sampler
+    eta = config.ddim_eta if sampler == "ddim" else 0.0
+    if sampler == "dpm++":
+        sampler = "ddim"
+    if sampler not in ("ddpm", "ddim"):
+        raise ValueError(f"unknown sampler {config.sampler!r}")
+    return fused_step_lib.resolve_fused_step(config.fused_step), dict(
+        sampler=sampler, objective=config.objective, eta=eta,
+        cfg_rescale=phi, clip_denoised=config.clip_denoised)
+
+
+def _guided_step(spec, z, ec, eu, noise, coefs, w):
+    """z_next of one reverse step from the forward's (ε̂_cond, ε̂_uncond):
+    CFG combine → x̂₀ + clip → ddpm/ddim update → noise add, by the fused
+    Pallas kernel or its unfused reference twin (`spec`:
+    _ring_update_spec) — one HBM pass or ~a dozen elementwise HLOs,
+    identical math and RNG stream. `coefs` is the (B,
+    len(STEP_COEF_KEYS)) matrix and `w` the (B,) guidance weights."""
+    use_fused, kwargs = spec
+    # Pinned inputs + one shared implementation: the fused and unfused
+    # programs are bit-identical (the barrier note above).
+    pinned = jax.lax.optimization_barrier((z, ec, eu, noise, coefs, w))
+    # Per-shape fusion decision at trace time: rows past the VMEM slab
+    # budget keep the unfused chain (same policy as the fused GroupNorm's
+    # over-VMEM fallback).
+    fused = use_fused and fused_step_lib.fits_vmem(np.prod(z.shape[1:]))
+    step_impl = (fused_step_lib.fused_denoise_step if fused
+                 else fused_step_lib.unfused_reference_step)
+    return step_impl(*pinned, **kwargs)
 
 
 def _sched_coef_row(schedule: DiffusionSchedule, t) -> jnp.ndarray:
@@ -471,21 +508,19 @@ def make_request_sampler(model, schedule: DiffusionSchedule,
     """
     require_family(
         model.config, "xunet", "sample.ddpm.make_request_sampler",
-        "the per-sample-keyed sampler hoists the X-UNet's pose embeddings by name; it has to call the model's precompute seam as make_sampler does")
+        "it calls the model's precompute seam as make_sampler does, but row independence under padding and co-riders has only been held to the X-UNet (tests/test_serve.py)")
     w = config.guidance_weight
     T = schedule.num_timesteps
-    use_fused = _resolve_request_fused(config)
-    # ddpm/ddim run the shared per-step implementation (fused kernel or
-    # its unfused reference twin, ops/fused_step.py — the same code the
-    # slot stepper runs, so the two schedulers stay bit-aligned); dpm++
-    # keeps the _make_update multistep scan (never fused).
+    # ddpm/ddim run the shared per-step implementation (_guided_step —
+    # the same code the ring step runs, so the two schedulers stay
+    # bit-aligned); dpm++ keeps the _make_update multistep scan (never
+    # fused).
     shared_impl = config.sampler in ("ddpm", "ddim")
     if shared_impl:
-        update, init_aux = None, lambda z0: ()
-        impl_eta = config.ddim_eta if config.sampler == "ddim" else 0.0
+        spec, init_aux = _ring_update_spec(config), lambda z0: ()
     else:
+        _refuse_fused_multistep(config)
         update, init_aux = _make_update(schedule, config)
-        impl_eta = 0.0
 
     @jax.jit
     @jax.named_scope("lk.update")
@@ -497,13 +532,8 @@ def make_request_sampler(model, schedule: DiffusionSchedule,
         keys0, k_init = both[:, 0], both[:, 1]
         z0 = jax.vmap(lambda k: jax.random.normal(k, z_shape))(k_init)
         ts = jnp.arange(T - 1, -1, -1)
-        pose_embs = _doubled_pose_embs(model, params, cond)
+        precomputed = model.precompute(params, cond)
         B = keys.shape[0]
-        # Per-shape fusion decision at trace time: rows past the VMEM
-        # slab budget keep the unfused chain (same policy as the fused
-        # GroupNorm's over-VMEM fallback).
-        fused = (shared_impl and use_fused
-                 and fused_step_lib.fits_vmem(int(np.prod(z_shape))))
 
         def body(carry, t):
             z, ks, aux = carry
@@ -512,29 +542,15 @@ def make_request_sampler(model, schedule: DiffusionSchedule,
             batch = dict(cond, z=z,
                          logsnr=jnp.full((z.shape[0],), schedule.logsnr(t)))
             if shared_impl:
-                ec, eu = _raw_eps(model, params, batch,
-                                  pose_embs=pose_embs)
+                ec, eu = _raw_eps(model, params, batch, precomputed)
                 # k_step is (B, 2): per-sample noise streams.
                 noise = _step_noise(k_step, z)
-                coefs = jnp.broadcast_to(
-                    _sched_coef_row(schedule, t),
-                    (B, len(STEP_COEF_KEYS)))
+                coefs = jnp.broadcast_to(_sched_coef_row(schedule, t),
+                                         (B, len(STEP_COEF_KEYS)))
                 wvec = jnp.full((B,), w, jnp.float32)
-                # Pinned inputs + one shared implementation: the fused
-                # and unfused programs are bit-identical (see the
-                # barrier note above _resolve_request_fused).
-                z_in, ec, eu, noise, coefs, wvec = \
-                    jax.lax.optimization_barrier(
-                        (z, ec, eu, noise, coefs, wvec))
-                step_impl = (fused_step_lib.fused_denoise_step if fused
-                             else fused_step_lib.unfused_reference_step)
-                z = step_impl(
-                    z_in, ec, eu, noise, coefs, wvec,
-                    sampler=config.sampler, objective=config.objective,
-                    eta=impl_eta, cfg_rescale=config.cfg_rescale,
-                    clip_denoised=config.clip_denoised)
+                z = _guided_step(spec, z, ec, eu, noise, coefs, wvec)
                 return (z, ks, aux), None
-            outs = _cfg_eps(model, params, batch, w, pose_embs=pose_embs)
+            outs = _cfg_eps(model, params, batch, w, precomputed)
             z, aux = update(z, t, outs, k_step, aux)
             return (z, ks, aux), None
 
@@ -573,424 +589,241 @@ assert tuple(fused_step_lib._COEF_COLS) == STEP_COEF_KEYS
 assert fused_step_lib._W_COL == len(STEP_COEF_KEYS)
 
 
-def make_slot_step_fn(model, config: DiffusionConfig, *,
-                      param_transform=None, cond_cache=False):
-    """ONE reverse-process step over a ring batch with per-row schedules.
+def _ring_head(z, keys, first, bank_state, stochastic):
+    """`(z, keys_next, k_step, pick)`: what one ring step does before its
+    conditioning — the RNG layout of the ring.
 
-    The serving stepper's device program (sample/service.py,
-    docs/DESIGN.md "Continuous batching & distillation"):
+    Rows with first=True draw their init noise here, reproducing
+    `make_request_sampler`'s pre-scan key split exactly: split(key) →
+    (carry, k_init), z₀ = N(0,1) from k_init. Every row then splits its
+    carry into (next_carry, k_step) exactly like that sampler's scan
+    body. `bank_state` is None on a bank-free ring (pick None), else the
+    (B, 2) [count, latest], and pick is (traj, idx): which rows are
+    trajectory rows (count > 0) and the bank entry each conditions on —
+    `latest`, or with `stochastic` a uniform draw over the first `count`
+    entries from a THIRD stream. Single-shot rows must consume the exact
+    two-way split of the bank-free program, so both splits are computed
+    and selected per row — never assume split(k, 3)[:2] == split(k, 2)."""
+    if bank_state is not None:
+        count, latest = bank_state[:, 0], bank_state[:, 1]
+        traj = count > 0
+    both = jax.vmap(jax.random.split)(keys)
+    k_carry, k_init = both[:, 0], both[:, 1]
+    z0 = _step_noise(k_init, z)
+    fmask = first.reshape(first.shape + (1,) * (z.ndim - 1))
+    z = jnp.where(fmask, z0.astype(z.dtype), z)
+    keys = jnp.where(first[:, None], k_carry, keys)
+    two = jax.vmap(jax.random.split)(keys)
+    if bank_state is None:
+        return z, two[:, 0], two[:, 1], None
+    if not stochastic:
+        return z, two[:, 0], two[:, 1], (traj, latest)
+    three = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
+    keys_next = jnp.where(traj[:, None], three[:, 0], two[:, 0])
+    k_step = jnp.where(traj[:, None], three[:, 1], two[:, 1])
+    idx = jax.vmap(
+        lambda k, n: jax.random.randint(k, (), 0, n))(
+            three[:, 2], jnp.maximum(count, 1))
+    return z, keys_next, k_step, (traj, idx)
 
-      step(params, z, keys, first, cond, coefs, w)
+
+# The four conditioning sources of the ring step — the only place its
+# four programs differ. Each takes (model, params, cond, pick, *what the
+# program is handed past `w`) with pick = (traj, idx) on a bank-enabled
+# ring, and returns the batch's conditioning entries and `_raw_eps`'s
+# `precomputed` mapping. Every encode everywhere is the B=1 row
+# computation (_per_row_encode) behind the same _assemble_cached_cond
+# barrier, so the forward sees bit-identical inputs and identical traced
+# structure whichever source fed it: encoding in the program matches the
+# admission encode of the cache, and encoding a gathered bank view
+# commutes bitwise with gathering bank entries that were row-encoded at
+# the frame boundary (tests/test_cond_cache.py pins array_equal; a
+# batched encode would drift co-riding rows ~1e-6).
+
+
+def _cond_from_request(model, params, cond, pick):
+    B = cond["x"].shape[0]
+    pose_c, feats_c = _per_row_encode(model, params, cond, jnp.ones((B,)))
+    pose_u = precompute_pose_embs(
+        model, params, jax.tree.map(lambda a: a[:1], cond), jnp.zeros((1,)))
+    return cond, _assemble_cached_cond((pose_c, pose_u, feats_c))
+
+
+def _cond_from_slot_cache(model, params, cond, pick, cc):
+    return cond, _assemble_cached_cond(cc)
+
+
+def _pick_rows(bank, idx):
+    """Row i's entry idx[i] of a (B, k_max, …) bank."""
+    return jax.vmap(lambda b, i: jax.lax.dynamic_index_in_dim(
+        b, i, 0, keepdims=False))(bank, idx)
+
+
+def _cond_from_bank(model, params, cond, pick, R2, t2, bank_x, bank_R,
+                    bank_t, bank_state):
+    traj, idx = pick
+    B = traj.shape[0]
+    # Bank gather, then per-row select against the request cond.
+    x_eff = jnp.where(traj.reshape((B, 1, 1, 1)),
+                      _pick_rows(bank_x, idx), cond["x"])
+    R1_eff = jnp.where(traj.reshape((B, 1, 1)),
+                       _pick_rows(bank_R, idx), cond["R1"])
+    t1_eff = jnp.where(traj.reshape((B, 1)),
+                       _pick_rows(bank_t, idx), cond["t1"])
+    # Pin the effective conditioning: the forward must see materialized
+    # inputs, exactly like the bank-free program's cond PARAMETERS, so XLA
+    # cannot fuse the gather/select producers into the UNet and drift
+    # single-shot rows a ulp apart (the same rationale as the update
+    # barrier).
+    x_eff, R1_eff, t1_eff, R2, t2 = jax.lax.optimization_barrier(
+        (x_eff, R1_eff, t1_eff, R2, t2))
+    eff = {"x": x_eff, "R1": R1_eff, "t1": t1_eff,
+           "R2": R2, "t2": t2, "K": cond["K"]}
+    return _cond_from_request(model, params, eff, pick)
+
+
+def _cond_from_bank_cache(model, params, cond, pick, R2, t2, bank_x, bank_R,
+                          bank_t, bank_state, cc):
+    # The same per-row gather/select, lifted from pixels to cached
+    # activations; single-shot rows select the request-level cache. The
+    # raw bank_x/bank_R/bank_t (and R2/t2, baked into the encodes) are
+    # never read — kept for the commit path's carry structure only, XLA
+    # drops them.
+    traj, idx = pick
+    pose_c, pose_u, feats_c, bank_pose, bank_feats = cc
+    tmask = traj.reshape((traj.shape[0], 1, 1, 1, 1))
+    sel_pose = tuple(
+        jnp.where(tmask, _pick_rows(bp, idx), pc)
+        for bp, pc in zip(bank_pose, pose_c))
+    sel_feats = jnp.where(tmask, _pick_rows(bank_feats, idx), feats_c)
+    return cond, _assemble_cached_cond((sel_pose, pose_u, sel_feats))
+
+
+# [bank-enabled][cond_cache]
+_COND_SOURCES = ((_cond_from_request, _cond_from_slot_cache),
+                 (_cond_from_bank, _cond_from_bank_cache))
+
+
+def make_ring_step_fn(model, config: DiffusionConfig, *, k_max=0,
+                      cond_cache=False, param_transform=None):
+    """ONE reverse-process step over a ring batch with per-row schedules:
+    the serving stepper's device program (sample/service.py;
+    docs/DESIGN.md "Continuous batching & distillation" and "Trajectory
+    serving & stochastic conditioning").
+
+      step(params, z, keys, first, cond, coefs, w, *bank, *cache)
         -> (z_next, keys_next, finite)
 
     with z (B, H, W, 3), keys a (B, 2) per-row PRNG carry, `first` a (B,)
     bool marking rows entering the ring THIS step, `coefs` a
     (B, len(STEP_COEF_KEYS)) float32 matrix (every schedule table value
     the update reads, gathered on host per row — one packed transfer per
-    step), and w the (B,) per-row guidance
-    weight. Rows are fully independent: row i's output depends on
-    (z_i, keys_i, cond_i, coefs_i, w_i) alone, so a request's image is
-    bit-identical whether it steps solo or interleaved with any co-riders
-    joining/leaving the ring — the ring-composition invariance the service
-    asserts (tests/test_stepper.py).
+    step), and w the (B,) per-row guidance weight. Rows are fully
+    independent: row i's output depends on (z_i, keys_i, cond_i, coefs_i,
+    w_i) alone, so a request's image is bit-identical whether it steps
+    solo or interleaved with any co-riders joining/leaving the ring — the
+    ring-composition invariance the service asserts
+    (tests/test_stepper.py).
 
-    Rows with first=True draw their init noise HERE, reproducing
-    `make_request_sampler`'s pre-scan key split exactly: split(key) →
-    (carry, k_init), z₀ = N(0,1) from k_init; every row then splits its
-    carry into (next_carry, k_step) exactly like the scan body — so a
-    request stepped t times through this program sees the same RNG stream
-    (and the same per-step math) as the whole-request sampler.
+    RNG layout (_ring_head): entering rows draw their init noise here,
+    then every row splits its carry — so a request stepped t times
+    through this program sees the same RNG stream (and the same per-step
+    math, _guided_step) as the whole-request sampler.
 
     The compiled program depends on the BUCKET SHAPE only: a mixed
-    4-step/256-step batch, or mixed guidance weights, runs one program —
-    t/steps_remaining/w are device arguments (the program-cache key
-    contract, docs/DESIGN.md). `sampler='dpm++'` runs its first-order
-    (history-free) update here — ring membership changes between steps,
-    so multistep history is invalid, the same rule `_make_update` applies
-    to stochastic conditioning; serve with serve.scheduler='request' for
-    exact 2M.
-
-    `diffusion.fused_step` routes everything after the UNet forward
-    (CFG combine → x̂₀ + clip → update → noise add) through the fused
-    Pallas kernel (ops/fused_step.py), consuming the SAME (B, K) coefs
-    matrix — one HBM pass per step instead of ~a dozen elementwise
-    HLOs, identical math and RNG stream. `param_transform` (optional)
-    is applied to `params` INSIDE the jit — the int8 serving path
-    passes the dequantizer here (sample/precision.py).
+    4-step/256-step batch, or mixed guidance weights, poses and bank
+    fills, run one program — they are device arguments (the
+    program-cache key contract, docs/DESIGN.md), and mixed single-shot +
+    trajectory traffic compiles nothing after warmup. `k_max` and
+    `cond_cache` are service constants (serve.k_max, serve.cond_cache)
+    and part of the program; `param_transform` (optional) is applied to
+    `params` INSIDE the jit — the int8 serving path passes the
+    dequantizer here (sample/precision.py). dpm++ and
+    `diffusion.fused_step`: _ring_update_spec.
 
     `finite` is a (B,) bool — a device-side all-reduce of
     isfinite(z_next) per row, the in-ring anomaly mask the service's
-    quarantine consumes (docs/DESIGN.md "Serving survivability"). It is
-    computed FROM z_next and never feeds back into the update, so
-    clean-path z/keys bits are untouched, and an extra output does not
-    change the program-cache identity (still bucket/shape-only).
+    quarantine consumes (docs/DESIGN.md "Serving survivability"); vital
+    with a bank: a non-finite frame committed to it would poison every
+    later frame's stochastic conditioning. It is computed FROM z_next and
+    never feeds back into the update, so clean-path z/keys bits are
+    untouched, and an extra output does not change the program-cache
+    identity.
 
-    `cond_cache=True` returns the cached-conditioning twin:
+    `k_max > 0` gives every row a FRAME BANK:
 
-      step(params, z, keys, first, cond, coefs, w, cc)
-        -> (z_next, keys_next, finite)
+      *bank = R2, t2, bank_x, bank_R, bank_t, bank_state
 
-    with `cc = (pose_c, pose_u, feats_c)` the admission-time encode
-    (make_cond_encode_fn): per-level (B, …) cond-half pose embeddings,
-    the shared (1, …) uncond half, and the (B, Fc, H, W, ch) cond stem
-    features — all device arguments stacked by the service from its
-    ring slots, so the program identity stays bucket/shape-only. The
-    doubled CFG layout is assembled in-program (_assemble_cached_cond)
-    and the UNet convolves only the noised target frame
-    (models/xunet.py `cond_feats` seam); everything else — RNG stream,
-    update math, anomaly mask — is byte-for-byte the uncached body, and
-    the two programs produce BIT-identical rows
-    (tests/test_cond_cache.py)."""
+    `bank_x` (B, k_max, H, W, C) holds each row's clean conditioning
+    frames (the request's source view plus every frame it has generated
+    so far, committed in-jit by `make_bank_commit_fn`), `bank_R`/`bank_t`
+    their poses, and `bank_state` a (B, 2) int32 of [count, latest]. Rows
+    with count > 0 are TRAJECTORY rows: their conditioning view is drawn
+    from the bank — uniformly over the first `count` entries with a third
+    per-row PRNG split when `diffusion.stochastic_cond` is True (the 3DiM
+    protocol), or the `latest` entry when False — and their target pose
+    comes from the per-step (B, 3, 3)/(B, 3) `R2`/`t2` (the host uploads
+    the CURRENT frame's pose each step, like the schedule coefficients,
+    so advancing to the next orbit pose never rebuilds the ring). Rows
+    with count == 0 are SINGLE-SHOT rows: they read their conditioning
+    from `cond` and consume the IDENTICAL per-row RNG stream, so a
+    single-shot request is BIT-identical whether it rides this program
+    next to trajectory rows or the bank-free program of a service with
+    serve.k_max=0 (tests/test_trajectory.py). The gather happens BEFORE
+    the forward, so the update after it is the bank-free one.
+
+    `cond_cache=True` hands the conditioning branch in as device
+    arguments, stacked by the service from its ring slots:
+
+      *cache = (cc,)
+      cc = (pose_c, pose_u, feats_c[, bank_pose, bank_feats])
+
+    the admission-time encode (make_cond_encode_fn): per-level (B, …)
+    cond-half pose embeddings, the shared (1, …) uncond half, the
+    (B, Fc, H, W, ch) cond stem features and, with a bank, per-level
+    (B, k_max, …) bank-entry pose embeddings and (B, k_max, Fc, H, W, ch)
+    bank-entry stem features — every bank entry encoded against the row's
+    CURRENT target pose at the frame boundary (sample/service.py
+    re-encodes when the target advances, exactly when it restacks
+    R2/t2). The doubled CFG layout is assembled in-program
+    (_assemble_cached_cond) and the UNet convolves only the noised target
+    frame (models/xunet.py `cond_feats` seam); everything else — RNG
+    stream, update math, anomaly mask — is the uncached program's, and
+    the two produce BIT-identical rows (tests/test_cond_cache.py)."""
     require_family(
-        model.config, "xunet", "sample.ddpm.make_slot_step_fn (the step ring)",
-        "a latent cache per ring slot, written when a request is admitted and read by every step of its rows")
-    phi = config.cfg_rescale
-    if not 0.0 <= phi <= 1.0:
-        raise ValueError(f"cfg_rescale must be in [0, 1], got {phi}")
-    clip_denoised = config.clip_denoised
-    objective = config.objective
-    if objective not in ("eps", "x0", "v"):
-        raise ValueError(f"unknown objective {objective!r}")
-    sampler = config.sampler
-    eta = config.ddim_eta if sampler == "ddim" else 0.0
-    if sampler == "dpm++":
-        sampler = "ddim"  # first-order fallback (see docstring)
-    if sampler not in ("ddpm", "ddim"):
-        raise ValueError(f"unknown sampler {config.sampler!r}")
-    # The stepper's dpm++ fallback is already first-order ddim, so the
-    # fused kernel serves every sampler the stepper does.
-    use_fused = fused_step_lib.resolve_fused_step(config.fused_step)
-
-    logsnr_col = STEP_COEF_KEYS.index("logsnr")
-
-    @jax.jit
-    @jax.named_scope("lk.update")
-    def step(params, z, keys, first, cond, coefs, w):
-        if param_transform is not None:
-            params = param_transform(params)
-        B = z.shape[0]
-        # Rows entering the ring draw init noise from their own stream.
-        both = jax.vmap(jax.random.split)(keys)
-        k_carry, k_init = both[:, 0], both[:, 1]
-        z0 = jax.vmap(lambda k: jax.random.normal(k, z.shape[1:]))(k_init)
-        fmask = first.reshape((B,) + (1,) * (z.ndim - 1))
-        z = jnp.where(fmask, z0.astype(z.dtype), z)
-        keys = jnp.where(first[:, None], k_carry, keys)
-        # Per-step draw: identical split layout to the scan body.
-        both = jax.vmap(jax.random.split)(keys)
-        keys_next, k_step = both[:, 0], both[:, 1]
-
-        # Cond branch: computed in-program, but row-unrolled through the
-        # SAME B=1 encode computation (_per_row_encode) and the same
-        # _assemble_cached_cond barrier as the cached twin's admission
-        # encodes, so the downstream UNet sees bit-identical inputs and
-        # identical traced structure in both programs — a batched encode
-        # here would drift co-riding rows ~1e-6 from their admission
-        # encodes (tests/test_cond_cache.py pins array_equal).
-        pose_c, feats_c = _per_row_encode(model, params, cond,
-                                          jnp.ones((B,)))
-        pose_u = precompute_pose_embs(
-            model, params, jax.tree.map(lambda a: a[:1], cond),
-            jnp.zeros((1,)))
-        pose_embs, cond_feats = _assemble_cached_cond(
-            (pose_c, pose_u, feats_c))
-        batch = dict(cond, z=z, logsnr=coefs[:, logsnr_col])
-        ec, eu = _raw_eps(model, params, batch, pose_embs=pose_embs,
-                          cond_feats=cond_feats)
-        noise = _step_noise(k_step, z)
-        # Pin the update's inputs so both branches see identical bits
-        # (see the barrier note above _resolve_request_fused).
-        z_in, ec, eu, noise, coefs_in, w_in = jax.lax.optimization_barrier(
-            (z, ec, eu, noise, coefs, w))
-        fused = use_fused and fused_step_lib.fits_vmem(
-            int(np.prod(z.shape[1:])))
-        # Per-shape trace-time decision (over-VMEM rows keep the
-        # unfused chain, same policy as fused GroupNorm).
-        step_impl = (fused_step_lib.fused_denoise_step if fused
-                     else fused_step_lib.unfused_reference_step)
-        z_next = step_impl(
-            z_in, ec, eu, noise, coefs_in, w_in, sampler=sampler,
-            objective=objective, eta=eta, cfg_rescale=phi,
-            clip_denoised=clip_denoised)
-        # Per-row anomaly mask: reduced on device so the host learns
-        # "row i went non-finite" from a (B,) bool instead of pulling
-        # the latent back every step. Read-only over z_next.
-        finite = jnp.all(jnp.isfinite(z_next).reshape(B, -1), axis=1)
-        return z_next, keys_next, finite
-
-    @jax.jit
-    @jax.named_scope("lk.update")
-    def step_cached(params, z, keys, first, cond, coefs, w, cc):
-        # Cached-conditioning twin (see docstring): identical body
-        # except the cond branch arrives as device arguments.
-        if param_transform is not None:
-            params = param_transform(params)
-        B = z.shape[0]
-        both = jax.vmap(jax.random.split)(keys)
-        k_carry, k_init = both[:, 0], both[:, 1]
-        z0 = jax.vmap(lambda k: jax.random.normal(k, z.shape[1:]))(k_init)
-        fmask = first.reshape((B,) + (1,) * (z.ndim - 1))
-        z = jnp.where(fmask, z0.astype(z.dtype), z)
-        keys = jnp.where(first[:, None], k_carry, keys)
-        both = jax.vmap(jax.random.split)(keys)
-        keys_next, k_step = both[:, 0], both[:, 1]
-
-        pose_embs, cond_feats = _assemble_cached_cond(cc)
-        batch = dict(cond, z=z, logsnr=coefs[:, logsnr_col])
-        ec, eu = _raw_eps(model, params, batch, pose_embs=pose_embs,
-                          cond_feats=cond_feats)
-        noise = _step_noise(k_step, z)
-        z_in, ec, eu, noise, coefs_in, w_in = jax.lax.optimization_barrier(
-            (z, ec, eu, noise, coefs, w))
-        fused = use_fused and fused_step_lib.fits_vmem(
-            int(np.prod(z.shape[1:])))
-        step_impl = (fused_step_lib.fused_denoise_step if fused
-                     else fused_step_lib.unfused_reference_step)
-        z_next = step_impl(
-            z_in, ec, eu, noise, coefs_in, w_in, sampler=sampler,
-            objective=objective, eta=eta, cfg_rescale=phi,
-            clip_denoised=clip_denoised)
-        finite = jnp.all(jnp.isfinite(z_next).reshape(B, -1), axis=1)
-        return z_next, keys_next, finite
-
-    return step_cached if cond_cache else step
-
-
-def make_bank_step_fn(model, config: DiffusionConfig, k_max: int, *,
-                      param_transform=None, cond_cache=False):
-    """`make_slot_step_fn` with an optional per-row FRAME BANK — the
-    trajectory-serving stepper program (sample/service.py; docs/DESIGN.md
-    "Trajectory serving & stochastic conditioning").
-
-      step(params, z, keys, first, cond, coefs, w, R2, t2,
-           bank_x, bank_R, bank_t, bank_state)
-        -> (z_next, keys_next, finite)
-
-    On top of the slot-step contract: `bank_x` (B, k_max, H, W, C) holds
-    each row's clean conditioning frames (the request's source view plus
-    every frame it has generated so far, committed in-jit by
-    `make_bank_commit_fn`), `bank_R`/`bank_t` their poses, and
-    `bank_state` a (B, 2) int32 of [count, latest]. Rows with count > 0
-    are TRAJECTORY rows: their conditioning view is drawn from the bank
-    — uniformly over the first `count` entries with a third per-row PRNG
-    split when `diffusion.stochastic_cond` is True (the 3DiM protocol),
-    or the `latest` entry when False — and their target pose comes from
-    the per-step (B, 3, 3)/(B, 3) `R2`/`t2` device arguments (the host
-    uploads the CURRENT frame's pose each step, like the schedule
-    coefficients, so advancing to the next orbit pose never rebuilds the
-    ring). Rows with count == 0 are SINGLE-SHOT rows: they read their
-    conditioning from `cond` exactly like `make_slot_step_fn`, and —
-    crucially — consume the IDENTICAL per-row RNG stream (the pick split
-    is computed for every row but single-shot rows select the two-way
-    split results), so a single-shot request is BIT-identical whether it
-    rides this program next to trajectory rows or the bank-free program
-    of a service with serve.k_max=0 (tests/test_trajectory.py).
-
-    The bank gather happens BEFORE the UNet forward, so
-    `diffusion.fused_step` routes the post-forward update through the
-    same fused Pallas kernel unchanged. k_max is part of the program
-    SHAPE (one service = one k_max); everything per-request — step
-    count, guidance, pose, bank fill — is a device argument, so the
-    program identity stays bucket/shape-only and mixed single-shot +
-    trajectory traffic compiles nothing after warmup.
-
-    `cond_cache=True` returns the cached-conditioning twin:
-
-      step(params, z, keys, first, cond, coefs, w, R2, t2,
-           bank_x, bank_R, bank_t, bank_state, cc)
-        -> (z_next, keys_next, finite)
-
-    with `cc = (pose_c, pose_u, feats_c, bank_pose, bank_feats)`:
-    the slot-step triple plus per-level (B, k_max, …) bank-entry pose
-    embeddings and (B, k_max, Fc, H, W, ch) bank-entry stem features —
-    every bank entry encoded against the row's CURRENT target pose at
-    the frame boundary (sample/service.py re-encodes when the target
-    advances, exactly when it restacks R2/t2). The stochastic pick
-    gathers the cached EMBEDDINGS with the same idx (per-row encode
-    commutes with the gather bitwise), single-shot rows select the
-    request-level cache, and the raw bank_x/bank_R/bank_t stay in the
-    signature only for the commit path's carry structure — the forward
-    never reads them, so XLA drops the gathers. RNG stream and update
-    math are byte-for-byte the uncached body.
-    """
-    require_family(
-        model.config, "xunet", "sample.ddpm.make_bank_step_fn (the trajectory ring)",
-        "a latent cache per slot and per frame of the bank, re-made when the step's conditioning frame changes")
-    if k_max < 1:
-        raise ValueError(
-            f"make_bank_step_fn: k_max={k_max} must be >= 1 (a bank-less "
-            "stepper is make_slot_step_fn)")
+        model.config, "xunet", "sample.ddpm.make_ring_step_fn (the step ring)",
+        "a latent cache per ring slot, written when a request is admitted and read by every step of its rows, and one per slot and frame of the bank for trajectories, re-made when the step's conditioning frame changes")
     stochastic = config.stochastic_cond
-    if stochastic not in (True, False):
+    if k_max and stochastic not in (True, False):
         raise ValueError(
             f"diffusion.stochastic_cond={stochastic!r} must be True "
             "(random bank view per step) or False (most recent frame)")
-    phi = config.cfg_rescale
-    if not 0.0 <= phi <= 1.0:
-        raise ValueError(f"cfg_rescale must be in [0, 1], got {phi}")
-    clip_denoised = config.clip_denoised
-    objective = config.objective
-    if objective not in ("eps", "x0", "v"):
-        raise ValueError(f"unknown objective {objective!r}")
-    sampler = config.sampler
-    eta = config.ddim_eta if sampler == "ddim" else 0.0
-    if sampler == "dpm++":
-        sampler = "ddim"  # first-order fallback, as in make_slot_step_fn
-    if sampler not in ("ddpm", "ddim"):
-        raise ValueError(f"unknown sampler {config.sampler!r}")
-    use_fused = fused_step_lib.resolve_fused_step(config.fused_step)
+    spec = _ring_update_spec(config)
+    source = _COND_SOURCES[k_max > 0][bool(cond_cache)]
     logsnr_col = STEP_COEF_KEYS.index("logsnr")
 
-    @jax.jit
-    @jax.named_scope("lk.update")
-    def step(params, z, keys, first, cond, coefs, w, R2, t2,
-             bank_x, bank_R, bank_t, bank_state):
+    def step(params, z, keys, first, cond, coefs, w, *rest):
         if param_transform is not None:
             params = param_transform(params)
-        B = z.shape[0]
-        count, latest = bank_state[:, 0], bank_state[:, 1]
-        traj = count > 0
-        # Init-noise draw for rows entering the ring: identical split
-        # layout to make_slot_step_fn (and make_request_sampler).
-        both = jax.vmap(jax.random.split)(keys)
-        k_carry, k_init = both[:, 0], both[:, 1]
-        z0 = jax.vmap(lambda k: jax.random.normal(k, z.shape[1:]))(k_init)
-        fmask = first.reshape((B,) + (1,) * (z.ndim - 1))
-        z = jnp.where(fmask, z0.astype(z.dtype), z)
-        keys = jnp.where(first[:, None], k_carry, keys)
-        # Per-step draw. Trajectory rows need a THIRD stream for the
-        # stochastic-conditioning pick; single-shot rows must consume
-        # the exact two-way split of the bank-free program, so both
-        # splits are computed and selected per row — never assume
-        # split(k, 3)[:2] == split(k, 2).
-        two = jax.vmap(jax.random.split)(keys)
-        if stochastic:
-            three = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
-            keys_next = jnp.where(traj[:, None], three[:, 0], two[:, 0])
-            k_step = jnp.where(traj[:, None], three[:, 1], two[:, 1])
-            idx = jax.vmap(
-                lambda k, n: jax.random.randint(k, (), 0, n))(
-                    three[:, 2], jnp.maximum(count, 1))
-        else:
-            keys_next, k_step = two[:, 0], two[:, 1]
-            idx = latest
-        # Bank gather, then per-row select against the request cond.
-        take = lambda bank: jax.vmap(  # noqa: E731
-            lambda b, i: jax.lax.dynamic_index_in_dim(
-                b, i, 0, keepdims=False))(bank, idx)
-        x_eff = jnp.where(traj.reshape((B, 1, 1, 1)),
-                          take(bank_x), cond["x"])
-        R1_eff = jnp.where(traj.reshape((B, 1, 1)),
-                           take(bank_R), cond["R1"])
-        t1_eff = jnp.where(traj.reshape((B, 1)),
-                           take(bank_t), cond["t1"])
-        # Pin the effective conditioning: the forward must see
-        # materialized inputs, exactly like the bank-free program's cond
-        # PARAMETERS, so XLA cannot fuse the gather/select producers
-        # into the UNet and drift single-shot rows a ulp apart (the
-        # same rationale as the update barrier below).
-        x_eff, R1_eff, t1_eff, R2_in, t2_in = jax.lax.optimization_barrier(
-            (x_eff, R1_eff, t1_eff, R2, t2))
-        eff = {"x": x_eff, "R1": R1_eff, "t1": t1_eff,
-               "R2": R2_in, "t2": t2_in, "K": cond["K"]}
-        # Same row-unrolled encode + assembly barrier as the cached twin
-        # (see the make_slot_step_fn note): every encode everywhere is
-        # the B=1 row computation, so encoding the gathered view here
-        # commutes bitwise with the cached twin's gather over bank
-        # entries that were themselves row-encoded at the frame boundary.
-        pose_c, feats_c = _per_row_encode(model, params, eff,
-                                          jnp.ones((B,)))
-        pose_u = precompute_pose_embs(
-            model, params, jax.tree.map(lambda a: a[:1], eff),
-            jnp.zeros((1,)))
-        pose_embs, cond_feats = _assemble_cached_cond(
-            (pose_c, pose_u, feats_c))
-        batch = dict(eff, z=z, logsnr=coefs[:, logsnr_col])
-        ec, eu = _raw_eps(model, params, batch, pose_embs=pose_embs,
-                          cond_feats=cond_feats)
-        noise = _step_noise(k_step, z)
-        z_in, ec, eu, noise, coefs_in, w_in = jax.lax.optimization_barrier(
-            (z, ec, eu, noise, coefs, w))
-        fused = use_fused and fused_step_lib.fits_vmem(
-            int(np.prod(z.shape[1:])))
-        step_impl = (fused_step_lib.fused_denoise_step if fused
-                     else fused_step_lib.unfused_reference_step)
-        z_next = step_impl(
-            z_in, ec, eu, noise, coefs_in, w_in, sampler=sampler,
-            objective=objective, eta=eta, cfg_rescale=phi,
-            clip_denoised=clip_denoised)
-        # Same read-only per-row anomaly mask as make_slot_step_fn —
-        # vital here: a non-finite frame committed to the bank would
-        # poison every later frame's stochastic conditioning.
-        finite = jnp.all(jnp.isfinite(z_next).reshape(B, -1), axis=1)
+        # Past w the program is handed R2, t2, bank_x, bank_R, bank_t,
+        # bank_state where it has a bank, then cc where it has a cache.
+        z, keys_next, k_step, pick = _ring_head(
+            z, keys, first, rest[5] if k_max else None, stochastic)
+        batch_cond, precomputed = source(model, params, cond, pick, *rest)
+        batch = dict(batch_cond, z=z, logsnr=coefs[:, logsnr_col])
+        ec, eu = _raw_eps(model, params, batch, precomputed)
+        z_next = _guided_step(spec, z, ec, eu, _step_noise(k_step, z),
+                              coefs, w)
+        # Per-row anomaly mask: reduced on device so the host learns
+        # "row i went non-finite" from a (B,) bool instead of pulling
+        # the latent back every step. Read-only over z_next.
+        finite = jnp.all(jnp.isfinite(z_next).reshape(len(z), -1), axis=1)
         return z_next, keys_next, finite
 
-    @jax.jit
-    @jax.named_scope("lk.update")
-    def step_cached(params, z, keys, first, cond, coefs, w, R2, t2,
-                    bank_x, bank_R, bank_t, bank_state, cc):
-        # Cached-conditioning twin (see docstring): identical RNG head
-        # and pick, but the gather runs over cached EMBEDDINGS and the
-        # raw bank_x/bank_R/bank_t are never read (kept for the carry
-        # structure only — XLA drops them).
-        if param_transform is not None:
-            params = param_transform(params)
-        pose_c, pose_u, feats_c, bank_pose, bank_feats = cc
-        B = z.shape[0]
-        count, latest = bank_state[:, 0], bank_state[:, 1]
-        traj = count > 0
-        both = jax.vmap(jax.random.split)(keys)
-        k_carry, k_init = both[:, 0], both[:, 1]
-        z0 = jax.vmap(lambda k: jax.random.normal(k, z.shape[1:]))(k_init)
-        fmask = first.reshape((B,) + (1,) * (z.ndim - 1))
-        z = jnp.where(fmask, z0.astype(z.dtype), z)
-        keys = jnp.where(first[:, None], k_carry, keys)
-        two = jax.vmap(jax.random.split)(keys)
-        if stochastic:
-            three = jax.vmap(lambda k: jax.random.split(k, 3))(keys)
-            keys_next = jnp.where(traj[:, None], three[:, 0], two[:, 0])
-            k_step = jnp.where(traj[:, None], three[:, 1], two[:, 1])
-            idx = jax.vmap(
-                lambda k, n: jax.random.randint(k, (), 0, n))(
-                    three[:, 2], jnp.maximum(count, 1))
-        else:
-            keys_next, k_step = two[:, 0], two[:, 1]
-            idx = latest
-        # Same per-row gather/select as the uncached body, lifted from
-        # pixels to cached activations: the per-row encode commutes with
-        # the gather bitwise (the bank entries were encoded row-wise at
-        # the frame boundary), and single-shot rows select the
-        # request-level cache. _assemble_cached_cond pins the assembled
-        # result, so the forward sees materialized inputs exactly like
-        # the uncached program's eff barrier.
-        take = lambda bank: jax.vmap(  # noqa: E731
-            lambda b, i: jax.lax.dynamic_index_in_dim(
-                b, i, 0, keepdims=False))(bank, idx)
-        tmask = traj.reshape((B, 1, 1, 1, 1))
-        sel_pose = tuple(
-            jnp.where(tmask, take(bp), pc)
-            for bp, pc in zip(bank_pose, pose_c))
-        sel_feats = jnp.where(tmask, take(bank_feats), feats_c)
-        pose_embs, cond_feats = _assemble_cached_cond(
-            (sel_pose, pose_u, sel_feats))
-        batch = dict(cond, z=z, logsnr=coefs[:, logsnr_col])
-        ec, eu = _raw_eps(model, params, batch, pose_embs=pose_embs,
-                          cond_feats=cond_feats)
-        noise = _step_noise(k_step, z)
-        z_in, ec, eu, noise, coefs_in, w_in = jax.lax.optimization_barrier(
-            (z, ec, eu, noise, coefs, w))
-        fused = use_fused and fused_step_lib.fits_vmem(
-            int(np.prod(z.shape[1:])))
-        step_impl = (fused_step_lib.fused_denoise_step if fused
-                     else fused_step_lib.unfused_reference_step)
-        z_next = step_impl(
-            z_in, ec, eu, noise, coefs_in, w_in, sampler=sampler,
-            objective=objective, eta=eta, cfg_rescale=phi,
-            clip_denoised=clip_denoised)
-        finite = jnp.all(jnp.isfinite(z_next).reshape(B, -1), axis=1)
-        return z_next, keys_next, finite
-
-    return step_cached if cond_cache else step
+    # The program's name, in its lowered text and in a device trace
+    # (jit_step, jit_step_cached), is the one each program has had.
+    step.__name__ = "step_cached" if cond_cache else "step"
+    return jax.jit(jax.named_scope("lk.update")(step))
 
 
 def make_bank_commit_fn():
@@ -1122,7 +955,8 @@ def make_stochastic_sampler(model, schedule: DiffusionSchedule,
                 "z": z,
                 "logsnr": jnp.full((B,), schedule.logsnr(t)),
             }
-            outs = _cfg_eps(model, params, batch, w, pose_embs=doubled_emb)
+            outs = _cfg_eps(model, params, batch, w,
+                            {"pose_embs": doubled_emb} if do_pre else None)
             z, aux = update(z, t, outs, k_step, aux)
             return (z, key, aux), None
 

@@ -14,7 +14,7 @@ Two schedulers share the front-end (serve.scheduler):
   - 'step' (default; docs/DESIGN.md "Continuous batching & distillation"):
     a persistent STEPPER — the diffusion analogue of LLM continuous
     batching. One compiled denoise-STEP program per bucket shape
-    (sample/ddpm.make_slot_step_fn) runs over a ring of active request
+    (sample/ddpm.make_ring_step_fn) runs over a ring of active request
     slots, each slot carrying its own (z, t, cond, keys, steps_remaining,
     model_version). New arrivals join the ring BETWEEN steps (filling
     padded slots), finished rows exit and respond immediately — a 4-step
@@ -124,10 +124,9 @@ from novel_view_synthesis_3d_tpu.ops.fused_step import resolve_fused_step
 from novel_view_synthesis_3d_tpu.sample import precision as precision_lib
 from novel_view_synthesis_3d_tpu.sample.ddpm import (
     make_bank_commit_fn,
-    make_bank_step_fn,
     make_cond_encode_fn,
     make_request_sampler,
-    make_slot_step_fn,
+    make_ring_step_fn,
 )
 from novel_view_synthesis_3d_tpu.sample.stepper import FrameBank, ScheduleBank
 from novel_view_synthesis_3d_tpu.utils.profiling import ServiceStats
@@ -163,7 +162,7 @@ class SampleAnomaly(ServeError):
     """A ring row's latent went non-finite and the slot was quarantined.
 
     The per-row finite mask (a device-side reduce folded into the step
-    program, sample/ddpm.make_slot_step_fn) flagged this request's z;
+    program, sample/ddpm.make_ring_step_fn) flagged this request's z;
     after `serve.anomaly_strikes` consecutive strikes the slot is
     EVICTED — its co-riders are untouched (ring-composition invariance
     means the poison cannot spread across rows) and nothing non-finite
@@ -1768,14 +1767,10 @@ class SamplingService:
                 d.stochastic_cond, self._cond_cache)
 
     def _build_step_program(self):
-        if self._k_max > 0:
-            return make_bank_step_fn(
-                self.model, self.diffusion, self._k_max,
-                param_transform=self._param_transform,
-                cond_cache=self._cond_cache)
-        return make_slot_step_fn(self.model, self.diffusion,
-                                 param_transform=self._param_transform,
-                                 cond_cache=self._cond_cache)
+        return make_ring_step_fn(
+            self.model, self.diffusion, k_max=self._k_max,
+            cond_cache=self._cond_cache,
+            param_transform=self._param_transform)
 
     def _zero_bank(self, H: int, W: int) -> tuple:
         """Staged-once zero bank arrays for single-shot rows riding a

@@ -1,6 +1,6 @@
 """Host-side schedule bank for the step-level serving scheduler.
 
-The stepper's device program (`sample/ddpm.make_slot_step_fn`) is keyed
+The stepper's device program (`sample/ddpm.make_ring_step_fn`) is keyed
 on the bucket SHAPE only; everything schedule-dependent — a row's
 timestep position, its respaced ladder, its guidance weight — rides as
 device arguments. This module owns the host side of that contract: for
@@ -43,7 +43,7 @@ class StepBank:
     so n <= requested steps). A request walks t = n-1, n-2, …, 0; its
     per-step device argument is `table[t]`, one packed
     (len(STEP_COEF_KEYS),) row — the stepper stacks one such row per
-    slot into the (B, K) matrix `make_slot_step_fn` consumes, so the
+    slot into the (B, K) matrix `make_ring_step_fn` consumes, so the
     whole ring's schedule state moves host→device in ONE transfer per
     step. `coefs` exposes the same values as named column views.
     """
@@ -94,7 +94,7 @@ class FrameBank:
     the stepper's batched latent — a finished frame joins its own
     conditioning pool without touching the host. The serving stepper
     stacks the ring's banks (a device-side jnp.stack) into the
-    (B, k_max, …) tensors `make_bank_step_fn` gathers from; because the
+    (B, k_max, …) tensors `make_ring_step_fn` gathers from; because the
     per-slot arrays are the authoritative copy, a ring rebuild restacks
     bit-identically to what the previous carry held — trajectory rows
     stay ring-composition invariant.

@@ -68,7 +68,8 @@ def parents_make_sampler(model, schedule, config, trajectory_every):
         key, k_step = jax.random.split(key)
         batch = dict(cond, z=z,
                      logsnr=jnp.full((z.shape[0],), schedule.logsnr(t)))
-        outs = ddpm._cfg_eps(model, params, batch, w, pose_embs=pose_embs)
+        outs = ddpm._cfg_eps(model, params, batch, w,
+                             {"pose_embs": pose_embs})
         z, aux = update(z, t, outs, k_step, aux)
         return (z, key, aux), None
 
@@ -197,9 +198,9 @@ REFUSALS = {
     "trainer": (_refuse_trainer, "balance loss"),
     "request_sampler": (lambda: ddpm.make_request_sampler(
         _tokens()[1], sampling_schedule(DIFF, 2), DIFF), "precompute seam"),
-    "slot_step": (lambda: ddpm.make_slot_step_fn(_tokens()[1], DIFF),
+    "slot_step": (lambda: ddpm.make_ring_step_fn(_tokens()[1], DIFF, k_max=0),
                   "latent cache per ring slot"),
-    "bank_step": (lambda: ddpm.make_bank_step_fn(_tokens()[1], DIFF, 2),
+    "bank_step": (lambda: ddpm.make_ring_step_fn(_tokens()[1], DIFF, k_max=2),
                   "frame of the bank"),
     "stochastic": (lambda: ddpm.make_stochastic_sampler(
         _tokens()[1], sampling_schedule(DIFF, 2), DIFF, 2), "pool view"),

@@ -33,7 +33,7 @@ from novel_view_synthesis_3d_tpu.sample import precision as precision_lib
 from novel_view_synthesis_3d_tpu.sample.ddpm import (
     STEP_COEF_KEYS,
     make_request_sampler,
-    make_slot_step_fn,
+    make_ring_step_fn,
 )
 from novel_view_synthesis_3d_tpu.sample.service import (
     SamplingService,
@@ -182,9 +182,9 @@ def test_slot_step_fused_bit_identical(setup, sampler_name):
     first = jnp.asarray([True, False, True, False])
     coefs = jnp.asarray(np.stack([bank.table[2]] * B))
     w = jnp.asarray([3.0, 1.5, 0.0, 7.0], jnp.float32)
-    zu, ku, fu = make_slot_step_fn(model, dcfg)(
+    zu, ku, fu = make_ring_step_fn(model, dcfg)(
         params, z, keys, first, cond, coefs, w)
-    zf, kf, ff = make_slot_step_fn(
+    zf, kf, ff = make_ring_step_fn(
         model, dataclasses.replace(dcfg, fused_step=True))(
             params, z, keys, first, cond, coefs, w)
     np.testing.assert_array_equal(np.asarray(zu), np.asarray(zf))

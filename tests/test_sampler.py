@@ -508,7 +508,6 @@ def test_split_pose_embs_match_full_extent_and_inline():
     unconditional rows at 1 × 1) gives the output of the same rows at
     full extent and of the in-loop path, mask [1, 0]."""
     from novel_view_synthesis_3d_tpu.models.xunet import precompute_pose_embs
-    from novel_view_synthesis_3d_tpu.sample.ddpm import _doubled_pose_embs
 
     model, params, cond = _model_and_params(B=1)
     params = _perturbed(params)
@@ -519,7 +518,7 @@ def test_split_pose_embs_match_full_extent_and_inline():
     mask = jnp.asarray([1.0, 0.0])
 
     full = precompute_pose_embs(model, params, pair, mask)
-    split = _doubled_pose_embs(model, params, cond)
+    split = model.precompute(params, cond)["pose_embs"]
     for lvl, (f, (c, u)) in enumerate(zip(full, split)):
         side = 16 // 2 ** lvl
         assert f.shape == (2, 2, side, side, TINY.emb_ch)
@@ -567,7 +566,7 @@ def test_sampler_matches_inline_loop_whatever_the_extent(flags, capsys):
         {"params": jax.random.PRNGKey(0)}, batch, cond_mask=jnp.ones((2,)),
         train=False)["params"])
 
-    embs = ddpm._doubled_pose_embs(model, params, cond)
+    embs = model.precompute(params, cond)["pose_embs"]
     assert all(isinstance(e, tuple) != bool(flags) for e in embs)
 
     dcfg = DiffusionConfig(timesteps=8, sample_timesteps=2,
