@@ -3,11 +3,27 @@
 The reference computes attention with `flax.nn.dot_product_attention`
 (/root/reference/model/xunet.py:101), which materializes the (L, L) score
 matrix in HBM between ops. This kernel keeps the whole
-score→softmax→weighted-sum chain in VMEM, streaming one query block at a
-time against the full key/value sequence (which for one (batch, head) pair
-fits comfortably in VMEM at every config in the ladder — L ≤ 65k would not,
-but attention only runs at coarse resolutions {8,16,32} ⇒ L ≤ 1024 tokens,
-and cross-frame attention at k+1 frames tops out at a few thousand).
+score→softmax→weighted-sum chain in VMEM. A grid step takes one query
+block of one (batch, head) pair; that pair's keys and values sit whole in
+VMEM (their block index does not change over a head's query blocks, so
+the pipeline fetches them once a head: 2 × 512 KB at the token trunk's
+2048 keys of 128 bfloat16), and the step WALKS them in key blocks with a
+running row max, row sum and float32 accumulator (online softmax). The
+score tile is then (block_q, block_k) whatever the key length, so the
+query block can grow until it fills a grid step (a quarter of the grid
+steps, and a score tile that stays a quarter of the size: on v5e that is
+the gain, 2.0 → 1.5 ms at the trunk's shapes, 91 % of what the two
+products take at the MXU's peak). The walk is unrolled at trace time —
+static slices, and block j+1's q·kᵀ does not wait for block j's softmax;
+as a `fori_loop` it measured slower than no blocking at all.
+`forward_blocks` chooses both blocks from the operands' shapes alone.
+Where one key block covers the padded key axis — every X-UNet preset:
+attention runs at {8,16,32}² ⇒ L ≤ 1024 tokens — the walk has one
+iteration, no running statistic is rescaled, and the body is the plain
+max → exp → sum → p·v over a (block_q, Lk) tile. The token trunk
+(models/token_denoiser.py) attends one frame's 1024 queries to 2048 keys
+and takes the blocked form (PERF.md §6, PR 29, has the table that set the
+blocks).
 
 Layout notes (pallas_guide.md "Tiling Constraints"):
   - lanes (last dim) padded to a multiple of 128; sublanes to the dtype
@@ -18,11 +34,13 @@ Layout notes (pallas_guide.md "Tiling Constraints"):
 
 The backward pass is a custom VJP using the standard flash-attention
 residuals (out, logsumexp): probabilities are recomputed from q·k and lse —
-no (L, L) tensor is saved between forward and backward. For head_dim ≥
-_PALLAS_BWD_MIN_HEAD_DIM the backward runs as two blocked Pallas kernels
-(_dq_kernel over query blocks, _dkv_kernel over kv blocks — scores never
-leave VMEM); below that, lane padding (D → 128) wastes more MXU than VMEM
-residency saves, and an XLA einsum backward (_flash_bwd_xla) is used
+no (L, L) tensor is saved between forward and backward. Only the VJP's
+forward writes lse; the primal call (a sampler takes no gradient) leaves
+that output out, a kernel's output being nothing XLA can eliminate. For
+head_dim ≥ _PALLAS_BWD_MIN_HEAD_DIM the backward runs as two blocked Pallas
+kernels (_dq_kernel over query blocks, _dkv_kernel over kv blocks — scores
+never leave VMEM); below that, lane padding (D → 128) wastes more MXU than
+VMEM residency saves, and an XLA einsum backward (_flash_bwd_xla) is used
 instead (measured on v5e at D=16: ~20% faster train step).
 
 Falls back to interpreter mode off-TPU so the same code path is unit-tested
@@ -55,44 +73,100 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
     return jnp.pad(x, widths)
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
-                 kv_len: int):
-    """One query block vs. the full key/value sequence, entirely in VMEM.
+# The forward's blocks (`forward_blocks`): past _SINGLE_BLOCK_KEYS padded
+# keys a grid step walks the key axis _BLOCK_K at a time and takes up to
+# _BLOCKED_Q query rows; up to it, one block is the key axis and the query
+# block is the _BLOCK_Q it always was (the backward kernels' too).
+_BLOCK_Q = 256
+_SINGLE_BLOCK_KEYS = 1024
+_BLOCK_K = 512
+_BLOCKED_Q = 1024
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def forward_blocks(Lq: int, Lk: int, D: int, itemsize: int,
+                   block_q: int = _BLOCKED_Q) -> tuple[int, int, int]:
+    """(block_q, block_k, key_blocks) of the forward kernel for q (·, Lq, D)
+    against k/v (·, Lk, D) of `itemsize` bytes an element: the whole of the
+    choice, a function of shapes (the kernel's wrapper calls it).
+
+    `key_blocks` > 1 is the blocked form (a running max and sum over
+    `block_k` keys at a time); 1 is the plain body over the padded key
+    axis. `block_q` caps the query block (rounded up to the 16 sublanes
+    that cover the f32 and bf16 tile minima)."""
+    # Neither moves the choice at a shape a caller has: the table that set
+    # the blocks read D 128 and 256 in bfloat16 (PERF.md §6, PR 29).
+    del D, itemsize
+    lk = _round_up(Lk, 128)
+    if lk <= _SINGLE_BLOCK_KEYS:
+        bq, bk = _BLOCK_Q, lk
+    else:
+        bq, bk = _BLOCKED_Q, _BLOCK_K
+    bq = min(bq, _round_up(block_q, 16), max(16, _round_up(Lq, 16)))
+    return bq, bk, _round_up(lk, bk) // bk
+
+
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, scale: float,
+                 kv_len: int, block_k: int):
+    """One query block vs. one (batch, head)'s keys and values, all in VMEM,
+    the key axis walked `block_k` at a time (unrolled: static slices).
 
     q_ref (1, Bq, D) · k_ref/v_ref (1, Lk_pad, D) · o_ref (1, Bq, D) ·
-    lse_ref (1, Bq, 128) — lse broadcast across the lane dim to satisfy the
-    TPU (sublane, lane) tiling constraint on output blocks.
-    `kv_len` is the true (unpadded) kv length — static.
+    `lse_out` is empty or one more output ref (1, Bq, 128) — lse broadcast
+    across the lane dim to satisfy the TPU (sublane, lane) tiling
+    constraint on output blocks. `kv_len` is the true (unpadded) kv length
+    — static; the block that holds the boundary masks its padded columns
+    (padding never fills a whole block, so no block's max is the mask's).
     """
     q = q_ref[0]
-    k = k_ref[0]
-    s = jax.lax.dot_general(
-        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    if kv_len < k.shape[0]:  # mask padded kv columns (static condition)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < kv_len, s, _NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    o_ref[0] = (o / l).astype(o_ref.dtype)
-    lse = m + jnp.log(l)  # (Bq, 1)
-    lse_ref[0] = jnp.broadcast_to(lse, (lse.shape[0], lse_ref.shape[-1]))
+    m = l = acc = None
+    for lo in range(0, k_ref.shape[1], block_k):
+        s = jax.lax.dot_general(
+            q, k_ref[0, lo:lo + block_k, :],
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if kv_len < lo + block_k:  # mask padded kv columns (static)
+            col = lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col < kv_len, s, _NEG_INF)
+        m_blk = jnp.max(s, axis=-1, keepdims=True)
+        m_new = m_blk if m is None else jnp.maximum(m, m_blk)
+        p = jnp.exp(s - m_new)
+        l_blk = jnp.sum(p, axis=-1, keepdims=True)
+        o_blk = jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, lo:lo + block_k, :],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if m is None:
+            l, acc = l_blk, o_blk
+        else:
+            alpha = jnp.exp(m - m_new)
+            l, acc = alpha * l + l_blk, alpha * acc + o_blk
+        m = m_new
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    for lse_ref in lse_out:
+        lse = m + jnp.log(l)  # (Bq, 1)
+        lse_ref[0] = jnp.broadcast_to(lse, (lse.shape[0], lse_ref.shape[-1]))
 
 
 def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
-                      interpret: bool):
-    """q (N, Lq_pad, Dp) · k,v (N, Lk_pad, Dp) → (out, lse)."""
+                      block_k: int, with_lse: bool, interpret: bool):
+    """q (N, Lq_pad, Dp) · k,v (N, Lk_pad, Dp) → (out, lse or None)."""
     N, Lq, D = q.shape
     Lk = k.shape[1]
     grid = (N, Lq // block_q)
-    kernel = functools.partial(_attn_kernel, scale=scale, kv_len=kv_len)
+    kernel = functools.partial(_attn_kernel, scale=scale, kv_len=kv_len,
+                               block_k=block_k)
     mem = {} if interpret else {"memory_space": _pallas.VMEM}
-    out, lse = pl.pallas_call(
+    out_specs = [pl.BlockSpec((1, block_q, D), lambda n, i: (n, i, 0), **mem)]
+    out_shape = [jax.ShapeDtypeStruct((N, Lq, D), q.dtype)]
+    if with_lse:
+        out_specs.append(
+            pl.BlockSpec((1, block_q, 128), lambda n, i: (n, i, 0), **mem))
+        out_shape.append(jax.ShapeDtypeStruct((N, Lq, 128), jnp.float32))
+    out, *lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -100,18 +174,12 @@ def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
             pl.BlockSpec((1, Lk, D), lambda n, i: (n, 0, 0), **mem),
             pl.BlockSpec((1, Lk, D), lambda n, i: (n, 0, 0), **mem),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda n, i: (n, i, 0), **mem),
-            pl.BlockSpec((1, block_q, 128), lambda n, i: (n, i, 0), **mem),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((N, Lq, D), q.dtype),
-            jax.ShapeDtypeStruct((N, Lq, 128), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
-    return out, lse[:, :, 0]
+    return out, (lse[0][:, :, 0] if with_lse else None)
 
 
 def _use_interpret() -> bool:
@@ -126,12 +194,14 @@ def resolve_flash(flag) -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_attention(q, k, v, scale: float, block_q: int):
-    out, _ = _flash_fwd_core(q, k, v, scale, block_q)
+    out, _ = _flash_fwd_core(q, k, v, scale, block_q, with_lse=False)
     return out
 
 
-def _flash_fwd_core(q, k, v, scale: float, block_q: int):
-    """(B, L, H, D) inputs → padded kernel call → unpadded (out, lse)."""
+def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
+                    with_lse: bool):
+    """(B, L, H, D) inputs → padded kernel call → unpadded (out, lse);
+    lse is None unless asked for."""
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
     interpret = _use_interpret()
@@ -139,27 +209,25 @@ def _flash_fwd_core(q, k, v, scale: float, block_q: int):
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
     kt = k.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
     vt = v.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
-    # Query block: a multiple of 16 sublanes (covers the f32 and bf16 tile
-    # minima) no larger than the padded query length. User-supplied block_q
-    # is rounded up so any value Mosaic-compiles on hardware.
-    block_q = ((block_q + 15) // 16) * 16
-    bq = min(block_q, max(16, ((Lq + 15) // 16) * 16))
+    bq, bk, _ = forward_blocks(Lq, Lk, D, q.dtype.itemsize, block_q)
     qt = _pad_to(qt, 1, bq)
-    kt = _pad_to(kt, 1, 128)
-    vt = _pad_to(vt, 1, 128)
+    kt = _pad_to(kt, 1, bk)
+    vt = _pad_to(vt, 1, bk)
     if not interpret:  # lane alignment for the MXU
         qt = _pad_to(qt, 2, 128)
         kt = _pad_to(kt, 2, 128)
         vt = _pad_to(vt, 2, 128)
     out, lse = _flash_fwd_padded(qt, kt, vt, scale=scale, kv_len=Lk,
-                                 block_q=bq, interpret=interpret)
+                                 block_q=bq, block_k=bk, with_lse=with_lse,
+                                 interpret=interpret)
     out = out[:, :Lq, :D].reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
-    lse = lse[:, :Lq].reshape(B, H, Lq)
+    if with_lse:
+        lse = lse[:, :Lq].reshape(B, H, Lq)
     return out, lse
 
 
 def _flash_vjp_fwd(q, k, v, scale: float, block_q: int):
-    out, lse = _flash_fwd_core(q, k, v, scale, block_q)
+    out, lse = _flash_fwd_core(q, k, v, scale, block_q, with_lse=True)
     return out, (q, k, v, out, lse)
 
 
@@ -209,9 +277,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dk_ref,
         preferred_element_type=jnp.float32).astype(dk_ref.dtype)
 
 
-def _flash_bwd_pallas(q, k, v, out, lse, g, scale: float, block_q: int):
+def _flash_bwd_pallas(q, k, v, out, lse, g, scale: float,
+                      block_q: int):
     """Blocked Pallas backward: one pass for dq (grid over q blocks), one
     for dk/dv (grid over kv blocks); no (Lq, Lk) tensor ever leaves VMEM."""
+    block_q = min(block_q, _BLOCK_Q)
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
     interpret = _use_interpret()
@@ -336,10 +406,12 @@ _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     scale: Optional[float] = None,
-                    block_q: int = 256) -> jnp.ndarray:
+                    block_q: int = _BLOCKED_Q) -> jnp.ndarray:
     """Fused softmax(q·kᵀ/√D)·v. q (B, Lq, H, D), k/v (B, Lk, H, D).
 
     Drop-in for `flax.linen.dot_product_attention` (same layout/scaling).
+    `block_q` is an upper bound on the query (and the backward's kv)
+    block; under it the shapes choose (`forward_blocks`).
     """
     D = q.shape[-1]
     scale = float(D ** -0.5) if scale is None else float(scale)

@@ -11,11 +11,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from novel_view_synthesis_3d_tpu.ops.flash_attention import flash_attention
+from novel_view_synthesis_3d_tpu.ops import flash_attention as fa
+from novel_view_synthesis_3d_tpu.ops.flash_attention import (
+    flash_attention, forward_blocks)
 
 
 def _ref_attention(q, k, v):
     return nn.dot_product_attention(q, k, v)
+
+
+def _qkv(seed, B, Lq, Lk, H, D, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (B, Lq, H, D), dtype),
+            jax.random.normal(ks[1], (B, Lk, H, D), dtype),
+            jax.random.normal(ks[2], (B, Lk, H, D), dtype))
 
 
 @pytest.mark.parametrize(
@@ -24,25 +33,112 @@ def _ref_attention(q, k, v):
         (2, 64, 64, 4, 8),     # tiny64 self-attn shape class
         (1, 100, 300, 2, 16),  # ragged lengths → padding/masking path
         (2, 256, 256, 4, 64),
+        # Past 1024 padded keys a grid step walks the key axis in blocks
+        # (forward_blocks): a whole multiple of the key block; a ragged Lk
+        # whose padding boundary falls inside the last block; Lq != Lk
+        # with a ragged Lq too.
+        (1, 128, 2048, 2, 16),
+        (1, 64, 1300, 2, 16),
+        (2, 100, 1536, 1, 32),
     ],
 )
 def test_matches_xla_attention(B, Lq, Lk, H, D):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (B, Lq, H, D))
-    k = jax.random.normal(ks[1], (B, Lk, H, D))
-    v = jax.random.normal(ks[2], (B, Lk, H, D))
+    q, k, v = _qkv(0, B, Lq, Lk, H, D)
+    assert (forward_blocks(Lq, Lk, D, 4)[2] > 1) == (Lk > 1024)
     out = flash_attention(q, k, v, block_q=64)
     ref = _ref_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 
 
-def test_gradients_match_xla():
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    B, L, H, D = 1, 48, 2, 8
-    q = jax.random.normal(ks[0], (B, L, H, D))
-    k = jax.random.normal(ks[1], (B, L, H, D))
-    v = jax.random.normal(ks[2], (B, L, H, D))
+def test_blocked_form_in_bfloat16_is_as_close_as_the_single_block():
+    """The token trunk's dtype and head width: against float32 attention
+    over the same bfloat16 operands, the walk over key blocks may be off
+    by no more than the one-block body is (its rescaling is float32; both
+    round p to bfloat16 before p·v), with a little room for the order of
+    the sums."""
+    B, Lq, Lk, H, D = 1, 128, 2048, 2, 128
+    q, k, v = _qkv(4, B, Lq, Lk, H, D, jnp.bfloat16)
+    want = _ref_attention(*(x.astype(jnp.float32) for x in (q, k, v)))
+    to_nld = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, -1, D)
+
+    def err(block_k):
+        out, _ = fa._flash_fwd_padded(
+            to_nld(q), to_nld(k), to_nld(v), scale=D ** -0.5, kv_len=Lk,
+            block_q=Lq, block_k=block_k, with_lse=False, interpret=True)
+        out = out.reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
+        return float(jnp.max(jnp.abs(out.astype(jnp.float32) - want)))
+
+    bq, bk, key_blocks = forward_blocks(Lq, Lk, D, 2)
+    assert key_blocks == Lk // bk > 1
+    single = err(Lk)
+    assert 0 < single < 0.02  # bfloat16's own rounding of the output
+    assert err(bk) <= 1.5 * single
+    shipped = flash_attention(q, k, v).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(shipped - want))) <= 1.5 * single
+
+
+@pytest.mark.parametrize("Lq,Lk", [(96, 1280), (64, 1100), (48, 200)])
+def test_lse_is_the_reference_logsumexp(Lq, Lk):
+    """What the backward kernels read: lse = log Σ exp(scale · q·kᵀ) over
+    the true keys, from the blocked form (running max and sum) as from the
+    one-block body; the primal call does not write it."""
+    B, H, D = 1, 2, 16
+    q, k, v = _qkv(5, B, Lq, Lk, H, D)
+    scale = D ** -0.5
+    out, lse = fa._flash_fwd_core(q, k, v, scale, 32, with_lse=True)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.scipy.special.logsumexp(s, -1)),
+        atol=1e-5, rtol=1e-5)
+    primal, none = fa._flash_fwd_core(q, k, v, scale, 32, with_lse=False)
+    assert none is None
+    np.testing.assert_array_equal(np.asarray(primal), np.asarray(out))
+
+
+def _xunet_attention_shapes():
+    """(preset, tokens, head width) of every attention the X-UNet presets
+    run: self and cross attention both see one frame's H·W tokens."""
+    from novel_view_synthesis_3d_tpu.config import get_preset
+
+    shapes = []
+    for name in ("tiny64", "base128", "paper256", "pod64"):
+        cfg = get_preset(name)
+        m = cfg.model
+        for level, mult in enumerate(m.ch_mult):
+            res = cfg.data.img_sidelength >> level
+            if res in m.attn_resolutions:
+                shapes.append((name, res * res, m.ch * mult // m.attn_heads))
+    return shapes
+
+
+def test_forward_blocks_at_the_shapes_that_run():
+    """The mechanism's counter: whether the key axis is walked in blocks
+    is a function of shapes. The token cell's steps (1024 queries, 2048
+    keys) take the blocked form with a query block that fills the frame;
+    its once-a-call pass (1024 keys) and every X-UNet preset keep the
+    one-block body at the query block they had."""
+    assert forward_blocks(1024, 2048, 128, 2) == (1024, 512, 4)
+    assert forward_blocks(1024, 1024, 128, 2) == (256, 1024, 1)
+    shapes = _xunet_attention_shapes()
+    assert {(L, D) for _, L, D in shapes} == {
+        (1024, 16), (1024, 64), (256, 128), (1024, 256), (256, 256)}
+    for name, L, D in shapes:
+        bq, bk, key_blocks = forward_blocks(L, L, D, 2)
+        assert (bq, bk, key_blocks) == (min(256, L), max(128, L), 1), name
+    # block_q= stays an upper bound, rounded up to 16 sublanes.
+    assert forward_blocks(1024, 2048, 128, 2, block_q=200) == (208, 512, 4)
+    assert forward_blocks(100, 300, 16, 4, block_q=64) == (64, 384, 1)
+    assert forward_blocks(40, 1300, 16, 4) == (48, 512, 3)
+
+
+@pytest.mark.parametrize("L,Lk", [(48, 48), (40, 1100)])
+def test_gradients_match_xla(L, Lk):
+    """The second case's forward is the blocked form (three key blocks,
+    the last one ragged): the backward kernels read its lse."""
+    B, H, D = 1, 2, 8
+    q, k, v = _qkv(1, B, L, Lk, H, D)
+    assert (forward_blocks(L, Lk, D, 4)[2] > 1) == (Lk > 1024)
 
     def f_flash(q, k, v):
         return jnp.sum(jnp.sin(flash_attention(q, k, v, block_q=16)))

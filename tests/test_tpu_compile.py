@@ -121,7 +121,11 @@ CASES = {
        _attn(flash_attention.flash_attention, L, hd, g)
        for g in (False, True)
        for L, hd in ((1024, 64), (256, 128), (1024, 256))},
+    # The token trunk's two shapes: a step's 2048 keys take the forward's
+    # blocked form, the once-a-call pass's 1024 the one-block body
+    # (test_token_trunk_shapes_compile_in_both_forms).
     "flash_fwd_Lq1024_Lk2048_d128": _rect_attn(1024, 2048, 32, 128),
+    "flash_fwd_Lq1024_Lk1024_d128": _rect_attn(1024, 1024, 32, 128),
     "grouped_matmul_up_4096x2048": _grouped(32768, 32, 4096, 2048),
     "grouped_matmul_down_2048x4096": _grouped(32768, 32, 2048, 4096),
     **{f"serving_attention_L{L}_d{hd}":
@@ -188,6 +192,25 @@ def test_flash_kernels_are_distinct_instructions(v5e, monkeypatch):
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert sum(kernel in c for c in calls) == 1, (kernel, calls)
     assert len(set(calls)) == len(calls) == 3
+
+
+def test_token_trunk_shapes_compile_in_both_forms(v5e, monkeypatch):
+    """The two `flash_fwd_Lq1024_*` cases are the forward's two forms
+    (`forward_blocks`), each compiled above with the blocks it ships with
+    and inside the scoped VMEM the kernel asks for (the compiler refuses a
+    kernel that needs more); a sampler's call writes no lse, so its custom
+    call has the one output."""
+    import re
+
+    assert flash_attention.forward_blocks(1024, 2048, 128, 2)[2] > 1
+    assert flash_attention.forward_blocks(1024, 1024, 128, 2)[2] == 1
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    for case in ("flash_fwd_Lq1024_Lk2048_d128",
+                 "flash_fwd_Lq1024_Lk1024_d128"):
+        text = _lowered(case, v5e).compile().as_text()
+        (call,) = re.findall(r"^\s*%flash_fwd\S* = (.*) custom-call\(",
+                             text, re.M)
+        assert call.startswith("bf16[64,1024,128]"), (case, call)
 
 
 def test_flash_compiles_under_a_four_chip_data_mesh(v5e_devices,

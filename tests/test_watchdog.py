@@ -223,10 +223,17 @@ def test_data_stall_fires_watchdog_and_exits(srn_root, tmp_path,
                                              monkeypatch):
     from novel_view_synthesis_3d_tpu.train.trainer import Trainer
 
-    # Fetch ordinal 2 = mid-run host batch fetch (0 feeds the cold start).
-    monkeypatch.setenv("NVS3D_FI_STALL_DATA_AT", "2:6")
+    # A mid-run host batch fetch the loop cannot ride out, whatever the
+    # machine's speed: at `data.prefetch` 1 the producer reaches fetch
+    # ordinal 4 only once the loop has taken batch 2 (the cold start is
+    # over), and one buffered batch is all the loop has before it waits.
+    # With the default buffer of 4 and the stall at ordinal 2, a host
+    # with slow compiles and saves spent the whole 6 s on buffered
+    # batches and the drill never fired (PR 29's sandbox, parent too).
+    monkeypatch.setenv("NVS3D_FI_STALL_DATA_AT", "4:12")
     cfg = _cfg(srn_root, tmp_path,
                wd=WatchdogConfig(data_fetch_s=2.0, check_interval_s=0.25))
+    cfg = cfg.override(**{"data.prefetch": 1})
     tr = Trainer(config=cfg, use_grain=False)
     tr.train()
     assert tr.stalled and 0 < tr.step < 8
