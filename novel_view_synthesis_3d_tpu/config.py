@@ -71,6 +71,71 @@ class TokenTrunkConfig:
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    # What the expert layer both trunks share (models/token_denoiser.py:
+    # `route`, `held_expert_part`) reads besides the fields above.
+    expert_activation = "silu"
+
+
+_WINDOW_PERIOD = (0, 1, 1, 1)
+
+
+@dataclasses.dataclass(frozen=True)
+class SmallThinkerTrunkConfig:
+    """The token denoiser's second trunk: SmallThinker-21BA3B-Instruct's
+    decoder layer under the key names of its `config.json` — grouped-query
+    attention, per layer rotary or no positional term (`rope_layout`) and
+    a one-sided window or none (`sliding_window_layout`), a router that
+    reads the attention's input, ReGLU experts, no shared expert. The
+    defaults are the published values; a preset sets the depth."""
+
+    hidden_size: int = 2560
+    num_hidden_layers: int = 52
+    num_attention_heads: int = 28
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    max_position_embeddings: int = 16384
+    # Per layer, 1 = rotary on q and k / a window of sliding_window_size;
+    # 0 = no positional term at all / every key of the sequence.
+    rope_layout: Tuple[int, ...] = _WINDOW_PERIOD * 13
+    sliding_window_layout: Tuple[int, ...] = _WINDOW_PERIOD * 13
+    sliding_window_size: int = 4096
+    rope_theta: float = 1500000
+    moe_num_primary_experts: int = 64
+    moe_num_active_primary_experts: int = 6
+    moe_ffn_hidden_size: int = 768
+    moe_primary_router_apply_softmax: bool = True
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    # As TokenTrunkConfig's: the (first, count) experts this chip holds,
+    # and the patch adapter.
+    held_experts: Tuple[int, int] = (0, 64)
+    patch_size: int = 4
+
+    # The expert layer's names for the same things (`route`,
+    # `held_expert_part` are one function each for both trunks).
+    expert_activation = "relu"
+    routed_scaling_factor = 1.0
+
+    @property
+    def n_routed_experts(self) -> int:
+        return self.moe_num_primary_experts
+
+    @property
+    def num_experts_per_tok(self) -> int:
+        return self.moe_num_active_primary_experts
+
+
+# The trunks `ModelConfig.tokens` may hold; a serialized config says
+# which by its keys (the two share only sizes every trunk has).
+TOKEN_TRUNKS = (TokenTrunkConfig, SmallThinkerTrunkConfig)
+
+
+def _trunk_of_keys(keys) -> type:
+    for tp in TOKEN_TRUNKS:
+        if set(keys) <= {f.name for f in dataclasses.fields(tp)}:
+            return tp
+    raise KeyError(f"model.tokens: no trunk has the keys {sorted(keys)}")
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -169,7 +234,7 @@ class ModelConfig:
     # the trunk that `tokens` describes; of the fields above it reads
     # dtype, param_dtype, num_cond_frames and use_flash_attention).
     family: str = "xunet"
-    tokens: TokenTrunkConfig = None
+    tokens: Any = None   # one of TOKEN_TRUNKS
 
     @property
     def num_frames(self) -> int:
@@ -1686,6 +1751,9 @@ class Config:
                 ftype = fields[k].type
                 if isinstance(ftype, str):  # from __future__ annotations
                     ftype = globals().get(ftype, ftype)
+                if tp is ModelConfig and k == "tokens" and isinstance(
+                        v, dict):
+                    ftype = _trunk_of_keys(v)
                 if dataclasses.is_dataclass(ftype) and isinstance(v, dict):
                     # Nested sub-config (e.g. TrainConfig.watchdog): rebuild
                     # the dataclass so dotted overrides round-trip through
@@ -1843,7 +1911,21 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
         errors.append(
             f"data.img_sidelength={d.img_sidelength} is not a multiple of "
             f"model.tokens.patch_size={k.patch_size}")
-    if k.qk_rope_head_dim % 2:
+    if isinstance(k, SmallThinkerTrunkConfig):
+        if k.head_dim % 2:
+            errors.append("model.tokens.head_dim must be even (rotary "
+                          "pairs)")
+        if k.num_attention_heads % k.num_key_value_heads:
+            errors.append(
+                f"model.tokens.num_attention_heads={k.num_attention_heads} "
+                f"is not a multiple of num_key_value_heads="
+                f"{k.num_key_value_heads}")
+        for name in ("rope_layout", "sliding_window_layout"):
+            if len(getattr(k, name)) < k.num_hidden_layers:
+                errors.append(
+                    f"model.tokens.{name} has {len(getattr(k, name))} "
+                    f"entries for {k.num_hidden_layers} layers")
+    elif k.qk_rope_head_dim % 2:
         errors.append("model.tokens.qk_rope_head_dim must be even (rotary "
                       "pairs)")
     if m.num_cond_frames != 1:
@@ -1856,7 +1938,7 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
 # Config ladder presets (BASELINE.json "configs")
 # ----------------------------------------------------------------------
 PRESET_NAMES = ("reference", "tiny64", "base128", "paper256", "pod64",
-                "ms4_denoiser128")
+                "ms4_denoiser128", "st21_denoiser256")
 
 
 def get_preset(name: str) -> Config:
@@ -1947,6 +2029,22 @@ def get_preset(name: str) -> Config:
                 tokens=TokenTrunkConfig(num_hidden_layers=6,
                                         held_experts=(0, 32))),
             data=DataConfig(img_sidelength=128),
+            diffusion=DiffusionConfig(sample_timesteps=256),
+        )
+    if name == "st21_denoiser256":
+        # A token denoiser whose trunk is SmallThinker-21BA3B-Instruct's
+        # decoder layer at its published widths (SmallThinkerTrunkConfig's
+        # defaults), cut to one pipeline stage that holds every expert of
+        # its layers: layers 0-11 of 52, three periods of [full attention
+        # without a positional term, window 4096 with rotary x 3]. 256 px,
+        # 4096 tokens a frame: the size at which the published window
+        # binds. bfloat16 parameters: 0.80 GB a layer, 9.6 GB in all.
+        return Config(
+            model=ModelConfig(
+                family="tokens", dtype="bfloat16", param_dtype="bfloat16",
+                dropout=0.0,
+                tokens=SmallThinkerTrunkConfig(num_hidden_layers=12)),
+            data=DataConfig(img_sidelength=256),
             diffusion=DiffusionConfig(sample_timesteps=256),
         )
     raise KeyError(f"unknown preset {name!r}")

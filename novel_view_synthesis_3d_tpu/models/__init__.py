@@ -16,8 +16,13 @@
     module.
 
 `model.family` selects: "xunet" (models/xunet.XUNet, the default) or
-"tokens" (models/token_denoiser.TokenDenoiser). Entry points that carry
-only the X-UNet say so through `require_family`.
+"tokens" (models/token_denoiser.TokenDenoiser). The token family has two
+trunks behind that one class — `model.tokens` is one of
+config.TOKEN_TRUNKS and names the layer: Mistral-Small-4's (latent
+attention, a shared expert; its cache entry a latent) or SmallThinker's
+(grouped-query heads, a window and rotary per layer, the router ahead of
+attention; its cache entry keys and values). Entry points that carry only
+the X-UNet say so through `require_family`.
 """
 
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays  # noqa: F401

@@ -1,31 +1,48 @@
 """A token denoiser: patch tokens of both frames through a decoder trunk
-with latent attention (MLA) and sparse experts, ε̂ of the target frame out.
+of a published language model, ε̂ of the target frame out.
 
-The trunk's layer is Mistral-Small-4-119B-2603's (`mistral4` config.json;
-config.TokenTrunkConfig holds its keys): RMSNorm → low-rank queries and a
-compressed key/value latent with one shared rotary key head → softmax
-attention → RMSNorm → a router over ALL `n_routed_experts`, top-k,
-renormalised → gated-SiLU experts plus one shared expert. What is this
-repo's and not the source's is the frame around it:
+**Two trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
+and names the layer:
+
+  - `Mistral4Layer` (config.TokenTrunkConfig; Mistral-Small-4-119B-2603,
+    `mistral4` config.json): RMSNorm → low-rank queries and a compressed
+    key/value latent with one shared rotary key head (yarn) → softmax
+    attention → RMSNorm → a router over ALL `n_routed_experts`, top-k,
+    renormalised → gated-SiLU experts plus one shared expert. Its cache
+    of a frame is the latent: (normalised c_kv, rotated shared key).
+  - `SmallThinkerLayer` (config.SmallThinkerTrunkConfig;
+    SmallThinker-21BA3B-Instruct): RMSNorm → the ROUTER's logits, taken
+    from the attention's normalised input, so a token's experts are known
+    before attention runs → grouped-query attention (28 query heads on 4
+    key/value heads), per layer rotary or no positional term at all
+    (`rope_layout`) and a one-sided window or none
+    (`sliding_window_layout`) → RMSNorm → ReGLU experts, top-k of the
+    router's softmax renormalised, no shared expert. Its cache of a frame
+    is that frame's keys (rotated where the layer rotates) and values.
+
+`route` and `held_expert_part` are one function each for both (top-k,
+the renormalisation and the activation come from the trunk's config), as
+are the grouped product and the attention kernel under them. What is this
+repo's and not a source's is the frame around the trunk:
 
   - both frames are cut into `patch_size`² patches, one token each:
     token = Dense(patch) + Dense(posenc of the patch's rays)·cond_mask +
     the logsnr embedding (the X-UNet's two-layer MLP on `posenc_ddpm`);
     the conditioning frame's tokens take it at logsnr 20, the clean frame;
-  - the sequence is [conditioning frame, target frame], a token's rotary
+  - the sequence is [conditioning frame, target frame], a token's
     position its index in it, and a token sees its own frame and the
-    frames before it — so the conditioning frame's tokens never depend on
-    z_t or the step;
+    frames before it (in a window layer: those of them less than a
+    window behind it) — so the conditioning frame's tokens never depend
+    on z_t or the step;
   - the last RMSNorm is followed by Dense(hidden → patch pixels) on the
     target's tokens, un-patched to ε̂ (B, H, W, 3). No vocabulary.
 
 **The once-a-call pass.** Because of that mask, everything a step needs of
-the conditioning frame is its per-layer latent cache — the normalised
-c_kv (kv_lora_rank) and the rotated shared key (qk_rope_head_dim) of each
-of its tokens. `precompute` runs the conditioning frame through all layers
-once (prefill) and every denoise step runs the target's tokens alone
-against [cache ; own] (decode through the latent cache). `apply` without a
-cache does exactly the two in a row, so there is one set of equations.
+the conditioning frame is its per-layer cache. `precompute` runs the
+conditioning frame through all layers once (prefill) and every denoise
+step runs the target's tokens alone against [cache ; own] (decode through
+the cache). `apply` without a cache does exactly the two in a row, so
+there is one set of equations.
 
 **The expert layer is told which experts it holds** (`held_experts`, a
 (first, count) range: this chip's share of an expert-parallel deployment).
@@ -51,10 +68,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from novel_view_synthesis_3d_tpu.config import ModelConfig, TokenTrunkConfig
+from novel_view_synthesis_3d_tpu.config import (
+    ModelConfig, SmallThinkerTrunkConfig, TokenTrunkConfig)
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
-    flash_attention, resolve_flash)
+    band_key_columns, flash_attention, resolve_flash, window_binds)
 from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
     ROW_TILE, buffer_rows, grouped_matmul, span_sizes)
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
@@ -148,33 +166,13 @@ def param_shapes(cfg: ModelConfig) -> dict:
     an expert stack is (held, in, out). No biases in the trunk."""
     k = cfg.tokens
     dt = jnp.dtype(cfg.param_dtype)
-    H, NH = k.hidden_size, k.num_attention_heads
-    held, inter = k.held_experts[1], k.moe_intermediate_size
+    H = k.hidden_size
     pix = 3 * k.patch_size ** 2
 
     def w(*shape):
         return jax.ShapeDtypeStruct(shape, dt)
 
-    def mlp(width, *lead):
-        return {"gate": {"kernel": w(*lead, H, width)},
-                "up": {"kernel": w(*lead, H, width)},
-                "down": {"kernel": w(*lead, width, H)}}
-
-    layer = {
-        "attn_norm": {"scale": w(H)},
-        "q_a": {"kernel": w(H, k.q_lora_rank)},
-        "q_norm": {"scale": w(k.q_lora_rank)},
-        "q_b": {"kernel": w(k.q_lora_rank, NH * k.qk_head_dim)},
-        "kv_a": {"kernel": w(H, k.kv_lora_rank + k.qk_rope_head_dim)},
-        "kv_norm": {"scale": w(k.kv_lora_rank)},
-        "kv_b": {"kernel": w(k.kv_lora_rank,
-                             NH * (k.qk_nope_head_dim + k.v_head_dim))},
-        "o": {"kernel": w(NH * k.v_head_dim, H)},
-        "mlp_norm": {"scale": w(H)},
-        "router": {"kernel": w(H, k.n_routed_experts)},
-        "shared": mlp(k.moe_intermediate_size * k.n_shared_experts),
-        "experts": mlp(inter, held),
-    }
+    layer = trunk_layer(cfg).param_shapes(w)
     tree = {
         "patch_in": {"kernel": w(pix, H)},
         "ray_in": {"kernel": w(RAY_CHANNELS * k.patch_size ** 2, H)},
@@ -186,6 +184,12 @@ def param_shapes(cfg: ModelConfig) -> dict:
     for i in range(k.num_hidden_layers):
         tree[layer_label(i)] = layer
     return tree
+
+
+def _mlp_shapes(w, hidden, width, *lead):
+    return {"gate": {"kernel": w(*lead, hidden, width)},
+            "up": {"kernel": w(*lead, hidden, width)},
+            "down": {"kernel": w(*lead, width, hidden)}}
 
 
 def _init_leaf(key, path, s):
@@ -213,22 +217,43 @@ def _dense(x, p):
     return jnp.dot(x, p["kernel"].astype(x.dtype))
 
 
-def _attention(q, k, v, scale, use_flash):
-    """softmax(q·kᵀ·scale)·v, softmax in float32, no mask (the caller
-    hands each frame's queries the keys they may see). q (B, Lq, N, D),
-    k/v (B, Lk, N, D)."""
+def _attention(q, k, v, scale, use_flash, window=None):
+    """softmax(q·kᵀ·scale)·v, softmax in float32. q (B, Lq, N, D), k/v
+    (B, Lk, Nkv, D), query head n on key/value head n // (N // Nkv). The
+    queries are the last Lq positions of the key axis. No mask but the
+    window's (the caller hands each frame's queries the keys of the frames
+    they may see): with `window`, a query at p sees key j iff j > p −
+    window."""
     if use_flash:
-        return flash_attention(q, k, v, scale=scale)
-    s = jnp.einsum("bqnd,bknd->bnqk", q, k,
+        return flash_attention(q, k, v, scale=scale, window=window)
+    B, Lq, N, D = q.shape
+    Lk, Nkv = k.shape[1], k.shape[2]
+    binds = window_binds(Lq, window, Lk - Lq)
+    if N == Nkv and not binds:
+        s = jnp.einsum("bqnd,bknd->bnqk", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bnqk,bknd->bqnd", p, v)
+    qg = q.reshape(B, Lq, Nkv, N // Nkv, D)
+    s = jnp.einsum("bqngd,bknd->bngqk", qg, k,
                    preferred_element_type=jnp.float32) * scale
+    if binds:
+        seen = np.arange(Lk)[None] > (Lk - Lq + np.arange(Lq))[:, None] \
+            - window
+        s = jnp.where(jnp.asarray(seen), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("bnqk,bknd->bqnd", p, v)
+    return jnp.einsum("bngqk,bknd->bqngd", p, v).reshape(B, Lq, N, D)
 
 
-def route(b32, p_router, k: TokenTrunkConfig):
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def route(b32, p_router, k):
     """(top-k probabilities (T, k) float32, expert ids (T, k) int32) of
     normalised tokens b32 (T, H) float32: softmax over ALL experts' logits
-    in float32, top-k, renormalised to sum 1 where the config says so."""
+    in float32, top-k, renormalised to sum 1 where the config says so
+    (the same numbers as a softmax over the chosen logits alone). `k` is
+    either trunk's config."""
     logits = jnp.dot(b32, p_router["kernel"].astype(jnp.float32),
                      precision=HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -238,10 +263,11 @@ def route(b32, p_router, k: TokenTrunkConfig):
     return top_p * float(k.routed_scaling_factor), top_i.astype(jnp.int32)
 
 
-def held_expert_part(b, top_p, top_i, p_experts, k: TokenTrunkConfig):
+def held_expert_part(b, top_p, top_i, p_experts, k):
     """Σ over a token's top-k experts THAT ARE HELD HERE of p_e·expert_e(b),
     and the tokens each held expert was given (held,) int32 — the true
-    counts, not the spans'.
+    counts, not the spans'. expert_e(b) = down(act(gate·b) ⊙ up·b), `act`
+    the trunk's `expert_activation`.
 
     Each held expert's rows get a span of whole row tiles in a static
     buffer (ops/grouped_matmul.py's contract: no tile belongs to two
@@ -278,8 +304,8 @@ def held_expert_part(b, top_p, top_i, p_experts, k: TokenTrunkConfig):
     with jax.named_scope("lk.moe_experts"):
         g = grouped_matmul(x, p_experts["gate"]["kernel"], spans)
         u = grouped_matmul(x, p_experts["up"]["kernel"], spans)
-        y = grouped_matmul(jax.nn.silu(g) * u, p_experts["down"]["kernel"],
-                           spans)
+        y = grouped_matmul(_ACTIVATIONS[k.expert_activation](g) * u,
+                           p_experts["down"]["kernel"], spans)
         # Back to token order and summed over a token's choices, one
         # gather per choice (no (token, choice, hidden) relayout). A choice
         # that is not held has weight 0 and points past the last span,
@@ -299,6 +325,214 @@ def gated_mlp(x, p):
                   p["down"])
 
 
+# ---------------------------------------------------------------------------
+# The two trunks' layers. A layer object is built from the ModelConfig and
+# gives: `cache_name` (the batch entry `precompute` returns its caches
+# under), `param_shapes(w)`, `tables(positions)` (static numpy, made once
+# a frame) and `__call__(i, p, h, tables, cache)` → (h, this frame's cache
+# entry, (tokens per held expert (held,), each token's chosen experts (B,
+# L, k))) for layer i over one frame's tokens h (B, L, hidden), `cache`
+# the entry of the frames before it or None; and `key_columns(L)`, the
+# (visited, visible) key columns of its windowed layers' attention over a
+# step's L target queries, (0, 0) for a trunk without windows.
+# ---------------------------------------------------------------------------
+class Mistral4Layer:
+    """Mistral-Small-4's layer (latent attention, a shared expert)."""
+
+    cache_name = "latent_cache"
+
+    def __init__(self, config: ModelConfig):
+        self.config = config
+
+    def param_shapes(self, w):
+        k = self.config.tokens
+        H, NH = k.hidden_size, k.num_attention_heads
+        return {
+            "attn_norm": {"scale": w(H)},
+            "q_a": {"kernel": w(H, k.q_lora_rank)},
+            "q_norm": {"scale": w(k.q_lora_rank)},
+            "q_b": {"kernel": w(k.q_lora_rank, NH * k.qk_head_dim)},
+            "kv_a": {"kernel": w(H, k.kv_lora_rank + k.qk_rope_head_dim)},
+            "kv_norm": {"scale": w(k.kv_lora_rank)},
+            "kv_b": {"kernel": w(k.kv_lora_rank,
+                                 NH * (k.qk_nope_head_dim + k.v_head_dim))},
+            "o": {"kernel": w(NH * k.v_head_dim, H)},
+            "mlp_norm": {"scale": w(H)},
+            "router": {"kernel": w(H, k.n_routed_experts)},
+            "shared": _mlp_shapes(
+                w, H, k.moe_intermediate_size * k.n_shared_experts),
+            "experts": _mlp_shapes(w, H, k.moe_intermediate_size,
+                                   k.held_experts[1]),
+        }
+
+    def tables(self, positions):
+        return rope_tables(positions, self.config.tokens)
+
+    def __call__(self, i, p, h, tables, cache):
+        """`cache` is the (c_kv, k_rope) of the frames before this one."""
+        del i  # every layer is the same
+        cfg, k = self.config, self.config.tokens
+        dt = jnp.dtype(cfg.dtype)
+        eps = k.rms_norm_eps
+        B, L, _ = h.shape
+        NH, dn, dr, dv = (k.num_attention_heads, k.qk_nope_head_dim,
+                          k.qk_rope_head_dim, k.v_head_dim)
+        cos, sin, qscale = tables
+        with jax.named_scope("lk.mla_proj"):
+            a = rms_norm(h, p["attn_norm"]["scale"], eps).astype(dt)
+            c_q = rms_norm(_dense(a, p["q_a"]), p["q_norm"]["scale"],
+                           eps).astype(dt)
+            q = _dense(c_q, p["q_b"]).reshape(B, L, NH, dn + dr)
+            q = jnp.concatenate(
+                [q[..., :dn],
+                 apply_rope(q[..., dn:], cos, sin, k.rope_interleave)],
+                axis=-1)
+            if np.any(qscale != 1.0):
+                q = q * jnp.asarray(qscale, dt)[None, :, None, None]
+            kv_a = _dense(a, p["kv_a"])
+            c_kv = rms_norm(kv_a[..., :k.kv_lora_rank],
+                            p["kv_norm"]["scale"], eps).astype(dt)
+            k_rope = apply_rope(kv_a[..., k.kv_lora_rank:], cos, sin,
+                                k.rope_interleave)
+            own = (c_kv, k_rope)
+            if cache is not None:
+                c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
+                k_rope = jnp.concatenate([cache[1].astype(dt), k_rope],
+                                         axis=1)
+            # Keys and values up-projected from the latent (the form a
+            # chip run chose over absorbed weights; PERF.md, PR 26).
+            Lk = c_kv.shape[1]
+            kv = _dense(c_kv, p["kv_b"]).reshape(B, Lk, NH, dn + dv)
+            keys = jnp.concatenate(
+                [kv[..., :dn],
+                 jnp.broadcast_to(k_rope[:, :, None, :], (B, Lk, NH, dr))],
+                axis=-1)
+            values = kv[..., dn:]
+        with jax.named_scope("lk.mla_core"):
+            o = _attention(q, keys, values, softmax_scale(k),
+                           resolve_flash(cfg.use_flash_attention))
+        with jax.named_scope("lk.mla_proj"):
+            h = h + _dense(o.reshape(B, L, NH * dv), p["o"])
+        with jax.named_scope("lk.moe_route"):
+            b32 = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(B * L, -1)
+            top_p, top_i = route(b32, p["router"], k)
+            b = b32.astype(dt)
+        routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
+        with jax.named_scope("lk.moe_shared"):
+            shared = gated_mlp(b, p["shared"])
+        h = h + (shared + routed).reshape(B, L, -1)
+        return h, own, (counts, top_i.reshape(B, L, -1))
+
+    def key_columns(self, L: int):
+        """No layer of this trunk has a window."""
+        return 0, 0
+
+
+def plain_rope_tables(positions, dim: int, theta: float):
+    """cos, sin (L, dim/2) float32: θ^(−2i/dim), no scaling."""
+    freq = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ang = np.asarray(positions, np.float64)[:, None] * freq[None]
+    return np.cos(ang).astype(np.float32), np.sin(ang).astype(np.float32)
+
+
+class SmallThinkerLayer:
+    """SmallThinker's layer (grouped-query heads, a window or none and
+    rotary or none per layer, the router ahead of attention, ReGLU
+    experts)."""
+
+    cache_name = "kv_cache"
+
+    def __init__(self, config: ModelConfig):
+        self.config = config
+
+    def param_shapes(self, w):
+        k = self.config.tokens
+        H, D = k.hidden_size, k.head_dim
+        return {
+            "attn_norm": {"scale": w(H)},
+            "router": {"kernel": w(H, k.n_routed_experts)},
+            "q": {"kernel": w(H, k.num_attention_heads * D)},
+            "k": {"kernel": w(H, k.num_key_value_heads * D)},
+            "v": {"kernel": w(H, k.num_key_value_heads * D)},
+            "o": {"kernel": w(k.num_attention_heads * D, H)},
+            "mlp_norm": {"scale": w(H)},
+            "experts": _mlp_shapes(w, H, k.moe_ffn_hidden_size,
+                                   k.held_experts[1]),
+        }
+
+    def tables(self, positions):
+        k = self.config.tokens
+        return plain_rope_tables(positions, k.head_dim, k.rope_theta)
+
+    def window(self, i):
+        """Layer i's window, or None where it sees every key."""
+        k = self.config.tokens
+        return k.sliding_window_size if k.sliding_window_layout[i] else None
+
+    def __call__(self, i, p, h, tables, cache):
+        """`cache` is the (keys, values) (B, L', kv heads, head_dim) of
+        the frames before this one, keys rotated where this layer
+        rotates."""
+        cfg, k = self.config, self.config.tokens
+        dt = jnp.dtype(cfg.dtype)
+        eps = k.rms_norm_eps
+        B, L, _ = h.shape
+        NH, NKV, D = k.num_attention_heads, k.num_key_value_heads, k.head_dim
+        window = self.window(i)
+        with jax.named_scope("lk.gqa_proj"):
+            a32 = rms_norm(h, p["attn_norm"]["scale"], eps)
+        with jax.named_scope("lk.moe_route"):
+            # The router reads the attention's input: the experts of a
+            # token are known before its attention runs.
+            top_p, top_i = route(a32.reshape(B * L, -1), p["router"], k)
+        with jax.named_scope("lk.gqa_proj"):
+            a = a32.astype(dt)
+            q = _dense(a, p["q"]).reshape(B, L, NH, D)
+            keys = _dense(a, p["k"]).reshape(B, L, NKV, D)
+            values = _dense(a, p["v"]).reshape(B, L, NKV, D)
+            if k.rope_layout[i]:
+                q = apply_rope(q, *tables, False)
+                keys = apply_rope(keys, *tables, False)
+            own = (keys, values)
+            if cache is not None:
+                keys = jnp.concatenate([cache[0].astype(dt), keys], axis=1)
+                values = jnp.concatenate([cache[1].astype(dt), values],
+                                         axis=1)
+        binds = window_binds(L, window, keys.shape[1] - L)
+        with jax.named_scope("lk.attn_window" if binds else "lk.attn_full"):
+            o = _attention(q, keys, values, D ** -0.5,
+                           resolve_flash(cfg.use_flash_attention), window)
+        with jax.named_scope("lk.gqa_proj"):
+            h = h + _dense(o.reshape(B, L, NH * D), p["o"])
+        with jax.named_scope("lk.moe_route"):
+            b = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(
+                B * L, -1).astype(dt)
+        routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
+        with jax.named_scope("lk.moe_experts"):
+            h = h + routed.reshape(B, L, -1)
+        return h, own, (counts, top_i.reshape(B, L, -1))
+
+    def key_columns(self, L: int):
+        """(visited, visible) key columns of one head's L target queries
+        against [cache ; own], summed over the layers whose window binds
+        there: what the banded kernel walks over what the band lets
+        through (ops/flash_attention.band_key_columns)."""
+        k = self.config.tokens
+        per_layer = [band_key_columns(L, 2 * L, self.window(i), L)
+                     for i in range(k.num_hidden_layers)
+                     if window_binds(L, self.window(i), L)]
+        return tuple(sum(c) for c in zip(*per_layer)) if per_layer else (0, 0)
+
+
+TRUNK_LAYERS = {TokenTrunkConfig: Mistral4Layer,
+                SmallThinkerTrunkConfig: SmallThinkerLayer}
+
+
+def trunk_layer(cfg: ModelConfig):
+    """The layer object of `cfg.tokens`' trunk."""
+    return TRUNK_LAYERS[type(cfg.tokens)](cfg)
+
+
 class TokenDenoiser:
     """The denoiser contract (models/__init__.py) for `family: tokens`."""
 
@@ -314,6 +548,7 @@ class TokenDenoiser:
                 "layer is not in parallel/ yet")
         self.config = config
         self.mesh = mesh
+        self.layer = trunk_layer(config)
 
     # -- parameters --------------------------------------------------------
     def init(self, rngs, batch=None, *, cond_mask=None, train=False):
@@ -378,82 +613,26 @@ class TokenDenoiser:
             tok = tok + ray_tok
         return tok + self._logsnr_emb(params, logsnr)[:, None, :]
 
-    def _layer(self, p, h, tables, cache):
-        """One trunk layer over one frame's tokens h (B, L, hidden), whose
-        rotary tables are `tables`; `cache` is the (c_kv, k_rope) of the
-        frames before it, or None for the first frame. → (h, this frame's
-        (c_kv, k_rope), tokens per held expert)."""
-        cfg, k = self.config, self.config.tokens
-        dt = jnp.dtype(cfg.dtype)
-        eps = k.rms_norm_eps
-        B, L, _ = h.shape
-        NH, dn, dr, dv = (k.num_attention_heads, k.qk_nope_head_dim,
-                          k.qk_rope_head_dim, k.v_head_dim)
-        cos, sin, qscale = tables
-        with jax.named_scope("lk.mla_proj"):
-            a = rms_norm(h, p["attn_norm"]["scale"], eps).astype(dt)
-            c_q = rms_norm(_dense(a, p["q_a"]), p["q_norm"]["scale"],
-                           eps).astype(dt)
-            q = _dense(c_q, p["q_b"]).reshape(B, L, NH, dn + dr)
-            q = jnp.concatenate(
-                [q[..., :dn],
-                 apply_rope(q[..., dn:], cos, sin, k.rope_interleave)],
-                axis=-1)
-            if np.any(qscale != 1.0):
-                q = q * jnp.asarray(qscale, dt)[None, :, None, None]
-            kv_a = _dense(a, p["kv_a"])
-            c_kv = rms_norm(kv_a[..., :k.kv_lora_rank],
-                            p["kv_norm"]["scale"], eps).astype(dt)
-            k_rope = apply_rope(kv_a[..., k.kv_lora_rank:], cos, sin,
-                                k.rope_interleave)
-            own = (c_kv, k_rope)
-            if cache is not None:
-                c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
-                k_rope = jnp.concatenate([cache[1].astype(dt), k_rope],
-                                         axis=1)
-            # Keys and values up-projected from the latent (the form a
-            # chip run chose over absorbed weights; PERF.md, PR 26).
-            Lk = c_kv.shape[1]
-            kv = _dense(c_kv, p["kv_b"]).reshape(B, Lk, NH, dn + dv)
-            keys = jnp.concatenate(
-                [kv[..., :dn],
-                 jnp.broadcast_to(k_rope[:, :, None, :], (B, Lk, NH, dr))],
-                axis=-1)
-            values = kv[..., dn:]
-        with jax.named_scope("lk.mla_core"):
-            o = _attention(q, keys, values, softmax_scale(k),
-                           resolve_flash(cfg.use_flash_attention))
-        with jax.named_scope("lk.mla_proj"):
-            h = h + _dense(o.reshape(B, L, NH * dv), p["o"])
-        with jax.named_scope("lk.moe_route"):
-            b32 = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(B * L, -1)
-            top_p, top_i = route(b32, p["router"], k)
-            b = b32.astype(dt)
-        routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
-        with jax.named_scope("lk.moe_shared"):
-            shared = gated_mlp(b, p["shared"])
-        h = h + (shared + routed).reshape(B, L, -1)
-        return h, own, counts
-
     def _frame(self, params, tok, frame_index, caches):
-        """One frame's tokens through every layer. → (h, per-layer own
-        (c_kv, k_rope), per-layer tokens per held expert)."""
+        """One frame's tokens through every layer. → (h, per-layer cache
+        entries of this frame, (per-layer tokens per held expert (layers,
+        held), per-layer chosen experts, a tuple of (B, L, k)))."""
         L = tok.shape[1]
-        tables = rope_tables(np.arange(L) + frame_index * L,
-                             self.config.tokens)
-        h, owns, counts = tok, [], []
+        tables = self.layer.tables(np.arange(L) + frame_index * L)
+        h, owns, counts, choices = tok, [], [], []
         for i in range(self.config.tokens.num_hidden_layers):
             label = layer_label(i)
             with jax.named_scope(f"og.{label}"):
-                h, own, c = self._layer(
-                    params[label], h, tables,
+                h, own, (c, chosen) = self.layer(
+                    i, params[label], h, tables,
                     None if caches is None else caches[i])
             owns.append(own)
             counts.append(c)
-        return h, tuple(owns), jnp.stack(counts)
+            choices.append(chosen)
+        return h, tuple(owns), (jnp.stack(counts), tuple(choices))
 
     def _cond_frame(self, params, cond, cond_mask):
-        """The conditioning frame through the trunk: its latent cache."""
+        """The conditioning frame through the trunk: its per-layer cache."""
         x, R1, t1 = cond["x"], cond["R1"], cond["t1"]
         if x.ndim == 5:       # (B, 1, H, W, 3): one conditioning frame
             x, R1, t1 = x[:, 0], R1[:, 0], t1[:, 0]
@@ -462,31 +641,32 @@ class TokenDenoiser:
             tok = self._frame_tokens(
                 params, x, R1, t1, cond["K"],
                 jnp.full((B,), LOGSNR_CLEAN, jnp.float32), cond_mask)
-        _, cache, counts = self._frame(params, tok, 0, None)
-        return cache, counts
+        _, cache, routed = self._frame(params, tok, 0, None)
+        return cache, routed
 
     # -- the contract --------------------------------------------------------
     def precompute(self, params, cond: dict):
         """What does not change over a call, for `_raw_eps`'s doubled
         guidance layout (rows [conditional…, unconditional…]): the
-        conditioning frame's per-layer latent cache, as batch entries."""
+        conditioning frame's per-layer cache (the trunk's own: a latent,
+        or keys and values), as one batch entry."""
         B = cond["x"].shape[0]
         doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0),
                                dict(cond))
         mask = jnp.concatenate([jnp.ones((B,)), jnp.zeros((B,))])
         with jax.named_scope("precompute"):
             cache, _ = self._cond_frame(params, doubled, mask)
-        return {"latent_cache": cache}
+        return {self.layer.cache_name: cache}
 
     def _forward(self, params, batch, cond_mask):
-        cache = batch.get("latent_cache")
+        cache = batch.get(self.layer.cache_name)
         if cache is None:
             cache, _ = self._cond_frame(params, batch, cond_mask)
         z = batch["z"]
         with jax.named_scope("og.prelude"):
             tok = self._frame_tokens(params, z, batch["R2"], batch["t2"],
                                      batch["K"], batch["logsnr"], cond_mask)
-        h, _, counts = self._frame(params, tok, 1, cache)
+        h, _, routed = self._frame(params, tok, 1, cache)
         with jax.named_scope("og.final"):
             with jax.named_scope("lk.patch"):
                 k = self.config.tokens
@@ -495,13 +675,13 @@ class TokenDenoiser:
                 out = jnp.dot(hn, params["out"]["kernel"].astype(h.dtype),
                               preferred_element_type=jnp.float32)
                 eps = self._unpatch(out, z.shape[1], z.shape[2])
-        return eps, counts
+        return eps, routed
 
     def apply(self, variables, batch, *, cond_mask=None, train=False,
               **unsupported):
-        """ε̂ (B, H, W, 3) float32 of the target frame. With
-        `batch["latent_cache"]` (from `precompute`) only the target's
-        tokens run; without it the conditioning frame runs first."""
+        """ε̂ (B, H, W, 3) float32 of the target frame. With the cache
+        entry `precompute` returns in `batch`, only the target's tokens
+        run; without it the conditioning frame runs first."""
         if unsupported:
             raise NotImplementedError(
                 "the token denoiser has no "
@@ -513,4 +693,21 @@ class TokenDenoiser:
         """Tokens each held expert is given, per layer, in the pass that
         `apply` makes over the target's tokens: (layers, held) int32. Every
         assignment to a held expert is in it — nothing is dropped."""
-        return self._forward(params, batch, cond_mask)[1]
+        return self._forward(params, batch, cond_mask)[1][0]
+
+    def routing_choices(self, params, batch, cond_mask=None):
+        """The experts each token of [conditioning frame ; target frame]
+        is sent to, per layer, in the passes `apply` makes: (layers, B,
+        2L, k) int32, held or not."""
+        cache, (_, cond) = self._cond_frame(params, batch, cond_mask)
+        own = self._forward(params, dict(batch, **{
+            self.layer.cache_name: cache}), cond_mask)[1][1]
+        return jnp.stack([jnp.concatenate(pair, axis=1)
+                          for pair in zip(cond, own)])
+
+    def window_key_columns(self, side: int):
+        """(visited, visible) key columns a head of one target row's
+        queries walks and sees in a step at `side` px, summed over the
+        layers whose window binds; (0, 0) for a trunk without windows."""
+        return self.layer.key_columns(
+            (side // self.config.tokens.patch_size) ** 2)

@@ -16,12 +16,18 @@ import re
 XUNET_LAYER_KINDS = ("conv", "gn", "attn", "emb", "pose", "update")
 TOKEN_LAYER_KINDS = ("mla_proj", "mla_core", "moe_route", "moe_experts",
                      "moe_shared", "patch", "emb", "pose", "update")
+# The token family's second trunk (grouped-query attention, a window on
+# some layers, no shared expert): its attention is stamped by what the
+# layer's mask is, so a windowed layer's time is told from a full one's.
+GQA_TOKEN_LAYER_KINDS = ("gqa_proj", "attn_window", "attn_full",
+                         "moe_route", "moe_experts", "patch", "emb", "pose",
+                         "update")
 # Every kind a `jax.named_scope("lk.<kind>")` may stamp. The stamps sit
 # where the work happens (models/layers.py, models/xunet.py,
 # models/token_denoiser.py, sample/ddpm.py); these tuples and layer_of are
 # the only other place a kind is spelled.
-LAYER_KINDS = XUNET_LAYER_KINDS + tuple(
-    k for k in TOKEN_LAYER_KINDS if k not in XUNET_LAYER_KINDS)
+LAYER_KINDS = tuple(dict.fromkeys(
+    XUNET_LAYER_KINDS + TOKEN_LAYER_KINDS + GQA_TOKEN_LAYER_KINDS))
 
 
 def layer_of(path: str):

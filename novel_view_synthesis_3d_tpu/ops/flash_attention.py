@@ -25,6 +25,25 @@ max → exp → sum → p·v over a (block_q, Lk) tile. The token trunk
 and takes the blocked form (PERF.md §6, PR 29, has the table that set the
 blocks).
 
+Two things the second token trunk (SmallThinker's layer) brought, both
+forward only. **Grouped key/value heads**: k and v may have fewer heads
+than q; the query heads of a group lie end to end in ONE grid row against
+that row's one key/value head, so K and V are neither repeated in HBM nor
+fetched more than once a group (the block index of K and V does not change
+over a group's query blocks). **A one-sided window with an offset**: a
+query at position p sees key j iff j > p − window, the queries being the
+last Lq positions of the key axis by default. Which key blocks a query
+block visits is then static ONCE THE QUERY BLOCK IS: the wrapper makes one
+kernel call a query block of a head (four at the trunk's 4096 queries),
+each with its own unrolled walk — blocks no row sees left out at trace
+time, blocks on the band's edge masked, the others bare — over every
+head's block of that index, and concatenates. (One call for all query
+blocks, its skip a `pl.when` on the block's index and its running
+statistics in VMEM scratch, read 27 ms where the unwindowed walk over MORE
+keys reads 10.6: PERF.md §6, PR 30.) At the trunk's 8192 keys a head's K
+and V are 2 × 2 MB, whole in VMEM and double-buffered: the call asks for a
+64 MiB scoped limit where the default 16 would not hold them.
+
 Layout notes (pallas_guide.md "Tiling Constraints"):
   - lanes (last dim) padded to a multiple of 128; sublanes to the dtype
     minimum. Padding is applied in the wrapper, masked inside the kernel
@@ -57,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from novel_view_synthesis_3d_tpu.ops import _pallas
 
@@ -109,8 +129,19 @@ def forward_blocks(Lq: int, Lk: int, D: int, itemsize: int,
     return bq, bk, _round_up(lk, bk) // bk
 
 
+def _band_blocks(Lk_pad: int, block_k: int, band, rows: int) -> list:
+    """The key blocks a query block of `rows` rows visits under `band` =
+    (window, position of its first row), last block first: [(lo, masked)],
+    `masked` where some row of it does not see the whole block. A block no
+    row sees is not listed."""
+    window, first = band
+    return [(lo, lo <= first + rows - 1 - window)
+            for lo in range(Lk_pad - block_k, -1, -block_k)
+            if lo + block_k - 1 > first - window]
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, scale: float,
-                 kv_len: int, block_k: int):
+                 kv_len: int, block_k: int, band=None):
     """One query block vs. one (batch, head)'s keys and values, all in VMEM,
     the key axis walked `block_k` at a time (unrolled: static slices).
 
@@ -120,10 +151,20 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, scale: float,
     constraint on output blocks. `kv_len` is the true (unpadded) kv length
     — static; the block that holds the boundary masks its padded columns
     (padding never fills a whole block, so no block's max is the mask's).
-    """
+
+    `band` = (window, position of this block's first query row), static: a
+    query at p sees key j iff j > p − window. The walk is then
+    `_band_blocks`' — from the last key block down, so that a row has met
+    keys it sees before any block that is masked for it; blocks no row
+    sees are left out at trace time, blocks on the band's edge are masked,
+    the others run bare."""
     q = q_ref[0]
     m = l = acc = None
-    for lo in range(0, k_ref.shape[1], block_k):
+    if band is None:
+        blocks = [(lo, False) for lo in range(0, k_ref.shape[1], block_k)]
+    else:
+        blocks = _band_blocks(k_ref.shape[1], block_k, band, q.shape[0])
+    for lo, edge in blocks:
         s = jax.lax.dot_general(
             q, k_ref[0, lo:lo + block_k, :],
             dimension_numbers=(((1,), (1,)), ((), ())),
@@ -131,6 +172,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, scale: float,
         if kv_len < lo + block_k:  # mask padded kv columns (static)
             col = lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(col < kv_len, s, _NEG_INF)
+        if edge:                   # and the keys behind a row's window
+            col = lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            row = band[1] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            s = jnp.where(col > row - band[0], s, _NEG_INF)
         m_blk = jnp.max(s, axis=-1, keepdims=True)
         m_new = m_blk if m is None else jnp.maximum(m, m_blk)
         p = jnp.exp(s - m_new)
@@ -151,15 +196,65 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, scale: float,
         lse_ref[0] = jnp.broadcast_to(lse, (lse.shape[0], lse_ref.shape[-1]))
 
 
+def band_key_columns(Lq: int, Lk: int, window: int, q_offset: int,
+                     block_q: int = _BLOCKED_Q) -> tuple[int, int]:
+    """(key columns the banded walk visits, key columns the band lets
+    through), each summed over one head's Lq query rows: from shapes
+    alone, `_band_blocks` with `forward_blocks`' blocks. Their ratio is 1
+    where every masked block is skipped and nothing visited is masked."""
+    bq, bk, _ = forward_blocks(Lq, Lk, 0, 0, block_q)
+    lk = _round_up(_round_up(Lk, 128), bk)
+    visited = sum(
+        len(_band_blocks(lk, bk, (window, q_offset + q0), bq)) * bk
+        * min(bq, Lq - q0) for q0 in range(0, Lq, bq))
+    visible = sum(Lk - max(q_offset + r - window + 1, 0) for r in range(Lq))
+    return visited, visible
+
+
+# Past this many bytes of one head's keys and values, fetched whole and
+# double-buffered, the forward asks for more than the 16 MiB of scoped
+# VMEM a kernel has by default (v5e has 128 MiB a core).
+_KV_DEFAULT_VMEM_BYTES = 4 * 1024 * 1024
+_LONG_KV_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
 def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
-                      block_k: int, with_lse: bool, interpret: bool):
-    """q (N, Lq_pad, Dp) · k,v (N, Lk_pad, Dp) → (out, lse or None)."""
+                      block_k: int, with_lse: bool, interpret: bool,
+                      band=None):
+    """q (N, Lq_pad, Dp) · k,v (N, Lk_pad, Dp) → (out, lse or None). A row
+    of q may hold several heads' queries end to end (grouped-query
+    attention), `band` = (window, q_offset, a head's padded rows): one
+    kernel call a query block of a head then, each with its own static
+    walk (`_attn_kernel`), every head's block i in the call's grid."""
     N, Lq, D = q.shape
     Lk = k.shape[1]
+    mem = {} if interpret else {"memory_space": _pallas.VMEM}
+    extra = {}
+    if 4 * Lk * D * k.dtype.itemsize > _KV_DEFAULT_VMEM_BYTES:
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=_LONG_KV_VMEM_LIMIT_BYTES)
+    kv_spec = pl.BlockSpec((1, Lk, D), lambda n, i: (n, 0, 0), **mem)
+    if band is not None:
+        window, q_offset, rows = band
+        per_head, heads = rows // block_q, Lq // rows
+        outs = [pl.pallas_call(
+            functools.partial(_attn_kernel, scale=scale, kv_len=kv_len,
+                              block_k=block_k,
+                              band=(window, q_offset + i * block_q)),
+            grid=(N, heads),
+            in_specs=[pl.BlockSpec(
+                (1, block_q, D),
+                lambda n, g, i=i: (n, g * per_head + i, 0), **mem),
+                kv_spec, kv_spec],
+            out_specs=pl.BlockSpec((1, block_q, D), lambda n, g: (n, g, 0),
+                                   **mem),
+            out_shape=jax.ShapeDtypeStruct((N, heads * block_q, D), q.dtype),
+            name="flash_fwd", interpret=interpret, **extra,
+        )(q, k, v).reshape(N, heads, 1, block_q, D) for i in range(per_head)]
+        return jnp.concatenate(outs, axis=2).reshape(N, Lq, D), None
     grid = (N, Lq // block_q)
     kernel = functools.partial(_attn_kernel, scale=scale, kv_len=kv_len,
                                block_k=block_k)
-    mem = {} if interpret else {"memory_space": _pallas.VMEM}
     out_specs = [pl.BlockSpec((1, block_q, D), lambda n, i: (n, i, 0), **mem)]
     out_shape = [jax.ShapeDtypeStruct((N, Lq, D), q.dtype)]
     if with_lse:
@@ -178,6 +273,7 @@ def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
         out_shape=out_shape,
         name="flash_fwd",
         interpret=interpret,
+        **extra,
     )(q, k, v)
     return out, (lse[0][:, :, 0] if with_lse else None)
 
@@ -199,16 +295,20 @@ def _flash_attention(q, k, v, scale: float, block_q: int):
 
 
 def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
-                    with_lse: bool):
+                    with_lse: bool, window=None):
     """(B, L, H, D) inputs → padded kernel call → unpadded (out, lse);
-    lse is None unless asked for."""
+    lse is None unless asked for. k and v may have fewer heads than q
+    (grouped-query attention): the query heads of a group lie end to end
+    in one grid row, against that row's one key/value head, so K and V
+    are neither repeated in HBM nor fetched more than once a group.
+    `window` is (size, q_offset) or None."""
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, Hkv = k.shape[1], k.shape[2]
     interpret = _use_interpret()
     # (B, L, H, D) → (B·H, L, D): heads become independent grid rows.
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-    kt = k.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H, Lk, D)
+    kt = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, D)
+    vt = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, D)
     bq, bk, _ = forward_blocks(Lq, Lk, D, q.dtype.itemsize, block_q)
     qt = _pad_to(qt, 1, bq)
     kt = _pad_to(kt, 1, bk)
@@ -217,9 +317,13 @@ def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
         qt = _pad_to(qt, 2, 128)
         kt = _pad_to(kt, 2, 128)
         vt = _pad_to(vt, 2, 128)
-    out, lse = _flash_fwd_padded(qt, kt, vt, scale=scale, kv_len=Lk,
-                                 block_q=bq, block_k=bk, with_lse=with_lse,
-                                 interpret=interpret)
+    rows = qt.shape[1]                     # a head's padded query rows
+    out, lse = _flash_fwd_padded(
+        qt.reshape(B * Hkv, (H // Hkv) * rows, qt.shape[2]), kt, vt,
+        scale=scale, kv_len=Lk, block_q=bq, block_k=bk, with_lse=with_lse,
+        interpret=interpret,
+        band=None if window is None else (*window, rows))
+    out = out.reshape(B * H, rows, out.shape[2])
     out = out[:, :Lq, :D].reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
     if with_lse:
         lse = lse[:, :Lq].reshape(B, H, Lq)
@@ -404,15 +508,66 @@ def _flash_vjp_bwd(scale: float, block_q: int, res, g):
 _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_attention_forward_only(q, k, v, scale: float, block_q: int,
+                                  window):
+    return _flash_fwd_core(q, k, v, scale, block_q, with_lse=False,
+                           window=window)[0]
+
+
+def _forward_only_fwd(q, k, v, scale, block_q, window):
+    return _flash_attention_forward_only(q, k, v, scale, block_q,
+                                         window), None
+
+
+def _forward_only_bwd(scale, block_q, window, res, g):
+    raise NotImplementedError(
+        "flash_attention has no backward for grouped key/value heads or a "
+        "window yet: the dq and dk/dv kernels take one key/value head a "
+        "query head and no band")
+
+
+_flash_attention_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
+
+
+def window_binds(Lq: int, window: Optional[int], q_offset: int) -> bool:
+    """Whether any of Lq queries, row i at position q_offset + i, has a
+    key j ≥ 0 outside its band j > position − window."""
+    return window is not None and q_offset + Lq - 1 - window >= 0
+
+
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     scale: Optional[float] = None,
-                    block_q: int = _BLOCKED_Q) -> jnp.ndarray:
-    """Fused softmax(q·kᵀ/√D)·v. q (B, Lq, H, D), k/v (B, Lk, H, D).
+                    block_q: int = _BLOCKED_Q,
+                    window: Optional[int] = None,
+                    q_offset: Optional[int] = None) -> jnp.ndarray:
+    """Fused softmax(q·kᵀ/√D)·v. q (B, Lq, H, D), k/v (B, Lk, Hkv, D) with
+    H a multiple of Hkv: query head h reads key/value head h // (H // Hkv).
 
-    Drop-in for `flax.linen.dot_product_attention` (same layout/scaling).
-    `block_q` is an upper bound on the query (and the backward's kv)
-    block; under it the shapes choose (`forward_blocks`).
+    Drop-in for `flax.linen.dot_product_attention` (same layout/scaling)
+    where Hkv = H and there is no window. `block_q` is an upper bound on
+    the query (and the backward's kv) block; under it the shapes choose
+    (`forward_blocks`).
+
+    `window`: key j is at position j, query row i at `q_offset` + i
+    (default Lk − Lq: the queries are the sequence's last), and a query at
+    p sees key j iff j > p − window. The band is one-sided — keys past
+    the query are the caller's to leave out or in; every query must see
+    at least one key. Key blocks wholly outside a query block's band are
+    not visited. Forward only, as are grouped heads.
     """
-    D = q.shape[-1]
+    B, Lq, H, D = q.shape
+    Lk, Hkv = k.shape[1], k.shape[2]
     scale = float(D ** -0.5) if scale is None else float(scale)
-    return _flash_attention(q, k, v, scale, int(block_q))
+    if H % Hkv:
+        raise ValueError(f"{H} query heads do not divide into {Hkv} "
+                         "key/value heads")
+    q_offset = Lk - Lq if q_offset is None else int(q_offset)
+    if not window_binds(Lq, window, q_offset):
+        if H == Hkv:
+            return _flash_attention(q, k, v, scale, int(block_q))
+        window = None
+    else:
+        window = (int(window), q_offset)
+    return _flash_attention_forward_only(q, k, v, scale, int(block_q),
+                                         window)

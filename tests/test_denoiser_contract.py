@@ -42,6 +42,24 @@ TINY_TOKENS = {
 }
 
 
+# The second token trunk (SmallThinker's layer) at toy sizes: a full and
+# a window layer, the window as long as a frame.
+TINY_GQA_TOKENS = {
+    "model.tokens.hidden_size": 32, "model.tokens.num_hidden_layers": 2,
+    "model.tokens.num_attention_heads": 4,
+    "model.tokens.num_key_value_heads": 2, "model.tokens.head_dim": 8,
+    "model.tokens.sliding_window_size": 16,
+    "model.tokens.moe_num_primary_experts": 4,
+    "model.tokens.moe_num_active_primary_experts": 2,
+    "model.tokens.moe_ffn_hidden_size": 16,
+    "model.tokens.held_experts": [0, 4], "data.img_sidelength": 16,
+    "model.dtype": "float32", "model.param_dtype": "float32",
+    "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
+}
+TINY_BY_PRESET = {"ms4_denoiser128": TINY_TOKENS,
+                  "st21_denoiser256": TINY_GQA_TOKENS}
+
+
 def token_cfg(**over) -> Config:
     return get_preset("ms4_denoiser128").override(
         **dict(TINY_TOKENS, **over)).validate()
@@ -235,10 +253,12 @@ def test_token_denoiser_refuses_what_is_the_xunets():
                     ops=(0, 1))
 
 
-def test_cli_sample_runs_the_preset_through_the_factory(tmp_path, capsys):
-    """`nvs3d sample --preset ms4_denoiser128` at tiny overrides: the
-    factory builds the token denoiser, a checkpoint of its tree restores,
-    and `make_sampler` writes views."""
+@pytest.mark.parametrize("preset", sorted(TINY_BY_PRESET))
+def test_cli_sample_runs_the_preset_through_the_factory(tmp_path, capsys,
+                                                        preset):
+    """`nvs3d sample --preset <a token preset>` at tiny overrides: the
+    factory builds the token denoiser on that preset's trunk, a checkpoint
+    of its tree restores, and `make_sampler` writes views."""
     from novel_view_synthesis_3d_tpu.cli import main
     from novel_view_synthesis_3d_tpu.data.synthetic import (
         write_synthetic_srn)
@@ -251,10 +271,10 @@ def test_cli_sample_runs_the_preset_through_the_factory(tmp_path, capsys):
     root = str(tmp_path / "srn")
     write_synthetic_srn(root, num_instances=1, views_per_instance=3,
                         image_size=16)
-    over = dict(TINY_TOKENS, **{
+    over = dict(TINY_BY_PRESET[preset], **{
         "train.checkpoint_dir": str(tmp_path / "ckpt"),
         "train.results_folder": str(tmp_path / "results")})
-    cfg = token_cfg(**over)
+    cfg = get_preset(preset).override(**over).validate()
     model = build_denoiser(cfg.model)
     b = make_example_batch(batch_size=1, sidelength=16, seed=0)
     state = create_train_state(cfg.train, model, _sample_model_batch(
@@ -270,7 +290,7 @@ def test_cli_sample_runs_the_preset_through_the_factory(tmp_path, capsys):
     out = str(tmp_path / "views")
     args = [f"{k}={v if not isinstance(v, list) else str(v).replace(' ', '')}"
             for k, v in over.items()]
-    assert main(["sample", root, "--preset", "ms4_denoiser128", "--out", out,
+    assert main(["sample", root, "--preset", preset, "--out", out,
                  "--num-views", "2", "--sample-steps", "2"] + args) == 0
     assert "restored checkpoint at step 3" in capsys.readouterr().out
     assert os.path.exists(os.path.join(out, "view_000.png"))

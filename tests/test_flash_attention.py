@@ -246,3 +246,99 @@ def test_attention_layer_runs_the_kernel_per_data_shard():
         params, sharded).as_text()
     assert "shard_map" in text or "manual" in text
 
+
+# ---------------------------------------------------------------------------
+# Grouped key/value heads and a one-sided window (forward only)
+# ---------------------------------------------------------------------------
+def _banded_reference(q, k, v, scale, window, q_offset):
+    """Dense einsum: query head h on key/value head h // group; the query
+    of row i at position q_offset + i sees key j iff j > position −
+    window."""
+    B, Lq, H, D = q.shape
+    Lk, Hkv = k.shape[1], k.shape[2]
+    k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    if window is not None:
+        seen = np.arange(Lk)[None] > q_offset + np.arange(Lq)[:, None] \
+            - window
+        s = jnp.where(jnp.asarray(seen)[None, None], s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+BANDED = {
+    # name: (Lq, Lk, H, Hkv, D, window, q_offset, block_q)
+    "grouped_heads_no_window": (64, 128, 4, 2, 16, None, None, 1024),
+    "grouped_heads_one_key_block": (64, 128, 4, 2, 16, 64, None, 1024),
+    # the trunk's geometry in small: queries are the second half of the
+    # keys and the window is a frame long, row r sees cached keys c > r
+    "window_of_a_frame_ragged": (200, 400, 6, 2, 16, 200, None, 64),
+    # past 1024 keys the walk is blocked (512 keys a block). The band's
+    # edge inside a block for every row:
+    "edge_inside_a_block": (256, 1536, 2, 2, 8, 700, 1111, 128),
+    # the edge of the first row of each query block ON a block boundary
+    # (q_offset − window + 1 = 512; query blocks of 128 and 512 rows):
+    "edge_at_block_boundaries": (256, 1536, 2, 1, 8, 769, 1280, 128),
+    "query_block_as_long_as_a_key_block": (1024, 2048, 1, 1, 8, 1024, None,
+                                           512),
+    # a band that leaves whole key blocks out for every query block (the
+    # first 512 keys are never visited) and for some (dynamic skip):
+    "skips_whole_blocks": (256, 2048, 4, 2, 8, 300, 1500, 64),
+    "ragged_keys_and_rows": (100, 1300, 3, 1, 16, 333, 1100, 64),
+    # a window that never binds takes the plain body
+    "window_longer_than_the_sequence": (64, 128, 2, 2, 16, 500, None, 64),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BANDED))
+def test_grouped_heads_and_window_match_a_dense_einsum(name):
+    Lq, Lk, H, Hkv, D, window, q_offset, block_q = BANDED[name]
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
+    q = jax.random.normal(ks[0], (2, Lq, H, D))
+    k = jax.random.normal(ks[1], (2, Lk, Hkv, D))
+    v = jax.random.normal(ks[2], (2, Lk, Hkv, D))
+    out = flash_attention(q, k, v, scale=0.3, window=window,
+                          q_offset=q_offset, block_q=block_q)
+    ref = _banded_reference(q, k, v, 0.3, window,
+                            Lk - Lq if q_offset is None else q_offset)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    if window is not None and name != "window_longer_than_the_sequence":
+        off = Lk - Lq if q_offset is None else q_offset
+        assert fa.window_binds(Lq, window, off)
+        # and the band matters: without it the answer is another
+        assert float(jnp.max(jnp.abs(
+            out - _banded_reference(q, k, v, 0.3, None, 0)))) > 1e-2
+
+
+def test_band_walk_skips_what_no_row_sees():
+    """`band_key_columns` is the walk the kernel makes, from shapes: at
+    the token trunk's size (a frame of 4096 queries against [cache ; own],
+    window 4096) a head visits 6656 of 8192 key columns a row on average
+    for the 6143.5 its rows see. `_band_blocks` lists a query block's key
+    blocks last first, leaves out the ones none of its rows sees and
+    marks the ones on the band's edge."""
+    visited, visible = fa.band_key_columns(4096, 8192, 4096, 4096)
+    assert visible == 4096 * 8192 - sum(range(1, 4097))   # c > r
+    assert visited == 1024 * (8192 + 7168 + 6144 + 5120)
+    assert 1.0 < visited / visible < 1.25
+    # no window to speak of: every column visited is seen
+    assert fa.band_key_columns(256, 2048, 10 ** 6, 1792)[0] == 256 * 2048
+    # 256 rows from position 1500 under a window of 300: keys 1201… for
+    # the first row, 1456… for the last
+    assert fa._band_blocks(2048, 512, (300, 1500), 256) == [
+        (1536, False), (1024, True)]
+    # the trunk's third query block: cached keys from 2049 on, the edge
+    # crossing two key blocks
+    assert fa._band_blocks(8192, 512, (4096, 4096 + 2048), 1024)[-3:] == [
+        (3072, False), (2560, True), (2048, True)]
+    # the plain body's blocks are untouched by all this
+    assert forward_blocks(4096, 8192, 128, 2) == (1024, 512, 16)
+
+
+def test_grouped_heads_and_window_are_forward_only():
+    q, k, v = _qkv(3, 1, 32, 64, 4, 8)
+    k, v = k[:, :, :2], v[:, :, :2]
+    with pytest.raises(NotImplementedError, match="no backward"):
+        jax.grad(lambda q: jnp.sum(flash_attention(q, k, v)))(q)
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(q[:, :, :3], k, v)

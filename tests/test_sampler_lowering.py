@@ -1,0 +1,170 @@
+"""`make_sampler` of the configurations a change did not mean to touch
+lowers to the text it lowered to before.
+
+The offline sampler is one XLA program a call; a PR that adds a second
+trunk, a kernel form or a seam must leave the other configurations'
+programs alone, and "the lowered text is the parent's" is the proof that
+costs no chip time. The digests below are of the text lowered on the CPU
+at toy sizes (kernels through the Pallas interpreter, as tier-1 runs
+them) and, where the TPU compiler can describe a v5e here, of the text
+lowered for it with the kernels compiled (a Mosaic body enters as the
+digest of its module printed without locations: the serialized form
+carries the checkout's path and line numbers). A digest changes only with
+what the program computes — scopes and names are not part of the text.
+
+When a PR does mean to change one of these programs, it replaces the
+digest and says so in CHANGES.md; `python tests/test_sampler_lowering.py`
+prints the current ones.
+"""
+
+import base64
+import hashlib
+import json
+import os
+import re
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from novel_view_synthesis_3d_tpu.config import get_preset  # noqa: E402
+from novel_view_synthesis_3d_tpu.diffusion.schedules import (  # noqa: E402
+    sampling_schedule)
+from novel_view_synthesis_3d_tpu.models import build_denoiser  # noqa: E402
+from novel_view_synthesis_3d_tpu.ops import _pallas  # noqa: E402
+from novel_view_synthesis_3d_tpu.sample.ddpm import make_sampler  # noqa: E402
+
+TOY = {
+    "paper256": {
+        "model.ch": 32, "model.ch_mult": [1, 2], "model.emb_ch": 32,
+        "model.num_res_blocks": 1, "model.attn_resolutions": [8],
+        "data.img_sidelength": 16, "model.use_flash_attention": True},
+    "ms4_denoiser128": {
+        "model.tokens.hidden_size": 64, "model.tokens.num_hidden_layers": 2,
+        "model.tokens.num_attention_heads": 4,
+        "model.tokens.q_lora_rank": 32, "model.tokens.kv_lora_rank": 16,
+        "model.tokens.qk_nope_head_dim": 8,
+        "model.tokens.qk_rope_head_dim": 8, "model.tokens.v_head_dim": 16,
+        "model.tokens.n_routed_experts": 16,
+        "model.tokens.num_experts_per_tok": 4,
+        "model.tokens.moe_intermediate_size": 32,
+        "model.tokens.held_experts": [0, 4], "data.img_sidelength": 16,
+        "model.use_flash_attention": True},
+}
+# (preset, "cpu" | "v5e") → sha256 of the lowered text, from the parent
+# of the PR that last meant to change it (CHANGES.md, PR 30).
+DIGESTS = {
+    ("paper256", "cpu"):
+        "bd3e5b2ab3cf08e37531729ca8fe60b93e85e8ff14d43b9ed14a2eac3640564b",
+    ("ms4_denoiser128", "cpu"):
+        "b543c837af4ac88bdc87d448985cc8528042f2a069535005fc388d714e9c060b",
+    ("paper256", "v5e"):
+        "2a63bc4201e17fb3349dcf656b8f1463d4354cb23fcb03ceea931efefbbc0bd2",
+    ("ms4_denoiser128", "v5e"):
+        "c733feca9b7be61d75490621feba9e2a21c6cbc0318a9f6bfe7bd91ef825aa22",
+}
+
+
+def lowered_text(preset, sharding=None):
+    """The text of `make_sampler(trajectory_every=1)` at the toy size: 2
+    views a call, 4 respaced ddpm steps, guidance 3."""
+    cfg = get_preset(preset).override(**dict(
+        TOY[preset], **{"diffusion.sample_timesteps": 4,
+                        "diffusion.guidance_weight": 3.0})).validate()
+    model = build_denoiser(cfg.model)
+    side, B = cfg.data.img_sidelength, 2
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    cond = {"x": spec(B, side, side, 3), "R1": spec(B, 3, 3),
+            "t1": spec(B, 3), "R2": spec(B, 3, 3), "t2": spec(B, 3),
+            "K": spec(B, 3, 3)}
+
+    def init():
+        batch = {k: jnp.zeros(v.shape) for k, v in cond.items()}
+        batch.update(z=jnp.zeros((B, side, side, 3)),
+                     logsnr=jnp.zeros((B,)))
+        return model.init({"params": jax.random.PRNGKey(0),
+                           "dropout": jax.random.PRNGKey(1)}, batch,
+                          cond_mask=jnp.ones((B,)), train=False)["params"]
+
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(init))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding)
+    sampler = make_sampler(model, sampling_schedule(cfg.diffusion, 4),
+                           cfg.diffusion, trajectory_every=1)
+    return jax.jit(sampler).lower(params, key, cond).as_text()
+
+
+def _body_without_locations(match):
+    """A compiled kernel's `backend_config` with its serialized body (MLIR
+    bytecode, which carries the source's path and line numbers) replaced
+    by the digest of the same module printed without locations."""
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    config = json.loads(re.sub(
+        r"\\([0-9A-Fa-f]{2})", lambda m: chr(int(m.group(1), 16)),
+        match.group(1)))
+    call = config.get("custom_call_config", {})
+    if "body" in call:
+        with jax_mlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            asm = ir.Module.parse(base64.b64decode(call["body"])) \
+                .operation.get_asm(enable_debug_info=False)
+        call["body"] = hashlib.sha256(asm.encode()).hexdigest()
+    return "backend_config = " + json.dumps(config, sort_keys=True)
+
+
+def digest(text):
+    text = re.sub(r'backend_config = "([^"]*)"', _body_without_locations,
+                  text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def v5e_sharding():
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("preset", sorted(TOY))
+def test_sampler_lowers_to_the_pinned_text_on_the_cpu(preset):
+    assert digest(lowered_text(preset)) == DIGESTS[preset, "cpu"]
+
+
+@pytest.mark.parametrize("preset", sorted(TOY))
+def test_sampler_lowers_to_the_pinned_text_for_a_described_v5e(
+        preset, monkeypatch):
+    try:
+        sharding = v5e_sharding()
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    text = lowered_text(preset, sharding)
+    assert "tpu_custom_call" in text
+    assert digest(text) == DIGESTS[preset, "v5e"]
+
+
+if __name__ == "__main__":
+    jax.config.update("jax_platforms", "cpu")
+    for preset in sorted(TOY):
+        print(preset, "cpu", digest(lowered_text(preset)))
+    sharding = v5e_sharding()
+    _pallas.use_interpret = lambda: False
+    for preset in sorted(TOY):
+        text = lowered_text(preset, sharding)
+        print(preset, "v5e", digest(text), len(text),
+              text.count("tpu_custom_call"))

@@ -102,6 +102,17 @@ def _rect_attn(Lq, Lk, heads, hd):
         [((2, Lq, heads, hd), BF16)] + [((2, Lk, heads, hd), BF16)] * 2)
 
 
+def _gqa_attn(Lq, Lk, heads, kv_heads, hd, window):
+    """The grouped-query trunk's core at the size its cell runs: a frame's
+    4096 queries against [cache ; own], 28 query heads on 4 key/value
+    heads whose 8192 keys and values sit whole in VMEM (the kernel asks
+    for the scoped limit that takes), with the window's banded walk or
+    without."""
+    return (lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, window=window),
+        [((1, Lq, heads, hd), BF16)] + [((1, Lk, kv_heads, hd), BF16)] * 2)
+
+
 def _grouped(assignments, experts, k, n):
     """The expert layer's grouped product at the published widths: the
     static row count of the worst case (a step's 8192 tokens × top-4, all
@@ -126,7 +137,14 @@ CASES = {
     # (test_token_trunk_shapes_compile_in_both_forms).
     "flash_fwd_Lq1024_Lk2048_d128": _rect_attn(1024, 2048, 32, 128),
     "flash_fwd_Lq1024_Lk1024_d128": _rect_attn(1024, 1024, 32, 128),
+    "flash_fwd_gqa_Lq4096_Lk8192_d128": _gqa_attn(4096, 8192, 28, 4, 128,
+                                                  None),
+    "flash_fwd_gqa_window4096_Lq4096_Lk8192_d128": _gqa_attn(
+        4096, 8192, 28, 4, 128, 4096),
     "grouped_matmul_up_4096x2048": _grouped(32768, 32, 4096, 2048),
+    # the second token trunk's experts: 64 held, 16384 tokens x top-6
+    "grouped_matmul_up_2560x768": _grouped(98304, 64, 2560, 768),
+    "grouped_matmul_down_768x2560": _grouped(98304, 64, 768, 2560),
     "grouped_matmul_down_2048x4096": _grouped(32768, 32, 2048, 4096),
     **{f"serving_attention_L{L}_d{hd}":
        _attn(serving_attention.serving_attention, L, hd, False)
