@@ -215,7 +215,7 @@ def check_kernels(seed: int) -> dict:
 
     # GroupNorm(+swish) and the GN → FiLM → swish epilogue at base128's
     # 32² × 256 level, bf16 — against the XLA branches of models/layers.
-    h = normal((2, 2, 32, 32, 256), jnp.bfloat16)
+    h = normal((4, 32, 32, 256), jnp.bfloat16)
     fused = GroupNorm(act="swish", fused=True, dtype=jnp.bfloat16)
     plain = GroupNorm(act="swish", fused=False, dtype=jnp.bfloat16)
     gn_params = jax.tree.map(lambda a: a + normal(a.shape) * 0.3,

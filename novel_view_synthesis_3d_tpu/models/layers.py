@@ -7,12 +7,23 @@ two deliberate layout changes for TPU:
      instead of the reference's 3-D `Conv(kernel=(1,3,3))` over (B,F,H,W,C).
      The math is identical (the frame-axis kernel is 1), but 2-D NHWC convs
      hit XLA:TPU's well-tuned conv→MXU path and avoid degenerate-dim layouts.
-  2. GroupNorm defaults to **per-frame** statistics (reshape to (B·F,H,W,C)).
-     The reference shares statistics across frames (xunet.py:46-52 applies
-     flax GroupNorm over the full (B,2,H,W,C) view — SURVEY.md §2.2 quirk);
-     set `per_frame=False` for bit-faithful reference behavior.
+  2. GroupNorm defaults to **per-frame** statistics (one set per row of
+     B·F). The reference shares statistics across frames (xunet.py:46-52
+     applies flax GroupNorm over the full (B,2,H,W,C) view — SURVEY.md §2.2
+     quirk); set `per_frame=False` for bit-faithful reference behavior.
 
-Frame count F is a free dimension (the reference hardcodes F=2).
+Shape contract: every module here takes and returns the activation as
+**(B·F, H, W, C)** — rows batch-major, a sample's F frames adjacent — with
+the frame count F a static integer beside it (`frames=`), free (the
+reference hardcodes 2). No reshape splits or merges the row axis between
+two convolutions: XLA:TPU lays a convolution's operand out with the rows
+in the sublanes and the channels in the lanes (`{3,0,2,1:T(8,128)}` at 8
+rows), a (B, F, …) array cannot carry that tiling, and every such reshape
+was a physical copy to row-major with the norm, FiLM, swish and residual
+passes run apart from the convolutions (PERF.md §6, PR 31). (B, F) exists
+in two places only: inside AttnBlock, whose tokens are (B, F, H·W, C) —
+it runs at the small levels —, and inside GroupNorm(per_frame=False),
+whose statistics span a sample's frames.
 
 Each leaf (FrameConv, GroupNorm, FiLM, an AttnBlock's attention) runs
 inside one `jax.named_scope("lk.<kind>")`, and so do a block's few own
@@ -58,7 +69,8 @@ def out_init_scale():
 
 
 class FrameConv(nn.Module):
-    """k×k spatial conv applied independently to every frame."""
+    """k×k spatial conv applied independently to every row of (B·F, H, W,
+    C_in): a frame is a batch row of the 2-D convolution."""
 
     features: int
     kernel: int = 3
@@ -70,9 +82,7 @@ class FrameConv(nn.Module):
     @nn.compact
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
         with jax.named_scope("lk.conv"):
-            B, F = h.shape[:2]
-            h = h.reshape((B * F,) + h.shape[2:])
-            h = nn.Conv(
+            return nn.Conv(
                 self.features,
                 kernel_size=(self.kernel, self.kernel),
                 strides=(self.stride, self.stride),
@@ -81,7 +91,6 @@ class FrameConv(nn.Module):
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
             )(h)
-            return h.reshape((B, F) + h.shape[1:])
 
 
 class _GNParams(nn.Module):
@@ -102,7 +111,11 @@ class _GNParams(nn.Module):
 
 
 class GroupNorm(nn.Module):
-    """32-group GroupNorm over (B, F, H, W, C), optional fused activation.
+    """32-group GroupNorm over (B·F, H, W, C), optional fused activation.
+
+    Per-frame statistics are per row. `per_frame=False` (statistics over a
+    sample's `frames` rows jointly) views the rows as (B, F, H, W, C) for
+    the norm alone, and needs `frames`.
 
     `act='swish'` applies the nonlinearity INSIDE the norm op — on the
     fused Pallas path (ops/fused_groupnorm.py) the whole GN→swish chain is
@@ -114,25 +127,29 @@ class GroupNorm(nn.Module):
     per_frame: bool = True
     act: Optional[str] = None
     fused: bool = False
+    frames: Optional[int] = None
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
         with jax.named_scope("lk.gn"):
-            B, F, H, W, C = h.shape
+            N, H, W, C = h.shape
             if self.fused and self.per_frame:
                 if fits_vmem(H * W, C, h.dtype):
                     scale, bias = _GNParams(features=C, name="GroupNorm_0")()
                     # out_dtype=self.dtype matches the XLA branch's semantics:
                     # nn.GroupNorm casts to the module dtype, THEN swish runs
                     # in that dtype.
-                    y = fused_group_norm(h.reshape(B * F, H * W, C), scale,
+                    y = fused_group_norm(h.reshape(N, H * W, C), scale,
                                          bias, 32, 1e-6, self.act, self.dtype)
-                    return y.reshape(B, F, H, W, C)
-                # Silent fallbacks hide perf cliffs: paper256's top level
-                # loses the fused kernel here and the byte budget regresses
-                # with no trace. One line per (H·W, C, dtype) per process —
-                # fired at trace time, so steady-state steps stay clean.
+                    return y.reshape(N, H, W, C)
+                # A fallback says so: one line per (H·W, C, dtype) per
+                # process, fired at trace time, so steady-state steps stay
+                # clean. What the XLA path costs is the compiler's to
+                # decide: between two convolutions of (B·F, H, W, C) the
+                # chip's fuses the statistics into the convolution before
+                # and the apply into the one after, no pass of its own
+                # (PERF.md §6, PR 31).
                 from novel_view_synthesis_3d_tpu.utils.profiling import (
                     log_once)
 
@@ -142,13 +159,15 @@ class GroupNorm(nn.Module):
                     f"(H·W={H * W}, C={C}, {h.dtype}): "
                     f"{H * W * C * jnp.dtype(h.dtype).itemsize} bytes exceeds "
                     "the kernel's VMEM budget (ops/fused_groupnorm.py) — "
-                    "this level pays ~3 HBM passes per GN instead of 2")
+                    "this level's norm is XLA's to fuse into its neighbours")
             norm = nn.GroupNorm(num_groups=32, dtype=self.dtype)
             if self.per_frame:
-                y = norm(h.reshape(B * F, H, W, C)).reshape(B, F, H, W, C)
+                y = norm(h)
             else:
                 # Reference-compat: statistics reduce over (F, H, W) jointly.
-                y = norm(h)
+                F = self.frames
+                assert F and N % F == 0, (N, F)
+                y = norm(h.reshape(N // F, F, H, W, C)).reshape(h.shape)
             return nonlinearity(y) if self.act == "swish" else y
 
 
@@ -234,6 +253,7 @@ class ResnetBlock(nn.Module):
     per_frame_gn: bool = True
     fused_gn: bool = False
     fused_epilogue: bool = False
+    frames: Optional[int] = None  # read by GroupNorm(per_frame=False) alone
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -247,7 +267,7 @@ class ResnetBlock(nn.Module):
         features = C if self.features is None else self.features
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         gn_kw = dict(per_frame=self.per_frame_gn, fused=self.fused_gn,
-                     dtype=self.dtype)
+                     frames=self.frames, dtype=self.dtype)
 
         h = GroupNorm(act="swish", **gn_kw)(h_in)
         if self.resample is not None:
@@ -259,7 +279,7 @@ class ResnetBlock(nn.Module):
                 h = updown(h)
                 h_in = updown(h_in)
         h = FrameConv(features, **kw)(h)
-        B, F, H, W, _ = h.shape
+        N, H, W, _ = h.shape
         if (self.fused_epilogue and self.per_frame_gn
                 and epilogue_fits_vmem(H * W, features, h.dtype)):
             # Fused GN → FiLM-modulate → swish tail (one HBM pass,
@@ -268,14 +288,14 @@ class ResnetBlock(nn.Module):
             gscale, gbias = _GNParamsNested(features=features,
                                             name="GroupNorm_1")()
             fscale, fshift = FiLM(features=features, **kw)(None, emb)
-            flat = (B * F, H * W, features)
+            flat = (N, H * W, features)
             with jax.named_scope("lk.gn"):
                 h = fused_film_epilogue(
                     h.reshape(flat),
                     gscale, gbias,
                     jnp.broadcast_to(fscale, h.shape).reshape(flat),
                     jnp.broadcast_to(fshift, h.shape).reshape(flat),
-                    32, 1e-6, self.dtype).reshape(B, F, H, W, features)
+                    32, 1e-6, self.dtype).reshape(N, H, W, features)
         else:
             if self.fused_epilogue and self.per_frame_gn:
                 from novel_view_synthesis_3d_tpu.utils.profiling import (
@@ -287,8 +307,8 @@ class ResnetBlock(nn.Module):
                     f"note: fused block epilogue falling back to XLA for "
                     f"slab (H·W={H * W}, C={features}, {h.dtype}): 3× "
                     "resident rows exceed the kernel's VMEM budget "
-                    "(ops/fused_epilogue.py) — this level pays the "
-                    "three-pass GN→FiLM→swish tail")
+                    "(ops/fused_epilogue.py) — this level's GN→FiLM→swish "
+                    "tail is XLA's to fuse into the next convolution")
             h = FiLM(features=features, **kw)(GroupNorm(**gn_kw)(h), emb)
             with jax.named_scope("lk.gn"):
                 h = nonlinearity(h)
@@ -366,9 +386,14 @@ class AttnBlock(nn.Module):
     batched over B·F in one call. 'cross': frame i attends to the
     concatenation of all *other* frames' pre-update tokens (for F=2 this is
     exactly the reference's frame0↔frame1 exchange). Residual scaled 1/√2.
+
+    Takes and returns (B·F, H, W, C) like its neighbours; cross attention
+    alone tells a sample's `frames` apart, as (B, F, H·W, C) tokens formed
+    and dissolved in here.
     """
 
     attn_type: str
+    frames: int
     attn_heads: int = 4
     out_proj: bool = False
     use_flash: bool = False
@@ -382,14 +407,15 @@ class AttnBlock(nn.Module):
 
     @nn.compact
     def __call__(self, h_in: jnp.ndarray) -> jnp.ndarray:
-        B, F, H, W, C = h_in.shape
+        N, H, W, C = h_in.shape
+        F = self.frames
         h = GroupNorm(per_frame=self.per_frame_gn, fused=self.fused_gn,
-                      dtype=self.dtype)(h_in)
+                      frames=F, dtype=self.dtype)(h_in)
         # Everything after the norm is `attn`: the one stamp covers the
         # AttnLayer (this block is its only caller) and the block's own
         # token shuffling and residual.
         with jax.named_scope("lk.attn"):
-            tokens = h.reshape(B, F, H * W, C)
+            tokens = h.reshape(N, H * W, C)
             layer = AttnLayer(attn_heads=self.attn_heads,
                               out_proj=self.out_proj,
                               use_flash=self.use_flash,
@@ -397,12 +423,11 @@ class AttnBlock(nn.Module):
                               ring=self.ring,
                               dtype=self.dtype, param_dtype=self.param_dtype)
             if self.attn_type == "self":
-                out = layer(q=tokens.reshape(B * F, H * W, C),
-                            kv=tokens.reshape(B * F, H * W, C))
-                out = out.reshape(B, F, H * W, C)
+                out = layer(q=tokens, kv=tokens)
             elif self.attn_type == "cross":
                 if F < 2:
                     raise ValueError("cross-frame attention needs F >= 2")
+                tokens = tokens.reshape(N // F, F, H * W, C)
                 outs = []
                 for i in range(F):
                     others = [tokens[:, j] for j in range(F) if j != i]
@@ -411,8 +436,7 @@ class AttnBlock(nn.Module):
                 out = jnp.stack(outs, axis=1)
             else:
                 raise NotImplementedError(self.attn_type)
-            out = out.reshape(B, F, H, W, C)
-            return (out + h_in) * INV_SQRT2
+            return (out.reshape(h_in.shape) + h_in) * INV_SQRT2
 
 
 class XUNetBlock(nn.Module):
@@ -422,6 +446,7 @@ class XUNetBlock(nn.Module):
     """
 
     features: int
+    frames: int
     use_attn: bool = False
     attn_heads: int = 4
     attn_out_proj: bool = False
@@ -440,6 +465,7 @@ class XUNetBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray, emb: Emb) -> jnp.ndarray:
         kw = dict(per_frame_gn=self.per_frame_gn, fused_gn=self.fused_gn,
+                  frames=self.frames,
                   dtype=self.dtype, param_dtype=self.param_dtype)
         attn_kw = dict(attn_heads=self.attn_heads, out_proj=self.attn_out_proj,
                        use_flash=self.attn_use_flash,
@@ -451,6 +477,6 @@ class XUNetBlock(nn.Module):
                         **kw)(x, emb, train=self.train)
         if self.use_attn:
             h = AttnBlock(attn_type="self", **attn_kw)(h)
-            if h.shape[1] >= 2:
+            if self.frames >= 2:
                 h = AttnBlock(attn_type="cross", **attn_kw)(h)
         return h

@@ -19,6 +19,14 @@ Batch contract (canonical keys, reference train.py:23-34):
   R1, t1 (B, 3, 3) / (B, 3) or (B, Fc, ...)  cond camera cam→world pose(s)
   R2, t2 (B, 3, 3) / (B, 3)                  target camera pose
   K      (B, 3, 3)                           shared pinhole intrinsics
+
+Inside, between the stem and the selection of the target frame, the
+activation is (B·F, H, W, C) — rows batch-major, a sample's F frames
+adjacent — and so is everything added to it (each level's pose embedding,
+the logsnr embedding repeated per frame): models/layers.py says why. What
+crosses the boundary keeps its (B, F, …) shape — `x`, `cond_feats`,
+`pose_embs` (precompute_pose_embs, precompute_guidance_pose_embs) — and is
+flattened once where it enters (`_rows`).
 """
 
 from __future__ import annotations
@@ -59,6 +67,24 @@ def _as_frames(arr: jnp.ndarray, frame_rank: int) -> jnp.ndarray:
     if arr.ndim == frame_rank:
         return arr[:, None]
     return arr
+
+
+def _rows(arr: jnp.ndarray) -> jnp.ndarray:
+    """(B, F, …) → (B·F, …): the form the network carries."""
+    return arr.reshape((-1,) + arr.shape[2:])
+
+
+def _frames(arr: jnp.ndarray, F: int) -> jnp.ndarray:
+    """(B·F, …) → (B, F, …): the form that crosses the model's boundary."""
+    return arr.reshape((-1, F) + arr.shape[1:])
+
+
+def _num_frames(batch: dict) -> int:
+    """F = conditioning frames + the target, from the batch's shapes."""
+    if "cond_feats" in batch:
+        return batch["cond_feats"].shape[1] + 1
+    x = batch["x"]
+    return (1 if x.ndim == 4 else x.shape[1]) + 1
 
 
 def _named_remat(policy=None):
@@ -106,7 +132,9 @@ class ConditioningProcessor(nn.Module):
     """logsnr + camera-pose conditioning → per-level FiLM embeddings.
 
     Reference: model/xunet.py:142-203. Produces `logsnr_emb` (B, emb_ch) and
-    one (B, F, H/2ˡ, W/2ˡ, emb_ch) pose embedding per UNet resolution level.
+    one (B·F, H/2ˡ, W/2ˡ, emb_ch) pose embedding per UNet resolution level
+    (precomputed ones arrive as (B, F, …), a pair's parts each, and leave
+    in the same rows-flattened form).
     """
 
     emb_ch: int
@@ -174,7 +202,7 @@ class ConditioningProcessor(nn.Module):
         # zeroes the pose embedding, xunet.py:174-179 in the reference).
         # init() never takes this path, so the param tree is unchanged.
         if "pose_embs" in batch:
-            return logsnr_emb, list(batch["pose_embs"])
+            return logsnr_emb, jax.tree.map(_rows, list(batch["pose_embs"]))
 
         with jax.named_scope("lk.pose"):
             # --- pose embeddings (reference xunet.py:158-173) ---
@@ -225,6 +253,7 @@ class ConditioningProcessor(nn.Module):
 
             # Per-resolution strided downsampling of the full-res embedding
             # (reference xunet.py:197-202): one conv per level, stride 2ˡ.
+            pose_emb = _rows(pose_emb)
             pose_embs = []
             for i_level in range(self.num_resolutions):
                 pose_embs.append(FrameConv(
@@ -266,7 +295,8 @@ def precompute_pose_embs(model: "XUNet", params, cond: dict,
                  logsnr=jnp.zeros((B,)))
     _, pose_embs = proc.apply({"params": params["ConditioningProcessor_0"]},
                               batch, cond_mask)
-    return tuple(pose_embs)
+    F = _num_frames(cond)
+    return tuple(_frames(p, F) for p in pose_embs)
 
 
 def precompute_guidance_pose_embs(model: "XUNet", params, cond: dict):
@@ -334,8 +364,9 @@ def precompute_cond_feats(model: "XUNet", params, cond: dict) -> jnp.ndarray:
         x = x[:, None]
     conv = FrameConv(cfg.ch, dtype=jnp.dtype(cfg.dtype),
                      param_dtype=jnp.dtype(cfg.param_dtype))
-    return conv.apply({"params": params["FrameConv_0"]},
-                      x.astype(jnp.dtype(cfg.dtype)))
+    return _frames(conv.apply({"params": params["FrameConv_0"]},
+                              _rows(x.astype(jnp.dtype(cfg.dtype)))),
+                   x.shape[1])
 
 
 def pipeline_op_specs(cfg: ModelConfig):
@@ -422,7 +453,8 @@ class XUNet(nn.Module):
     monolithic forward — while `ops=(a, b)` runs the half-open slice
     [a, b) for pipeline-stage execution (parallel/pipeline.py): a slice
     starting at 0 consumes `batch`/`cond_mask` and later slices consume
-    `carry` (the (h, skip-stack, logsnr_emb, pose_embs) state); a slice
+    `carry` (the (h, skip-stack, logsnr_emb, pose_embs) state, `h`, the
+    skips and each pose embedding as (B·F, H/2ˡ, W/2ˡ, ·)); a slice
     ending before the last op returns the carry instead of the output.
     `batch` is still required for ops>0 slices — only its SHAPES are used
     (e.g. the output-channel count), never its values.
@@ -464,8 +496,10 @@ class XUNet(nn.Module):
         param_dtype = jnp.dtype(cfg.param_dtype)
         kw = dict(dtype=dtype, param_dtype=param_dtype)
         fused_gn = resolve_fused_gn(cfg.use_fused_groupnorm)
+        F = _num_frames(batch)
         blk_kw = dict(per_frame_gn=cfg.groupnorm_per_frame,
                       fused_gn=fused_gn,
+                      frames=F,
                       fused_epilogue=resolve_fused_epilogue(
                           cfg.use_fused_epilogue),
                       **kw)
@@ -515,29 +549,31 @@ class XUNet(nn.Module):
                     # here. Bitwise identical to the joint conv below
                     # (per-frame batch rows are independent).
                     # init() never takes this path: param tree unchanged.
-                    hz = batch["z"][:, None].astype(dtype)
-                    hz = FrameConv(cfg.ch, name=info["stem"], **kw)(hz)
-                    h = jnp.concatenate(
-                        [batch["cond_feats"].astype(hz.dtype), hz], axis=1)
+                    hz = FrameConv(cfg.ch, name=info["stem"], **kw)(
+                        batch["z"].astype(dtype))
+                    h = _rows(jnp.concatenate(
+                        [batch["cond_feats"].astype(hz.dtype), hz[:, None]],
+                        axis=1))
                 else:
                     x = batch["x"]
                     if x.ndim == 4:  # (B,H,W,3) → (B,1,H,W,3)
                         x = x[:, None]
                     h = jnp.concatenate([x, batch["z"][:, None]],
                                         axis=1).astype(dtype)
-                    h = FrameConv(cfg.ch, name=info["stem"], **kw)(h)
+                    h = FrameConv(cfg.ch, name=info["stem"], **kw)(_rows(h))
                 return (h, (h,), logsnr_emb, tuple(pose_embs))
 
             h, hs, logsnr_emb, pose_embs = state
 
             def level_emb(i_level):
-                # (B, 1, 1, 1, emb) + (B, F, H/2ˡ, W/2ˡ, emb) broadcast add.
+                # (B·F, 1, 1, emb) + (B·F, H/2ˡ, W/2ˡ, emb) broadcast add,
+                # a sample's logsnr embedding on each of its F rows.
                 # A level given as a pair (precompute_guidance_pose_embs:
                 # leading rows at full extent, the rest at 1 × 1) stays a
                 # pair, each part with its own rows of logsnr_emb.
                 pose = pose_embs[i_level]
                 with jax.named_scope("lk.emb"):
-                    lemb = logsnr_emb[:, None, None, None, :]
+                    lemb = jnp.repeat(logsnr_emb, F, axis=0)[:, None, None, :]
                     if not isinstance(pose, tuple):
                         return lemb + pose
                     full, per_frame = pose
@@ -549,7 +585,7 @@ class XUNet(nn.Module):
                     return lemb[:n] + full, lemb[n:] + per_frame
 
             if kind == "down_block":
-                use_attn = h.shape[3] in cfg.attn_resolutions
+                use_attn = h.shape[2] in cfg.attn_resolutions
                 h = block(info["features"], use_attn, h,
                           level_emb(info["level"]), train, info["name"])
                 return (h, hs + (h,), logsnr_emb, pose_embs)
@@ -562,14 +598,14 @@ class XUNet(nn.Module):
                 return (h, hs + (h,), logsnr_emb, pose_embs)
             if kind == "middle":
                 # Bottleneck features = ch·ch_mult[-1], ref xunet.py:248-255.
-                use_attn = h.shape[3] in cfg.attn_resolutions
+                use_attn = h.shape[2] in cfg.attn_resolutions
                 h = block(info["features"], use_attn, h,
                           level_emb(num_resolutions - 1), train,
                           info["name"])
                 return (h, hs, logsnr_emb, pose_embs)
             if kind == "up_block":
                 # Skip-concat then block (num_res_blocks+1 per level).
-                use_attn = hs[-1].shape[3] in cfg.attn_resolutions
+                use_attn = hs[-1].shape[2] in cfg.attn_resolutions
                 h = jnp.concatenate([h, hs[-1]], axis=-1)
                 h = block(info["features"], use_attn, h,
                           level_emb(info["level"]), train, info["name"])
@@ -584,12 +620,13 @@ class XUNet(nn.Module):
             assert kind == "final", kind
             assert not hs
             h = GroupNorm(per_frame=cfg.groupnorm_per_frame, act="swish",
-                          fused=fused_gn, dtype=dtype, name=info["gn"])(h)
+                          fused=fused_gn, frames=F, dtype=dtype,
+                          name=info["gn"])(h)
             # Zero-init output conv in float32 for stable noise predictions.
             out = FrameConv(C, zero_init=True, dtype=jnp.float32,
                             param_dtype=param_dtype, name=info["out"])(
                 h.astype(jnp.float32))
-            return out[:, -1]
+            return _frames(out, F)[:, -1]
 
         specs = pipeline_op_specs(cfg)
         a, b = (0, len(specs)) if ops is None else ops

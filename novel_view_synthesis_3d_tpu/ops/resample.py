@@ -1,4 +1,5 @@
-"""Spatial up/down-sampling for frame-stacked feature maps (B, F, H, W, C).
+"""Spatial up/down-sampling for feature maps (…, H, W, C): any leading axes,
+(B·F,) as the X-UNet carries them or (B, F).
 
 Behavior-matches /root/reference/model/xunet.py:14-21: 2× nearest-neighbor
 upsampling via broadcast (no gather — XLA lowers this to a cheap reshape
@@ -11,19 +12,19 @@ import jax.numpy as jnp
 
 
 def nearest_neighbor_upsample(h: jnp.ndarray, k: int = 2) -> jnp.ndarray:
-    """(B, F, H, W, C) → (B, F, kH, kW, C) by nearest neighbor."""
-    B, F, H, W, C = h.shape
-    h = h.reshape(B, F, H, 1, W, 1, C)
-    h = jnp.broadcast_to(h, (B, F, H, k, W, k, C))
-    return h.reshape(B, F, H * k, W * k, C)
+    """(…, H, W, C) → (…, kH, kW, C) by nearest neighbor."""
+    *lead, H, W, C = h.shape
+    h = h.reshape(*lead, H, 1, W, 1, C)
+    h = jnp.broadcast_to(h, (*lead, H, k, W, k, C))
+    return h.reshape(*lead, H * k, W * k, C)
 
 
 def avgpool_downsample(h: jnp.ndarray, k: int = 2) -> jnp.ndarray:
-    """(B, F, H, W, C) → (B, F, H/k, W/k, C) by k×k mean pooling.
+    """(…, H, W, C) → (…, H/k, W/k, C) by k×k mean pooling.
 
     Implemented as a reshape + mean (not a conv): maps to a pure VPU
     reduction on TPU with no MXU round-trip.
     """
-    B, F, H, W, C = h.shape
-    h = h.reshape(B, F, H // k, k, W // k, k, C)
-    return h.mean(axis=(3, 5))
+    *lead, H, W, C = h.shape
+    h = h.reshape(*lead, H // k, k, W // k, k, C)
+    return h.mean(axis=(-4, -2))

@@ -141,60 +141,65 @@ def test_dropout_train_uses_rng():
 
 
 # ---------------------------------------------------------------------------
-# Blocks
+# Blocks: each takes and returns (B·F, H, W, C), rows batch-major, F beside it
 # ---------------------------------------------------------------------------
 def test_groupnorm_per_frame_vs_shared():
     h = jax.random.normal(jax.random.PRNGKey(0), (2, 2, 8, 8, 32))
     # Make frame 1 have a huge offset; per-frame GN must normalize each frame
     # to ~zero mean independently, shared GN must not.
-    h = h.at[:, 1].add(100.0)
+    h = h.at[:, 1].add(100.0).reshape(4, 8, 8, 32)
     gn_pf = GroupNorm(per_frame=True)
     out_pf = gn_pf.apply(gn_pf.init(jax.random.PRNGKey(1), h), h)
-    gn_sh = GroupNorm(per_frame=False)
+    gn_sh = GroupNorm(per_frame=False, frames=2)
     out_sh = gn_sh.apply(gn_sh.init(jax.random.PRNGKey(1), h), h)
-    m0 = float(jnp.abs(out_pf[:, 1].mean()))
-    m1 = float(jnp.abs(out_sh[:, 1].mean()))
+    assert out_pf.shape == out_sh.shape == h.shape
+    m0 = float(jnp.abs(out_pf[1::2].mean()))
+    m1 = float(jnp.abs(out_sh[1::2].mean()))
     assert m0 < 1e-4          # per-frame: frame 1 normalized on its own
     assert m1 > 0.5           # shared stats: offset leaks through
 
 
 def test_resnet_block_resample_shapes():
-    h = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 8, 8, 32))
-    emb = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 8, 8, 32))
+    h = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 8, 32))
+    emb = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 8, 32))
     blk = ResnetBlock(features=64, resample=None)
     v = blk.init(jax.random.PRNGKey(2), h, emb, train=False)
-    assert blk.apply(v, h, emb, train=False).shape == (1, 2, 8, 8, 64)
+    assert blk.apply(v, h, emb, train=False).shape == (2, 8, 8, 64)
 
-    emb_dn = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 4, 4, 32))
+    emb_dn = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 4, 32))
     blk = ResnetBlock(resample="down")
     v = blk.init(jax.random.PRNGKey(2), h, emb_dn, train=False)
-    assert blk.apply(v, h, emb_dn, train=False).shape == (1, 2, 4, 4, 32)
+    assert blk.apply(v, h, emb_dn, train=False).shape == (2, 4, 4, 32)
 
-    emb_up = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 16, 16, 32))
+    emb_up = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16, 32))
     blk = ResnetBlock(resample="up")
     v = blk.init(jax.random.PRNGKey(2), h, emb_up, train=False)
-    assert blk.apply(v, h, emb_up, train=False).shape == (1, 2, 16, 16, 32)
+    assert blk.apply(v, h, emb_up, train=False).shape == (2, 16, 16, 32)
 
 
 def test_attn_block_cross_matches_reference_semantics_f2():
     """For F=2, generalized cross attention must reduce to frame0↔frame1
     with PRE-update frame-0 keys (reference model/xunet.py:118-121)."""
     h = jax.random.normal(jax.random.PRNGKey(0), (2, 2, 4, 4, 32))
-    blk = AttnBlock(attn_type="cross", attn_heads=4)
-    v = blk.init(jax.random.PRNGKey(1), h)
-    out = blk.apply(v, h)
-    assert out.shape == h.shape
+
+    def rows(a):
+        return a.reshape((4,) + a.shape[2:])
+
+    blk = AttnBlock(attn_type="cross", frames=2, attn_heads=4)
+    v = blk.init(jax.random.PRNGKey(1), rows(h))
+    out = blk.apply(v, rows(h))
+    assert out.shape == rows(h).shape
     # Permuting the two frames on input permutes them on output (symmetry of
     # the shared-weight cross exchange).
-    h_swap = h[:, ::-1]
-    out_swap = blk.apply(v, h_swap)
-    np.testing.assert_allclose(np.asarray(out_swap), np.asarray(out[:, ::-1]),
-                               atol=1e-5)
+    out_swap = blk.apply(v, rows(h[:, ::-1]))
+    np.testing.assert_allclose(
+        np.asarray(out_swap),
+        np.asarray(rows(out.reshape(h.shape)[:, ::-1])), atol=1e-5)
 
 
 def test_film_zero_emb_is_identity():
-    h = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 4, 4, 8))
-    emb = jnp.zeros((1, 2, 4, 4, 8))
+    h = jax.random.normal(jax.random.PRNGKey(0), (2, 4, 4, 8))
+    emb = jnp.zeros((2, 4, 4, 8))
     film = FiLM(features=8)
     v = film.init(jax.random.PRNGKey(1), h, emb)
     # Dense(swish(0)) = bias-init = 0 → scale=shift=0 → identity.
@@ -203,14 +208,14 @@ def test_film_zero_emb_is_identity():
 
 
 def test_frameconv_equivalent_to_per_frame_conv():
-    h = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 8, 8, 4))
+    h = jax.random.normal(jax.random.PRNGKey(0), (6, 8, 8, 4))
     conv = FrameConv(6)
     v = conv.init(jax.random.PRNGKey(1), h)
     out = conv.apply(v, h)
-    assert out.shape == (2, 3, 8, 8, 6)
+    assert out.shape == (6, 8, 8, 6)
     # Frame independence: conv(frames separately) == conv(stacked).
-    out0 = conv.apply(v, h[:, :1])
-    np.testing.assert_allclose(np.asarray(out[:, :1]), np.asarray(out0),
+    out0 = conv.apply(v, h[::3])
+    np.testing.assert_allclose(np.asarray(out[::3]), np.asarray(out0),
                                atol=1e-5)
 
 

@@ -60,14 +60,16 @@ TOY = {
         "model.use_flash_attention": True},
 }
 # (preset, "cpu" | "v5e") → sha256 of the lowered text, from the parent
-# of the PR that last meant to change it (CHANGES.md, PR 30).
+# of the PR that last meant to change it (CHANGES.md, PR 30) — `paper256`'s
+# two from PR 31's own tree, which meant to change that program (the
+# X-UNet carries (B·F, H, W, C)) and no other.
 DIGESTS = {
     ("paper256", "cpu"):
-        "bd3e5b2ab3cf08e37531729ca8fe60b93e85e8ff14d43b9ed14a2eac3640564b",
+        "39347a7dc4a454945a858ac36c13fad5a51d48cd03e335f9c293d145eaad0b23",
     ("ms4_denoiser128", "cpu"):
         "b543c837af4ac88bdc87d448985cc8528042f2a069535005fc388d714e9c060b",
     ("paper256", "v5e"):
-        "2a63bc4201e17fb3349dcf656b8f1463d4354cb23fcb03ceea931efefbbc0bd2",
+        "63517c08226f48f0a0478fde26dc9d9b22e2bfe776035c1783a9bf535b087e71",
     ("ms4_denoiser128", "v5e"):
         "c733feca9b7be61d75490621feba9e2a21c6cbc0318a9f6bfe7bd91ef825aa22",
 }

@@ -374,7 +374,7 @@ def test_fused_gn_over_vmem_fallback_logs_once(capsys):
     C = 96  # 128·128·96·4 B ≈ 6.3 MiB > the 3 MiB slab budget
     assert not fits_vmem(H * W, C, jnp.float32)
     gn = GroupNorm(per_frame=True, fused=True)
-    x = jnp.ones((1, 1, H, W, C), jnp.float32)
+    x = jnp.ones((1, H, W, C), jnp.float32)
     params = gn.init(jax.random.PRNGKey(0), x)
     y = gn.apply(params, x)
     assert y.shape == x.shape

@@ -277,15 +277,15 @@ def test_admitted_slabs_are_cases():
 _HLO_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
 
 
-def _bytes_written_by_kind(text):
-    """Bytes each top-level instruction of a compiled program's entry
-    writes, summed by the layer kind its `op_name` is stamped with
-    (models/xunet.layer_of); what a fusion keeps inside is not written."""
+def _entry_writes(text):
+    """(opcode, layer kind, `op_name`, bytes written) of each top-level
+    instruction of a compiled program's entry; the kind is the one its
+    `op_name` is stamped with (models/xunet.layer_of). What a fusion
+    keeps inside is not written."""
     import re
 
     from novel_view_synthesis_3d_tpu.models.xunet import layer_of
 
-    written = {}
     for line in text[text.index("ENTRY"):].splitlines():
         m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
         name = re.search(r'op_name="([^"]*)"', line)
@@ -297,7 +297,13 @@ def _bytes_written_by_kind(text):
             _HLO_BYTES[dt] * int(np.prod([int(d) for d in dims.split(",")]))
             for dt, dims in re.findall(r"\b(bf16|f32|s32|u32|pred)\[([\d,]+)\]",
                                        m.group(1)))
-        kind = layer_of(name.group(1))[1]
+        yield m.group(2), layer_of(name.group(1))[1], name.group(1), size
+
+
+def _bytes_written_by_kind(text):
+    """Bytes the entry's top-level instructions write, summed by kind."""
+    written = {}
+    for _, kind, _, size in _entry_writes(text):
         written[kind] = written.get(kind, 0) + size
     return written
 
@@ -307,9 +313,12 @@ def test_film_pair_buys_no_pass_over_h_on_v5e(v5e):
     with a guidance pair's embedding (conditional rows at full extent,
     unconditional rows at 1 × 1) and with the same rows at full extent.
     With the pair, what is written under `lk.emb` is the conditional
-    rows' projection and nothing else of its size — no (scale, shift) at
-    full extent for every row, no copy, slice or concatenate of `h` — and
-    the norms and convolutions write what they write at full extent. It
+    rows' projection, once as the matmul writes it (W in the sublanes)
+    and once re-laid to the convolutions' tiling (the rows in the
+    sublanes; `copy_add_fusion`, PERF.md §7), and nothing else of its
+    size — no (scale, shift) at full extent for every row, no copy, slice
+    or concatenate of `h` — and the norms and convolutions write what
+    they write at full extent. It
     holds only while XLA:TPU fuses FiLM's pad → split → sum into the
     modulation's one pass over `h` (models/layers.FiLM says which other
     orders cost which passes); the chip would show a loss as `gn` time."""
@@ -324,21 +333,83 @@ def test_film_pair_buys_no_pass_over_h_on_v5e(v5e):
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e),
         jax.eval_shape(lambda: block.init(
-            jax.random.PRNGKey(0), jnp.zeros((2 * n, F, 8, 8, C), BF16),
-            jnp.zeros((2 * n, F, 8, 8, E), BF16), train=False)))
-    h = S(2 * n, F, side, side, C)
+            jax.random.PRNGKey(0), jnp.zeros((2 * n * F, 8, 8, C), BF16),
+            jnp.zeros((2 * n * F, 8, 8, E), BF16), train=False)))
+    h = S(2 * n * F, side, side, C)
     written = {
         name: _bytes_written_by_kind(jax.jit(
             lambda p, h, e: block.apply(p, h, e, train=False)
         ).lower(params, h, emb).compile().as_text())
-        for name, emb in (("pair", (S(n, F, side, side, E),
-                                    S(n, F, 1, 1, E))),
-                          ("full", S(2 * n, F, side, side, E)))}
+        for name, emb in (("pair", (S(n * F, side, side, E),
+                                    S(n * F, 1, 1, E))),
+                          ("full", S(2 * n * F, side, side, E)))}
     cond_projection = n * F * side * side * 2 * C * 2  # bf16 (scale, shift)
-    assert written["full"]["emb"] >= 2 * cond_projection, written
-    assert written["pair"]["emb"] <= 1.02 * cond_projection, written
+    assert written["full"]["emb"] >= 4 * cond_projection, written
+    assert written["pair"]["emb"] <= 2.02 * cond_projection, written
     for kind in written["full"]:
         if kind != "emb":
             assert written["pair"].get(kind, 0) <= 1.01 * written["full"][
                 kind], (kind, written)
     assert set(written["pair"]) <= set(written["full"]), written
+
+
+@pytest.mark.parametrize("skips", [False, True],
+                         ids=["256_to_256", "up_512_to_256"])
+def test_resnet_blocks_keep_the_convolutions_layout_on_v5e(skips, v5e):
+    """Two chained ResnetBlocks at paper256's level-0 shape (8 rows, 256
+    px, 256 channels, a guidance pair's embedding) between a stem and a
+    head convolution, compiled for the chip; with `skips` each block's
+    input is the up path's channel concatenation with a skip (512 → 256).
+
+    The network carries (B·F, H, W, C), so XLA:TPU keeps the
+    convolutions' layout (`{3,0,2,1:T(8,128)}`: the rows in the sublanes)
+    through a block: no `copy`, `reshape` or `transpose` of `h`'s size
+    under any stamp, none for the concatenation, and a block
+    writes 4 passes of `h` — its two convolutions (the norms' apply, the
+    swishes, FiLM's modulation and the residual sum fused into them), the
+    conditional rows' FiLM projection and that projection's relayout —
+    and a fifth, the 1 × 1 skip projection, where the channels change.
+    While `h` was (B, F, H, W, C) every reshape to and from B·F was a
+    copy to row-major and the passes between two convolutions ran apart:
+    6 and 11 passes a block in this fragment (PERF.md §6, PR 31)."""
+    import flax.linen as nn
+
+    from novel_view_synthesis_3d_tpu.models.layers import (
+        FrameConv, ResnetBlock)
+
+    # 2 views × 2 guidance halves × 2 frames; the conditional half's rows
+    rows, cond_rows, side, C, E = 8, 4, 256, 256, 1024
+
+    class Chain(nn.Module):
+        @nn.compact
+        def __call__(self, x, emb):
+            h = FrameConv(C, dtype=BF16)(x)
+            skip = FrameConv(C, dtype=BF16)(x) if skips else None
+            for _ in range(2):
+                if skips:
+                    h = jnp.concatenate([h, skip], axis=-1)
+                h = ResnetBlock(features=C, dtype=BF16)(h, emb, train=False)
+            return FrameConv(3, dtype=BF16)(h)
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=v5e)
+
+    chain = Chain()
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e),
+        jax.eval_shape(lambda: chain.init(
+            jax.random.PRNGKey(0), jnp.zeros((rows, 8, 8, C), BF16),
+            jnp.zeros((rows, 8, 8, E), BF16))))
+    emb = (S(cond_rows, side, side, E), S(rows - cond_rows, 1, 1, E))
+    text = jax.jit(chain.apply).lower(
+        params, S(rows, side, side, C), emb).compile().as_text()
+    h_bytes = rows * side * side * C * 2
+    writes = list(_entry_writes(text))
+    moved = [(op, kind, name) for op, kind, name, size in writes
+             if op in ("copy", "reshape", "transpose")
+             and size >= h_bytes // 2]
+    assert not moved, moved
+    in_blocks = sum(size for _, _, name, size in writes
+                    if "ResnetBlock_" in name or "concatenate" in name)
+    assert in_blocks <= 2 * (5 if skips else 4) * h_bytes * 1.01, (
+        in_blocks / h_bytes)
