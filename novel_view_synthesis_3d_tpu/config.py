@@ -71,9 +71,10 @@ class TokenTrunkConfig:
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
-    # What the expert layer both trunks share (models/token_denoiser.py:
+    # What the expert layer every trunk shares (models/token_denoiser.py:
     # `route`, `held_expert_part`) reads besides the fields above.
     expert_activation = "silu"
+    router_activation = "softmax"
 
 
 _WINDOW_PERIOD = (0, 1, 1, 1)
@@ -112,8 +113,9 @@ class SmallThinkerTrunkConfig:
     patch_size: int = 4
 
     # The expert layer's names for the same things (`route`,
-    # `held_expert_part` are one function each for both trunks).
+    # `held_expert_part` are one function each for every trunk).
     expert_activation = "relu"
+    router_activation = "softmax"
     routed_scaling_factor = 1.0
 
     @property
@@ -125,9 +127,104 @@ class SmallThinkerTrunkConfig:
         return self.moe_num_active_primary_experts
 
 
+@dataclasses.dataclass(frozen=True)
+class KimiLinearAttnConfig:
+    """`linear_attn_config` of a `kimi_linear` config.json, key for key:
+    which layers (1-BASED) are KDA and which full attention, the KDA
+    heads and their size, the short convolution's taps."""
+
+    full_attn_layers: Tuple[int, ...] = (4, 8, 12, 16, 20, 24, 27)
+    kda_layers: Tuple[int, ...] = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15,
+                                   17, 18, 19, 21, 22, 23, 25, 26)
+    head_dim: int = 128
+    num_heads: int = 32
+    short_conv_kernel_size: int = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiLinearTrunkConfig:
+    """The token denoiser's third trunk: Kimi-Linear-48B-A3B-Instruct's
+    decoder stack under the key names of its `config.json` — layers of two
+    kinds by index (`linear_attn_config`): KDA, a gated delta rule with a
+    per-channel decay behind a short causal convolution, whose cache is a
+    recurrent state; and latent attention with NO positional term
+    (`mla_use_nope`, no low-rank query path: `q_lora_rank` is null in the
+    source and has no key here), whose cache is the latent. The first
+    `first_k_dense_replace` layers carry a dense gated-SiLU MLP, the others
+    a sigmoid router over `num_experts` with a per-expert correction bias
+    in the choice, top-k renormalised and scaled, and one shared expert.
+    The defaults are the published values (the source's `head_dim` 72 is
+    read by neither kind of layer and has no key here); a preset sets the
+    depth and the experts this chip holds."""
+
+    hidden_size: int = 2304
+    num_hidden_layers: int = 27
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64     # the shared key head; never rotated
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    linear_attn_config: KimiLinearAttnConfig = dataclasses.field(
+        default_factory=KimiLinearAttnConfig)
+    first_k_dense_replace: int = 1
+    intermediate_size: int = 9216
+    hidden_act: str = "silu"
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    moe_intermediate_size: int = 1024
+    moe_router_activation_func: str = "sigmoid"
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 2.446
+    # One group of which one is taken: the grouped top-k is the identity.
+    num_expert_group: int = 1
+    topk_group: int = 1
+    rms_norm_eps: float = 1e-5
+    # As TokenTrunkConfig's: the (first, count) experts this chip holds,
+    # and the patch adapter.
+    held_experts: Tuple[int, int] = (0, 256)
+    patch_size: int = 4
+
+    # The expert layer's names for the same things.
+    @property
+    def expert_activation(self) -> str:
+        return self.hidden_act
+
+    @property
+    def router_activation(self) -> str:
+        return self.moe_router_activation_func
+
+    @property
+    def n_routed_experts(self) -> int:
+        return self.num_experts
+
+    @property
+    def num_experts_per_tok(self) -> int:
+        return self.num_experts_per_token
+
+    @property
+    def norm_topk_prob(self) -> bool:
+        return self.moe_renormalize
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def is_full_attention(self, i: int) -> bool:
+        """Layer i (0-based; the source counts from 1) is latent
+        attention; the others are KDA."""
+        return i + 1 in self.linear_attn_config.full_attn_layers
+
+    def is_dense(self, i: int) -> bool:
+        """Layer i carries the dense MLP, not experts."""
+        return i < self.first_k_dense_replace
+
+
 # The trunks `ModelConfig.tokens` may hold; a serialized config says
-# which by its keys (the two share only sizes every trunk has).
-TOKEN_TRUNKS = (TokenTrunkConfig, SmallThinkerTrunkConfig)
+# which by its keys (they share only sizes every trunk has).
+TOKEN_TRUNKS = (TokenTrunkConfig, SmallThinkerTrunkConfig,
+                KimiLinearTrunkConfig)
 
 
 def _trunk_of_keys(keys) -> type:
@@ -1925,6 +2022,25 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
                 errors.append(
                     f"model.tokens.{name} has {len(getattr(k, name))} "
                     f"entries for {k.num_hidden_layers} layers")
+    elif isinstance(k, KimiLinearTrunkConfig):
+        lin = k.linear_attn_config
+        layers = set(range(1, k.num_hidden_layers + 1))
+        kda, full = set(lin.kda_layers) & layers, \
+            set(lin.full_attn_layers) & layers
+        if kda & full or kda | full != layers:
+            errors.append(
+                "model.tokens.linear_attn_config: kda_layers and "
+                f"full_attn_layers do not divide layers 1-"
+                f"{k.num_hidden_layers} between them")
+        if (k.num_expert_group, k.topk_group) != (1, 1):
+            errors.append("model.tokens.num_expert_group and topk_group "
+                          "other than 1 (a grouped top-k) are not carried")
+        if k.moe_router_activation_func not in ("sigmoid", "softmax"):
+            errors.append("model.tokens.moe_router_activation_func must be "
+                          "'sigmoid' or 'softmax'")
+        if not k.mla_use_nope:
+            errors.append("model.tokens.mla_use_nope=False (rotary in this "
+                          "trunk's latent attention) is not carried")
     elif k.qk_rope_head_dim % 2:
         errors.append("model.tokens.qk_rope_head_dim must be even (rotary "
                       "pairs)")
@@ -1938,7 +2054,7 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
 # Config ladder presets (BASELINE.json "configs")
 # ----------------------------------------------------------------------
 PRESET_NAMES = ("reference", "tiny64", "base128", "paper256", "pod64",
-                "ms4_denoiser128", "st21_denoiser256")
+                "ms4_denoiser128", "st21_denoiser256", "kl48_denoiser256")
 
 
 def get_preset(name: str) -> Config:
@@ -2044,6 +2160,25 @@ def get_preset(name: str) -> Config:
                 family="tokens", dtype="bfloat16", param_dtype="bfloat16",
                 dropout=0.0,
                 tokens=SmallThinkerTrunkConfig(num_hidden_layers=12)),
+            data=DataConfig(img_sidelength=256),
+            diffusion=DiffusionConfig(sample_timesteps=256),
+        )
+    if name == "kl48_denoiser256":
+        # A token denoiser whose trunk is Kimi-Linear-48B-A3B-Instruct's
+        # decoder stack at its published widths (KimiLinearTrunkConfig's
+        # defaults), cut to chip 0's share of a deployment in which two
+        # chips divide each layer by expert parallelism: layers 1-5 of 27
+        # (the leading dense layer once, then a whole period of the four
+        # expert layers that follow: KDA, KDA, latent attention, KDA),
+        # experts 0-127 of 256 held (the router keeps 256 and top-8).
+        # bfloat16 parameters: 3.92 B = 7.84 GB. 256 px, 4096 tokens a
+        # frame: 64 chunks of 64 a head in every KDA layer's scan.
+        return Config(
+            model=ModelConfig(
+                family="tokens", dtype="bfloat16", param_dtype="bfloat16",
+                dropout=0.0,
+                tokens=KimiLinearTrunkConfig(num_hidden_layers=5,
+                                             held_experts=(0, 128))),
             data=DataConfig(img_sidelength=256),
             diffusion=DiffusionConfig(sample_timesteps=256),
         )

@@ -16,13 +16,18 @@
     module.
 
 `model.family` selects: "xunet" (models/xunet.XUNet, the default) or
-"tokens" (models/token_denoiser.TokenDenoiser). The token family has two
+"tokens" (models/token_denoiser.TokenDenoiser). The token family has three
 trunks behind that one class — `model.tokens` is one of
-config.TOKEN_TRUNKS and names the layer: Mistral-Small-4's (latent
-attention, a shared expert; its cache entry a latent) or SmallThinker's
+config.TOKEN_TRUNKS and names the layers: Mistral-Small-4's (latent
+attention, a shared expert; its cache entry a latent), SmallThinker's
 (grouped-query heads, a window and rotary per layer, the router ahead of
-attention; its cache entry keys and values). Entry points that carry only
-the X-UNet say so through `require_family`.
+attention; its cache entry keys and values) or Kimi-Linear's stack, whose
+layers differ BY INDEX (KDA, a gated delta rule, or latent attention
+without a positional term; a dense MLP or sigmoid-routed experts): what
+`precompute` returns holds one cache entry a layer, each of its layer's
+own kind — there a recurrent state with its convolution's tail beside a
+latent. Entry points that carry only the X-UNet say so through
+`require_family`.
 """
 
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays  # noqa: F401

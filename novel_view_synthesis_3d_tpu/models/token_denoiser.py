@@ -1,8 +1,10 @@
 """A token denoiser: patch tokens of both frames through a decoder trunk
 of a published language model, ε̂ of the target frame out.
 
-**Two trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
-and names the layer:
+**Three trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
+and names the layers; the frame asks the layer object for layer i's
+parameter tree and takes back layer i's cache entry, so a trunk's layers
+may differ by index:
 
   - `Mistral4Layer` (config.TokenTrunkConfig; Mistral-Small-4-119B-2603,
     `mistral4` config.json): RMSNorm → low-rank queries and a compressed
@@ -19,10 +21,26 @@ and names the layer:
     (`sliding_window_layout`) → RMSNorm → ReGLU experts, top-k of the
     router's softmax renormalised, no shared expert. Its cache of a frame
     is that frame's keys (rotated where the layer rotates) and values.
+  - `KimiLinearLayer` (config.KimiLinearTrunkConfig;
+    Kimi-Linear-48B-A3B-Instruct): by index (`linear_attn_config`) KDA —
+    q, k, v through a causal depthwise convolution of 4 taps and SiLU,
+    L2-normalised, a decay per head AND channel, a write strength β, the
+    gated delta rule S_t = (I − β k kᵀ) Diag(α) S_{t−1} + β k vᵀ in
+    SEQUENCE order (ops/kda.py, chunked), a head-wise RMSNorm under a
+    sigmoid gate — or latent attention with no positional term at all and
+    no low-rank query path (192-wide keys against 128-wide values); by
+    index a dense gated-SiLU MLP (the leading layers) or experts scored by
+    a SIGMOID, chosen on score + a per-expert bias, gated by the score
+    alone, renormalised and scaled, plus one shared expert. A KDA layer's
+    cache of a frame is the state after its last token (float32) and the
+    last three pre-convolution rows; a latent layer's is (c_kv, the shared
+    key part). A KDA layer has no frame rule: the target frame's scan is
+    entered with the conditioning frame's state, every step anew.
 
-`route` and `held_expert_part` are one function each for both (top-k,
-the renormalisation and the activation come from the trunk's config), as
-are the grouped product and the attention kernel under them. What is this
+`route` and `held_expert_part` are one function each for all (the scoring
+function, top-k, the renormalisation and the activation come from the
+trunk's config), as are the grouped product and the attention kernel
+under them. What is this
 repo's and not a source's is the frame around the trunk:
 
   - both frames are cut into `patch_size`² patches, one token each:
@@ -40,9 +58,9 @@ repo's and not a source's is the frame around the trunk:
 **The once-a-call pass.** Because of that mask, everything a step needs of
 the conditioning frame is its per-layer cache. `precompute` runs the
 conditioning frame through all layers once (prefill) and every denoise
-step runs the target's tokens alone against [cache ; own] (decode through
-the cache). `apply` without a cache does exactly the two in a row, so
-there is one set of equations.
+step runs the target's tokens alone against [cache ; own], or from the
+cached state (decode through the cache). `apply` without a cache does
+exactly the two in a row, so there is one set of equations.
 
 **The expert layer is told which experts it holds** (`held_experts`, a
 (first, count) range: this chip's share of an expert-parallel deployment).
@@ -69,12 +87,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from novel_view_synthesis_3d_tpu.config import (
-    ModelConfig, SmallThinkerTrunkConfig, TokenTrunkConfig)
+    KimiLinearTrunkConfig, ModelConfig, SmallThinkerTrunkConfig,
+    TokenTrunkConfig)
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
     band_key_columns, flash_attention, resolve_flash, window_binds)
 from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
     ROW_TILE, buffer_rows, grouped_matmul, span_sizes)
+from novel_view_synthesis_3d_tpu.ops.kda import kda_chunked, short_conv
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
 
 LOGSNR_CLEAN = 20.0   # the conditioning frame's logsnr: 3DiM's clean frame
@@ -172,7 +192,7 @@ def param_shapes(cfg: ModelConfig) -> dict:
     def w(*shape):
         return jax.ShapeDtypeStruct(shape, dt)
 
-    layer = trunk_layer(cfg).param_shapes(w)
+    layer = trunk_layer(cfg)
     tree = {
         "patch_in": {"kernel": w(pix, H)},
         "ray_in": {"kernel": w(RAY_CHANNELS * k.patch_size ** 2, H)},
@@ -182,7 +202,7 @@ def param_shapes(cfg: ModelConfig) -> dict:
         "out": {"kernel": w(H, pix)},
     }
     for i in range(k.num_hidden_layers):
-        tree[layer_label(i)] = layer
+        tree[layer_label(i)] = layer.param_shapes(w, i)
     return tree
 
 
@@ -198,6 +218,15 @@ def _init_leaf(key, path, s):
         return jnp.ones(s.shape, s.dtype)
     if name == "bias" or path[0] == "out":
         return jnp.zeros(s.shape, s.dtype)  # ε̂ = 0 at init, as the X-UNet
+    if name in ("A_log", "dt_bias"):
+        # KDA's decay as its public implementation starts it: a head's
+        # rate A from U(1, 16), a channel's step from log-U(1e-3, 1e-1)
+        # through the inverse of the softplus it goes through.
+        u = jax.random.uniform(key, s.shape, jnp.float32)
+        if name == "A_log":
+            return jnp.log(1.0 + 15.0 * u).astype(s.dtype)
+        dt = jnp.exp(math.log(1e-3) + u * math.log(1e2))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(s.dtype)
     fan_in = s.shape[-2]
     return (jax.random.normal(key, s.shape, jnp.float32)
             / math.sqrt(fan_in)).astype(s.dtype)
@@ -249,15 +278,25 @@ _ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route(b32, p_router, k):
-    """(top-k probabilities (T, k) float32, expert ids (T, k) int32) of
-    normalised tokens b32 (T, H) float32: softmax over ALL experts' logits
-    in float32, top-k, renormalised to sum 1 where the config says so
-    (the same numbers as a softmax over the chosen logits alone). `k` is
-    either trunk's config."""
+    """(top-k gates (T, k) float32, expert ids (T, k) int32) of normalised
+    tokens b32 (T, H) float32, scored over ALL experts in float32 as the
+    trunk's `router_activation` says. "softmax": the probabilities' top-k,
+    renormalised to sum 1 where the config says so (the same numbers as a
+    softmax over the chosen logits alone). "sigmoid": each expert's score
+    on its own; the CHOICE is the top-k of score + the router's per-expert
+    correction bias, the gate the score without it, renormalised and
+    scaled as before. `k` is any trunk's config."""
     logits = jnp.dot(b32, p_router["kernel"].astype(jnp.float32),
                      precision=HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k.num_experts_per_tok)
+    if k.router_activation == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_i = jax.lax.top_k(
+            scores + p_router["bias"].astype(jnp.float32),
+            k.num_experts_per_tok)
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+    else:
+        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                     k.num_experts_per_tok)
     if k.norm_topk_prob:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     return top_p * float(k.routed_scaling_factor), top_i.astype(jnp.int32)
@@ -326,15 +365,18 @@ def gated_mlp(x, p):
 
 
 # ---------------------------------------------------------------------------
-# The two trunks' layers. A layer object is built from the ModelConfig and
+# The trunks' layers. A layer object is built from the ModelConfig and
 # gives: `cache_name` (the batch entry `precompute` returns its caches
-# under), `param_shapes(w)`, `tables(positions)` (static numpy, made once
+# under, one entry a layer), `cache_kind(i)` (what layer i's entry is:
+# a latent, keys and values, a recurrent state), `param_shapes(w, i)`
+# (layer i's parameter tree), `tables(positions)` (static numpy, made once
 # a frame) and `__call__(i, p, h, tables, cache)` → (h, this frame's cache
 # entry, (tokens per held expert (held,), each token's chosen experts (B,
-# L, k))) for layer i over one frame's tokens h (B, L, hidden), `cache`
-# the entry of the frames before it or None; and `key_columns(L)`, the
-# (visited, visible) key columns of its windowed layers' attention over a
-# step's L target queries, (0, 0) for a trunk without windows.
+# L, k)) — (None, None) from a layer without experts) for layer i over one
+# frame's tokens h (B, L, hidden), `cache` layer i's entry of the frames
+# before it or None; and `key_columns(L)`, the (visited, visible) key
+# columns of its windowed layers' attention over a step's L target
+# queries, (0, 0) for a trunk without windows.
 # ---------------------------------------------------------------------------
 class Mistral4Layer:
     """Mistral-Small-4's layer (latent attention, a shared expert)."""
@@ -344,7 +386,11 @@ class Mistral4Layer:
     def __init__(self, config: ModelConfig):
         self.config = config
 
-    def param_shapes(self, w):
+    def cache_kind(self, i):
+        return "latent"
+
+    def param_shapes(self, w, i=0):
+        """Every layer's tree is the same."""
         k = self.config.tokens
         H, NH = k.hidden_size, k.num_attention_heads
         return {
@@ -445,7 +491,11 @@ class SmallThinkerLayer:
     def __init__(self, config: ModelConfig):
         self.config = config
 
-    def param_shapes(self, w):
+    def cache_kind(self, i):
+        return "keys_values"
+
+    def param_shapes(self, w, i=0):
+        """Every layer's tree is the same."""
         k = self.config.tokens
         H, D = k.hidden_size, k.head_dim
         return {
@@ -524,8 +574,180 @@ class SmallThinkerLayer:
         return tuple(sum(c) for c in zip(*per_layer)) if per_layer else (0, 0)
 
 
+def l2_normalise(x, eps=1e-6):
+    """x / sqrt(Σ x² + eps) over the last axis, float32."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+class KimiLinearLayer:
+    """Kimi-Linear's layers: by index KDA (a gated delta rule behind a
+    short convolution; its cache entry the state after the frame's last
+    token and the convolution's tail) or latent attention without a
+    positional term (its cache entry the latent and the shared key part);
+    by index a dense MLP or a sigmoid-routed expert layer with one shared
+    expert."""
+
+    cache_name = "layer_cache"
+
+    def __init__(self, config: ModelConfig):
+        self.config = config
+
+    def cache_kind(self, i):
+        return "latent" if self.config.tokens.is_full_attention(i) \
+            else "recurrent_state"
+
+    def param_shapes(self, w, i):
+        k = self.config.tokens
+        H = k.hidden_size
+        if k.is_full_attention(i):
+            NH = k.num_attention_heads
+            mix = {"mla": {
+                "q": {"kernel": w(H, NH * k.qk_head_dim)},
+                "kv_a": {"kernel": w(H, k.kv_lora_rank
+                                     + k.qk_rope_head_dim)},
+                "kv_norm": {"scale": w(k.kv_lora_rank)},
+                "kv_b": {"kernel": w(k.kv_lora_rank, NH * (
+                    k.qk_nope_head_dim + k.v_head_dim))},
+                "o": {"kernel": w(NH * k.v_head_dim, H)}}}
+        else:
+            lin = k.linear_attn_config
+            NH, D, K = lin.num_heads, lin.head_dim, \
+                lin.short_conv_kernel_size
+            mix = {"kda": {
+                **{n: {"kernel": w(H, NH * D)} for n in ("q", "k", "v")},
+                **{n + "_conv": {"kernel": w(K, NH * D)}
+                   for n in ("q", "k", "v")},
+                # the decay's and the output gate's low-rank pairs (rank =
+                # the head dimension), down then up
+                "f_a": {"kernel": w(H, D)}, "f_b": {"kernel": w(D, NH * D)},
+                "A_log": w(NH), "dt_bias": w(NH * D),
+                "beta": {"kernel": w(H, NH)},
+                "g_a": {"kernel": w(H, D)}, "g_b": {"kernel": w(D, NH * D)},
+                "o_norm": {"scale": w(D)},
+                "o": {"kernel": w(NH * D, H)}}}
+        if k.is_dense(i):
+            ffn = {"mlp": _mlp_shapes(w, H, k.intermediate_size)}
+        else:
+            ffn = {"router": {"kernel": w(H, k.num_experts),
+                              "bias": w(k.num_experts)},
+                   "shared": _mlp_shapes(
+                       w, H, k.moe_intermediate_size * k.num_shared_experts),
+                   "experts": _mlp_shapes(w, H, k.moe_intermediate_size,
+                                          k.held_experts[1])}
+        return {"attn_norm": {"scale": w(H)}, **mix,
+                "mlp_norm": {"scale": w(H)}, **ffn}
+
+    def tables(self, positions):
+        """No layer of this trunk has a positional term."""
+        return None
+
+    def _kda(self, layer, h, cache):
+        """h + KDA(RMSNorm(h)) over one frame's tokens h (B, L, hidden),
+        from `cache` = (the state, the convolution's tail) of the frames
+        before (None: the sequence starts here). → (h, this frame's
+        (state, tail))."""
+        k, p = self.config.tokens, layer["kda"]
+        lin = k.linear_attn_config
+        NH, D = lin.num_heads, lin.head_dim
+        B, L, _ = h.shape
+        state, tail = (None, None) if cache is None else cache
+        f32 = jnp.float32
+        with jax.named_scope("lk.kda_proj"):
+            a = rms_norm(h, layer["attn_norm"]["scale"],
+                         k.rms_norm_eps).astype(jnp.dtype(self.config.dtype))
+            qkv = jnp.concatenate([_dense(a, p[n]) for n in ("q", "k", "v")],
+                                  axis=-1)
+            # per head AND per channel, float32 from the projection on
+            g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+                _dense(_dense(a, p["f_a"]), p["f_b"]).astype(f32)
+                + p["dt_bias"].astype(f32)).reshape(B, L, NH, D)
+            beta = jax.nn.sigmoid(_dense(a, p["beta"]).astype(f32))
+            gate = jax.nn.sigmoid(
+                _dense(_dense(a, p["g_a"]), p["g_b"]).astype(f32))
+        with jax.named_scope("lk.kda_conv"):
+            taps = jnp.concatenate(
+                [p[n + "_conv"]["kernel"] for n in ("q", "k", "v")], axis=-1)
+            y, tail = short_conv(qkv, taps, tail)
+            q, keys, v = (t.reshape(B, L, NH, D)
+                          for t in jnp.split(jax.nn.silu(y), 3, axis=-1))
+            # into the scan in the compute type (it widens a row at a time)
+            q = (l2_normalise(q) * D ** -0.5).astype(a.dtype)
+            keys = l2_normalise(keys).astype(a.dtype)
+            v = v.astype(a.dtype)
+        with jax.named_scope("lk.kda_core"):
+            o, state = kda_chunked(q, keys, v, g, beta, state)
+        with jax.named_scope("lk.kda_proj"):
+            o = rms_norm(o, p["o_norm"]["scale"], k.rms_norm_eps) \
+                * gate.reshape(B, L, NH, D)
+            h = h + _dense(o.reshape(B, L, NH * D).astype(a.dtype), p["o"])
+        return h, (state, tail)
+
+    def _mla(self, layer, h, cache):
+        """h + latent attention, without a positional term, of RMSNorm(h)
+        over one frame's tokens; `cache` = (c_kv, the shared key part) of
+        the frames before."""
+        cfg, k, p = self.config, self.config.tokens, layer["mla"]
+        dt, eps = jnp.dtype(cfg.dtype), k.rms_norm_eps
+        B, L, _ = h.shape
+        NH, dn, dr, dv = (k.num_attention_heads, k.qk_nope_head_dim,
+                          k.qk_rope_head_dim, k.v_head_dim)
+        with jax.named_scope("lk.mla_proj"):
+            a = rms_norm(h, layer["attn_norm"]["scale"], eps).astype(dt)
+            q = _dense(a, p["q"]).reshape(B, L, NH, dn + dr)
+            kv_a = _dense(a, p["kv_a"])
+            c_kv = rms_norm(kv_a[..., :k.kv_lora_rank],
+                            p["kv_norm"]["scale"], eps).astype(dt)
+            k_pe = kv_a[..., k.kv_lora_rank:]       # used as it is: NoPE
+            own = (c_kv, k_pe)
+            if cache is not None:
+                c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
+                k_pe = jnp.concatenate([cache[1].astype(dt), k_pe], axis=1)
+            # Keys and values up-projected from the latent at use, as
+            # Mistral4Layer does (PERF.md, PR 26).
+            Lk = c_kv.shape[1]
+            kv = _dense(c_kv, p["kv_b"]).reshape(B, Lk, NH, dn + dv)
+            keys = jnp.concatenate(
+                [kv[..., :dn],
+                 jnp.broadcast_to(k_pe[:, :, None, :], (B, Lk, NH, dr))],
+                axis=-1)
+            values = kv[..., dn:]
+        with jax.named_scope("lk.mla_core"):
+            o = _attention(q, keys, values, (dn + dr) ** -0.5,
+                           resolve_flash(cfg.use_flash_attention))
+        with jax.named_scope("lk.mla_proj"):
+            h = h + _dense(o.reshape(B, L, NH * dv), p["o"])
+        return h, own
+
+    def __call__(self, i, p, h, tables, cache):
+        del tables
+        k = self.config.tokens
+        dt, eps = jnp.dtype(self.config.dtype), k.rms_norm_eps
+        B, L, _ = h.shape
+        mix = self._mla if k.is_full_attention(i) else self._kda
+        h, own = mix(p, h, cache)
+        if k.is_dense(i):
+            with jax.named_scope("lk.dense_mlp"):
+                b = rms_norm(h, p["mlp_norm"]["scale"], eps).astype(dt)
+                h = h + gated_mlp(b, p["mlp"])
+            return h, own, (None, None)
+        with jax.named_scope("lk.moe_route"):
+            b32 = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(B * L, -1)
+            top_p, top_i = route(b32, p["router"], k)
+            b = b32.astype(dt)
+        routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
+        with jax.named_scope("lk.moe_shared"):
+            shared = gated_mlp(b, p["shared"])
+        h = h + (shared + routed).reshape(B, L, -1)
+        return h, own, (counts, top_i.reshape(B, L, -1))
+
+    def key_columns(self, L: int):
+        """No layer of this trunk has a window."""
+        return 0, 0
+
+
 TRUNK_LAYERS = {TokenTrunkConfig: Mistral4Layer,
-                SmallThinkerTrunkConfig: SmallThinkerLayer}
+                SmallThinkerTrunkConfig: SmallThinkerLayer,
+                KimiLinearTrunkConfig: KimiLinearLayer}
 
 
 def trunk_layer(cfg: ModelConfig):
@@ -615,8 +837,9 @@ class TokenDenoiser:
 
     def _frame(self, params, tok, frame_index, caches):
         """One frame's tokens through every layer. → (h, per-layer cache
-        entries of this frame, (per-layer tokens per held expert (layers,
-        held), per-layer chosen experts, a tuple of (B, L, k)))."""
+        entries of this frame, (tokens per held expert of each layer that
+        has experts (expert layers, held), those layers' chosen experts, a
+        tuple of (B, L, k)))."""
         L = tok.shape[1]
         tables = self.layer.tables(np.arange(L) + frame_index * L)
         h, owns, counts, choices = tok, [], [], []
@@ -627,8 +850,9 @@ class TokenDenoiser:
                     i, params[label], h, tables,
                     None if caches is None else caches[i])
             owns.append(own)
-            counts.append(c)
-            choices.append(chosen)
+            if c is not None:      # a layer with experts
+                counts.append(c)
+                choices.append(chosen)
         return h, tuple(owns), (jnp.stack(counts), tuple(choices))
 
     def _cond_frame(self, params, cond, cond_mask):
@@ -648,8 +872,9 @@ class TokenDenoiser:
     def precompute(self, params, cond: dict):
         """What does not change over a call, for `_raw_eps`'s doubled
         guidance layout (rows [conditional…, unconditional…]): the
-        conditioning frame's per-layer cache (the trunk's own: a latent,
-        or keys and values), as one batch entry."""
+        conditioning frame's per-layer cache (each layer's own: a latent,
+        keys and values, or a recurrent state with its convolution's
+        tail), as one batch entry."""
         B = cond["x"].shape[0]
         doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0),
                                dict(cond))
@@ -690,15 +915,16 @@ class TokenDenoiser:
         return self._forward(variables["params"], batch, cond_mask)[0]
 
     def routing_counts(self, params, batch, cond_mask=None):
-        """Tokens each held expert is given, per layer, in the pass that
-        `apply` makes over the target's tokens: (layers, held) int32. Every
-        assignment to a held expert is in it — nothing is dropped."""
+        """Tokens each held expert is given, per layer that has experts, in
+        the pass that `apply` makes over the target's tokens: (expert
+        layers, held) int32. Every assignment to a held expert is in it —
+        nothing is dropped."""
         return self._forward(params, batch, cond_mask)[1][0]
 
     def routing_choices(self, params, batch, cond_mask=None):
         """The experts each token of [conditioning frame ; target frame]
-        is sent to, per layer, in the passes `apply` makes: (layers, B,
-        2L, k) int32, held or not."""
+        is sent to, per layer that has experts, in the passes `apply`
+        makes: (expert layers, B, 2L, k) int32, held or not."""
         cache, (_, cond) = self._cond_frame(params, batch, cond_mask)
         own = self._forward(params, dict(batch, **{
             self.layer.cache_name: cache}), cond_mask)[1][1]
@@ -711,3 +937,25 @@ class TokenDenoiser:
         layers whose window binds; (0, 0) for a trunk without windows."""
         return self.layer.key_columns(
             (side // self.config.tokens.patch_size) ** 2)
+
+    def cond_cache_bytes(self, side: int) -> dict:
+        """Bytes ONE row of the doubled batch keeps of the conditioning
+        frame at `side` px, summed over the layers, by kind of cache entry
+        (`cache_kind`): what `precompute` returns, from shapes."""
+        cfg = self.config
+
+        def one_row(params):
+            f32 = jnp.float32
+            cond = {"x": jnp.zeros((1, side, side, 3), f32),
+                    "R1": jnp.zeros((1, 3, 3), f32),
+                    "t1": jnp.zeros((1, 3), f32),
+                    "K": jnp.zeros((1, 3, 3), f32)}
+            return self._cond_frame(params, cond, jnp.ones((1,)))[0]
+
+        out = {}
+        for i, entry in enumerate(jax.eval_shape(one_row,
+                                                 param_shapes(cfg))):
+            kind = self.layer.cache_kind(i)
+            out[kind] = out.get(kind, 0) + sum(
+                a.size * a.dtype.itemsize for a in jax.tree.leaves(entry))
+        return out

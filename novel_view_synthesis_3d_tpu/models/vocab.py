@@ -22,12 +22,22 @@ TOKEN_LAYER_KINDS = ("mla_proj", "mla_core", "moe_route", "moe_experts",
 GQA_TOKEN_LAYER_KINDS = ("gqa_proj", "attn_window", "attn_full",
                          "moe_route", "moe_experts", "patch", "emb", "pose",
                          "update")
+# The token family's third trunk (Kimi-Linear's stack): KDA layers stamp
+# their projections (with the gates, the output norm and `o`), the short
+# convolution (with its SiLU and the L2 norms) and the chunked scan apart;
+# its latent-attention and expert layers stamp as the first trunk's, its
+# leading dense layer's MLP as `dense_mlp`.
+KDA_TOKEN_LAYER_KINDS = ("kda_proj", "kda_conv", "kda_core", "mla_proj",
+                         "mla_core", "moe_route", "moe_experts",
+                         "moe_shared", "dense_mlp", "patch", "emb", "pose",
+                         "update")
 # Every kind a `jax.named_scope("lk.<kind>")` may stamp. The stamps sit
 # where the work happens (models/layers.py, models/xunet.py,
 # models/token_denoiser.py, sample/ddpm.py); these tuples and layer_of are
 # the only other place a kind is spelled.
 LAYER_KINDS = tuple(dict.fromkeys(
-    XUNET_LAYER_KINDS + TOKEN_LAYER_KINDS + GQA_TOKEN_LAYER_KINDS))
+    XUNET_LAYER_KINDS + TOKEN_LAYER_KINDS + GQA_TOKEN_LAYER_KINDS
+    + KDA_TOKEN_LAYER_KINDS))
 
 
 def layer_of(path: str):

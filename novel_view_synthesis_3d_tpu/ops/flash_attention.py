@@ -44,6 +44,15 @@ keys reads 10.6: PERF.md §6, PR 30.) At the trunk's 8192 keys a head's K
 and V are 2 × 2 MB, whole in VMEM and double-buffered: the call asks for a
 64 MiB scoped limit where the default 16 would not hold them.
 
+One thing the third token trunk (Kimi-Linear's latent attention) brought,
+forward only too. **Values of another width than the keys**: its keys and
+queries are 192 wide (128 up-projected from the latent beside a 64-wide
+part all heads share), its values 128. K and V have a BlockSpec each and
+the output takes V's width; each is padded to its OWN whole lanes (192 →
+256 for q and k, so a quarter of the score product multiplies zeros; 128
+as it is for v and the output). Where the two widths are equal the
+program is the one it was.
+
 Layout notes (pallas_guide.md "Tiling Constraints"):
   - lanes (last dim) padded to a multiple of 128; sublanes to the dtype
     minimum. Padding is applied in the wrapper, masked inside the kernel
@@ -221,19 +230,22 @@ _LONG_KV_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
                       block_k: int, with_lse: bool, interpret: bool,
                       band=None):
-    """q (N, Lq_pad, Dp) · k,v (N, Lk_pad, Dp) → (out, lse or None). A row
-    of q may hold several heads' queries end to end (grouped-query
-    attention), `band` = (window, q_offset, a head's padded rows): one
-    kernel call a query block of a head then, each with its own static
-    walk (`_attn_kernel`), every head's block i in the call's grid."""
+    """q (N, Lq_pad, Dp) · k (N, Lk_pad, Dp) · v (N, Lk_pad, Dvp) → (out
+    (N, Lq_pad, Dvp), lse or None); the values' width may differ from the
+    keys'. A row of q may hold several heads' queries end to end
+    (grouped-query attention), `band` = (window, q_offset, a head's padded
+    rows): one kernel call a query block of a head then, each with its own
+    static walk (`_attn_kernel`), every head's block i in the call's
+    grid."""
     N, Lq, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[2]
     mem = {} if interpret else {"memory_space": _pallas.VMEM}
     extra = {}
-    if 4 * Lk * D * k.dtype.itemsize > _KV_DEFAULT_VMEM_BYTES:
+    if 2 * Lk * (D + Dv) * k.dtype.itemsize > _KV_DEFAULT_VMEM_BYTES:
         extra["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=_LONG_KV_VMEM_LIMIT_BYTES)
-    kv_spec = pl.BlockSpec((1, Lk, D), lambda n, i: (n, 0, 0), **mem)
+    k_spec = pl.BlockSpec((1, Lk, D), lambda n, i: (n, 0, 0), **mem)
+    v_spec = pl.BlockSpec((1, Lk, Dv), lambda n, i: (n, 0, 0), **mem)
     if band is not None:
         window, q_offset, rows = band
         per_head, heads = rows // block_q, Lq // rows
@@ -245,18 +257,19 @@ def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
             in_specs=[pl.BlockSpec(
                 (1, block_q, D),
                 lambda n, g, i=i: (n, g * per_head + i, 0), **mem),
-                kv_spec, kv_spec],
-            out_specs=pl.BlockSpec((1, block_q, D), lambda n, g: (n, g, 0),
+                k_spec, v_spec],
+            out_specs=pl.BlockSpec((1, block_q, Dv), lambda n, g: (n, g, 0),
                                    **mem),
-            out_shape=jax.ShapeDtypeStruct((N, heads * block_q, D), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((N, heads * block_q, Dv), q.dtype),
             name="flash_fwd", interpret=interpret, **extra,
-        )(q, k, v).reshape(N, heads, 1, block_q, D) for i in range(per_head)]
-        return jnp.concatenate(outs, axis=2).reshape(N, Lq, D), None
+        )(q, k, v).reshape(N, heads, 1, block_q, Dv) for i in range(per_head)]
+        return jnp.concatenate(outs, axis=2).reshape(N, Lq, Dv), None
     grid = (N, Lq // block_q)
     kernel = functools.partial(_attn_kernel, scale=scale, kv_len=kv_len,
                                block_k=block_k)
-    out_specs = [pl.BlockSpec((1, block_q, D), lambda n, i: (n, i, 0), **mem)]
-    out_shape = [jax.ShapeDtypeStruct((N, Lq, D), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, Dv), lambda n, i: (n, i, 0),
+                              **mem)]
+    out_shape = [jax.ShapeDtypeStruct((N, Lq, Dv), q.dtype)]
     if with_lse:
         out_specs.append(
             pl.BlockSpec((1, block_q, 128), lambda n, i: (n, i, 0), **mem))
@@ -266,8 +279,7 @@ def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda n, i: (n, i, 0), **mem),
-            pl.BlockSpec((1, Lk, D), lambda n, i: (n, 0, 0), **mem),
-            pl.BlockSpec((1, Lk, D), lambda n, i: (n, 0, 0), **mem),
+            k_spec, v_spec,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -301,14 +313,16 @@ def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
     (grouped-query attention): the query heads of a group lie end to end
     in one grid row, against that row's one key/value head, so K and V
     are neither repeated in HBM nor fetched more than once a group.
-    `window` is (size, q_offset) or None."""
+    `window` is (size, q_offset) or None. v's last axis may be narrower
+    or wider than q's and k's (latent attention with a key part no value
+    has): each is padded to its own whole lanes, and the output has v's."""
     B, Lq, H, D = q.shape
-    Lk, Hkv = k.shape[1], k.shape[2]
+    Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     interpret = _use_interpret()
     # (B, L, H, D) → (B·H, L, D): heads become independent grid rows.
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
     kt = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, D)
+    vt = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, Dv)
     bq, bk, _ = forward_blocks(Lq, Lk, D, q.dtype.itemsize, block_q)
     qt = _pad_to(qt, 1, bq)
     kt = _pad_to(kt, 1, bk)
@@ -324,7 +338,7 @@ def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
         interpret=interpret,
         band=None if window is None else (*window, rows))
     out = out.reshape(B * H, rows, out.shape[2])
-    out = out[:, :Lq, :D].reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
+    out = out[:, :Lq, :Dv].reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3)
     if with_lse:
         lse = lse[:, :Lq].reshape(B, H, Lq)
     return out, lse
@@ -522,9 +536,10 @@ def _forward_only_fwd(q, k, v, scale, block_q, window):
 
 def _forward_only_bwd(scale, block_q, window, res, g):
     raise NotImplementedError(
-        "flash_attention has no backward for grouped key/value heads or a "
-        "window yet: the dq and dk/dv kernels take one key/value head a "
-        "query head and no band")
+        "flash_attention has no backward for grouped key/value heads, a "
+        "window or values of another width than the keys yet: the dq and "
+        "dk/dv kernels take one key/value head a query head, one width and "
+        "no band")
 
 
 _flash_attention_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
@@ -541,8 +556,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     block_q: int = _BLOCKED_Q,
                     window: Optional[int] = None,
                     q_offset: Optional[int] = None) -> jnp.ndarray:
-    """Fused softmax(q·kᵀ/√D)·v. q (B, Lq, H, D), k/v (B, Lk, Hkv, D) with
-    H a multiple of Hkv: query head h reads key/value head h // (H // Hkv).
+    """Fused softmax(q·kᵀ/√D)·v. q (B, Lq, H, D), k (B, Lk, Hkv, D), v (B,
+    Lk, Hkv, Dv) with H a multiple of Hkv: query head h reads key/value
+    head h // (H // Hkv); → (B, Lq, H, Dv). Dv ≠ D is forward only.
 
     Drop-in for `flax.linen.dot_product_attention` (same layout/scaling)
     where Hkv = H and there is no window. `block_q` is an upper bound on
@@ -564,7 +580,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          "key/value heads")
     q_offset = Lk - Lq if q_offset is None else int(q_offset)
     if not window_binds(Lq, window, q_offset):
-        if H == Hkv:
+        if H == Hkv and v.shape[-1] == D:
             return _flash_attention(q, k, v, scale, int(block_q))
         window = None
     else:

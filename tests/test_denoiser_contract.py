@@ -56,8 +56,25 @@ TINY_GQA_TOKENS = {
     "model.dtype": "float32", "model.param_dtype": "float32",
     "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
 }
+# The third token trunk (Kimi-Linear's stack) at toy sizes: KDA + dense,
+# KDA + experts, KDA + experts, latent attention + experts.
+TINY_KDA_TOKENS = {
+    "model.tokens.hidden_size": 32, "model.tokens.num_hidden_layers": 4,
+    "model.tokens.num_attention_heads": 2, "model.tokens.kv_lora_rank": 8,
+    "model.tokens.qk_nope_head_dim": 8, "model.tokens.qk_rope_head_dim": 4,
+    "model.tokens.v_head_dim": 8,
+    "model.tokens.linear_attn_config.num_heads": 2,
+    "model.tokens.linear_attn_config.head_dim": 8,
+    "model.tokens.intermediate_size": 48, "model.tokens.num_experts": 4,
+    "model.tokens.num_experts_per_token": 2,
+    "model.tokens.moe_intermediate_size": 16,
+    "model.tokens.held_experts": [0, 2], "data.img_sidelength": 16,
+    "model.dtype": "float32", "model.param_dtype": "float32",
+    "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
+}
 TINY_BY_PRESET = {"ms4_denoiser128": TINY_TOKENS,
-                  "st21_denoiser256": TINY_GQA_TOKENS}
+                  "st21_denoiser256": TINY_GQA_TOKENS,
+                  "kl48_denoiser256": TINY_KDA_TOKENS}
 
 
 def token_cfg(**over) -> Config:

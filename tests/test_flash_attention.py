@@ -342,3 +342,33 @@ def test_grouped_heads_and_window_are_forward_only():
         jax.grad(lambda q: jnp.sum(flash_attention(q, k, v)))(q)
     with pytest.raises(ValueError, match="do not divide"):
         flash_attention(q[:, :, :3], k, v)
+
+
+VALUE_WIDTHS = {
+    # name: (Lq, Lk, H, Hkv, D, Dv, window): values narrower (latent
+    # attention whose keys carry a part no value has: 192 against 128 at
+    # the third token trunk's widths) and wider than the keys
+    "trunk_widths_one_key_block": (64, 128, 2, 2, 192, 128, None),
+    "trunk_widths_blocked_walk": (256, 1536, 2, 2, 192, 128, None),
+    "narrow_keys_wide_values": (100, 300, 3, 3, 8, 24, None),
+    "with_grouped_heads_and_a_window": (200, 400, 4, 2, 24, 16, 200),
+}
+
+
+@pytest.mark.parametrize("name", sorted(VALUE_WIDTHS))
+def test_values_of_another_width_than_the_keys_match_xla(name):
+    Lq, Lk, H, Hkv, D, Dv, window = VALUE_WIDTHS[name]
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
+    q = jax.random.normal(ks[0], (2, Lq, H, D))
+    k = jax.random.normal(ks[1], (2, Lk, Hkv, D))
+    v = jax.random.normal(ks[2], (2, Lk, Hkv, Dv))
+    scale = D ** -0.5
+    out = flash_attention(q, k, v, scale=scale, window=window)
+    assert out.shape == (2, Lq, H, Dv)
+    ref = _banded_reference(q, k, v, scale, window, Lk - Lq)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    # forward only, and it says so
+    with pytest.raises(NotImplementedError, match="another width"):
+        jax.grad(lambda q: jnp.sum(flash_attention(q, k, v)))(q)
+

@@ -31,6 +31,7 @@ from novel_view_synthesis_3d_tpu.ops import (
     fused_groupnorm,
     fused_step,
     grouped_matmul,
+    kda,
     serving_attention,
 )
 
@@ -113,6 +114,16 @@ def _gqa_attn(Lq, Lk, heads, kv_heads, hd, window):
         [((1, Lq, heads, hd), BF16)] + [((1, Lk, kv_heads, hd), BF16)] * 2)
 
 
+def _latent_attn(Lq, Lk, heads, qk, dv):
+    """The third token trunk's latent attention at the size its cell runs:
+    keys and queries 192 wide (lane-padded to 256) against values of 128,
+    a frame's 4096 queries against [cache ; own]."""
+    return (lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, scale=qk ** -0.5),
+        [((1, Lq, heads, qk), BF16), ((1, Lk, heads, qk), BF16),
+         ((1, Lk, heads, dv), BF16)])
+
+
 def _grouped(assignments, experts, k, n):
     """The expert layer's grouped product at the published widths: the
     static row count of the worst case (a step's 8192 tokens × top-4, all
@@ -141,6 +152,13 @@ CASES = {
                                                   None),
     "flash_fwd_gqa_window4096_Lq4096_Lk8192_d128": _gqa_attn(
         4096, 8192, 28, 4, 128, 4096),
+    "flash_fwd_Lq4096_Lk8192_qk192_v128": _latent_attn(4096, 8192, 32, 192,
+                                                        128),
+    "flash_fwd_Lq4096_Lk4096_qk192_v128": _latent_attn(4096, 4096, 32, 192,
+                                                        128),
+    # the third token trunk's experts: 128 held, 16384 tokens x top-8
+    "grouped_matmul_up_2304x1024": _grouped(131072, 128, 2304, 1024),
+    "grouped_matmul_down_1024x2304": _grouped(131072, 128, 1024, 2304),
     "grouped_matmul_up_4096x2048": _grouped(32768, 32, 4096, 2048),
     # the second token trunk's experts: 64 held, 16384 tokens x top-6
     "grouped_matmul_up_2560x768": _grouped(98304, 64, 2560, 768),
@@ -413,3 +431,41 @@ def test_resnet_blocks_keep_the_convolutions_layout_on_v5e(skips, v5e):
                     if "ResnetBlock_" in name or "concatenate" in name)
     assert in_blocks <= 2 * (5 if skips else 4) * h_bytes * 1.01, (
         in_blocks / h_bytes)
+
+
+# ops/kda.py is XLA, not a kernel: what the chip's compiler is asked is
+# whether the chunked scan and the short convolution lower and FIT at the
+# shapes the third token trunk's cell runs (4 rows of 4096 tokens, 32
+# heads of 128), the scan from a cached state.
+def _kda_scan(rows, L, heads, d):
+    tok = ((rows, L, heads, d), BF16)
+    return (lambda q, k, v, g, beta, S0: kda.kda_chunked(q, k, v, g, beta,
+                                                         S0),
+            [tok, tok, tok, ((rows, L, heads, d), F32),
+             ((rows, L, heads), F32), ((rows, heads, d, d), F32)])
+
+
+def _kda_conv(rows, L, width, taps):
+    return (kda.short_conv,
+            [((rows, L, width), BF16), ((taps, width), BF16),
+             ((rows, taps - 1, width), BF16)])
+
+
+XLA_CASES = {
+    "kda_chunked_4x4096_h32_d128": _kda_scan(4, 4096, 32, 128),
+    "kda_chunked_ragged_1x4000_h32_d128": _kda_scan(1, 4000, 32, 128),
+    "kda_short_conv_4x4096x12288_k4": _kda_conv(4, 4096, 3 * 32 * 128, 4),
+}
+
+
+@pytest.mark.parametrize("name", sorted(XLA_CASES))
+def test_kda_compiles_and_fits_for_v5e(name, v5e):
+    fn, arg_specs = XLA_CASES[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in arg_specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    # a row's float32 working set, not the batch's (ops/kda.py): a step
+    # of the cell must leave the scan room beside 7.8 GB of weights
+    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+
