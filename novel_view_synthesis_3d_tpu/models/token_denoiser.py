@@ -574,9 +574,20 @@ class SmallThinkerLayer:
         return tuple(sum(c) for c in zip(*per_layer)) if per_layer else (0, 0)
 
 
-def l2_normalise(x, eps=1e-6):
-    """x / sqrt(Σ x² + eps) over the last axis, float32."""
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+def l2_normalise_heads(x, heads: int, eps=1e-6):
+    """x / sqrt(Σ x² + eps) over each of `heads` equal blocks of the last
+    axis, float32, the blocks left side by side. The sums, and their way
+    back to the channels, are products with the blocks' 0/1 indicator at
+    the MXU's full precision (the sum in float32, the broadcast exact):
+    a reduce over (…, heads, d) makes the chip's compiler re-lay x there
+    and back, 1.3 GB a norm at the third trunk's size, where both
+    neighbours want (…, heads·d) (PERF.md §6, PR 35)."""
+    width = x.shape[-1]
+    own = (jnp.arange(width)[:, None] // (width // heads)
+           == jnp.arange(heads)[None]).astype(x.dtype)
+    highest = jax.lax.Precision.HIGHEST
+    scale = jax.lax.rsqrt(jnp.matmul(x * x, own, precision=highest) + eps)
+    return x * jnp.matmul(scale, own.T, precision=highest)
 
 
 class KimiLinearLayer:
@@ -658,9 +669,10 @@ class KimiLinearLayer:
             qkv = jnp.concatenate([_dense(a, p[n]) for n in ("q", "k", "v")],
                                   axis=-1)
             # per head AND per channel, float32 from the projection on
-            g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
-                _dense(_dense(a, p["f_a"]), p["f_b"]).astype(f32)
-                + p["dt_bias"].astype(f32)).reshape(B, L, NH, D)
+            g = -jnp.repeat(jnp.exp(p["A_log"].astype(f32)), D) \
+                * jax.nn.softplus(
+                    _dense(_dense(a, p["f_a"]), p["f_b"]).astype(f32)
+                    + p["dt_bias"].astype(f32))
             beta = jax.nn.sigmoid(_dense(a, p["beta"]).astype(f32))
             gate = jax.nn.sigmoid(
                 _dense(_dense(a, p["g_a"]), p["g_b"]).astype(f32))
@@ -668,17 +680,18 @@ class KimiLinearLayer:
             taps = jnp.concatenate(
                 [p[n + "_conv"]["kernel"] for n in ("q", "k", "v")], axis=-1)
             y, tail = short_conv(qkv, taps, tail)
-            q, keys, v = (t.reshape(B, L, NH, D)
-                          for t in jnp.split(jax.nn.silu(y), 3, axis=-1))
-            # into the scan in the compute type (it widens a row at a time)
-            q = (l2_normalise(q) * D ** -0.5).astype(a.dtype)
-            keys = l2_normalise(keys).astype(a.dtype)
+            q, keys, v = jnp.split(jax.nn.silu(y), 3, axis=-1)
+            # Into the scan in the compute type, the heads side by side as
+            # the projections left them: a head is a block of lanes to the
+            # kernel.
+            q = (l2_normalise_heads(q, NH) * D ** -0.5).astype(a.dtype)
+            keys = l2_normalise_heads(keys, NH).astype(a.dtype)
             v = v.astype(a.dtype)
         with jax.named_scope("lk.kda_core"):
             o, state = kda_chunked(q, keys, v, g, beta, state)
         with jax.named_scope("lk.kda_proj"):
-            o = rms_norm(o, p["o_norm"]["scale"], k.rms_norm_eps) \
-                * gate.reshape(B, L, NH, D)
+            o = rms_norm(o.reshape(B, L, NH, D), p["o_norm"]["scale"],
+                         k.rms_norm_eps) * gate.reshape(B, L, NH, D)
             h = h + _dense(o.reshape(B, L, NH * D).astype(a.dtype), p["o"])
         return h, (state, tail)
 

@@ -17,19 +17,29 @@ a chunk entered at S_0:
 
     A   = Diag(β)·strict_tril[ Σ_c k_r[c]·k_i[c]·exp(G_r[c] − G_i[c]) ]
     T   = (I + A)⁻¹                      unit lower triangular, (C, C)
-    U   = T·Diag(β)·V − T·Diag(β)·(K ⊙ exp G)·S_0        = U⁰ − W·S_0
+    U   = T·Diag(β)·(V − (K ⊙ exp G)·S_0)
     O   = (Q ⊙ exp G)·S_0 + tril[ Σ_c q_r[c]·k_i[c]·exp(G_r[c] − G_i[c]) ]·U
     S_C = Diag(exp G_C)·S_0 + (K ⊙ exp(G_C − G))ᵀ·U
 
-**What is made for all chunks at once, and what is walked.** A, T, U⁰, W,
-the query-key matrix and the decayed operands depend on no state: they are
-batched products over every (row, head, chunk). What remains in sequence
-is a `lax.scan` over the chunks whose body is three products against the
-(d_k, d_v) state — U⁰ − W·S, Q̄·S + A_qk·U, K̂ᵀ·U — batched over the heads:
-64 dependent steps a row at 4096 tokens, where the recurrence has 4096.
-Resident across the scan: a row's state (H, d_k, d_v) float32. The rows of
-the batch go one after another (`lax.map`): a row's float32 working set is
-a dozen arrays of the size of q.
+**One Pallas kernel, `kda_fwd`; what the grid walks and what stays in
+VMEM.** The grid is (row, pair of heads, run of 4 chunks), the runs last
+and in order. A head is a 128-lane block of the model's own (B, L, H·d)
+arrays — q, k, v in the compute type, g in float32, o in float32 — so
+nothing is re-laid for the kernel; β (B, L, H) comes whole and a head's
+column is picked out of it. Resident while the grid walks a (row, heads)'s
+runs: the heads' states, (d_v, d_k) float32 each (kept TRANSPOSED: a
+channel's decay is then a lane's factor), loaded from `S0` at the first
+run and written to `S_L` at the last; they are never rounded. Inside a
+run everything lives in VMEM and registers: G, the decayed operands, the
+two (C, C) matrices, T. What no state enters is made for the run at once,
+two chunks side by side wherever a product's operand is (C, C) — their
+matrices lie block-diagonally in one (128, 128), so the product fills an
+MXU tile with both —: A's sub-blocks and T's of 16 rows by substitution
+on the VPU, the sub-blocks against earlier ones and T's merges on the MXU.
+Then the chunks in sequence, four products each: [K̄ ; Q̄]·S, U = T·(β ⊙
+(V − K̄·S)), O = Q̄·S + A_qk·U, S ← Diag(e^{G_C})·S + K̂ᵀ·U. The two heads
+of a grid step share no number; side by side, one's products run while
+the other's wait for their operands.
 
 **The decay never leaves the exponent's safe side.** exp(G_r − G_i) with
 i ≤ r is at most 1, but factored as (k_r·exp G_r)·(k_i·exp(−G_i)) the
@@ -46,12 +56,21 @@ sub-block, then block halves (T₂₁ = −T₂₂·A₂₁·T₁₁) — and no
 Σ(−A)ⁿ, whose terms grow combinatorially for near-parallel keys (a mostly
 white frame's are) before they cancel.
 
-Everything here is float32 with the MXU's full-precision passes: the
-state is the layer's memory over thousands of tokens and is not rounded
-between chunks. **XLA, not a Pallas kernel, for now** (PERF.md §6, PR 34,
-has the chip's reading of `lk.kda_core`); the operations and bytes counted
-for its roofline share (benchmarks/flops_tokens_kda.py) are of the chunked
-form above, whatever implements it.
+**Every product is the configuration's float32**
+(`kda_state_precision`: the state, the decays, β and the whole scan):
+float32 operands at `Precision.HIGHEST` — six MXU passes of bfloat16
+parts — into a float32 accumulator, the inverse and every exponential in
+float32; q, k, v are widened in VMEM as they arrive. No product takes
+fewer passes: Mosaic refuses `Precision.HIGH`, and a single pass of
+bfloat16 operands is another result (at the cell's shape o moves by 3e-3
+of its largest value and the state by 8e-3, where the six passes stay
+within 2e-6 of the same scan in XLA's float32). The kernel is bound by those
+passes on 64- and 128-row operands (PERF.md §6, PR 35, has the chip's
+split); the operations and bytes counted for its roofline share
+(benchmarks/flops_tokens_kda.py) are of the chunked form above in ONE
+pass over triangles, whatever implements it. Off the TPU the same kernel
+runs through the Pallas interpreter (ops/_pallas.py's contract), at any
+head width; compiled, a head must be whole 128-lane blocks.
 
 Forward only: a gradient through `kda_chunked` raises by name.
 """
@@ -62,10 +81,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from novel_view_synthesis_3d_tpu.ops import _pallas
 
 CHUNK = 64      # tokens a chunk: one step of the scan
 SUB_BLOCK = 16  # rows a sub-block: the decay's reference row moves this often
+RUN_CHUNKS = 4  # chunks a grid step
+RUN_HEADS = 2   # heads a grid step, where H divides
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -85,124 +109,207 @@ def short_conv(x, w, tail=None):
     return y, ext[:, L:]
 
 
-def _mm(a, b):
-    return jnp.matmul(a, b, precision=_HIGHEST)
+def _mm(a, b, contract=((1,), (0,))):
+    """A product in the configuration's float32: float32 operands, every
+    pass of the MXU (six of bfloat16 parts), a float32 accumulator.
+    `contract`: the contracted axis of each operand."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
-def _unit_lower_inverse(M):
-    """(I + A)⁻¹ for M = I + A (..., n, n), A strictly lower triangular:
-    by rows up to SUB_BLOCK, by halves above it."""
-    n = M.shape[-1]
-    if n <= SUB_BLOCK:
-        eye = jnp.broadcast_to(jnp.eye(n, dtype=M.dtype), M.shape)
-        rows = [eye[..., 0, :]]
-        for r in range(1, n):
-            above = jnp.stack(rows, axis=-2)                  # (..., r, n)
-            rows.append(eye[..., r, :] - jnp.sum(
-                M[..., r, :r, None] * above, axis=-2))
-        return jnp.stack(rows, axis=-2)
-    h = n // 2
-    halves = _unit_lower_inverse(jnp.stack(
-        [M[..., :h, :h], M[..., h:, h:]], axis=-3))
-    t11, t22 = halves[..., 0, :, :], halves[..., 1, :, :]
-    t21 = -_mm(_mm(t22, M[..., h:, :h]), t11)
-    top = jnp.concatenate([t11, jnp.zeros_like(t21)], axis=-1)
-    return jnp.concatenate([top, jnp.concatenate([t21, t22], axis=-1)],
-                           axis=-2)
+def _placed(x, at: int, rows: int):
+    """x as rows `at`, … of `rows` rows, zeros around it."""
+    def zeros(n):
+        return [jnp.zeros((n, x.shape[1]), x.dtype)] if n else []
+
+    return jnp.concatenate(
+        zeros(at) + [x] + zeros(rows - at - x.shape[0]), axis=0)
 
 
-def _decayed_products(q, k, G):
-    """The two (C, C) matrices of a chunk, before any mask's β: rows r,
-    columns i ≤ r of Σ_c x_r[c]·k_i[c]·exp(G_r[c] − G_i[c]) for x = q
-    (diagonal included) and x = k (strictly lower). q, k, G (..., C, d)."""
-    C, d = k.shape[-2:]
-    n = C // SUB_BLOCK
-    lead = k.shape[:-2]
+def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, sl_ref,
+                st_ref, *, chunk: int, chunks: int, wide: int, heads: int):
+    """One (row, `heads` heads, run of `chunks` chunks). Blocks: q, k, g
+    (1, R, heads·d_k), v, o (1, R, heads·d_v) — the heads' lanes of the
+    model's own (B, L, H·d) arrays, R = chunks·chunk rows —, β (1, R, H)
+    every head's, S0 / S_L (1, heads, d_k, d_v); `st_ref` (heads, d_v, d_k)
+    float32 is the state, TRANSPOSED (a channel's decay is then a lane's
+    factor), resident while the grid walks a (row, heads)'s runs."""
+    f32 = jnp.float32
+    C, s = chunk, SUB_BLOCK
+    # Chunks are taken `wide` at a time where no state is involved: their
+    # (C, C) matrices lie block-diagonally in one (P, P), P = wide·C ≤ 128
+    # lanes, so a product on them fills an MXU tile with two chunks' work.
+    R, P = chunks * C, wide * C
+    nb = heads * R // s
+    run = pl.program_id(2)
+
+    @pl.when(run == 0)
+    def _enter():
+        for i in range(heads):
+            st_ref[i] = s0_ref[0, i].T
+
+    # The heads one under the other: head i is rows i·R, … of everything
+    # below, a sequence of its own. They share no number; side by side,
+    # one's products run while another's wait for their operands.
+    def stacked(ref):
+        x = ref[0].astype(f32)
+        d = x.shape[1] // heads
+        return jnp.concatenate([x[:, i * d:(i + 1) * d]
+                                for i in range(heads)], axis=0)
+
+    q, k, g, v = (stacked(ref) for ref in (q_ref, k_ref, g_ref, v_ref))
+    dk = q.shape[1]
+    every = beta_ref[0]
+    head_of = jax.lax.broadcasted_iota(jnp.int32, every.shape, 1) \
+        - pl.program_id(1) * heads
+    beta = jnp.concatenate(
+        [jnp.sum(jnp.where(head_of == i, every, 0.0), axis=1, keepdims=True)
+         for i in range(heads)], axis=0)
+
+    rows = jax.lax.broadcasted_iota(jnp.int32, (P, P), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (P, P), 1)
+    same_chunk = rows // C == cols // C
+    rows, cols = rows % C, cols % C
+    # G: the running sum of g inside each chunk.
+    tril = (same_chunk & (rows >= cols)).astype(f32)
+    G = jnp.concatenate([_mm(tril, g[at:at + P])
+                         for at in range(0, heads * R, P)], axis=0)
 
     def sub(x):
-        return x.reshape(lead + (n, SUB_BLOCK, d))
+        return x.reshape(nb, s, x.shape[-1])
 
-    qs, ks, Gs = sub(q), sub(k), sub(G)
-    # A sub-block against itself: from the differences, channel by channel.
-    both = jnp.concatenate([qs, ks], axis=-2)               # (.., n, 2s, d)
-    Gb = jnp.concatenate([Gs, Gs], axis=-2)
-    r = np.arange(SUB_BLOCK)
-    seen = np.concatenate([r[:, None] >= r[None], r[:, None] > r[None]])
-    diff = Gb[..., :, None, :] - Gs[..., None, :, :]         # (.., 2s, s, d)
-    decay = jnp.exp(jnp.where(seen[..., None], diff, -jnp.inf))
-    own = jnp.sum(both[..., :, None, :] * ks[..., None, :, :] * decay,
-                  axis=-1)                                   # (.., n, 2s, s)
-    # Against earlier sub-blocks: both factors at most 1, taken against
-    # this sub-block's first row.
-    down = both * jnp.exp(Gb - Gs[..., :1, :])
-    rows_q, rows_k = [], []
-    for i in range(n):
-        parts = []
-        if i:
-            before = i * SUB_BLOCK
-            up = k[..., :before, :] * jnp.exp(
-                Gs[..., i, :1, :] - G[..., :before, :])
-            parts.append(_mm(down[..., i, :, :],
-                             jnp.swapaxes(up, -1, -2)))     # (.., 2s, before)
-        parts.append(own[..., i, :, :])
-        rest = C - (i + 1) * SUB_BLOCK
-        if rest:
-            parts.append(jnp.zeros(lead + (2 * SUB_BLOCK, rest), k.dtype))
-        row = jnp.concatenate(parts, axis=-1)                # (.., 2s, C)
-        rows_q.append(row[..., :SUB_BLOCK, :])
-        rows_k.append(row[..., SUB_BLOCK:, :])
-    return jnp.concatenate(rows_q, axis=-2), jnp.concatenate(rows_k, axis=-2)
+    q3, k3, G3, b3 = sub(q), sub(k), sub(G), sub(beta)
+    # A sub-block against itself, row r at a time and channel by channel
+    # from the differences (a row's products come out as a COLUMN over the
+    # sub-block's earlier rows: what the substitution scales T's rows by),
+    # and row r of the sub-block's inverse in the same step. The inverses
+    # and the query-key sub-blocks (these transposed) are written where
+    # they lie in (P, P): the sub-block at rows 16·i has lanes 16·i.
+    shape = (nb, s, P)
+    row_of = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 2) \
+        - jax.lax.broadcasted_iota(jnp.int32, shape, 0) % (P // s) * s
+    T = jnp.zeros(shape, f32)
+    qk_t = jnp.zeros(shape, f32)
+    for r in range(s):
+        decay = jnp.exp(jnp.minimum(G3[:, r:r + 1] - G3, 0.0))
+        kd = k3 * decay
+        col_k = jnp.sum(kd * k3[:, r:r + 1], axis=2, keepdims=True)
+        col_q = jnp.sum(kd * q3[:, r:r + 1], axis=2, keepdims=True)
+        here = lane == r
+        qk_t = jnp.where(here & (row_of <= r), col_q, qk_t)
+        above = jnp.sum(jnp.where(row_of < r, col_k, 0.0) * T, axis=1,
+                        keepdims=True)
+        new = jnp.where(here[:, :1], 1.0, 0.0) - b3[:, r:r + 1] * above
+        T = jnp.where(row_of == r, new, T)
+    T, qk_t = T.reshape(heads * R, P), qk_t.reshape(heads * R, P)
+
+    # Against earlier sub-blocks of its chunk: both factors at most 1,
+    # taken against the later sub-block's first row.
+    down = jnp.exp(G3 - G3[:, :1]).reshape(heads * R, dk)
+    qd, kdn = q * down, k * down
+
+    def group(at):
+        """T and the query-key matrix of the `wide` chunks at rows `at`, …"""
+        a_qk, a_kk = [], []
+        for c0 in range(0, P, C):
+            kc, Gc = k[at + c0:at + c0 + C], G[at + c0:at + c0 + C]
+            a_qk.append(jnp.zeros((s, P), f32))
+            a_kk.append(jnp.zeros((s, P), f32))
+            for n in range(s, C, s):
+                up = _placed(kc[:n] * jnp.exp(Gc[n:n + 1] - Gc[:n]), c0, P)
+                block = slice(at + c0 + n, at + c0 + n + s)
+                both = _mm(jnp.concatenate([qd[block], kdn[block]], axis=0),
+                           up, ((1,), (1,)))
+                a_qk.append(both[:s])
+                a_kk.append(both[s:])
+        M = beta[at:at + P] * jnp.concatenate(a_kk, axis=0)
+        # The chunks' T from their sub-blocks': T₂₁ = −T₂₂·M₂₁·T₁₁, a
+        # level of twice the block size at a time.
+        Tg = T[at:at + P]
+        size = s
+        while size < C:
+            pair = same_chunk & (rows // size % 2 == 1) \
+                & (cols // size == rows // size - 1)
+            Tg = Tg - _mm(Tg, _mm(jnp.where(pair, M, 0.0), Tg))
+            size *= 2
+        return Tg, jnp.concatenate(a_qk, axis=0) + qk_t[at:at + P].T
+
+    def step(i, at, c0, Tg, a_qk):
+        """Head i's chunk at rows at + c0, …, from its state and on it."""
+        here = slice(at + c0, at + c0 + C)
+        kc, Gc = k[here], G[here]
+        S = st_ref[i]                                         # (d_v, d_k)
+        into = jnp.exp(Gc)
+        kqs = _mm(jnp.concatenate([kc * into, q[here] * into], axis=0), S,
+                  ((1,), (1,)))                               # (2C, d_v)
+        # the group's other chunks' columns of T and A_qk are zeros
+        U = _mm(Tg[c0:c0 + C],
+                _placed(beta[here] * (v[here] - kqs[:C]), c0, P))
+        end = Gc[C - 1:]
+        st_ref[i] = jnp.exp(end) * S + _mm(U, kc * jnp.exp(end - Gc),
+                                           ((0,), (0,)))
+        return kqs[C:] + _mm(a_qk[c0:c0 + C], _placed(U, c0, P))
+
+    o = [[] for _ in range(heads)]
+    for at in range(0, R, P):
+        made = [group(i * R + at) for i in range(heads)]
+        for c0 in range(0, P, C):
+            for i in range(heads):
+                o[i].append(step(i, i * R + at, c0, *made[i]))
+    o_ref[0] = jnp.concatenate([jnp.concatenate(x, axis=0) for x in o],
+                               axis=1)
+
+    @pl.when(run == pl.num_programs(2) - 1)
+    def _leave():
+        for i in range(heads):
+            sl_ref[0, i] = st_ref[i].T
 
 
-def _kda_row(q, k, v, g, beta, S0, chunk):
-    """One row of the batch: q, k, g (L, H, d_k), v (L, H, d_v), β (L, H),
-    S0 (H, d_k, d_v) → (o (L, H, d_v), the last state). Everything is
-    laid out (chunks, H, chunk, ·) from the start: what is made for all
-    chunks at once is then already in the order the scan walks."""
-    L, H, _ = q.shape
-    dv = v.shape[-1]
-    pad = (-L) % chunk
-    NC = (L + pad) // chunk
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _kda_call(q, k, v, g, beta, S0, *, chunk: int, interpret: bool):
+    B, L, H = beta.shape
+    dk, dv = q.shape[-1] // H, v.shape[-1] // H
+    if not interpret and (dk % 128 or dv % 128):
+        raise ValueError(
+            f"kda_fwd on the chip takes heads that are whole 128-lane "
+            f"blocks of (B, L, H·d); got d_k={dk}, d_v={dv}")
+    heads = max(n for n in range(1, RUN_HEADS + 1) if H % n == 0)
+    # a run is whole groups of `wide` chunks, a group at most 128 rows
+    chunks = min(RUN_CHUNKS, -(-L // chunk))
+    wide = min(chunks, max(1, 128 // chunk))
+    chunks = -(-chunks // wide) * wide
+    R = chunks * chunk
+    pad = (-L) % R
+    if pad:   # k = v = β = g = 0 rows: the state passes them unchanged
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                            for x in (q, k, v, g, beta))
 
-    def blocks(x):
-        """(L, H, ·) → (chunks, H, chunk, ·) float32; the padding's rows
-        have k = v = β = g = 0: the state passes them unchanged."""
-        x = jnp.pad(x.astype(jnp.float32),
-                    ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-        x = x.reshape((NC, chunk) + x.shape[1:])
-        return jnp.moveaxis(x, 1, 2)
+    def lanes(d):
+        return pl.BlockSpec((1, R, heads * d), lambda b, h, c: (b, c, h))
 
-    q, k, v, g, beta = (blocks(x) for x in (q, k, v, g, beta))
-    G = jnp.cumsum(g, axis=2)
-    a_qk, a_kk = _decayed_products(q, k, G)
-    T = _unit_lower_inverse(
-        jnp.eye(chunk, dtype=jnp.float32) + beta[..., None] * a_kk)
-    Tb = T * beta[..., None, :]                              # T·Diag(β)
-    G_end = G[..., -1:, :]
-    xs = (_mm(Tb, v),                                        # U⁰
-          _mm(Tb, k * jnp.exp(G)),                           # W
-          q * jnp.exp(G), a_qk,
-          k * jnp.exp(G_end - G),                            # K̂
-          jnp.exp(G_end[..., 0, :]))                         # (NC, H, dk)
-
-    def step(S, x):
-        u0, w, q_in, a_qk, k_out, keep = x
-        u = u0 - _mm(w, S)
-        o = _mm(q_in, S) + _mm(a_qk, u)
-        S = keep[..., None] * S + _mm(jnp.swapaxes(k_out, -1, -2), u)
-        return S, o
-
-    S_end, o = jax.lax.scan(step, S0.astype(jnp.float32), xs)
-    return jnp.moveaxis(o, 1, 2).reshape(NC * chunk, H, dv)[:L], S_end
+    state = pl.BlockSpec((1, heads, dk, dv), lambda b, h, c: (b, h, 0, 0))
+    o, S = pl.pallas_call(
+        functools.partial(_kda_kernel, chunk=chunk, chunks=chunks, wide=wide,
+                          heads=heads),
+        out_shape=(jax.ShapeDtypeStruct((B, L + pad, H * dv), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, dk, dv), jnp.float32)),
+        grid=(B, H // heads, (L + pad) // R),
+        in_specs=[lanes(dk), lanes(dk), lanes(dv), lanes(dk),
+                  pl.BlockSpec((1, R, H), lambda b, h, c: (b, c, 0)), state],
+        out_specs=(lanes(dv), state),
+        scratch_shapes=[_pallas.VMEM((heads, dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="kda_fwd", interpret=interpret,
+    )(q, k, v, g, beta, S0)
+    return o[:, :L], S
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _kda(q, k, v, g, beta, S0, chunk):
-    """A row of the batch at a time: a row's float32 working set (a dozen
-    arrays of the size of q) is then a row's, not the batch's — 1.1 GB in
-    place of 4.5 at 4 rows × 4096 tokens × 32 heads of 128."""
-    return jax.lax.map(lambda x: _kda_row(*x, chunk),
-                       (q, k, v, g, beta, S0))
+    return _kda_call(q, k, v, g, beta, S0, chunk=chunk,
+                     interpret=_pallas.use_interpret())
 
 
 def _kda_fwd(q, k, v, g, beta, S0, chunk):
@@ -220,15 +327,18 @@ _kda.defvjp(_kda_fwd, _kda_bwd)
 
 def kda_chunked(q, k, v, g, beta, S0=None, *, chunk: int = CHUNK):
     """The gated delta rule with a per-channel decay over a sequence, in
-    chunks. q, k (B, L, H, d_k) — the caller's to normalise and scale —,
-    v (B, L, H, d_v), g (B, L, H, d_k) log-decays ≤ 0, β (B, L, H) in
-    [0, 1], `S0` (B, H, d_k, d_v) the state the sequence is entered with
-    (zeros where None). → (o (B, L, H, d_v) float32, the state after the
-    last token (B, H, d_k, d_v) float32). L need not be a multiple of
+    chunks. Heads lie side by side in the last axis, as the projections
+    leave them: q, k (B, L, H·d_k) — the caller's to normalise and scale —,
+    v (B, L, H·d_v), g (B, L, H·d_k) float32 log-decays ≤ 0, β (B, L, H)
+    in [0, 1], `S0` (B, H, d_k, d_v) the state the sequence is entered
+    with (zeros where None). → (o (B, L, H·d_v) float32, the state after
+    the last token (B, H, d_k, d_v) float32). L need not be a multiple of
     `chunk` (itself one of SUB_BLOCK)."""
     if chunk % SUB_BLOCK:
         raise ValueError(f"chunk={chunk} is not a multiple of {SUB_BLOCK}")
-    B, _, H, dk = q.shape
+    B, _, H = beta.shape
     if S0 is None:
-        S0 = jnp.zeros((B, H, dk, v.shape[-1]), jnp.float32)
-    return _kda(q, k, v, g, beta, S0, int(chunk))
+        S0 = jnp.zeros((B, H, q.shape[-1] // H, v.shape[-1] // H),
+                       jnp.float32)
+    return _kda(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32),
+                S0.astype(jnp.float32), int(chunk))

@@ -119,11 +119,13 @@ def small(request):
 
 
 # ---------------------------------------------------------------------------
-# ops/kda.py: the chunked scan and the short convolution
+# ops/kda.py: the chunked scan (the Pallas kernel `kda_fwd`, interpreted
+# here) and the short convolution
 # ---------------------------------------------------------------------------
 def kda_inputs(L, rate, seed=0, B=2, H=3, dk=16, dv=24):
     """Near-parallel keys (a mostly white frame's are), decays planted at
-    `rate` = A·softplus(·) a token."""
+    `rate` = A·softplus(·) a token; heads apart, as the reference takes
+    them."""
     rng = np.random.default_rng(seed)
 
     def n(*shape):
@@ -139,12 +141,30 @@ def kda_inputs(L, rate, seed=0, B=2, H=3, dk=16, dv=24):
             n(B, H, dk, dv))
 
 
+def chunked(q, k, v, g, beta, S0=None, **kw):
+    """`kda_chunked` on heads given apart: it takes them side by side in
+    the last axis, (B, L, H·d), and returns o so."""
+    o, S = kda.kda_chunked(*(x.reshape(x.shape[:2] + (-1,))
+                             for x in (q, k, v, g)), beta, S0, **kw)
+    return o.reshape(v.shape), S
+
+
+def close(got, want, tol=1e-5):
+    """Both sides float32, differing by the order of their sums: the
+    largest difference against the largest value."""
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.max(jnp.abs(got - want))) < tol * float(
+        jnp.max(jnp.abs(want)))
+
+
 @pytest.mark.parametrize("L,rate,with_state", [
     (128, 0.05, True),     # two whole chunks, slow decay, entered mid-way
     (150, 1.0, True),      # no multiple of the chunk
     (150, 16.0, True),     # A = 16: 1/Γ would overflow within a sub-block
     (150, 16.0, False),    # the sequence's start
     (7, 0.3, True),        # shorter than a sub-block
+    (300, 1.0, True),      # more than one run of chunks: two grid steps
+    (69, 1.0, True),       # ends inside the second chunk's first sub-block
 ])
 def test_kda_chunked_is_the_token_by_token_recurrence(L, rate, with_state):
     q, k, v, g, beta, S0 = kda_inputs(L, rate)
@@ -153,24 +173,82 @@ def test_kda_chunked_is_the_token_by_token_recurrence(L, rate, with_state):
         with np.errstate(over="ignore"):
             assert not np.isfinite(np.exp(-np.cumsum(
                 np.asarray(g[:, :kda.CHUNK], np.float32), axis=1))).all()
-    o, S = kda.kda_chunked(q, k, v, g, beta, S0)
+    o, S = chunked(q, k, v, g, beta, S0)
     want_o, want_S = ref.delta_rule(q, k, v, g, beta, S0)
     assert o.shape == v.shape and S.shape == (2, 3, 16, 24)
-    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
-    assert float(jnp.max(jnp.abs(o - want_o))) < 1e-5 * float(
-        jnp.max(jnp.abs(want_o)))
-    assert float(jnp.max(jnp.abs(S - want_S))) < 1e-5 * float(
-        jnp.max(jnp.abs(want_S)))
+    close(o, want_o)
+    close(S, want_S)
+
+
+def test_kda_chunked_fast_decay_neither_overflows_nor_nans():
+    """g = −16 a token on every channel (and −16·softplus beside it): a
+    chunk's running sum passes −1000, every factor is taken from a
+    difference ≤ 0, an underflow to 0 is the value."""
+    q, k, v, _, beta, S0 = kda_inputs(150, 1.0)
+    for g in (jnp.full(q.shape, -16.0),
+              -16.0 * jax.nn.softplus(kda_inputs(150, 1.0, seed=1)[0] + 3)):
+        o, S = chunked(q, k, v, g, beta, S0)
+        want_o, want_S = ref.delta_rule(q, k, v, g, beta, S0)
+        close(o, want_o)
+        close(S, want_S)
+
+
+@pytest.mark.parametrize("H", [3, 4], ids=["a-head-a-step", "two-heads-a-step"])
+def test_kda_chunked_rows_and_heads_keep_their_own_state(H):
+    """B = 4 with another `S0` a row (and head): the state is a scratch
+    the grid re-enters at every (row, heads) — nothing of one reaches
+    another, whatever the order: a row computed alone is the row computed
+    among four (to the last bits: XLA on the CPU compiles the one-row
+    program apart)."""
+    q, k, v, g, beta, S0 = kda_inputs(140, 0.5, B=4, H=H)
+    S0 = S0 * jnp.arange(1.0, 5.0)[:, None, None, None]
+    o, S = chunked(q, k, v, g, beta, S0)
+    want_o, want_S = ref.delta_rule(q, k, v, g, beta, S0)
+    assert S.shape == (4, H, 16, 24)
+    close(o, want_o)
+    close(S, want_S)
+    for row in (0, 3):
+        alone = chunked(*(x[row:row + 1] for x in (q, k, v, g, beta, S0)))
+        close(alone[0][0], o[row], 1e-6)
+        close(alone[1][0], S[row], 1e-6)
+
+
+def test_kda_chunked_takes_bfloat16_as_the_same_values_widened():
+    """q k v as the model hands them, in bfloat16: the kernel widens them
+    in VMEM, so the result is that of the same values given in float32 —
+    to the bit, tighter than the 1e-5 both forms are held to."""
+    q, k, v, g, beta, S0 = kda_inputs(150, 1.0)
+    low = [x.astype(jnp.bfloat16) for x in (q, k, v)]
+    o, S = chunked(*low, g, beta, S0)
+    assert o.dtype == jnp.float32 and S.dtype == jnp.float32
+    wide = [x.astype(jnp.float32) for x in low]
+    same_o, same_S = chunked(*wide, g, beta, S0)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(same_o))
+    np.testing.assert_array_equal(np.asarray(S), np.asarray(same_S))
+    want_o, want_S = ref.delta_rule(*wide, g, beta, S0)
+    close(o, want_o)
+    close(S, want_S)
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 48, 128])
+def test_kda_chunked_any_chunk_of_whole_sub_blocks(chunk):
+    """One, two, three (no power of two) and eight sub-blocks a chunk:
+    the merge of the sub-blocks' inverses pairs them inside a chunk."""
+    q, k, v, g, beta, S0 = kda_inputs(200, 1.0, H=2)
+    o, S = chunked(q, k, v, g, beta, S0, chunk=chunk)
+    want_o, want_S = ref.delta_rule(q, k, v, g, beta, S0)
+    close(o, want_o)
+    close(S, want_S)
 
 
 def test_kda_chunked_frame_by_frame_is_one_pass():
     """The state a frame leaves is what the next is entered with."""
     q, k, v, g, beta, S0 = kda_inputs(96, 0.2)
-    o, S = kda.kda_chunked(q, k, v, g, beta, S0)
+    o, S = chunked(q, k, v, g, beta, S0)
     first = [x[:, :40] for x in (q, k, v, g, beta)]
     rest = [x[:, 40:] for x in (q, k, v, g, beta)]
-    o1, S1 = kda.kda_chunked(*first, S0)
-    o2, S2 = kda.kda_chunked(*rest, S1)
+    o1, S1 = chunked(*first, S0)
+    o2, S2 = chunked(*rest, S1)
     assert rel(jnp.concatenate([o1, o2], axis=1), o) < 1e-6
     assert rel(S2, S) < 1e-6
 
@@ -179,9 +257,18 @@ def test_kda_chunked_has_no_backward_and_says_so():
     q, k, v, g, beta, S0 = kda_inputs(32, 0.2)
     with pytest.raises(NotImplementedError, match="kda_chunked has no "
                                                   "backward"):
-        jax.grad(lambda v: kda.kda_chunked(q, k, v, g, beta, S0)[0].sum())(v)
+        jax.grad(lambda v: chunked(q, k, v, g, beta, S0)[0].sum())(v)
     with pytest.raises(ValueError, match="chunk"):
-        kda.kda_chunked(q, k, v, g, beta, S0, chunk=24)
+        chunked(q, k, v, g, beta, S0, chunk=24)
+
+
+def test_kda_chunked_on_the_chip_takes_whole_lane_blocks_only(monkeypatch):
+    """Compiled, a head must be whole 128-lane blocks of (B, L, H·d); the
+    refusal names the widths (the interpreter, above, takes any)."""
+    monkeypatch.setattr(kda._pallas, "use_interpret", lambda: False)
+    q, k, v, g, beta, S0 = kda_inputs(32, 0.2)
+    with pytest.raises(ValueError, match="d_k=16, d_v=24"):
+        chunked(q, k, v, g, beta, S0)
 
 
 def test_short_conv_tail_frame_by_frame_is_one_pass():

@@ -135,6 +135,16 @@ def _grouped(assignments, experts, k, n):
              ((experts, k, n), BF16), ((experts,), jnp.int32)])
 
 
+def _kda_scan(rows, L, heads, d):
+    """The third token trunk's chunked scan: q, k, v in the compute type
+    and g in float32, (B, L, H·d) as the layer's projections leave them,
+    β (B, L, H), from a cached state."""
+    tok = ((rows, L, heads * d), BF16)
+    return (kda.kda_chunked,
+            [tok, tok, tok, ((rows, L, heads * d), F32),
+             ((rows, L, heads), F32), ((rows, heads, d, d), F32)])
+
+
 # base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
 # head dim 256. GroupNorm and epilogue cases are UNet level slabs (H·W, C)
 # that `fits_vmem` admits, the largest included.
@@ -164,6 +174,10 @@ CASES = {
     "grouped_matmul_up_2560x768": _grouped(98304, 64, 2560, 768),
     "grouped_matmul_down_768x2560": _grouped(98304, 64, 768, 2560),
     "grouped_matmul_down_2048x4096": _grouped(32768, 32, 2048, 4096),
+    # the third token trunk's scan at the size its cell runs, and a ragged
+    # length (padded to whole runs of chunks)
+    "kda_chunked_4x4096_h32_d128": _kda_scan(4, 4096, 32, 128),
+    "kda_chunked_ragged_1x4000_h32_d128": _kda_scan(1, 4000, 32, 128),
     **{f"serving_attention_L{L}_d{hd}":
        _attn(serving_attention.serving_attention, L, hd, False)
        for L, hd in ((1024, 64), (1024, 256))},
@@ -199,6 +213,7 @@ KERNEL_NAMES = {
     "fused_epilogue": "fused_epilogue_256x512",
     "fused_step": "fused_step_ddpm_B2_128px",
     "gmm": "grouped_matmul_up_4096x2048",
+    "kda_fwd": "kda_chunked_ragged_1x4000_h32_d128",
 }
 
 
@@ -433,18 +448,11 @@ def test_resnet_blocks_keep_the_convolutions_layout_on_v5e(skips, v5e):
         in_blocks / h_bytes)
 
 
-# ops/kda.py is XLA, not a kernel: what the chip's compiler is asked is
-# whether the chunked scan and the short convolution lower and FIT at the
-# shapes the third token trunk's cell runs (4 rows of 4096 tokens, 32
-# heads of 128), the scan from a cached state.
-def _kda_scan(rows, L, heads, d):
-    tok = ((rows, L, heads, d), BF16)
-    return (lambda q, k, v, g, beta, S0: kda.kda_chunked(q, k, v, g, beta,
-                                                         S0),
-            [tok, tok, tok, ((rows, L, heads, d), F32),
-             ((rows, L, heads), F32), ((rows, heads, d, d), F32)])
-
-
+# The third token trunk's two sequence operators at the shapes its cell
+# runs (4 rows of 4096 tokens, 32 heads of 128): the scan — the kernel
+# `kda_fwd`, entered from a cached state, the heads side by side in the
+# last axis as the model hands them — among the kernels' CASES above, and
+# the short convolution (XLA). Both must FIT beside 7.8 GB of weights.
 def _kda_conv(rows, L, width, taps):
     return (kda.short_conv,
             [((rows, L, width), BF16), ((taps, width), BF16),
@@ -452,20 +460,68 @@ def _kda_conv(rows, L, width, taps):
 
 
 XLA_CASES = {
-    "kda_chunked_4x4096_h32_d128": _kda_scan(4, 4096, 32, 128),
-    "kda_chunked_ragged_1x4000_h32_d128": _kda_scan(1, 4000, 32, 128),
     "kda_short_conv_4x4096x12288_k4": _kda_conv(4, 4096, 3 * 32 * 128, 4),
+}
+# Temporaries the compiled program may take. The kernel keeps a chunk's
+# working set in VMEM: at whole runs of chunks it needs NO buffer in HBM
+# (2 MB: β re-tiled); a ragged length pays the padded copies of its five
+# operands and the slice of o, 0.1 GB a row — where the XLA scan took a
+# row's float32 working set, 1.1 GB.
+TEMP_LIMITS = {
+    "kda_chunked_4x4096_h32_d128": 4e6,
+    "kda_chunked_ragged_1x4000_h32_d128": 0.12e9,
+    "kda_short_conv_4x4096x12288_k4": 2.5e9,
 }
 
 
-@pytest.mark.parametrize("name", sorted(XLA_CASES))
-def test_kda_compiles_and_fits_for_v5e(name, v5e):
-    fn, arg_specs = XLA_CASES[name]
+@pytest.mark.parametrize("name", sorted(TEMP_LIMITS))
+def test_kda_compiles_and_fits_for_v5e(name, v5e, monkeypatch):
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    fn, arg_specs = (CASES | XLA_CASES)[name]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
             for shape, dtype in arg_specs]
     compiled = jax.jit(fn).lower(*args).compile()
     mem = compiled.memory_analysis()
-    # a row's float32 working set, not the batch's (ops/kda.py): a step
-    # of the cell must leave the scan room beside 7.8 GB of weights
-    assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < TEMP_LIMITS[name], mem.temp_size_in_bytes
 
+
+def test_kl48_layer_hands_the_scan_its_operands_where_they_lie(v5e,
+                                                               monkeypatch):
+    """A KDA layer of `kl48_denoiser256` at the cell's shape (4 rows of
+    4096 tokens from a cached state), compiled for the chip: under
+    `lk.kda_core` there is the kernel and nothing else of q's size — no
+    `copy`, `transpose` or `reshape` re-lays q, k, v, g or o for it (a head
+    is a 128-lane block of the (B, L, H·128) arrays the layer already
+    has), and the kernel writes o in float32 and the states."""
+    from novel_view_synthesis_3d_tpu.config import get_preset
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
+
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    cfg = get_preset("kl48_denoiser256")
+    model = build_denoiser(cfg.model)
+    k, lin = cfg.model.tokens, cfg.model.tokens.linear_attn_config
+    i, rows, L = 0, 4, 4096
+    assert not k.is_full_attention(i)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}))["params"][f"layer_{i}"])
+    width = lin.num_heads * lin.head_dim
+    cache = (S((rows, lin.num_heads, lin.head_dim, lin.head_dim), F32),
+             S((rows, lin.short_conv_kernel_size - 1, 3 * width), BF16))
+    text = jax.jit(lambda p, h, c: model.layer(i, p, h, None, c)[:2]).lower(
+        params, S((rows, L, k.hidden_size), BF16), cache).compile().as_text()
+    q_bytes = rows * L * width * 2
+    core = [(op, name, size) for op, kind, name, size in _entry_writes(text)
+            if kind == "kda_core"]
+    assert [op for op, _, size in core if size >= q_bytes // 2] == [
+        "custom-call"], core
+    assert not [c for c in core if c[0] in ("copy", "transpose", "reshape")
+                and c[2] >= q_bytes // 64], core
+    (call,) = [c for c in core if c[0] == "custom-call"]
+    assert "kda_fwd" in call[1]
+    assert call[2] == 2 * q_bytes + rows * width * lin.head_dim * 4
