@@ -12,8 +12,8 @@
     them in `batch`. What is in it is the model's own affair
     (sample/ddpm.make_sampler hands it through and names no model);
   - a scope vocabulary: `lk.<kind>` stamps from models/vocab.LAYER_KINDS
-    and `og.<label>` blocks named by `op_groups(config)` of the family's
-    module.
+    (with `pt.<part>` of LAYER_PARTS inside a kind) and `og.<label>`
+    blocks named by `op_groups(config)` of the family's module.
 
 `model.family` selects: "xunet" (models/xunet.XUNet, the default) or
 "tokens" (models/token_denoiser.TokenDenoiser). The token family has three

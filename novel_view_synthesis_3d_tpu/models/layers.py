@@ -29,7 +29,9 @@ Each leaf (FrameConv, GroupNorm, FiLM, an AttnBlock's attention) runs
 inside one `jax.named_scope("lk.<kind>")`, and so do a block's few own
 ops: the layer kind its instructions are booked under when device time is
 read back from a profiler capture (models/xunet.layer_of holds the
-vocabulary and the precedence). Metadata only, like `og.<label>`.
+vocabulary and the precedence). Metadata only, like `og.<label>`. The
+attention kernel's `pt.kernel` / `pt.layout` parts inside `lk.attn` are
+stamped by its wrapper (ops/flash_attention.py), not here.
 """
 
 from __future__ import annotations

@@ -243,7 +243,11 @@ def rms_norm(x, scale, eps):
 
 
 def _dense(x, p):
-    return jnp.dot(x, p["kernel"].astype(x.dtype))
+    """x · kernel: every dense product of the trunks, stamped `pt.matmul`
+    (models/vocab.py) so that a kind's matmul time is told from its norms,
+    rotary and slices."""
+    with jax.named_scope("pt.matmul"):
+        return jnp.dot(x, p["kernel"].astype(x.dtype))
 
 
 def _attention(q, k, v, scale, use_flash, window=None):
@@ -286,8 +290,9 @@ def route(b32, p_router, k):
     on its own; the CHOICE is the top-k of score + the router's per-expert
     correction bias, the gate the score without it, renormalised and
     scaled as before. `k` is any trunk's config."""
-    logits = jnp.dot(b32, p_router["kernel"].astype(jnp.float32),
-                     precision=HIGHEST)
+    with jax.named_scope("pt.matmul"):
+        logits = jnp.dot(b32, p_router["kernel"].astype(jnp.float32),
+                         precision=HIGHEST)
     if k.router_activation == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         _, top_i = jax.lax.top_k(
@@ -338,8 +343,9 @@ def held_expert_part(b, top_p, top_i, p_experts, k):
             jnp.minimum(i // ROW_TILE, count), count)
         # Stable, the spare rows first: a span's pad lies before its rows.
         order = jnp.argsort(jnp.concatenate([pad_slot, slot]), stable=True)
-        x = jnp.take(b, jnp.maximum(order - spare, 0) // K,
-                     axis=0, mode="clip")             # (rows, H) by expert
+        with jax.named_scope("pt.gather"):            # token → expert order
+            x = jnp.take(b, jnp.maximum(order - spare, 0) // K,
+                         axis=0, mode="clip")         # (rows, H) by expert
     with jax.named_scope("lk.moe_experts"):
         g = grouped_matmul(x, p_experts["gate"]["kernel"], spans)
         u = grouped_matmul(x, p_experts["up"]["kernel"], spans)
@@ -352,11 +358,13 @@ def held_expert_part(b, top_p, top_i, p_experts, k):
         back = jnp.argsort(order)[spare:].reshape(T, K)
         w = jnp.where(is_held.reshape(T, K), top_p, 0.0)
         out = jnp.zeros((T, y.shape[-1]), jnp.float32)
-        for c in range(K):
-            yc = jnp.take(y, back[:, c], axis=0).astype(jnp.float32)
-            wc = w[:, c:c + 1]
-            out = out + jnp.where(wc > 0, yc * wc, 0.0)
-    return out.astype(b.dtype), group_sizes
+        with jax.named_scope("pt.gather"):            # expert → token order
+            for c in range(K):
+                yc = jnp.take(y, back[:, c], axis=0).astype(jnp.float32)
+                wc = w[:, c:c + 1]
+                out = out + jnp.where(wc > 0, yc * wc, 0.0)
+        out = out.astype(b.dtype)
+    return out, group_sizes
 
 
 def gated_mlp(x, p):
@@ -466,7 +474,7 @@ class Mistral4Layer:
         routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
         with jax.named_scope("lk.moe_shared"):
             shared = gated_mlp(b, p["shared"])
-        h = h + (shared + routed).reshape(B, L, -1)
+            h = h + (shared + routed).reshape(B, L, -1)
         return h, own, (counts, top_i.reshape(B, L, -1))
 
     def key_columns(self, L: int):
@@ -750,7 +758,7 @@ class KimiLinearLayer:
         routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
         with jax.named_scope("lk.moe_shared"):
             shared = gated_mlp(b, p["shared"])
-        h = h + (shared + routed).reshape(B, L, -1)
+            h = h + (shared + routed).reshape(B, L, -1)
         return h, own, (counts, top_i.reshape(B, L, -1))
 
     def key_columns(self, L: int):
@@ -846,7 +854,9 @@ class TokenDenoiser:
                 # the X-UNet's pose embedding does.
                 ray_tok = ray_tok * cond_mask.astype(dt)[:, None, None]
             tok = tok + ray_tok
-        return tok + self._logsnr_emb(params, logsnr)[:, None, :]
+        emb = self._logsnr_emb(params, logsnr)
+        with jax.named_scope("lk.emb"):
+            return tok + emb[:, None, :]
 
     def _frame(self, params, tok, frame_index, caches):
         """One frame's tokens through every layer. → (h, per-layer cache
@@ -910,8 +920,9 @@ class TokenDenoiser:
                 k = self.config.tokens
                 hn = rms_norm(h, params["final_norm"]["scale"],
                               k.rms_norm_eps).astype(h.dtype)
-                out = jnp.dot(hn, params["out"]["kernel"].astype(h.dtype),
-                              preferred_element_type=jnp.float32)
+                with jax.named_scope("pt.matmul"):
+                    out = jnp.dot(hn, params["out"]["kernel"].astype(h.dtype),
+                                  preferred_element_type=jnp.float32)
                 eps = self._unpatch(out, z.shape[1], z.shape[2])
         return eps, routed
 

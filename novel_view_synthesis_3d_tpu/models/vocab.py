@@ -1,9 +1,21 @@
 """The scope vocabulary of tracing and staging, for every denoiser family.
 
-The program stamps `jax.named_scope("lk.<kind>")` where the work happens
-and `og.<label>` around each op group of a model's op loop; `layer_of`
-reads a scope path back into (block, kind). One vocabulary serves both
-families, so one reader (benchmarks/layer_metrics/layer_ms_per_call.py)
+Two levels of stamp inside the `og.<label>` around each op group of a
+model's op loop. The program stamps `jax.named_scope("lk.<kind>")` where
+the work happens; `layer_of` reads a scope path back into (block, kind).
+Inside a kind it stamps `pt.<part>`, one of four (LAYER_PARTS): `kernel`
+around a `pl.pallas_call` and nothing else; `layout` around what a
+kernel's wrapper does to feed it and to hand its result back (transposes,
+reshapes, pads, slices); `gather` around a row gather between token order
+and expert order; `matmul` around a dense product. `layer_part_of` reads
+a path into (block, kind or kind.part). What a kind holds outside every
+part is its remainder (norms, rotary, activations, sorts, casts): kind −
+Σ parts, no fifth name. A new kernel's wrapper stamps `pt.kernel` and
+`pt.layout` itself, in `ops/`, not at its callers, so every caller's
+capture splits alike.
+
+One vocabulary serves both families, so one reader a level
+(benchmarks/layer_metrics/layer_ms_per_call.py, part_ms_per_call.py)
 serves every cell; `models/xunet.py` re-exports these names.
 """
 
@@ -38,6 +50,11 @@ KDA_TOKEN_LAYER_KINDS = ("kda_proj", "kda_conv", "kda_core", "mla_proj",
 LAYER_KINDS = tuple(dict.fromkeys(
     XUNET_LAYER_KINDS + TOKEN_LAYER_KINDS + GQA_TOKEN_LAYER_KINDS
     + KDA_TOKEN_LAYER_KINDS))
+# Every part a `jax.named_scope("pt.<part>")` may stamp inside a kind
+# (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py,
+# models/token_denoiser.py); this tuple and layer_part_of are the only
+# other place a part is spelled.
+LAYER_PARTS = ("kernel", "layout", "gather", "matmul")
 
 
 def layer_of(path: str):
@@ -69,3 +86,21 @@ def layer_of(path: str):
     if kinds:
         return block, kinds[-1][1]
     return block, "other" if blocks else "unattributed"
+
+
+def layer_part_of(path: str):
+    """(block, "<kind>" or "<kind>.<part>") of a scope path: `layer_of`'s
+    block and kind, then the innermost `pt.<part>` (LAYER_PARTS) that
+    lies INSIDE the winning `lk.<kind>` segment — a part stamped before
+    its kind, or under a kind further out that an inner kind or an `og.`
+    block took the instruction from, is not this kind's. `pose`, `other`
+    and `unattributed` take no part. Summing the keys of one kind over
+    its parts gives `layer_of`'s kind."""
+    block, kind = layer_of(path)
+    if kind in ("pose", "other", "unattributed"):
+        return block, kind
+    segs = [s for s in re.split(r"[/()]", path.split(";", 1)[0]) if s]
+    at = len(segs) - 1 - segs[::-1].index("lk." + kind)
+    parts = [s[3:] for s in segs[at + 1:]
+             if s.startswith("pt.") and s[3:] in LAYER_PARTS]
+    return block, f"{kind}.{parts[-1]}" if parts else kind

@@ -49,8 +49,10 @@ from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 # names stay importable here (benchmarks/layer_metrics reads them here).
 from novel_view_synthesis_3d_tpu.models.vocab import (  # noqa: F401
     LAYER_KINDS,
+    LAYER_PARTS,
     XUNET_LAYER_KINDS,
     layer_of,
+    layer_part_of,
 )
 from novel_view_synthesis_3d_tpu.ops.flash_attention import resolve_flash
 from novel_view_synthesis_3d_tpu.ops.fused_epilogue import (

@@ -71,6 +71,12 @@ never leave VMEM); below that, lane padding (D → 128) wastes more MXU than
 VMEM residency saves, and an XLA einsum backward (_flash_bwd_xla) is used
 instead (measured on v5e at D=16: ~20% faster train step).
 
+The forward's wrapper stamps what it does for every caller
+(models/vocab.py, LAYER_PARTS): `pt.kernel` around each `flash_fwd` call
+and nothing else, `pt.layout` around the transposes, pads and slices that
+feed it and hand its result back, the windowed form's concatenation of
+its calls' outputs among them. Metadata only.
+
 Falls back to interpreter mode off-TPU so the same code path is unit-tested
 on the CPU mesh (tests/test_flash_attention.py).
 """
@@ -249,21 +255,28 @@ def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
     if band is not None:
         window, q_offset, rows = band
         per_head, heads = rows // block_q, Lq // rows
-        outs = [pl.pallas_call(
-            functools.partial(_attn_kernel, scale=scale, kv_len=kv_len,
-                              block_k=block_k,
-                              band=(window, q_offset + i * block_q)),
-            grid=(N, heads),
-            in_specs=[pl.BlockSpec(
-                (1, block_q, D),
-                lambda n, g, i=i: (n, g * per_head + i, 0), **mem),
-                k_spec, v_spec],
-            out_specs=pl.BlockSpec((1, block_q, Dv), lambda n, g: (n, g, 0),
-                                   **mem),
-            out_shape=jax.ShapeDtypeStruct((N, heads * block_q, Dv), q.dtype),
-            name="flash_fwd", interpret=interpret, **extra,
-        )(q, k, v).reshape(N, heads, 1, block_q, Dv) for i in range(per_head)]
-        return jnp.concatenate(outs, axis=2).reshape(N, Lq, Dv), None
+        outs = []
+        for i in range(per_head):
+            with jax.named_scope("pt.kernel"):
+                out = pl.pallas_call(
+                    functools.partial(_attn_kernel, scale=scale,
+                                      kv_len=kv_len, block_k=block_k,
+                                      band=(window, q_offset + i * block_q)),
+                    grid=(N, heads),
+                    in_specs=[pl.BlockSpec(
+                        (1, block_q, D),
+                        lambda n, g, i=i: (n, g * per_head + i, 0), **mem),
+                        k_spec, v_spec],
+                    out_specs=pl.BlockSpec((1, block_q, Dv),
+                                           lambda n, g: (n, g, 0), **mem),
+                    out_shape=jax.ShapeDtypeStruct(
+                        (N, heads * block_q, Dv), q.dtype),
+                    name="flash_fwd", interpret=interpret, **extra,
+                )(q, k, v)
+            with jax.named_scope("pt.layout"):
+                outs.append(out.reshape(N, heads, 1, block_q, Dv))
+        with jax.named_scope("pt.layout"):
+            return jnp.concatenate(outs, axis=2).reshape(N, Lq, Dv), None
     grid = (N, Lq // block_q)
     kernel = functools.partial(_attn_kernel, scale=scale, kv_len=kv_len,
                                block_k=block_k)
@@ -274,20 +287,22 @@ def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
         out_specs.append(
             pl.BlockSpec((1, block_q, 128), lambda n, i: (n, i, 0), **mem))
         out_shape.append(jax.ShapeDtypeStruct((N, Lq, 128), jnp.float32))
-    out, *lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda n, i: (n, i, 0), **mem),
-            k_spec, v_spec,
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        name="flash_fwd",
-        interpret=interpret,
-        **extra,
-    )(q, k, v)
-    return out, (lse[0][:, :, 0] if with_lse else None)
+    with jax.named_scope("pt.kernel"):
+        out, *lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), lambda n, i: (n, i, 0), **mem),
+                k_spec, v_spec,
+            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            name="flash_fwd",
+            interpret=interpret,
+            **extra,
+        )(q, k, v)
+    with jax.named_scope("pt.layout"):
+        return out, (lse[0][:, :, 0] if with_lse else None)
 
 
 def _use_interpret() -> bool:
@@ -319,28 +334,30 @@ def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
     B, Lq, H, D = q.shape
     Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     interpret = _use_interpret()
-    # (B, L, H, D) → (B·H, L, D): heads become independent grid rows.
-    qt = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-    kt = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, D)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, Dv)
     bq, bk, _ = forward_blocks(Lq, Lk, D, q.dtype.itemsize, block_q)
-    qt = _pad_to(qt, 1, bq)
-    kt = _pad_to(kt, 1, bk)
-    vt = _pad_to(vt, 1, bk)
-    if not interpret:  # lane alignment for the MXU
-        qt = _pad_to(qt, 2, 128)
-        kt = _pad_to(kt, 2, 128)
-        vt = _pad_to(vt, 2, 128)
-    rows = qt.shape[1]                     # a head's padded query rows
+    with jax.named_scope("pt.layout"):
+        # (B, L, H, D) → (B·H, L, D): heads become independent grid rows.
+        qt = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
+        kt = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, D)
+        vt = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, Dv)
+        qt = _pad_to(qt, 1, bq)
+        kt = _pad_to(kt, 1, bk)
+        vt = _pad_to(vt, 1, bk)
+        if not interpret:  # lane alignment for the MXU
+            qt = _pad_to(qt, 2, 128)
+            kt = _pad_to(kt, 2, 128)
+            vt = _pad_to(vt, 2, 128)
+        rows = qt.shape[1]                 # a head's padded query rows
+        qt = qt.reshape(B * Hkv, (H // Hkv) * rows, qt.shape[2])
     out, lse = _flash_fwd_padded(
-        qt.reshape(B * Hkv, (H // Hkv) * rows, qt.shape[2]), kt, vt,
-        scale=scale, kv_len=Lk, block_q=bq, block_k=bk, with_lse=with_lse,
-        interpret=interpret,
+        qt, kt, vt, scale=scale, kv_len=Lk, block_q=bq, block_k=bk,
+        with_lse=with_lse, interpret=interpret,
         band=None if window is None else (*window, rows))
-    out = out.reshape(B * H, rows, out.shape[2])
-    out = out[:, :Lq, :Dv].reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3)
-    if with_lse:
-        lse = lse[:, :Lq].reshape(B, H, Lq)
+    with jax.named_scope("pt.layout"):
+        out = out.reshape(B * H, rows, out.shape[2])
+        out = out[:, :Lq, :Dv].reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3)
+        if with_lse:
+            lse = lse[:, :Lq].reshape(B, H, Lq)
     return out, lse
 
 

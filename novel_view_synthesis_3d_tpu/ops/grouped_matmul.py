@@ -28,6 +28,10 @@ widest column block whose two slots fit the budget; at the chip's sizes
 that is all of N, so `lhs` is read once too. Off the TPU the same kernel
 runs through the Pallas interpreter (ops/_pallas.py's contract).
 
+The wrapper stamps `pt.kernel` around the `gmm` call and nothing else,
+`pt.layout` around what feeds it (the weights' cast, the row tiles'
+place among the groups) — models/vocab.py, LAYER_PARTS; metadata only.
+
 Chip runs (PERF.md, PR 27; one product of the token cell, ms) chose it:
 JAX's `megablox.gmm` on unaligned groups 1.83 as shipped before, 1.52–1.54
 at its best tiling, 1.59 on aligned spans (it exposes no VMEM limit, so
@@ -105,19 +109,22 @@ def _gmm(lhs, rhs, group_sizes, *, interpret: bool):
     n = rhs.shape[-1]
     if m % ROW_TILE:
         raise ValueError(f"{m} rows are not whole {ROW_TILE}-row tiles")
-    rhs = rhs.astype(lhs.dtype)
     tm, tn = ROW_TILE, _column_block(k, n, lhs.dtype.itemsize)
     tiles_n = n // tn
 
-    # Weight blocks run in the order (column block, non-empty group). Per
-    # row tile, its group's place among the non-empty ones; and those
-    # groups in order.
-    ends = jnp.cumsum(group_sizes) // tm
-    held = group_sizes > 0
-    tile = jnp.arange(m // tm, dtype=jnp.int32)
-    place = jnp.sum(held & (ends <= tile[:, None]), axis=1).astype(jnp.int32)
-    in_order = jnp.argsort(~held, stable=True).astype(jnp.int32)
-    n_held = jnp.sum(held).astype(jnp.int32)[None]
+    with jax.named_scope("pt.layout"):
+        rhs = rhs.astype(lhs.dtype)
+        # Weight blocks run in the order (column block, non-empty group).
+        # Per row tile, its group's place among the non-empty ones; and
+        # those groups in order.
+        ends = jnp.cumsum(group_sizes) // tm
+        held = group_sizes > 0
+        tile = jnp.arange(m // tm, dtype=jnp.int32)
+        place = jnp.sum(held & (ends <= tile[:, None]),
+                        axis=1).astype(jnp.int32)
+        in_order = jnp.argsort(~held, stable=True).astype(jnp.int32)
+        n_held = jnp.sum(held).astype(jnp.int32)[None]
+        row_tiles = ends[-1]   # a dynamic bound: the tail costs nothing
 
     def kernel(place, in_order, n_held, lhs_ref, rhs_hbm, out_ref, slots, sem):
         j, i = pl.program_id(0), pl.program_id(1)
@@ -147,18 +154,19 @@ def _gmm(lhs, rhs, group_sizes, *, interpret: bool):
             lhs_ref[...], slots[slot],
             preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(tiles_n, ends[-1]),
-            in_specs=[pl.BlockSpec((tm, k), lambda j, i, *_: (i, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((tm, tn), lambda j, i, *_: (i, j)),
-            scratch_shapes=[pltpu.VMEM((2, k, tn), lhs.dtype),
-                            pltpu.SemaphoreType.DMA((2,))]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        name="gmm", interpret=interpret,
-    )(place, in_order, n_held, lhs, rhs)
+    with jax.named_scope("pt.kernel"):
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(tiles_n, row_tiles),
+                in_specs=[pl.BlockSpec((tm, k), lambda j, i, *_: (i, 0)),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((tm, tn), lambda j, i, *_: (i, j)),
+                scratch_shapes=[pltpu.VMEM((2, k, tn), lhs.dtype),
+                                pltpu.SemaphoreType.DMA((2,))]),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            name="gmm", interpret=interpret,
+        )(place, in_order, n_held, lhs, rhs)

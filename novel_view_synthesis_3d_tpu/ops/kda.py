@@ -72,6 +72,11 @@ pass over triangles, whatever implements it. Off the TPU the same kernel
 runs through the Pallas interpreter (ops/_pallas.py's contract), at any
 head width; compiled, a head must be whole 128-lane blocks.
 
+`kda_chunked` and `_kda_call` stamp `pt.kernel` around the `kda_fwd` call
+and nothing else, `pt.layout` around what feeds it and hands its result
+back (the casts of g, β and the state, the pad to whole runs and its slice)
+— models/vocab.py, LAYER_PARTS; metadata only.
+
 Forward only: a gradient through `kda_chunked` raises by name.
 """
 
@@ -282,28 +287,33 @@ def _kda_call(q, k, v, g, beta, S0, *, chunk: int, interpret: bool):
     R = chunks * chunk
     pad = (-L) % R
     if pad:   # k = v = β = g = 0 rows: the state passes them unchanged
-        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-                            for x in (q, k, v, g, beta))
+        with jax.named_scope("pt.layout"):
+            q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                                for x in (q, k, v, g, beta))
 
     def lanes(d):
         return pl.BlockSpec((1, R, heads * d), lambda b, h, c: (b, c, h))
 
     state = pl.BlockSpec((1, heads, dk, dv), lambda b, h, c: (b, h, 0, 0))
-    o, S = pl.pallas_call(
-        functools.partial(_kda_kernel, chunk=chunk, chunks=chunks, wide=wide,
-                          heads=heads),
-        out_shape=(jax.ShapeDtypeStruct((B, L + pad, H * dv), jnp.float32),
-                   jax.ShapeDtypeStruct((B, H, dk, dv), jnp.float32)),
-        grid=(B, H // heads, (L + pad) // R),
-        in_specs=[lanes(dk), lanes(dk), lanes(dv), lanes(dk),
-                  pl.BlockSpec((1, R, H), lambda b, h, c: (b, c, 0)), state],
-        out_specs=(lanes(dv), state),
-        scratch_shapes=[_pallas.VMEM((heads, dv, dk), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="kda_fwd", interpret=interpret,
-    )(q, k, v, g, beta, S0)
-    return o[:, :L], S
+    with jax.named_scope("pt.kernel"):
+        o, S = pl.pallas_call(
+            functools.partial(_kda_kernel, chunk=chunk, chunks=chunks,
+                              wide=wide, heads=heads),
+            out_shape=(jax.ShapeDtypeStruct((B, L + pad, H * dv),
+                                            jnp.float32),
+                       jax.ShapeDtypeStruct((B, H, dk, dv), jnp.float32)),
+            grid=(B, H // heads, (L + pad) // R),
+            in_specs=[lanes(dk), lanes(dk), lanes(dv), lanes(dk),
+                      pl.BlockSpec((1, R, H), lambda b, h, c: (b, c, 0)),
+                      state],
+            out_specs=(lanes(dv), state),
+            scratch_shapes=[_pallas.VMEM((heads, dv, dk), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="kda_fwd", interpret=interpret,
+        )(q, k, v, g, beta, S0)
+    with jax.named_scope("pt.layout"):
+        return o[:, :L], S
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -337,8 +347,9 @@ def kda_chunked(q, k, v, g, beta, S0=None, *, chunk: int = CHUNK):
     if chunk % SUB_BLOCK:
         raise ValueError(f"chunk={chunk} is not a multiple of {SUB_BLOCK}")
     B, _, H = beta.shape
-    if S0 is None:
-        S0 = jnp.zeros((B, H, q.shape[-1] // H, v.shape[-1] // H),
-                       jnp.float32)
-    return _kda(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32),
-                S0.astype(jnp.float32), int(chunk))
+    with jax.named_scope("pt.layout"):
+        if S0 is None:
+            S0 = jnp.zeros((B, H, q.shape[-1] // H, v.shape[-1] // H),
+                           jnp.float32)
+        g, beta, S0 = (x.astype(jnp.float32) for x in (g, beta, S0))
+    return _kda(q, k, v, g, beta, S0, int(chunk))
