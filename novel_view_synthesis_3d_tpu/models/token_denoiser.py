@@ -90,6 +90,7 @@ from novel_view_synthesis_3d_tpu.config import (
     KimiLinearTrunkConfig, ModelConfig, SmallThinkerTrunkConfig,
     TokenTrunkConfig)
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
+from novel_view_synthesis_3d_tpu.ops.expert_combine import combine
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
     band_key_columns, flash_attention, resolve_flash, window_binds)
 from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
@@ -351,19 +352,15 @@ def held_expert_part(b, top_p, top_i, p_experts, k):
         u = grouped_matmul(x, p_experts["up"]["kernel"], spans)
         y = grouped_matmul(_ACTIVATIONS[k.expert_activation](g) * u,
                            p_experts["down"]["kernel"], spans)
-        # Back to token order and summed over a token's choices, one
-        # gather per choice (no (token, choice, hidden) relayout). A choice
-        # that is not held has weight 0 and points past the last span,
-        # at rows the product never wrote: masked, not multiplied.
+        # Back to token order and summed over a token's choices: one
+        # kernel (ops/expert_combine.py) that fetches from `y`, where the
+        # product left it, the rows of the experts a tile's tokens were
+        # given and of no others. A choice that is not held has weight 0
+        # and points past the last span, at rows the product never wrote:
+        # never read.
         back = jnp.argsort(order)[spare:].reshape(T, K)
         w = jnp.where(is_held.reshape(T, K), top_p, 0.0)
-        out = jnp.zeros((T, y.shape[-1]), jnp.float32)
-        with jax.named_scope("pt.gather"):            # expert → token order
-            for c in range(K):
-                yc = jnp.take(y, back[:, c], axis=0).astype(jnp.float32)
-                wc = w[:, c:c + 1]
-                out = out + jnp.where(wc > 0, yc * wc, 0.0)
-        out = out.astype(b.dtype)
+        out = combine(y, back, w, slot, group_sizes, b.dtype)
     return out, group_sizes
 
 

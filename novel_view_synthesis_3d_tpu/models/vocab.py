@@ -12,7 +12,11 @@ a path into (block, kind or kind.part). What a kind holds outside every
 part is its remainder (norms, rotary, activations, sorts, casts): kind −
 Σ parts, no fifth name. A new kernel's wrapper stamps `pt.kernel` and
 `pt.layout` itself, in `ops/`, not at its callers, so every caller's
-capture splits alike.
+capture splits alike. ONE kernel is not `kernel`: `moe_combine`
+(ops/expert_combine.py) IS the row gather from expert order back to token
+order, so its wrapper stamps `pt.gather` around its `pl.pallas_call` —
+`moe_experts.gather` goes on reading the combine and `moe_experts.kernel`
+the three grouped products, whatever implements either.
 
 One vocabulary serves both families, so one reader a level
 (benchmarks/layer_metrics/layer_ms_per_call.py, part_ms_per_call.py)
@@ -52,8 +56,8 @@ LAYER_KINDS = tuple(dict.fromkeys(
     + KDA_TOKEN_LAYER_KINDS))
 # Every part a `jax.named_scope("pt.<part>")` may stamp inside a kind
 # (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py,
-# models/token_denoiser.py); this tuple and layer_part_of are the only
-# other place a part is spelled.
+# ops/expert_combine.py, models/token_denoiser.py); this tuple and
+# layer_part_of are the only other place a part is spelled.
 LAYER_PARTS = ("kernel", "layout", "gather", "matmul")
 
 

@@ -158,6 +158,13 @@ PART_PATHS = {
     "innermost_part_wins": (
         TOKENS + "/og.layer_1/lk.mla_core/pt.layout/pt.kernel/flash_fwd",
         ("layer_1", "mla_core.kernel")),
+    # the one kernel that is not `kernel`: the combine is the row gather
+    "the_combine_kernel_is_its_kinds_gather": (
+        TOKENS + "/og.layer_1/lk.moe_experts/pt.gather/moe_combine",
+        ("layer_1", "moe_experts.gather")),
+    "the_combine_kernels_interpreted_body": (
+        TOKENS + "/og.layer_1/lk.moe_experts/pt.gather/moe_combine/while/"
+        "body/cond/branch_1_fun/mul", ("layer_1", "moe_experts.gather")),
     # a part outside its kind is ignored
     "part_before_its_kind": (
         TOKENS + "/og.layer_1/pt.matmul/lk.mla_proj/add",
@@ -435,6 +442,23 @@ def test_each_kernel_call_is_stamped_kernel_and_nothing_else_is(
     assert {p for p in paths if "/pt.kernel" in p} == set(calls)
 
 
+def test_the_combine_kernel_is_stamped_gather(stamped_paths):
+    """The one kernel that is not `kernel` (models/vocab.py): the expert
+    layer's combine, `moe_combine`, is the row gather from expert order to
+    token order, so its call lies under `lk.moe_experts/pt.gather` — the
+    part that read XLA's gathers goes on reading the combine — and the
+    X-UNet has none."""
+    which, paths = stamped_paths
+    calls = [p for p in paths if re.search(r"/moe_combine(/|$)", p)]
+    assert bool(calls) == (which != "x_unet")
+    for p in calls:
+        assert re.search(r"/lk\.moe_experts/(jit\(_combine\)/)?pt\.gather/"
+                         r"moe_combine(/|$)", p), p
+        assert layer_part_of(p)[1] == "moe_experts.gather", p
+    under = {p for p in paths if "/lk.moe_experts/" in p and "/pt.gather" in p}
+    assert under == set(calls)
+
+
 def test_summing_parts_gives_the_kinds(stamped_paths):
     _, paths = stamped_paths
     for p in paths:
@@ -558,6 +582,34 @@ def test_moe_rows_visited_over_held_on_hand_made_counts(counters, want):
     import harness
 
     got = harness.layer_reader("moe_rows_visited_over_held", BENCH)(
+        [], None, counters)
+    assert got == (want if want is None else pytest.approx(want))
+
+
+_SIZES = {"side": 16, "patch_size": 4, "num_experts_per_tok": 4}
+
+
+@pytest.mark.parametrize("counters,want", [
+    # one layer, one pass of 2 x 1 rows of 16 tokens: 32 held of 128 choices
+    ({"routing_counts": [[20, 12, 0, 0]], "counted_rows": 2, "views": 1,
+      "sizes": _SIZES}, 32 / 128),
+    # two layers add up before the division
+    ({"routing_counts": [[32, 32, 32, 32], [0, 0, 0, 64]],
+      "counted_rows": 2, "views": 1, "sizes": _SIZES}, (128 + 64) / 256),
+    # counted over 4 rows where a step of the timed program has 2 x 1
+    ({"routing_counts": [[40, 24, 0, 0]], "counted_rows": 4, "views": 1,
+      "sizes": _SIZES}, 32 / 128),
+    # every choice held
+    ({"routing_counts": [[64, 64, 0, 128]], "counted_rows": 4, "views": 2,
+      "sizes": _SIZES}, 1.0),
+    ({"routing_counts": [[20, 12]], "sizes": _SIZES}, None),
+    ({"routing_counts": [[20, 12]], "counted_rows": 2, "views": 1}, None),
+    ({}, None)])
+def test_moe_combine_fetched_over_choices_on_hand_made_counts(counters,
+                                                              want):
+    import harness
+
+    got = harness.layer_reader("moe_combine_fetched_over_choices", BENCH)(
         [], None, counters)
     assert got == (want if want is None else pytest.approx(want))
 

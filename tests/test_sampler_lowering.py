@@ -62,16 +62,18 @@ TOY = {
 # (preset, "cpu" | "v5e") → sha256 of the lowered text, from the parent
 # of the PR that last meant to change it (CHANGES.md, PR 30) — `paper256`'s
 # two from PR 31's own tree, which meant to change that program (the
-# X-UNet carries (B·F, H, W, C)) and no other.
+# X-UNet carries (B·F, H, W, C)) and no other, and `ms4_denoiser128`'s two
+# from PR 37's, which meant to change that one (the expert layer's combine
+# is the kernel `moe_combine`) and left `paper256`'s as they were.
 DIGESTS = {
     ("paper256", "cpu"):
         "39347a7dc4a454945a858ac36c13fad5a51d48cd03e335f9c293d145eaad0b23",
     ("ms4_denoiser128", "cpu"):
-        "b543c837af4ac88bdc87d448985cc8528042f2a069535005fc388d714e9c060b",
+        "e1116f1eeaaa10248ac67a2016934a786d7d303045df7dc20c87fd35a04b8d4e",
     ("paper256", "v5e"):
         "63517c08226f48f0a0478fde26dc9d9b22e2bfe776035c1783a9bf535b087e71",
     ("ms4_denoiser128", "v5e"):
-        "c733feca9b7be61d75490621feba9e2a21c6cbc0318a9f6bfe7bd91ef825aa22",
+        "3c9874938e8fdba4f6ff895d76c5b6423762b73f3a7467c81779b0344a4d8243",
 }
 
 

@@ -37,7 +37,7 @@ from novel_view_synthesis_3d_tpu.diffusion.schedules import (  # noqa: E402
 from novel_view_synthesis_3d_tpu.models import (  # noqa: E402
     build_denoiser, token_denoiser)
 from novel_view_synthesis_3d_tpu.models.vocab import (  # noqa: E402
-    TOKEN_LAYER_KINDS, layer_of)
+    TOKEN_LAYER_KINDS, layer_of, layer_part_of)
 from novel_view_synthesis_3d_tpu.sample.ddpm import make_sampler  # noqa: E402
 
 TOL = 2e-5
@@ -399,6 +399,150 @@ def test_column_blocks_and_the_worst_case_buffer():
             assert gm.rows_visited(sizes) <= rows
 
 
+# The combine kernel against the XLA form it replaced. (T, K, H, experts,
+# held (first, count), dtype, VMEM slot bytes — small slots make small
+# tiles, so a few hundred tokens walk many tiles and both slots —, what
+# the case plants.)
+COMBINE = {
+    "one_tile_h64_float32": (96, 2, 64, 8, (0, 4), jnp.float32, None, ""),
+    "ragged_tokens_bfloat16": (100, 4, 128, 16, (4, 8), jnp.bfloat16, None,
+                               ""),
+    "many_tiles_two_slots": (200, 4, 128, 16, (4, 8), jnp.bfloat16, 62000,
+                             ""),
+    "many_tiles_float32": (150, 3, 64, 8, (2, 5), jnp.float32, 30000, ""),
+    "all_k_held": (130, 6, 256, 8, (0, 8), jnp.bfloat16, 160000, ""),
+    "nothing_held": (64, 4, 128, 16, (4, 8), jnp.bfloat16, 46000,
+                     "nothing_held"),
+    "a_token_without_a_held_choice": (96, 2, 128, 8, (0, 2), jnp.bfloat16,
+                                      23000, "some_none"),
+    "a_held_choice_of_weight_zero": (160, 4, 128, 8, (0, 6), jnp.bfloat16,
+                                     54000, "zero_weight"),
+    "one_expert_takes_everything": (256, 2, 128, 8, (3, 2), jnp.bfloat16,
+                                    39000, "one_expert"),
+    "float16_rows": (48, 2, 128, 4, (0, 4), jnp.float16, None, ""),
+}
+
+
+def _xla_combine(y, back, w, dtype):
+    """The combine as it stood until the kernel: one gather a choice, its
+    cast, mask and weighted float32 sum, one cast at the end."""
+    out = jnp.zeros((back.shape[0], y.shape[-1]), jnp.float32)
+    for c in range(back.shape[1]):
+        yc = jnp.take(y, back[:, c], axis=0).astype(jnp.float32)
+        wc = w[:, c:c + 1]
+        out = out + jnp.where(wc > 0, yc * wc, 0.0)
+    return out.astype(dtype)
+
+
+@pytest.mark.parametrize("case", sorted(COMBINE))
+def test_expert_combine_is_the_shipped_kernel(case, monkeypatch):
+    """ops/expert_combine.py through the Pallas interpreter (the kernel the
+    chip compiles) against the XLA form written out above, on
+    held_expert_part's own layout: BITWISE equal — the choice order, the
+    float32 sum and the one cast are the same operations. `y` is NaN in
+    every row no fetched choice points at (pad rows, the rows past the last
+    span, rows of weight 0), so a chunk's other rows, a skipped choice's
+    stale slot and a masked choice never reach a sum."""
+    from novel_view_synthesis_3d_tpu.ops import expert_combine as ec
+    from novel_view_synthesis_3d_tpu.ops import grouped_matmul as gm
+
+    T, K, H, n_experts, (first, count), dtype, slot_bytes, plant = \
+        COMBINE[case]
+    if slot_bytes:
+        monkeypatch.setattr(ec, "SLOT_BYTES", slot_bytes)
+        tt = ec.token_tile(T, K, count, H, jnp.dtype(dtype).itemsize
+                           if dtype != jnp.float16 else 4)
+        assert T > 2 * tt, (case, tt)                 # both slots, refilled
+    ec._combine.clear_cache()
+    rng = np.random.default_rng(len(case))
+    top_i = _choices({"nothing_held": "nothing_held",
+                      "one_expert": "everything_held"}.get(
+        plant, "independent" if count < n_experts else "everything_held"),
+        T, K, first, count, n_experts, rng)
+    if plant == "one_expert":
+        top_i[:, 0] = first + 1
+        top_i[:, 1] = (first + count) % n_experts     # not held
+    top_p = rng.random((T, K)).astype(np.float32) + 0.05
+    top_i = jnp.asarray(top_i, jnp.int32)
+    local = top_i.reshape(-1) - first
+    is_held = (local >= 0) & (local < count)
+    slot = jnp.where(is_held, local, count)
+    sizes = jnp.bincount(slot, length=count + 1)[:count].astype(jnp.int32)
+    spans = gm.span_sizes(sizes)
+    spare = gm.buffer_rows(T * K, count) - T * K
+    i = jnp.arange(spare)
+    pad_slot = jnp.where(i % gm.ROW_TILE < jnp.repeat(
+        spans - sizes, gm.ROW_TILE, total_repeat_length=spare),
+        jnp.minimum(i // gm.ROW_TILE, count), count)
+    order = jnp.argsort(jnp.concatenate([pad_slot, slot]), stable=True)
+    back = jnp.argsort(order)[spare:].reshape(T, K)
+    w = np.where(np.asarray(is_held).reshape(T, K), top_p, 0.0)
+    if plant == "zero_weight":
+        w[::3, 1] = 0.0
+    held_per_token = (w > 0).sum(-1)
+    if plant in ("", "some_none") and count < n_experts:
+        assert 0 in held_per_token and held_per_token.max() >= 2
+    if case == "all_k_held":
+        assert np.all(held_per_token == K)
+    y = rng.standard_normal((gm.buffer_rows(T * K, count), H)).astype(
+        np.float32)
+    read = np.zeros(len(y), bool)
+    read[np.asarray(back)[w > 0]] = True
+    assert read.sum() == (w > 0).sum()
+    y[~read] = np.nan
+    y, w = jnp.asarray(y).astype(dtype), jnp.asarray(w, jnp.float32)
+    try:
+        got = ec.combine(y, back, w, slot, sizes, dtype)
+    finally:
+        ec._combine.clear_cache()
+    want = _xla_combine(y, back, w, dtype)
+    assert got.shape == (T, H) and got.dtype == want.dtype
+    assert not np.isnan(np.asarray(got, np.float32)).any()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert ec.rows_fetched(sizes) == int(np.asarray(is_held).sum())
+
+
+def test_token_tiles_and_the_worst_case_slot():
+    """The token tile adapts to (K, experts held, H, the rows' bytes) alone:
+    the widest whose VMEM slot holds the chunks a tile can need in the
+    worst case — and no routing needs more: every expert's range a row
+    past a chunk's end at both ends is the bound, random ranges stay
+    under it."""
+    from novel_view_synthesis_3d_tpu.ops import expert_combine as ec
+
+    assert ec.token_tile(8192, 4, 32, 4096, 2) == 512      # the three cells
+    assert ec.token_tile(16384, 6, 64, 2560, 2) == 512
+    assert ec.token_tile(16384, 8, 128, 2304, 2) == 256
+    assert ec.token_tile(96, 2, 4, 64, 4) == 96            # no wider than T
+    assert ec.token_tile(100, 2, 4, 64, 4) == 112
+    for tt, K, groups, H in ((512, 4, 32, 4096), (512, 6, 64, 2560),
+                             (256, 8, 128, 2304)):
+        assert (ec.chunks_max(tt, K, groups) * ec.CHUNK * H * 2
+                <= ec.SLOT_BYTES
+                < ec.chunks_max(2 * tt, K, groups) * ec.CHUNK * H * 2)
+    rng = np.random.default_rng(0)
+    for tt, K, groups in ((32, 4, 8), (16, 2, 64), (64, 6, 5)):
+        most = ec.chunks_max(tt, K, groups)
+        for _ in range(50):
+            # a tile's assignments among the experts, each range anywhere
+            count = rng.multinomial(tt * K, rng.dirichlet(
+                np.full(groups, 0.3)))
+            first = rng.integers(0, 1 << 16, groups)
+            chunks = np.where(count > 0, -(-(first + count) // ec.CHUNK)
+                              - first // ec.CHUNK, 0)
+            assert chunks.sum() <= most
+        # the worst case: as many experts as may be, one row each across a
+        # chunk's end... (a range of one row touches one chunk; of two, two)
+        count = np.zeros(groups, np.int64)
+        count[:min(groups, tt * K // 2)] = 2
+        count[0] += tt * K - count.sum()
+        first = np.full(groups, ec.CHUNK - 1)
+        chunks = np.where(count > 0, -(-(first + count) // ec.CHUNK)
+                          - first // ec.CHUNK, 0)
+        assert chunks.sum() <= most
+
+
 def _spy_on_the_products(monkeypatch):
     """Every (lhs, group_sizes) that held_expert_part hands the seam the
     benchmark's control replaces, the product itself untouched."""
@@ -594,6 +738,17 @@ def test_compiled_sampler_stamps_are_the_token_vocabulary(small):
     blocks = {layer_of(p)[0] for p in paths} - {""}
     assert blocks == labels
     assert any("/precompute/" in p for p in paths)
+    # The combine's kernel is its kind's `gather`, not a `kernel`
+    # (models/vocab.py names the exception): every expert layer of the
+    # step and of the once-a-call pass has it, under that stamp alone.
+    combine = [p for part in paths for p in part.split(";")
+               if "/moe_combine" in p]
+    assert len({layer_of(p)[0] for p in combine}) == \
+        cfg.model.tokens.num_hidden_layers
+    for p in combine:
+        assert "/lk.moe_experts/" in p and "/pt.gather/moe_combine" in p, p
+        assert "pt.kernel" not in p, p
+        assert layer_part_of(p)[1] == "moe_experts.gather", p
     top = set(params)
     assert {n for _, names in token_denoiser.op_groups(cfg.model)
             for n in names} == top

@@ -26,6 +26,7 @@ import pytest
 
 from novel_view_synthesis_3d_tpu.ops import (
     _pallas,
+    expert_combine,
     flash_attention,
     fused_epilogue,
     fused_groupnorm,
@@ -135,6 +136,19 @@ def _grouped(assignments, experts, k, n):
              ((experts, k, n), BF16), ((experts,), jnp.int32)])
 
 
+def _combine(tokens, choices, width, experts):
+    """The expert layer's combine at a cell's step: the down product's
+    static buffer in HBM, a row and a gate a choice, each assignment's
+    held expert and the counts the row ranges come from; the token tile
+    is the one the shapes choose (its two VMEM slots of chunks, the scoped
+    limit the kernel asks for)."""
+    return (lambda y, back, w, slot, sizes: expert_combine.combine(
+        y, back, w, slot, sizes, BF16),
+        [((grouped_matmul.buffer_rows(tokens * choices, experts), width),
+          BF16), ((tokens, choices), jnp.int32), ((tokens, choices), F32),
+         ((tokens * choices,), jnp.int32), ((experts,), jnp.int32)])
+
+
 def _kda_scan(rows, L, heads, d):
     """The third token trunk's chunked scan: q, k, v in the compute type
     and g in float32, (B, L, H·d) as the layer's projections leave them,
@@ -174,6 +188,11 @@ CASES = {
     "grouped_matmul_up_2560x768": _grouped(98304, 64, 2560, 768),
     "grouped_matmul_down_768x2560": _grouped(98304, 64, 768, 2560),
     "grouped_matmul_down_2048x4096": _grouped(32768, 32, 2048, 4096),
+    # the combine of the three token cells: (tokens a step, top-k, hidden,
+    # experts held)
+    "moe_combine_8192x4x4096": _combine(8192, 4, 4096, 32),
+    "moe_combine_16384x6x2560": _combine(16384, 6, 2560, 64),
+    "moe_combine_16384x8x2304": _combine(16384, 8, 2304, 128),
     # the third token trunk's scan at the size its cell runs, and a ragged
     # length (padded to whole runs of chunks)
     "kda_chunked_4x4096_h32_d128": _kda_scan(4, 4096, 32, 128),
@@ -214,6 +233,7 @@ KERNEL_NAMES = {
     "fused_step": "fused_step_ddpm_B2_128px",
     "gmm": "grouped_matmul_up_4096x2048",
     "kda_fwd": "kda_chunked_ragged_1x4000_h32_d128",
+    "moe_combine": "moe_combine_8192x4x4096",
 }
 
 
@@ -243,6 +263,41 @@ def test_flash_kernels_are_distinct_instructions(v5e, monkeypatch):
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert sum(kernel in c for c in calls) == 1, (kernel, calls)
     assert len(set(calls)) == len(calls) == 3
+
+
+def test_held_expert_part_compiles_to_four_kernels_and_no_gather(
+        v5e, monkeypatch):
+    """The expert layer compiled for the chip: the three grouped products
+    and the combine are its four custom calls, and under `lk.moe_experts`
+    no XLA `gather` is left (the one under `lk.moe_route` is the dispatch
+    into expert order)."""
+    import re
+    from types import SimpleNamespace
+
+    from novel_view_synthesis_3d_tpu.models import token_denoiser
+
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    T, K, H, F, held = 2048, 4, 1024, 512, 8
+    k = SimpleNamespace(held_experts=(0, held), expert_activation="silu")
+
+    def spec(*shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    experts = {"gate": {"kernel": spec(held, H, F)},
+               "up": {"kernel": spec(held, H, F)},
+               "down": {"kernel": spec(held, F, H)}}
+    text = jax.jit(lambda b, p, i, w: token_denoiser.held_expert_part(
+        b, p, i, w, k)).lower(
+        spec(T, H), spec(T, K, dtype=F32), spec(T, K, dtype=jnp.int32),
+        experts).compile().as_text()
+    calls = re.findall(r"^\s*%(\S+) = .* custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert sorted(c.split(".")[0] for c in calls) == [
+        "gmm", "gmm", "gmm", "moe_combine"], calls
+    gathers = re.findall(r"^.* gather\(.*$", text, re.M)
+    assert gathers and not [g for g in gathers if "lk.moe_experts" in g]
+    assert all("lk.moe_route/pt.gather" in g for g in gathers
+               if "op_name" in g)
 
 
 def test_token_trunk_shapes_compile_in_both_forms(v5e, monkeypatch):
