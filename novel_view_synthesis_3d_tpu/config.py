@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 from typing import Any, Optional, Sequence, Tuple
 
@@ -221,10 +222,84 @@ class KimiLinearTrunkConfig:
         return i < self.first_k_dense_replace
 
 
+@dataclasses.dataclass(frozen=True)
+class Phi4FlashTrunkConfig:
+    """The token denoiser's fourth trunk: Phi-4-mini-flash-reasoning's whole
+    decoder stack (SambaY) under the key names of its `phi4flash`
+    `config.json` — layers of FIVE kinds by index (`layer_kind`, the
+    source's own rule in `num_hidden_layers` and `mb_per_layer`): Mamba-1
+    selective-scan layers whose cache is a recurrent state; differential
+    attention (adjacent head pairs, two softmax maps subtracted) under a
+    one-sided `sliding_window`; ONE layer of differential attention over
+    everything, the only layer whose keys and values are kept; and, past
+    it, gated memory units that read the LAST Mamba layer's scan output and
+    differential cross-attention that projects queries only and reads that
+    one layer's keys and values. LayerNorm (weight and bias), a dense
+    gated-SiLU MLP in every layer, no expert layer at all. The defaults
+    are the published values; the Mamba sizes are Mamba-1's defaults,
+    which the source's config class carries and `config.json` does not
+    repeat."""
+
+    hidden_size: int = 2560
+    num_hidden_layers: int = 32
+    num_attention_heads: int = 40
+    num_key_value_heads: int = 20
+    intermediate_size: int = 10240
+    hidden_act: str = "silu"
+    mlp_bias: bool = False
+    layer_norm_eps: float = 1e-5
+    # Every `mb_per_layer`-th layer (from 0) is a Mamba layer in the first
+    # half and a gated memory unit past layer N/2 + 1.
+    mb_per_layer: int = 2
+    sliding_window: int = 512
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    # The patch adapter (this repo's), as every trunk's.
+    patch_size: int = 4
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def mamba_dt_rank(self) -> int:
+        """Mamba-1's "auto": ⌈hidden / 16⌉."""
+        return -(-self.hidden_size // 16)
+
+    @property
+    def rms_norm_eps(self) -> float:
+        """The frame's own last RMSNorm takes the trunk's eps."""
+        return self.layer_norm_eps
+
+    def layer_kind(self, i: int) -> str:
+        """Layer i's (0-based) kind, as the source writes it. With N
+        layers: a layer is a Mamba SLOT where i is a multiple of
+        `mb_per_layer`; up to N/2 + 1 (the self-decoder) a slot is "mamba"
+        and the others differential attention, "attn_window" except layer
+        N/2 + 1, "attn_full"; from N/2 + 2 on (the cross-decoder) a slot
+        is "gmu" and the others "attn_cross"."""
+        half = self.num_hidden_layers // 2
+        slot = self.mb_per_layer > 0 and i % self.mb_per_layer == 0
+        if i >= half + 2:
+            return "gmu" if slot else "attn_cross"
+        if slot:
+            return "mamba"
+        return "attn_full" if i == half + 1 else "attn_window"
+
+    def lambda_init(self, i: int) -> float:
+        """λ⁰ of layer i's differential attention: 0.8 − 0.6·e^(−0.3 i)."""
+        return 0.8 - 0.6 * math.exp(-0.3 * i)
+
+
 # The trunks `ModelConfig.tokens` may hold; a serialized config says
 # which by its keys (they share only sizes every trunk has).
 TOKEN_TRUNKS = (TokenTrunkConfig, SmallThinkerTrunkConfig,
-                KimiLinearTrunkConfig)
+                KimiLinearTrunkConfig, Phi4FlashTrunkConfig)
 
 
 def _trunk_of_keys(keys) -> type:
@@ -1988,22 +2063,50 @@ def _xunet_family_errors(m: ModelConfig, d: DataConfig) -> list:
     return errors
 
 
+def _phi4flash_errors(k: Phi4FlashTrunkConfig) -> list:
+    """What `layer_kind`'s rule and the paired heads need."""
+    errors = []
+    N, mb = k.num_hidden_layers, k.mb_per_layer
+    if N < 4 or N % 2 or mb < 2 or (N // 2) % mb:
+        errors.append(
+            f"model.tokens.num_hidden_layers={N}, mb_per_layer={mb}: layer "
+            "N/2 must be a Mamba layer (the one whose scan output the "
+            "gated memory units read) and layer N/2 + 1 attention (the one "
+            "whose keys and values are kept)")
+    if k.num_attention_heads % 2 or k.num_key_value_heads % 2 \
+            or (k.num_attention_heads // 2) % (k.num_key_value_heads // 2):
+        errors.append(
+            "model.tokens: differential attention pairs adjacent heads: "
+            "num_attention_heads and num_key_value_heads must be even and "
+            "the query pairs a multiple of the key pairs")
+    if k.hidden_size % k.num_attention_heads:
+        errors.append("model.tokens.hidden_size is not a multiple of "
+                      "num_attention_heads")
+    if k.hidden_act != "silu" or k.mlp_bias:
+        errors.append("model.tokens.hidden_act other than 'silu' and "
+                      "mlp_bias=True are not carried")
+    return errors
+
+
 def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
     """What a `family: tokens` model needs of its settings."""
     k = m.tokens
     if k is None:
         return ["model.family='tokens' needs model.tokens (the trunk)"]
     errors = []
-    first, count = k.held_experts
-    if not (0 <= first and count >= 1
-            and first + count <= k.n_routed_experts):
-        errors.append(
-            f"model.tokens.held_experts={tuple(k.held_experts)} is not a "
-            f"(first, count) range inside the router's "
-            f"{k.n_routed_experts} experts")
-    if not 1 <= k.num_experts_per_tok <= k.n_routed_experts:
-        errors.append("model.tokens.num_experts_per_tok must be in "
-                      "[1, n_routed_experts]")
+    if isinstance(k, Phi4FlashTrunkConfig):     # the trunk without experts
+        errors += _phi4flash_errors(k)
+    else:
+        first, count = k.held_experts
+        if not (0 <= first and count >= 1
+                and first + count <= k.n_routed_experts):
+            errors.append(
+                f"model.tokens.held_experts={tuple(k.held_experts)} is not "
+                f"a (first, count) range inside the router's "
+                f"{k.n_routed_experts} experts")
+        if not 1 <= k.num_experts_per_tok <= k.n_routed_experts:
+            errors.append("model.tokens.num_experts_per_tok must be in "
+                          "[1, n_routed_experts]")
     if d.img_sidelength % k.patch_size:
         errors.append(
             f"data.img_sidelength={d.img_sidelength} is not a multiple of "
@@ -2041,7 +2144,7 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
         if not k.mla_use_nope:
             errors.append("model.tokens.mla_use_nope=False (rotary in this "
                           "trunk's latent attention) is not carried")
-    elif k.qk_rope_head_dim % 2:
+    elif isinstance(k, TokenTrunkConfig) and k.qk_rope_head_dim % 2:
         errors.append("model.tokens.qk_rope_head_dim must be even (rotary "
                       "pairs)")
     if m.num_cond_frames != 1:
@@ -2054,7 +2157,8 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
 # Config ladder presets (BASELINE.json "configs")
 # ----------------------------------------------------------------------
 PRESET_NAMES = ("reference", "tiny64", "base128", "paper256", "pod64",
-                "ms4_denoiser128", "st21_denoiser256", "kl48_denoiser256")
+                "ms4_denoiser128", "st21_denoiser256", "kl48_denoiser256",
+                "p4f_denoiser256")
 
 
 def get_preset(name: str) -> Config:
@@ -2179,6 +2283,23 @@ def get_preset(name: str) -> Config:
                 dropout=0.0,
                 tokens=KimiLinearTrunkConfig(num_hidden_layers=5,
                                              held_experts=(0, 128))),
+            data=DataConfig(img_sidelength=256),
+            diffusion=DiffusionConfig(sample_timesteps=256),
+        )
+    if name == "p4f_denoiser256":
+        # A token denoiser whose trunk is Phi-4-mini-flash-reasoning's
+        # WHOLE decoder stack at its published widths and depth
+        # (Phi4FlashTrunkConfig's defaults): 9 Mamba layers, 8 of
+        # differential attention under the 512 window, 1 over everything,
+        # then 7 gated memory units and 7 cross layers that read layer
+        # 16's scan output and layer 17's keys and values; a dense MLP in
+        # each. bfloat16 parameters: 3.34 B = 6.68 GB, one chip holds all
+        # 32 layers. 256 px, 4096 tokens a frame: the window is an eighth
+        # of a frame.
+        return Config(
+            model=ModelConfig(
+                family="tokens", dtype="bfloat16", param_dtype="bfloat16",
+                dropout=0.0, tokens=Phi4FlashTrunkConfig()),
             data=DataConfig(img_sidelength=256),
             diffusion=DiffusionConfig(sample_timesteps=256),
         )

@@ -16,17 +16,23 @@
     blocks named by `op_groups(config)` of the family's module.
 
 `model.family` selects: "xunet" (models/xunet.XUNet, the default) or
-"tokens" (models/token_denoiser.TokenDenoiser). The token family has three
+"tokens" (models/token_denoiser.TokenDenoiser). The token family has four
 trunks behind that one class — `model.tokens` is one of
 config.TOKEN_TRUNKS and names the layers: Mistral-Small-4's (latent
 attention, a shared expert; its cache entry a latent), SmallThinker's
 (grouped-query heads, a window and rotary per layer, the router ahead of
-attention; its cache entry keys and values) or Kimi-Linear's stack, whose
+attention; its cache entry keys and values), Kimi-Linear's stack, whose
 layers differ BY INDEX (KDA, a gated delta rule, or latent attention
-without a positional term; a dense MLP or sigmoid-routed experts): what
-`precompute` returns holds one cache entry a layer, each of its layer's
-own kind — there a recurrent state with its convolution's tail beside a
-latent. Entry points that carry only the X-UNet say so through
+without a positional term; a dense MLP or sigmoid-routed experts), or
+Phi-4-mini-flash's whole stack (Mamba selective-scan layers, differential
+attention under a window and full, then gated memory units and cross
+layers that READ what two earlier layers publish in the same pass — one
+scan output, one key/value cache; no expert layer): what `precompute`
+returns holds one cache entry a layer, each of its layer's own kind — a
+recurrent state with its convolution's tail beside a latent, a window's
+tail beside one layer's keys and values — and None for a layer that keeps
+nothing of a frame; the once-a-call pass stops at the last layer that
+keeps one. Entry points that carry only the X-UNet say so through
 `require_family`.
 """
 

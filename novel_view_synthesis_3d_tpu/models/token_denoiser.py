@@ -1,10 +1,12 @@
 """A token denoiser: patch tokens of both frames through a decoder trunk
 of a published language model, ε̂ of the target frame out.
 
-**Three trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
+**Four trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
 and names the layers; the frame asks the layer object for layer i's
-parameter tree and takes back layer i's cache entry, so a trunk's layers
-may differ by index:
+parameter tree and takes back layer i's cache entry — or None, from a layer
+that keeps nothing of a frame — so a trunk's layers may differ by index,
+and it carries beside `h` what a layer PUBLISHES for later layers of the
+same pass (one trunk's; the others' calls do not take it):
 
   - `Mistral4Layer` (config.TokenTrunkConfig; Mistral-Small-4-119B-2603,
     `mistral4` config.json): RMSNorm → low-rank queries and a compressed
@@ -36,11 +38,27 @@ may differ by index:
     last three pre-convolution rows; a latent layer's is (c_kv, the shared
     key part). A KDA layer has no frame rule: the target frame's scan is
     entered with the conditioning frame's state, every step anew.
+  - `Phi4FlashLayer` (config.Phi4FlashTrunkConfig;
+    Phi-4-mini-flash-reasoning, SambaY): ALL 32 layers, five kinds by
+    index — Mamba-1 (a short convolution with a bias, an input-dependent
+    step, the selective scan s_t = exp(Δ_t ⊙ A) ⊙ s_{t−1} + (Δ_t ⊙ u_t)
+    B_tᵀ in SEQUENCE order, ops/ssm.py; its cache the state and the
+    convolution's tail), differential attention (adjacent head pairs, two
+    softmax maps over one 128-wide value pair, subtracted with a learned
+    λ, a pair-wise RMSNorm) under a one-sided 512 window (its cache the
+    window's TAIL, 511 rows of keys and values) or over everything (layer
+    17, the ONLY layer whose keys and values are kept whole), and past it
+    gated memory units that read layer 16's scan output token for token
+    and cross layers that project queries only and read layer 17's keys
+    and values: fourteen layers without a cache entry. LayerNorm with
+    weight and bias, biases on the attention projections and the
+    convolution, a dense gated-SiLU MLP in every layer, NO expert layer
+    (`routing_counts` and `routing_choices` refuse it by name).
 
-`route` and `held_expert_part` are one function each for all (the scoring
-function, top-k, the renormalisation and the activation come from the
-trunk's config), as are the grouped product and the attention kernel
-under them. What is this
+`route` and `held_expert_part` are one function each for all that route
+(the scoring function, top-k, the renormalisation and the activation come
+from the trunk's config), as are the grouped product and the attention
+kernel under them. What is this
 repo's and not a source's is the frame around the trunk:
 
   - both frames are cut into `patch_size`² patches, one token each:
@@ -57,10 +75,14 @@ repo's and not a source's is the frame around the trunk:
 
 **The once-a-call pass.** Because of that mask, everything a step needs of
 the conditioning frame is its per-layer cache. `precompute` runs the
-conditioning frame through all layers once (prefill) and every denoise
-step runs the target's tokens alone against [cache ; own], or from the
-cached state (decode through the cache). `apply` without a cache does
-exactly the two in a row, so there is one set of equations.
+conditioning frame once (prefill) through the layers UP TO THE LAST THAT
+KEEPS A CACHE ENTRY — all of them in three trunks, layers 0–17 of the
+fourth's 32: nothing its cross-decoder computes of that frame is ever
+read, and the pass is built without it, not left to the compiler to cut —
+and every denoise step runs the target's tokens alone against [cache ;
+own], or from the cached state (decode through the cache). `apply`
+without a cache does exactly the two in a row, so there is one set of
+equations.
 
 **The expert layer is told which experts it holds** (`held_experts`, a
 (first, count) range: this chip's share of an expert-parallel deployment).
@@ -87,8 +109,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from novel_view_synthesis_3d_tpu.config import (
-    KimiLinearTrunkConfig, ModelConfig, SmallThinkerTrunkConfig,
-    TokenTrunkConfig)
+    KimiLinearTrunkConfig, ModelConfig, Phi4FlashTrunkConfig,
+    SmallThinkerTrunkConfig, TokenTrunkConfig)
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 from novel_view_synthesis_3d_tpu.ops.expert_combine import combine
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
@@ -97,6 +119,7 @@ from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
     ROW_TILE, buffer_rows, grouped_matmul, span_sizes)
 from novel_view_synthesis_3d_tpu.ops.kda import kda_chunked, short_conv
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
+from novel_view_synthesis_3d_tpu.ops.ssm import selective_scan
 
 LOGSNR_CLEAN = 20.0   # the conditioning frame's logsnr: 3DiM's clean frame
 RAY_CHANNELS = 144    # posenc_nerf(origin, 15) 93 + posenc_nerf(dir, 8) 51
@@ -184,7 +207,10 @@ def op_groups(cfg: ModelConfig):
 
 def param_shapes(cfg: ModelConfig) -> dict:
     """The parameter tree as ShapeDtypeStructs. 2-D kernels are (in, out);
-    an expert stack is (held, in, out). No biases in the trunk."""
+    an expert stack is (held, in, out). Three trunks have no bias at all
+    (a router's correction bias apart); Phi4FlashLayer's has LayerNorm
+    weights AND biases, and biases on its attention projections, its
+    convolution and its step projection."""
     k = cfg.tokens
     dt = jnp.dtype(cfg.param_dtype)
     H = k.hidden_size
@@ -215,6 +241,25 @@ def _mlp_shapes(w, hidden, width, *lead):
 
 def _init_leaf(key, path, s):
     name = path[-1]
+    if "mamba" in path and (name in ("A_log", "D") or path[-2] == "dt"):
+        # Mamba-1 as its public implementation starts it: a channel's
+        # rates A = 1..N, the skip D = 1, the step's projection from
+        # U(±rank^(−1/2)) and its bias the inverse softplus of a step
+        # from log-U(1e-3, 1e-1).
+        if name == "A_log":
+            return jnp.broadcast_to(jnp.log(jnp.arange(
+                1, s.shape[1] + 1, dtype=jnp.float32)), s.shape).astype(
+                    s.dtype)
+        if name == "D":
+            return jnp.ones(s.shape, s.dtype)
+        u = jax.random.uniform(key, s.shape, jnp.float32)
+        if name == "kernel":
+            return ((2.0 * u - 1.0) / math.sqrt(s.shape[0])).astype(s.dtype)
+        dt = jnp.exp(math.log(1e-3) + u * math.log(1e2))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(s.dtype)
+    if name.startswith("lambda_"):   # differential attention's: N(0, 0.1)
+        return (0.1 * jax.random.normal(key, s.shape, jnp.float32)).astype(
+            s.dtype)
     if name == "scale":
         return jnp.ones(s.shape, s.dtype)
     if name == "bias" or path[0] == "out":
@@ -243,6 +288,14 @@ def rms_norm(x, scale, eps):
         * scale.astype(jnp.float32)
 
 
+def layer_norm(x, p, eps):
+    """LayerNorm with weight and bias; float32 in, float32 out."""
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
+
+
 def _dense(x, p):
     """x · kernel: every dense product of the trunks, stamped `pt.matmul`
     (models/vocab.py) so that a kind's matmul time is told from its norms,
@@ -252,8 +305,9 @@ def _dense(x, p):
 
 
 def _attention(q, k, v, scale, use_flash, window=None):
-    """softmax(q·kᵀ·scale)·v, softmax in float32. q (B, Lq, N, D), k/v
-    (B, Lk, Nkv, D), query head n on key/value head n // (N // Nkv). The
+    """softmax(q·kᵀ·scale)·v, softmax in float32. q (B, Lq, N, D), k (B,
+    Lk, Nkv, D), v (B, Lk, Nkv, Dv), query head n on key/value head n //
+    (N // Nkv). The
     queries are the last Lq positions of the key axis. No mask but the
     window's (the caller hands each frame's queries the keys of the frames
     they may see): with `window`, a query at p sees key j iff j > p −
@@ -276,7 +330,8 @@ def _attention(q, k, v, scale, use_flash, window=None):
             - window
         s = jnp.where(jnp.asarray(seen), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("bngqk,bknd->bqngd", p, v).reshape(B, Lq, N, D)
+    return jnp.einsum("bngqk,bknd->bqngd", p, v).reshape(B, Lq, N,
+                                                         v.shape[-1])
 
 
 _ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
@@ -381,12 +436,18 @@ def gated_mlp(x, p):
 # frame's tokens h (B, L, hidden), `cache` layer i's entry of the frames
 # before it or None; and `key_columns(L)`, the (visited, visible) key
 # columns of its windowed layers' attention over a step's L target
-# queries, (0, 0) for a trunk without windows.
+# queries, (0, 0) for a trunk without windows. `has_experts`: whether any
+# layer of the trunk routes. `publishes`: whether its layers hand state to
+# LATER layers of the same pass — such a trunk's `__call__` takes one more
+# argument, a dict the frame makes anew for every pass, which a layer
+# writes and later layers read; its `cache_kind(i)` is None, and its cache
+# entry None, for a layer that keeps nothing of a frame.
 # ---------------------------------------------------------------------------
 class Mistral4Layer:
     """Mistral-Small-4's layer (latent attention, a shared expert)."""
 
     cache_name = "latent_cache"
+    has_experts, publishes = True, False
 
     def __init__(self, config: ModelConfig):
         self.config = config
@@ -492,6 +553,7 @@ class SmallThinkerLayer:
     experts)."""
 
     cache_name = "kv_cache"
+    has_experts, publishes = True, False
 
     def __init__(self, config: ModelConfig):
         self.config = config
@@ -604,6 +666,7 @@ class KimiLinearLayer:
     expert."""
 
     cache_name = "layer_cache"
+    has_experts, publishes = True, False
 
     def __init__(self, config: ModelConfig):
         self.config = config
@@ -763,9 +826,215 @@ class KimiLinearLayer:
         return 0, 0
 
 
+class Phi4FlashLayer:
+    """Phi-4-mini-flash-reasoning's layers (SambaY), five kinds by index
+    (`config.Phi4FlashTrunkConfig.layer_kind`), a dense gated-SiLU MLP in
+    each, LayerNorm before both halves:
+
+      - "mamba": Mamba-1 — in-projection to (u, z), a causal depthwise
+        convolution of 4 taps with a bias and SiLU, (δ, B, C) from u′, Δ =
+        softplus(W_dt δ + b), the selective scan (ops/ssm.py) from the
+        cached state, m ⊙ SiLU(z), out-projection. Its cache entry is the
+        state after the frame's last token (float32, (B, N, channels)) and
+        the convolution's tail. The LAST Mamba layer publishes its scan
+        output m (with the D term, before the gate).
+      - "attn_window" / "attn_full": differential attention — adjacent
+        query heads pair, adjacent key heads pair, a pair's two value heads
+        side by side are ONE value of twice the width; two softmax maps
+        over it, subtracted with a learned λ, a pair-wise RMSNorm, × (1 −
+        λ⁰). No positional term. A window layer's cache entry is the
+        window's TAIL alone: the last `sliding_window` − 1 rows of the
+        frame's keys and values (no later query sees an earlier row); the
+        full layer's is the frame's keys and values whole, and it
+        publishes them over [cache ; own].
+      - "gmu": y = W_out(SiLU(W_in x) ⊙ m), m the published scan output,
+        token for token. No cache entry.
+      - "attn_cross": queries only, against the published keys and
+        values, the same differential form. No cache entry."""
+
+    cache_name = "layer_cache"
+    has_experts, publishes = False, True
+    CACHE_KINDS = {"mamba": "recurrent_state", "attn_window": "window_tail",
+                   "attn_full": "keys_values"}
+
+    def __init__(self, config: ModelConfig):
+        self.config = config
+
+    def cache_kind(self, i):
+        """None for a layer that keeps nothing of a frame."""
+        return self.CACHE_KINDS.get(self.config.tokens.layer_kind(i))
+
+    def param_shapes(self, w, i):
+        k = self.config.tokens
+        H, D = k.hidden_size, k.head_dim
+        NH, NKV = k.num_attention_heads, k.num_key_value_heads
+        C, N, R = k.mamba_d_inner, k.mamba_d_state, k.mamba_dt_rank
+        kind = k.layer_kind(i)
+
+        def norm(width):
+            return {"scale": w(width), "bias": w(width)}
+
+        def diff(qkv_width):
+            return {"qkv": {"kernel": w(H, qkv_width), "bias": w(qkv_width)},
+                    **{"lambda_" + n: w(D) for n in ("q1", "k1", "q2", "k2")},
+                    "sub_norm": {"scale": w(2 * D)},
+                    "o": {"kernel": w(NH * D, H), "bias": w(H)}}
+
+        if kind == "mamba":
+            mix = {"mamba": {
+                "in": {"kernel": w(H, 2 * C)},
+                "conv": {"kernel": w(k.mamba_d_conv, C), "bias": w(C)},
+                "x": {"kernel": w(C, R + 2 * N)},
+                "dt": {"kernel": w(R, C), "bias": w(C)},
+                "A_log": w(C, N), "D": w(C),
+                "out": {"kernel": w(C, H)}}}
+        elif kind == "gmu":
+            mix = {"gmu": {"in": {"kernel": w(H, C)},
+                           "out": {"kernel": w(C, H)}}}
+        elif kind == "attn_cross":      # queries only
+            mix = {"attn": diff(NH * D)}
+        else:
+            mix = {"attn": diff((NH + 2 * NKV) * D)}
+        return {"norm": norm(H), **mix, "mlp_norm": norm(H),
+                "mlp": _mlp_shapes(w, H, k.intermediate_size)}
+
+    def tables(self, positions):
+        """No layer of this trunk has a positional term."""
+        return None
+
+    def window(self, i):
+        """Layer i's window, or None where it sees every key."""
+        k = self.config.tokens
+        return k.sliding_window if k.layer_kind(i) == "attn_window" else None
+
+    def _mamba(self, i, layer, h, cache, published):
+        """h + Mamba(LN(h)) over one frame's tokens, from `cache` = (the
+        state, the convolution's tail) of the frames before (None: the
+        sequence starts here). → (h, this frame's (state, tail))."""
+        k, p = self.config.tokens, layer["mamba"]
+        dt = jnp.dtype(self.config.dtype)
+        N, R = k.mamba_d_state, k.mamba_dt_rank
+        state, tail = (None, None) if cache is None else cache
+        f32 = jnp.float32
+        with jax.named_scope("lk.ssm_proj"):
+            a = layer_norm(h, layer["norm"], k.layer_norm_eps).astype(dt)
+            u, z = jnp.split(_dense(a, p["in"]), 2, axis=-1)
+        with jax.named_scope("lk.ssm_conv"):
+            y, tail = short_conv(u, p["conv"]["kernel"], tail,
+                                 p["conv"]["bias"])
+            x = jax.nn.silu(y).astype(dt)
+        with jax.named_scope("lk.ssm_proj"):
+            dbc = _dense(x, p["x"])
+            with jax.named_scope("pt.matmul"):   # the step, float32 out
+                step = jnp.dot(dbc[..., :R], p["dt"]["kernel"].astype(dt),
+                               preferred_element_type=f32)
+            step = jax.nn.softplus(step + p["dt"]["bias"].astype(f32))
+            A = -jnp.exp(p["A_log"].astype(f32))
+        with jax.named_scope("lk.ssm_core"):
+            m, state = selective_scan(x, step, A, dbc[..., R:R + N],
+                                      dbc[..., R + N:], p["D"], state)
+        if k.layer_kind(i + k.mb_per_layer) != "mamba":   # the last one
+            published["m"] = m
+        with jax.named_scope("lk.ssm_proj"):
+            h = h + _dense((m * jax.nn.silu(z.astype(f32))).astype(dt),
+                           p["out"])
+        return h, (state, tail)
+
+    def _attn(self, i, layer, h, cache, published):
+        """h + differential attention of LN(h) over one frame's tokens.
+        A window or full layer projects q, k, v and reads [`cache` ; own];
+        a cross layer projects q alone and reads the published keys and
+        values. → (h, this frame's cache entry or None)."""
+        cfg, k, p = self.config, self.config.tokens, layer["attn"]
+        dt, f32 = jnp.dtype(cfg.dtype), jnp.float32
+        B, L, _ = h.shape
+        NH, NKV, D = k.num_attention_heads, k.num_key_value_heads, k.head_dim
+        kind, window = k.layer_kind(i), self.window(i)
+        own = None
+        with jax.named_scope("lk.gqa_proj"):
+            a = layer_norm(h, layer["norm"], k.layer_norm_eps).astype(dt)
+            qkv = _dense(a, p["qkv"]) + p["qkv"]["bias"].astype(dt)
+            q = qkv[..., :NH * D]
+            if kind == "attn_cross":
+                keys, values = published["kv"]
+            else:
+                keys = qkv[..., NH * D:(NH + NKV) * D]
+                values = qkv[..., (NH + NKV) * D:]
+                own = (keys, values)
+                if window is not None and L >= window:
+                    # no query of a later frame sees an earlier row
+                    own = (keys[:, L - window + 1:],
+                           values[:, L - window + 1:])
+                if cache is not None:
+                    keys = jnp.concatenate([cache[0].astype(dt), keys],
+                                           axis=1)
+                    values = jnp.concatenate([cache[1].astype(dt), values],
+                                             axis=1)
+                if kind == "attn_full":
+                    published["kv"] = (keys, values)
+            Lk = keys.shape[1]
+            # Adjacent heads pair: a pair's queries and keys are maps 1
+            # and 2, its two value heads side by side one value.
+            q = q.reshape(B, L, NH // 2, 2, D)
+            kp = keys.reshape(B, Lk, NKV // 2, 2, D)
+            vp = values.reshape(B, Lk, NKV // 2, 2 * D)
+            lam0 = k.lambda_init(i)
+            lam = jnp.exp(jnp.sum(p["lambda_q1"].astype(f32)
+                                  * p["lambda_k1"].astype(f32))) \
+                - jnp.exp(jnp.sum(p["lambda_q2"].astype(f32)
+                                  * p["lambda_k2"].astype(f32))) + lam0
+        binds = window_binds(L, window, Lk - L)
+        with jax.named_scope("lk.attn_cross" if kind == "attn_cross" else
+                             "lk.attn_window" if binds else "lk.attn_full"):
+            flash = resolve_flash(cfg.use_flash_attention)
+            o1, o2 = (_attention(q[:, :, :, s], kp[:, :, :, s], vp, D ** -0.5,
+                                 flash, window) for s in (0, 1))
+        with jax.named_scope("lk.gqa_proj"):
+            o = rms_norm(o1.astype(f32) - lam * o2.astype(f32),
+                         p["sub_norm"]["scale"], k.layer_norm_eps) \
+                * (1.0 - lam0)
+            h = h + _dense(o.reshape(B, L, NH * D).astype(dt), p["o"]) \
+                + p["o"]["bias"].astype(dt)
+        return h, own
+
+    def _gmu(self, i, layer, h, cache, published):
+        """h + W_out(SiLU(W_in LN(h)) ⊙ m), m the published scan output."""
+        k, p = self.config.tokens, layer["gmu"]
+        dt = jnp.dtype(self.config.dtype)
+        with jax.named_scope("lk.gmu"):
+            a = layer_norm(h, layer["norm"], k.layer_norm_eps).astype(dt)
+            g = jax.nn.silu(_dense(a, p["in"]).astype(jnp.float32))
+            h = h + _dense((g * published["m"]).astype(dt), p["out"])
+        return h, None
+
+    def __call__(self, i, p, h, tables, cache, published):
+        del tables
+        k = self.config.tokens
+        mix = {"mamba": self._mamba, "gmu": self._gmu}.get(
+            k.layer_kind(i), self._attn)
+        h, own = mix(i, p, h, cache, published)
+        with jax.named_scope("lk.dense_mlp"):
+            b = layer_norm(h, p["mlp_norm"], k.layer_norm_eps).astype(
+                jnp.dtype(self.config.dtype))
+            h = h + gated_mlp(b, p["mlp"])
+        return h, own, (None, None)
+
+    def key_columns(self, L: int):
+        """(visited, visible) key columns of one map's L target queries
+        against [the window's tail ; own], summed over the window layers
+        whose window binds there."""
+        k = self.config.tokens
+        tail = min(L, k.sliding_window - 1)
+        per_layer = [band_key_columns(L, tail + L, self.window(i), tail)
+                     for i in range(k.num_hidden_layers)
+                     if window_binds(L, self.window(i), tail)]
+        return tuple(sum(c) for c in zip(*per_layer)) if per_layer else (0, 0)
+
+
 TRUNK_LAYERS = {TokenTrunkConfig: Mistral4Layer,
                 SmallThinkerTrunkConfig: SmallThinkerLayer,
-                KimiLinearTrunkConfig: KimiLinearLayer}
+                KimiLinearTrunkConfig: KimiLinearLayer,
+                Phi4FlashTrunkConfig: Phi4FlashLayer}
 
 
 def trunk_layer(cfg: ModelConfig):
@@ -855,25 +1124,32 @@ class TokenDenoiser:
         with jax.named_scope("lk.emb"):
             return tok + emb[:, None, :]
 
-    def _frame(self, params, tok, frame_index, caches):
-        """One frame's tokens through every layer. → (h, per-layer cache
-        entries of this frame, (tokens per held expert of each layer that
-        has experts (expert layers, held), those layers' chosen experts, a
-        tuple of (B, L, k)))."""
+    def _frame(self, params, tok, frame_index, caches, layers=None):
+        """One frame's tokens through the first `layers` layers (all where
+        None). → (h, per-layer cache entries of this frame — None for a
+        layer that keeps nothing or did not run —, (tokens per held expert
+        of each layer that has experts (expert layers, held), None for a
+        trunk without experts; those layers' chosen experts, a tuple of
+        (B, L, k)))."""
         L = tok.shape[1]
+        N = self.config.tokens.num_hidden_layers
         tables = self.layer.tables(np.arange(L) + frame_index * L)
         h, owns, counts, choices = tok, [], [], []
-        for i in range(self.config.tokens.num_hidden_layers):
+        # What a layer publishes for later layers of THIS pass, beside h.
+        published = ({},) if self.layer.publishes else ()
+        for i in range(N if layers is None else layers):
             label = layer_label(i)
             with jax.named_scope(f"og.{label}"):
                 h, own, (c, chosen) = self.layer(
                     i, params[label], h, tables,
-                    None if caches is None else caches[i])
+                    None if caches is None else caches[i], *published)
             owns.append(own)
             if c is not None:      # a layer with experts
                 counts.append(c)
                 choices.append(chosen)
-        return h, tuple(owns), (jnp.stack(counts), tuple(choices))
+        owns += [None] * (N - len(owns))
+        return h, tuple(owns), (jnp.stack(counts) if counts else None,
+                                tuple(choices))
 
     def _cond_frame(self, params, cond, cond_mask):
         """The conditioning frame through the trunk: its per-layer cache."""
@@ -885,7 +1161,11 @@ class TokenDenoiser:
             tok = self._frame_tokens(
                 params, x, R1, t1, cond["K"],
                 jnp.full((B,), LOGSNR_CLEAN, jnp.float32), cond_mask)
-        _, cache, routed = self._frame(params, tok, 0, None)
+        # Nothing past the last layer that keeps a cache entry is ever read
+        # of this frame: the pass stops there.
+        last = max(i for i in range(self.config.tokens.num_hidden_layers)
+                   if self.layer.cache_kind(i) is not None)
+        _, cache, routed = self._frame(params, tok, 0, None, last + 1)
         return cache, routed
 
     # -- the contract --------------------------------------------------------
@@ -893,8 +1173,9 @@ class TokenDenoiser:
         """What does not change over a call, for `_raw_eps`'s doubled
         guidance layout (rows [conditional…, unconditional…]): the
         conditioning frame's per-layer cache (each layer's own: a latent,
-        keys and values, or a recurrent state with its convolution's
-        tail), as one batch entry."""
+        keys and values or their window's tail, or a recurrent state with
+        its convolution's tail; None for a layer that keeps nothing), as
+        one batch entry."""
         B = cond["x"].shape[0]
         doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0),
                                dict(cond))
@@ -935,17 +1216,25 @@ class TokenDenoiser:
                 "and op slices are the X-UNet's)")
         return self._forward(variables["params"], batch, cond_mask)[0]
 
+    def _needs_experts(self, who: str):
+        if not self.layer.has_experts:
+            raise NotImplementedError(
+                f"{who}: {type(self.config.tokens).__name__} is a trunk "
+                "without expert layers; it routes nothing")
+
     def routing_counts(self, params, batch, cond_mask=None):
         """Tokens each held expert is given, per layer that has experts, in
         the pass that `apply` makes over the target's tokens: (expert
         layers, held) int32. Every assignment to a held expert is in it —
         nothing is dropped."""
+        self._needs_experts("routing_counts")
         return self._forward(params, batch, cond_mask)[1][0]
 
     def routing_choices(self, params, batch, cond_mask=None):
         """The experts each token of [conditioning frame ; target frame]
         is sent to, per layer that has experts, in the passes `apply`
         makes: (expert layers, B, 2L, k) int32, held or not."""
+        self._needs_experts("routing_choices")
         cache, (_, cond) = self._cond_frame(params, batch, cond_mask)
         own = self._forward(params, dict(batch, **{
             self.layer.cache_name: cache}), cond_mask)[1][1]
@@ -977,6 +1266,8 @@ class TokenDenoiser:
         for i, entry in enumerate(jax.eval_shape(one_row,
                                                  param_shapes(cfg))):
             kind = self.layer.cache_kind(i)
+            if kind is None:       # a layer that keeps nothing
+                continue
             out[kind] = out.get(kind, 0) + sum(
                 a.size * a.dtype.itemsize for a in jax.tree.leaves(entry))
         return out

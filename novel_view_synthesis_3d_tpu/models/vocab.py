@@ -47,15 +47,26 @@ KDA_TOKEN_LAYER_KINDS = ("kda_proj", "kda_conv", "kda_core", "mla_proj",
                          "mla_core", "moe_route", "moe_experts",
                          "moe_shared", "dense_mlp", "patch", "emb", "pose",
                          "update")
+# The token family's fourth trunk (Phi-4-mini-flash's stack, SambaY): a
+# Mamba layer stamps its products (in, x, dt, out, with the gate) as
+# `ssm_proj`, the short convolution with its SiLU as `ssm_conv` and the
+# selective scan as `ssm_core`; differential attention stamps its
+# projections, λ and the pair-wise norm as `gqa_proj` and its two maps by
+# what the layer reads — `attn_window`, `attn_full`, or `attn_cross` where
+# the keys and values are another layer's —; a gated memory unit is `gmu`;
+# every layer's MLP `dense_mlp`. No expert kind.
+SSM_TOKEN_LAYER_KINDS = ("ssm_proj", "ssm_conv", "ssm_core", "gqa_proj",
+                         "attn_window", "attn_full", "attn_cross", "gmu",
+                         "dense_mlp", "patch", "emb", "pose", "update")
 # Every kind a `jax.named_scope("lk.<kind>")` may stamp. The stamps sit
 # where the work happens (models/layers.py, models/xunet.py,
 # models/token_denoiser.py, sample/ddpm.py); these tuples and layer_of are
 # the only other place a kind is spelled.
 LAYER_KINDS = tuple(dict.fromkeys(
     XUNET_LAYER_KINDS + TOKEN_LAYER_KINDS + GQA_TOKEN_LAYER_KINDS
-    + KDA_TOKEN_LAYER_KINDS))
+    + KDA_TOKEN_LAYER_KINDS + SSM_TOKEN_LAYER_KINDS))
 # Every part a `jax.named_scope("pt.<part>")` may stamp inside a kind
-# (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py,
+# (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py, ops/ssm.py,
 # ops/expert_combine.py, models/token_denoiser.py); this tuple and
 # layer_part_of are the only other place a part is spelled.
 LAYER_PARTS = ("kernel", "layout", "gather", "matmul")

@@ -98,12 +98,13 @@ RUN_HEADS = 2   # heads a grid step, where H divides
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def short_conv(x, w, tail=None):
+def short_conv(x, w, tail=None, bias=None):
     """Causal depthwise convolution over the sequence: y_t = Σ_j w_j ⊙
-    x_{t−(K−1)+j}, the last tap on the token itself, no bias. x (B, L, D),
-    w (K, D), `tail` (B, K−1, D) the rows before x's first (zeros where
-    None: the sequence starts here). → (y (B, L, D) float32, the last K−1
-    rows of [tail ; x], which a continuation takes as its `tail`)."""
+    x_{t−(K−1)+j} (+ `bias` (D,) where one is given: Mamba's; KDA's has
+    none), the last tap on the token itself. x (B, L, D), w (K, D), `tail`
+    (B, K−1, D) the rows before x's first (zeros where None: the sequence
+    starts here). → (y (B, L, D) float32, the last K−1 rows of [tail ; x],
+    which a continuation takes as its `tail`)."""
     K = w.shape[0]
     B, L, D = x.shape
     if tail is None:
@@ -111,6 +112,8 @@ def short_conv(x, w, tail=None):
     ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     w32 = w.astype(jnp.float32)
     y = sum(ext[:, j:j + L].astype(jnp.float32) * w32[j] for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return y, ext[:, L:]
 
 

@@ -72,9 +72,21 @@ TINY_KDA_TOKENS = {
     "model.dtype": "float32", "model.param_dtype": "float32",
     "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
 }
+# The fourth token trunk (Phi-4-mini-flash's stack) at toy sizes: Mamba,
+# window, Mamba, window, Mamba, full, a gated memory unit, a cross layer.
+TINY_SSM_TOKENS = {
+    "model.tokens.hidden_size": 32, "model.tokens.num_hidden_layers": 8,
+    "model.tokens.num_attention_heads": 4,
+    "model.tokens.num_key_value_heads": 2,
+    "model.tokens.intermediate_size": 48, "model.tokens.sliding_window": 6,
+    "model.tokens.mamba_d_state": 4, "data.img_sidelength": 16,
+    "model.dtype": "float32", "model.param_dtype": "float32",
+    "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
+}
 TINY_BY_PRESET = {"ms4_denoiser128": TINY_TOKENS,
                   "st21_denoiser256": TINY_GQA_TOKENS,
-                  "kl48_denoiser256": TINY_KDA_TOKENS}
+                  "kl48_denoiser256": TINY_KDA_TOKENS,
+                  "p4f_denoiser256": TINY_SSM_TOKENS}
 
 
 def token_cfg(**over) -> Config:

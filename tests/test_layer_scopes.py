@@ -326,12 +326,13 @@ def test_each_module_call_is_stamped_once(sampler_paths):
 
 
 # The compiled toy sampler of every denoiser the benchmark has a cell of:
-# the X-UNet above, and the token family's three trunks at the sizes their
+# the X-UNet above, and the token family's four trunks at the sizes their
 # cells rehearse at (benchmarks/traffic/<traffic>.json, `rehearse`).
 TRUNKS = {"ms4_denoiser128": "sample_scan_tokens",
           "st21_denoiser256": "sample_scan_swa",
-          "kl48_denoiser256": "sample_scan_kda"}
-KERNELS = ("flash_fwd", "gmm", "kda_fwd")
+          "kl48_denoiser256": "sample_scan_kda",
+          "p4f_denoiser256": "sample_scan_ssm"}
+KERNELS = ("flash_fwd", "gmm", "kda_fwd", "ssm_fwd")
 # The parts each compiled sampler must show (it may show more: the
 # wrappers' own `layout` under `moe_experts` and `kda_core`).
 PARTS_SEEN = {
@@ -352,6 +353,12 @@ PARTS_SEEN = {
         "moe_route.matmul", "moe_route.gather", "moe_experts.kernel",
         "moe_experts.gather", "moe_shared.matmul", "patch.matmul",
         "emb.matmul"},
+    "p4f_denoiser256": {
+        "ssm_core.kernel", "ssm_core.layout", "ssm_proj.matmul",
+        "attn_window.kernel", "attn_window.layout", "attn_full.kernel",
+        "attn_full.layout", "attn_cross.kernel", "attn_cross.layout",
+        "gqa_proj.matmul", "gmu.matmul", "dense_mlp.matmul",
+        "patch.matmul", "emb.matmul"},
 }
 # What the X-UNet's op loop does between modules stays `other` (the frame
 # stacking, the skip concatenation, a cast: `paper256.sample_scan` reads
@@ -447,10 +454,10 @@ def test_the_combine_kernel_is_stamped_gather(stamped_paths):
     layer's combine, `moe_combine`, is the row gather from expert order to
     token order, so its call lies under `lk.moe_experts/pt.gather` — the
     part that read XLA's gathers goes on reading the combine — and the
-    X-UNet has none."""
+    X-UNet and the trunk without expert layers have none."""
     which, paths = stamped_paths
     calls = [p for p in paths if re.search(r"/moe_combine(/|$)", p)]
-    assert bool(calls) == (which != "x_unet")
+    assert bool(calls) == (which not in ("x_unet", "p4f_denoiser256"))
     for p in calls:
         assert re.search(r"/lk\.moe_experts/(jit\(_combine\)/)?pt\.gather/"
                          r"moe_combine(/|$)", p), p

@@ -58,6 +58,13 @@ TOY = {
         "model.tokens.moe_intermediate_size": 32,
         "model.tokens.held_experts": [0, 4], "data.img_sidelength": 16,
         "model.use_flash_attention": True},
+    "p4f_denoiser256": {
+        "model.tokens.hidden_size": 64, "model.tokens.num_hidden_layers": 8,
+        "model.tokens.num_attention_heads": 4,
+        "model.tokens.num_key_value_heads": 2,
+        "model.tokens.intermediate_size": 96,
+        "model.tokens.sliding_window": 6, "model.tokens.mamba_d_state": 8,
+        "data.img_sidelength": 16, "model.use_flash_attention": True},
 }
 # (preset, "cpu" | "v5e") → sha256 of the lowered text, from the parent
 # of the PR that last meant to change it (CHANGES.md, PR 30) — `paper256`'s
@@ -74,6 +81,12 @@ DIGESTS = {
         "63517c08226f48f0a0478fde26dc9d9b22e2bfe776035c1783a9bf535b087e71",
     ("ms4_denoiser128", "v5e"):
         "3c9874938e8fdba4f6ff895d76c5b6423762b73f3a7467c81779b0344a4d8243",
+    # PR 38's own tree: the fourth trunk's sampler as that PR made it (the
+    # three above are the parent's, untouched by its seam in the frame).
+    ("p4f_denoiser256", "cpu"):
+        "04b8d37bb8944bd71deba61de2ee1c6b8a1c39f79266e57514e1210c68370ce0",
+    ("p4f_denoiser256", "v5e"):
+        "1482754728b851968fe80f38d640c89be3a02bfed89eae8efb8838b659075f15",
 }
 
 

@@ -1,0 +1,133 @@
+"""ops/ssm.py's selective scan (the Pallas kernel `ssm_fwd`, interpreted
+here) against the token-by-token recurrence, and ops/kda.py's short
+convolution with a bias, at small sizes on the CPU; float32 on both sides,
+differing by nothing but the order of the sum over states."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from novel_view_synthesis_3d_tpu.ops import _pallas, kda, ssm
+
+
+def recurrence(u, dt, A, B, C, D, s0):
+    """s_t = exp(Δ_t ⊙ A) ⊙ s_{t−1} + (Δ_t ⊙ u_t) B_tᵀ, m_t = s_t C_t + D ⊙
+    u_t, a token at a time; the state (rows, C, N) as the equations have
+    it."""
+    def step(s, x):
+        u_t, dt_t, b_t, c_t = x
+        s = jnp.exp(dt_t[:, :, None] * A) * s \
+            + (dt_t * u_t)[:, :, None] * b_t[:, None, :]
+        return s, jnp.sum(s * c_t[:, None, :], axis=-1) + D * u_t
+
+    last, m = jax.lax.scan(step, s0, tuple(
+        jnp.moveaxis(x, 1, 0) for x in (u, dt, B, C)))
+    return jnp.moveaxis(m, 0, 1), last
+
+
+def inputs(L, rate=1.0, rows=2, C=48, N=8, seed=0):
+    """Steps Δ with Δ·|A| planted about `rate` a token."""
+    rng = np.random.default_rng(seed)
+
+    def n(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    A = -jnp.exp(0.3 * n(C, N))
+    dt = rate * jax.nn.softplus(n(rows, L, C) + 1.0)
+    return n(rows, L, C), dt, A, n(rows, L, N), n(rows, L, N), n(C), \
+        n(rows, C, N)
+
+
+def close(got, want, tol=1e-5):
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.max(jnp.abs(got - want))) <= tol * float(
+        jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("L,rate,with_state", [
+    (16, 1.0, False),   # one chunk, from a zero state
+    (64, 0.05, True),   # whole chunks, slow decays, entered from a state
+    (37, 1.0, True),    # no multiple of the chunk: padded rows pass the state
+    (5, 1.0, True),     # shorter than a chunk
+], ids=["one-chunk", "whole-chunks-slow", "ragged", "short"])
+def test_selective_scan_is_the_token_by_token_recurrence(L, rate,
+                                                         with_state):
+    u, dt, A, B, C, D, s0 = inputs(L, rate)
+    s0 = s0 if with_state else jnp.zeros_like(s0)
+    m, last = ssm.selective_scan(u, dt, A, B, C, D,
+                                 jnp.swapaxes(s0, 1, 2) if with_state
+                                 else None)
+    want, want_last = recurrence(u, dt, A, B, C, D, s0)
+    close(m, want)
+    # the state after the LAST TRUE token, kept transposed: (rows, N, C)
+    assert last.shape == (2, 8, 48) and last.dtype == jnp.float32
+    close(jnp.swapaxes(last, 1, 2), want_last)
+
+
+def test_selective_scan_fast_decay_neither_overflows_nor_nans():
+    """Δ·|A| = 16 a token: exp(−16) a step, 1e-7 of a state survives one
+    token and nothing overflows on the way."""
+    u, dt, A, B, C, D, s0 = inputs(40)
+    A = jnp.full_like(A, -16.0)
+    dt = jnp.ones_like(dt)
+    m, last = ssm.selective_scan(u, dt, A, B, C, D, jnp.swapaxes(s0, 1, 2))
+    want, want_last = recurrence(u, dt, A, B, C, D, s0)
+    close(m, want)
+    close(jnp.swapaxes(last, 1, 2), want_last)
+
+
+def test_selective_scan_frame_by_frame_is_one_pass():
+    """A sequence scanned in two halves, the second entered with the
+    first's last state: the once-a-call pass and a step."""
+    u, dt, A, B, C, D, _ = inputs(48)
+    whole, end = ssm.selective_scan(u, dt, A, B, C, D)
+    first, mid = ssm.selective_scan(u[:, :21], dt[:, :21], A, B[:, :21],
+                                    C[:, :21], D)
+    second, last = ssm.selective_scan(u[:, 21:], dt[:, 21:], A, B[:, 21:],
+                                      C[:, 21:], D, mid)
+    close(jnp.concatenate([first, second], axis=1), whole, 1e-6)
+    close(last, end, 1e-6)
+
+
+def test_selective_scan_takes_bfloat16_as_the_same_values_widened():
+    u, dt, A, B, C, D, s0 = inputs(24)
+    u16 = u.astype(jnp.bfloat16)
+    got = ssm.selective_scan(u16, dt, A, B, C, D)[0]
+    close(got, ssm.selective_scan(u16.astype(jnp.float32), dt, A, B, C,
+                                  D)[0], 1e-7)
+
+
+def test_selective_scan_has_no_backward_and_says_so():
+    u, dt, A, B, C, D, _ = inputs(8)
+    with pytest.raises(NotImplementedError, match="selective_scan has no "
+                       "backward"):
+        jax.grad(lambda x: ssm.selective_scan(x, dt, A, B, C, D)[0].sum())(u)
+
+
+def test_selective_scan_on_the_chip_takes_whole_lane_blocks_only(
+        monkeypatch):
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    u, dt, A, B, C, D, _ = inputs(8)
+    with pytest.raises(ValueError, match="whole 128-lane"):
+        ssm.selective_scan(u, dt, A, B, C, D)
+
+
+@pytest.mark.parametrize("with_bias", [False, True], ids=["kda", "mamba"])
+def test_short_conv_with_a_bias_frame_by_frame_is_one_pass(with_bias):
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(2, 20, 12)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 12)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(12,)), jnp.float32) if with_bias \
+        else None
+    whole, tail = kda.short_conv(x, w, None, b)
+    padded = jnp.pad(x, ((0, 0), (3, 0), (0, 0)))
+    want = sum(padded[:, j:j + 20] * w[j] for j in range(4)) \
+        + (b if with_bias else 0.0)
+    np.testing.assert_allclose(whole, want, rtol=1e-6, atol=1e-6)
+    first, mid = kda.short_conv(x[:, :9], w, None, b)
+    second, last = kda.short_conv(x[:, 9:], w, mid, b)
+    np.testing.assert_array_equal(
+        jnp.concatenate([first, second], axis=1), whole)
+    np.testing.assert_array_equal(last, tail)
+    np.testing.assert_array_equal(tail, x[:, -3:])

@@ -34,6 +34,7 @@ from novel_view_synthesis_3d_tpu.ops import (
     grouped_matmul,
     kda,
     serving_attention,
+    ssm,
 )
 
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -159,6 +160,29 @@ def _kda_scan(rows, L, heads, d):
              ((rows, L, heads), F32), ((rows, heads, d, d), F32)])
 
 
+def _diff_attn(Lq, Lk, pairs, kv_pairs, hd, window):
+    """One softmax map of the fourth token trunk's differential attention
+    at the size its cell runs: a frame's 4096 queries of 20 pairs on 10
+    key pairs, keys 64 wide (lane-padded to 128) against a value PAIR of
+    128, over [the window's 511-row tail ; own] — no multiple of a key
+    block — under the 512 window, or over the 8192-key shared cache."""
+    return (lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, scale=hd ** -0.5, window=window),
+        [((2, Lq, pairs, hd), BF16), ((2, Lk, kv_pairs, hd), BF16),
+         ((2, Lk, kv_pairs, 2 * hd), BF16)])
+
+
+def _ssm_scan(rows, L, channels, states):
+    """The fourth token trunk's selective scan: u in the compute type, Δ,
+    B, C in float32, (B, L, ·) as the layer's projections leave them, from
+    a cached state kept (rows, states, channels)."""
+    return (ssm.selective_scan,
+            [((rows, L, channels), BF16), ((rows, L, channels), F32),
+             ((channels, states), F32), ((rows, L, states), F32),
+             ((rows, L, states), F32), ((channels,), F32),
+             ((rows, states, channels), F32)])
+
+
 # base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
 # head dim 256. GroupNorm and epilogue cases are UNet level slabs (H·W, C)
 # that `fits_vmem` admits, the largest included.
@@ -197,6 +221,16 @@ CASES = {
     # length (padded to whole runs of chunks)
     "kda_chunked_4x4096_h32_d128": _kda_scan(4, 4096, 32, 128),
     "kda_chunked_ragged_1x4000_h32_d128": _kda_scan(1, 4000, 32, 128),
+    # the fourth token trunk's scan at the size its cell runs (2 rows of
+    # 4096 tokens, 5120 channels of 16 states), a ragged length (padded to
+    # whole chunks), and one map of its differential attention under the
+    # window and on the shared cache
+    "ssm_scan_2x4096_c5120_n16": _ssm_scan(2, 4096, 5120, 16),
+    "ssm_scan_ragged_1x4000_c5120_n16": _ssm_scan(1, 4000, 5120, 16),
+    "flash_fwd_diff_window512_Lq4096_Lk4607_qk64_v128": _diff_attn(
+        4096, 4607, 20, 10, 64, 512),
+    "flash_fwd_diff_Lq4096_Lk8192_qk64_v128": _diff_attn(
+        4096, 8192, 20, 10, 64, None),
     **{f"serving_attention_L{L}_d{hd}":
        _attn(serving_attention.serving_attention, L, hd, False)
        for L, hd in ((1024, 64), (1024, 256))},
@@ -233,6 +267,7 @@ KERNEL_NAMES = {
     "fused_step": "fused_step_ddpm_B2_128px",
     "gmm": "grouped_matmul_up_4096x2048",
     "kda_fwd": "kda_chunked_ragged_1x4000_h32_d128",
+    "ssm_fwd": "ssm_scan_ragged_1x4000_c5120_n16",
     "moe_combine": "moe_combine_8192x4x4096",
 }
 
@@ -526,6 +561,13 @@ TEMP_LIMITS = {
     "kda_chunked_4x4096_h32_d128": 4e6,
     "kda_chunked_ragged_1x4000_h32_d128": 0.12e9,
     "kda_short_conv_4x4096x12288_k4": 2.5e9,
+    # The selective scan keeps its state in VMEM and takes u, Δ and m where
+    # they lie: at whole chunks its only buffers in HBM are Bᵀ, Cᵀ and Aᵀ
+    # (1.4 MB); a ragged length pays the padded copies of u and Δ and the
+    # slice of m, 0.21 GB a row — where an `associative_scan` would write
+    # (L, 5120, 16) float32, 1.34 GB a row, several times.
+    "ssm_scan_2x4096_c5120_n16": 4e6,
+    "ssm_scan_ragged_1x4000_c5120_n16": 0.25e9,
 }
 
 
