@@ -117,8 +117,9 @@ from novel_view_synthesis_3d_tpu.ops.flash_attention import (
     band_key_columns, flash_attention, resolve_flash, window_binds)
 from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
     ROW_TILE, buffer_rows, grouped_matmul, span_sizes)
-from novel_view_synthesis_3d_tpu.ops.kda import kda_chunked, short_conv
+from novel_view_synthesis_3d_tpu.ops.kda import kda_chunked
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
+from novel_view_synthesis_3d_tpu.ops.short_conv import short_conv
 from novel_view_synthesis_3d_tpu.ops.ssm import selective_scan
 
 LOGSNR_CLEAN = 20.0   # the conditioning frame's logsnr: 3DiM's clean frame
@@ -641,22 +642,6 @@ class SmallThinkerLayer:
         return tuple(sum(c) for c in zip(*per_layer)) if per_layer else (0, 0)
 
 
-def l2_normalise_heads(x, heads: int, eps=1e-6):
-    """x / sqrt(Σ x² + eps) over each of `heads` equal blocks of the last
-    axis, float32, the blocks left side by side. The sums, and their way
-    back to the channels, are products with the blocks' 0/1 indicator at
-    the MXU's full precision (the sum in float32, the broadcast exact):
-    a reduce over (…, heads, d) makes the chip's compiler re-lay x there
-    and back, 1.3 GB a norm at the third trunk's size, where both
-    neighbours want (…, heads·d) (PERF.md §6, PR 35)."""
-    width = x.shape[-1]
-    own = (jnp.arange(width)[:, None] // (width // heads)
-           == jnp.arange(heads)[None]).astype(x.dtype)
-    highest = jax.lax.Precision.HIGHEST
-    scale = jax.lax.rsqrt(jnp.matmul(x * x, own, precision=highest) + eps)
-    return x * jnp.matmul(scale, own.T, precision=highest)
-
-
 class KimiLinearLayer:
     """Kimi-Linear's layers: by index KDA (a gated delta rule behind a
     short convolution; its cache entry the state after the frame's last
@@ -734,8 +719,7 @@ class KimiLinearLayer:
         with jax.named_scope("lk.kda_proj"):
             a = rms_norm(h, layer["attn_norm"]["scale"],
                          k.rms_norm_eps).astype(jnp.dtype(self.config.dtype))
-            qkv = jnp.concatenate([_dense(a, p[n]) for n in ("q", "k", "v")],
-                                  axis=-1)
+            qkv = [_dense(a, p[n]) for n in "qkv"]
             # per head AND per channel, float32 from the projection on
             g = -jnp.repeat(jnp.exp(p["A_log"].astype(f32)), D) \
                 * jax.nn.softplus(
@@ -745,16 +729,20 @@ class KimiLinearLayer:
             gate = jax.nn.sigmoid(
                 _dense(_dense(a, p["g_a"]), p["g_b"]).astype(f32))
         with jax.named_scope("lk.kda_conv"):
-            taps = jnp.concatenate(
-                [p[n + "_conv"]["kernel"] for n in ("q", "k", "v")], axis=-1)
-            y, tail = short_conv(qkv, taps, tail)
-            q, keys, v = jnp.split(jax.nn.silu(y), 3, axis=-1)
-            # Into the scan in the compute type, the heads side by side as
-            # the projections left them: a head is a block of lanes to the
-            # kernel.
-            q = (l2_normalise_heads(q, NH) * D ** -0.5).astype(a.dtype)
-            keys = l2_normalise_heads(keys, NH).astype(a.dtype)
-            v = v.astype(a.dtype)
+            # Each projection through its own taps, SiLU, q's and k's
+            # head-wise L2 norm (q's with the scan's D^-1/2) and into the
+            # scan in the compute type, the heads side by side as the
+            # projections left them: a head is a block of lanes to both
+            # kernels. The cache keeps the three tails side by side.
+            tails = (None,) * 3 if tail is None \
+                else jnp.split(tail, 3, axis=-1)
+            (q, tq), (keys, tk), (v, tv) = (
+                short_conv(x, p[n + "_conv"]["kernel"], t, heads=heads,
+                           scale=scale)
+                for n, x, t, heads, scale in zip(
+                    "qkv", qkv, tails, (NH, NH, None),
+                    (D ** -0.5, 1.0, 1.0)))
+            tail = jnp.concatenate([tq, tk, tv], axis=-1)
         with jax.named_scope("lk.kda_core"):
             o, state = kda_chunked(q, keys, v, g, beta, state)
         with jax.named_scope("lk.kda_proj"):
@@ -920,9 +908,8 @@ class Phi4FlashLayer:
             a = layer_norm(h, layer["norm"], k.layer_norm_eps).astype(dt)
             u, z = jnp.split(_dense(a, p["in"]), 2, axis=-1)
         with jax.named_scope("lk.ssm_conv"):
-            y, tail = short_conv(u, p["conv"]["kernel"], tail,
+            x, tail = short_conv(u, p["conv"]["kernel"], tail,
                                  p["conv"]["bias"])
-            x = jax.nn.silu(y).astype(dt)
         with jax.named_scope("lk.ssm_proj"):
             dbc = _dense(x, p["x"])
             with jax.named_scope("pt.matmul"):   # the step, float32 out

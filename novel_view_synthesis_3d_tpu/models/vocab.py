@@ -40,7 +40,8 @@ GQA_TOKEN_LAYER_KINDS = ("gqa_proj", "attn_window", "attn_full",
                          "update")
 # The token family's third trunk (Kimi-Linear's stack): KDA layers stamp
 # their projections (with the gates, the output norm and `o`), the short
-# convolution (with its SiLU and the L2 norms) and the chunked scan apart;
+# convolution (with its SiLU and the L2 norms: the kernel `short_conv_fwd`,
+# a call a projection) and the chunked scan apart;
 # its latent-attention and expert layers stamp as the first trunk's, its
 # leading dense layer's MLP as `dense_mlp`.
 KDA_TOKEN_LAYER_KINDS = ("kda_proj", "kda_conv", "kda_core", "mla_proj",
@@ -67,8 +68,8 @@ LAYER_KINDS = tuple(dict.fromkeys(
     + KDA_TOKEN_LAYER_KINDS + SSM_TOKEN_LAYER_KINDS))
 # Every part a `jax.named_scope("pt.<part>")` may stamp inside a kind
 # (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py, ops/ssm.py,
-# ops/expert_combine.py, models/token_denoiser.py); this tuple and
-# layer_part_of are the only other place a part is spelled.
+# ops/short_conv.py, ops/expert_combine.py, models/token_denoiser.py); this
+# tuple and layer_part_of are the only other place a part is spelled.
 LAYER_PARTS = ("kernel", "layout", "gather", "matmul")
 
 

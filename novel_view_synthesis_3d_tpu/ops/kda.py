@@ -1,6 +1,8 @@
-"""Kimi Delta Attention's two sequence operators: the causal depthwise
-short convolution with a carried tail, and the gated delta rule with a
-per-channel decay, computed in chunks from an initial state.
+"""Kimi Delta Attention's sequence operator: the gated delta rule with a
+per-channel decay, computed in chunks from an initial state. (The short
+convolution in front of it, with its SiLU and the head-wise L2 norms, is
+ops/short_conv.py's kernel, which hands q, k, v over as this one takes
+them.)
 
 The recurrence, a head at a time (q, k of width d_k, v of width d_v, a
 state S (d_k, d_v) in float32, g_t ≤ 0 the log-decay PER CHANNEL of d_k,
@@ -96,25 +98,6 @@ SUB_BLOCK = 16  # rows a sub-block: the decay's reference row moves this often
 RUN_CHUNKS = 4  # chunks a grid step
 RUN_HEADS = 2   # heads a grid step, where H divides
 _HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def short_conv(x, w, tail=None, bias=None):
-    """Causal depthwise convolution over the sequence: y_t = Σ_j w_j ⊙
-    x_{t−(K−1)+j} (+ `bias` (D,) where one is given: Mamba's; KDA's has
-    none), the last tap on the token itself. x (B, L, D), w (K, D), `tail`
-    (B, K−1, D) the rows before x's first (zeros where None: the sequence
-    starts here). → (y (B, L, D) float32, the last K−1 rows of [tail ; x],
-    which a continuation takes as its `tail`)."""
-    K = w.shape[0]
-    B, L, D = x.shape
-    if tail is None:
-        tail = jnp.zeros((B, K - 1, D), x.dtype)
-    ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    w32 = w.astype(jnp.float32)
-    y = sum(ext[:, j:j + L].astype(jnp.float32) * w32[j] for j in range(K))
-    if bias is not None:
-        y = y + bias.astype(jnp.float32)
-    return y, ext[:, L:]
 
 
 def _mm(a, b, contract=((1,), (0,))):

@@ -142,6 +142,17 @@ PART_PATHS = {
     "wrapper_layout": (
         TOKENS + "/og.layer_1/lk.attn_window/pt.layout/transpose",
         ("layer_1", "attn_window.layout")),
+    # the short convolution in front of a scan: its call, what its wrapper
+    # does around it, and what the layer does between the calls
+    "conv_kernel_call": (
+        TOKENS + "/og.layer_0/lk.kda_conv/jit(_conv_call)/pt.kernel/"
+        "short_conv_fwd/pallas_call", ("layer_0", "kda_conv.kernel")),
+    "conv_wrapper_layout": (
+        TOKENS + "/og.layer_0/lk.kda_conv/pt.layout/pad",
+        ("layer_0", "kda_conv.layout")),
+    "conv_remainder": (
+        TOKENS + "/og.layer_0/lk.kda_conv/concatenate",
+        ("layer_0", "kda_conv")),
     "dispatch_gather": (
         TOKENS + "/og.layer_0/lk.moe_route/pt.gather/gather",
         ("layer_0", "moe_route.gather")),
@@ -332,7 +343,7 @@ TRUNKS = {"ms4_denoiser128": "sample_scan_tokens",
           "st21_denoiser256": "sample_scan_swa",
           "kl48_denoiser256": "sample_scan_kda",
           "p4f_denoiser256": "sample_scan_ssm"}
-KERNELS = ("flash_fwd", "gmm", "kda_fwd", "ssm_fwd")
+KERNELS = ("flash_fwd", "gmm", "kda_fwd", "ssm_fwd", "short_conv_fwd")
 # The parts each compiled sampler must show (it may show more: the
 # wrappers' own `layout` under `moe_experts` and `kda_core`).
 PARTS_SEEN = {
@@ -348,13 +359,15 @@ PARTS_SEEN = {
         "moe_route.gather", "moe_experts.kernel", "moe_experts.gather",
         "patch.matmul", "emb.matmul"},
     "kl48_denoiser256": {
-        "kda_core.kernel", "kda_proj.matmul", "mla_core.kernel",
+        "kda_core.kernel", "kda_conv.kernel", "kda_conv.layout",
+        "kda_proj.matmul", "mla_core.kernel",
         "mla_core.layout", "mla_proj.matmul", "dense_mlp.matmul",
         "moe_route.matmul", "moe_route.gather", "moe_experts.kernel",
         "moe_experts.gather", "moe_shared.matmul", "patch.matmul",
         "emb.matmul"},
     "p4f_denoiser256": {
-        "ssm_core.kernel", "ssm_core.layout", "ssm_proj.matmul",
+        "ssm_core.kernel", "ssm_core.layout", "ssm_conv.kernel",
+        "ssm_conv.layout", "ssm_proj.matmul",
         "attn_window.kernel", "attn_window.layout", "attn_full.kernel",
         "attn_full.layout", "attn_cross.kernel", "attn_cross.layout",
         "gqa_proj.matmul", "gmu.matmul", "dense_mlp.matmul",

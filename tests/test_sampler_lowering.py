@@ -81,12 +81,13 @@ DIGESTS = {
         "63517c08226f48f0a0478fde26dc9d9b22e2bfe776035c1783a9bf535b087e71",
     ("ms4_denoiser128", "v5e"):
         "3c9874938e8fdba4f6ff895d76c5b6423762b73f3a7467c81779b0344a4d8243",
-    # PR 38's own tree: the fourth trunk's sampler as that PR made it (the
-    # three above are the parent's, untouched by its seam in the frame).
+    # PR 39's own tree: the fourth trunk's sampler with its Mamba layers'
+    # short convolution as the kernel `short_conv_fwd` (PR 38's two
+    # replaced, CHANGES.md; the four above untouched).
     ("p4f_denoiser256", "cpu"):
-        "04b8d37bb8944bd71deba61de2ee1c6b8a1c39f79266e57514e1210c68370ce0",
+        "a1e966152de9a583a2316ffd726ee694d86b35d55bbf5d28deaa071320e49ca9",
     ("p4f_denoiser256", "v5e"):
-        "1482754728b851968fe80f38d640c89be3a02bfed89eae8efb8838b659075f15",
+        "d493bcf03616a4886b0dd0b99ba0b3e7b1749c7b12b6a33b9a2f21d5741f55c2",
 }
 
 

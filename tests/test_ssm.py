@@ -1,14 +1,16 @@
 """ops/ssm.py's selective scan (the Pallas kernel `ssm_fwd`, interpreted
-here) against the token-by-token recurrence, and ops/kda.py's short
-convolution with a bias, at small sizes on the CPU; float32 on both sides,
-differing by nothing but the order of the sum over states."""
+here) against the token-by-token recurrence, and ops/short_conv.py's short
+convolution (the Pallas kernel `short_conv_fwd`, interpreted here) against
+the plain jnp form kept below, at small sizes on the CPU; float32 on both
+sides, differing by nothing but the order of a sum."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from novel_view_synthesis_3d_tpu.ops import _pallas, kda, ssm
+from novel_view_synthesis_3d_tpu.ops import _pallas, ssm
+from novel_view_synthesis_3d_tpu.ops.short_conv import short_conv
 
 
 def recurrence(u, dt, A, B, C, D, s0):
@@ -113,6 +115,38 @@ def test_selective_scan_on_the_chip_takes_whole_lane_blocks_only(
         ssm.selective_scan(u, dt, A, B, C, D)
 
 
+# ---------------------------------------------------------------------------
+# ops/short_conv.py
+# ---------------------------------------------------------------------------
+def plain_conv(x, w, tail=None, bias=None, heads=None, scale=1.0, eps=1e-6):
+    """The taps over [tail ; x], the bias, SiLU, each of `heads` blocks of
+    the last axis over sqrt(Σ y² + eps), the scale: jnp, float32, no cast."""
+    f32 = jnp.float32
+    K, L = w.shape[0], x.shape[1]
+    before = jnp.zeros((x.shape[0], K - 1, x.shape[2]), f32) \
+        if tail is None else tail.astype(f32)
+    ext = jnp.concatenate([before, x.astype(f32)], axis=1)
+    y = sum(ext[:, j:j + L] * w[j].astype(f32) for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(f32)
+    y = jax.nn.silu(y)
+    if heads:
+        by_head = y.reshape(y.shape[:-1] + (heads, -1))
+        y = (by_head * jax.lax.rsqrt(jnp.sum(
+            by_head * by_head, axis=-1, keepdims=True) + eps)).reshape(
+                y.shape)
+    return y * scale, ext[:, L:]
+
+
+def conv_inputs(L, rows=2, D=24, K=4, dtype=jnp.float32, seed=3):
+    rng = np.random.default_rng(seed)
+
+    def n(*shape):
+        return jnp.asarray(rng.normal(size=shape), dtype)
+
+    return n(rows, L, D), n(K, D), n(rows, K - 1, D), n(D)
+
+
 @pytest.mark.parametrize("with_bias", [False, True], ids=["kda", "mamba"])
 def test_short_conv_with_a_bias_frame_by_frame_is_one_pass(with_bias):
     rng = np.random.default_rng(3)
@@ -120,14 +154,71 @@ def test_short_conv_with_a_bias_frame_by_frame_is_one_pass(with_bias):
     w = jnp.asarray(rng.normal(size=(4, 12)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(12,)), jnp.float32) if with_bias \
         else None
-    whole, tail = kda.short_conv(x, w, None, b)
-    padded = jnp.pad(x, ((0, 0), (3, 0), (0, 0)))
-    want = sum(padded[:, j:j + 20] * w[j] for j in range(4)) \
-        + (b if with_bias else 0.0)
-    np.testing.assert_allclose(whole, want, rtol=1e-6, atol=1e-6)
-    first, mid = kda.short_conv(x[:, :9], w, None, b)
-    second, last = kda.short_conv(x[:, 9:], w, mid, b)
+    whole, tail = short_conv(x, w, None, b)
+    np.testing.assert_allclose(whole, plain_conv(x, w, None, b)[0],
+                               rtol=1e-6, atol=1e-6)
+    first, mid = short_conv(x[:, :9], w, None, b)
+    second, last = short_conv(x[:, 9:], w, mid, b)
     np.testing.assert_array_equal(
         jnp.concatenate([first, second], axis=1), whole)
     np.testing.assert_array_equal(last, tail)
     np.testing.assert_array_equal(tail, x[:, -3:])
+
+
+@pytest.mark.parametrize("with_tail", [False, True], ids=["start", "tail"])
+@pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("heads,scale", [(None, 1.0), (3, 8 ** -0.5)],
+                         ids=["plain", "normed"])
+@pytest.mark.parametrize("L", [16, 37, 50, 2],
+                         ids=["one-run", "ragged", "runs", "short"])
+def test_short_conv_is_the_plain_form(L, heads, scale, with_bias, with_tail):
+    """One run; a length that is not whole runs; several runs a row, so
+    the carried rows are walked; a frame shorter than K − 1 rows. Float32
+    in, so nothing is cast: within 2e-6 of the largest value."""
+    x, w, tail, bias = conv_inputs(L)
+    tail, bias = tail if with_tail else None, bias if with_bias else None
+    y, new_tail = short_conv(x, w, tail, bias, heads=heads, scale=scale)
+    want, want_tail = plain_conv(x, w, tail, bias, heads, scale)
+    assert y.dtype == x.dtype and y.shape == x.shape
+    close(y, want, 2e-6)
+    np.testing.assert_array_equal(new_tail, want_tail)
+
+
+@pytest.mark.parametrize("K", [2, 4, 9])
+def test_short_conv_takes_two_to_nine_taps(K):
+    x, w, tail, _ = conv_inputs(40, K=K)
+    y, new_tail = short_conv(x, w, tail, heads=2)
+    want, want_tail = plain_conv(x, w, tail, heads=2)
+    close(y, want, 2e-6)
+    np.testing.assert_array_equal(new_tail, want_tail)
+    with pytest.raises(ValueError, match="K − 1 rows"):
+        short_conv(x, jnp.ones((10, 24)), None)
+
+
+def test_short_conv_in_the_compute_type_is_one_cast_of_the_float32_form():
+    x, w, tail, bias = conv_inputs(40, dtype=jnp.bfloat16)
+    y, new_tail = short_conv(x, w, tail, bias, heads=3, scale=0.5)
+    want, _ = plain_conv(x, w, tail, bias, 3, 0.5)
+    assert y.dtype == jnp.bfloat16 and new_tail.dtype == jnp.bfloat16
+    # the float32 values agree to rounding, so the casts differ by at most
+    # one step of bfloat16 where a value sits at a rounding boundary
+    off = jnp.abs(y.astype(jnp.float32) - want)
+    assert float(off.max()) <= 2.0 ** -8 * float(jnp.abs(want).max())
+    assert float((y == want.astype(jnp.bfloat16)).mean()) > 0.99
+    np.testing.assert_array_equal(new_tail, x[:, -3:])
+
+
+def test_short_conv_has_no_backward_and_says_so():
+    x, w, tail, _ = conv_inputs(16)
+    with pytest.raises(NotImplementedError, match="short_conv has no "
+                       "backward"):
+        jax.grad(lambda x: short_conv(x, w, tail, heads=3)[0].sum())(x)
+
+
+@pytest.mark.parametrize("heads", [None, 3], ids=["plain", "normed"])
+def test_short_conv_on_the_chip_takes_whole_lane_blocks_only(monkeypatch,
+                                                             heads):
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    x, w, tail, _ = conv_inputs(16)
+    with pytest.raises(ValueError, match="whole 128-lane"):
+        short_conv(x, w, tail, heads=heads)

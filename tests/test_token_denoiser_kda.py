@@ -45,6 +45,7 @@ from novel_view_synthesis_3d_tpu.models import (  # noqa: E402
 from novel_view_synthesis_3d_tpu.models.vocab import (  # noqa: E402
     KDA_TOKEN_LAYER_KINDS, layer_of)
 from novel_view_synthesis_3d_tpu.ops import kda  # noqa: E402
+from novel_view_synthesis_3d_tpu.ops.short_conv import short_conv  # noqa: E402
 from novel_view_synthesis_3d_tpu.sample.ddpm import make_sampler  # noqa: E402
 
 TOL = 2e-5
@@ -275,17 +276,17 @@ def test_short_conv_tail_frame_by_frame_is_one_pass():
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(2, 10, 8)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(4, 8)), jnp.float32)
-    y, tail = kda.short_conv(x, w)
+    y, tail = short_conv(x, w)
     np.testing.assert_allclose(np.asarray(y), np.asarray(
-        ref.causal_conv(x, w)), atol=1e-6)
-    y1, t1 = kda.short_conv(x[:, :6], w)
-    y2, t2 = kda.short_conv(x[:, 6:], w, t1)
+        jax.nn.silu(ref.causal_conv(x, w))), atol=1e-6)
+    y1, t1 = short_conv(x[:, :6], w)
+    y2, t2 = short_conv(x[:, 6:], w, t1)
     np.testing.assert_array_equal(
         np.asarray(jnp.concatenate([y1, y2], axis=1)), np.asarray(y))
     np.testing.assert_array_equal(np.asarray(t2), np.asarray(tail))
     np.testing.assert_array_equal(np.asarray(tail), np.asarray(x[:, -3:]))
     # a frame shorter than the taps still hands on three rows
-    _, t = kda.short_conv(x[:, :2], w, t1)
+    _, t = short_conv(x[:, :2], w, t1)
     assert t.shape == (2, 3, 8)
 
 

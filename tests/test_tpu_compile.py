@@ -34,6 +34,7 @@ from novel_view_synthesis_3d_tpu.ops import (
     grouped_matmul,
     kda,
     serving_attention,
+    short_conv,
     ssm,
 )
 
@@ -183,6 +184,28 @@ def _ssm_scan(rows, L, channels, states):
              ((rows, states, channels), F32)])
 
 
+def _short_conv(rows, L, width, taps, heads=None, bias=False):
+    """The short convolution in front of a scan, as its layer calls it: a
+    projection a call, (B, L, width) in the compute type with its own taps
+    and the K − 1 rows before it, from a cached tail — with `heads`, KDA's
+    q (head norm and scale), k (head norm) and v side by side as `kda_fwd`
+    takes them; without, Mamba's u with its bias."""
+    calls = [(heads, 0.5), (heads, 1.0), (None, 1.0)] if heads \
+        else [(None, 1.0)]
+    n = len(calls)
+
+    def conv(*args):
+        b = args[3 * n] if bias else None
+        return [short_conv.short_conv(x, w, t, b, heads=h, scale=c)
+                for x, w, t, (h, c) in zip(args[:n], args[n:2 * n],
+                                           args[2 * n:3 * n], calls)]
+
+    return (conv,
+            [((rows, L, width), BF16)] * n + [((taps, width), BF16)] * n
+            + [((rows, taps - 1, width), BF16)] * n
+            + ([((width,), BF16)] if bias else []))
+
+
 # base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
 # head dim 256. GroupNorm and epilogue cases are UNet level slabs (H·W, C)
 # that `fits_vmem` admits, the largest included.
@@ -227,6 +250,16 @@ CASES = {
     # window and on the shared cache
     "ssm_scan_2x4096_c5120_n16": _ssm_scan(2, 4096, 5120, 16),
     "ssm_scan_ragged_1x4000_c5120_n16": _ssm_scan(1, 4000, 5120, 16),
+    # the short convolutions in front of both scans at the sizes their
+    # cells run — KDA's q, k, v (32 heads of 128 each, q and k normalised
+    # by head), Mamba's u with its bias — and a ragged length (padded to
+    # whole tiles of rows)
+    "kda_short_conv_4x4096x12288_k4": _short_conv(4, 4096, 4096, 4,
+                                                  heads=32),
+    "kda_short_conv_ragged_1x4000x12288_k4": _short_conv(1, 4000, 4096, 4,
+                                                         heads=32),
+    "ssm_short_conv_2x4096x5120_k4": _short_conv(2, 4096, 5120, 4,
+                                                 bias=True),
     "flash_fwd_diff_window512_Lq4096_Lk4607_qk64_v128": _diff_attn(
         4096, 4607, 20, 10, 64, 512),
     "flash_fwd_diff_Lq4096_Lk8192_qk64_v128": _diff_attn(
@@ -268,6 +301,7 @@ KERNEL_NAMES = {
     "gmm": "grouped_matmul_up_4096x2048",
     "kda_fwd": "kda_chunked_ragged_1x4000_h32_d128",
     "ssm_fwd": "ssm_scan_ragged_1x4000_c5120_n16",
+    "short_conv_fwd": "ssm_short_conv_2x4096x5120_k4",
     "moe_combine": "moe_combine_8192x4x4096",
 }
 
@@ -538,20 +572,10 @@ def test_resnet_blocks_keep_the_convolutions_layout_on_v5e(skips, v5e):
         in_blocks / h_bytes)
 
 
-# The third token trunk's two sequence operators at the shapes its cell
-# runs (4 rows of 4096 tokens, 32 heads of 128): the scan — the kernel
-# `kda_fwd`, entered from a cached state, the heads side by side in the
-# last axis as the model hands them — among the kernels' CASES above, and
-# the short convolution (XLA). Both must FIT beside 7.8 GB of weights.
-def _kda_conv(rows, L, width, taps):
-    return (kda.short_conv,
-            [((rows, L, width), BF16), ((taps, width), BF16),
-             ((rows, taps - 1, width), BF16)])
-
-
-XLA_CASES = {
-    "kda_short_conv_4x4096x12288_k4": _kda_conv(4, 4096, 3 * 32 * 128, 4),
-}
+# The third and fourth token trunks' sequence operators at the shapes
+# their cells run, among the kernels' CASES above: the scans (`kda_fwd`,
+# `ssm_fwd`), entered from a cached state, and the short convolutions in
+# front of them (`short_conv_fwd`). All must FIT beside 7.8 GB of weights.
 # Temporaries the compiled program may take. The kernel keeps a chunk's
 # working set in VMEM: at whole runs of chunks it needs NO buffer in HBM
 # (2 MB: β re-tiled); a ragged length pays the padded copies of its five
@@ -560,7 +584,14 @@ XLA_CASES = {
 TEMP_LIMITS = {
     "kda_chunked_4x4096_h32_d128": 4e6,
     "kda_chunked_ragged_1x4000_h32_d128": 0.12e9,
-    "kda_short_conv_4x4096x12288_k4": 2.5e9,
+    # The short convolution keeps a run's float32 rows in VMEM and takes x
+    # and y where they lie: its only buffers in HBM are the tails' eight
+    # float32 rows a call (1.6 MB for q, k and v) — where the XLA form
+    # wrote [tail ; x] with the token axis minor and the float32 result,
+    # 2.5 GB —; a ragged length pays the padded x and the slice of y.
+    "kda_short_conv_4x4096x12288_k4": 4e6,
+    "kda_short_conv_ragged_1x4000x12288_k4": 0.12e9,
+    "ssm_short_conv_2x4096x5120_k4": 4e6,
     # The selective scan keeps its state in VMEM and takes u, Δ and m where
     # they lie: at whole chunks its only buffers in HBM are Bᵀ, Cᵀ and Aᵀ
     # (1.4 MB); a ragged length pays the padded copies of u and Δ and the
@@ -574,7 +605,7 @@ TEMP_LIMITS = {
 @pytest.mark.parametrize("name", sorted(TEMP_LIMITS))
 def test_kda_compiles_and_fits_for_v5e(name, v5e, monkeypatch):
     monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
-    fn, arg_specs = (CASES | XLA_CASES)[name]
+    fn, arg_specs = CASES[name]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
             for shape, dtype in arg_specs]
     compiled = jax.jit(fn).lower(*args).compile()
@@ -582,18 +613,13 @@ def test_kda_compiles_and_fits_for_v5e(name, v5e, monkeypatch):
     assert mem.temp_size_in_bytes < TEMP_LIMITS[name], mem.temp_size_in_bytes
 
 
-def test_kl48_layer_hands_the_scan_its_operands_where_they_lie(v5e,
-                                                               monkeypatch):
-    """A KDA layer of `kl48_denoiser256` at the cell's shape (4 rows of
-    4096 tokens from a cached state), compiled for the chip: under
-    `lk.kda_core` there is the kernel and nothing else of q's size — no
-    `copy`, `transpose` or `reshape` re-lays q, k, v, g or o for it (a head
-    is a 128-lane block of the (B, L, H·128) arrays the layer already
-    has), and the kernel writes o in float32 and the states."""
+def _kl48_kda_layer(v5e):
+    """(the compiled text of a KDA layer of `kl48_denoiser256` at the cell's
+    shape — 4 rows of 4096 tokens from a cached state —, q's bytes, rows,
+    width, head width)."""
     from novel_view_synthesis_3d_tpu.config import get_preset
     from novel_view_synthesis_3d_tpu.models import build_denoiser
 
-    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
     cfg = get_preset("kl48_denoiser256")
     model = build_denoiser(cfg.model)
     k, lin = cfg.model.tokens, cfg.model.tokens.linear_attn_config
@@ -612,7 +638,19 @@ def test_kl48_layer_hands_the_scan_its_operands_where_they_lie(v5e,
              S((rows, lin.short_conv_kernel_size - 1, 3 * width), BF16))
     text = jax.jit(lambda p, h, c: model.layer(i, p, h, None, c)[:2]).lower(
         params, S((rows, L, k.hidden_size), BF16), cache).compile().as_text()
-    q_bytes = rows * L * width * 2
+    return text, rows * L * width * 2, rows, width, lin.head_dim
+
+
+def test_kl48_layer_hands_the_scan_its_operands_where_they_lie(v5e,
+                                                               monkeypatch):
+    """A KDA layer of `kl48_denoiser256` at the cell's shape (4 rows of
+    4096 tokens from a cached state), compiled for the chip: under
+    `lk.kda_core` there is the kernel and nothing else of q's size — no
+    `copy`, `transpose` or `reshape` re-lays q, k, v, g or o for it (a head
+    is a 128-lane block of the (B, L, H·128) arrays the layer already
+    has), and the kernel writes o in float32 and the states."""
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    text, q_bytes, rows, width, head_dim = _kl48_kda_layer(v5e)
     core = [(op, name, size) for op, kind, name, size in _entry_writes(text)
             if kind == "kda_core"]
     assert [op for op, _, size in core if size >= q_bytes // 2] == [
@@ -621,4 +659,28 @@ def test_kl48_layer_hands_the_scan_its_operands_where_they_lie(v5e,
                 and c[2] >= q_bytes // 64], core
     (call,) = [c for c in core if c[0] == "custom-call"]
     assert "kda_fwd" in call[1]
-    assert call[2] == 2 * q_bytes + rows * width * lin.head_dim * 4
+    assert call[2] == 2 * q_bytes + rows * width * head_dim * 4
+
+
+def test_kl48_layer_convolves_the_projections_where_they_lie(v5e,
+                                                             monkeypatch):
+    """The same layer's `lk.kda_conv`: the only writes of q's size are the
+    three `short_conv_fwd` calls', q, k and v in bfloat16 — 3 · q's bytes
+    in all —; no `copy`, `transpose` or `reshape` re-lays a projection for
+    them (a call reads a (B, L, H·128) projection as the matmul left it),
+    and no float32 array of (B, L, width) is written anywhere under the
+    stamp: the taps' sum, SiLU and the norms stay in VMEM."""
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    text, q_bytes, _, _, _ = _kl48_kda_layer(v5e)
+    conv = [(op, name, size) for op, kind, name, size in _entry_writes(text)
+            if kind == "kda_conv"]
+    large = [c for c in conv if c[2] >= q_bytes // 2]
+    assert [op for op, _, _ in large] == ["custom-call"] * 3, conv
+    assert all("short_conv_fwd" in name for _, name, _ in large), large
+    assert sum(size for _, _, size in large) == 3 * q_bytes
+    assert not [c for c in conv if c[0] in ("copy", "transpose", "reshape")
+                and c[2] >= q_bytes // 64], conv
+    # nothing else under the stamp is even a sixty-fourth of a float32
+    # (B, L, width): the tails' rows are all there is
+    assert max(size for op, _, size in conv if op != "custom-call") \
+        < 2 * q_bytes // 64, conv
