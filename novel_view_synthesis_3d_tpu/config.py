@@ -296,10 +296,60 @@ class Phi4FlashTrunkConfig:
         return 0.8 - 0.6 * math.exp(-0.3 * i)
 
 
+_HYBRID_PERIOD = ("linear_attention",) * 3 + ("full_attention",)
+
+
+@dataclasses.dataclass(frozen=True)
+class OlmoHybridTrunkConfig:
+    """The token denoiser's fifth trunk: Olmo-Hybrid-7B's decoder stack
+    under the key names of its `olmo_hybrid` `config.json` — layers of two
+    kinds by index (`layer_types`, as published: three "linear_attention"
+    to one "full_attention"): Gated DeltaNet, a gated delta rule with ONE
+    decay a head behind a short causal convolution, keys narrower than
+    values (`linear_key_head_dim` on `linear_value_head_dim`), a write
+    strength β up to 2 (`linear_allow_neg_eigval`), whose cache is a
+    recurrent state; and full attention with as many key/value heads as
+    query heads, q and k RMS-normalised over the whole projection before
+    the head split, and no positional term (`rope_theta` is null in the
+    source and has no key here), whose cache is keys and values. Every
+    sublayer's OUTPUT is normalised inside the residual, h + Norm(f(h)); a
+    dense gated-SiLU MLP in every layer; no bias, no expert layer. The
+    defaults are the published values; a preset sets the depth, of which
+    the first `num_hidden_layers` entries of `layer_types` are run."""
+
+    hidden_size: int = 3840
+    num_hidden_layers: int = 32
+    num_attention_heads: int = 30
+    num_key_value_heads: int = 30
+    intermediate_size: int = 11008
+    hidden_act: str = "silu"
+    attention_bias: bool = False
+    rms_norm_eps: float = 1e-6
+    layer_types: Tuple[str, ...] = _HYBRID_PERIOD * 8
+    linear_num_key_heads: int = 30
+    linear_num_value_heads: int = 30
+    linear_key_head_dim: int = 96
+    linear_value_head_dim: int = 192
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
+    # The patch adapter (this repo's), as every trunk's.
+    patch_size: int = 4
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    def is_full_attention(self, i: int) -> bool:
+        """Layer i (0-based) is full attention; the others are Gated
+        DeltaNet."""
+        return self.layer_types[i] == "full_attention"
+
+
 # The trunks `ModelConfig.tokens` may hold; a serialized config says
 # which by its keys (they share only sizes every trunk has).
 TOKEN_TRUNKS = (TokenTrunkConfig, SmallThinkerTrunkConfig,
-                KimiLinearTrunkConfig, Phi4FlashTrunkConfig)
+                KimiLinearTrunkConfig, Phi4FlashTrunkConfig,
+                OlmoHybridTrunkConfig)
 
 
 def _trunk_of_keys(keys) -> type:
@@ -2088,14 +2138,42 @@ def _phi4flash_errors(k: Phi4FlashTrunkConfig) -> list:
     return errors
 
 
+def _olmo_hybrid_errors(k: OlmoHybridTrunkConfig) -> list:
+    """What the two kinds of layer need of the sizes."""
+    errors = []
+    kinds = set(k.layer_types[:k.num_hidden_layers])
+    if len(k.layer_types) < k.num_hidden_layers \
+            or not kinds <= {"linear_attention", "full_attention"}:
+        errors.append(
+            f"model.tokens.layer_types: {k.num_hidden_layers} layers need "
+            "an entry each, 'linear_attention' or 'full_attention'")
+    if k.hidden_size % k.num_attention_heads:
+        errors.append("model.tokens.hidden_size is not a multiple of "
+                      "num_attention_heads")
+    if k.num_attention_heads % k.num_key_value_heads:
+        errors.append(
+            f"model.tokens.num_attention_heads={k.num_attention_heads} is "
+            f"not a multiple of num_key_value_heads={k.num_key_value_heads}")
+    if k.linear_num_key_heads != k.linear_num_value_heads:
+        errors.append("model.tokens.linear_num_key_heads other than "
+                      "linear_num_value_heads (grouped delta-rule heads) is "
+                      "not carried")
+    if k.hidden_act != "silu" or k.attention_bias:
+        errors.append("model.tokens.hidden_act other than 'silu' and "
+                      "attention_bias=True are not carried")
+    return errors
+
+
 def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
     """What a `family: tokens` model needs of its settings."""
     k = m.tokens
     if k is None:
         return ["model.family='tokens' needs model.tokens (the trunk)"]
     errors = []
-    if isinstance(k, Phi4FlashTrunkConfig):     # the trunk without experts
+    if isinstance(k, Phi4FlashTrunkConfig):     # the trunks without experts
         errors += _phi4flash_errors(k)
+    elif isinstance(k, OlmoHybridTrunkConfig):
+        errors += _olmo_hybrid_errors(k)
     else:
         first, count = k.held_experts
         if not (0 <= first and count >= 1
@@ -2158,7 +2236,7 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
 # ----------------------------------------------------------------------
 PRESET_NAMES = ("reference", "tiny64", "base128", "paper256", "pod64",
                 "ms4_denoiser128", "st21_denoiser256", "kl48_denoiser256",
-                "p4f_denoiser256")
+                "p4f_denoiser256", "oh7_denoiser256")
 
 
 def get_preset(name: str) -> Config:
@@ -2300,6 +2378,22 @@ def get_preset(name: str) -> Config:
             model=ModelConfig(
                 family="tokens", dtype="bfloat16", param_dtype="bfloat16",
                 dropout=0.0, tokens=Phi4FlashTrunkConfig()),
+            data=DataConfig(img_sidelength=256),
+            diffusion=DiffusionConfig(sample_timesteps=256),
+        )
+    if name == "oh7_denoiser256":
+        # A token denoiser whose trunk is Olmo-Hybrid-7B's decoder stack at
+        # its published widths (OlmoHybridTrunkConfig's defaults), cut to
+        # stage 0 of a two-stage pipeline, one chip a stage and no layer
+        # shared between chips: layers 0-15 of 32, four whole periods of
+        # [Gated DeltaNet x 3, full attention], a dense MLP in each.
+        # bfloat16 parameters: 3.33 B = 6.66 GB. 256 px, 4096 tokens a
+        # frame: 64 chunks of 64 a head in every delta-rule layer's scan.
+        return Config(
+            model=ModelConfig(
+                family="tokens", dtype="bfloat16", param_dtype="bfloat16",
+                dropout=0.0,
+                tokens=OlmoHybridTrunkConfig(num_hidden_layers=16)),
             data=DataConfig(img_sidelength=256),
             diffusion=DiffusionConfig(sample_timesteps=256),
         )

@@ -16,14 +16,17 @@
     blocks named by `op_groups(config)` of the family's module.
 
 `model.family` selects: "xunet" (models/xunet.XUNet, the default) or
-"tokens" (models/token_denoiser.TokenDenoiser). The token family has four
+"tokens" (models/token_denoiser.TokenDenoiser). The token family has five
 trunks behind that one class — `model.tokens` is one of
 config.TOKEN_TRUNKS and names the layers: Mistral-Small-4's (latent
 attention, a shared expert; its cache entry a latent), SmallThinker's
 (grouped-query heads, a window and rotary per layer, the router ahead of
 attention; its cache entry keys and values), Kimi-Linear's stack, whose
 layers differ BY INDEX (KDA, a gated delta rule, or latent attention
-without a positional term; a dense MLP or sigmoid-routed experts), or
+without a positional term; a dense MLP or sigmoid-routed experts),
+Olmo-Hybrid's stack (Gated DeltaNet — a delta rule with one decay a head,
+keys narrower than values — or full attention under a QK norm, by index;
+every sublayer's OUTPUT normalised inside the residual; no expert layer), or
 Phi-4-mini-flash's whole stack (Mamba selective-scan layers, differential
 attention under a window and full, then gated memory units and cross
 layers that READ what two earlier layers publish in the same pass — one

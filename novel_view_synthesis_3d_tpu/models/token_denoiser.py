@@ -1,7 +1,7 @@
 """A token denoiser: patch tokens of both frames through a decoder trunk
 of a published language model, ε̂ of the target frame out.
 
-**Four trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
+**Five trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
 and names the layers; the frame asks the layer object for layer i's
 parameter tree and takes back layer i's cache entry — or None, from a layer
 that keeps nothing of a frame — so a trunk's layers may differ by index,
@@ -54,6 +54,19 @@ same pass (one trunk's; the others' calls do not take it):
     weight and bias, biases on the attention projections and the
     convolution, a dense gated-SiLU MLP in every layer, NO expert layer
     (`routing_counts` and `routing_choices` refuse it by name).
+  - `OlmoHybridLayer` (config.OlmoHybridTrunkConfig; Olmo-Hybrid-7B): by
+    index (`layer_types`) Gated DeltaNet — q, k, v through a causal
+    depthwise convolution of 4 taps and SiLU, q and k L2-normalised, ONE
+    decay a head, keys of 96 on values of 192, a write strength β up to
+    2, the gated delta rule in SEQUENCE order (ops/gdn.py, chunked), a
+    head-wise RMSNorm under a SiLU gate; its cache the state after the
+    frame's last token (float32) and the last three pre-convolution rows —
+    or full attention with as many key/value heads as query heads, q and
+    k RMS-normalised over the WHOLE projection before the head split, no
+    positional term; its cache the frame's keys and values. A dense
+    gated-SiLU MLP in every layer, no expert layer. What no other trunk
+    here does: NO norm on a sublayer's input — its OUTPUT is normalised
+    inside the residual, h + Norm(Mixer(h)), h + Norm(MLP(h)).
 
 `route` and `held_expert_part` are one function each for all that route
 (the scoring function, top-k, the renormalisation and the activation come
@@ -76,7 +89,7 @@ repo's and not a source's is the frame around the trunk:
 **The once-a-call pass.** Because of that mask, everything a step needs of
 the conditioning frame is its per-layer cache. `precompute` runs the
 conditioning frame once (prefill) through the layers UP TO THE LAST THAT
-KEEPS A CACHE ENTRY — all of them in three trunks, layers 0–17 of the
+KEEPS A CACHE ENTRY — all of them in four trunks, layers 0–17 of the
 fourth's 32: nothing its cross-decoder computes of that frame is ever
 read, and the pass is built without it, not left to the compiler to cut —
 and every denoise step runs the target's tokens alone against [cache ;
@@ -109,14 +122,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from novel_view_synthesis_3d_tpu.config import (
-    KimiLinearTrunkConfig, ModelConfig, Phi4FlashTrunkConfig,
-    SmallThinkerTrunkConfig, TokenTrunkConfig)
+    KimiLinearTrunkConfig, ModelConfig, OlmoHybridTrunkConfig,
+    Phi4FlashTrunkConfig, SmallThinkerTrunkConfig, TokenTrunkConfig)
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 from novel_view_synthesis_3d_tpu.ops.expert_combine import combine
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
     band_key_columns, flash_attention, resolve_flash, window_binds)
 from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
     ROW_TILE, buffer_rows, grouped_matmul, span_sizes)
+from novel_view_synthesis_3d_tpu.ops.gdn import gated_delta_chunked
 from novel_view_synthesis_3d_tpu.ops.kda import kda_chunked
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
 from novel_view_synthesis_3d_tpu.ops.short_conv import short_conv
@@ -208,7 +222,7 @@ def op_groups(cfg: ModelConfig):
 
 def param_shapes(cfg: ModelConfig) -> dict:
     """The parameter tree as ShapeDtypeStructs. 2-D kernels are (in, out);
-    an expert stack is (held, in, out). Three trunks have no bias at all
+    an expert stack is (held, in, out). Four trunks have no bias at all
     (a router's correction bias apart); Phi4FlashLayer's has LayerNorm
     weights AND biases, and biases on its attention projections, its
     convolution and its step projection."""
@@ -266,10 +280,14 @@ def _init_leaf(key, path, s):
     if name == "bias" or path[0] == "out":
         return jnp.zeros(s.shape, s.dtype)  # ε̂ = 0 at init, as the X-UNet
     if name in ("A_log", "dt_bias"):
-        # KDA's decay as its public implementation starts it: a head's
-        # rate A from U(1, 16), a channel's step from log-U(1e-3, 1e-1)
+        # KDA's and Gated DeltaNet's decay as their public implementations
+        # start it: a head's rate A from U(1, 16) (KDA) or U(0, 16)
+        # (Gated DeltaNet; floored at 1e-3 of it, so that its logarithm
+        # exists), a channel's or head's step from log-U(1e-3, 1e-1)
         # through the inverse of the softplus it goes through.
         u = jax.random.uniform(key, s.shape, jnp.float32)
+        if name == "A_log" and "gdn" in path:
+            return jnp.log(16.0 * jnp.maximum(u, 1e-3)).astype(s.dtype)
         if name == "A_log":
             return jnp.log(1.0 + 15.0 * u).astype(s.dtype)
         dt = jnp.exp(math.log(1e-3) + u * math.log(1e2))
@@ -423,6 +441,23 @@ def held_expert_part(b, top_p, top_i, p_experts, k):
 def gated_mlp(x, p):
     return _dense(jax.nn.silu(_dense(x, p["gate"])) * _dense(x, p["up"]),
                   p["down"])
+
+
+def conv_qkv(p, qkv, tail, heads: int, scale: float):
+    """A delta-rule layer's q, k, v projections each through its own taps
+    (`p["q_conv"]`, …), SiLU, q's and k's head-wise L2 norm (q's with the
+    scan's `scale`) and into the scan in the compute type, the heads side
+    by side as the projections left them: a head is a run of lanes to both
+    kernels, of any width (ops/short_conv.py packs heads into lane
+    blocks). `tail`: the three tails side by side, as the cache keeps them
+    (None: the sequence starts here). → ((q, k, v), the new tail)."""
+    widths = [x.shape[-1] for x in qkv]
+    tails = (None,) * 3 if tail is None else jnp.split(
+        tail, (widths[0], widths[0] + widths[1]), axis=-1)
+    out = [short_conv(x, p[n + "_conv"]["kernel"], t, heads=h, scale=c)
+           for n, x, t, h, c in zip("qkv", qkv, tails, (heads, heads, None),
+                                    (scale, 1.0, 1.0))]
+    return [y for y, _ in out], jnp.concatenate([t for _, t in out], axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -729,20 +764,7 @@ class KimiLinearLayer:
             gate = jax.nn.sigmoid(
                 _dense(_dense(a, p["g_a"]), p["g_b"]).astype(f32))
         with jax.named_scope("lk.kda_conv"):
-            # Each projection through its own taps, SiLU, q's and k's
-            # head-wise L2 norm (q's with the scan's D^-1/2) and into the
-            # scan in the compute type, the heads side by side as the
-            # projections left them: a head is a block of lanes to both
-            # kernels. The cache keeps the three tails side by side.
-            tails = (None,) * 3 if tail is None \
-                else jnp.split(tail, 3, axis=-1)
-            (q, tq), (keys, tk), (v, tv) = (
-                short_conv(x, p[n + "_conv"]["kernel"], t, heads=heads,
-                           scale=scale)
-                for n, x, t, heads, scale in zip(
-                    "qkv", qkv, tails, (NH, NH, None),
-                    (D ** -0.5, 1.0, 1.0)))
-            tail = jnp.concatenate([tq, tk, tv], axis=-1)
+            (q, keys, v), tail = conv_qkv(p, qkv, tail, NH, D ** -0.5)
         with jax.named_scope("lk.kda_core"):
             o, state = kda_chunked(q, keys, v, g, beta, state)
         with jax.named_scope("lk.kda_proj"):
@@ -1018,10 +1040,143 @@ class Phi4FlashLayer:
         return tuple(sum(c) for c in zip(*per_layer)) if per_layer else (0, 0)
 
 
+class OlmoHybridLayer:
+    """Olmo-Hybrid's layers: by index (`layer_types`) Gated DeltaNet (a
+    gated delta rule with one decay a head behind a short convolution; its
+    cache entry the state after the frame's last token and the
+    convolution's tail) or full attention under a QK norm without a
+    positional term (its cache entry the frame's keys and values); a dense
+    MLP in each. No sublayer normalises its input: each normalises its
+    OUTPUT, inside the residual."""
+
+    cache_name = "layer_cache"
+    has_experts, publishes = False, False
+
+    def __init__(self, config: ModelConfig):
+        self.config = config
+
+    def cache_kind(self, i):
+        return "keys_values" if self.config.tokens.is_full_attention(i) \
+            else "recurrent_state"
+
+    def param_shapes(self, w, i):
+        k = self.config.tokens
+        H = k.hidden_size
+        if k.is_full_attention(i):
+            D = k.head_dim
+            NQ, NKV = k.num_attention_heads * D, k.num_key_value_heads * D
+            mix = {"attn": {
+                "q": {"kernel": w(H, NQ)}, "k": {"kernel": w(H, NKV)},
+                "v": {"kernel": w(H, NKV)},
+                "q_norm": {"scale": w(NQ)}, "k_norm": {"scale": w(NKV)},
+                "o": {"kernel": w(NQ, H)}}}
+        else:
+            NH, K = k.linear_num_value_heads, k.linear_conv_kernel_dim
+            wk, wv = NH * k.linear_key_head_dim, NH * k.linear_value_head_dim
+            mix = {"gdn": {
+                **{n: {"kernel": w(H, d)}
+                   for n, d in (("q", wk), ("k", wk), ("v", wv))},
+                **{n + "_conv": {"kernel": w(K, d)}
+                   for n, d in (("q", wk), ("k", wk), ("v", wv))},
+                # the decay's and the write strength's projections, a
+                # number a head each; the output gate's, a value's width
+                "a": {"kernel": w(H, NH)}, "A_log": w(NH), "dt_bias": w(NH),
+                "b": {"kernel": w(H, NH)},
+                "g": {"kernel": w(H, wv)},
+                "o_norm": {"scale": w(k.linear_value_head_dim)},
+                "o": {"kernel": w(wv, H)}}}
+        return {**mix, "mix_norm": {"scale": w(H)},
+                "mlp": _mlp_shapes(w, H, k.intermediate_size),
+                "mlp_norm": {"scale": w(H)}}
+
+    def tables(self, positions):
+        """No layer of this trunk has a positional term."""
+        return None
+
+    def _gdn(self, layer, h, cache):
+        """h + RMSNorm(GatedDeltaNet(h)) over one frame's tokens h (B, L,
+        hidden), from `cache` = (the state, the convolution's tail) of the
+        frames before (None: the sequence starts here). → (h, this frame's
+        (state, tail))."""
+        k, p = self.config.tokens, layer["gdn"]
+        NH, dk, dv = (k.linear_num_value_heads, k.linear_key_head_dim,
+                      k.linear_value_head_dim)
+        B, L, _ = h.shape
+        state, tail = (None, None) if cache is None else cache
+        dt, f32 = jnp.dtype(self.config.dtype), jnp.float32
+        with jax.named_scope("lk.gdn_proj"):
+            a = h.astype(dt)          # the sublayer reads h as it is
+            qkv = [_dense(a, p[n]) for n in "qkv"]
+            # one number a head, float32 from the projection on
+            g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+                _dense(a, p["a"]).astype(f32) + p["dt_bias"].astype(f32))
+            beta = jax.nn.sigmoid(_dense(a, p["b"]).astype(f32))
+            if k.linear_allow_neg_eigval:
+                beta = 2.0 * beta
+            gate = jax.nn.silu(_dense(a, p["g"]).astype(f32))
+        with jax.named_scope("lk.gdn_conv"):
+            # heads of 96 and 192 lanes: the kernel packs them
+            (q, keys, v), tail = conv_qkv(p, qkv, tail, NH, dk ** -0.5)
+        with jax.named_scope("lk.gdn_core"):
+            o, state = gated_delta_chunked(q, keys, v, g, beta, state)
+        with jax.named_scope("lk.gdn_proj"):
+            o = rms_norm(o.reshape(B, L, NH, dv), p["o_norm"]["scale"],
+                         k.rms_norm_eps) * gate.reshape(B, L, NH, dv)
+            mixed = _dense(o.reshape(B, L, NH * dv).astype(dt), p["o"])
+            h = h + rms_norm(mixed, layer["mix_norm"]["scale"],
+                             k.rms_norm_eps).astype(dt)
+        return h, (state, tail)
+
+    def _attn(self, layer, h, cache):
+        """h + RMSNorm(attention(h)) over one frame's tokens; `cache` =
+        the (keys, values) (B, L', kv heads, head_dim) of the frames
+        before, keys normalised."""
+        cfg, k, p = self.config, self.config.tokens, layer["attn"]
+        dt, eps = jnp.dtype(cfg.dtype), k.rms_norm_eps
+        B, L, _ = h.shape
+        NH, NKV, D = k.num_attention_heads, k.num_key_value_heads, k.head_dim
+        with jax.named_scope("lk.gqa_proj"):
+            a = h.astype(dt)
+            # normalised over the whole projection, then split into heads
+            q = rms_norm(_dense(a, p["q"]), p["q_norm"]["scale"],
+                         eps).astype(dt).reshape(B, L, NH, D)
+            keys = rms_norm(_dense(a, p["k"]), p["k_norm"]["scale"],
+                            eps).astype(dt).reshape(B, L, NKV, D)
+            values = _dense(a, p["v"]).reshape(B, L, NKV, D)
+            own = (keys, values)
+            if cache is not None:
+                keys = jnp.concatenate([cache[0].astype(dt), keys], axis=1)
+                values = jnp.concatenate([cache[1].astype(dt), values],
+                                         axis=1)
+        with jax.named_scope("lk.attn_full"):
+            o = _attention(q, keys, values, D ** -0.5,
+                           resolve_flash(cfg.use_flash_attention))
+        with jax.named_scope("lk.gqa_proj"):
+            mixed = _dense(o.reshape(B, L, NH * D), p["o"])
+            h = h + rms_norm(mixed, layer["mix_norm"]["scale"],
+                             eps).astype(dt)
+        return h, own
+
+    def __call__(self, i, p, h, tables, cache):
+        del tables
+        k = self.config.tokens
+        mix = self._attn if k.is_full_attention(i) else self._gdn
+        h, own = mix(p, h, cache)
+        with jax.named_scope("lk.dense_mlp"):
+            h = h + rms_norm(gated_mlp(h, p["mlp"]), p["mlp_norm"]["scale"],
+                             k.rms_norm_eps).astype(h.dtype)
+        return h, own, (None, None)
+
+    def key_columns(self, L: int):
+        """No layer of this trunk has a window."""
+        return 0, 0
+
+
 TRUNK_LAYERS = {TokenTrunkConfig: Mistral4Layer,
                 SmallThinkerTrunkConfig: SmallThinkerLayer,
                 KimiLinearTrunkConfig: KimiLinearLayer,
-                Phi4FlashTrunkConfig: Phi4FlashLayer}
+                Phi4FlashTrunkConfig: Phi4FlashLayer,
+                OlmoHybridTrunkConfig: OlmoHybridLayer}
 
 
 def trunk_layer(cfg: ModelConfig):

@@ -59,16 +59,29 @@ KDA_TOKEN_LAYER_KINDS = ("kda_proj", "kda_conv", "kda_core", "mla_proj",
 SSM_TOKEN_LAYER_KINDS = ("ssm_proj", "ssm_conv", "ssm_core", "gqa_proj",
                          "attn_window", "attn_full", "attn_cross", "gmu",
                          "dense_mlp", "patch", "emb", "pose", "update")
+# The token family's fifth trunk (Olmo-Hybrid's stack): a Gated DeltaNet
+# layer stamps its projections (with the decay, β, the gate, the output
+# norm, `o` and the norm of the sublayer's output) as `gdn_proj`, the short
+# convolution (the kernel `short_conv_fwd`, a call a projection) as
+# `gdn_conv` and the chunked scalar-decay scan as `gdn_core`; a full layer
+# its projections, the QK norm and its output's norm as `gqa_proj` and its
+# attention as `attn_full`; every layer's MLP, with its output's norm, as
+# `dense_mlp`. No expert kind.
+GDN_TOKEN_LAYER_KINDS = ("gdn_proj", "gdn_conv", "gdn_core", "gqa_proj",
+                         "attn_full", "dense_mlp", "patch", "emb", "pose",
+                         "update")
 # Every kind a `jax.named_scope("lk.<kind>")` may stamp. The stamps sit
 # where the work happens (models/layers.py, models/xunet.py,
 # models/token_denoiser.py, sample/ddpm.py); these tuples and layer_of are
 # the only other place a kind is spelled.
 LAYER_KINDS = tuple(dict.fromkeys(
     XUNET_LAYER_KINDS + TOKEN_LAYER_KINDS + GQA_TOKEN_LAYER_KINDS
-    + KDA_TOKEN_LAYER_KINDS + SSM_TOKEN_LAYER_KINDS))
+    + KDA_TOKEN_LAYER_KINDS + SSM_TOKEN_LAYER_KINDS
+    + GDN_TOKEN_LAYER_KINDS))
 # Every part a `jax.named_scope("pt.<part>")` may stamp inside a kind
-# (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py, ops/ssm.py,
-# ops/short_conv.py, ops/expert_combine.py, models/token_denoiser.py); this
+# (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py, ops/gdn.py,
+# ops/ssm.py, ops/short_conv.py, ops/expert_combine.py,
+# models/token_denoiser.py); this
 # tuple and layer_part_of are the only other place a part is spelled.
 LAYER_PARTS = ("kernel", "layout", "gather", "matmul")
 

@@ -72,7 +72,13 @@ split); the operations and bytes counted for its roofline share
 (benchmarks/flops_tokens_kda.py) are of the chunked form above in ONE
 pass over triangles, whatever implements it. Off the TPU the same kernel
 runs through the Pallas interpreter (ops/_pallas.py's contract), at any
-head width; compiled, a head must be whole 128-lane blocks.
+head width; compiled, THIS kernel's head must be whole 128-lane blocks
+(the convolution in front of it no longer asks that: ops/short_conv.py
+packs narrower heads into lane blocks, and ops/gdn.py's kernel slices
+heads of 96 and 192 lanes out of them). What the two delta-rule kernels
+share —
+the float32 product, a block's placement among zeros, the upper levels of
+the triangular inverse — is ops/_delta_rule.py's.
 
 `kda_chunked` and `_kda_call` stamp `pt.kernel` around the `kda_fwd` call
 and nothing else, `pt.layout` around what feeds it and hands its result
@@ -92,29 +98,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from novel_view_synthesis_3d_tpu.ops import _pallas
+from novel_view_synthesis_3d_tpu.ops._delta_rule import (
+    merge_blocks, mm as _mm, placed as _placed)
 
 CHUNK = 64      # tokens a chunk: one step of the scan
 SUB_BLOCK = 16  # rows a sub-block: the decay's reference row moves this often
 RUN_CHUNKS = 4  # chunks a grid step
 RUN_HEADS = 2   # heads a grid step, where H divides
-_HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def _mm(a, b, contract=((1,), (0,))):
-    """A product in the configuration's float32: float32 operands, every
-    pass of the MXU (six of bfloat16 parts), a float32 accumulator.
-    `contract`: the contracted axis of each operand."""
-    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=_HIGHEST,
-                               preferred_element_type=jnp.float32)
-
-
-def _placed(x, at: int, rows: int):
-    """x as rows `at`, … of `rows` rows, zeros around it."""
-    def zeros(n):
-        return [jnp.zeros((n, x.shape[1]), x.dtype)] if n else []
-
-    return jnp.concatenate(
-        zeros(at) + [x] + zeros(rows - at - x.shape[0]), axis=0)
 
 
 def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, sl_ref,
@@ -215,15 +205,8 @@ def _kda_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, sl_ref,
                 a_qk.append(both[:s])
                 a_kk.append(both[s:])
         M = beta[at:at + P] * jnp.concatenate(a_kk, axis=0)
-        # The chunks' T from their sub-blocks': T₂₁ = −T₂₂·M₂₁·T₁₁, a
-        # level of twice the block size at a time.
-        Tg = T[at:at + P]
-        size = s
-        while size < C:
-            pair = same_chunk & (rows // size % 2 == 1) \
-                & (cols // size == rows // size - 1)
-            Tg = Tg - _mm(Tg, _mm(jnp.where(pair, M, 0.0), Tg))
-            size *= 2
+        # The chunks' T from their sub-blocks' (ops/_delta_rule.py).
+        Tg = merge_blocks(T[at:at + P], M, s, C, rows, cols, same_chunk)
         return Tg, jnp.concatenate(a_qk, axis=0) + qk_t[at:at + P].T
 
     def step(i, at, c0, Tg, a_qk):

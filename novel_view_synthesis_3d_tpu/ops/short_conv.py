@@ -19,8 +19,16 @@ hold the rows before the run's first: `tail` at run 0 (the rows before the
 sequence's first, zeros where it starts here), the run's own last eight
 after it — the convolution's carried state is K − 1 rows. The step is
 walked in tiles of TILE_ROWS rows (a loop: traced and compiled once) by a
-head's lanes, so that a tile's taps, SiLU, square, lane reduction, `rsqrt`
-and cast stay in registers. A tile is loaded with the eight rows before it; a
+GROUP of lanes, so that a tile's taps, SiLU, square, lane reduction, `rsqrt`
+and cast stay in registers. A group is a head where a head is whole
+128-lane blocks (KDA's 128), else the fewest whole heads that fill whole
+lane blocks — four heads of 96 lanes or two of 192 are 384: packing heads
+into lane blocks is the kernel's business, and every load, roll and store
+stays lane-aligned; a head's Σ y² is then the reduction of its own lanes
+with the group's other heads masked. Where no multiple of the group
+divides the width (30 heads of 96: 2880 = 7.5 × 384) the last grid step's
+block hangs over the array's edge: what it reads past the edge belongs to
+no head that exists and is never written. A tile is loaded with the eight rows before it; a
 tap is that block rolled down the sublanes by its distance (`pltpu.roll`:
 float32 rows shift by one, a packed bfloat16 tile does not), the first
 eight rows dropped — on the chip faster than loading the scratch at a
@@ -34,8 +42,9 @@ A length that is not whole runs pays a pad and a slice. `short_conv` stamps
 around what feeds it and hands its result back (the tail's eight float32
 rows, the pad and its slice, the new tail) — models/vocab.py, LAYER_PARTS;
 metadata only. Off the TPU the same kernel runs through the Pallas
-interpreter (ops/_pallas.py's contract), at any width; compiled, a head
-(a lane block where no norm is asked for) is whole 128-lane blocks.
+interpreter (ops/_pallas.py's contract), at any width and in the same
+groups; compiled, the width is whole heads, or whole 128-lane blocks where
+no norm is asked for.
 
 Forward only: a gradient through `short_conv` raises by name.
 """
@@ -43,6 +52,7 @@ Forward only: a gradient through `short_conv` raises by name.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +69,8 @@ _INTERPRET_ROWS = 16
 
 
 def _conv_kernel(*refs, taps: int, rows: int, tile: int, head: int,
-                 norm: bool, has_bias: bool, eps: float, scale: float):
+                 group: int, norm: bool, has_bias: bool, eps: float,
+                 scale: float):
     """One (row, lane block, run of `rows` tokens). Blocks: x, y (1, rows,
     W) — the block's lanes of the model's own (B, L, D) arrays —, the taps
     (K, W) and the bias (1, W) float32, `tail` (1, CARRY, W) float32 with
@@ -81,8 +92,8 @@ def _conv_kernel(*refs, taps: int, rows: int, tile: int, head: int,
     def through(i, carry):
         """Tile i of the run's rows, a head's lanes at a time."""
         r0 = pl.multiple_of(i * tile, tile)
-        for c0 in range(0, W, head):
-            lanes = slice(c0, c0 + head)
+        for c0 in range(0, W, group):
+            lanes = slice(c0, min(c0 + group, W))
             w = w_ref[:, lanes]
             # The tile's rows behind the CARRY before them: a tap is the
             # block rolled down by its distance, those first rows dropped.
@@ -96,9 +107,20 @@ def _conv_kernel(*refs, taps: int, rows: int, tile: int, head: int,
             if has_bias:
                 a = a + b_ref[:, lanes]
             y = a * jax.nn.sigmoid(a)
-            if norm:
+            if norm and head == group:
                 y = y * (jax.lax.rsqrt(
                     jnp.sum(y * y, axis=1, keepdims=True) + eps) * scale)
+            elif norm:
+                # Several heads share the group's lane blocks: a head's
+                # Σ y² is the reduction of its own lanes, the others
+                # masked, laid back over them.
+                lane = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+                sq, ss = y * y, jnp.zeros_like(y)
+                for h0 in range(0, y.shape[1], head):
+                    own = (lane >= h0) & (lane < h0 + head)
+                    ss = jnp.where(own, jnp.sum(
+                        jnp.where(own, sq, 0.0), axis=1, keepdims=True), ss)
+                y = y * (jax.lax.rsqrt(ss + eps) * scale)
             elif scale != 1.0:
                 y = y * scale
             o_ref[0, pl.ds(r0, tile), lanes] = y.astype(o_ref.dtype)
@@ -109,21 +131,31 @@ def _conv_kernel(*refs, taps: int, rows: int, tile: int, head: int,
 
 
 def _blocks(L: int, D: int, heads,
-            interpret: bool) -> tuple[int, int, int, int]:
-    """(rows a run, rows a tile, lanes a grid step, lanes a head) from the
-    shapes alone."""
+            interpret: bool) -> tuple[int, int, int, int, int]:
+    """(rows a run, rows a tile, lanes a grid step, lanes a head, lanes a
+    GROUP: what the kernel takes through the arithmetic at once — a head
+    that is whole 128-lane blocks, else the fewest whole heads that fill
+    whole lane blocks: four heads of 96 or two of 192 are 384 lanes) from
+    the shapes alone."""
+    head = D // heads if heads else 128
+    if heads and D % heads:
+        raise ValueError(f"short_conv: {heads} heads do not divide D={D}")
+    group = head * (128 // math.gcd(head, 128))
     if interpret:   # any width, whole; short runs, so the carry is walked
         rows = min(_INTERPRET_ROWS, -(-L // 8) * 8)
-        return rows, rows, D, D // heads if heads else D
-    head = D // heads if heads else 128
-    if head % 128 or D % head:
+        return rows, rows, D, head if heads else D, group if heads else D
+    if not heads and D % 128:
         raise ValueError(
-            f"short_conv_fwd on the chip takes heads that are whole "
-            f"128-lane blocks of (B, L, D); got D={D}, heads={heads}")
+            f"short_conv_fwd on the chip takes whole 128-lane blocks of "
+            f"(B, L, D) where no head is given; got D={D}")
     rows = min(RUN_ROWS, -(-L // TILE_ROWS) * TILE_ROWS)
-    lanes = max(n for n in range(head, max(RUN_LANES, head) + 1, head)
-                if D % n == 0)
-    return rows, TILE_ROWS, lanes, head
+    steps = range(group, max(min(RUN_LANES, -(-D // group) * group), group)
+                  + 1, group)
+    # A step's lanes divide the width where a multiple of the group does;
+    # else the last step's block hangs over the array's edge (its lanes
+    # past the edge are read as they come and never written: whole heads).
+    lanes = max([n for n in steps if D % n == 0] or steps)
+    return rows, TILE_ROWS, lanes, head, group
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "scale", "eps",
@@ -132,7 +164,7 @@ def _conv_call(x, w, bias, tail, *, heads, scale: float, eps: float,
                interpret: bool):
     B, L, D = x.shape
     K = w.shape[0]
-    rows, tile, lanes, head = _blocks(L, D, heads, interpret)
+    rows, tile, lanes, head, group = _blocks(L, D, heads, interpret)
     pad = (-L) % rows
     if pad:   # rows after the last: their y is sliced away
         with jax.named_scope("pt.layout"):
@@ -146,11 +178,12 @@ def _conv_call(x, w, bias, tail, *, heads, scale: float, eps: float,
     with jax.named_scope("pt.kernel"):
         y = pl.pallas_call(
             functools.partial(_conv_kernel, taps=K, rows=rows, tile=tile,
-                              head=head, norm=heads is not None,
+                              head=head, group=group,
+                              norm=heads is not None,
                               has_bias=bias is not None, eps=eps,
                               scale=scale),
             out_shape=jax.ShapeDtypeStruct((B, L + pad, D), x.dtype),
-            grid=(B, D // lanes, (L + pad) // rows),
+            grid=(B, -(-D // lanes), (L + pad) // rows),
             in_specs=[tokens, channels(K)]
             + ([] if bias is None else [channels(1)])
             + [pl.BlockSpec((1, CARRY, lanes), lambda b, c, r: (b, 0, c))],

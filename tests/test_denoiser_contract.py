@@ -83,10 +83,25 @@ TINY_SSM_TOKENS = {
     "model.dtype": "float32", "model.param_dtype": "float32",
     "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
 }
+# The fifth token trunk (Olmo-Hybrid's stack) at toy sizes: Gated DeltaNet
+# x 3 (keys of 8 on values of 16), full attention under the QK norm.
+TINY_GDN_TOKENS = {
+    "model.tokens.hidden_size": 32, "model.tokens.num_hidden_layers": 4,
+    "model.tokens.num_attention_heads": 4,
+    "model.tokens.num_key_value_heads": 4,
+    "model.tokens.intermediate_size": 48,
+    "model.tokens.linear_num_key_heads": 2,
+    "model.tokens.linear_num_value_heads": 2,
+    "model.tokens.linear_key_head_dim": 8,
+    "model.tokens.linear_value_head_dim": 16, "data.img_sidelength": 16,
+    "model.dtype": "float32", "model.param_dtype": "float32",
+    "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
+}
 TINY_BY_PRESET = {"ms4_denoiser128": TINY_TOKENS,
                   "st21_denoiser256": TINY_GQA_TOKENS,
                   "kl48_denoiser256": TINY_KDA_TOKENS,
-                  "p4f_denoiser256": TINY_SSM_TOKENS}
+                  "p4f_denoiser256": TINY_SSM_TOKENS,
+                  "oh7_denoiser256": TINY_GDN_TOKENS}
 
 
 def token_cfg(**over) -> Config:

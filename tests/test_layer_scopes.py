@@ -337,13 +337,15 @@ def test_each_module_call_is_stamped_once(sampler_paths):
 
 
 # The compiled toy sampler of every denoiser the benchmark has a cell of:
-# the X-UNet above, and the token family's four trunks at the sizes their
+# the X-UNet above, and the token family's five trunks at the sizes their
 # cells rehearse at (benchmarks/traffic/<traffic>.json, `rehearse`).
 TRUNKS = {"ms4_denoiser128": "sample_scan_tokens",
           "st21_denoiser256": "sample_scan_swa",
           "kl48_denoiser256": "sample_scan_kda",
-          "p4f_denoiser256": "sample_scan_ssm"}
-KERNELS = ("flash_fwd", "gmm", "kda_fwd", "ssm_fwd", "short_conv_fwd")
+          "p4f_denoiser256": "sample_scan_ssm",
+          "oh7_denoiser256": "sample_scan_gdn"}
+KERNELS = ("flash_fwd", "gmm", "kda_fwd", "ssm_fwd", "short_conv_fwd",
+           "gdn_fwd")
 # The parts each compiled sampler must show (it may show more: the
 # wrappers' own `layout` under `moe_experts` and `kda_core`).
 PARTS_SEEN = {
@@ -372,6 +374,12 @@ PARTS_SEEN = {
         "attn_full.layout", "attn_cross.kernel", "attn_cross.layout",
         "gqa_proj.matmul", "gmu.matmul", "dense_mlp.matmul",
         "patch.matmul", "emb.matmul"},
+    "oh7_denoiser256": {
+        "gdn_core.kernel", "gdn_core.layout", "gdn_conv.kernel",
+        "gdn_conv.layout",
+        "gdn_proj.matmul", "attn_full.kernel", "attn_full.layout",
+        "gqa_proj.matmul", "dense_mlp.matmul", "patch.matmul",
+        "emb.matmul"},
 }
 # What the X-UNet's op loop does between modules stays `other` (the frame
 # stacking, the skip concatenation, a cast: `paper256.sample_scan` reads
@@ -467,10 +475,11 @@ def test_the_combine_kernel_is_stamped_gather(stamped_paths):
     layer's combine, `moe_combine`, is the row gather from expert order to
     token order, so its call lies under `lk.moe_experts/pt.gather` — the
     part that read XLA's gathers goes on reading the combine — and the
-    X-UNet and the trunk without expert layers have none."""
+    X-UNet and the trunks without expert layers have none."""
     which, paths = stamped_paths
     calls = [p for p in paths if re.search(r"/moe_combine(/|$)", p)]
-    assert bool(calls) == (which not in ("x_unet", "p4f_denoiser256"))
+    assert bool(calls) == (which not in (
+        "x_unet", "p4f_denoiser256", "oh7_denoiser256"))
     for p in calls:
         assert re.search(r"/lk\.moe_experts/(jit\(_combine\)/)?pt\.gather/"
                          r"moe_combine(/|$)", p), p

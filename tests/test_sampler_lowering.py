@@ -65,6 +65,31 @@ TOY = {
         "model.tokens.intermediate_size": 96,
         "model.tokens.sliding_window": 6, "model.tokens.mamba_d_state": 8,
         "data.img_sidelength": 16, "model.use_flash_attention": True},
+    # KDA heads of 128 lanes, as its cell's: the width at which its two
+    # kernels take a head as a whole lane block
+    "kl48_denoiser256": {
+        "model.tokens.hidden_size": 64, "model.tokens.num_hidden_layers": 4,
+        "model.tokens.num_attention_heads": 4,
+        "model.tokens.kv_lora_rank": 16, "model.tokens.qk_nope_head_dim": 16,
+        "model.tokens.qk_rope_head_dim": 8, "model.tokens.v_head_dim": 16,
+        "model.tokens.linear_attn_config.num_heads": 2,
+        "model.tokens.linear_attn_config.head_dim": 128,
+        "model.tokens.intermediate_size": 96, "model.tokens.num_experts": 8,
+        "model.tokens.num_experts_per_token": 4,
+        "model.tokens.moe_intermediate_size": 32,
+        "model.tokens.held_experts": [0, 8], "data.img_sidelength": 16,
+        "model.use_flash_attention": True},
+    # delta-rule heads of 32 lanes on 64: four key heads fill a lane block
+    "oh7_denoiser256": {
+        "model.tokens.hidden_size": 64, "model.tokens.num_hidden_layers": 4,
+        "model.tokens.num_attention_heads": 4,
+        "model.tokens.num_key_value_heads": 4,
+        "model.tokens.intermediate_size": 96,
+        "model.tokens.linear_num_key_heads": 4,
+        "model.tokens.linear_num_value_heads": 4,
+        "model.tokens.linear_key_head_dim": 32,
+        "model.tokens.linear_value_head_dim": 64,
+        "data.img_sidelength": 16, "model.use_flash_attention": True},
 }
 # (preset, "cpu" | "v5e") → sha256 of the lowered text, from the parent
 # of the PR that last meant to change it (CHANGES.md, PR 30) — `paper256`'s
@@ -88,6 +113,20 @@ DIGESTS = {
         "a1e966152de9a583a2316ffd726ee694d86b35d55bbf5d28deaa071320e49ca9",
     ("p4f_denoiser256", "v5e"):
         "d493bcf03616a4886b0dd0b99ba0b3e7b1749c7b12b6a33b9a2f21d5741f55c2",
+    # PR 40's PARENT (PR 39's tree), pinned by PR 40, which moved what
+    # `kda_fwd` and `gdn_fwd` share into one module and gave `short_conv`
+    # its head groups: at heads that are whole lane blocks the third
+    # trunk's sampler lowers to the parent's text, on both.
+    ("kl48_denoiser256", "cpu"):
+        "80ad7fb1518157a8ef8804111937ba4400e59ec82e7671671f0ccf2a7124ad64",
+    ("kl48_denoiser256", "v5e"):
+        "c133b30ad66c75314497d2f55a5061cbdbe1d982aed6cd97caf288be12482ef4",
+    # PR 40's own tree: the fifth trunk's sampler as that PR made it (the
+    # eight above are the parent's).
+    ("oh7_denoiser256", "cpu"):
+        "328545c2ea4d64c3277074b67ff93e974658a36db19eaec829fc5641f71a2cf1",
+    ("oh7_denoiser256", "v5e"):
+        "3fef6256c52f8b55141f5909b832719b2369bee294a889763adf03cf01393508",
 }
 
 

@@ -216,9 +216,19 @@ def test_short_conv_has_no_backward_and_says_so():
 
 
 @pytest.mark.parametrize("heads", [None, 3], ids=["plain", "normed"])
-def test_short_conv_on_the_chip_takes_whole_lane_blocks_only(monkeypatch,
-                                                             heads):
+def test_short_conv_on_the_chip_takes_whole_lane_blocks_or_whole_heads(
+        monkeypatch, heads):
+    """Compiled, a width without heads is whole 128-lane blocks; with heads
+    (since PR 40) any width that is whole heads — they are packed into
+    lane blocks inside the kernel (tests/test_gdn.py has the blocks)."""
+    from novel_view_synthesis_3d_tpu.ops.short_conv import _blocks
+
     monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
     x, w, tail, _ = conv_inputs(16)
-    with pytest.raises(ValueError, match="whole 128-lane"):
-        short_conv(x, w, tail, heads=heads)
+    if heads is None:
+        with pytest.raises(ValueError, match="whole 128-lane"):
+            short_conv(x, w, tail, heads=heads)
+    else:   # three heads of 8 lanes in one group of 128
+        assert _blocks(16, 24, heads, False) == (64, 64, 128, 8, 128)
+        with pytest.raises(ValueError, match="do not divide"):
+            short_conv(x[..., :23], w[:, :23], tail[..., :23], heads=heads)

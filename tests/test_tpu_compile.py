@@ -31,6 +31,7 @@ from novel_view_synthesis_3d_tpu.ops import (
     fused_epilogue,
     fused_groupnorm,
     fused_step,
+    gdn,
     grouped_matmul,
     kda,
     serving_attention,
@@ -161,6 +162,18 @@ def _kda_scan(rows, L, heads, d):
              ((rows, L, heads), F32), ((rows, heads, d, d), F32)])
 
 
+def _gdn_scan(rows, L, heads, dk, dv):
+    """The fifth token trunk's chunked scalar-decay scan: q, k, v in the
+    compute type, (B, L, H·d) as the layer's projections leave them — keys
+    of 96 lanes on values of 192, four heads a grid step sliced out of
+    whole lane blocks, the eighth step's blocks over the edge of 30 heads
+    —, g and β (B, L, H), from a cached state."""
+    return (gdn.gated_delta_chunked,
+            [((rows, L, heads * dk), BF16), ((rows, L, heads * dk), BF16),
+             ((rows, L, heads * dv), BF16), ((rows, L, heads), F32),
+             ((rows, L, heads), F32), ((rows, heads, dk, dv), F32)])
+
+
 def _diff_attn(Lq, Lk, pairs, kv_pairs, hd, window):
     """One softmax map of the fourth token trunk's differential attention
     at the size its cell runs: a frame's 4096 queries of 20 pairs on 10
@@ -204,6 +217,22 @@ def _short_conv(rows, L, width, taps, heads=None, bias=False):
             [((rows, L, width), BF16)] * n + [((taps, width), BF16)] * n
             + [((rows, taps - 1, width), BF16)] * n
             + ([((width,), BF16)] if bias else []))
+
+
+def _short_conv_widths(rows, L, taps, calls):
+    """The short convolutions of a layer whose projections differ in width:
+    `calls` = (width, heads or None, scale) each, a `short_conv_fwd` call a
+    projection, from a cached tail."""
+    def conv(*args):
+        n = len(calls)
+        return [short_conv.short_conv(x, w, t, heads=h, scale=c)
+                for x, w, t, (_, h, c) in zip(args[:n], args[n:2 * n],
+                                              args[2 * n:], calls)]
+
+    return (conv,
+            [((rows, L, d), BF16) for d, _, _ in calls]
+            + [((taps, d), BF16) for d, _, _ in calls]
+            + [((rows, taps - 1, d), BF16) for d, _, _ in calls])
 
 
 # base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
@@ -260,6 +289,21 @@ CASES = {
                                                          heads=32),
     "ssm_short_conv_2x4096x5120_k4": _short_conv(2, 4096, 5120, 4,
                                                  bias=True),
+    # the fifth token trunk: the short convolutions in front of its delta
+    # rule at the size its cell runs — q and k 30 heads of 96 lanes (2880 =
+    # 7.5 groups of four heads: the last grid step's block hangs over the
+    # edge), v 30 of 192 without a norm — and its full attention, 30 query
+    # heads each on its own key/value head
+    "gdn_chunked_2x4096_h30_k96_v192": _gdn_scan(2, 4096, 30, 96, 192),
+    "gdn_chunked_ragged_1x4000_h30_k96_v192": _gdn_scan(1, 4000, 30, 96,
+                                                        192),
+    "gdn_short_conv_2x4096x11520_k4": _short_conv_widths(
+        2, 4096, 4, [(2880, 30, 96 ** -0.5), (2880, 30, 1.0),
+                     (5760, None, 1.0)]),
+    "gdn_short_conv_heads192_1x4000x5760_k4": _short_conv_widths(
+        1, 4000, 4, [(5760, 30, 1.0)]),
+    "flash_fwd_Lq4096_Lk8192_h30_d128": _gqa_attn(4096, 8192, 30, 30, 128,
+                                                  None),
     "flash_fwd_diff_window512_Lq4096_Lk4607_qk64_v128": _diff_attn(
         4096, 4607, 20, 10, 64, 512),
     "flash_fwd_diff_Lq4096_Lk8192_qk64_v128": _diff_attn(
@@ -300,6 +344,7 @@ KERNEL_NAMES = {
     "fused_step": "fused_step_ddpm_B2_128px",
     "gmm": "grouped_matmul_up_4096x2048",
     "kda_fwd": "kda_chunked_ragged_1x4000_h32_d128",
+    "gdn_fwd": "gdn_chunked_ragged_1x4000_h30_k96_v192",
     "ssm_fwd": "ssm_scan_ragged_1x4000_c5120_n16",
     "short_conv_fwd": "ssm_short_conv_2x4096x5120_k4",
     "moe_combine": "moe_combine_8192x4x4096",
@@ -599,6 +644,17 @@ TEMP_LIMITS = {
     # (L, 5120, 16) float32, 1.34 GB a row, several times.
     "ssm_scan_2x4096_c5120_n16": 4e6,
     "ssm_scan_ragged_1x4000_c5120_n16": 0.25e9,
+    # heads of 96 and 192 lanes are packed into lane blocks INSIDE the
+    # kernel: nothing is padded or re-laid in HBM for them (the tails'
+    # eight float32 rows again); a ragged length pays the padded x and the
+    # slice of y
+    # The scalar-decay scan keeps a run's working set in VMEM too: at whole
+    # runs its only buffers in HBM are γ's running sum and the casts (a
+    # few MB); a ragged length pays the padded operands and the slice of o.
+    "gdn_chunked_2x4096_h30_k96_v192": 8e6,
+    "gdn_chunked_ragged_1x4000_h30_k96_v192": 0.12e9,
+    "gdn_short_conv_2x4096x11520_k4": 4e6,
+    "gdn_short_conv_heads192_1x4000x5760_k4": 0.1e9,
 }
 
 
@@ -684,3 +740,4 @@ def test_kl48_layer_convolves_the_projections_where_they_lie(v5e,
     # (B, L, width): the tails' rows are all there is
     assert max(size for op, _, size in conv if op != "custom-call") \
         < 2 * q_bytes // 64, conv
+
