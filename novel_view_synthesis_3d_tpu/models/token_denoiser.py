@@ -95,7 +95,10 @@ read, and the pass is built without it, not left to the compiler to cut —
 and every denoise step runs the target's tokens alone against [cache ;
 own], or from the cached state (decode through the cache). `apply`
 without a cache does exactly the two in a row, so there is one set of
-equations.
+equations. What a trunk makes of its PARAMETERS alone (`derive`: the two
+latent trunks' kernels that write q, keys and values where the attention
+kernel reads them) is made in the same pass and handed on beside the
+cache, under `derived`; `apply` without that entry makes it itself.
 
 **The expert layer is told which experts it holds** (`held_experts`, a
 (first, count) range: this chip's share of an expert-parallel deployment).
@@ -307,6 +310,42 @@ def rms_norm(x, scale, eps):
         * scale.astype(jnp.float32)
 
 
+def bfloat16_terms(a):
+    """The three bfloat16 arrays that hold a float32's 24 bits: a = t₀ +
+    t₁ + t₂ exactly, each the rounding of what the ones before it left.
+    `reduce_precision`, not a cast and back: a round trip through bfloat16
+    is one XLA may drop (xla_allow_excess_precision)."""
+    terms = []
+    for _ in range(3):
+        t = jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+        terms.append(t.astype(jnp.bfloat16))
+        a = a - t
+    return terms
+
+
+def rms_norm_lane_groups(x, scale, width: int, eps):
+    """RMSNorm of x (…, groups·width) float32 over each run of `width`
+    lanes, times `scale` (width,) — `rms_norm` of x seen as (…, groups,
+    width), without that view: a group's mean of squares, and its way back
+    to the group's lanes, are products with the groups' 0/1 indicator, so
+    everything stays where x lies. In float32: a value goes through the
+    MXU as its `bfloat16_terms`, every product with 0 or 1 is exact and
+    the sums are float32's — the mean differs from `jnp.mean`'s by the
+    order of its sum alone, and the way back (one product, the terms side
+    by side against the indicator three times over) returns the statistic
+    to float32's last bit."""
+    groups = x.shape[-1] // width
+    member = np.repeat(np.eye(groups), width, axis=0)  # (groups·width, groups)
+    mean = sum(jnp.dot(t, jnp.asarray(member, jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+               for t in bfloat16_terms(x * x)) / width
+    inv = jnp.dot(jnp.concatenate(bfloat16_terms(jax.lax.rsqrt(mean + eps)),
+                                  axis=-1),
+                  jnp.asarray(np.tile(member.T, (3, 1)), jnp.bfloat16),
+                  preferred_element_type=jnp.float32)
+    return x * inv * jnp.tile(scale.astype(jnp.float32), groups)
+
+
 def layer_norm(x, p, eps):
     """LayerNorm with weight and bias; float32 in, float32 out."""
     x = x.astype(jnp.float32)
@@ -321,6 +360,78 @@ def _dense(x, p):
     rotary and slices."""
     with jax.named_scope("pt.matmul"):
         return jnp.dot(x, p["kernel"].astype(x.dtype))
+
+
+def pair_swapped_kernel(q_b, heads: int, nope: int, interleave: bool):
+    """q_b's kernel (rank, heads·(nope + rope)) with every rotary column
+    swapped with its pair's — the lane `apply_rope` rotates it with — and
+    zero under the lanes that do not rotate: x against it puts each lane's
+    pair under it (`rotated_queries`)."""
+    kernel = q_b["kernel"].reshape(q_b["kernel"].shape[0], heads, -1)
+    lane = np.arange(kernel.shape[-1] - nope)
+    pair = lane ^ 1 if interleave else (lane + lane.size // 2) % lane.size
+    swapped = jnp.pad(jnp.take(kernel, nope + pair, axis=-1),
+                      ((0, 0), (0, 0), (nope, 0)))
+    return {"kernel": swapped.reshape(q_b["kernel"].shape)}
+
+
+def rotated_queries(x, q_b, q_b_pair, heads: int, cos, sin,
+                    interleave: bool):
+    """x · q_b (B, L, heads·(nope + rope)), the last `rope` lanes of every
+    head rotated by the tables (L, rope/2) as `apply_rope` rotates them and
+    the first `nope` as they are — written where the product writes it,
+    the heads side by side, no head sliced apart (XLA rotates a slice
+    token-minor, through 5-D arrays, and re-lays q for the attention
+    kernel: PERF.md §6, PR 41). A second product, against `q_b_pair`
+    (`pair_swapped_kernel` of q_b), puts each lane's pair under it; the
+    rotation is then x·c + x'·s a lane, c = [1…, cos…], s = [0…, ∓sin…]:
+    `apply_rope`'s float32 products and sum."""
+    L, half = cos.shape
+    nope = q_b["kernel"].shape[-1] // heads - 2 * half
+    if interleave:
+        c = np.repeat(cos, 2, axis=1)
+        s = np.stack([-sin, sin], axis=-1).reshape(L, -1)
+    else:
+        c = np.concatenate([cos, cos], axis=1)
+        s = np.concatenate([-sin, sin], axis=1)
+    c = np.concatenate([np.ones((L, nope), np.float32), c], axis=1)
+    s = np.concatenate([np.zeros((L, nope), np.float32), s], axis=1)
+    return (_dense(x, q_b).astype(jnp.float32) * jnp.tile(c, (1, heads))
+            + _dense(x, q_b_pair).astype(jnp.float32)
+            * jnp.tile(s, (1, heads))).astype(x.dtype)
+
+
+def latent_kernels(kv_b, heads: int, dn: int, dr: int):
+    """`kv_b` (rank, heads·(dn + dv), a head's dn key columns before its
+    dv value columns) as the two kernels `latent_keys_values` multiplies:
+    `k_b` (rank + dr, heads·(dn + dr)), the keys' columns over an identity
+    block that carries the dr shared lanes under every head's last dr; and
+    `v_b` (rank, heads·dv), the values' columns."""
+    w = kv_b["kernel"].reshape(kv_b["kernel"].shape[0], heads, -1)
+    carry = jnp.pad(jnp.eye(dr, dtype=w.dtype), ((0, 0), (dn, 0)))
+    k_b = jnp.concatenate(
+        [jnp.pad(w[..., :dn], ((0, 0), (0, 0), (0, dr))),
+         jnp.broadcast_to(carry[:, None], (dr, heads, dn + dr))], axis=0)
+    return {"k_b": {"kernel": k_b.reshape(-1, heads * (dn + dr))},
+            "v_b": {"kernel": w[..., dn:].reshape(w.shape[0], -1)}}
+
+
+def latent_keys_values(c_kv, k_shared, k_b, v_b, heads: int):
+    """Keys (B, Lk, heads, dn + dr) and values (B, Lk, heads, dv)
+    up-projected from the latent c_kv (B, Lk, rank), every head's key
+    ending in the part all heads share, k_shared (B, Lk, dr). Each is a
+    product of its own — the values' against `v_b`, the keys' against
+    `k_b` (`latent_kernels` of the layer's `kv_b`) — so that each leaves
+    its product as (B, Lk, heads·width), where the attention kernel reads
+    it, and no head is sliced apart or put together lane by lane
+    afterwards (XLA does that token-minor and re-lays both arrays for the
+    kernel: PERF.md §6, PR 41). The values of slicing `kv_b`'s whole
+    product and concatenating: a column's sum has the same terms, and the
+    identity's products are exact."""
+    B, Lk = c_kv.shape[:2]
+    keys = _dense(jnp.concatenate([c_kv, k_shared], axis=-1), k_b)
+    values = _dense(c_kv, v_b)
+    return keys.reshape(B, Lk, heads, -1), values.reshape(B, Lk, heads, -1)
 
 
 def _attention(q, k, v, scale, use_flash, window=None):
@@ -477,7 +588,10 @@ def conv_qkv(p, qkv, tail, heads: int, scale: float):
 # LATER layers of the same pass — such a trunk's `__call__` takes one more
 # argument, a dict the frame makes anew for every pass, which a layer
 # writes and later layers read; its `cache_kind(i)` is None, and its cache
-# entry None, for a layer that keeps nothing of a frame.
+# entry None, for a layer that keeps nothing of a frame. A trunk may also
+# give `derive(i, p)`: arrays made of layer i's parameters `p` alone, as a
+# tree that is merged over `p` before `__call__` sees it — made once a
+# sampling call (`TokenDenoiser.precompute`), not once a step.
 # ---------------------------------------------------------------------------
 class Mistral4Layer:
     """Mistral-Small-4's layer (latent attention, a shared expert)."""
@@ -516,6 +630,15 @@ class Mistral4Layer:
     def tables(self, positions):
         return rope_tables(positions, self.config.tokens)
 
+    def derive(self, i, p):
+        """The kernels that write q, the keys and the values where the
+        attention kernel reads them."""
+        k = self.config.tokens
+        NH, dn = k.num_attention_heads, k.qk_nope_head_dim
+        return {"q_b_pair": pair_swapped_kernel(p["q_b"], NH, dn,
+                                                k.rope_interleave),
+                **latent_kernels(p["kv_b"], NH, dn, k.qk_rope_head_dim)}
+
     def __call__(self, i, p, h, tables, cache):
         """`cache` is the (c_kv, k_rope) of the frames before this one."""
         del i  # every layer is the same
@@ -530,13 +653,11 @@ class Mistral4Layer:
             a = rms_norm(h, p["attn_norm"]["scale"], eps).astype(dt)
             c_q = rms_norm(_dense(a, p["q_a"]), p["q_norm"]["scale"],
                            eps).astype(dt)
-            q = _dense(c_q, p["q_b"]).reshape(B, L, NH, dn + dr)
-            q = jnp.concatenate(
-                [q[..., :dn],
-                 apply_rope(q[..., dn:], cos, sin, k.rope_interleave)],
-                axis=-1)
+            q = rotated_queries(c_q, p["q_b"], p["q_b_pair"], NH, cos, sin,
+                                k.rope_interleave)
             if np.any(qscale != 1.0):
-                q = q * jnp.asarray(qscale, dt)[None, :, None, None]
+                q = q * jnp.asarray(qscale, dt)[None, :, None]
+            q = q.reshape(B, L, NH, dn + dr)
             kv_a = _dense(a, p["kv_a"])
             c_kv = rms_norm(kv_a[..., :k.kv_lora_rank],
                             p["kv_norm"]["scale"], eps).astype(dt)
@@ -549,13 +670,8 @@ class Mistral4Layer:
                                          axis=1)
             # Keys and values up-projected from the latent (the form a
             # chip run chose over absorbed weights; PERF.md, PR 26).
-            Lk = c_kv.shape[1]
-            kv = _dense(c_kv, p["kv_b"]).reshape(B, Lk, NH, dn + dv)
-            keys = jnp.concatenate(
-                [kv[..., :dn],
-                 jnp.broadcast_to(k_rope[:, :, None, :], (B, Lk, NH, dr))],
-                axis=-1)
-            values = kv[..., dn:]
+            keys, values = latent_keys_values(c_kv, k_rope, p["k_b"],
+                                              p["v_b"], NH)
         with jax.named_scope("lk.mla_core"):
             o = _attention(q, keys, values, softmax_scale(k),
                            resolve_flash(cfg.use_flash_attention))
@@ -773,6 +889,15 @@ class KimiLinearLayer:
             h = h + _dense(o.reshape(B, L, NH * D).astype(a.dtype), p["o"])
         return h, (state, tail)
 
+    def derive(self, i, p):
+        """A latent layer's two kernels for the keys and the values."""
+        k = self.config.tokens
+        if not k.is_full_attention(i):
+            return {}
+        return {"mla": latent_kernels(
+            p["mla"]["kv_b"], k.num_attention_heads, k.qk_nope_head_dim,
+            k.qk_rope_head_dim)}
+
     def _mla(self, layer, h, cache):
         """h + latent attention, without a positional term, of RMSNorm(h)
         over one frame's tokens; `cache` = (c_kv, the shared key part) of
@@ -795,13 +920,8 @@ class KimiLinearLayer:
                 k_pe = jnp.concatenate([cache[1].astype(dt), k_pe], axis=1)
             # Keys and values up-projected from the latent at use, as
             # Mistral4Layer does (PERF.md, PR 26).
-            Lk = c_kv.shape[1]
-            kv = _dense(c_kv, p["kv_b"]).reshape(B, Lk, NH, dn + dv)
-            keys = jnp.concatenate(
-                [kv[..., :dn],
-                 jnp.broadcast_to(k_pe[:, :, None, :], (B, Lk, NH, dr))],
-                axis=-1)
-            values = kv[..., dn:]
+            keys, values = latent_keys_values(c_kv, k_pe, p["k_b"],
+                                              p["v_b"], NH)
         with jax.named_scope("lk.mla_core"):
             o = _attention(q, keys, values, (dn + dr) ** -0.5,
                            resolve_flash(cfg.use_flash_attention))
@@ -983,9 +1103,16 @@ class Phi4FlashLayer:
                     published["kv"] = (keys, values)
             Lk = keys.shape[1]
             # Adjacent heads pair: a pair's queries and keys are maps 1
-            # and 2, its two value heads side by side one value.
-            q = q.reshape(B, L, NH // 2, 2, D)
-            kp = keys.reshape(B, Lk, NKV // 2, 2, D)
+            # and 2, its two value heads side by side one value. A pair
+            # stays whole, 2·D lanes: map s reads the pair's queries with
+            # the other map's lanes zeroed against the pair's keys as
+            # they lie — the other map's products are exact zeros —, so
+            # no map is sliced out of a pair and padded back to whole
+            # lanes for the kernel.
+            lane_map = np.tile(np.arange(2 * D) // D, NH // 2)
+            maps = [(q * jnp.asarray(lane_map == s, dt)).reshape(
+                B, L, NH // 2, 2 * D) for s in (0, 1)]
+            kp = keys.reshape(B, Lk, NKV // 2, 2 * D)
             vp = values.reshape(B, Lk, NKV // 2, 2 * D)
             lam0 = k.lambda_init(i)
             lam = jnp.exp(jnp.sum(p["lambda_q1"].astype(f32)
@@ -996,14 +1123,17 @@ class Phi4FlashLayer:
         with jax.named_scope("lk.attn_cross" if kind == "attn_cross" else
                              "lk.attn_window" if binds else "lk.attn_full"):
             flash = resolve_flash(cfg.use_flash_attention)
-            o1, o2 = (_attention(q[:, :, :, s], kp[:, :, :, s], vp, D ** -0.5,
-                                 flash, window) for s in (0, 1))
+            o1, o2 = (_attention(m, kp, vp, D ** -0.5, flash, window)
+                      for m in maps)
         with jax.named_scope("lk.gqa_proj"):
-            o = rms_norm(o1.astype(f32) - lam * o2.astype(f32),
-                         p["sub_norm"]["scale"], k.layer_norm_eps) \
-                * (1.0 - lam0)
-            h = h + _dense(o.reshape(B, L, NH * D).astype(dt), p["o"]) \
-                + p["o"]["bias"].astype(dt)
+            # A¹V − λA²V and its pair-wise RMSNorm on the heads side by
+            # side, as the kernel wrote them: on (B, L, pairs, 2·D) XLA
+            # re-lays both float32 arrays for the norm (PERF.md §6, PR 41).
+            d = o1.reshape(B, L, NH * D).astype(f32) \
+                - lam * o2.reshape(B, L, NH * D).astype(f32)
+            o = rms_norm_lane_groups(d, p["sub_norm"]["scale"], 2 * D,
+                                     k.layer_norm_eps) * (1.0 - lam0)
+            h = h + _dense(o.astype(dt), p["o"]) + p["o"]["bias"].astype(dt)
         return h, own
 
     def _gmu(self, i, layer, h, cache, published):
@@ -1179,6 +1309,12 @@ TRUNK_LAYERS = {TokenTrunkConfig: Mistral4Layer,
                 OlmoHybridTrunkConfig: OlmoHybridLayer}
 
 
+def laid_over(p: dict, d: dict) -> dict:
+    """The tree `p` with the tree `d` laid over it, dict by dict."""
+    return {**p, **{n: laid_over(p[n], v) if isinstance(p.get(n), dict)
+                    else v for n, v in d.items()}}
+
+
 def trunk_layer(cfg: ModelConfig):
     """The layer object of `cfg.tokens`' trunk."""
     return TRUNK_LAYERS[type(cfg.tokens)](cfg)
@@ -1266,10 +1402,21 @@ class TokenDenoiser:
         with jax.named_scope("lk.emb"):
             return tok + emb[:, None, :]
 
+    def _derived(self, params):
+        """{layer label: what the trunk `derive`s from that layer's
+        parameters}, a tree to lay over `params`; {} for a trunk that
+        derives nothing."""
+        derive = getattr(self.layer, "derive", None)
+        if derive is None:
+            return {}
+        return {layer_label(i): derive(i, params[layer_label(i)])
+                for i in range(self.config.tokens.num_hidden_layers)}
+
     def _frame(self, params, tok, frame_index, caches, layers=None):
         """One frame's tokens through the first `layers` layers (all where
-        None). → (h, per-layer cache entries of this frame — None for a
-        layer that keeps nothing or did not run —, (tokens per held expert
+        None), `params` with `_derived`'s tree laid over them. → (h,
+        per-layer cache entries of this frame — None for a layer that
+        keeps nothing or did not run —, (tokens per held expert
         of each layer that has experts (expert layers, held), None for a
         trunk without experts; those layers' chosen experts, a tuple of
         (B, L, k)))."""
@@ -1294,7 +1441,8 @@ class TokenDenoiser:
                                 tuple(choices))
 
     def _cond_frame(self, params, cond, cond_mask):
-        """The conditioning frame through the trunk: its per-layer cache."""
+        """The conditioning frame through the trunk: its per-layer cache.
+        `params` with `_derived`'s tree laid over them."""
         x, R1, t1 = cond["x"], cond["R1"], cond["t1"]
         if x.ndim == 5:       # (B, 1, H, W, 3): one conditioning frame
             x, R1, t1 = x[:, 0], R1[:, 0], t1[:, 0]
@@ -1317,16 +1465,23 @@ class TokenDenoiser:
         conditioning frame's per-layer cache (each layer's own: a latent,
         keys and values or their window's tail, or a recurrent state with
         its convolution's tail; None for a layer that keeps nothing), as
-        one batch entry."""
+        one batch entry; and, where the trunk `derive`s arrays from its
+        parameters, those under `derived`."""
         B = cond["x"].shape[0]
         doubled = jax.tree.map(lambda a: jnp.concatenate([a, a], axis=0),
                                dict(cond))
         mask = jnp.concatenate([jnp.ones((B,)), jnp.zeros((B,))])
         with jax.named_scope("precompute"):
-            cache, _ = self._cond_frame(params, doubled, mask)
-        return {self.layer.cache_name: cache}
+            derived = self._derived(params)
+            cache, _ = self._cond_frame(laid_over(params, derived), doubled,
+                                        mask)
+        return {self.layer.cache_name: cache,
+                **({"derived": derived} if derived else {})}
 
     def _forward(self, params, batch, cond_mask):
+        derived = batch.get("derived")
+        params = laid_over(params, self._derived(params) if derived is None
+                           else derived)
         cache = batch.get(self.layer.cache_name)
         if cache is None:
             cache, _ = self._cond_frame(params, batch, cond_mask)
@@ -1377,8 +1532,10 @@ class TokenDenoiser:
         is sent to, per layer that has experts, in the passes `apply`
         makes: (expert layers, B, 2L, k) int32, held or not."""
         self._needs_experts("routing_choices")
-        cache, (_, cond) = self._cond_frame(params, batch, cond_mask)
-        own = self._forward(params, dict(batch, **{
+        derived = self._derived(params)
+        cache, (_, cond) = self._cond_frame(laid_over(params, derived),
+                                            batch, cond_mask)
+        own = self._forward(params, dict(batch, derived=derived, **{
             self.layer.cache_name: cache}), cond_mask)[1][1]
         return jnp.stack([jnp.concatenate(pair, axis=1)
                           for pair in zip(cond, own)])
@@ -1402,7 +1559,9 @@ class TokenDenoiser:
                     "R1": jnp.zeros((1, 3, 3), f32),
                     "t1": jnp.zeros((1, 3), f32),
                     "K": jnp.zeros((1, 3, 3), f32)}
-            return self._cond_frame(params, cond, jnp.ones((1,)))[0]
+            return self._cond_frame(
+                laid_over(params, self._derived(params)), cond,
+                jnp.ones((1,)))[0]
 
         out = {}
         for i, entry in enumerate(jax.eval_shape(one_row,

@@ -3,13 +3,21 @@
 The reference computes attention with `flax.nn.dot_product_attention`
 (/root/reference/model/xunet.py:101), which materializes the (L, L) score
 matrix in HBM between ops. This kernel keeps the whole
-score→softmax→weighted-sum chain in VMEM. A grid step takes one query
-block of one (batch, head) pair; that pair's keys and values sit whole in
-VMEM (their block index does not change over a head's query blocks, so
-the pipeline fetches them once a head: 2 × 512 KB at the token trunk's
-2048 keys of 128 bfloat16), and the step WALKS them in key blocks with a
-running row max, row sum and float32 accumulator (online softmax). The
-score tile is then (block_q, block_k) whatever the key length, so the
+score→softmax→weighted-sum chain in VMEM. The operands stay where the
+layer has them, token-major with the heads side by side: q (B, Lq, H·D),
+k and v (B, Lk, Hkv·D), a head a block of lanes, and the output is
+written the same way, (B, Lq, H·Dv), which is what the caller's `o`
+projection reads. The grid is (batch, query head, query block): a grid
+step takes q's block (1, block_q, D) at (b, i, h) against that head's
+keys and values, whole in VMEM, at (b, 0, h // (H // Hkv)) — an index
+that does not change over a head's query blocks, nor over the query heads
+of a group, so the pipeline fetches them once a key/value head: 2 × 512
+KB at the token trunk's 2048 keys of 128 bfloat16, in tiles of 16 tokens
+by 128 lanes, 4 KB each, H·4 KB apart (on v5e the kernel reads such
+blocks as fast as contiguous ones: 2.179 ms against 2.180 at 8 × 32 heads
+of 1024 × 2048; PERF.md §6, PR 41) — and the step WALKS them in key blocks
+with a running row max, row sum and float32 accumulator (online softmax).
+The score tile is then (block_q, block_k) whatever the key length, so the
 query block can grow until it fills a grid step (a quarter of the grid
 steps, and a score tile that stays a quarter of the size: on v5e that is
 the gain, 2.0 → 1.5 ms at the trunk's shapes, 91 % of what the two
@@ -27,22 +35,24 @@ blocks).
 
 Two things the second token trunk (SmallThinker's layer) brought, both
 forward only. **Grouped key/value heads**: k and v may have fewer heads
-than q; the query heads of a group lie end to end in ONE grid row against
-that row's one key/value head, so K and V are neither repeated in HBM nor
-fetched more than once a group (the block index of K and V does not change
-over a group's query blocks). **A one-sided window with an offset**: a
-query at position p sees key j iff j > p − window, the queries being the
-last Lq positions of the key axis by default. Which key blocks a query
-block visits is then static ONCE THE QUERY BLOCK IS: the wrapper makes one
-kernel call a query block of a head (four at the trunk's 4096 queries),
-each with its own unrolled walk — blocks no row sees left out at trace
-time, blocks on the band's edge masked, the others bare — over every
-head's block of that index, and concatenates. (One call for all query
-blocks, its skip a `pl.when` on the block's index and its running
-statistics in VMEM scratch, read 27 ms where the unwindowed walk over MORE
-keys reads 10.6: PERF.md §6, PR 30.) At the trunk's 8192 keys a head's K
-and V are 2 × 2 MB, whole in VMEM and double-buffered: the call asks for a
-64 MiB scoped limit where the default 16 would not hold them.
+than q; query head h reads the lanes of key/value head h // (H // Hkv) —
+the index map above —, so K and V are neither repeated in HBM nor fetched
+more than once a group (the heads of a group are consecutive grid rows,
+and K's and V's block index does not change over them). **A one-sided
+window with an offset**: a query at position p sees key j iff j > p −
+window, the queries being the last Lq positions of the key axis by
+default. Which key blocks a query block visits is then static ONCE THE
+QUERY BLOCK IS: the wrapper makes one kernel call a query block (four at
+the trunk's 4096 queries), each with its own unrolled walk — blocks no
+row sees left out at trace time, blocks on the band's edge masked, the
+others bare — over every head's block of that index, each call writing
+its (B, block_q, H·Dv) slab, and joins the slabs along the token axis.
+(One call for all query blocks, its skip a `pl.when` on the block's index
+and its running statistics in VMEM scratch, read 27 ms where the
+unwindowed walk over MORE keys reads 10.6: PERF.md §6, PR 30.) At the
+trunk's 8192 keys a head's K and V are 2 × 2 MB, whole in VMEM and
+double-buffered: the call asks for a 64 MiB scoped limit where the
+default 16 would not hold them.
 
 One thing the third token trunk (Kimi-Linear's latent attention) brought,
 forward only too. **Values of another width than the keys**: its keys and
@@ -54,9 +64,22 @@ as it is for v and the output). Where the two widths are equal the
 program is the one it was.
 
 Layout notes (pallas_guide.md "Tiling Constraints"):
-  - lanes (last dim) padded to a multiple of 128; sublanes to the dtype
-    minimum. Padding is applied in the wrapper, masked inside the kernel
-    with a statically-known length, and sliced off afterwards.
+  - the wrapper transposes nothing. (B, L, H, D) → (B, L, H·D) is a
+    reshape of the trailing axes, and the output's way back the same;
+    on the chip it costs nothing WHERE THE CALLER LAST WROTE ITS OPERAND
+    AS (B, L, H·D) — a projection's product, an elementwise pass over it:
+    the two reshapes cancel. A caller that works on the 4-D form (slices
+    a head apart, concatenates along it, rotates pairs of its lanes) has
+    its array in tiles of (H, D), or token-minor, and XLA re-lays it
+    once for the kernel; the layers of models/token_denoiser.py that
+    matter write their operands in the 3-D form for that reason.
+  - lanes (a head's width) are padded to a multiple of 128 per head in
+    place, (B, L, H, D) → (B, L, H, Dp) → (B, L, H·Dp), and the token axes
+    to their blocks: one pad an operand that needs one (192-wide latent
+    keys → 256, 64-wide maps → 128, the X-UNet's 16- and 64-wide heads;
+    under the interpreter off the chip no lane is padded), none where
+    none does. Padding is masked inside the kernel with a
+    statically-known length, and sliced off afterwards.
   - matmuls request `preferred_element_type=float32` so the MXU accumulates
     in f32 even for bf16 inputs; softmax runs in f32.
 
@@ -73,9 +96,10 @@ instead (measured on v5e at D=16: ~20% faster train step).
 
 The forward's wrapper stamps what it does for every caller
 (models/vocab.py, LAYER_PARTS): `pt.kernel` around each `flash_fwd` call
-and nothing else, `pt.layout` around the transposes, pads and slices that
-feed it and hand its result back, the windowed form's concatenation of
-its calls' outputs among them. Metadata only.
+and nothing else, `pt.layout` around the pads and slices that feed it
+and hand its result back, the windowed form's concatenation of its
+calls' outputs among them (where an operand needs no pad nothing is left
+under the stamp, and the part reads 0). Metadata only.
 
 Falls back to interpreter mode off-TPU so the same code path is unit-tested
 on the CPU mesh (tests/test_flash_attention.py).
@@ -233,66 +257,72 @@ _KV_DEFAULT_VMEM_BYTES = 4 * 1024 * 1024
 _LONG_KV_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-def _flash_fwd_padded(q, k, v, *, scale: float, kv_len: int, block_q: int,
-                      block_k: int, with_lse: bool, interpret: bool,
-                      band=None):
-    """q (N, Lq_pad, Dp) · k (N, Lk_pad, Dp) · v (N, Lk_pad, Dvp) → (out
-    (N, Lq_pad, Dvp), lse or None); the values' width may differ from the
-    keys'. A row of q may hold several heads' queries end to end
-    (grouped-query attention), `band` = (window, q_offset, a head's padded
-    rows): one kernel call a query block of a head then, each with its own
-    static walk (`_attn_kernel`), every head's block i in the call's
-    grid."""
-    N, Lq, D = q.shape
-    Lk, Dv = k.shape[1], v.shape[2]
+def _flash_fwd_padded(q, k, v, *, heads: tuple[int, int], scale: float,
+                      kv_len: int, block_q: int, block_k: int,
+                      with_lse: bool, interpret: bool, band=None):
+    """q (B, Lq_pad, H·Dp) · k (B, Lk_pad, Hkv·Dp) · v (B, Lk_pad, Hkv·Dvp),
+    `heads` = (H, Hkv), a head a block of lanes → (out (B, Lq_pad, H·Dvp),
+    lse (B·H, Lq_pad) or None); the values' width may differ from the
+    keys'. The grid is (batch, query head, query block), the query blocks
+    innermost: K's and V's block index (b, 0, h // (H // Hkv)) does not
+    change over a group's query heads and query blocks, so a key/value
+    head is fetched once a group. `band` = (window, q_offset): one kernel
+    call a query block then, each with its own static walk
+    (`_attn_kernel`) and every head's block i in its grid, their
+    (B, block_q, H·Dvp) slabs joined along the token axis."""
+    B, Lq, Lk = q.shape[0], q.shape[1], k.shape[1]
+    H, Hkv = heads
+    group, D, Dv = H // Hkv, q.shape[2] // H, v.shape[2] // Hkv
     mem = {} if interpret else {"memory_space": _pallas.VMEM}
     extra = {}
     if 2 * Lk * (D + Dv) * k.dtype.itemsize > _KV_DEFAULT_VMEM_BYTES:
         extra["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=_LONG_KV_VMEM_LIMIT_BYTES)
-    k_spec = pl.BlockSpec((1, Lk, D), lambda n, i: (n, 0, 0), **mem)
-    v_spec = pl.BlockSpec((1, Lk, Dv), lambda n, i: (n, 0, 0), **mem)
+    def its_group(b, h, *_):  # both grids: (b, h) and (b, h, i)
+        # lax.div, not `//`: floor division's sign handling is a dozen
+        # scalar ops an index map, and seconds of lowering a sampler
+        return b, 0, h if group == 1 else jax.lax.div(h, group)
+
+    k_spec = pl.BlockSpec((1, Lk, D), its_group, **mem)
+    v_spec = pl.BlockSpec((1, Lk, Dv), its_group, **mem)
     if band is not None:
-        window, q_offset, rows = band
-        per_head, heads = rows // block_q, Lq // rows
+        window, q_offset = band
         outs = []
-        for i in range(per_head):
+        for i in range(Lq // block_q):
             with jax.named_scope("pt.kernel"):
-                out = pl.pallas_call(
+                outs.append(pl.pallas_call(
                     functools.partial(_attn_kernel, scale=scale,
                                       kv_len=kv_len, block_k=block_k,
                                       band=(window, q_offset + i * block_q)),
-                    grid=(N, heads),
-                    in_specs=[pl.BlockSpec(
-                        (1, block_q, D),
-                        lambda n, g, i=i: (n, g * per_head + i, 0), **mem),
-                        k_spec, v_spec],
+                    grid=(B, H),
+                    in_specs=[pl.BlockSpec((1, block_q, D),
+                                           lambda b, h, i=i: (b, i, h),
+                                           **mem),
+                              k_spec, v_spec],
                     out_specs=pl.BlockSpec((1, block_q, Dv),
-                                           lambda n, g: (n, g, 0), **mem),
-                    out_shape=jax.ShapeDtypeStruct(
-                        (N, heads * block_q, Dv), q.dtype),
+                                           lambda b, h: (b, 0, h), **mem),
+                    out_shape=jax.ShapeDtypeStruct((B, block_q, H * Dv),
+                                                   q.dtype),
                     name="flash_fwd", interpret=interpret, **extra,
-                )(q, k, v)
-            with jax.named_scope("pt.layout"):
-                outs.append(out.reshape(N, heads, 1, block_q, Dv))
+                )(q, k, v))
         with jax.named_scope("pt.layout"):
-            return jnp.concatenate(outs, axis=2).reshape(N, Lq, Dv), None
-    grid = (N, Lq // block_q)
+            return jnp.concatenate(outs, axis=1), None
     kernel = functools.partial(_attn_kernel, scale=scale, kv_len=kv_len,
                                block_k=block_k)
-    out_specs = [pl.BlockSpec((1, block_q, Dv), lambda n, i: (n, i, 0),
+    out_specs = [pl.BlockSpec((1, block_q, Dv), lambda b, h, i: (b, i, h),
                               **mem)]
-    out_shape = [jax.ShapeDtypeStruct((N, Lq, Dv), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((B, Lq, H * Dv), q.dtype)]
     if with_lse:
-        out_specs.append(
-            pl.BlockSpec((1, block_q, 128), lambda n, i: (n, i, 0), **mem))
-        out_shape.append(jax.ShapeDtypeStruct((N, Lq, 128), jnp.float32))
+        out_specs.append(pl.BlockSpec(
+            (1, block_q, 128), lambda b, h, i: (b * H + h, i, 0), **mem))
+        out_shape.append(jax.ShapeDtypeStruct((B * H, Lq, 128), jnp.float32))
     with jax.named_scope("pt.kernel"):
         out, *lse = pl.pallas_call(
             kernel,
-            grid=grid,
+            grid=(B, H, Lq // block_q),
             in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda n, i: (n, i, 0), **mem),
+                pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h),
+                             **mem),
                 k_spec, v_spec,
             ],
             out_specs=out_specs,
@@ -321,41 +351,43 @@ def _flash_attention(q, k, v, scale: float, block_q: int):
     return out
 
 
+def _heads_side_by_side(x, block: int, lanes: int):
+    """x (B, L, H, D) → (B, L', H·D'): the token axis padded to a multiple
+    of `block` and every head, in place, to a multiple of `lanes` — one
+    pad, none where neither is short —, then the heads side by side."""
+    x = jnp.pad(x, ((0, 0), (0, -x.shape[1] % block), (0, 0),
+                    (0, -x.shape[3] % lanes)))
+    return x.reshape(*x.shape[:2], -1)
+
+
 def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
                     with_lse: bool, window=None):
-    """(B, L, H, D) inputs → padded kernel call → unpadded (out, lse);
-    lse is None unless asked for. k and v may have fewer heads than q
-    (grouped-query attention): the query heads of a group lie end to end
-    in one grid row, against that row's one key/value head, so K and V
-    are neither repeated in HBM nor fetched more than once a group.
-    `window` is (size, q_offset) or None. v's last axis may be narrower
-    or wider than q's and k's (latent attention with a key part no value
-    has): each is padded to its own whole lanes, and the output has v's."""
+    """(B, L, H, D) inputs → padded kernel call → unpadded (out (B, Lq, H,
+    Dv), lse (B, H, Lq)); lse is None unless asked for. The kernel reads
+    q, k and v where they lie: the heads side by side, (B, L, H·D), a
+    free reshape of what the caller holds, and writes the output the same
+    way — nothing is transposed. k and v may have fewer heads than q
+    (grouped-query attention): K and V are neither repeated in HBM nor
+    fetched more than once a group. `window` is (size, q_offset) or None.
+    v's last axis may be narrower or wider than q's and k's (latent
+    attention with a key part no value has). A width that is no whole
+    number of 128-lane blocks is padded per head in place, each operand
+    to its own, and the token axes to their blocks: one pad an operand
+    that needs one, none where none does."""
     B, Lq, H, D = q.shape
     Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     interpret = _use_interpret()
     bq, bk, _ = forward_blocks(Lq, Lk, D, q.dtype.itemsize, block_q)
+    lanes = 1 if interpret else 128  # lane alignment for the MXU
     with jax.named_scope("pt.layout"):
-        # (B, L, H, D) → (B·H, L, D): heads become independent grid rows.
-        qt = q.transpose(0, 2, 1, 3).reshape(B * H, Lq, D)
-        kt = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, D)
-        vt = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Lk, Dv)
-        qt = _pad_to(qt, 1, bq)
-        kt = _pad_to(kt, 1, bk)
-        vt = _pad_to(vt, 1, bk)
-        if not interpret:  # lane alignment for the MXU
-            qt = _pad_to(qt, 2, 128)
-            kt = _pad_to(kt, 2, 128)
-            vt = _pad_to(vt, 2, 128)
-        rows = qt.shape[1]                 # a head's padded query rows
-        qt = qt.reshape(B * Hkv, (H // Hkv) * rows, qt.shape[2])
+        qt = _heads_side_by_side(q, bq, lanes)
+        kt = _heads_side_by_side(k, bk, lanes)
+        vt = _heads_side_by_side(v, bk, lanes)
     out, lse = _flash_fwd_padded(
-        qt, kt, vt, scale=scale, kv_len=Lk, block_q=bq, block_k=bk,
-        with_lse=with_lse, interpret=interpret,
-        band=None if window is None else (*window, rows))
+        qt, kt, vt, heads=(H, Hkv), scale=scale, kv_len=Lk, block_q=bq,
+        block_k=bk, with_lse=with_lse, interpret=interpret, band=window)
     with jax.named_scope("pt.layout"):
-        out = out.reshape(B * H, rows, out.shape[2])
-        out = out[:, :Lq, :Dv].reshape(B, H, Lq, Dv).transpose(0, 2, 1, 3)
+        out = out.reshape(B, out.shape[1], H, -1)[:, :Lq, :, :Dv]
         if with_lse:
             lse = lse[:, :Lq].reshape(B, H, Lq)
     return out, lse

@@ -60,13 +60,14 @@ def test_blocked_form_in_bfloat16_is_as_close_as_the_single_block():
     B, Lq, Lk, H, D = 1, 128, 2048, 2, 128
     q, k, v = _qkv(4, B, Lq, Lk, H, D, jnp.bfloat16)
     want = _ref_attention(*(x.astype(jnp.float32) for x in (q, k, v)))
-    to_nld = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, -1, D)
+    flat = lambda x: x.reshape(B, -1, H * D)  # the heads side by side
 
     def err(block_k):
         out, _ = fa._flash_fwd_padded(
-            to_nld(q), to_nld(k), to_nld(v), scale=D ** -0.5, kv_len=Lk,
-            block_q=Lq, block_k=block_k, with_lse=False, interpret=True)
-        out = out.reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
+            flat(q), flat(k), flat(v), heads=(H, H), scale=D ** -0.5,
+            kv_len=Lk, block_q=Lq, block_k=block_k, with_lse=False,
+            interpret=True)
+        out = out.reshape(B, Lq, H, D)
         return float(jnp.max(jnp.abs(out.astype(jnp.float32) - want)))
 
     bq, bk, key_blocks = forward_blocks(Lq, Lk, D, 2)
@@ -372,3 +373,82 @@ def test_values_of_another_width_than_the_keys_match_xla(name):
     with pytest.raises(NotImplementedError, match="another width"):
         jax.grad(lambda q: jnp.sum(flash_attention(q, k, v)))(q)
 
+
+
+# ---------------------------------------------------------------------------
+# The kernel's own operands: token-major, a head a block of lanes
+# ---------------------------------------------------------------------------
+TOKEN_MAJOR = {
+    # name: (Lq, Lk, H, Hkv, D, Dv, window, block_q); every head padded in
+    # place to whole 128-lane blocks, as on the chip
+    "grouped_heads_under_a_window": (256, 1536, 6, 2, 16, 16, 700, 128),
+    "latent_widths_192_on_128": (64, 1280, 2, 2, 192, 128, None, 64),
+    "pairs_of_64_on_128": (128, 256, 4, 2, 64, 128, None, 64),
+    "rows_short_of_a_block": (100, 300, 3, 1, 24, 24, None, 64),
+    "rows_short_of_a_block_under_a_window": (200, 400, 4, 2, 24, 16, 200,
+                                             64),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TOKEN_MAJOR))
+def test_token_major_operands_match_a_dense_einsum(name):
+    """`_flash_fwd_padded` on what the chip's lane hands it — (B, L', H·D')
+    with D' whole lane blocks, the pad lanes of every head zero, K and V
+    with their own fewer heads — against the dense einsum: query head h
+    reads the lanes of key/value head h // (H // Hkv), and the output
+    has the values' padded width a head, the heads side by side."""
+    Lq, Lk, H, Hkv, D, Dv, window, block_q = TOKEN_MAJOR[name]
+    B = 2
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
+    q = jax.random.normal(ks[0], (B, Lq, H, D))
+    k = jax.random.normal(ks[1], (B, Lk, Hkv, D))
+    v = jax.random.normal(ks[2], (B, Lk, Hkv, Dv))
+    bq, bk, _ = forward_blocks(Lq, Lk, D, 4, block_q)
+    qt, kt, vt = (fa._heads_side_by_side(x, block, 128)
+                  for x, block in ((q, bq), (k, bk), (v, bk)))
+    Dp, Dvp = -(-D // 128) * 128, -(-Dv // 128) * 128
+    assert qt.shape == (B, -(-Lq // bq) * bq, H * Dp)
+    assert kt.shape[2] == Hkv * Dp and vt.shape[2] == Hkv * Dvp
+    out, none = fa._flash_fwd_padded(
+        qt, kt, vt, heads=(H, Hkv), scale=0.3, kv_len=Lk, block_q=bq,
+        block_k=bk, with_lse=False, interpret=True,
+        band=None if window is None else (window, Lk - Lq))
+    assert none is None and out.shape == (B, qt.shape[1], H * Dvp)
+    out = out.reshape(B, -1, H, Dvp)
+    ref = _banded_reference(q, k, v, 0.3, window, Lk - Lq)
+    np.testing.assert_allclose(np.asarray(out[:, :Lq, :, :Dv]),
+                               np.asarray(ref), atol=2e-5, rtol=2e-5)
+    # the pad lanes of the output are the zero values' sums
+    assert not np.asarray(out[:, :Lq, :, Dv:]).any()
+    # and the wrapper, which pads nothing but the token axis here, agrees
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, scale=0.3, window=window,
+                                   block_q=block_q)),
+        np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def test_vjp_forward_hands_the_backward_a_head_major_lse():
+    """The VJP's forward goes through the token-major kernel and still
+    hands the backward (B, H, Lq) — row b·H + h of the kernel's lse output
+    is (b, h) —: lse is the reference's logsumexp head by head, and the
+    gradients through `_flash_bwd_pallas` (D ≥ 64) are XLA's."""
+    B, Lq, Lk, H, D = 2, 40, 1100, 3, 64
+    q, k, v = _qkv(7, B, Lq, Lk, H, D)
+    scale = D ** -0.5
+    out, (_, _, _, saved, lse) = fa._flash_vjp_fwd(q, k, v, scale, 16)
+    assert lse.shape == (B, H, Lq)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.scipy.special.logsumexp(s, -1)),
+        atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(saved), np.asarray(out))
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_ref_attention(q, k, v)),
+                               atol=1e-5, rtol=1e-5)
+    g_flash = jax.grad(lambda *a: jnp.sum(jnp.sin(flash_attention(
+        *a, block_q=16))), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(jnp.sin(_ref_attention(*a))),
+                     argnums=(0, 1, 2))(q, k, v)
+    for gf, gr in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   atol=1e-5, rtol=1e-4)

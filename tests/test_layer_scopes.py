@@ -347,7 +347,11 @@ TRUNKS = {"ms4_denoiser128": "sample_scan_tokens",
 KERNELS = ("flash_fwd", "gmm", "kda_fwd", "ssm_fwd", "short_conv_fwd",
            "gdn_fwd")
 # The parts each compiled sampler must show (it may show more: the
-# wrappers' own `layout` under `moe_experts` and `kda_core`).
+# wrappers' own `layout` under `moe_experts` and `kda_core`). A `layout`
+# of `flash_fwd` is listed where the toy size leaves something under the
+# stamp — a token axis padded to its block, the windowed calls' slabs
+# joined —: since PR 41 the wrapper transposes nothing, and the fourth
+# trunk's cross layers, whose operands need no pad, show none.
 PARTS_SEEN = {
     "x_unet": {"attn.kernel", "attn.layout"},
     "ms4_denoiser128": {
@@ -371,7 +375,7 @@ PARTS_SEEN = {
         "ssm_core.kernel", "ssm_core.layout", "ssm_conv.kernel",
         "ssm_conv.layout", "ssm_proj.matmul",
         "attn_window.kernel", "attn_window.layout", "attn_full.kernel",
-        "attn_full.layout", "attn_cross.kernel", "attn_cross.layout",
+        "attn_full.layout", "attn_cross.kernel",
         "gqa_proj.matmul", "gmu.matmul", "dense_mlp.matmul",
         "patch.matmul", "emb.matmul"},
     "oh7_denoiser256": {

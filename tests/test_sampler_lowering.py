@@ -91,42 +91,37 @@ TOY = {
         "model.tokens.linear_value_head_dim": 64,
         "data.img_sidelength": 16, "model.use_flash_attention": True},
 }
-# (preset, "cpu" | "v5e") → sha256 of the lowered text, from the parent
-# of the PR that last meant to change it (CHANGES.md, PR 30) — `paper256`'s
-# two from PR 31's own tree, which meant to change that program (the
-# X-UNet carries (B·F, H, W, C)) and no other, and `ms4_denoiser128`'s two
-# from PR 37's, which meant to change that one (the expert layer's combine
-# is the kernel `moe_combine`) and left `paper256`'s as they were.
+# (preset, "cpu" | "v5e") → sha256 of the lowered text, from the tree of
+# the PR that last meant to change it.
 DIGESTS = {
+    # PR 41's own tree, all ten: `flash_fwd` reads q, K and V token-major,
+    # a head a block of lanes, and every sampler has it in it (the
+    # X-UNet's `AttnLayer` too); `ms4` and `kl48` make their latent
+    # layers' keys and values each by a product of its own and `ms4`
+    # rotates q where its product writes it, by kernels derived once a
+    # call, `p4f` hands a pair's two maps to the kernel without slicing
+    # the pair and norms A¹V − λA²V where the kernel wrote it
+    # (CHANGES.md, PR 41).
     ("paper256", "cpu"):
-        "39347a7dc4a454945a858ac36c13fad5a51d48cd03e335f9c293d145eaad0b23",
-    ("ms4_denoiser128", "cpu"):
-        "e1116f1eeaaa10248ac67a2016934a786d7d303045df7dc20c87fd35a04b8d4e",
+        "8cd2128e75b5b22a83aab8ad4cfd3167e9cfdd8ce111b123c2c84fe84031c0ef",
     ("paper256", "v5e"):
-        "63517c08226f48f0a0478fde26dc9d9b22e2bfe776035c1783a9bf535b087e71",
+        "e4f5062f6e47b970b0b079c0b8b1e6fa815fb60c3c780ebc5f68914bc9567a95",
+    ("ms4_denoiser128", "cpu"):
+        "cdc63de9af67baa26e39ac8cb6734d5757b555f6e91b1ff5ca14ec01128b3f08",
     ("ms4_denoiser128", "v5e"):
-        "3c9874938e8fdba4f6ff895d76c5b6423762b73f3a7467c81779b0344a4d8243",
-    # PR 39's own tree: the fourth trunk's sampler with its Mamba layers'
-    # short convolution as the kernel `short_conv_fwd` (PR 38's two
-    # replaced, CHANGES.md; the four above untouched).
+        "30f7d595b672193d7c91ab3813229d8acf223326e6b5a4a2864ad62977ada6dc",
     ("p4f_denoiser256", "cpu"):
-        "a1e966152de9a583a2316ffd726ee694d86b35d55bbf5d28deaa071320e49ca9",
+        "05f8f63c7fa473cb754df607ae529763c219dde26b375809c49a704be6f2f054",
     ("p4f_denoiser256", "v5e"):
-        "d493bcf03616a4886b0dd0b99ba0b3e7b1749c7b12b6a33b9a2f21d5741f55c2",
-    # PR 40's PARENT (PR 39's tree), pinned by PR 40, which moved what
-    # `kda_fwd` and `gdn_fwd` share into one module and gave `short_conv`
-    # its head groups: at heads that are whole lane blocks the third
-    # trunk's sampler lowers to the parent's text, on both.
+        "8d3815a1662ead5a40d76159f15dcc8e09008623a3a645cd0baedb5df00a31eb",
     ("kl48_denoiser256", "cpu"):
-        "80ad7fb1518157a8ef8804111937ba4400e59ec82e7671671f0ccf2a7124ad64",
+        "10168f90f1b1974c1fa173bd8608c14fe9c8d388351e61e0463fe1ef8e1ddeda",
     ("kl48_denoiser256", "v5e"):
-        "c133b30ad66c75314497d2f55a5061cbdbe1d982aed6cd97caf288be12482ef4",
-    # PR 40's own tree: the fifth trunk's sampler as that PR made it (the
-    # eight above are the parent's).
+        "54062d168cfda27570670afcc6037b6271263a5acf57b3a307a38fbba4b0247c",
     ("oh7_denoiser256", "cpu"):
-        "328545c2ea4d64c3277074b67ff93e974658a36db19eaec829fc5641f71a2cf1",
+        "9f6591c454bb313f763d671fd36c13904226a3d262980fb07907783de5a1e94d",
     ("oh7_denoiser256", "v5e"):
-        "3fef6256c52f8b55141f5909b832719b2369bee294a889763adf03cf01393508",
+        "c917584dd3817adfb5760e4dc859ed39872383f0e621420f2c5de05a3be627e2",
 }
 
 

@@ -199,6 +199,81 @@ def test_rope_interleaved_pairs_by_hand():
     assert half[4] == pytest.approx(3.0 * c + 1.0 * s, abs=1e-6)
 
 
+@pytest.mark.parametrize("interleave", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_queries_rotated_where_their_product_writes_them(interleave, dtype):
+    """`rotated_queries` — x·q_b with every head's rotary lanes rotated by
+    way of a second product against pair-swapped columns — is, to the bit,
+    the product sliced head by head, its rotary part through `apply_rope`
+    and the two parts concatenated again."""
+    heads, nope, rope, L = 3, 8, 8, 5
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    x = jax.random.normal(ks[0], (2, L, 16), dtype)
+    q_b = {"kernel": jax.random.normal(ks[1], (16, heads * (nope + rope)),
+                                       dtype)}
+    ang = np.random.RandomState(1).uniform(0, 6, (L, rope // 2))
+    cos, sin = (f(ang).astype(np.float32) for f in (np.cos, np.sin))
+    q = jnp.dot(x, q_b["kernel"]).reshape(2, L, heads, nope + rope)
+    want = jnp.concatenate(
+        [q[..., :nope],
+         token_denoiser.apply_rope(q[..., nope:], cos, sin, interleave)],
+        axis=-1).reshape(2, L, -1)
+    got = token_denoiser.rotated_queries(
+        x, q_b, token_denoiser.pair_swapped_kernel(q_b, heads, nope,
+                                                   interleave),
+        heads, cos, sin, interleave)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("dn,dr,dv", [(8, 8, 16), (16, 8, 16)])
+def test_latent_keys_and_values_each_by_a_product_of_their_own(dn, dr, dv):
+    """`latent_keys_values` — the values by their columns of `kv_b`, the
+    keys by theirs over an identity block that carries the shared part to
+    every head — is, to the bit, `kv_b`'s whole product sliced head by
+    head and the shared part concatenated behind every head's keys."""
+    heads, rank, B, Lk = 3, 12, 2, 7
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    c_kv = jax.random.normal(ks[0], (B, Lk, rank), jnp.bfloat16)
+    shared = jax.random.normal(ks[1], (B, Lk, dr), jnp.bfloat16)
+    kv_b = {"kernel": jax.random.normal(
+        ks[2], (rank, heads * (dn + dv)), jnp.bfloat16)}
+    kv = jnp.dot(c_kv, kv_b["kernel"]).reshape(B, Lk, heads, dn + dv)
+    want_k = jnp.concatenate(
+        [kv[..., :dn],
+         jnp.broadcast_to(shared[:, :, None, :], (B, Lk, heads, dr))],
+        axis=-1)
+    keys, values = token_denoiser.latent_keys_values(
+        c_kv, shared, heads=heads,
+        **token_denoiser.latent_kernels(kv_b, heads, dn, dr))
+    np.testing.assert_array_equal(np.asarray(keys, np.float32),
+                                  np.asarray(want_k, np.float32))
+    np.testing.assert_array_equal(np.asarray(values, np.float32),
+                                  np.asarray(kv[..., dn:], np.float32))
+
+
+def test_rms_norm_over_runs_of_lanes_is_the_norm_of_the_4d_view():
+    """`rms_norm_lane_groups` — the statistic and its way back as products
+    with the groups' indicator, a float32 as the three bfloat16 terms that
+    hold all its bits — is `rms_norm` of x seen as (…, groups, width) to
+    float32's rounding: the indicator's products are exact (a group's
+    statistic comes back bit for bit), only the mean's float32 sum runs in
+    another order. Values over six decades, so a dropped low term (2⁻¹⁷
+    of the statistic) could not hide."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    x = jax.random.normal(ks[0], (2, 9, 5 * 16)) \
+        * 10.0 ** jax.random.uniform(ks[1], (2, 9, 5 * 16), minval=-3,
+                                     maxval=3)
+    scale = jax.random.normal(ks[2], (16,), jnp.bfloat16)
+    want = token_denoiser.rms_norm(x.reshape(2, 9, 5, 16), scale,
+                                   1e-5).reshape(2, 9, -1)
+    got = jax.jit(token_denoiser.rms_norm_lane_groups,
+                  static_argnums=2)(x, scale, 16, 1e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=4 * 2.0 ** -24, atol=0)
+
+
 def test_yarn_blend_at_the_published_sizes_by_hand():
     """dim 64, θ 10000, factor 128, original length 8192, β 32/1: the
     correction dimensions are ⌊64·ln(8192/(32·2π))/(2·ln 10000)⌋ = 12 and

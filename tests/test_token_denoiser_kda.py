@@ -312,7 +312,11 @@ def test_precompute_then_step_matches_the_full_forward(small):
     pre = model.precompute(params, cond)
     k = cfg.model.tokens
     lin = k.linear_attn_config
-    assert set(pre) == {"layer_cache"}
+    # the caches, and the latent layers' key and value kernels made once
+    assert set(pre) == {"layer_cache", "derived"}
+    assert {n for n, d in pre["derived"].items() if d} == {
+        f"layer_{i}" for i in range(k.num_hidden_layers)
+        if k.is_full_attention(i)}
     assert len(pre["layer_cache"]) == k.num_hidden_layers
     L = (SIDE // k.patch_size) ** 2
     for i, entry in enumerate(pre["layer_cache"]):
@@ -354,8 +358,10 @@ def test_each_kind_of_layer_matches_the_reference(small, i):
     rng = np.random.default_rng(i)
     h = jnp.asarray(rng.normal(size=(2, 32, 64)), jnp.float32)
     p = params[f"layer_{i}"]
-    first, cache, _ = model.layer(i, p, h[:, :16], None, None)
-    second, _, (counts, chosen) = model.layer(i, p, h[:, 16:], None, cache)
+    mine = token_denoiser.laid_over(p, model.layer.derive(i, p))
+    first, cache, _ = model.layer(i, mine, h[:, :16], None, None)
+    second, _, (counts, chosen) = model.layer(i, mine, h[:, 16:], None,
+                                              cache)
     got = jnp.concatenate([first, second], axis=1)
     want, aux = ref.layer(p, m, h, i, parts=True)
     assert rel(got - h, want - h) < TOL
@@ -365,7 +371,7 @@ def test_each_kind_of_layer_matches_the_reference(small, i):
                                       np.asarray(aux["chosen"])[:, 16:])
     if not k.is_full_attention(i):
         # the cached state is needed: without it the target frame differs
-        lost, _, _ = model.layer(i, p, h[:, 16:], None, (
+        lost, _, _ = model.layer(i, mine, h[:, 16:], None, (
             jnp.zeros_like(cache[0]), cache[1]))
         assert rel(lost - h[:, 16:], second - h[:, 16:]) > 1e-2
         zeroed, _ = ref.layer(p, m, h, i, zero_state_at=16)

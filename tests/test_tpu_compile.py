@@ -419,7 +419,8 @@ def test_token_trunk_shapes_compile_in_both_forms(v5e, monkeypatch):
     (`forward_blocks`), each compiled above with the blocks it ships with
     and inside the scoped VMEM the kernel asks for (the compiler refuses a
     kernel that needs more); a sampler's call writes no lse, so its custom
-    call has the one output."""
+    call has the one output — (B, Lq, H·D), the heads side by side as the
+    caller's `o` projection reads them."""
     import re
 
     assert flash_attention.forward_blocks(1024, 2048, 128, 2)[2] > 1
@@ -430,7 +431,7 @@ def test_token_trunk_shapes_compile_in_both_forms(v5e, monkeypatch):
         text = _lowered(case, v5e).compile().as_text()
         (call,) = re.findall(r"^\s*%flash_fwd\S* = (.*) custom-call\(",
                              text, re.M)
-        assert call.startswith("bf16[64,1024,128]"), (case, call)
+        assert call.startswith("bf16[2,1024,4096]"), (case, call)
 
 
 def test_flash_compiles_under_a_four_chip_data_mesh(v5e_devices,
@@ -740,4 +741,3 @@ def test_kl48_layer_convolves_the_projections_where_they_lie(v5e,
     # (B, L, width): the tails' rows are all there is
     assert max(size for op, _, size in conv if op != "custom-call") \
         < 2 * q_bytes // 64, conv
-
