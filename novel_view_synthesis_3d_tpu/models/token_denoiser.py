@@ -29,8 +29,9 @@ same pass (one trunk's; the others' calls do not take it):
     L2-normalised, a decay per head AND channel, a write strength β, the
     gated delta rule S_t = (I − β k kᵀ) Diag(α) S_{t−1} + β k vᵀ in
     SEQUENCE order (ops/kda.py, chunked), a head-wise RMSNorm under a
-    sigmoid gate — or latent attention with no positional term at all and
-    no low-rank query path (192-wide keys against 128-wide values); by
+    sigmoid gate (ops/head_norm.py) — or latent attention with no
+    positional term at all and no low-rank query path (192-wide keys
+    against 128-wide values); by
     index a dense gated-SiLU MLP (the leading layers) or experts scored by
     a SIGMOID, chosen on score + a per-expert bias, gated by the score
     alone, renormalised and scaled, plus one shared expert. A KDA layer's
@@ -59,7 +60,8 @@ same pass (one trunk's; the others' calls do not take it):
     depthwise convolution of 4 taps and SiLU, q and k L2-normalised, ONE
     decay a head, keys of 96 on values of 192, a write strength β up to
     2, the gated delta rule in SEQUENCE order (ops/gdn.py, chunked), a
-    head-wise RMSNorm under a SiLU gate; its cache the state after the
+    head-wise RMSNorm under a SiLU gate (ops/head_norm.py, the op KDA's
+    layer calls with the other activation); its cache the state after the
     frame's last token (float32) and the last three pre-convolution rows —
     or full attention with as many key/value heads as query heads, q and
     k RMS-normalised over the WHOLE projection before the head split, no
@@ -134,6 +136,7 @@ from novel_view_synthesis_3d_tpu.ops.flash_attention import (
 from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
     ROW_TILE, buffer_rows, grouped_matmul, span_sizes)
 from novel_view_synthesis_3d_tpu.ops.gdn import gated_delta_chunked
+from novel_view_synthesis_3d_tpu.ops.head_norm import gated_head_norm
 from novel_view_synthesis_3d_tpu.ops.kda import kda_chunked
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
 from novel_view_synthesis_3d_tpu.ops.short_conv import short_conv
@@ -864,7 +867,6 @@ class KimiLinearLayer:
         k, p = self.config.tokens, layer["kda"]
         lin = k.linear_attn_config
         NH, D = lin.num_heads, lin.head_dim
-        B, L, _ = h.shape
         state, tail = (None, None) if cache is None else cache
         f32 = jnp.float32
         with jax.named_scope("lk.kda_proj"):
@@ -877,16 +879,16 @@ class KimiLinearLayer:
                     _dense(_dense(a, p["f_a"]), p["f_b"]).astype(f32)
                     + p["dt_bias"].astype(f32))
             beta = jax.nn.sigmoid(_dense(a, p["beta"]).astype(f32))
-            gate = jax.nn.sigmoid(
-                _dense(_dense(a, p["g_a"]), p["g_b"]).astype(f32))
+            # before its logistic, which `gated_head_norm` takes in VMEM
+            gate = _dense(_dense(a, p["g_a"]), p["g_b"])
         with jax.named_scope("lk.kda_conv"):
             (q, keys, v), tail = conv_qkv(p, qkv, tail, NH, D ** -0.5)
         with jax.named_scope("lk.kda_core"):
             o, state = kda_chunked(q, keys, v, g, beta, state)
         with jax.named_scope("lk.kda_proj"):
-            o = rms_norm(o.reshape(B, L, NH, D), p["o_norm"]["scale"],
-                         k.rms_norm_eps) * gate.reshape(B, L, NH, D)
-            h = h + _dense(o.reshape(B, L, NH * D).astype(a.dtype), p["o"])
+            h = h + _dense(gated_head_norm(
+                o, gate, p["o_norm"]["scale"], heads=NH, eps=k.rms_norm_eps,
+                activation="sigmoid"), p["o"])
         return h, (state, tail)
 
     def derive(self, i, p):
@@ -1229,9 +1231,7 @@ class OlmoHybridLayer:
         frames before (None: the sequence starts here). → (h, this frame's
         (state, tail))."""
         k, p = self.config.tokens, layer["gdn"]
-        NH, dk, dv = (k.linear_num_value_heads, k.linear_key_head_dim,
-                      k.linear_value_head_dim)
-        B, L, _ = h.shape
+        NH, dk = k.linear_num_value_heads, k.linear_key_head_dim
         state, tail = (None, None) if cache is None else cache
         dt, f32 = jnp.dtype(self.config.dtype), jnp.float32
         with jax.named_scope("lk.gdn_proj"):
@@ -1243,16 +1243,17 @@ class OlmoHybridLayer:
             beta = jax.nn.sigmoid(_dense(a, p["b"]).astype(f32))
             if k.linear_allow_neg_eigval:
                 beta = 2.0 * beta
-            gate = jax.nn.silu(_dense(a, p["g"]).astype(f32))
+            # before its SiLU, which `gated_head_norm` takes in VMEM
+            gate = _dense(a, p["g"])
         with jax.named_scope("lk.gdn_conv"):
             # heads of 96 and 192 lanes: the kernel packs them
             (q, keys, v), tail = conv_qkv(p, qkv, tail, NH, dk ** -0.5)
         with jax.named_scope("lk.gdn_core"):
             o, state = gated_delta_chunked(q, keys, v, g, beta, state)
         with jax.named_scope("lk.gdn_proj"):
-            o = rms_norm(o.reshape(B, L, NH, dv), p["o_norm"]["scale"],
-                         k.rms_norm_eps) * gate.reshape(B, L, NH, dv)
-            mixed = _dense(o.reshape(B, L, NH * dv).astype(dt), p["o"])
+            mixed = _dense(gated_head_norm(
+                o, gate, p["o_norm"]["scale"], heads=NH, eps=k.rms_norm_eps,
+                activation="silu"), p["o"])
             h = h + rms_norm(mixed, layer["mix_norm"]["scale"],
                              k.rms_norm_eps).astype(dt)
         return h, (state, tail)

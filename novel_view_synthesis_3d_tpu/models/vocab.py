@@ -39,9 +39,10 @@ GQA_TOKEN_LAYER_KINDS = ("gqa_proj", "attn_window", "attn_full",
                          "moe_route", "moe_experts", "patch", "emb", "pose",
                          "update")
 # The token family's third trunk (Kimi-Linear's stack): KDA layers stamp
-# their projections (with the gates, the output norm and `o`), the short
-# convolution (with its SiLU and the L2 norms: the kernel `short_conv_fwd`,
-# a call a projection) and the chunked scan apart;
+# their projections (with the gates, `o` and, in front of it, the scan's
+# output under its head-wise norm and gate: the kernel `head_norm_fwd`),
+# the short convolution (with its SiLU and the L2 norms: the kernel
+# `short_conv_fwd`, a call a projection) and the chunked scan apart;
 # its latent-attention and expert layers stamp as the first trunk's, its
 # leading dense layer's MLP as `dense_mlp`.
 KDA_TOKEN_LAYER_KINDS = ("kda_proj", "kda_conv", "kda_core", "mla_proj",
@@ -60,8 +61,9 @@ SSM_TOKEN_LAYER_KINDS = ("ssm_proj", "ssm_conv", "ssm_core", "gqa_proj",
                          "attn_window", "attn_full", "attn_cross", "gmu",
                          "dense_mlp", "patch", "emb", "pose", "update")
 # The token family's fifth trunk (Olmo-Hybrid's stack): a Gated DeltaNet
-# layer stamps its projections (with the decay, β, the gate, the output
-# norm, `o` and the norm of the sublayer's output) as `gdn_proj`, the short
+# layer stamps its projections (with the decay, β, the gate, the scan's
+# output under its head-wise norm and gate — the kernel `head_norm_fwd` —,
+# `o` and the norm of the sublayer's output) as `gdn_proj`, the short
 # convolution (the kernel `short_conv_fwd`, a call a projection) as
 # `gdn_conv` and the chunked scalar-decay scan as `gdn_core`; a full layer
 # its projections, the QK norm and its output's norm as `gqa_proj` and its
@@ -80,7 +82,7 @@ LAYER_KINDS = tuple(dict.fromkeys(
     + GDN_TOKEN_LAYER_KINDS))
 # Every part a `jax.named_scope("pt.<part>")` may stamp inside a kind
 # (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py, ops/gdn.py,
-# ops/ssm.py, ops/short_conv.py, ops/expert_combine.py,
+# ops/ssm.py, ops/short_conv.py, ops/head_norm.py, ops/expert_combine.py,
 # models/token_denoiser.py); this
 # tuple and layer_part_of are the only other place a part is spelled.
 LAYER_PARTS = ("kernel", "layout", "gather", "matmul")

@@ -12,6 +12,8 @@ ops/fused_groupnorm.py; this module is their one home, and new kernels
 
 from __future__ import annotations
 
+import math
+
 import jax
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -90,3 +92,21 @@ def resolve_flag(flag, field: str) -> bool:
 def fits_vmem(nbytes: int, limit: int = SLAB_LIMIT_BYTES) -> bool:
     """True if a per-program input slab of `nbytes` fits the budget."""
     return nbytes < limit
+
+
+def head_group(head: int) -> int:
+    """Lanes of the fewest whole heads of `head` lanes that fill whole
+    128-lane blocks: a head of 128 or 256 by itself, two of 192 or four of
+    96 are 384, two of 64 are 128."""
+    return head * (128 // math.gcd(head, 128))
+
+
+def lanes_a_step(D: int, group: int, most: int) -> int:
+    """Lanes a grid step takes of a width D walked in whole groups: the
+    largest multiple of `group` up to `most` that divides D; where none
+    does, the largest there is, and the last step's block hangs over the
+    array's edge (its lanes past the edge are read as they come and never
+    written: whole heads)."""
+    steps = range(group, max(min(most, -(-D // group) * group), group) + 1,
+                  group)
+    return max([n for n in steps if D % n == 0] or steps)

@@ -52,7 +52,6 @@ Forward only: a gradient through `short_conv` raises by name.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -140,7 +139,7 @@ def _blocks(L: int, D: int, heads,
     head = D // heads if heads else 128
     if heads and D % heads:
         raise ValueError(f"short_conv: {heads} heads do not divide D={D}")
-    group = head * (128 // math.gcd(head, 128))
+    group = _pallas.head_group(head)
     if interpret:   # any width, whole; short runs, so the carry is walked
         rows = min(_INTERPRET_ROWS, -(-L // 8) * 8)
         return rows, rows, D, head if heads else D, group if heads else D
@@ -149,13 +148,8 @@ def _blocks(L: int, D: int, heads,
             f"short_conv_fwd on the chip takes whole 128-lane blocks of "
             f"(B, L, D) where no head is given; got D={D}")
     rows = min(RUN_ROWS, -(-L // TILE_ROWS) * TILE_ROWS)
-    steps = range(group, max(min(RUN_LANES, -(-D // group) * group), group)
-                  + 1, group)
-    # A step's lanes divide the width where a multiple of the group does;
-    # else the last step's block hangs over the array's edge (its lanes
-    # past the edge are read as they come and never written: whole heads).
-    lanes = max([n for n in steps if D % n == 0] or steps)
-    return rows, TILE_ROWS, lanes, head, group
+    return (rows, TILE_ROWS, _pallas.lanes_a_step(D, group, RUN_LANES), head,
+            group)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "scale", "eps",

@@ -147,6 +147,11 @@ PART_PATHS = {
     "conv_kernel_call": (
         TOKENS + "/og.layer_0/lk.kda_conv/jit(_conv_call)/pt.kernel/"
         "short_conv_fwd/pallas_call", ("layer_0", "kda_conv.kernel")),
+    # o's gated head-wise norm behind a delta rule: its call is the
+    # projections' kind's `kernel` part
+    "head_norm_kernel_call": (
+        TOKENS + "/og.layer_0/lk.kda_proj/jit(_norm_call)/pt.kernel/"
+        "head_norm_fwd/pallas_call", ("layer_0", "kda_proj.kernel")),
     "conv_wrapper_layout": (
         TOKENS + "/og.layer_0/lk.kda_conv/pt.layout/pad",
         ("layer_0", "kda_conv.layout")),
@@ -345,7 +350,7 @@ TRUNKS = {"ms4_denoiser128": "sample_scan_tokens",
           "p4f_denoiser256": "sample_scan_ssm",
           "oh7_denoiser256": "sample_scan_gdn"}
 KERNELS = ("flash_fwd", "gmm", "kda_fwd", "ssm_fwd", "short_conv_fwd",
-           "gdn_fwd")
+           "gdn_fwd", "head_norm_fwd")
 # The parts each compiled sampler must show (it may show more: the
 # wrappers' own `layout` under `moe_experts` and `kda_core`). A `layout`
 # of `flash_fwd` is listed where the toy size leaves something under the
@@ -366,7 +371,7 @@ PARTS_SEEN = {
         "patch.matmul", "emb.matmul"},
     "kl48_denoiser256": {
         "kda_core.kernel", "kda_conv.kernel", "kda_conv.layout",
-        "kda_proj.matmul", "mla_core.kernel",
+        "kda_proj.matmul", "kda_proj.kernel", "mla_core.kernel",
         "mla_core.layout", "mla_proj.matmul", "dense_mlp.matmul",
         "moe_route.matmul", "moe_route.gather", "moe_experts.kernel",
         "moe_experts.gather", "moe_shared.matmul", "patch.matmul",
@@ -381,7 +386,8 @@ PARTS_SEEN = {
     "oh7_denoiser256": {
         "gdn_core.kernel", "gdn_core.layout", "gdn_conv.kernel",
         "gdn_conv.layout",
-        "gdn_proj.matmul", "attn_full.kernel", "attn_full.layout",
+        "gdn_proj.matmul", "gdn_proj.kernel", "attn_full.kernel",
+        "attn_full.layout",
         "gqa_proj.matmul", "dense_mlp.matmul", "patch.matmul",
         "emb.matmul"},
 }

@@ -94,8 +94,8 @@ TOY = {
 # (preset, "cpu" | "v5e") → sha256 of the lowered text, from the tree of
 # the PR that last meant to change it.
 DIGESTS = {
-    # PR 41's own tree, all ten: `flash_fwd` reads q, K and V token-major,
-    # a head a block of lanes, and every sampler has it in it (the
+    # PR 41's own tree (six of its ten stand): `flash_fwd` reads q, K and V
+    # token-major, a head a block of lanes, and every sampler has it (the
     # X-UNet's `AttnLayer` too); `ms4` and `kl48` make their latent
     # layers' keys and values each by a product of its own and `ms4`
     # rotates q where its product writes it, by kernels derived once a
@@ -114,14 +114,18 @@ DIGESTS = {
         "05f8f63c7fa473cb754df607ae529763c219dde26b375809c49a704be6f2f054",
     ("p4f_denoiser256", "v5e"):
         "8d3815a1662ead5a40d76159f15dcc8e09008623a3a645cd0baedb5df00a31eb",
+    # PR 42's tree, these four: both delta-rule layers hand their scan's o
+    # and the gate's projection to `head_norm_fwd` (ops/head_norm.py) where
+    # they held the norm on a (B, L, H, d) view; the six above are PR 41's
+    # (CHANGES.md, PR 42).
     ("kl48_denoiser256", "cpu"):
-        "10168f90f1b1974c1fa173bd8608c14fe9c8d388351e61e0463fe1ef8e1ddeda",
+        "dc905a6a296b60701ef8a03434249a06e932ac3b7807f8720d1e086e32131e93",
     ("kl48_denoiser256", "v5e"):
-        "54062d168cfda27570670afcc6037b6271263a5acf57b3a307a38fbba4b0247c",
+        "68cdf34ce68050d780dc62a05b8ac8f02b7faaf56073d5f4f5b037f68a805f9e",
     ("oh7_denoiser256", "cpu"):
-        "9f6591c454bb313f763d671fd36c13904226a3d262980fb07907783de5a1e94d",
+        "cb2f8f0dade55f98ceb4c906475321c4ab09c7d6f05e5830750ed2f2f13439b8",
     ("oh7_denoiser256", "v5e"):
-        "c917584dd3817adfb5760e4dc859ed39872383f0e621420f2c5de05a3be627e2",
+        "9b558d7285dee20601aff53a5019b4a0caabfbdc05fd28fa9b433037e6be6e29",
 }
 
 

@@ -260,6 +260,9 @@ def test_guided_eps_through_make_sampler(small):
      "dot_general", ("layer_0", "gdn_proj"), "gdn_proj.matmul"),
     ("jit(sampler)/while/body/lk.update/og.layer_3/lk.attn_full/pt.kernel/"
      "flash_fwd", ("layer_3", "attn_full"), "attn_full.kernel"),
+    ("jit(sampler)/while/body/lk.update/og.layer_1/lk.gdn_proj/"
+     "jit(_norm_call)/pt.kernel/head_norm_fwd/pallas_call",
+     ("layer_1", "gdn_proj"), "gdn_proj.kernel"),
 ])
 def test_layer_of_reads_the_trunks_paths(path, want, part):
     assert layer_of(path) == want

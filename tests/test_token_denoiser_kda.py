@@ -547,6 +547,9 @@ def test_the_shares_add_up_to_the_uncut_layer(layer8, shares):
      ("layer_0", "dense_mlp")),
     ("jit(sample)/lk.update/og.layer_3/lk.mla_core/flash_fwd",
      ("layer_3", "mla_core")),
+    # o's gated head-wise norm: the kernel's time is `kda_proj`'s
+    ("jit(sample)/lk.update/og.layer_1/lk.kda_proj/jit(_norm_call)/"
+     "pt.kernel/head_norm_fwd/pallas_call", ("layer_1", "kda_proj")),
 ])
 def test_layer_of_reads_the_trunks_paths(path, want):
     assert layer_of(path) == want

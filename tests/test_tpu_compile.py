@@ -33,6 +33,7 @@ from novel_view_synthesis_3d_tpu.ops import (
     fused_step,
     gdn,
     grouped_matmul,
+    head_norm,
     kda,
     serving_attention,
     short_conv,
@@ -235,6 +236,16 @@ def _short_conv_widths(rows, L, taps, calls):
             + [((rows, taps - 1, d), BF16) for d, _, _ in calls])
 
 
+def _head_norm(rows, L, heads, d, activation):
+    """What a delta-rule layer does between its scan and `o`: the scan's
+    float32 o, the gate's projection in the compute type, the one scale
+    the heads share."""
+    return (lambda o, gate, scale: head_norm.gated_head_norm(
+        o, gate, scale, heads=heads, eps=1e-6, activation=activation),
+        [((rows, L, heads * d), F32), ((rows, L, heads * d), BF16),
+         ((d,), BF16)])
+
+
 # base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
 # head dim 256. GroupNorm and epilogue cases are UNet level slabs (H·W, C)
 # that `fits_vmem` admits, the largest included.
@@ -304,6 +315,13 @@ CASES = {
         1, 4000, 4, [(5760, 30, 1.0)]),
     "flash_fwd_Lq4096_Lk8192_h30_d128": _gqa_attn(4096, 8192, 30, 30, 128,
                                                   None),
+    # the gated head-wise norm behind both delta rules at the sizes their
+    # cells run — 32 heads of a lane block under the logistic, 30 heads of
+    # 192 lanes (two to three lane blocks) under SiLU — and a ragged one:
+    # tokens that are no whole run, 30 heads of 96 that fill 7.5 groups
+    "head_norm_4x4096x4096_h32": _head_norm(4, 4096, 32, 128, "sigmoid"),
+    "head_norm_2x4096x5760_h30": _head_norm(2, 4096, 30, 192, "silu"),
+    "head_norm_ragged_1x4000x2880_h30": _head_norm(1, 4000, 30, 96, "silu"),
     "flash_fwd_diff_window512_Lq4096_Lk4607_qk64_v128": _diff_attn(
         4096, 4607, 20, 10, 64, 512),
     "flash_fwd_diff_Lq4096_Lk8192_qk64_v128": _diff_attn(
@@ -347,6 +365,7 @@ KERNEL_NAMES = {
     "gdn_fwd": "gdn_chunked_ragged_1x4000_h30_k96_v192",
     "ssm_fwd": "ssm_scan_ragged_1x4000_c5120_n16",
     "short_conv_fwd": "ssm_short_conv_2x4096x5120_k4",
+    "head_norm_fwd": "head_norm_ragged_1x4000x2880_h30",
     "moe_combine": "moe_combine_8192x4x4096",
 }
 
@@ -656,6 +675,13 @@ TEMP_LIMITS = {
     "gdn_chunked_ragged_1x4000_h30_k96_v192": 0.12e9,
     "gdn_short_conv_2x4096x11520_k4": 4e6,
     "gdn_short_conv_heads192_1x4000x5760_k4": 0.1e9,
+    # The gated head norm takes o, the gate and its result where they lie,
+    # whole runs or not (an edge block is masked, not padded): its only
+    # buffer in HBM is the scale laid a step's lanes wide (8 KB) — no
+    # float32 array of the operand's size, where the 4-D form wrote three.
+    "head_norm_4x4096x4096_h32": 1e6,
+    "head_norm_2x4096x5760_h30": 1e6,
+    "head_norm_ragged_1x4000x2880_h30": 1e6,
 }
 
 
@@ -670,17 +696,21 @@ def test_kda_compiles_and_fits_for_v5e(name, v5e, monkeypatch):
     assert mem.temp_size_in_bytes < TEMP_LIMITS[name], mem.temp_size_in_bytes
 
 
-def _kl48_kda_layer(v5e):
-    """(the compiled text of a KDA layer of `kl48_denoiser256` at the cell's
-    shape — 4 rows of 4096 tokens from a cached state —, q's bytes, rows,
-    width, head width)."""
+_LAYER_TEXTS = {}   # a preset's delta-rule layer is compiled once a run
+
+
+def _delta_rule_layer(v5e, preset, rows, state, tail):
+    """The compiled text of layer 0 of `preset`, a delta-rule layer, over
+    `rows` rows of 4096 tokens from a cached (state float32, convolution
+    tail bfloat16) of the shapes `state` and `tail` behind the rows."""
     from novel_view_synthesis_3d_tpu.config import get_preset
     from novel_view_synthesis_3d_tpu.models import build_denoiser
 
-    cfg = get_preset("kl48_denoiser256")
-    model = build_denoiser(cfg.model)
-    k, lin = cfg.model.tokens, cfg.model.tokens.linear_attn_config
-    i, rows, L = 0, 4, 4096
+    if preset in _LAYER_TEXTS:
+        return _LAYER_TEXTS[preset]
+    cfg = get_preset(preset)
+    model, k = build_denoiser(cfg.model), cfg.model.tokens
+    i, L = 0, 4096
     assert not k.is_full_attention(i)
 
     def S(shape, dtype):
@@ -690,12 +720,42 @@ def _kl48_kda_layer(v5e):
         lambda a: S(a.shape, a.dtype),
         jax.eval_shape(lambda: model.init(
             {"params": jax.random.PRNGKey(0)}))["params"][f"layer_{i}"])
-    width = lin.num_heads * lin.head_dim
-    cache = (S((rows, lin.num_heads, lin.head_dim, lin.head_dim), F32),
-             S((rows, lin.short_conv_kernel_size - 1, 3 * width), BF16))
     text = jax.jit(lambda p, h, c: model.layer(i, p, h, None, c)[:2]).lower(
-        params, S((rows, L, k.hidden_size), BF16), cache).compile().as_text()
-    return text, rows * L * width * 2, rows, width, lin.head_dim
+        params, S((rows, L, k.hidden_size), BF16),
+        (S((rows,) + state, F32), S((rows,) + tail, BF16))).compile(
+        ).as_text()
+    _LAYER_TEXTS[preset] = text
+    return text
+
+
+def _kl48_kda_layer(v5e):
+    """(the compiled text of a KDA layer of `kl48_denoiser256` at the cell's
+    shape — 4 rows of 4096 tokens from a cached state —, q's bytes, rows,
+    width, head width)."""
+    from novel_view_synthesis_3d_tpu.config import get_preset
+
+    lin = get_preset("kl48_denoiser256").model.tokens.linear_attn_config
+    rows, width = 4, lin.num_heads * lin.head_dim
+    text = _delta_rule_layer(
+        v5e, "kl48_denoiser256", rows,
+        (lin.num_heads, lin.head_dim, lin.head_dim),
+        (lin.short_conv_kernel_size - 1, 3 * width))
+    return text, rows * 4096 * width * 2, rows, width, lin.head_dim
+
+
+def _oh7_gdn_layer(v5e):
+    """(the compiled text of a Gated DeltaNet layer of `oh7_denoiser256` at
+    the cell's shape — 2 rows of 4096 tokens from a cached state —, the
+    bytes of the scan's o in float32, heads, lanes a head)."""
+    from novel_view_synthesis_3d_tpu.config import get_preset
+
+    k = get_preset("oh7_denoiser256").model.tokens
+    rows, NH = 2, k.linear_num_value_heads
+    dk, dv = k.linear_key_head_dim, k.linear_value_head_dim
+    text = _delta_rule_layer(
+        v5e, "oh7_denoiser256", rows, (NH, dk, dv),
+        (k.linear_conv_kernel_dim - 1, NH * (2 * dk + dv)))
+    return text, rows * 4096 * NH * dv * 4, NH, dv
 
 
 def test_kl48_layer_hands_the_scan_its_operands_where_they_lie(v5e,
@@ -741,3 +801,56 @@ def test_kl48_layer_convolves_the_projections_where_they_lie(v5e,
     # (B, L, width): the tails' rows are all there is
     assert max(size for op, _, size in conv if op != "custom-call") \
         < 2 * q_bytes // 64, conv
+
+
+def _between_the_scan_and_o(text, kind, o_bytes, heads, d):
+    """What a delta-rule layer compiled for the chip holds between its
+    scan's kernel and `o`'s product: ONE `head_norm_fwd` call under the
+    layer's `proj` stamp, which writes o's elements once in the compute
+    type; no float32 array seen as (…, heads, d) anywhere in the program,
+    under a stamp or under none (the 4-D view of the norm was a `copy`, a
+    `reshape`, a `broadcast` and a `mul` of o's size each); and under the
+    stamp no `reshape`, `broadcast` or `transpose` of even a sixty-fourth
+    of o. → the stamp's writes, for what a caller holds them to besides."""
+    import re
+
+    writes = [(op, name, size) for op, k, name, size in _entry_writes(text)
+              if k == kind]
+    calls = [w for w in writes if w[0] == "custom-call"]
+    assert [("head_norm_fwd" in name, size) for _, name, size in calls] == [
+        (True, o_bytes // 2)], calls
+    assert calls[0][1].endswith("pt.kernel/head_norm_fwd/pallas_call")
+    assert not re.findall(rf"f32\[[\d,]+,{heads},{d}\]", text)
+    moved = [w for w in writes if w[2] >= o_bytes // 64 and w[0] in (
+        "reshape", "broadcast", "transpose")]
+    assert not moved, moved
+    return writes
+
+
+def test_kl48_layer_norms_the_scans_output_where_it_lies(v5e, monkeypatch):
+    """The same layer's way from `kda_fwd` to `o` (ops/head_norm.py): the
+    kernel's float32 o (B, L, 32·128) goes to `head_norm_fwd` as it lies
+    and the gate's projection in bfloat16 as its product left it — no
+    float32 gate, no `copy` at all under the stamp."""
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    text, q_bytes, _, _, head_dim = _kl48_kda_layer(v5e)
+    writes = _between_the_scan_and_o(text, "kda_proj", 2 * q_bytes, 32,
+                                     head_dim)
+    assert not [w for w in writes if w[0] == "copy"
+                and w[2] >= q_bytes // 32], writes
+    # the one float32 array of o's size under the stamp is the decay's
+    # (per head AND per channel: `kda_fwd`'s operand g)
+    assert [op for op, _, size in writes if size == 2 * q_bytes] == [
+        "fusion"], writes
+
+
+def test_oh7_layer_norms_the_scans_output_where_it_lies(v5e, monkeypatch):
+    """A Gated DeltaNet layer of `oh7_denoiser256` at the cell's shape: from
+    `gdn_fwd` to `o` the float32 o (B, L, 30·192) goes to `head_norm_fwd` as
+    it lies — two heads to three lane blocks INSIDE the kernel, where the
+    4-D view padded a head to 256 lanes — and nothing of o's float32 size
+    is written under the stamp (the decay is a number a head here)."""
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    text, o_bytes, heads, d = _oh7_gdn_layer(v5e)
+    writes = _between_the_scan_and_o(text, "gdn_proj", o_bytes, heads, d)
+    assert max(size for _, _, size in writes) <= o_bytes // 2, writes
