@@ -173,15 +173,10 @@ def check_kernels(seed: int) -> dict:
     import flax.linen as nn
 
     from novel_view_synthesis_3d_tpu.config import DiffusionConfig
-    from novel_view_synthesis_3d_tpu.models.layers import GroupNorm
     from novel_view_synthesis_3d_tpu.ops.flash_attention import (
         flash_attention)
-    from novel_view_synthesis_3d_tpu.ops.fused_epilogue import (
-        fused_film_epilogue)
     from novel_view_synthesis_3d_tpu.ops.fused_step import (
         fused_denoise_step, unfused_reference_step)
-    from novel_view_synthesis_3d_tpu.ops.serving_attention import (
-        serving_attention)
     from novel_view_synthesis_3d_tpu.sample.stepper import ScheduleBank
 
     rng = np.random.default_rng(seed)
@@ -213,39 +208,14 @@ def check_kernels(seed: int) -> dict:
                   z, ec, eu, nz, coefs, w),
               TOL_F32)
 
-    # GroupNorm(+swish) and the GN → FiLM → swish epilogue at base128's
-    # 32² × 256 level, bf16 — against the XLA branches of models/layers.
-    h = normal((4, 32, 32, 256), jnp.bfloat16)
-    fused = GroupNorm(act="swish", fused=True, dtype=jnp.bfloat16)
-    plain = GroupNorm(act="swish", fused=False, dtype=jnp.bfloat16)
-    gn_params = jax.tree.map(lambda a: a + normal(a.shape) * 0.3,
-                             plain.init(jax.random.PRNGKey(seed), h))
-    close("fused_groupnorm", jax.jit(fused.apply)(gn_params, h),
-          jax.jit(plain.apply)(gn_params, h), TOL_BF16)
-
-    s, t = normal(h.shape, jnp.bfloat16) * 0.5, normal(h.shape, jnp.bfloat16)
-    gscale = gn_params["params"]["GroupNorm_0"]["scale"]
-    gbias = gn_params["params"]["GroupNorm_0"]["bias"]
-    flat = (4, 32 * 32, 256)
-    norm = GroupNorm(act=None, fused=False, dtype=jnp.bfloat16)
-    close("fused_epilogue",
-          jax.jit(lambda x, s, t: fused_film_epilogue(
-              x.reshape(flat), gscale, gbias, s.reshape(flat),
-              t.reshape(flat), 32, 1e-6, jnp.bfloat16))(h, s, t),
-          jax.jit(lambda x, s, t: nn.swish(
-              norm.apply(gn_params, x) * (1.0 + s) + t))(
-                  h, s, t).reshape(flat), TOL_BF16)
-
     # Attention at base128's two attention levels: 32² tokens at head
     # dim 64, 16² tokens at head dim 128 (4 heads) — flash forward and
-    # backward, and the forward-only serving kernel, against XLA.
+    # backward against XLA.
     for name, L, hd in (("L1024_d64", 1024, 64), ("L256_d128", 256, 128)):
         q, k, v = (normal((4, L, 4, hd), jnp.bfloat16) for _ in range(3))
         want = jax.jit(nn.dot_product_attention)(q, k, v)
         close(f"flash_fwd_{name}", jax.jit(flash_attention)(q, k, v),
               want, TOL_BF16)
-        close(f"serving_attention_{name}",
-              jax.jit(serving_attention)(q, k, v), want, TOL_BF16)
         cot = normal(want.shape, jnp.bfloat16)
 
         def grads(attn):

@@ -406,35 +406,6 @@ class ModelConfig:
     # r4 bench matrix re-validates with tiny64/base128 flash-off A/Bs
     # (results/tpu_r04/).
     use_flash_attention: Any = "auto"
-    # Fused single-HBM-pass GroupNorm(+swish) Pallas kernel
-    # (ops/fused_groupnorm.py) for the per-frame GN chains. False (default)
-    # keeps the XLA norm until the kernel has a measured TPU win; "auto"
-    # enables it on TPU backends; True forces it (interpret mode off-TPU).
-    # Shared-stats GN (groupnorm_per_frame=False) and over-VMEM slabs fall
-    # back to XLA automatically.
-    use_fused_groupnorm: Any = False
-    # Fused single-kernel SERVING attention (ops/serving_attention.py):
-    # a forward-only Pallas kernel that keeps one (batch·head) attention
-    # head entirely in VMEM — scores, softmax, and the value contraction
-    # in one pass, no backward residuals. Sized for serving token counts
-    # (H·W at the attn resolutions); shapes whose slabs exceed the VMEM
-    # budget fall back to the XLA path per shape, and every decision is
-    # recorded in a coverage registry that tools/summarize_bench.py
-    # renders. "auto" enables it on TPU backends only; True forces the
-    # kernel (interpret mode off-TPU — exact, slow, the tier-1 parity
-    # path); False keeps XLA. Takes precedence over use_flash_attention
-    # when both resolve on (flash keeps the trained backward path; this
-    # kernel is inference-only).
-    use_serving_attention: Any = False
-    # Fused GroupNorm → FiLM-modulate → SiLU block epilogue
-    # (ops/fused_epilogue.py): the ResnetBlock tail after the FiLM Dense
-    # — normalize, scale/shift by the per-pixel FiLM tensors, activate —
-    # runs as ONE Pallas pass per (B·F) row instead of three HBM
-    # round-trips. The FiLM Dense projection itself stays in XLA (it is
-    # a matmul; the kernel fuses the bandwidth-bound elementwise tail).
-    # Same flag semantics as use_fused_groupnorm; requires
-    # groupnorm_per_frame=True and falls back to XLA for over-VMEM slabs.
-    use_fused_epilogue: Any = False
     # Sequence parallelism: shard the H·W token axis of every attention over
     # the mesh 'seq' axis and run ring attention (parallel/ring_attention.py,
     # ppermute over ICI). Requires mesh.seq > 1 and token counts divisible
@@ -1781,21 +1752,6 @@ class Config:
                 "cannot run as one fused step (use 'auto' to fuse where "
                 "possible; the step scheduler's first-order dpm++ "
                 "fallback still fuses)")
-        for fname in ("use_serving_attention", "use_fused_epilogue"):
-            fv = getattr(self.model, fname)
-            if fv not in (True, False, "auto"):
-                errors.append(
-                    f"model.{fname}={fv!r} must be True, False, or "
-                    "'auto' (Pallas serving kernel; 'auto' = TPU "
-                    "backends only, interpret mode when forced True "
-                    "off-TPU)")
-        if (self.model.use_fused_epilogue is True
-                and not self.model.groupnorm_per_frame):
-            errors.append(
-                "model.use_fused_epilogue=True requires "
-                "model.groupnorm_per_frame=True — the epilogue kernel "
-                "normalizes one (frame, H·W, C) slab per grid row; "
-                "shared-stats GN spans frames and keeps the XLA path")
         rg = self.registry
         if rg.publish_every < 0:
             errors.append(

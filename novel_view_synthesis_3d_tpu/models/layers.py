@@ -44,14 +44,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from novel_view_synthesis_3d_tpu.ops._pallas import over_data_axis
-from novel_view_synthesis_3d_tpu.ops.fused_epilogue import (
-    fits_vmem as epilogue_fits_vmem,
-    fused_film_epilogue,
-)
-from novel_view_synthesis_3d_tpu.ops.fused_groupnorm import (
-    fits_vmem,
-    fused_group_norm,
-)
 from novel_view_synthesis_3d_tpu.ops.resample import (
     avgpool_downsample,
     nearest_neighbor_upsample,
@@ -95,40 +87,22 @@ class FrameConv(nn.Module):
             )(h)
 
 
-class _GNParams(nn.Module):
-    """scale/bias params matching flax GroupNorm's tree leaf names, so the
-    fused and XLA paths share one checkpoint layout (instantiated with
-    name='GroupNorm_0', the auto-name the nn.GroupNorm submodule gets)."""
-
-    features: int
-    param_dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self):
-        scale = self.param("scale", nn.initializers.ones,
-                           (self.features,), self.param_dtype)
-        bias = self.param("bias", nn.initializers.zeros,
-                          (self.features,), self.param_dtype)
-        return scale, bias
-
-
 class GroupNorm(nn.Module):
-    """32-group GroupNorm over (B·F, H, W, C), optional fused activation.
+    """32-group GroupNorm over (B·F, H, W, C), then `act` ('swish' or
+    none) in the module's dtype.
 
     Per-frame statistics are per row. `per_frame=False` (statistics over a
     sample's `frames` rows jointly) views the rows as (B, F, H, W, C) for
     the norm alone, and needs `frames`.
 
-    `act='swish'` applies the nonlinearity INSIDE the norm op — on the
-    fused Pallas path (ops/fused_groupnorm.py) the whole GN→swish chain is
-    one HBM pass; on the XLA path it is applied after the norm (identical
-    math, same param tree). `fused=True` requires per-frame statistics and
-    falls back to XLA when a row slab exceeds the kernel's VMEM budget.
+    The norm has no pass of its own on the chip: between two convolutions
+    of (B·F, H, W, C) XLA:TPU fuses the statistics into the convolution
+    before and the apply into the one after (PERF.md §6, PR 31;
+    tests/test_tpu_compile.py pins it a block shape).
     """
 
     per_frame: bool = True
     act: Optional[str] = None
-    fused: bool = False
     frames: Optional[int] = None
     dtype: jnp.dtype = jnp.float32
 
@@ -136,32 +110,6 @@ class GroupNorm(nn.Module):
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
         with jax.named_scope("lk.gn"):
             N, H, W, C = h.shape
-            if self.fused and self.per_frame:
-                if fits_vmem(H * W, C, h.dtype):
-                    scale, bias = _GNParams(features=C, name="GroupNorm_0")()
-                    # out_dtype=self.dtype matches the XLA branch's semantics:
-                    # nn.GroupNorm casts to the module dtype, THEN swish runs
-                    # in that dtype.
-                    y = fused_group_norm(h.reshape(N, H * W, C), scale,
-                                         bias, 32, 1e-6, self.act, self.dtype)
-                    return y.reshape(N, H, W, C)
-                # A fallback says so: one line per (H·W, C, dtype) per
-                # process, fired at trace time, so steady-state steps stay
-                # clean. What the XLA path costs is the compiler's to
-                # decide: between two convolutions of (B·F, H, W, C) the
-                # chip's fuses the statistics into the convolution before
-                # and the apply into the one after, no pass of its own
-                # (PERF.md §6, PR 31).
-                from novel_view_synthesis_3d_tpu.utils.profiling import (
-                    log_once)
-
-                log_once(
-                    ("fused_gn_fallback", H * W, C, str(h.dtype)),
-                    f"note: fused GroupNorm falling back to XLA for slab "
-                    f"(H·W={H * W}, C={C}, {h.dtype}): "
-                    f"{H * W * C * jnp.dtype(h.dtype).itemsize} bytes exceeds "
-                    "the kernel's VMEM budget (ops/fused_groupnorm.py) — "
-                    "this level's norm is XLA's to fuse into its neighbours")
             norm = nn.GroupNorm(num_groups=32, dtype=self.dtype)
             if self.per_frame:
                 y = norm(h)
@@ -175,11 +123,6 @@ class GroupNorm(nn.Module):
 
 class FiLM(nn.Module):
     """Feature-wise linear modulation (reference model/xunet.py:54-61).
-
-    `h=None` returns the raw (scale, shift) pair instead of applying the
-    modulation — the fused-epilogue path (ops/fused_epilogue.py) feeds
-    them to the Pallas kernel while this module keeps sole ownership of
-    the Dense projection (same param tree either way).
 
     `emb` is one array over all of `h`'s rows, or a pair (leading rows at
     `h`'s spatial extent, the remaining rows at 1 × 1): what
@@ -196,7 +139,7 @@ class FiLM(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, h: Optional[jnp.ndarray], emb: Emb):
+    def __call__(self, h: jnp.ndarray, emb: Emb) -> jnp.ndarray:
         with jax.named_scope("lk.emb"):
             dense = nn.Dense(2 * self.features, dtype=self.dtype,
                              param_dtype=self.param_dtype)
@@ -222,23 +165,7 @@ class FiLM(nn.Module):
             else:
                 scale, shift = jnp.split(dense(nonlinearity(emb)), 2,
                                          axis=-1)
-            if h is None:
-                return scale, shift
             return h * (1.0 + scale) + shift
-
-
-class _GNParamsNested(nn.Module):
-    """_GNParams one level down (…/GroupNorm_1/GroupNorm_0/{scale,bias}):
-    the tree path a GroupNorm module's nn.GroupNorm child would occupy,
-    so the fused-epilogue path shares the XLA path's checkpoint layout
-    (instantiated with name='GroupNorm_1', the auto-name the second
-    GroupNorm in a ResnetBlock gets)."""
-
-    features: int
-
-    @nn.compact
-    def __call__(self):
-        return _GNParams(features=self.features, name="GroupNorm_0")()
 
 
 class ResnetBlock(nn.Module):
@@ -253,8 +180,6 @@ class ResnetBlock(nn.Module):
     dropout: float = 0.0
     resample: Optional[str] = None
     per_frame_gn: bool = True
-    fused_gn: bool = False
-    fused_epilogue: bool = False
     frames: Optional[int] = None  # read by GroupNorm(per_frame=False) alone
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
@@ -268,8 +193,8 @@ class ResnetBlock(nn.Module):
         C = h_in.shape[-1]
         features = C if self.features is None else self.features
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
-        gn_kw = dict(per_frame=self.per_frame_gn, fused=self.fused_gn,
-                     frames=self.frames, dtype=self.dtype)
+        gn_kw = dict(per_frame=self.per_frame_gn, frames=self.frames,
+                     dtype=self.dtype)
 
         h = GroupNorm(act="swish", **gn_kw)(h_in)
         if self.resample is not None:
@@ -281,39 +206,9 @@ class ResnetBlock(nn.Module):
                 h = updown(h)
                 h_in = updown(h_in)
         h = FrameConv(features, **kw)(h)
-        N, H, W, _ = h.shape
-        if (self.fused_epilogue and self.per_frame_gn
-                and epilogue_fits_vmem(H * W, features, h.dtype)):
-            # Fused GN → FiLM-modulate → swish tail (one HBM pass,
-            # ops/fused_epilogue.py). The FiLM Dense stays in XLA; GN
-            # params ride the XLA path's GroupNorm_1/GroupNorm_0 tree.
-            gscale, gbias = _GNParamsNested(features=features,
-                                            name="GroupNorm_1")()
-            fscale, fshift = FiLM(features=features, **kw)(None, emb)
-            flat = (N, H * W, features)
-            with jax.named_scope("lk.gn"):
-                h = fused_film_epilogue(
-                    h.reshape(flat),
-                    gscale, gbias,
-                    jnp.broadcast_to(fscale, h.shape).reshape(flat),
-                    jnp.broadcast_to(fshift, h.shape).reshape(flat),
-                    32, 1e-6, self.dtype).reshape(N, H, W, features)
-        else:
-            if self.fused_epilogue and self.per_frame_gn:
-                from novel_view_synthesis_3d_tpu.utils.profiling import (
-                    log_once)
-
-                log_once(
-                    ("fused_epilogue_fallback", H * W, features,
-                     str(h.dtype)),
-                    f"note: fused block epilogue falling back to XLA for "
-                    f"slab (H·W={H * W}, C={features}, {h.dtype}): 3× "
-                    "resident rows exceed the kernel's VMEM budget "
-                    "(ops/fused_epilogue.py) — this level's GN→FiLM→swish "
-                    "tail is XLA's to fuse into the next convolution")
-            h = FiLM(features=features, **kw)(GroupNorm(**gn_kw)(h), emb)
-            with jax.named_scope("lk.gn"):
-                h = nonlinearity(h)
+        h = FiLM(features=features, **kw)(GroupNorm(**gn_kw)(h), emb)
+        with jax.named_scope("lk.gn"):
+            h = nonlinearity(h)
         with jax.named_scope("lk.conv"):
             h = nn.Dropout(rate=self.dropout)(h, deterministic=not train)
         h = FrameConv(features, zero_init=True, **kw)(h)
@@ -334,7 +229,6 @@ class AttnLayer(nn.Module):
     attn_heads: int = 4
     out_proj: bool = False
     use_flash: bool = False
-    use_serving: bool = False  # forward-only Pallas serving kernel
     # jax Mesh the program is partitioned over: the Pallas kernels run
     # per 'data' shard (ops/_pallas.over_data_axis); with `ring`, exact
     # ring attention over its 'seq' axis instead.
@@ -359,15 +253,6 @@ class AttnLayer(nn.Module):
                 ring_self_attention)
             out = ring_self_attention(qh, kh, vh, self.mesh,
                                       batch_axis=DATA_AXIS)
-        elif self.use_serving:
-            # Inference twin of the flash kernel: no residuals, no VJP,
-            # per-shape VMEM gate + coverage registry
-            # (ops/serving_attention.py). Takes precedence over
-            # use_flash — both fuse, this one is trace- and HBM-lighter
-            # for forward-only step programs.
-            from novel_view_synthesis_3d_tpu.ops.serving_attention import (
-                serving_attention)
-            out = over_data_axis(serving_attention, self.mesh)(qh, kh, vh)
         elif self.use_flash:
             from novel_view_synthesis_3d_tpu.ops.flash_attention import (
                 flash_attention)
@@ -399,11 +284,9 @@ class AttnBlock(nn.Module):
     attn_heads: int = 4
     out_proj: bool = False
     use_flash: bool = False
-    use_serving: bool = False
     mesh: Optional[object] = None
     ring: bool = False
     per_frame_gn: bool = True
-    fused_gn: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -411,8 +294,8 @@ class AttnBlock(nn.Module):
     def __call__(self, h_in: jnp.ndarray) -> jnp.ndarray:
         N, H, W, C = h_in.shape
         F = self.frames
-        h = GroupNorm(per_frame=self.per_frame_gn, fused=self.fused_gn,
-                      frames=F, dtype=self.dtype)(h_in)
+        h = GroupNorm(per_frame=self.per_frame_gn, frames=F,
+                      dtype=self.dtype)(h_in)
         # Everything after the norm is `attn`: the one stamp covers the
         # AttnLayer (this block is its only caller) and the block's own
         # token shuffling and residual.
@@ -420,8 +303,7 @@ class AttnBlock(nn.Module):
             tokens = h.reshape(N, H * W, C)
             layer = AttnLayer(attn_heads=self.attn_heads,
                               out_proj=self.out_proj,
-                              use_flash=self.use_flash,
-                              use_serving=self.use_serving, mesh=self.mesh,
+                              use_flash=self.use_flash, mesh=self.mesh,
                               ring=self.ring,
                               dtype=self.dtype, param_dtype=self.param_dtype)
             if self.attn_type == "self":
@@ -453,29 +335,22 @@ class XUNetBlock(nn.Module):
     attn_heads: int = 4
     attn_out_proj: bool = False
     attn_use_flash: bool = False
-    attn_use_serving: bool = False
     attn_mesh: Optional[object] = None
     attn_ring: bool = False
     dropout: float = 0.0
     train: bool = False  # attribute (not call arg) so nn.remat needs no statics
     per_frame_gn: bool = True
-    fused_gn: bool = False
-    fused_epilogue: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, emb: Emb) -> jnp.ndarray:
-        kw = dict(per_frame_gn=self.per_frame_gn, fused_gn=self.fused_gn,
-                  frames=self.frames,
+        kw = dict(per_frame_gn=self.per_frame_gn, frames=self.frames,
                   dtype=self.dtype, param_dtype=self.param_dtype)
         attn_kw = dict(attn_heads=self.attn_heads, out_proj=self.attn_out_proj,
-                       use_flash=self.attn_use_flash,
-                       use_serving=self.attn_use_serving, mesh=self.attn_mesh,
-                       ring=self.attn_ring,
-                       **kw)
+                       use_flash=self.attn_use_flash, mesh=self.attn_mesh,
+                       ring=self.attn_ring, **kw)
         h = ResnetBlock(features=self.features, dropout=self.dropout,
-                        fused_epilogue=self.fused_epilogue,
                         **kw)(x, emb, train=self.train)
         if self.use_attn:
             h = AttnBlock(attn_type="self", **attn_kw)(h)

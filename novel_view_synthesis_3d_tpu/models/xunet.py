@@ -55,11 +55,6 @@ from novel_view_synthesis_3d_tpu.models.vocab import (  # noqa: F401
     layer_part_of,
 )
 from novel_view_synthesis_3d_tpu.ops.flash_attention import resolve_flash
-from novel_view_synthesis_3d_tpu.ops.fused_epilogue import (
-    resolve_fused_epilogue)
-from novel_view_synthesis_3d_tpu.ops.fused_groupnorm import resolve_fused_gn
-from novel_view_synthesis_3d_tpu.ops.serving_attention import (
-    resolve_serving_attention)
 from novel_view_synthesis_3d_tpu.ops.posenc import posenc_ddpm, posenc_nerf
 from novel_view_synthesis_3d_tpu.utils.profiling import log_once
 
@@ -497,14 +492,8 @@ class XUNet(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         param_dtype = jnp.dtype(cfg.param_dtype)
         kw = dict(dtype=dtype, param_dtype=param_dtype)
-        fused_gn = resolve_fused_gn(cfg.use_fused_groupnorm)
         F = _num_frames(batch)
-        blk_kw = dict(per_frame_gn=cfg.groupnorm_per_frame,
-                      fused_gn=fused_gn,
-                      frames=F,
-                      fused_epilogue=resolve_fused_epilogue(
-                          cfg.use_fused_epilogue),
-                      **kw)
+        blk_kw = dict(per_frame_gn=cfg.groupnorm_per_frame, frames=F, **kw)
         num_resolutions = len(cfg.ch_mult)
         C = batch["z"].shape[-1]
 
@@ -519,8 +508,6 @@ class XUNet(nn.Module):
                 attn_heads=cfg.attn_heads,
                 attn_out_proj=cfg.attn_out_proj,
                 attn_use_flash=resolve_flash(cfg.use_flash_attention),
-                attn_use_serving=resolve_serving_attention(
-                    cfg.use_serving_attention),
                 attn_mesh=self.mesh,
                 attn_ring=(cfg.sequence_parallel
                            and self.mesh is not None),
@@ -622,8 +609,7 @@ class XUNet(nn.Module):
             assert kind == "final", kind
             assert not hs
             h = GroupNorm(per_frame=cfg.groupnorm_per_frame, act="swish",
-                          fused=fused_gn, frames=F, dtype=dtype,
-                          name=info["gn"])(h)
+                          frames=F, dtype=dtype, name=info["gn"])(h)
             # Zero-init output conv in float32 for stable noise predictions.
             out = FrameConv(C, zero_init=True, dtype=jnp.float32,
                             param_dtype=param_dtype, name=info["out"])(

@@ -2,12 +2,11 @@
 
 Every kernel in this package carries the same off-TPU contract: the
 IDENTICAL kernel code path runs through the Pallas interpreter on CPU
-(so tier-1 exercises the real kernel, not a shadow implementation), the
-config flag that enables it resolves 'auto' → TPU-only, and slab-sized
-kernels bound their VMEM residency and fall back to XLA above it. Those
-three pieces were duplicated between ops/flash_attention.py and
-ops/fused_groupnorm.py; this module is their one home, and new kernels
-(ops/fused_step.py) use it from day one.
+(so tier-1 exercises the real kernel, not a shadow implementation). The
+two kernels that still have an XLA form beside them
+(ops/flash_attention.py, ops/fused_step.py) resolve their 'auto' | bool
+config value here, and the slab-sized one bounds its VMEM residency and
+falls back to XLA above it.
 """
 
 from __future__ import annotations
@@ -23,21 +22,11 @@ from novel_view_synthesis_3d_tpu.parallel.mesh import DATA_AXIS
 
 VMEM = pltpu.VMEM
 
-# Per-program budget for a kernel's resident input slab(s). A slab
-# kernel holds its in/out blocks double-buffered plus f32 working
-# copies (2× a bf16 slab each): a 2 MiB bf16 slab already measures
-# 16.25 MiB of scoped VMEM in the chip's compiler, past its 16 MiB
-# default, so the slab kernels ask for VMEM_LIMIT_BYTES (v5e has
-# 128 MiB of VMEM per core). Strict `<` in fits_vmem so power-of-two
-# slab sizes (every UNet level is one) can't sit on a zero-headroom
-# boundary.
+# Per-program budget for a kernel's resident input slab. A slab kernel
+# holds its in/out blocks double-buffered plus f32 working copies.
+# Strict `<` in fits_vmem so power-of-two slab sizes (every UNet level
+# is one) can't sit on a zero-headroom boundary.
 SLAB_LIMIT_BYTES = 3 * 1024 * 1024
-VMEM_LIMIT_BYTES = 32 * 1024 * 1024
-
-
-def slab_compiler_params() -> pltpu.CompilerParams:
-    """Compiler parameters of the kernels guarded by `fits_vmem`."""
-    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def use_interpret() -> bool:

@@ -176,9 +176,10 @@ def log_once(key, msg: str) -> bool:
     """Emit `msg` on stderr the FIRST time `key` is seen; drop repeats.
 
     For conditions that are worth exactly one line per process — e.g. a
-    fused kernel silently falling back to XLA (ops/fused_groupnorm.py via
-    models/layers.py): the fallback fires per traced call site, and a log
-    per trace would be noise while zero logs hides a perf cliff."""
+    configuration the guidance pair's 1 × 1 unconditional embedding does
+    not hold for (models/xunet.py): the condition fires per traced call
+    site, and a log per trace would be noise while zero logs hides a perf
+    cliff."""
     if key in _logged_once:
         return False
     _logged_once.add(key)

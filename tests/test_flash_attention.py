@@ -33,6 +33,9 @@ def _qkv(seed, B, Lq, Lk, H, D, dtype=jnp.float32):
         (2, 64, 64, 4, 8),     # tiny64 self-attn shape class
         (1, 100, 300, 2, 16),  # ragged lengths → padding/masking path
         (2, 256, 256, 4, 64),
+        (1, 50, 50, 2, 8),     # a length no multiple of 16 (or of 128)
+        (2, 256, 320, 4, 32),  # several query blocks, keys ragged past a
+                               # lane block, a head of 32
         # Past 1024 padded keys a grid step walks the key axis in blocks
         # (forward_blocks): a whole multiple of the key block; a ragged Lk
         # whose padding boundary falls inside the last block; Lq != Lk

@@ -1,5 +1,6 @@
 """X-UNet shape/behavior tests (SURVEY.md §4: per-block + end-to-end)."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -157,6 +158,38 @@ def test_groupnorm_per_frame_vs_shared():
     m1 = float(jnp.abs(out_sh[1::2].mean()))
     assert m0 < 1e-4          # per-frame: frame 1 normalized on its own
     assert m1 > 0.5           # shared stats: offset leaks through
+
+
+def test_groupnorm_output_is_in_the_module_dtype_on_a_float32_input():
+    """The norm casts to the module's dtype and the activation runs in
+    that dtype: a bfloat16 module on a float32 input gives bfloat16, the
+    float32 module's values to bfloat16's precision."""
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 8, 8, 64), jnp.float32)
+    low = GroupNorm(act="swish", dtype=jnp.bfloat16)
+    full = GroupNorm(act="swish")
+    params = jax.tree.map(lambda a: a + 0.3,
+                          low.init(jax.random.PRNGKey(3), x))
+    y, y32 = low.apply(params, x), full.apply(params, x)
+    assert (y.dtype, y32.dtype) == (jnp.bfloat16, jnp.float32)
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y32),
+                               rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_groupnorm_swish_is_swish_of_the_plain_norm(dtype):
+    """`act='swish'` is the nonlinearity applied to the norm's output in
+    the module's dtype, on the same parameter tree — to the bit."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (4, 8, 8, 64), dtype)
+    plain = GroupNorm(dtype=dtype)
+    params = jax.tree.map(lambda a: a + 0.3,
+                          plain.init(jax.random.PRNGKey(1), x))
+    assert set(params["params"]) == {"GroupNorm_0"}
+    assert set(params["params"]["GroupNorm_0"]) == {"scale", "bias"}
+    y = plain.apply(params, x)
+    ys = GroupNorm(act="swish", dtype=dtype).apply(params, x)
+    assert y.dtype == ys.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(ys, np.float32),
+                                  np.asarray(nn.swish(y), np.float32))
 
 
 def test_resnet_block_resample_shapes():

@@ -353,36 +353,12 @@ def test_trainer_honors_prefetch_depth_and_completes(tmp_path):
     assert trainer.step == num_steps
 
 
-# ---------------------------------------------------------------------------
-# fused-GN fallback logging satellites
-# ---------------------------------------------------------------------------
 def test_log_once_dedups():
     from novel_view_synthesis_3d_tpu.utils.profiling import log_once
 
     key = ("test_log_once", time.time())
     assert log_once(key, "first") is True
     assert log_once(key, "second") is False
-
-
-def test_fused_gn_over_vmem_fallback_logs_once(capsys):
-    """A slab over the VMEM budget silently lost the fused kernel before;
-    now the fallback announces itself exactly once per slab shape."""
-    from novel_view_synthesis_3d_tpu.models.layers import GroupNorm
-    from novel_view_synthesis_3d_tpu.ops.fused_groupnorm import fits_vmem
-
-    H = W = 128
-    C = 96  # 128·128·96·4 B ≈ 6.3 MiB > the 3 MiB slab budget
-    assert not fits_vmem(H * W, C, jnp.float32)
-    gn = GroupNorm(per_frame=True, fused=True)
-    x = jnp.ones((1, H, W, C), jnp.float32)
-    params = gn.init(jax.random.PRNGKey(0), x)
-    y = gn.apply(params, x)
-    assert y.shape == x.shape
-    err = capsys.readouterr().err
-    assert "falling back to XLA" in err
-    # Same shape again: no second line (log_once dedups).
-    gn.apply(params, x)
-    assert "falling back to XLA" not in capsys.readouterr().err
 
 
 def test_service_stats_summary():

@@ -28,14 +28,11 @@ from novel_view_synthesis_3d_tpu.ops import (
     _pallas,
     expert_combine,
     flash_attention,
-    fused_epilogue,
-    fused_groupnorm,
     fused_step,
     gdn,
     grouped_matmul,
     head_norm,
     kda,
-    serving_attention,
     short_conv,
     ssm,
 )
@@ -77,19 +74,6 @@ def _attn(fn, L, hd, grad):
             return jnp.sum(fn(q, k, v).astype(F32))
         return jax.grad(loss, argnums=(0, 1, 2)), [(shape, BF16)] * 3
     return fn, [(shape, BF16)] * 3
-
-
-def _gn(hw, c):
-    return (lambda x, s, b: fused_groupnorm.fused_group_norm(
-        x, s, b, 32, 1e-6, "swish", BF16),
-        [((16, hw, c), BF16), ((c,), F32), ((c,), F32)])
-
-
-def _epilogue(hw, c):
-    return (lambda x, gs, gb, s, t: fused_epilogue.fused_film_epilogue(
-        x, gs, gb, s, t, 32, 1e-6, BF16),
-        [((16, hw, c), BF16), ((c,), F32), ((c,), F32),
-         ((16, hw, c), BF16), ((16, hw, c), BF16)])
 
 
 def _step(sampler, B, px):
@@ -247,13 +231,14 @@ def _head_norm(rows, L, heads, d, activation):
 
 
 # base128 attends at 32² tokens / head dim 64 and 16² / 128; paper256 at
-# head dim 256. GroupNorm and epilogue cases are UNet level slabs (H·W, C)
-# that `fits_vmem` admits, the largest included.
+# head dim 256, at 32² tokens and (forward, what its cell runs) at 16².
 CASES = {
     **{f"flash_{'fwdbwd' if g else 'fwd'}_L{L}_d{hd}":
        _attn(flash_attention.flash_attention, L, hd, g)
        for g in (False, True)
        for L, hd in ((1024, 64), (256, 128), (1024, 256))},
+    "flash_fwd_L256_d256": _attn(flash_attention.flash_attention, 256, 256,
+                                 False),
     # The token trunk's two shapes: a step's 2048 keys take the forward's
     # blocked form, the once-a-call pass's 1024 the one-block body
     # (test_token_trunk_shapes_compile_in_both_forms).
@@ -326,13 +311,6 @@ CASES = {
         4096, 4607, 20, 10, 64, 512),
     "flash_fwd_diff_Lq4096_Lk8192_qk64_v128": _diff_attn(
         4096, 8192, 20, 10, 64, None),
-    **{f"serving_attention_L{L}_d{hd}":
-       _attn(serving_attention.serving_attention, L, hd, False)
-       for L, hd in ((1024, 64), (1024, 256))},
-    **{f"fused_groupnorm_{hw}x{c}": _gn(hw, c)
-       for hw, c in ((4096, 256), (1024, 1024), (256, 512))},
-    **{f"fused_epilogue_{hw}x{c}": _epilogue(hw, c)
-       for hw, c in ((1024, 256), (256, 512), (256, 1024))},
     **{f"fused_step_{s}_B{B}_{px}px": _step(s, B, px)
        for s in ("ddpm", "ddim") for B in (1, 2, 16) for px in (128, 256)},
 }
@@ -356,9 +334,6 @@ KERNEL_NAMES = {
     "flash_fwd": "flash_fwdbwd_L256_d128",
     "flash_dq": "flash_fwdbwd_L256_d128",
     "flash_dkv": "flash_fwdbwd_L256_d128",
-    "serving_attention": "serving_attention_L1024_d64",
-    "fused_groupnorm": "fused_groupnorm_256x512",
-    "fused_epilogue": "fused_epilogue_256x512",
     "fused_step": "fused_step_ddpm_B2_128px",
     "gmm": "grouped_matmul_up_4096x2048",
     "kda_fwd": "kda_chunked_ragged_1x4000_h32_d128",
@@ -484,18 +459,6 @@ def test_flash_compiles_under_a_four_chip_data_mesh(v5e_devices,
     assert "all-gather(" not in text and "all-reduce(" not in text
 
 
-def test_admitted_slabs_are_cases():
-    """The GroupNorm/epilogue cases above sit inside the VMEM guards the
-    model applies (a case the guard rejects would never reach the kernel
-    and would prove nothing)."""
-    for name in CASES:
-        if name.startswith(("fused_groupnorm_", "fused_epilogue_")):
-            hw, c = map(int, name.rsplit("_", 1)[1].split("x"))
-            mod = (fused_groupnorm if "groupnorm" in name
-                   else fused_epilogue)
-            assert mod.fits_vmem(hw, c, BF16), name
-
-
 _HLO_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
 
 
@@ -575,66 +538,139 @@ def test_film_pair_buys_no_pass_over_h_on_v5e(v5e):
     assert set(written["pair"]) <= set(written["full"]), written
 
 
-@pytest.mark.parametrize("skips", [False, True],
-                         ids=["256_to_256", "up_512_to_256"])
-def test_resnet_blocks_keep_the_convolutions_layout_on_v5e(skips, v5e):
-    """Two chained ResnetBlocks at paper256's level-0 shape (8 rows, 256
-    px, 256 channels, a guidance pair's embedding) between a stem and a
-    head convolution, compiled for the chip; with `skips` each block's
-    input is the up path's channel concatenation with a skip (512 → 256).
+# Every distinct ResnetBlock shape of paper256 (1024-wide embedding) and
+# base128's at 128 and 64 px (512-wide): the side the block's input has,
+# the channels of `h` and of the skip it is concatenated with on the up
+# path (0: no concatenation), the block's features, its resampling — and,
+# as found on the tree this table was read off (PR 43's parent; PERF.md §7
+# row 17), the bytes the block writes and those of them under `lk.gn`, in
+# units of the block's output.
+RESNET_SHAPES = {
+    "paper256": (1024, [
+        (256, 256, 0, 256, None, 4.01, 0),
+        (256, 256, 0, 256, "down", 23.01, 0),
+        (256, 256, 256, 256, None, 5.01, 0),
+        (256, 512, 256, 256, None, 5.01, 0),
+        (128, 256, 0, 512, None, 4.01, 0),
+        (128, 512, 0, 512, None, 4.01, 0),
+        (128, 512, 0, 512, "down", 24.01, 16),
+        (128, 512, 0, 512, "up", 6.26, 0.25),
+        (128, 512, 256, 512, None, 5.01, 0),
+        (128, 512, 512, 512, None, 5.01, 0),
+        (64, 512, 0, 512, None, 4.01, 0),
+        (64, 512, 0, 512, "down", 24.04, 16),
+        (64, 512, 0, 512, "up", 6.26, 0.25),
+        (64, 512, 512, 512, None, 5.02, 0),
+        (64, 1024, 512, 512, None, 5.02, 0),
+        (32, 512, 0, 1024, None, 5.03, 0),
+        (32, 1024, 0, 1024, None, 4.04, 0),
+        (32, 1024, 0, 1024, "down", 24.15, 16),
+        (32, 1024, 0, 1024, "up", 6.26, 0.25),
+        (32, 1024, 512, 1024, None, 5.05, 0),
+        (32, 1024, 1024, 1024, None, 5.06, 0),
+        (16, 1024, 0, 1024, None, 4.15, 0),
+        (16, 1024, 0, 1024, "up", 6.29, 0.25),
+        (16, 1024, 1024, 1024, None, 5.24, 0),
+    ]),
+    "base128": (512, [
+        (128, 128, 0, 128, None, 4.01, 0),
+        (128, 128, 0, 128, "down", 24.02, 16),
+        (128, 128, 128, 128, None, 5.01, 0),
+        (128, 256, 128, 128, None, 4.01, 0),
+        (64, 128, 0, 256, None, 4.01, 0),
+        (64, 256, 0, 256, None, 4.01, 0),
+        (64, 256, 0, 256, "down", 24.04, 16),
+        (64, 256, 0, 256, "up", 5.26, 0.25),
+        (64, 256, 128, 256, None, 5.02, 0),
+        (64, 256, 256, 256, None, 5.02, 0),
+    ]),
+}
+RESNET_CASES = {
+    f"{preset}-{px}px-{c_h + c_skip}to{c_out}" + (f"-{resample}"
+                                                   if resample else ""):
+    (emb_ch, px, c_h, c_skip, c_out, resample, passes, gn_passes)
+    for preset, (emb_ch, shapes) in RESNET_SHAPES.items()
+    for px, c_h, c_skip, c_out, resample, passes, gn_passes in shapes}
+
+
+@pytest.mark.parametrize("case", list(RESNET_CASES))
+def test_resnet_blocks_keep_the_convolutions_layout_on_v5e(case, v5e):
+    """One ResnetBlock between a stem and a head convolution at a shape
+    its preset runs (8 rows: 2 views × 2 guidance halves × 2 frames, a
+    guidance pair's embedding), compiled for the chip; where the block
+    sits on the up path its input IS the channel concatenation with a
+    skip.
 
     The network carries (B·F, H, W, C), so XLA:TPU keeps the
     convolutions' layout (`{3,0,2,1:T(8,128)}`: the rows in the sublanes)
     through a block: no `copy`, `reshape` or `transpose` of `h`'s size
-    under any stamp, none for the concatenation, and a block
-    writes 4 passes of `h` — its two convolutions (the norms' apply, the
-    swishes, FiLM's modulation and the residual sum fused into them), the
-    conditional rows' FiLM projection and that projection's relayout —
-    and a fifth, the 1 × 1 skip projection, where the channels change.
-    While `h` was (B, F, H, W, C) every reshape to and from B·F was a
-    copy to row-major and the passes between two convolutions ran apart:
-    6 and 11 passes a block in this fragment (PERF.md §6, PR 31)."""
+    under any stamp, none for the concatenation. Between two convolutions
+    the norm has no pass of its own — nothing under `lk.gn` writes an
+    array of `h`'s size: the statistics fuse into the convolution before,
+    the apply, the swishes, FiLM's modulation and the residual sum into
+    the one after — and a block writes 4 passes of `h`: its two
+    convolutions, the conditional rows' FiLM projection and that
+    projection's relayout; a fifth, the 1 × 1 skip projection, where the
+    channels change (not everywhere: the table has the count a shape was
+    found with). That is what the Pallas norm kernels PR 43 deleted were
+    for, at every level and not only the two their VMEM guard admitted.
+    While `h` was (B, F, H, W, C) it was 6 and 11 passes a block
+    (PERF.md §6, PR 31).
+
+    Pinned as found, and open (PERF.md §7 row 17): a `down` block writes
+    its normed, activated input and the skip's in float32 before the
+    average pool's reduction (16 outputs' worth, then 2 × 2 more for the
+    pooled float32 sums), an `up` block writes both nearest-neighbour
+    broadcasts and gives the swish in front of them a pass at the input's
+    size."""
     import flax.linen as nn
 
     from novel_view_synthesis_3d_tpu.models.layers import (
         FrameConv, ResnetBlock)
 
-    # 2 views × 2 guidance halves × 2 frames; the conditional half's rows
-    rows, cond_rows, side, C, E = 8, 4, 256, 256, 1024
+    emb_ch, px, c_h, c_skip, c_out, resample, passes, gn_passes = (
+        RESNET_CASES[case])
+    rows, cond_rows = 8, 4
+    out_px = {"up": 2 * px, "down": px // 2, None: px}[resample]
 
     class Chain(nn.Module):
         @nn.compact
         def __call__(self, x, emb):
-            h = FrameConv(C, dtype=BF16)(x)
-            skip = FrameConv(C, dtype=BF16)(x) if skips else None
-            for _ in range(2):
-                if skips:
-                    h = jnp.concatenate([h, skip], axis=-1)
-                h = ResnetBlock(features=C, dtype=BF16)(h, emb, train=False)
+            h = FrameConv(c_h, dtype=BF16)(x)
+            if c_skip:
+                h = jnp.concatenate(
+                    [h, FrameConv(c_skip, dtype=BF16)(x)], axis=-1)
+            h = ResnetBlock(features=c_out, resample=resample,
+                            dtype=BF16)(h, emb, train=False)
             return FrameConv(3, dtype=BF16)(h)
 
     def S(*shape):
         return jax.ShapeDtypeStruct(shape, BF16, sharding=v5e)
 
     chain = Chain()
+    toy = 8 * out_px // px
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e),
         jax.eval_shape(lambda: chain.init(
-            jax.random.PRNGKey(0), jnp.zeros((rows, 8, 8, C), BF16),
-            jnp.zeros((rows, 8, 8, E), BF16))))
-    emb = (S(cond_rows, side, side, E), S(rows - cond_rows, 1, 1, E))
+            jax.random.PRNGKey(0), jnp.zeros((rows, 8, 8, c_h), BF16),
+            jnp.zeros((rows, toy, toy, emb_ch), BF16))))
+    emb = (S(cond_rows, out_px, out_px, emb_ch),
+           S(rows - cond_rows, 1, 1, emb_ch))
     text = jax.jit(chain.apply).lower(
-        params, S(rows, side, side, C), emb).compile().as_text()
-    h_bytes = rows * side * side * C * 2
+        params, S(rows, px, px, c_h), emb).compile().as_text()
+    h_bytes = rows * out_px * out_px * c_out * 2
+    # an array of `h`'s size: half the smaller of the block's two ends
+    sized = min(h_bytes, rows * px * px * (c_h + c_skip) * 2) // 2
     writes = list(_entry_writes(text))
     moved = [(op, kind, name) for op, kind, name, size in writes
-             if op in ("copy", "reshape", "transpose")
-             and size >= h_bytes // 2]
+             if op in ("copy", "reshape", "transpose") and size >= sized]
     assert not moved, moved
-    in_blocks = sum(size for _, _, name, size in writes
-                    if "ResnetBlock_" in name or "concatenate" in name)
-    assert in_blocks <= 2 * (5 if skips else 4) * h_bytes * 1.01, (
-        in_blocks / h_bytes)
+    under_gn = [(op, name, size / h_bytes) for op, kind, name, size in writes
+                if kind == "gn" and size >= sized]
+    assert sum(s for _, _, s in under_gn) <= gn_passes * 1.01, under_gn
+    in_block = sum(size for _, _, name, size in writes
+                   if "ResnetBlock_" in name or "concatenate" in name)
+    assert in_block <= passes * h_bytes * 1.01, in_block / h_bytes
 
 
 # The third and fourth token trunks' sequence operators at the shapes

@@ -937,50 +937,9 @@ def make_cond_cache_trace(conds, args, rate: float) -> list:
     return trace
 
 
-def _attention_coverage_probe(cfg, sidelength: int) -> dict:
-    """Untimed: one forward of the bench backbone with cross-frame
-    attention at the bottleneck and use_serving_attention=True, so the
-    artifact records WHICH serving attention shapes ran the fused
-    kernel vs the XLA fallback (ops/serving_attention.py's per-shape
-    coverage registry). The timed A/B stays attention-free (see
-    cond_cache_bench); this probe is the kernel-coverage evidence that
-    rides the same artifact."""
-    import dataclasses as _dc
-
-    from novel_view_synthesis_3d_tpu.data.synthetic import make_example_batch
-    from novel_view_synthesis_3d_tpu.models import build_denoiser
-    from novel_view_synthesis_3d_tpu.ops.serving_attention import (
-        attention_coverage, reset_attention_coverage)
-
-    bottleneck = sidelength // (2 ** (len(cfg.model.ch_mult) - 1))
-    mcfg = _dc.replace(cfg.model, attn_resolutions=(bottleneck,),
-                       use_serving_attention=True)
-    model = build_denoiser(mcfg)
-    raw = make_example_batch(batch_size=2, sidelength=sidelength, seed=1)
-    mb = {
-        "x": jnp.asarray(raw["x"]), "z": jnp.asarray(raw["target"]),
-        "logsnr": jnp.zeros((2,)),
-        "R1": jnp.asarray(raw["R1"]), "t1": jnp.asarray(raw["t1"]),
-        "R2": jnp.asarray(raw["R2"]), "t2": jnp.asarray(raw["t2"]),
-        "K": jnp.asarray(raw["K"]),
-    }
-    params = model.init(
-        {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
-        mb, cond_mask=jnp.ones((2,)), train=False)["params"]
-    reset_attention_coverage()
-    out = model.apply({"params": params}, mb, cond_mask=jnp.ones((2,)),
-                      train=False)
-    jax.block_until_ready(out)
-    return {
-        f"B{b}_Lq{lq}_Lk{lk}_H{h}_D{d}_{dt}": mode
-        for (b, lq, lk, h, d, dt), mode
-        in sorted(attention_coverage().items())
-    }
-
-
 def cond_cache_bench(model, params, cfg, conds, args) -> dict:
     """The judged --cond-cache scenario (docs/DESIGN.md "Conditioning
-    cache & fused serving attention").
+    cache").
 
     ONE deterministic mixed Poisson trace (single-shot requests plus
     orbits, --cc-steps denoise steps each) runs through two services
@@ -1010,9 +969,7 @@ def cond_cache_bench(model, params, cfg, conds, args) -> dict:
     and emb_ch raised (--cc-emb-ch) so the conditioning branch is a
     production-shaped ~25%+ of step time: tiny CPU stand-in models
     undersize the cond branch relative to the real checkpoints, and
-    cross-frame attention here would only re-dilute what the fused
-    serving-attention kernel (TPU-only; coverage probe below) wins
-    back on real hardware."""
+    cross-frame attention here would only dilute it."""
     from novel_view_synthesis_3d_tpu.config import ServeConfig
     from novel_view_synthesis_3d_tpu.sample.service import (
         Rejected, SamplingService)
@@ -1168,8 +1125,6 @@ def cond_cache_bench(model, params, cfg, conds, args) -> dict:
     result["speedup"] = round(
         result["on"]["row_steps_per_sec"]
         / max(result["off"]["row_steps_per_sec"], 1e-9), 3)
-    result["attention_coverage"] = _attention_coverage_probe(
-        cfg, args.cc_sidelength)
     return result
 
 
@@ -1210,11 +1165,6 @@ def check_cond_cache(cc: dict) -> int:
               f"{cc['off']['row_steps_per_sec']} row-steps/s) — the "
               "acceptance bar is 1.3x on the same trace",
               file=sys.stderr)
-        rc = 1
-    if not cc["attention_coverage"]:
-        print("error: the serving-attention coverage probe recorded no "
-              "shapes — the fused-attention evidence is missing from "
-              "the artifact", file=sys.stderr)
         rc = 1
     return rc
 
@@ -3020,8 +2970,7 @@ def main() -> int:
                          "config otherwise), asserting full delivery, "
                          "zero warm recompiles on BOTH lanes, and >= "
                          "1.3x delivered row-steps/s (rc=1 on "
-                         "violation); the artifact also carries the "
-                         "fused serving-attention coverage table")
+                         "violation)")
     ap.add_argument("--cc-requests", type=int, default=14,
                     help="arrivals in the --cond-cache trace (both "
                          "lanes replay it)")

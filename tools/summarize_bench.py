@@ -391,9 +391,7 @@ def trajectory_serving_lines(rows):
 def cond_cache_lines(rows):
     """Tables for serve_bench --cond-cache artifacts: the cached vs
     re-encode-every-step lanes with the cache-hit attribution
-    (hits/misses/resident bytes from the service's cond_cache summary)
-    and the fused serving-attention coverage table — which attention
-    shapes ran the Pallas kernel vs the XLA fallback."""
+    (hits/misses/resident bytes from the service's cond_cache summary)."""
     lines = []
     for name, d in rows:
         cc = d.get("cond_cache")
@@ -436,15 +434,6 @@ def cond_cache_lines(rows):
                     deltas.get("jit_cache_entries"),
                     deltas.get("encode_jit_entries"),
                     "ok" if ln.get("delivery_ok") else "INCOMPLETE"))
-        cov = cc.get("attention_coverage") or {}
-        lines += ["", "### Fused serving-attention coverage", ""]
-        if cov:
-            lines += ["| shape | path |", "|---|---|"]
-            for shape, mode in sorted(cov.items()):
-                lines.append(f"| {shape} | {mode} |")
-        else:
-            lines.append("- none recorded — SKIPPED: the coverage probe "
-                         "left no shapes in the registry")
     return lines
 
 
@@ -983,8 +972,7 @@ def main() -> int:
     lines += precision_sweep_lines(rows)
     # Ring-native vs naive orbit serving for --trajectory artifacts.
     lines += trajectory_serving_lines(rows)
-    # Conditioning-cache A/B + fused-attention coverage for --cond-cache
-    # artifacts.
+    # Conditioning-cache A/B for --cond-cache artifacts.
     lines += cond_cache_lines(rows)
     # Survivability drill tables for any --chaos artifacts.
     lines += chaos_lines(rows)
