@@ -345,11 +345,80 @@ class OlmoHybridTrunkConfig:
         return self.layer_types[i] == "full_attention"
 
 
+@dataclasses.dataclass(frozen=True)
+class LongcatFlashTrunkConfig:
+    """The token denoiser's sixth trunk: LongCat-Flash's decoder stack
+    (LongCat-Flash-Omni's language model) under the key names of its
+    `config.json` — the shortcut-connected DOUBLE layer: `num_layers`
+    counts layers of two sublayers each, a latent attention (low-rank
+    queries, a compressed key/value latent, one shared rotary key head;
+    both latents SCALED after their norms, `mla_scale_q_lora` /
+    `mla_scale_kv_lora`: × (hidden_size / rank)^½) and a dense gated-SiLU
+    MLP of `ffn_hidden_size`, twice; the expert branch reads the FIRST
+    attention's normalised output and joins the residual after the SECOND
+    MLP. Its router has `n_routed_experts + zero_expert_num` outputs: the
+    last `zero_expert_num` ids are identities ("zero-compute" experts) that
+    return the router's input. Softmax scores over all outputs, the choice
+    on score + a per-output correction bias, the gate the score alone ×
+    `routed_scaling_factor`, not renormalised. A layer's cache of a frame
+    is its TWO latents. The defaults are the published values; a preset
+    sets the depth and the experts this chip holds."""
+
+    hidden_size: int = 6144
+    num_layers: int = 28           # double layers, as published
+    num_attention_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+    attention_method: str = "MLA"
+    attention_bias: bool = False
+    ffn_hidden_size: int = 12288
+    expert_ffn_hidden_size: int = 2048
+    # The REAL experts; the router is `zero_expert_num` outputs wider.
+    n_routed_experts: int = 512
+    zero_expert_num: int = 256
+    zero_expert_type: str = "identity"
+    moe_topk: int = 12
+    routed_scaling_factor: float = 6.0
+    rope_theta: float = 10000000
+    rms_norm_eps: float = 1e-5
+    # As TokenTrunkConfig's: the (first, count) REAL experts this chip
+    # holds, and the patch adapter.
+    held_experts: Tuple[int, int] = (0, 512)
+    patch_size: int = 4
+
+    # The frame's and the expert layer's names for the same things.
+    expert_activation = "silu"
+    router_activation = "softmax"
+    norm_topk_prob = False
+    rope_interleave = True
+
+    @property
+    def num_hidden_layers(self) -> int:
+        return self.num_layers
+
+    @property
+    def num_experts_per_tok(self) -> int:
+        return self.moe_topk
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts + self.zero_expert_num
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
 # The trunks `ModelConfig.tokens` may hold; a serialized config says
 # which by its keys (they share only sizes every trunk has).
 TOKEN_TRUNKS = (TokenTrunkConfig, SmallThinkerTrunkConfig,
                 KimiLinearTrunkConfig, Phi4FlashTrunkConfig,
-                OlmoHybridTrunkConfig)
+                OlmoHybridTrunkConfig, LongcatFlashTrunkConfig)
 
 
 def _trunk_of_keys(keys) -> type:
@@ -2120,6 +2189,24 @@ def _olmo_hybrid_errors(k: OlmoHybridTrunkConfig) -> list:
     return errors
 
 
+def _longcat_flash_errors(k: LongcatFlashTrunkConfig) -> list:
+    """What the double layer and its wider router need of the settings."""
+    errors = []
+    if k.qk_rope_head_dim % 2:
+        errors.append("model.tokens.qk_rope_head_dim must be even (rotary "
+                      "pairs)")
+    if k.attention_method != "MLA" or k.attention_bias:
+        errors.append("model.tokens.attention_method other than 'MLA' and "
+                      "attention_bias=True are not carried")
+    if k.zero_expert_num < 0 or k.zero_expert_type != "identity":
+        errors.append("model.tokens.zero_expert_type other than 'identity' "
+                      "(and a negative zero_expert_num) is not carried")
+    if not 1 <= k.moe_topk <= k.router_width:
+        errors.append("model.tokens.moe_topk must be in [1, "
+                      "n_routed_experts + zero_expert_num]")
+    return errors
+
+
 def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
     """What a `family: tokens` model needs of its settings."""
     k = m.tokens
@@ -2138,7 +2225,9 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
                 f"model.tokens.held_experts={tuple(k.held_experts)} is not "
                 f"a (first, count) range inside the router's "
                 f"{k.n_routed_experts} experts")
-        if not 1 <= k.num_experts_per_tok <= k.n_routed_experts:
+        if isinstance(k, LongcatFlashTrunkConfig):
+            errors += _longcat_flash_errors(k)
+        elif not 1 <= k.num_experts_per_tok <= k.n_routed_experts:
             errors.append("model.tokens.num_experts_per_tok must be in "
                           "[1, n_routed_experts]")
     if d.img_sidelength % k.patch_size:
@@ -2192,7 +2281,7 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
 # ----------------------------------------------------------------------
 PRESET_NAMES = ("reference", "tiny64", "base128", "paper256", "pod64",
                 "ms4_denoiser128", "st21_denoiser256", "kl48_denoiser256",
-                "p4f_denoiser256", "oh7_denoiser256")
+                "p4f_denoiser256", "oh7_denoiser256", "lcf_denoiser256")
 
 
 def get_preset(name: str) -> Config:
@@ -2350,6 +2439,26 @@ def get_preset(name: str) -> Config:
                 family="tokens", dtype="bfloat16", param_dtype="bfloat16",
                 dropout=0.0,
                 tokens=OlmoHybridTrunkConfig(num_hidden_layers=16)),
+            data=DataConfig(img_sidelength=256),
+            diffusion=DiffusionConfig(sample_timesteps=256),
+        )
+    if name == "lcf_denoiser256":
+        # A token denoiser whose trunk is LongCat-Flash-Omni's decoder
+        # stack at its published widths (LongcatFlashTrunkConfig's
+        # defaults), cut to chip 0 of stage 0 of a deployment in which 32
+        # chips share each layer by expert parallelism (16 of 512 real
+        # experts a chip; attention and the dense MLPs replicated) and
+        # seven pipeline stages hold 4 double layers each: layers 0-3 of
+        # 28, experts 0-15 held (the router keeps its 768 outputs, of
+        # which 256 are identities, and top-12). bfloat16 parameters:
+        # 1.243 B a layer, 4.97 B = 9.94 GB. 256 px, 4096 tokens a frame:
+        # 8192 keys a target query in each of a layer's two attentions.
+        return Config(
+            model=ModelConfig(
+                family="tokens", dtype="bfloat16", param_dtype="bfloat16",
+                dropout=0.0,
+                tokens=LongcatFlashTrunkConfig(num_layers=4,
+                                               held_experts=(0, 16))),
             data=DataConfig(img_sidelength=256),
             diffusion=DiffusionConfig(sample_timesteps=256),
         )

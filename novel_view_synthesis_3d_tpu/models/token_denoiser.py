@@ -1,7 +1,7 @@
 """A token denoiser: patch tokens of both frames through a decoder trunk
 of a published language model, ε̂ of the target frame out.
 
-**Five trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
+**Six trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
 and names the layers; the frame asks the layer object for layer i's
 parameter tree and takes back layer i's cache entry — or None, from a layer
 that keeps nothing of a frame — so a trunk's layers may differ by index,
@@ -70,10 +70,23 @@ same pass (one trunk's; the others' calls do not take it):
     here does: NO norm on a sublayer's input — its OUTPUT is normalised
     inside the residual, h + Norm(Mixer(h)), h + Norm(MLP(h)).
 
+  - `LongcatFlashLayer` (config.LongcatFlashTrunkConfig; LongCat-Flash-
+    Omni's language model): the shortcut-connected DOUBLE layer — latent
+    attention with rotary (64 heads, a 1536-wide query latent, 192-wide
+    keys on 128-wide values, both latents scaled after their norms), a
+    12288-wide dense MLP, a second latent attention, a second dense MLP,
+    and one expert branch ACROSS them: it reads the first attention's
+    normalised output and joins the residual after the second MLP. Its
+    router is wider than its experts: 512 + 256 outputs, the last 256
+    IDENTITY experts that return the router's input; softmax scores, the
+    choice on score + a correction bias, the gate 6 × the score, not
+    renormalised — a token's twelve choices hold 0 to 12 real experts.
+    Its cache of a frame is TWO latents a layer.
+
 `route` and `held_expert_part` are one function each for all that route
-(the scoring function, top-k, the renormalisation and the activation come
-from the trunk's config), as are the grouped product and the attention
-kernel under them. What is this
+(the scoring function, the choice's bias, top-k, the renormalisation and
+the activation come from the trunk's config and its router's parameters),
+as are the grouped product and the attention kernel under them. What is this
 repo's and not a source's is the frame around the trunk:
 
   - both frames are cut into `patch_size`² patches, one token each:
@@ -91,13 +104,13 @@ repo's and not a source's is the frame around the trunk:
 **The once-a-call pass.** Because of that mask, everything a step needs of
 the conditioning frame is its per-layer cache. `precompute` runs the
 conditioning frame once (prefill) through the layers UP TO THE LAST THAT
-KEEPS A CACHE ENTRY — all of them in four trunks, layers 0–17 of the
+KEEPS A CACHE ENTRY — all of them in five trunks, layers 0–17 of the
 fourth's 32: nothing its cross-decoder computes of that frame is ever
 read, and the pass is built without it, not left to the compiler to cut —
 and every denoise step runs the target's tokens alone against [cache ;
 own], or from the cached state (decode through the cache). `apply`
 without a cache does exactly the two in a row, so there is one set of
-equations. What a trunk makes of its PARAMETERS alone (`derive`: the two
+equations. What a trunk makes of its PARAMETERS alone (`derive`: the three
 latent trunks' kernels that write q, keys and values where the attention
 kernel reads them) is made in the same pass and handed on beside the
 cache, under `derived`; `apply` without that entry makes it itself.
@@ -127,8 +140,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from novel_view_synthesis_3d_tpu.config import (
-    KimiLinearTrunkConfig, ModelConfig, OlmoHybridTrunkConfig,
-    Phi4FlashTrunkConfig, SmallThinkerTrunkConfig, TokenTrunkConfig)
+    KimiLinearTrunkConfig, LongcatFlashTrunkConfig, ModelConfig,
+    OlmoHybridTrunkConfig, Phi4FlashTrunkConfig, SmallThinkerTrunkConfig,
+    TokenTrunkConfig)
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 from novel_view_synthesis_3d_tpu.ops.expert_combine import combine
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
@@ -228,7 +242,7 @@ def op_groups(cfg: ModelConfig):
 
 def param_shapes(cfg: ModelConfig) -> dict:
     """The parameter tree as ShapeDtypeStructs. 2-D kernels are (in, out);
-    an expert stack is (held, in, out). Four trunks have no bias at all
+    an expert stack is (held, in, out). Five trunks have no bias at all
     (a router's correction bias apart); Phi4FlashLayer's has LayerNorm
     weights AND biases, and biases on its attention projections, its
     convolution and its step projection."""
@@ -437,6 +451,60 @@ def latent_keys_values(c_kv, k_shared, k_b, v_b, heads: int):
     return keys.reshape(B, Lk, heads, -1), values.reshape(B, Lk, heads, -1)
 
 
+def low_rank_queries(cfg, p, a, cos, sin, scale=1.0):
+    """Queries (B, L, heads·(dn + dr)) of normalised tokens `a` through the
+    query latent: c_q = RMSNorm(a·q_a) — times `scale` where the trunk
+    scales the latent after its norm —, then `rotated_queries` of it."""
+    k = cfg.tokens
+    c_q = rms_norm(_dense(a, p["q_a"]), p["q_norm"]["scale"], k.rms_norm_eps)
+    if scale != 1.0:
+        c_q = c_q * scale
+    return rotated_queries(c_q.astype(a.dtype), p["q_b"], p["q_b_pair"],
+                           k.num_attention_heads, cos, sin, k.rope_interleave)
+
+
+def latent_attention(cfg, p, h, a, q, cache, scale, rope=None,
+                     kv_scale=1.0):
+    """h + W_o · attention of the queries `q` (B, L, heads·(dn + dr), made
+    by the caller from `a` its trunk's way) over keys and values
+    up-projected at use from the latent of the normalised tokens `a` (the
+    form a chip run chose over absorbed weights; PERF.md, PR 26): c_kv =
+    RMSNorm of a·kv_a's first `kv_lora_rank` lanes, times `kv_scale` where
+    the trunk scales the latent after its norm; the lanes past them are
+    the key part all heads share, rotated by `rope` = (cos, sin) where the
+    trunk has a positional term and never scaled. `cache` = (c_kv, shared
+    key part) of the frames before, as this returns them for this frame:
+    → (h, (c_kv, shared key part)). Every trunk with a latent cache runs
+    this one function, under the stamps `lk.mla_proj` and `lk.mla_core`."""
+    k = cfg.tokens
+    dt, eps = jnp.dtype(cfg.dtype), k.rms_norm_eps
+    B, L, _ = h.shape
+    NH = k.num_attention_heads
+    with jax.named_scope("lk.mla_proj"):
+        q = q.reshape(B, L, NH, -1)
+        kv_a = _dense(a, p["kv_a"])
+        c_kv = rms_norm(kv_a[..., :k.kv_lora_rank], p["kv_norm"]["scale"],
+                        eps)
+        if kv_scale != 1.0:
+            c_kv = c_kv * kv_scale
+        c_kv = c_kv.astype(dt)
+        k_shared = kv_a[..., k.kv_lora_rank:]
+        if rope is not None:
+            k_shared = apply_rope(k_shared, *rope, k.rope_interleave)
+        own = (c_kv, k_shared)
+        if cache is not None:
+            c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
+            k_shared = jnp.concatenate([cache[1].astype(dt), k_shared],
+                                       axis=1)
+        keys, values = latent_keys_values(c_kv, k_shared, p["k_b"],
+                                          p["v_b"], NH)
+    with jax.named_scope("lk.mla_core"):
+        o = _attention(q, keys, values, scale,
+                       resolve_flash(cfg.use_flash_attention))
+    with jax.named_scope("lk.mla_proj"):
+        return h + _dense(o.reshape(B, L, -1), p["o"]), own
+
+
 def _attention(q, k, v, scale, use_flash, window=None):
     """softmax(q·kᵀ·scale)·v, softmax in float32. q (B, Lq, N, D), k (B,
     Lk, Nkv, D), v (B, Lk, Nkv, Dv), query head n on key/value head n //
@@ -471,26 +539,31 @@ _ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def route(b32, p_router, k):
-    """(top-k gates (T, k) float32, expert ids (T, k) int32) of normalised
-    tokens b32 (T, H) float32, scored over ALL experts in float32 as the
-    trunk's `router_activation` says. "softmax": the probabilities' top-k,
-    renormalised to sum 1 where the config says so (the same numbers as a
-    softmax over the chosen logits alone). "sigmoid": each expert's score
-    on its own; the CHOICE is the top-k of score + the router's per-expert
-    correction bias, the gate the score without it, renormalised and
-    scaled as before. `k` is any trunk's config."""
+    """(top-k gates (T, k) float32, ids (T, k) int32) of normalised tokens
+    b32 (T, H) float32, scored in float32 over ALL the router's outputs —
+    as many as its kernel has columns: a trunk's router may be wider than
+    its experts (ids past `n_routed_experts` are that trunk's to read;
+    `held_expert_part` gives them no row). Three things, each on its own:
+    the SCORES are the trunk's `router_activation` of the logits, "softmax"
+    over all outputs or "sigmoid", each output on its own; the CHOICE is
+    the top-k of score + the router's per-output correction bias where it
+    has one (`p_router["bias"]`), of the score where it has none; the GATE
+    is the chosen's score without the bias, renormalised to sum 1 where
+    `norm_topk_prob` says so (for a softmax without a bias the same
+    numbers as a softmax over the chosen logits alone), times
+    `routed_scaling_factor`. `k` is any trunk's config."""
     with jax.named_scope("pt.matmul"):
         logits = jnp.dot(b32, p_router["kernel"].astype(jnp.float32),
                          precision=HIGHEST)
-    if k.router_activation == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.sigmoid(logits) if k.router_activation == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    if "bias" in p_router:
         _, top_i = jax.lax.top_k(
             scores + p_router["bias"].astype(jnp.float32),
             k.num_experts_per_tok)
         top_p = jnp.take_along_axis(scores, top_i, axis=-1)
     else:
-        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                     k.num_experts_per_tok)
+        top_p, top_i = jax.lax.top_k(scores, k.num_experts_per_tok)
     if k.norm_topk_prob:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     return top_p * float(k.routed_scaling_factor), top_i.astype(jnp.int32)
@@ -511,8 +584,9 @@ def held_expert_part(b, top_p, top_i, p_experts, k):
     behind the buffer's spare rows, of which each held expert's first
     span − size are keyed to it as pad and the rest to the absent. Every
     assignment to a held expert has exactly one row (no capacity: the
-    buffer covers all T·k landing here); assignments to absent experts lie
-    past the last span. Pad rows hold token 0's copy, rows past the last
+    buffer covers all T·k landing here); assignments to absent experts —
+    and to ids past the real experts, a wider router's — lie past the last
+    span. Pad rows hold token 0's copy, rows past the last
     span are never written; the combine reads neither."""
     with jax.named_scope("lk.moe_route"):
         first, count = k.held_experts
@@ -550,6 +624,16 @@ def held_expert_part(b, top_p, top_i, p_experts, k):
         w = jnp.where(is_held.reshape(T, K), top_p, 0.0)
         out = combine(y, back, w, slot, group_sizes, b.dtype)
     return out, group_sizes
+
+
+def identity_part(b, top_p, top_i, k):
+    """b · Σ of the gates of a token's choices that are IDENTITY experts
+    (ids from `n_routed_experts` on: "zero-compute" experts that return
+    the router's input): token-local, no row and no product, so every chip
+    of a deployment computes it whole for its own tokens. b (T, H), → (T,
+    H) in b's type."""
+    w = jnp.sum(jnp.where(top_i >= k.n_routed_experts, top_p, 0.0), axis=-1)
+    return (b.astype(jnp.float32) * w[:, None]).astype(b.dtype)
 
 
 def gated_mlp(x, p):
@@ -649,37 +733,14 @@ class Mistral4Layer:
         dt = jnp.dtype(cfg.dtype)
         eps = k.rms_norm_eps
         B, L, _ = h.shape
-        NH, dn, dr, dv = (k.num_attention_heads, k.qk_nope_head_dim,
-                          k.qk_rope_head_dim, k.v_head_dim)
         cos, sin, qscale = tables
         with jax.named_scope("lk.mla_proj"):
             a = rms_norm(h, p["attn_norm"]["scale"], eps).astype(dt)
-            c_q = rms_norm(_dense(a, p["q_a"]), p["q_norm"]["scale"],
-                           eps).astype(dt)
-            q = rotated_queries(c_q, p["q_b"], p["q_b_pair"], NH, cos, sin,
-                                k.rope_interleave)
+            q = low_rank_queries(cfg, p, a, cos, sin)
             if np.any(qscale != 1.0):
                 q = q * jnp.asarray(qscale, dt)[None, :, None]
-            q = q.reshape(B, L, NH, dn + dr)
-            kv_a = _dense(a, p["kv_a"])
-            c_kv = rms_norm(kv_a[..., :k.kv_lora_rank],
-                            p["kv_norm"]["scale"], eps).astype(dt)
-            k_rope = apply_rope(kv_a[..., k.kv_lora_rank:], cos, sin,
-                                k.rope_interleave)
-            own = (c_kv, k_rope)
-            if cache is not None:
-                c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
-                k_rope = jnp.concatenate([cache[1].astype(dt), k_rope],
-                                         axis=1)
-            # Keys and values up-projected from the latent (the form a
-            # chip run chose over absorbed weights; PERF.md, PR 26).
-            keys, values = latent_keys_values(c_kv, k_rope, p["k_b"],
-                                              p["v_b"], NH)
-        with jax.named_scope("lk.mla_core"):
-            o = _attention(q, keys, values, softmax_scale(k),
-                           resolve_flash(cfg.use_flash_attention))
-        with jax.named_scope("lk.mla_proj"):
-            h = h + _dense(o.reshape(B, L, NH * dv), p["o"])
+        h, own = latent_attention(cfg, p, h, a, q, cache, softmax_scale(k),
+                                  rope=(cos, sin))
         with jax.named_scope("lk.moe_route"):
             b32 = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(B * L, -1)
             top_p, top_i = route(b32, p["router"], k)
@@ -901,35 +962,15 @@ class KimiLinearLayer:
             k.qk_rope_head_dim)}
 
     def _mla(self, layer, h, cache):
-        """h + latent attention, without a positional term, of RMSNorm(h)
-        over one frame's tokens; `cache` = (c_kv, the shared key part) of
-        the frames before."""
+        """h + latent attention, without a positional term and with
+        full-rank queries, of RMSNorm(h) over one frame's tokens; `cache` =
+        (c_kv, the shared key part) of the frames before."""
         cfg, k, p = self.config, self.config.tokens, layer["mla"]
-        dt, eps = jnp.dtype(cfg.dtype), k.rms_norm_eps
-        B, L, _ = h.shape
-        NH, dn, dr, dv = (k.num_attention_heads, k.qk_nope_head_dim,
-                          k.qk_rope_head_dim, k.v_head_dim)
         with jax.named_scope("lk.mla_proj"):
-            a = rms_norm(h, layer["attn_norm"]["scale"], eps).astype(dt)
-            q = _dense(a, p["q"]).reshape(B, L, NH, dn + dr)
-            kv_a = _dense(a, p["kv_a"])
-            c_kv = rms_norm(kv_a[..., :k.kv_lora_rank],
-                            p["kv_norm"]["scale"], eps).astype(dt)
-            k_pe = kv_a[..., k.kv_lora_rank:]       # used as it is: NoPE
-            own = (c_kv, k_pe)
-            if cache is not None:
-                c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
-                k_pe = jnp.concatenate([cache[1].astype(dt), k_pe], axis=1)
-            # Keys and values up-projected from the latent at use, as
-            # Mistral4Layer does (PERF.md, PR 26).
-            keys, values = latent_keys_values(c_kv, k_pe, p["k_b"],
-                                              p["v_b"], NH)
-        with jax.named_scope("lk.mla_core"):
-            o = _attention(q, keys, values, (dn + dr) ** -0.5,
-                           resolve_flash(cfg.use_flash_attention))
-        with jax.named_scope("lk.mla_proj"):
-            h = h + _dense(o.reshape(B, L, NH * dv), p["o"])
-        return h, own
+            a = rms_norm(h, layer["attn_norm"]["scale"],
+                         k.rms_norm_eps).astype(jnp.dtype(cfg.dtype))
+            q = _dense(a, p["q"])
+        return latent_attention(cfg, p, h, a, q, cache, k.qk_head_dim ** -0.5)
 
     def __call__(self, i, p, h, tables, cache):
         del tables
@@ -1303,11 +1344,131 @@ class OlmoHybridLayer:
         return 0, 0
 
 
+class LongcatFlashLayer:
+    """LongCat-Flash's shortcut-connected double layer: latent attention
+    (low-rank queries and a key/value latent, both scaled after their
+    norms, one shared rotary key head), a dense MLP, latent attention, a
+    dense MLP — each with its own norm and weights — and ONE expert branch
+    across them: it reads the first attention's normalised output b (the
+    first MLP's input) and its result m joins the residual after the
+    second MLP,
+
+        h₁ = h + MLA₀(N(h));  b = N(h₁);  m = MoE(b);  h₂ = h₁ + MLP₀(b)
+        h₃ = h₂ + MLA₁(N(h₂));  h_out = h₃ + MLP₁(N(h₃)) + m.
+
+    MoE(b): softmax scores over `n_routed_experts + zero_expert_num`
+    outputs, the choice on score + bias, the gate 6 × the score; a choice
+    under `n_routed_experts` is a gated-SiLU expert (the held ones computed
+    here, `held_expert_part`), one past it an identity that returns b
+    (`identity_part`, whole). Both sublayers and the branch are ONE
+    `__call__`: `num_layers` keeps the source's meaning, layer i's cache
+    entry is its two latents ((c_kv, k_rope), (c_kv, k_rope)), and m lives
+    inside the call — nothing for the frame to carry between layers."""
+
+    cache_name = "layer_cache"
+    has_experts, publishes = True, False
+
+    def __init__(self, config: ModelConfig):
+        self.config = config
+
+    def cache_kind(self, i):
+        return "latent"
+
+    def param_shapes(self, w, i=0):
+        """Every layer's tree is the same."""
+        k = self.config.tokens
+        H, NH = k.hidden_size, k.num_attention_heads
+
+        def mla():
+            return {
+                "norm": {"scale": w(H)},
+                "q_a": {"kernel": w(H, k.q_lora_rank)},
+                "q_norm": {"scale": w(k.q_lora_rank)},
+                "q_b": {"kernel": w(k.q_lora_rank, NH * k.qk_head_dim)},
+                "kv_a": {"kernel": w(H, k.kv_lora_rank
+                                     + k.qk_rope_head_dim)},
+                "kv_norm": {"scale": w(k.kv_lora_rank)},
+                "kv_b": {"kernel": w(k.kv_lora_rank, NH * (
+                    k.qk_nope_head_dim + k.v_head_dim))},
+                "o": {"kernel": w(NH * k.v_head_dim, H)}}
+
+        return {"mla_0": mla(), "mla_1": mla(),
+                "mlp_norm_0": {"scale": w(H)}, "mlp_norm_1": {"scale": w(H)},
+                "mlp_0": _mlp_shapes(w, H, k.ffn_hidden_size),
+                "mlp_1": _mlp_shapes(w, H, k.ffn_hidden_size),
+                "router": {"kernel": w(H, k.router_width),
+                           "bias": w(k.router_width)},
+                "experts": _mlp_shapes(w, H, k.expert_ffn_hidden_size,
+                                       k.held_experts[1])}
+
+    def tables(self, positions):
+        k = self.config.tokens
+        return plain_rope_tables(positions, k.qk_rope_head_dim, k.rope_theta)
+
+    def derive(self, i, p):
+        """Both attentions' kernels that write q, the keys and the values
+        where the attention kernel reads them."""
+        k = self.config.tokens
+        NH, dn = k.num_attention_heads, k.qk_nope_head_dim
+        return {n: {"q_b_pair": pair_swapped_kernel(
+            p[n]["q_b"], NH, dn, k.rope_interleave), **latent_kernels(
+                p[n]["kv_b"], NH, dn, k.qk_rope_head_dim)}
+                for n in ("mla_0", "mla_1")}
+
+    def _mla(self, p, h, tables, cache):
+        """h + latent attention of RMSNorm(h) over one frame's tokens, both
+        latents scaled AFTER their norms; `cache` = this attention's (c_kv
+        after its scale, rotated shared key) of the frames before. → (h,
+        this frame's)."""
+        cfg, k = self.config, self.config.tokens
+        H = k.hidden_size
+        q_scale = (H / k.q_lora_rank) ** 0.5 if k.mla_scale_q_lora else 1.0
+        kv_scale = (H / k.kv_lora_rank) ** 0.5 if k.mla_scale_kv_lora \
+            else 1.0
+        with jax.named_scope("lk.mla_proj"):
+            a = rms_norm(h, p["norm"]["scale"],
+                         k.rms_norm_eps).astype(jnp.dtype(cfg.dtype))
+            q = low_rank_queries(cfg, p, a, *tables, scale=q_scale)
+        return latent_attention(cfg, p, h, a, q, cache,
+                                k.qk_head_dim ** -0.5, rope=tables,
+                                kv_scale=kv_scale)
+
+    def __call__(self, i, p, h, tables, cache):
+        """`cache` is layer i's pair of latents of the frames before."""
+        del i  # every layer is the same
+        k = self.config.tokens
+        dt, eps = jnp.dtype(self.config.dtype), k.rms_norm_eps
+        B, L, _ = h.shape
+        cache_0, cache_1 = (None, None) if cache is None else cache
+        h, own_0 = self._mla(p["mla_0"], h, tables, cache_0)
+        with jax.named_scope("lk.moe_route"):
+            b32 = rms_norm(h, p["mlp_norm_0"]["scale"], eps).reshape(
+                B * L, -1)
+            top_p, top_i = route(b32, p["router"], k)
+            b = b32.astype(dt)
+        routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
+        with jax.named_scope("lk.moe_zero"):
+            m = (routed + identity_part(b, top_p, top_i, k)).reshape(
+                B, L, -1)
+        with jax.named_scope("lk.dense_mlp"):
+            h = h + gated_mlp(b, p["mlp_0"]).reshape(B, L, -1)
+        h, own_1 = self._mla(p["mla_1"], h, tables, cache_1)
+        with jax.named_scope("lk.dense_mlp"):
+            b_1 = rms_norm(h, p["mlp_norm_1"]["scale"], eps).astype(dt)
+            h = h + gated_mlp(b_1, p["mlp_1"]) + m     # the branch joins
+        return h, (own_0, own_1), (counts, top_i.reshape(B, L, -1))
+
+    def key_columns(self, L: int):
+        """No layer of this trunk has a window."""
+        return 0, 0
+
+
 TRUNK_LAYERS = {TokenTrunkConfig: Mistral4Layer,
                 SmallThinkerTrunkConfig: SmallThinkerLayer,
                 KimiLinearTrunkConfig: KimiLinearLayer,
                 Phi4FlashTrunkConfig: Phi4FlashLayer,
-                OlmoHybridTrunkConfig: OlmoHybridLayer}
+                OlmoHybridTrunkConfig: OlmoHybridLayer,
+                LongcatFlashTrunkConfig: LongcatFlashLayer}
 
 
 def laid_over(p: dict, d: dict) -> dict:

@@ -72,6 +72,17 @@ SSM_TOKEN_LAYER_KINDS = ("ssm_proj", "ssm_conv", "ssm_core", "gqa_proj",
 GDN_TOKEN_LAYER_KINDS = ("gdn_proj", "gdn_conv", "gdn_core", "gqa_proj",
                          "attn_full", "dense_mlp", "patch", "emb", "pose",
                          "update")
+# The token family's sixth trunk (LongCat-Flash's shortcut-connected double
+# layer): each of a layer's two latent attentions stamps as the first
+# trunk's (`mla_proj`, `mla_core`), each of its two dense MLPs (the second
+# with the add that joins the expert branch) as `dense_mlp`, the router and
+# the sort as `moe_route`, the held experts' products and the combine as
+# `moe_experts`, and what the chosen IDENTITY experts give — the router's
+# input times the sum of their gates, token-local, with its sum onto the
+# held experts' part — as `moe_zero`. No shared expert.
+SCMOE_TOKEN_LAYER_KINDS = ("mla_proj", "mla_core", "dense_mlp", "moe_route",
+                           "moe_experts", "moe_zero", "patch", "emb", "pose",
+                           "update")
 # Every kind a `jax.named_scope("lk.<kind>")` may stamp. The stamps sit
 # where the work happens (models/layers.py, models/xunet.py,
 # models/token_denoiser.py, sample/ddpm.py); these tuples and layer_of are
@@ -79,7 +90,7 @@ GDN_TOKEN_LAYER_KINDS = ("gdn_proj", "gdn_conv", "gdn_core", "gqa_proj",
 LAYER_KINDS = tuple(dict.fromkeys(
     XUNET_LAYER_KINDS + TOKEN_LAYER_KINDS + GQA_TOKEN_LAYER_KINDS
     + KDA_TOKEN_LAYER_KINDS + SSM_TOKEN_LAYER_KINDS
-    + GDN_TOKEN_LAYER_KINDS))
+    + GDN_TOKEN_LAYER_KINDS + SCMOE_TOKEN_LAYER_KINDS))
 # Every part a `jax.named_scope("pt.<part>")` may stamp inside a kind
 # (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py, ops/gdn.py,
 # ops/ssm.py, ops/short_conv.py, ops/head_norm.py, ops/expert_combine.py,

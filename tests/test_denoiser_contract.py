@@ -97,11 +97,28 @@ TINY_GDN_TOKENS = {
     "model.dtype": "float32", "model.param_dtype": "float32",
     "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
 }
+# The sixth token trunk (LongCat-Flash's double layer) at toy sizes: two
+# latent attentions and two dense MLPs a layer, a router of 8 real + 4
+# identity outputs, experts 0-3 held.
+TINY_SCMOE_TOKENS = {
+    "model.tokens.hidden_size": 32, "model.tokens.num_layers": 2,
+    "model.tokens.num_attention_heads": 2, "model.tokens.q_lora_rank": 16,
+    "model.tokens.kv_lora_rank": 8, "model.tokens.qk_nope_head_dim": 8,
+    "model.tokens.qk_rope_head_dim": 4, "model.tokens.v_head_dim": 8,
+    "model.tokens.ffn_hidden_size": 48,
+    "model.tokens.expert_ffn_hidden_size": 16,
+    "model.tokens.n_routed_experts": 8, "model.tokens.zero_expert_num": 4,
+    "model.tokens.moe_topk": 3, "model.tokens.held_experts": [0, 4],
+    "data.img_sidelength": 16,
+    "model.dtype": "float32", "model.param_dtype": "float32",
+    "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
+}
 TINY_BY_PRESET = {"ms4_denoiser128": TINY_TOKENS,
                   "st21_denoiser256": TINY_GQA_TOKENS,
                   "kl48_denoiser256": TINY_KDA_TOKENS,
                   "p4f_denoiser256": TINY_SSM_TOKENS,
-                  "oh7_denoiser256": TINY_GDN_TOKENS}
+                  "oh7_denoiser256": TINY_GDN_TOKENS,
+                  "lcf_denoiser256": TINY_SCMOE_TOKENS}
 
 
 def token_cfg(**over) -> Config:

@@ -90,6 +90,17 @@ TOY = {
         "model.tokens.linear_key_head_dim": 32,
         "model.tokens.linear_value_head_dim": 64,
         "data.img_sidelength": 16, "model.use_flash_attention": True},
+    # 192-wide keys on 128-wide values, as its cell's latent attention
+    "lcf_denoiser256": {
+        "model.tokens.hidden_size": 64, "model.tokens.num_layers": 2,
+        "model.tokens.num_attention_heads": 2,
+        "model.tokens.q_lora_rank": 32, "model.tokens.kv_lora_rank": 16,
+        "model.tokens.ffn_hidden_size": 96,
+        "model.tokens.expert_ffn_hidden_size": 32,
+        "model.tokens.n_routed_experts": 16,
+        "model.tokens.zero_expert_num": 8, "model.tokens.moe_topk": 4,
+        "model.tokens.held_experts": [0, 4], "data.img_sidelength": 16,
+        "model.use_flash_attention": True},
 }
 # (preset, "cpu" | "v5e") → sha256 of the lowered text, from the tree of
 # the PR that last meant to change it.
@@ -126,6 +137,13 @@ DIGESTS = {
         "cb2f8f0dade55f98ceb4c906475321c4ab09c7d6f05e5830750ed2f2f13439b8",
     ("oh7_denoiser256", "v5e"):
         "9b558d7285dee20601aff53a5019b4a0caabfbdc05fd28fa9b433037e6be6e29",
+    # PR 44's tree: the sixth trunk, pinned as it landed (the ten above
+    # are the parent's, untouched by `route` taking a bias with either
+    # scoring function)
+    ("lcf_denoiser256", "cpu"):
+        "1984f6731aeaf5301517a79ee8560e4b10920e963a50e78557661d4802b8d660",
+    ("lcf_denoiser256", "v5e"):
+        "9cd675b78e002b06d30879140f3692f2ceb7837f8db2f0a3d19818a18ecc3df5",
 }
 
 

@@ -364,7 +364,8 @@ def test_preset_is_the_published_config_cut_in_depth_alone():
 
 
 def test_token_trunks_are_five_and_read_back_by_their_keys():
-    assert len(TOKEN_TRUNKS) == 5 and TOKEN_TRUNKS[-1] is \
+    # the fifth of them (a sixth came with PR 44, behind it)
+    assert len(TOKEN_TRUNKS) >= 5 and TOKEN_TRUNKS[4] is \
         OlmoHybridTrunkConfig
     seen = set()
     for name in PRESET_NAMES:

@@ -19,6 +19,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
 # is held; another test process doing the same would skip this file.
 os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -307,6 +309,17 @@ CASES = {
     "head_norm_4x4096x4096_h32": _head_norm(4, 4096, 32, 128, "sigmoid"),
     "head_norm_2x4096x5760_h30": _head_norm(2, 4096, 30, 192, "silu"),
     "head_norm_ragged_1x4000x2880_h30": _head_norm(1, 4000, 30, 96, "silu"),
+    # the sixth token trunk: its latent attention at 64 heads (a step's
+    # 8192 keys and the once-a-call pass's 4096), its 16 held experts'
+    # products over the static buffer of a step's 8192 tokens x top-12,
+    # and the combine of twelve choices a token at hidden 6144
+    "flash_fwd_Lq4096_Lk8192_h64_qk192_v128": _latent_attn(4096, 8192, 64,
+                                                            192, 128),
+    "flash_fwd_Lq4096_Lk4096_h64_qk192_v128": _latent_attn(4096, 4096, 64,
+                                                            192, 128),
+    "grouped_matmul_up_6144x2048": _grouped(98304, 16, 6144, 2048),
+    "grouped_matmul_down_2048x6144": _grouped(98304, 16, 2048, 6144),
+    "moe_combine_8192x12x6144": _combine(8192, 12, 6144, 16),
     "flash_fwd_diff_window512_Lq4096_Lk4607_qk64_v128": _diff_attn(
         4096, 4607, 20, 10, 64, 512),
     "flash_fwd_diff_Lq4096_Lk8192_qk64_v128": _diff_attn(
@@ -730,6 +743,54 @@ def test_kda_compiles_and_fits_for_v5e(name, v5e, monkeypatch):
     compiled = jax.jit(fn).lower(*args).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < TEMP_LIMITS[name], mem.temp_size_in_bytes
+
+
+def test_lcf_sampler_fits_the_described_v5e(v5e, monkeypatch):
+    """`make_sampler` of `lcf_denoiser256` at its cell's size (1 view, 8
+    steps, guidance 3) compiled for the described chip: the arguments are
+    the program's own tree (5.06 B bfloat16 parameters, 10.12 GB), the
+    temporaries — the derived kernels of both attentions of every layer,
+    the expert buffer of a step's 98 304 choices at hidden 6144 — stay
+    under 4 GB, and all of it inside the chip's 16 GB."""
+    from novel_view_synthesis_3d_tpu.config import get_preset
+    from novel_view_synthesis_3d_tpu.diffusion.schedules import (
+        sampling_schedule)
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
+    from novel_view_synthesis_3d_tpu.sample.ddpm import make_sampler
+
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    cfg = get_preset("lcf_denoiser256").override(**{
+        "diffusion.sample_timesteps": 8, "diffusion.guidance_weight": 3.0,
+        "diffusion.sampler": "ddpm"}).validate()
+    model = build_denoiser(cfg.model)
+    side = cfg.data.img_sidelength
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, F32, sharding=v5e)
+
+    cond = {"x": spec(1, side, side, 3), "R1": spec(1, 3, 3),
+            "t1": spec(1, 3), "R2": spec(1, 3, 3), "t2": spec(1, 3),
+            "K": spec(1, 3, 3)}
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e),
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)})["params"]))
+    sampler = make_sampler(model, sampling_schedule(cfg.diffusion, 8),
+                           cfg.diffusion, trajectory_every=1)
+    compiled = jax.jit(sampler).lower(
+        params, jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e),
+        cond).compile()
+    mem = compiled.memory_analysis()
+    assert 10.12e9 < mem.argument_size_in_bytes < 10.13e9
+    assert mem.temp_size_in_bytes < 4.0e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes < 15.0e9
+    text = compiled.as_text()
+    # eight latent attentions a step and seven in the once-a-call pass,
+    # each one kernel; three grouped products and a combine a branch
+    for name, least in (("flash_fwd", 2), ("gmm", 3), ("moe_combine", 1)):
+        assert len(re.findall(r"^\s*%%%s\S* = " % name, text, re.M)) \
+            >= least, name
 
 
 _LAYER_TEXTS = {}   # a preset's delta-rule layer is compiled once a run
