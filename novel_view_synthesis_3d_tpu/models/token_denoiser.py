@@ -146,7 +146,8 @@ from novel_view_synthesis_3d_tpu.config import (
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 from novel_view_synthesis_3d_tpu.ops.expert_combine import combine
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
-    band_key_columns, flash_attention, resolve_flash, window_binds)
+    band_key_columns, flash_attention, resolve_flash, shared_part_fits,
+    window_binds)
 from novel_view_synthesis_3d_tpu.ops.grouped_matmul import (
     ROW_TILE, buffer_rows, grouped_matmul, span_sizes)
 from novel_view_synthesis_3d_tpu.ops.gdn import gated_delta_chunked
@@ -451,22 +452,80 @@ def latent_keys_values(c_kv, k_shared, k_b, v_b, heads: int):
     return keys.reshape(B, Lk, heads, -1), values.reshape(B, Lk, heads, -1)
 
 
+def shares_key_part(k) -> bool:
+    """Whether trunk `k`'s latent attention hands the attention kernel the
+    heads' own lanes and the rotary lanes all heads share as operands
+    apart (`flash_attention`'s `shared`): where the own width fills whole
+    lane blocks and the sum does not — 128 + 64, Kimi-Linear's and
+    LongCat-Flash's heads. At 64 + 64 (Mistral-Small-4's) a head IS a lane
+    block and takes the one-operand form without a pad. Widths alone."""
+    return shared_part_fits(k.num_attention_heads, k.qk_nope_head_dim,
+                            k.qk_rope_head_dim)
+
+
+def split_columns(kernel, heads: int, dn: int):
+    """A kernel (rank, heads·(dn + d)) as every head's first dn columns
+    (rank, heads·dn) and its last d (rank, heads·d), each side by side."""
+    w = kernel["kernel"].reshape(kernel["kernel"].shape[0], heads, -1)
+    return ({"kernel": w[..., :dn].reshape(w.shape[0], -1)},
+            {"kernel": w[..., dn:].reshape(w.shape[0], -1)})
+
+
+def attention_kernels(k, p, q: str, rotary: bool) -> dict:
+    """The kernels one latent attention reads beside its parameters `p`,
+    derived from them once a call, so that every operand of the attention
+    kernel leaves a product where the kernel reads it. `q` names the
+    queries' kernel (`q_b` behind a query latent, `q` at full rank).
+
+    Where a head is whole lane blocks (`shares_key_part` false):
+    `latent_kernels`' `k_b` and `v_b`, and for a trunk that rotates
+    `<q>_pair`, `pair_swapped_kernel` of the whole kernel. Where it is
+    not, the two-operand form: `<q>_nope` and `<q>_rope`, the queries'
+    columns apart, `<q>_rope_pair` for a trunk that rotates — the
+    pair-swapped kernel of the rotary columns alone, no zero column —,
+    `k_nope`, the keys' columns of `kv_b` with no identity block under
+    them, and `v_b`."""
+    NH, dn, dr = k.num_attention_heads, k.qk_nope_head_dim, \
+        k.qk_rope_head_dim
+    if not shares_key_part(k):
+        pair = {q + "_pair": pair_swapped_kernel(
+            p[q], NH, dn, k.rope_interleave)} if rotary else {}
+        return {**pair, **latent_kernels(p["kv_b"], NH, dn, dr)}
+    nope, rope = split_columns(p[q], NH, dn)
+    pair = {q + "_rope_pair": pair_swapped_kernel(
+        rope, NH, 0, k.rope_interleave)} if rotary else {}
+    k_nope, v_b = split_columns(p["kv_b"], NH, dn)
+    return {q + "_nope": nope, q + "_rope": rope, **pair,
+            "k_nope": k_nope, "v_b": v_b}
+
+
 def low_rank_queries(cfg, p, a, cos, sin, scale=1.0):
-    """Queries (B, L, heads·(dn + dr)) of normalised tokens `a` through the
-    query latent: c_q = RMSNorm(a·q_a) — times `scale` where the trunk
-    scales the latent after its norm —, then `rotated_queries` of it."""
+    """Queries of normalised tokens `a` through the query latent: c_q =
+    RMSNorm(a·q_a) — times `scale` where the trunk scales the latent after
+    its norm —, then `rotated_queries` of it: (B, L, heads·(dn + dr)), or
+    where `shares_key_part` the pair (c_q·q_b's nope columns (B, L,
+    heads·dn), its rotary columns rotated (B, L, heads·dr)) — the rotation
+    and its second product on a third of the lanes."""
     k = cfg.tokens
+    NH = k.num_attention_heads
     c_q = rms_norm(_dense(a, p["q_a"]), p["q_norm"]["scale"], k.rms_norm_eps)
     if scale != 1.0:
         c_q = c_q * scale
-    return rotated_queries(c_q.astype(a.dtype), p["q_b"], p["q_b_pair"],
-                           k.num_attention_heads, cos, sin, k.rope_interleave)
+    c_q = c_q.astype(a.dtype)
+    if shares_key_part(k):
+        return _dense(c_q, p["q_b_nope"]), rotated_queries(
+            c_q, p["q_b_rope"], p["q_b_rope_pair"], NH, cos, sin,
+            k.rope_interleave)
+    return rotated_queries(c_q, p["q_b"], p["q_b_pair"], NH, cos, sin,
+                           k.rope_interleave)
 
 
 def latent_attention(cfg, p, h, a, q, cache, scale, rope=None,
                      kv_scale=1.0):
     """h + W_o · attention of the queries `q` (B, L, heads·(dn + dr), made
-    by the caller from `a` its trunk's way) over keys and values
+    by the caller from `a` its trunk's way; where `shares_key_part`, the
+    pair of their nope lanes (B, L, heads·dn) and their rotary lanes (B, L,
+    heads·dr)) over keys and values
     up-projected at use from the latent of the normalised tokens `a` (the
     form a chip run chose over absorbed weights; PERF.md, PR 26): c_kv =
     RMSNorm of a·kv_a's first `kv_lora_rank` lanes, times `kv_scale` where
@@ -475,13 +534,21 @@ def latent_attention(cfg, p, h, a, q, cache, scale, rope=None,
     trunk has a positional term and never scaled. `cache` = (c_kv, shared
     key part) of the frames before, as this returns them for this frame:
     → (h, (c_kv, shared key part)). Every trunk with a latent cache runs
-    this one function, under the stamps `lk.mla_proj` and `lk.mla_core`."""
+    this one function, under the stamps `lk.mla_proj` and `lk.mla_core`.
+
+    Two forms by the heads' widths. A head of whole lane blocks: keys (B,
+    Lk, heads·(dn + dr)) by `latent_keys_values`, the shared part copied
+    under every head by the product itself. Otherwise (`shares_key_part`)
+    nothing is copied or padded: the keys' nope lanes are c_kv · `k_nope`,
+    and the shared key part goes to the attention kernel as it is, ONE
+    (B, Lk, dr) operand beside the queries' rotary lanes — a score is the
+    sum of the two products."""
     k = cfg.tokens
     dt, eps = jnp.dtype(cfg.dtype), k.rms_norm_eps
     B, L, _ = h.shape
     NH = k.num_attention_heads
     with jax.named_scope("lk.mla_proj"):
-        q = q.reshape(B, L, NH, -1)
+        q = jax.tree.map(lambda x: x.reshape(B, L, NH, -1), q)
         kv_a = _dense(a, p["kv_a"])
         c_kv = rms_norm(kv_a[..., :k.kv_lora_rank], p["kv_norm"]["scale"],
                         eps)
@@ -496,25 +563,41 @@ def latent_attention(cfg, p, h, a, q, cache, scale, rope=None,
             c_kv = jnp.concatenate([cache[0].astype(dt), c_kv], axis=1)
             k_shared = jnp.concatenate([cache[1].astype(dt), k_shared],
                                        axis=1)
-        keys, values = latent_keys_values(c_kv, k_shared, p["k_b"],
-                                          p["v_b"], NH)
+        if shares_key_part(k):
+            q, q_rope = q
+            shared = (q_rope, k_shared)
+            keys, values = (
+                _dense(c_kv, p[n]).reshape(B, c_kv.shape[1], NH, -1)
+                for n in ("k_nope", "v_b"))
+        else:
+            shared = None
+            keys, values = latent_keys_values(c_kv, k_shared, p["k_b"],
+                                              p["v_b"], NH)
     with jax.named_scope("lk.mla_core"):
         o = _attention(q, keys, values, scale,
-                       resolve_flash(cfg.use_flash_attention))
+                       resolve_flash(cfg.use_flash_attention), shared=shared)
     with jax.named_scope("lk.mla_proj"):
         return h + _dense(o.reshape(B, L, -1), p["o"]), own
 
 
-def _attention(q, k, v, scale, use_flash, window=None):
+def _attention(q, k, v, scale, use_flash, window=None, shared=None):
     """softmax(q·kᵀ·scale)·v, softmax in float32. q (B, Lq, N, D), k (B,
     Lk, Nkv, D), v (B, Lk, Nkv, Dv), query head n on key/value head n //
     (N // Nkv). The
     queries are the last Lq positions of the key axis. No mask but the
     window's (the caller hands each frame's queries the keys of the frames
     they may see): with `window`, a query at p sees key j iff j > p −
-    window."""
+    window. `shared` = (qs (B, Lq, N, w), ks (B, Lk, w)): w more lanes of
+    every query, against ONE key part under every head — the kernel adds
+    the two products; without it they are put together here."""
     if use_flash:
-        return flash_attention(q, k, v, scale=scale, window=window)
+        return flash_attention(q, k, v, scale=scale, window=window,
+                               shared=shared)
+    if shared is not None:
+        q = jnp.concatenate([q, shared[0]], axis=-1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            shared[1][:, :, None], k.shape[:3] + shared[1].shape[-1:])],
+            axis=-1)
     B, Lq, N, D = q.shape
     Lk, Nkv = k.shape[1], k.shape[2]
     binds = window_binds(Lq, window, Lk - Lq)
@@ -720,11 +803,7 @@ class Mistral4Layer:
     def derive(self, i, p):
         """The kernels that write q, the keys and the values where the
         attention kernel reads them."""
-        k = self.config.tokens
-        NH, dn = k.num_attention_heads, k.qk_nope_head_dim
-        return {"q_b_pair": pair_swapped_kernel(p["q_b"], NH, dn,
-                                                k.rope_interleave),
-                **latent_kernels(p["kv_b"], NH, dn, k.qk_rope_head_dim)}
+        return attention_kernels(self.config.tokens, p, "q_b", rotary=True)
 
     def __call__(self, i, p, h, tables, cache):
         """`cache` is the (c_kv, k_rope) of the frames before this one."""
@@ -738,7 +817,8 @@ class Mistral4Layer:
             a = rms_norm(h, p["attn_norm"]["scale"], eps).astype(dt)
             q = low_rank_queries(cfg, p, a, cos, sin)
             if np.any(qscale != 1.0):
-                q = q * jnp.asarray(qscale, dt)[None, :, None]
+                q = jax.tree.map(
+                    lambda x: x * jnp.asarray(qscale, dt)[None, :, None], q)
         h, own = latent_attention(cfg, p, h, a, q, cache, softmax_scale(k),
                                   rope=(cos, sin))
         with jax.named_scope("lk.moe_route"):
@@ -953,13 +1033,12 @@ class KimiLinearLayer:
         return h, (state, tail)
 
     def derive(self, i, p):
-        """A latent layer's two kernels for the keys and the values."""
+        """A latent layer's kernels for the keys and the values, and at
+        its cell's widths for the queries' two operands."""
         k = self.config.tokens
         if not k.is_full_attention(i):
             return {}
-        return {"mla": latent_kernels(
-            p["mla"]["kv_b"], k.num_attention_heads, k.qk_nope_head_dim,
-            k.qk_rope_head_dim)}
+        return {"mla": attention_kernels(k, p["mla"], "q", rotary=False)}
 
     def _mla(self, layer, h, cache):
         """h + latent attention, without a positional term and with
@@ -969,7 +1048,8 @@ class KimiLinearLayer:
         with jax.named_scope("lk.mla_proj"):
             a = rms_norm(h, layer["attn_norm"]["scale"],
                          k.rms_norm_eps).astype(jnp.dtype(cfg.dtype))
-            q = _dense(a, p["q"])
+            q = (_dense(a, p["q_nope"]), _dense(a, p["q_rope"])) \
+                if shares_key_part(k) else _dense(a, p["q"])
         return latent_attention(cfg, p, h, a, q, cache, k.qk_head_dim ** -0.5)
 
     def __call__(self, i, p, h, tables, cache):
@@ -1408,11 +1488,8 @@ class LongcatFlashLayer:
     def derive(self, i, p):
         """Both attentions' kernels that write q, the keys and the values
         where the attention kernel reads them."""
-        k = self.config.tokens
-        NH, dn = k.num_attention_heads, k.qk_nope_head_dim
-        return {n: {"q_b_pair": pair_swapped_kernel(
-            p[n]["q_b"], NH, dn, k.rope_interleave), **latent_kernels(
-                p[n]["kv_b"], NH, dn, k.qk_rope_head_dim)}
+        return {n: attention_kernels(self.config.tokens, p[n], "q_b",
+                                     rotary=True)
                 for n in ("mla_0", "mla_1")}
 
     def _mla(self, p, h, tables, cache):

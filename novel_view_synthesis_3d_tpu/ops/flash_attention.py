@@ -54,14 +54,29 @@ trunk's 8192 keys a head's K and V are 2 × 2 MB, whole in VMEM and
 double-buffered: the call asks for a 64 MiB scoped limit where the
 default 16 would not hold them.
 
-One thing the third token trunk (Kimi-Linear's latent attention) brought,
-forward only too. **Values of another width than the keys**: its keys and
-queries are 192 wide (128 up-projected from the latent beside a 64-wide
-part all heads share), its values 128. K and V have a BlockSpec each and
-the output takes V's width; each is padded to its OWN whole lanes (192 →
-256 for q and k, so a quarter of the score product multiplies zeros; 128
-as it is for v and the output). Where the two widths are equal the
-program is the one it was.
+Two things the third token trunk (Kimi-Linear's latent attention) brought,
+forward only too. **Values of another width than the keys**: K and V have
+a BlockSpec each and the output takes V's width; each is padded to its OWN
+whole lanes (a 24-wide key beside a 16-wide value at the toy sizes; where
+the two widths are equal the program is the one it was). **A key part all
+heads share, as an operand of its own** (PR 45): that trunk's queries and
+keys are 128 lanes up-projected from the latent beside a 64-wide part that
+is ONE vector a token for every head (LongCat-Flash's too, rotated). As one
+192-wide head it was padded 192 → 256 in HBM, q and the keys, before every
+call — a fifth of the time under the attention's stamp at 64 heads
+(PERF.md §6, PR 45) — and the shared part was copied under every head.
+With `shared=(qs, ks)` a score is the sum of two
+products on whole lane blocks, q_h·k_hᵀ + qs_h·ksᵀ: q, k, v and o a head a
+lane block as ever; ks (B, Lk, 64) laid twice side by side, (B, Lk, 128),
+at block index (b, 0, 0) — fetched once a batch row, not once a head; qs
+(B, Lq, H·64) fetched as the 128-lane block of the head PAIR, the other
+head's half zeroed by one select a grid step. Every contraction is 128
+deep and aligned; the MXU makes the two passes a key block it made on
+256-lane heads (a 64-deep contraction takes a whole pass), so the kernel's
+own time is what it was (on v5e 17.82 ms against 17.62 on pre-padded
+256-lane heads, 2 × 4096 queries on 8192 keys of 64 heads) and the pad's
+is gone (24.04 with it). `shared_part_fits` says for which widths; a
+caller without the operand traces to the program it had.
 
 Layout notes (pallas_guide.md "Tiling Constraints"):
   - the wrapper transposes nothing. (B, L, H, D) → (B, L, H·D) is a
@@ -75,10 +90,11 @@ Layout notes (pallas_guide.md "Tiling Constraints"):
     matter write their operands in the 3-D form for that reason.
   - lanes (a head's width) are padded to a multiple of 128 per head in
     place, (B, L, H, D) → (B, L, H, Dp) → (B, L, H·Dp), and the token axes
-    to their blocks: one pad an operand that needs one (192-wide latent
-    keys → 256, 64-wide maps → 128, the X-UNet's 16- and 64-wide heads;
-    under the interpreter off the chip no lane is padded), none where
-    none does. Padding is masked inside the kernel with a
+    to their blocks: one pad an operand that needs one (64-wide maps →
+    128, the X-UNet's 16- and 64-wide heads; under the interpreter off the
+    chip no lane is padded), none where none does (the latent trunks'
+    128 + 64 go in as two operands, unpadded). Padding is masked inside
+    the kernel with a
     statically-known length, and sliced off afterwards.
   - matmuls request `preferred_element_type=float32` so the MXU accumulates
     in f32 even for bf16 inputs; softmax runs in f32.
@@ -179,17 +195,27 @@ def _band_blocks(Lk_pad: int, block_k: int, band, rows: int) -> list:
             if lo + block_k - 1 > first - window]
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, scale: float,
-                 kv_len: int, block_k: int, band=None):
+def _attn_kernel(q_ref, k_ref, v_ref, *refs, scale: float, kv_len: int,
+                 block_k: int, band=None, shared_width: int = 0):
     """One query block vs. one (batch, head)'s keys and values, all in VMEM,
     the key axis walked `block_k` at a time (unrolled: static slices).
 
-    q_ref (1, Bq, D) · k_ref/v_ref (1, Lk_pad, D) · o_ref (1, Bq, D) ·
-    `lse_out` is empty or one more output ref (1, Bq, 128) — lse broadcast
-    across the lane dim to satisfy the TPU (sublane, lane) tiling
+    q_ref (1, Bq, D) · k_ref/v_ref (1, Lk_pad, D) · then o_ref (1, Bq, D) ·
+    and `lse_out`, empty or one more output ref (1, Bq, 128) — lse
+    broadcast across the lane dim to satisfy the TPU (sublane, lane) tiling
     constraint on output blocks. `kv_len` is the true (unpadded) kv length
     — static; the block that holds the boundary masks its padded columns
     (padding never fills a whole block, so no block's max is the mask's).
+
+    `shared_width` > 0: two more inputs stand before o_ref, and a score is
+    the sum of two products, q·kᵀ + qs·ksᵀ. qs_ref (1, Bq, 128) is the lane
+    block that holds this head's `shared_width` lanes beside its
+    neighbours' (head h's at lane (h mod 128/width)·width; the others are
+    zeroed here, one select a grid step), ks_ref (1, Lk_pad, 128) the ONE
+    key part all heads share, laid 128/width times side by side so that
+    whichever lanes are this head's meet it: a contraction 128 deep on
+    whole lane blocks, half of it zeros at a width of 64 — the MXU pass a
+    64-deep one would take.
 
     `band` = (window, position of this block's first query row), static: a
     query at p sees key j iff j > p − window. The walk is then
@@ -198,6 +224,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, scale: float,
     sees are left out at trace time, blocks on the band's edge are masked,
     the others run bare."""
     q = q_ref[0]
+    if shared_width:
+        qs_ref, ks_ref, o_ref, *lse_out = refs
+        lane = jax.lax.broadcasted_iota(jnp.int32, qs_ref.shape[1:], 1)
+        mine = jax.lax.rem(pl.program_id(1), 128 // shared_width)
+        qs = jnp.where(jax.lax.div(lane, shared_width) == mine, qs_ref[0],
+                       jnp.zeros((), qs_ref.dtype))
+    else:
+        o_ref, *lse_out = refs
     m = l = acc = None
     if band is None:
         blocks = [(lo, False) for lo in range(0, k_ref.shape[1], block_k)]
@@ -207,7 +241,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *lse_out, scale: float,
         s = jax.lax.dot_general(
             q, k_ref[0, lo:lo + block_k, :],
             dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            preferred_element_type=jnp.float32)
+        if shared_width:
+            s = s + jax.lax.dot_general(
+                qs, ks_ref[0, lo:lo + block_k, :],
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        s = s * scale
         if kv_len < lo + block_k:  # mask padded kv columns (static)
             col = lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(col < kv_len, s, _NEG_INF)
@@ -259,7 +299,8 @@ _LONG_KV_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 def _flash_fwd_padded(q, k, v, *, heads: tuple[int, int], scale: float,
                       kv_len: int, block_q: int, block_k: int,
-                      with_lse: bool, interpret: bool, band=None):
+                      with_lse: bool, interpret: bool, band=None,
+                      shared=None):
     """q (B, Lq_pad, H·Dp) · k (B, Lk_pad, Hkv·Dp) · v (B, Lk_pad, Hkv·Dvp),
     `heads` = (H, Hkv), a head a block of lanes → (out (B, Lq_pad, H·Dvp),
     lse (B·H, Lq_pad) or None); the values' width may differ from the
@@ -269,13 +310,27 @@ def _flash_fwd_padded(q, k, v, *, heads: tuple[int, int], scale: float,
     head is fetched once a group. `band` = (window, q_offset): one kernel
     call a query block then, each with its own static walk
     (`_attn_kernel`) and every head's block i in its grid, their
-    (B, block_q, H·Dvp) slabs joined along the token axis."""
+    (B, block_q, H·Dvp) slabs joined along the token axis. `shared` = (qs
+    (B, Lq_pad, H·w), ks (B, Lk_pad, 128)), without a band only: the
+    second product of a score (`_attn_kernel`) — qs's lane block of the
+    128/w heads that h is one of, and ks at (b, 0, 0), an index that never
+    changes over a row's heads and query blocks: fetched once a row."""
     B, Lq, Lk = q.shape[0], q.shape[1], k.shape[1]
     H, Hkv = heads
     group, D, Dv = H // Hkv, q.shape[2] // H, v.shape[2] // Hkv
     mem = {} if interpret else {"memory_space": _pallas.VMEM}
     extra = {}
-    if 2 * Lk * (D + Dv) * k.dtype.itemsize > _KV_DEFAULT_VMEM_BYTES:
+    operands, shared_specs, shared_width = (q, k, v), [], 0
+    if shared is not None:
+        operands += shared
+        shared_width = shared[0].shape[2] // H
+        per = 128 // shared_width
+        shared_specs = [
+            pl.BlockSpec((1, block_q, 128),
+                         lambda b, h, i: (b, i, jax.lax.div(h, per)), **mem),
+            pl.BlockSpec((1, Lk, 128), lambda b, h, i: (b, 0, 0), **mem)]
+    kv_lanes = D + Dv + (128 if shared_width else 0)
+    if 2 * Lk * kv_lanes * k.dtype.itemsize > _KV_DEFAULT_VMEM_BYTES:
         extra["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=_LONG_KV_VMEM_LIMIT_BYTES)
     def its_group(b, h, *_):  # both grids: (b, h) and (b, h, i)
@@ -308,7 +363,7 @@ def _flash_fwd_padded(q, k, v, *, heads: tuple[int, int], scale: float,
         with jax.named_scope("pt.layout"):
             return jnp.concatenate(outs, axis=1), None
     kernel = functools.partial(_attn_kernel, scale=scale, kv_len=kv_len,
-                               block_k=block_k)
+                               block_k=block_k, shared_width=shared_width)
     out_specs = [pl.BlockSpec((1, block_q, Dv), lambda b, h, i: (b, i, h),
                               **mem)]
     out_shape = [jax.ShapeDtypeStruct((B, Lq, H * Dv), q.dtype)]
@@ -323,14 +378,14 @@ def _flash_fwd_padded(q, k, v, *, heads: tuple[int, int], scale: float,
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, h, i: (b, i, h),
                              **mem),
-                k_spec, v_spec,
+                k_spec, v_spec, *shared_specs,
             ],
             out_specs=out_specs,
             out_shape=out_shape,
             name="flash_fwd",
             interpret=interpret,
             **extra,
-        )(q, k, v)
+        )(*operands)
     with jax.named_scope("pt.layout"):
         return out, (lse[0][:, :, 0] if with_lse else None)
 
@@ -360,8 +415,19 @@ def _heads_side_by_side(x, block: int, lanes: int):
     return x.reshape(*x.shape[:2], -1)
 
 
+def shared_part_fits(heads: int, width: int, shared: int) -> bool:
+    """Whether heads whose queries and keys are `width` lanes of their own
+    beside `shared` lanes of ONE key part all heads share want the
+    two-operand form (`flash_attention`'s `shared`): the own part whole
+    lane blocks, the sum not — the per-head pad the one-operand form would
+    write —, and the shared lanes of a whole number of heads a lane
+    block."""
+    return (width % 128 == 0 and 0 < shared < 128 and 128 % shared == 0
+            and heads % (128 // shared) == 0)
+
+
 def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
-                    with_lse: bool, window=None):
+                    with_lse: bool, window=None, shared=None):
     """(B, L, H, D) inputs → padded kernel call → unpadded (out (B, Lq, H,
     Dv), lse (B, H, Lq)); lse is None unless asked for. The kernel reads
     q, k and v where they lie: the heads side by side, (B, L, H·D), a
@@ -373,7 +439,10 @@ def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
     attention with a key part no value has). A width that is no whole
     number of 128-lane blocks is padded per head in place, each operand
     to its own, and the token axes to their blocks: one pad an operand
-    that needs one, none where none does."""
+    that needs one, none where none does. `shared` = (qs (B, Lq, H, w), ks
+    (B, Lk, w)) where `shared_part_fits`: no lane of them is padded — qs's
+    heads side by side as they are, ks laid 128/w times side by side
+    (B, Lk, 128), one small array a call."""
     B, Lq, H, D = q.shape
     Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     interpret = _use_interpret()
@@ -383,9 +452,14 @@ def _flash_fwd_core(q, k, v, scale: float, block_q: int, *,
         qt = _heads_side_by_side(q, bq, lanes)
         kt = _heads_side_by_side(k, bk, lanes)
         vt = _heads_side_by_side(v, bk, lanes)
+        if shared is not None:
+            qs, ks = shared
+            shared = (_heads_side_by_side(qs, bq, 1),
+                      jnp.tile(_pad_to(ks, 1, bk), 128 // ks.shape[-1]))
     out, lse = _flash_fwd_padded(
         qt, kt, vt, heads=(H, Hkv), scale=scale, kv_len=Lk, block_q=bq,
-        block_k=bk, with_lse=with_lse, interpret=interpret, band=window)
+        block_k=bk, with_lse=with_lse, interpret=interpret, band=window,
+        shared=shared)
     with jax.named_scope("pt.layout"):
         out = out.reshape(B, out.shape[1], H, -1)[:, :Lq, :, :Dv]
         if with_lse:
@@ -573,22 +647,22 @@ _flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_attention_forward_only(q, k, v, scale: float, block_q: int,
-                                  window):
+                                  window, shared):
     return _flash_fwd_core(q, k, v, scale, block_q, with_lse=False,
-                           window=window)[0]
+                           window=window, shared=shared)[0]
 
 
-def _forward_only_fwd(q, k, v, scale, block_q, window):
+def _forward_only_fwd(q, k, v, scale, block_q, window, shared):
     return _flash_attention_forward_only(q, k, v, scale, block_q,
-                                         window), None
+                                         window, shared), None
 
 
 def _forward_only_bwd(scale, block_q, window, res, g):
     raise NotImplementedError(
         "flash_attention has no backward for grouped key/value heads, a "
-        "window or values of another width than the keys yet: the dq and "
-        "dk/dv kernels take one key/value head a query head, one width and "
-        "no band")
+        "window, values of another width than the keys or a shared key "
+        "part yet: the dq and dk/dv kernels take one key/value head a "
+        "query head, one width, one product a score and no band")
 
 
 _flash_attention_forward_only.defvjp(_forward_only_fwd, _forward_only_bwd)
@@ -604,10 +678,18 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     scale: Optional[float] = None,
                     block_q: int = _BLOCKED_Q,
                     window: Optional[int] = None,
-                    q_offset: Optional[int] = None) -> jnp.ndarray:
+                    q_offset: Optional[int] = None,
+                    shared=None) -> jnp.ndarray:
     """Fused softmax(q·kᵀ/√D)·v. q (B, Lq, H, D), k (B, Lk, Hkv, D), v (B,
     Lk, Hkv, Dv) with H a multiple of Hkv: query head h reads key/value
     head h // (H // Hkv); → (B, Lq, H, Dv). Dv ≠ D is forward only.
+
+    `shared` = (qs (B, Lq, H, w), ks (B, Lk, w)): every head's queries
+    have w more lanes, which meet ONE key part that all heads share — head
+    h's score is q_h·k_hᵀ + qs_h·ksᵀ, the attention of [q ‖ qs] over
+    [k ‖ ks under every head] without either array being written
+    (`shared_part_fits` says for which widths; the default scale is then
+    (D + w)^−½). Forward only, and without a window.
 
     Drop-in for `flax.linen.dot_product_attention` (same layout/scaling)
     where Hkv = H and there is no window. `block_q` is an upper bound on
@@ -623,11 +705,19 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """
     B, Lq, H, D = q.shape
     Lk, Hkv = k.shape[1], k.shape[2]
-    scale = float(D ** -0.5) if scale is None else float(scale)
     if H % Hkv:
         raise ValueError(f"{H} query heads do not divide into {Hkv} "
                          "key/value heads")
     q_offset = Lk - Lq if q_offset is None else int(q_offset)
+    w = 0 if shared is None else shared[1].shape[-1]
+    scale = float((D + w) ** -0.5) if scale is None else float(scale)
+    if shared is not None:
+        if window is not None or not shared_part_fits(H, D, w):
+            raise ValueError(
+                f"no shared key part of {w} lanes beside {H} heads of {D}"
+                + (" under a window" if window is not None else ""))
+        return _flash_attention_forward_only(q, k, v, scale, int(block_q),
+                                             None, shared)
     if not window_binds(Lq, window, q_offset):
         if H == Hkv and v.shape[-1] == D:
             return _flash_attention(q, k, v, scale, int(block_q))
@@ -635,4 +725,4 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     else:
         window = (int(window), q_offset)
     return _flash_attention_forward_only(q, k, v, scale, int(block_q),
-                                         window)
+                                         window, None)

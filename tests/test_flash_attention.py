@@ -455,3 +455,109 @@ def test_vjp_forward_hands_the_backward_a_head_major_lse():
     for gf, gr in zip(g_flash, g_ref):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    atol=1e-5, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# A key part all heads share, as an operand of its own
+# ---------------------------------------------------------------------------
+SHARED_PART = {
+    # name: (Lq, Lk, H, w, dtype, block_q): heads of 128 lanes of their own
+    # beside w shared ones (the latent trunks' 128 + 64; a head's w lanes
+    # lie in the lane block of 128/w heads, every place in it exercised),
+    # the key axis one block or walked, with and without a pad
+    "pairs_one_key_block": (48, 200, 2, 64, jnp.float32, 1024),
+    "pairs_blocked_walk_keys_padded": (96, 1100, 4, 64, jnp.float32, 1024),
+    "pairs_blocked_walk_two_query_blocks": (80, 1536, 2, 64, jnp.float32,
+                                            64),
+    "pairs_bfloat16_blocked_walk": (64, 1280, 4, 64, jnp.bfloat16, 1024),
+    "pairs_bfloat16_one_key_block": (40, 130, 2, 64, jnp.bfloat16, 1024),
+    "four_heads_a_lane_block": (40, 300, 4, 32, jnp.float32, 1024),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SHARED_PART))
+def test_shared_key_part_as_its_own_operand_matches_a_dense_einsum(name):
+    """`flash_attention(…, shared=(qs, ks))` — a score the sum of the
+    head's own product and its w more lanes against the ONE key part —
+    against the dense float32 einsum on the concatenated (128 + w)-wide
+    heads, the shared part copied under every head."""
+    Lq, Lk, H, w, dtype, block_q = SHARED_PART[name]
+    B, D, Dv = 2, 128, 128
+    assert fa.shared_part_fits(H, D, w)
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 5)
+    q = jax.random.normal(ks[0], (B, Lq, H, D), dtype)
+    qs = jax.random.normal(ks[1], (B, Lq, H, w), dtype)
+    k = jax.random.normal(ks[2], (B, Lk, H, D), dtype)
+    shared = jax.random.normal(ks[3], (B, Lk, w), dtype)
+    v = jax.random.normal(ks[4], (B, Lk, H, Dv), dtype)
+    out = flash_attention(q, k, v, shared=(qs, shared), block_q=block_q)
+    assert out.shape == (B, Lq, H, Dv) and out.dtype == dtype
+    f32 = jnp.float32
+    whole_q = jnp.concatenate([q, qs], axis=-1).astype(f32)
+    whole_k = jnp.concatenate(
+        [k, jnp.broadcast_to(shared[:, :, None], (B, Lk, H, w))],
+        axis=-1).astype(f32)
+    ref = _banded_reference(whole_q, whole_k, v.astype(f32),
+                            (D + w) ** -0.5, None, Lk - Lq)
+    tol = 2e-5 if dtype == f32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=tol, rtol=tol)
+    if dtype == f32:
+        # every head reads ITS lanes of qs: another head's would show
+        swapped = flash_attention(q, k, v, shared=(qs[:, :, ::-1], shared),
+                                  block_q=block_q)
+        assert np.abs(np.asarray(swapped) - np.asarray(ref)).max() > 1e-2
+
+
+def test_shared_key_part_is_forward_only_and_refuses_what_it_cannot_lay():
+    q, k, v = _qkv(3, 1, 32, 64, 2, 128)
+    qs, ks = q[..., :64], k[:, :, 0, :64]
+    with pytest.raises(NotImplementedError, match="shared key"):
+        jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, shared=(qs, ks))))(q)
+    with pytest.raises(ValueError, match="under a window"):
+        flash_attention(q, k, v, shared=(qs, ks), window=40)
+    # heads that are no whole lane blocks, a part that is one, or a number
+    # of heads that leaves a lane block half filled: the one-operand form
+    assert not fa.shared_part_fits(2, 64, 64)
+    assert not fa.shared_part_fits(2, 128, 128)
+    assert not fa.shared_part_fits(3, 128, 64)
+    assert not fa.shared_part_fits(2, 128, 48)
+    with pytest.raises(ValueError, match="no shared key part"):
+        flash_attention(q[..., :64], k[..., :64], v, shared=(qs, ks))
+
+
+LOWERED_WITHOUT_A_SHARED_PART = {
+    # sha256 of the CPU-lowered text (the kernel through the interpreter)
+    # of a caller that hands no second operand, from PR 44's tree: the
+    # shared part is an OPTIONAL operand of one kernel body, and without
+    # it the program is the one it was
+    "forward": (
+        "3caab162e9e855555020eb7144db386b18a6ed302079a0e5784d368f20fa6022"),
+    "vjp": (
+        "d651a2a4512bd876058939c376a256dff8a07247768a82c150b81a10eda935cc"),
+    "values_narrower_than_keys": (
+        "e396d8dfe727b040b13c8a2795968b8be59064b2c985d360c80a82e10c3be2d2"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LOWERED_WITHOUT_A_SHARED_PART))
+def test_without_a_shared_part_the_lowered_text_is_what_it_was(name):
+    import hashlib
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    small = (S(2, 40, 3, 64), S(2, 1100, 3, 64), S(2, 1100, 3, 64))
+    fn, operands = {
+        "forward": (lambda q, k, v: flash_attention(q, k, v, block_q=16),
+                    small),
+        "vjp": (jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+            q, k, v, block_q=16))), argnums=(0, 1, 2)), small),
+        "values_narrower_than_keys": (
+            lambda q, k, v: flash_attention(q, k, v),
+            (S(2, 64, 2, 192), S(2, 1280, 2, 192), S(2, 1280, 2, 128))),
+    }[name]
+    text = jax.jit(fn).lower(*operands).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        LOWERED_WITHOUT_A_SHARED_PART[name]

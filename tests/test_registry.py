@@ -334,8 +334,17 @@ def test_e2e_hot_swap_under_live_submits(served_model, tmp_path):
         warm = svc.compile_counters()
 
         # Live submit stream on a client thread while the promotion lands.
+        # The stream stays open until the flip: its last three requests
+        # wait for it (bounded), so that "some ride v1, some v2" does not
+        # hang on how fast this host serves eleven requests beside a gate
+        # (it failed once on a loaded host with all 14 served by v1).
         def client():
             for j in range(14):
+                if j == 11:
+                    deadline = time.monotonic() + 120
+                    while svc.model_version == m1.version \
+                            and time.monotonic() < deadline:
+                        time.sleep(0.02)
                 try:
                     results.append(
                         (j, svc.submit(conds[j % len(conds)], seed=j)))

@@ -66,12 +66,13 @@ TOY = {
         "model.tokens.sliding_window": 6, "model.tokens.mamba_d_state": 8,
         "data.img_sidelength": 16, "model.use_flash_attention": True},
     # KDA heads of 128 lanes, as its cell's: the width at which its two
-    # kernels take a head as a whole lane block
+    # kernels take a head as a whole lane block; and since PR 45 its latent
+    # heads at the cell's 128 + 64 on 128 (the preset's), the widths at
+    # which the attention kernel takes the shared key part as an operand
     "kl48_denoiser256": {
         "model.tokens.hidden_size": 64, "model.tokens.num_hidden_layers": 4,
-        "model.tokens.num_attention_heads": 4,
-        "model.tokens.kv_lora_rank": 16, "model.tokens.qk_nope_head_dim": 16,
-        "model.tokens.qk_rope_head_dim": 8, "model.tokens.v_head_dim": 16,
+        "model.tokens.num_attention_heads": 2,
+        "model.tokens.kv_lora_rank": 16,
         "model.tokens.linear_attn_config.num_heads": 2,
         "model.tokens.linear_attn_config.head_dim": 128,
         "model.tokens.intermediate_size": 96, "model.tokens.num_experts": 8,
@@ -90,7 +91,19 @@ TOY = {
         "model.tokens.linear_key_head_dim": 32,
         "model.tokens.linear_value_head_dim": 64,
         "data.img_sidelength": 16, "model.use_flash_attention": True},
-    # 192-wide keys on 128-wide values, as its cell's latent attention
+    # grouped heads under a window that binds (16 tokens a frame), its
+    # rehearsal's sizes: `flash_fwd`'s banded form, one call a query block
+    "st21_denoiser256": {
+        "model.tokens.hidden_size": 64, "model.tokens.num_hidden_layers": 4,
+        "model.tokens.num_attention_heads": 4,
+        "model.tokens.num_key_value_heads": 2, "model.tokens.head_dim": 16,
+        "model.tokens.sliding_window_size": 16,
+        "model.tokens.moe_num_primary_experts": 8,
+        "model.tokens.moe_num_active_primary_experts": 3,
+        "model.tokens.moe_ffn_hidden_size": 32,
+        "model.tokens.held_experts": [0, 8], "data.img_sidelength": 16,
+        "model.use_flash_attention": True},
+    # heads of 128 + 64 on 128, as its cell's latent attention
     "lcf_denoiser256": {
         "model.tokens.hidden_size": 64, "model.tokens.num_layers": 2,
         "model.tokens.num_attention_heads": 2,
@@ -125,25 +138,35 @@ DIGESTS = {
         "05f8f63c7fa473cb754df607ae529763c219dde26b375809c49a704be6f2f054",
     ("p4f_denoiser256", "v5e"):
         "8d3815a1662ead5a40d76159f15dcc8e09008623a3a645cd0baedb5df00a31eb",
-    # PR 42's tree, these four: both delta-rule layers hand their scan's o
+    # PR 42's tree, these two: both delta-rule layers hand their scan's o
     # and the gate's projection to `head_norm_fwd` (ops/head_norm.py) where
     # they held the norm on a (B, L, H, d) view; the six above are PR 41's
     # (CHANGES.md, PR 42).
-    ("kl48_denoiser256", "cpu"):
-        "dc905a6a296b60701ef8a03434249a06e932ac3b7807f8720d1e086e32131e93",
-    ("kl48_denoiser256", "v5e"):
-        "68cdf34ce68050d780dc62a05b8ac8f02b7faaf56073d5f4f5b037f68a805f9e",
     ("oh7_denoiser256", "cpu"):
         "cb2f8f0dade55f98ceb4c906475321c4ab09c7d6f05e5830750ed2f2f13439b8",
     ("oh7_denoiser256", "v5e"):
         "9b558d7285dee20601aff53a5019b4a0caabfbdc05fd28fa9b433037e6be6e29",
-    # PR 44's tree: the sixth trunk, pinned as it landed (the ten above
-    # are the parent's, untouched by `route` taking a bias with either
-    # scoring function)
+    # PR 44's tree, pinned by PR 45 (whose tree lowers the same text: the
+    # banded form takes no second operand)
+    ("st21_denoiser256", "cpu"):
+        "8760b1e5edfb6f8975bc788e6f6d28dc38ecf25c2718547b4b08016899201c28",
+    ("st21_denoiser256", "v5e"):
+        "569eeeb217b5fc1eac55de5a3746e8472b52ccb57b781832e66fd990f29ef8d9",
+    # PR 45's tree, these four: latent attention at heads of 128 + 64
+    # hands `flash_fwd` the nope lanes and the shared rotary part as two
+    # operands (no pad 192 → 256, no identity block under the keys'
+    # kernel, the pair-swapped product on the rotary columns alone);
+    # `kl48`'s toy moved to those widths with it — at the 16 + 8 it had,
+    # its text was still PR 42's. The ten above are the parent's
+    # (CHANGES.md, PR 45).
+    ("kl48_denoiser256", "cpu"):
+        "9a7ef4a4488467e978719760f3467c70d3984e3c0d00f9b4d6c5cbbf1f7f1daf",
+    ("kl48_denoiser256", "v5e"):
+        "b2344b38923a9116bb7ffdf0ffa0407b17ab6705b56006b58b8948dc6e135478",
     ("lcf_denoiser256", "cpu"):
-        "1984f6731aeaf5301517a79ee8560e4b10920e963a50e78557661d4802b8d660",
+        "bd7ff69c8c69e00a33700e588bfd09f436a4f777630984e4a262ef8d0ba34f15",
     ("lcf_denoiser256", "v5e"):
-        "9cd675b78e002b06d30879140f3692f2ceb7837f8db2f0a3d19818a18ecc3df5",
+        "08af418bf9ade7147e962d60aeea843c45b7ed1a3ca3baf4247468c58aa53af9",
 }
 
 

@@ -253,6 +253,119 @@ def test_latent_keys_and_values_each_by_a_product_of_their_own(dn, dr, dv):
                                   np.asarray(kv[..., dn:], np.float32))
 
 
+def _widths(heads, dn, dr, interleave):
+    """The four fields `attention_kernels` and `shares_key_part` read."""
+    import types
+
+    return types.SimpleNamespace(
+        num_attention_heads=heads, qk_nope_head_dim=dn, qk_rope_head_dim=dr,
+        rope_interleave=interleave)
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_split_queries_are_the_slices_of_the_whole_product(interleave,
+                                                           dtype):
+    """At 128 + 64 (`shares_key_part`) the queries leave as two operands:
+    the nope one is the nope slice of x·q_b, the rotary one `apply_rope`
+    of its rotary slice — `rotated_queries` on the rotary columns ALONE,
+    against their own pair-swapped kernel with no zero column — to the bit
+    what `rotated_queries` writes into the whole 192-wide head."""
+    heads, dn, dr, L = 2, 128, 64, 5
+    k = _widths(heads, dn, dr, interleave)
+    assert token_denoiser.shares_key_part(k)
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(ks[0], (2, L, 16), dtype)
+    p = {"q_b": {"kernel": jax.random.normal(
+             ks[1], (16, heads * (dn + dr)), dtype)},
+         "kv_b": {"kernel": jax.random.normal(
+             ks[2], (12, heads * (dn + 128)), dtype)}}
+    d = token_denoiser.attention_kernels(k, p, "q_b", rotary=True)
+    assert {n: v["kernel"].shape for n, v in d.items()} == {
+        "q_b_nope": (16, heads * dn), "q_b_rope": (16, heads * dr),
+        "q_b_rope_pair": (16, heads * dr), "k_nope": (12, heads * dn),
+        "v_b": (12, heads * 128)}
+    ang = np.random.RandomState(1).uniform(0, 6, (L, dr // 2))
+    cos, sin = (f(ang).astype(np.float32) for f in (np.cos, np.sin))
+    q = jnp.dot(x, p["q_b"]["kernel"]).reshape(2, L, heads, dn + dr)
+    nope = jnp.dot(x, d["q_b_nope"]["kernel"])
+    rope = token_denoiser.rotated_queries(
+        x, d["q_b_rope"], d["q_b_rope_pair"], heads, cos, sin, interleave)
+    assert nope.dtype == rope.dtype == q.dtype
+    np.testing.assert_array_equal(
+        np.asarray(nope, np.float32).reshape(2, L, heads, dn),
+        np.asarray(q[..., :dn], np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(rope, np.float32).reshape(2, L, heads, dr),
+        np.asarray(token_denoiser.apply_rope(q[..., dn:], cos, sin,
+                                             interleave), np.float32))
+    whole = token_denoiser.rotated_queries(
+        x, p["q_b"], token_denoiser.pair_swapped_kernel(
+            p["q_b"], heads, dn, interleave), heads, cos, sin, interleave)
+    np.testing.assert_array_equal(
+        np.asarray(whole, np.float32).reshape(2, L, heads, dn + dr),
+        np.concatenate([np.asarray(nope, np.float32).reshape(
+            2, L, heads, dn), np.asarray(rope, np.float32).reshape(
+                2, L, heads, dr)], axis=-1))
+    # a trunk without a rotary derives no pair kernel, under its own name
+    assert set(token_denoiser.attention_kernels(
+        k, {"q": p["q_b"], "kv_b": p["kv_b"]}, "q", rotary=False)) == {
+        "q_nope", "q_rope", "k_nope", "v_b"}
+
+
+@pytest.mark.parametrize("dn,dr,rotary", [(64, 64, True), (16, 8, False),
+                                          (128, 128, True)])
+def test_heads_of_whole_lane_blocks_keep_the_one_operand_kernels(dn, dr,
+                                                                 rotary):
+    """Where a head is a whole number of lane blocks (64 + 64), or its own
+    part is none (16 + 8: the toy sizes), `attention_kernels` derives what
+    it derived: `latent_kernels`' pair and, for a trunk that rotates, the
+    whole pair-swapped kernel."""
+    heads = 2
+    k = _widths(heads, dn, dr, True)
+    assert not token_denoiser.shares_key_part(k)
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    p = {"q_b": {"kernel": jax.random.normal(ks[0],
+                                             (8, heads * (dn + dr)))},
+         "kv_b": {"kernel": jax.random.normal(ks[1],
+                                              (6, heads * (dn + 16)))}}
+    d = token_denoiser.attention_kernels(k, p, "q_b", rotary=rotary)
+    want = token_denoiser.latent_kernels(p["kv_b"], heads, dn, dr)
+    if rotary:
+        want["q_b_pair"] = token_denoiser.pair_swapped_kernel(
+            p["q_b"], heads, dn, True)
+    assert set(d) == set(want)
+    for n in want:
+        np.testing.assert_array_equal(d[n]["kernel"], want[n]["kernel"])
+
+
+def test_split_keys_are_kv_bs_key_columns_and_no_identity_block():
+    """`k_nope` — the keys' columns of `kv_b` side by side — gives, to the
+    bit, the nope lanes of `latent_keys_values`' keys, and `v_b` its
+    values; the shared part is no product's business any more."""
+    heads, dn, dr, dv, rank, B, Lk = 2, 128, 64, 128, 12, 2, 7
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    c_kv = jax.random.normal(ks[0], (B, Lk, rank), jnp.bfloat16)
+    shared = jax.random.normal(ks[1], (B, Lk, dr), jnp.bfloat16)
+    kv_b = {"kernel": jax.random.normal(
+        ks[2], (rank, heads * (dn + dv)), jnp.bfloat16)}
+    keys, values = token_denoiser.latent_keys_values(
+        c_kv, shared, heads=heads,
+        **token_denoiser.latent_kernels(kv_b, heads, dn, dr))
+    k_nope, v_b = token_denoiser.split_columns(kv_b, heads, dn)
+    assert k_nope["kernel"].shape == (rank, heads * dn)
+    np.testing.assert_array_equal(
+        np.asarray(jnp.dot(c_kv, k_nope["kernel"]), np.float32).reshape(
+            B, Lk, heads, dn), np.asarray(keys[..., :dn], np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(jnp.dot(c_kv, v_b["kernel"]), np.float32).reshape(
+            B, Lk, heads, dv), np.asarray(values, np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(keys[..., dn:], np.float32), np.asarray(
+            jnp.broadcast_to(shared[:, :, None], (B, Lk, heads, dr)),
+            np.float32))
+
+
 def test_rms_norm_over_runs_of_lanes_is_the_norm_of_the_4d_view():
     """`rms_norm_lane_groups` — the statistic and its way back as products
     with the groups' indicator, a float32 as the three bfloat16 terms that
@@ -323,6 +436,43 @@ def test_a_sequence_past_8192_scales_its_queries():
     flat = dict(m, rope_parameters=dict(m["rope_parameters"],
                                         llama_4_scaling_beta=0.0))
     assert rel(eps, ref.forward(params, flat, batch, mask)) > 100 * TOL
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_heads_of_128_and_64_take_two_operands_and_match_the_reference(
+        kernel):
+    """The same layer at heads of 128 + 64 on 128 — whole lane blocks of
+    their own beside half a block all heads share, where `shares_key_part`
+    chooses the two-operand form — with its second frame past the original
+    length (the position scale goes on BOTH query operands): the reference
+    on whole 192-wide heads, through XLA's attention and through the
+    kernel's two products a score."""
+    over = {"model.tokens.rope_parameters": dict(dataclasses.asdict(
+        small_cfg().model.tokens.rope_parameters),
+        original_max_position_embeddings=8, factor=4.0),
+        "model.tokens.num_attention_heads": 2,
+        "model.tokens.qk_nope_head_dim": 128,
+        "model.tokens.qk_rope_head_dim": 64,
+        "model.tokens.v_head_dim": 128,
+        "model.use_flash_attention": kernel}
+    cfg = small_cfg(**over)
+    assert token_denoiser.shares_key_part(cfg.model.tokens)
+    assert not token_denoiser.shares_key_part(small_cfg().model.tokens)
+    assert not token_denoiser.shares_key_part(
+        get_preset("ms4_denoiser128").model.tokens)
+    model, params = seeded(cfg)
+    batch, mask = doubled_batch()
+    m = token_check.model_sizes(cfg)
+    want = ref.forward(params, m, batch, mask)
+    eps = model.apply({"params": params}, batch, cond_mask=mask, train=False)
+    assert rel(eps, want) < TOL
+    cond = {k: v[:1] for k, v in batch.items() if k not in ("z", "logsnr")}
+    pre = model.precompute(params, cond)
+    assert set(pre["derived"]["layer_0"]) == {
+        "q_b_nope", "q_b_rope", "q_b_rope_pair", "k_nope", "v_b"}
+    eps = model.apply({"params": params}, dict(batch, **pre), cond_mask=mask,
+                      train=False)
+    assert rel(eps, want) < TOL
 
 
 # ---------------------------------------------------------------------------

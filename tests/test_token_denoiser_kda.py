@@ -378,6 +378,47 @@ def test_each_kind_of_layer_matches_the_reference(small, i):
         assert rel(jnp.concatenate([first, lost], axis=1), zeroed) < TOL
 
 
+@pytest.mark.parametrize("attention", ["xla", "kernel"])
+def test_latent_layer_at_the_cells_head_widths_matches_the_reference(
+        attention):
+    """Heads of 128 + 64 on 128, the cell's: the latent layer derives its
+    queries' nope and shared-part columns apart and the keys' without an
+    identity block (`shares_key_part`), and hands the attention kernel two
+    products a score — the reference's layer on whole 192-wide heads,
+    frame by frame from the cache, through XLA and through the kernel."""
+    cfg = small_cfg(**{
+        "model.tokens.num_attention_heads": 2,
+        "model.tokens.qk_nope_head_dim": 128,
+        "model.tokens.qk_rope_head_dim": 64, "model.tokens.v_head_dim": 128,
+        "model.use_flash_attention": attention == "kernel"})
+    k = cfg.model.tokens
+    assert token_denoiser.shares_key_part(k)
+    assert token_denoiser.shares_key_part(
+        get_preset("kl48_denoiser256").model.tokens)
+    assert not token_denoiser.shares_key_part(small_cfg().model.tokens)
+    model, params = seeded(cfg)
+    m = token_check_kda.model_sizes(cfg)
+    i = 3
+    p = params[f"layer_{i}"]
+    derived = model.layer.derive(i, p)
+    assert set(derived["mla"]) == {"q_nope", "q_rope", "k_nope", "v_b"}
+    mine = token_denoiser.laid_over(p, derived)
+    h = jnp.asarray(np.random.default_rng(i).normal(size=(2, 32, 64)),
+                    jnp.float32)
+    first, cache, _ = model.layer(i, mine, h[:, :16], None, None)
+    second, _, _ = model.layer(i, mine, h[:, 16:], None, cache)
+    assert cache[1].shape == (2, 16, 64)
+    want, _ = ref.layer(p, m, h, i, parts=True)
+    assert rel(jnp.concatenate([first, second], axis=1) - h, want - h) < TOL
+    # and the whole trunk, the once-a-call pass then a step
+    batch, mask = doubled_batch()
+    cond = {n: v[:1] for n, v in batch.items() if n not in ("z", "logsnr")}
+    eps = model.apply({"params": params},
+                      dict(batch, **model.precompute(params, cond)),
+                      cond_mask=mask, train=False)
+    assert rel(eps, ref.forward(params, m, batch, mask)) < TOL
+
+
 def test_the_lost_state_and_the_precision_both_show():
     """What the comparison must be able to see: a reference whose KDA
     state is lost between the frames, or in a lower precision than

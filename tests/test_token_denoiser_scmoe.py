@@ -139,6 +139,42 @@ def test_precompute_then_step_matches_the_full_forward(small):
     assert rel(wrong, got) > 100 * TOL
 
 
+@pytest.mark.parametrize("attention", ["xla", "kernel"])
+def test_heads_of_the_cells_widths_match_the_reference(attention):
+    """Heads of 128 + 64 on 128, the cell's (`shares_key_part`): both
+    attentions of a double layer derive `q_b`'s nope and rotary columns
+    apart, the pair-swapped kernel for the rotary columns ALONE and the
+    keys' columns without an identity block, rotate the rotary operand
+    where its product writes it, and hand the attention kernel two
+    products a score; the rotated shared key goes in as ONE (B, Lk, 64)
+    operand. The reference's forward on whole 192-wide heads, in one pass
+    and from the once-a-call pass's latents."""
+    cfg = small_cfg(**{
+        "model.tokens.num_attention_heads": 2,
+        "model.tokens.qk_nope_head_dim": 128,
+        "model.tokens.qk_rope_head_dim": 64, "model.tokens.v_head_dim": 128,
+        "model.use_flash_attention": attention == "kernel"})
+    assert token_denoiser.shares_key_part(cfg.model.tokens)
+    assert token_denoiser.shares_key_part(
+        get_preset("lcf_denoiser256").model.tokens)
+    assert not token_denoiser.shares_key_part(small_cfg().model.tokens)
+    model, params = seeded(cfg)
+    batch, mask = doubled_batch()
+    want = ref.forward(params, token_check_scmoe.model_sizes(cfg), batch,
+                       mask)
+    got = model.apply({"params": params}, batch, cond_mask=mask)
+    assert rel(got, want) < TOL
+    cond = {k: batch[k][:1] for k in ("x", "R1", "t1", "K")}
+    extra = model.precompute(params, cond)
+    for n in ("mla_0", "mla_1"):
+        assert set(extra["derived"]["layer_0"][n]) == {
+            "q_b_nope", "q_b_rope", "q_b_rope_pair", "k_nope", "v_b"}
+    assert extra["layer_cache"][0][1][1].shape == (2, 16, 64)
+    got = model.apply({"params": params}, dict(batch, **extra),
+                      cond_mask=mask)
+    assert rel(got, want) < TOL
+
+
 def test_the_layer_is_the_equations_written_out(small):
     """One double layer over both frames at once (no cache: every token
     sees every token, so the reference's mask is lifted) — the branch

@@ -115,6 +115,18 @@ def _latent_attn(Lq, Lk, heads, qk, dv):
          ((1, Lk, heads, dv), BF16)])
 
 
+def _shared_part_attn(rows, Lq, Lk, heads):
+    """Latent attention as the third and sixth trunks' cells run it since
+    PR 45: heads of 128 lanes of their own beside 64 that all heads share,
+    the shared key part ONE (rows, Lk, 64) operand — two products a score,
+    nothing padded to 256."""
+    return (lambda q, qs, k, ks, v: flash_attention.flash_attention(
+        q, k, v, scale=192 ** -0.5, shared=(qs, ks)),
+        [((rows, Lq, heads, 128), BF16), ((rows, Lq, heads, 64), BF16),
+         ((rows, Lk, heads, 128), BF16), ((rows, Lk, 64), BF16),
+         ((rows, Lk, heads, 128), BF16)])
+
+
 def _grouped(assignments, experts, k, n):
     """The expert layer's grouped product at the published widths: the
     static row count of the worst case (a step's 8192 tokens × top-4, all
@@ -317,6 +329,15 @@ CASES = {
                                                             192, 128),
     "flash_fwd_Lq4096_Lk4096_h64_qk192_v128": _latent_attn(4096, 4096, 64,
                                                             192, 128),
+    # both latent cells' attention in the two-operand form they run: a
+    # step's 4096 queries a row on 8192 keys, the once-a-call pass's on
+    # 4096 (one key block walk, the shared part in VMEM beside K and V)
+    "flash_fwd_shared64_2x4096_Lk8192_h64": _shared_part_attn(
+        2, 4096, 8192, 64),
+    "flash_fwd_shared64_1x4096_Lk4096_h64": _shared_part_attn(
+        1, 4096, 4096, 64),
+    "flash_fwd_shared64_4x4096_Lk8192_h32": _shared_part_attn(
+        4, 4096, 8192, 32),
     "grouped_matmul_up_6144x2048": _grouped(98304, 16, 6144, 2048),
     "grouped_matmul_down_2048x6144": _grouped(98304, 16, 2048, 6144),
     "moe_combine_8192x12x6144": _combine(8192, 12, 6144, 16),

@@ -37,8 +37,13 @@ def _attention_layer(v5e, preset, i, rows, L, cache=None, published=None):
             p, derive(i, p))
 
     def rows_of(shapes):
-        return None if shapes is None else tuple(
-            S((rows,) + tail) for tail in shapes(k))
+        """A (nested) list of shape tails as tuples of shapes."""
+        if shapes is None:
+            return None
+        if callable(shapes):
+            shapes = shapes(k)
+        return tuple(rows_of(s) for s in shapes) if isinstance(
+            shapes, list) else S((rows,) + shapes)
 
     def layer(p, h, c, kv):
         more = ({} if kv is None else {"kv": kv},) \
@@ -115,39 +120,70 @@ def test_st21_windowed_layer_writes_q_sized_arrays_in_its_calls_alone(
                 and c[2] >= q_bytes // 2], core
 
 
-def test_kl48_latent_layer_re_lays_nothing_but_what_its_pads_need(
+def _shared_part_layer_writes(text, k, rows, L, calls):
+    """What a latent layer at heads of 128 + 64 on 128 may write, compiled
+    for the chip, over `rows` rows of `L` queries on 2·`L` keys, `calls`
+    attentions in the layer. Under `lk.mla_core`: each kernel call's o,
+    (rows, L, H·128), and NOTHING else of a size worth counting — no `pad`
+    (the head is handed over as 128 lanes of its own and 64 all heads
+    share, two operands: no 192 → 256), no `copy`, no `transpose` — but
+    the shared key part laid twice side by side, (rows, 2L, 128): 1/H of
+    the keys. Under `lk.mla_proj`: no `copy` or `pad` of the keys' or the
+    values' size, nor of q's nope or rotary operand's (a trunk that rotates
+    lays its two tables over the heads, float32 (L, H·64), a constant of
+    the positions and no function of q: `…/tile`, not counted)."""
+    NH = k.num_attention_heads
+    assert (k.qk_nope_head_dim, k.qk_rope_head_dim, k.v_head_dim) == (
+        128, 64, 128)
+    writes = list(_entry_writes(text))
+    assert not [w for w in writes if w[0] == "transpose"], writes
+    o_bytes = rows * L * NH * 128 * 2
+    shared_bytes = rows * 2 * L * 128 * 2
+    core = [(op, size) for op, kind, _, size in writes if kind == "mla_core"]
+    assert [c for c in core if c[0] == "custom-call"] == [
+        ("custom-call", o_bytes)] * calls, core
+    assert all(size <= shared_bytes for op, size in core
+               if op != "custom-call"), core
+    assert sum(size for op, size in core if op != "custom-call") \
+        <= 2 * calls * shared_bytes, core
+    assert not [c for c in core if c[0] in ("pad", "transpose")], core
+    # q's rotary operand is half of o's bytes, its nope operand and the
+    # keys' and values' products o's and twice o's
+    assert [(op, name, size) for op, kind, name, size in writes
+            if kind == "mla_proj" and size >= o_bytes // 2
+            and op in ("copy", "reshape", "pad", "slice", "concatenate")
+            and not name.endswith("/tile")] == [], writes
+
+
+def test_kl48_latent_layer_writes_o_and_nothing_else_of_qs_size(
         v5e, monkeypatch):
     """The latent layer of `kl48_denoiser256` at the cell's shape (4 rows
-    of 4096 tokens on a 4096-token latent cache; 192-wide queries and keys
-    on 128-wide values): the values reach the kernel as their product
-    leaves them — nothing of their size is written but that product and
-    the kernel's o — and no array is transposed. What is left under
-    `lk.mla_core` beside the kernel is the per-head pad 192 → 256 of q and
-    of the keys, each once as the pad writes it and once re-tiled for the
-    kernel: the four passes PERF.md §7 row 16 counts, and no fifth."""
+    of 4096 tokens on a 4096-token latent cache; 32 heads of 128 + 64 on
+    128): every operand reaches the kernel as its product leaves it, and
+    the per-head pad 192 → 256 of q and of the keys — four passes until
+    PR 45 — is gone (`_shared_part_layer_writes`)."""
     monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
     rows, L = 4, 4096
     text, k = _attention_layer(
         v5e, "kl48_denoiser256", 3, rows, L,
         lambda k: [(L, k.kv_lora_rank), (L, k.qk_rope_head_dim)])
     assert k.is_full_attention(3)
-    NH, D = k.num_attention_heads, k.qk_nope_head_dim + k.qk_rope_head_dim
-    assert (D, k.v_head_dim) == (192, 128)
-    q_padded = rows * L * NH * 256 * 2
-    writes = list(_entry_writes(text))
-    assert not [w for w in writes if w[0] == "transpose"], writes
-    core = [(op, size) for op, kind, _, size in writes
-            if kind == "mla_core" and size >= q_padded // 8]
-    assert ("custom-call", rows * L * NH * k.v_head_dim * 2) in core, core
-    assert sorted(size for op, size in core if op != "custom-call") == [
-        q_padded, q_padded, 2 * q_padded, 2 * q_padded], core
-    # the values (8192 keys a row, 128 wide; q's padded bytes, as it
-    # happens, so the four passes above are all that size may show under
-    # the kernel's stamp): their product, and no copy of it anywhere else
-    v_bytes = rows * 2 * L * NH * k.v_head_dim * 2
-    assert [(op, kind) for op, kind, _, size in writes
-            if size == v_bytes and kind != "mla_core"
-            and op in ("copy", "reshape", "pad", "slice")] == [], writes
+    _shared_part_layer_writes(text, k, rows, L, calls=1)
+
+
+def test_lcf_double_layer_writes_o_and_nothing_else_of_qs_size(
+        v5e, monkeypatch):
+    """A double layer of `lcf_denoiser256` at the cell's shape (2 rows of
+    4096 tokens, each attention on its own 4096-token latent cache; 64
+    heads of 128 + 64 on 128, rotary): BOTH attentions hand the kernel
+    their operands where the products (and the rotary operand's x·c +
+    x'·s) write them (`_shared_part_layer_writes`)."""
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    rows, L = 2, 4096
+    text, k = _attention_layer(
+        v5e, "lcf_denoiser256", 0, rows, L,
+        lambda k: [[(L, k.kv_lora_rank), (L, k.qk_rope_head_dim)]] * 2)
+    _shared_part_layer_writes(text, k, rows, L, calls=2)
 
 
 @pytest.mark.parametrize("kind,calls", [("attn_window", 8),
