@@ -38,29 +38,50 @@ step takes the fewest heads whose keys and values both fill whole lane
 blocks (four: 384 lanes of q and k, 768 of v and o) and slices a head out
 of them in VMEM; nothing is padded or re-laid in HBM for the kernel. 30
 heads are 7.5 such groups: the last step's blocks hang over the arrays'
-edge, and what they read of heads that do not exist is set to zero and
-never written. γ, the running sum of g inside a chunk, is made outside (a
-(B, L, H) float32 array, 1 MB at the cell's size) and comes whole with β;
-a head's column is picked out of them. Resident while the grid walks a
-(row, group)'s runs: the heads' states, (d_k, d_v) float32 each, loaded
-from `S0` at the first run and written to `S_L` at the last; they are
-never rounded. Inside a run everything lives in VMEM and registers. What
-no state enters is made first for every (head, chunk) of the step: [Q ;
-K]·Kᵀ in one product, the mask D from γ's column against its transpose, A
-and Aᵀ; then T — rows inside a sub-block of 16 by substitution on the VPU
-for all the step's sub-blocks at once (row r of the inverse is e_r −
-Σ_{j<r} A[r, j]·row j, A's row a column of Aᵀ), block halves on the MXU
-(ops/_delta_rule.py says why substitution and not the series). Then the
-chunks in sequence, four products each: [K̄ ; Q̄]·S, U = T·(β ⊙ (V − K̄·S)),
-O = Q̄·S + A_qk·U, S ← e^{γ_C}·S + K̂ᵀ·U. exp(γ), exp(γ_C − γ), exp(γ_C)
-and D have non-positive exponents as they are; an underflow to 0 is the
-value, and nothing is ever divided by a decay.
+edge, and the heads that do not exist are neither read nor walked (the
+kernel holds the walk of a whole group and, for the last one, of the
+heads that exist). γ, the running sum of g inside
+a chunk, is made outside (a (B, L, H) float32 array, 1 MB at the cell's
+size) and comes whole with β; a head's column is picked out of them.
+Resident while the grid walks a (row, group)'s runs: the heads' states,
+(d_k, d_v) float32 each, loaded from `S0` at the first run and written to
+`S_L` at the last; they are never rounded. Inside a run everything lives in
+VMEM and registers. What no state enters is made first for every head of
+the step, TWO chunks side by side wherever an operand is (C, C) — their
+matrices lie block-diagonally in one (128, 128), so a product on them
+fills an MXU tile with both, as in ops/kda.py —: [Q ; K]·Kᵀ in one product,
+the mask D from γ's column against its transpose, A and Aᵀ; then T — rows
+inside a sub-block of 16 by substitution on the VPU for all the step's
+sub-blocks at once, a pair's eight sub-blocks side by side in a vreg's
+lanes (row r of the inverse is e_r − Σ_{j<r} A[r, j]·row j, A's row a
+column of Aᵀ), block halves on the MXU (ops/_delta_rule.py says why
+substitution and not the series) — the merges are this kernel's dearest
+products (PERF.md §6, PR 46), so a level multiplies only the rows it
+changes, the later half of each doubled block (`merge_rows`). Then the chunks in sequence, four
+products each: [K ; Q]·S, U = T·(β ⊙ (V − e^γ ⊙ K·S)), O = e^γ ⊙ Q·S +
+A_qk·U, S ← e^{γ_C}·S + Kᵀ·(e^{γ_C − γ} ⊙ U). exp(γ), exp(γ_C − γ),
+exp(γ_C) and D have non-positive exponents as they are; an underflow to 0
+is the value, and nothing is ever divided by a decay.
 
 **Every product is the configuration's float32**
-(`gdn_state_precision`: the state, the decays, β and the whole scan):
-float32 operands at `Precision.HIGHEST` — six MXU passes of bfloat16
-parts, which are the kernel's time as they are `kda_fwd`'s — into a
-float32 accumulator; q, k, v are widened in VMEM as they arrive. The
+(`gdn_state_precision`: the state, the decays, β, T, U, A_qk and every
+accumulator), at the MXU passes its operands' TYPES leave to do
+(`_delta_rule.mm_parts`, decided when the kernel is traced). q and k
+arrive in bfloat16 from the convolution (the cell's compute type) and are
+never widened for a product: a float32 that holds a bfloat16 has a middle
+and a low part of zeros, and five of `Precision.HIGHEST`'s six passes over
+[Q ; K]·Kᵀ multiplied them — bfloat16 · bfloat16 into the float32
+accumulator is the same number in ONE pass. The decay is a scalar a
+head-token, so it never rides on them: it scales the ROWS of a product's
+result ([K ; Q]·S) or of its float32 operand (U), and K and Q stay exact
+in one part against a float32 S or U cut into the three bfloat16 parts
+that hold its 24 bits — THREE passes, issued as one product with the
+parts stacked along the contraction (288 and 192 deep: the MXU sums them,
+and the state's fills two tiles where three products 64 deep fill three),
+and every term of the float32 product where six-pass `HIGHEST` drops the
+three smallest. The products of two float32 operands — T's merges, T·(…),
+A_qk·U — keep their six passes. Float32 q, k, v (the CPU tests, another
+compute type) run the same algebra at six passes throughout. The
 operations and bytes counted for its roofline share
 (benchmarks/flops_tokens_gdn.py) are of the chunked form above in ONE pass
 over triangles, whatever implements it. Off the TPU the same kernel runs
@@ -86,7 +107,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from novel_view_synthesis_3d_tpu.ops import _pallas
-from novel_view_synthesis_3d_tpu.ops._delta_rule import merge_blocks, mm
+from novel_view_synthesis_3d_tpu.ops._delta_rule import (
+    merge_rows, mm, mm_parts)
 
 CHUNK = 64      # tokens a chunk: one step of the scan
 SUB_BLOCK = 16  # rows of the inverse made by substitution before halves merge
@@ -94,21 +116,24 @@ RUN_CHUNKS = 4  # chunks a grid step
 
 
 def _gdn_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, s0_ref, o_ref,
-                sl_ref, st_ref, *, chunk: int, chunks: int, heads: int,
-                total_heads: int):
+                sl_ref, st_ref, *, chunk: int, chunks: int, wide: int,
+                heads: int, total_heads: int):
     """One (row, group of `heads` heads, run of `chunks` chunks). Blocks:
     q, k (1, R, heads·d_k), v, o (1, R, heads·d_v) — the group's lanes of
     the model's own (B, L, H·d) arrays, R = chunks·chunk rows —; γ and β
     (1, R, H), every head's, a head's column picked out of them; S0 / S_L
     (1, heads, d_k, d_v); `st_ref` (heads, d_k, d_v) float32 is the state,
-    resident while the grid walks a (row, group)'s runs. A head is a slice of lanes at any
-    offset (96 and 192 in the source: no whole lane blocks); the last
-    group may hang over the array's edge, and what it reads of heads that
-    do not exist is set to zero."""
+    resident while the grid walks a (row, group)'s runs. A head is a slice
+    of lanes at any offset (96 and 192 in the source: no whole lane
+    blocks); the last group may hang over the array's edge, and the heads
+    that do not exist are neither read nor walked."""
     f32 = jnp.float32
     C, s = chunk, min(SUB_BLOCK, chunk)
-    run = pl.program_id(2)
-    first = pl.program_id(1) * heads
+    # Chunks are taken `wide` at a time where no state is involved: their
+    # (C, C) matrices lie block-diagonally in one (P, P), P = wide·C ≤ 128
+    # lanes, so a product on them fills an MXU tile with two chunks' work.
+    P = wide * C
+    run, group = pl.program_id(2), pl.program_id(1)
     dk = q_ref.shape[2] // heads
     dv = v_ref.shape[2] // heads
 
@@ -116,70 +141,109 @@ def _gdn_kernel(q_ref, k_ref, v_ref, gam_ref, beta_ref, s0_ref, o_ref,
     def _enter():
         st_ref[...] = s0_ref[0]
 
-    every_g, every_b = gam_ref[0], beta_ref[0]                     # (R, H)
-    head_of = jax.lax.broadcasted_iota(jnp.int32, every_g.shape, 1)
-    at = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    to = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    def walk(live: int):
+        """The run for the group's first `live` heads."""
+        every_g, every_b = gam_ref[0], beta_ref[0]                 # (R, H)
+        head_of = jax.lax.broadcasted_iota(jnp.int32, every_g.shape, 1) \
+            - group * heads
+        rows = jax.lax.broadcasted_iota(jnp.int32, (P, P), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (P, P), 1)
+        same_chunk = rows // C == cols // C
+        same_block = rows // s == cols // s
+        rows, cols = rows % C, cols % C
 
-    # What no state enters, for every (head, chunk) of the step: the two
-    # (C, C) matrices, and A TRANSPOSED — A's row r over a sub-block's
-    # earlier rows is then a column, which is what the substitution scales
-    # T's rows by.
-    made = []
-    for i in range(heads):
-        live = first + i < total_heads
+        # What no state enters, for every (head, `wide` chunks) of the
+        # step: the two (P, P) matrices, and A TRANSPOSED — A's row r over
+        # a sub-block's earlier rows is then a column, which is what the
+        # substitution scales T's rows by.
+        made = []
+        for i in range(live):
+            q, k, v = (ref[0, :, i * d:(i + 1) * d]
+                       for ref, d in ((q_ref, dk), (k_ref, dk), (v_ref, dv)))
+            col_g, col_b = (jnp.sum(jnp.where(head_of == i, x, 0.0), axis=1,
+                                    keepdims=True)
+                            for x in (every_g, every_b))           # (R, 1)
+            for at in range(0, chunks * C, P):
+                here = slice(at, at + P)
+                both = mm_parts(jnp.concatenate([q[here], k[here]], axis=0),
+                                k[here], ((1,), (1,)))             # (2P, P)
+                down = jnp.broadcast_to(col_g[here], (P, P))
+                diff = down - down.T                     # γ_r − γ_i at [r, i]
+                a_qk = both[:P] * jnp.exp(
+                    jnp.where(same_chunk & (rows >= cols), diff, -jnp.inf))
+                M = col_b[here] * both[P:] * jnp.exp(
+                    jnp.where(same_chunk & (rows > cols), diff, -jnp.inf))
+                made.append((i, q[here], k[here], v[here], col_g[here],
+                             col_b[here], a_qk, M))
 
-        def lanes(ref, d):
-            return jnp.where(live, ref[0, :, i * d:(i + 1) * d].astype(f32),
-                             0.0)
+        # Rows inside a sub-block of `s`, every sub-block of the step at
+        # once and a (P, P)'s sub-blocks SIDE BY SIDE in lanes — (s, P),
+        # lanes s·b, … sub-block b's (s, s): a vreg is full of entries the
+        # substitution moves, where T's own rows are zeros outside their
+        # sub-block. Row r of a sub-block's inverse is e_r − Σ_{j<r}
+        # A[r, j]·row j; A[r, j] is lane s·b + r of Aᵀ's row j, spread over
+        # the sub-block's lanes by rolls (which no T enters: they are off
+        # the chain).
+        def side_by_side(x):
+            """(P, P) → its diagonal sub-blocks, (s, P)."""
+            x = jnp.where(same_block, x, 0.0)
+            return sum(x[b:b + s] for b in range(0, P, s))
 
-        q, k, v = lanes(q_ref, dk), lanes(k_ref, dk), lanes(v_ref, dv)
-        col_g, col_b = (jnp.sum(jnp.where(head_of == first + i, x, 0.0),
-                                axis=1, keepdims=True)
-                        for x in (every_g, every_b))               # (R, 1)
-        for c0 in range(0, chunks * C, C):
-            here = slice(c0, c0 + C)
-            both = mm(jnp.concatenate([q[here], k[here]], axis=0), k[here],
-                      ((1,), (1,)))                                # (2C, C)
-            down = jnp.broadcast_to(col_g[here], (C, C))
-            diff = down - down.T                         # γ_r − γ_i at [r, i]
-            a_qk = both[:C] * jnp.exp(jnp.where(at >= to, diff, -jnp.inf))
-            M = col_b[here] * both[C:] * jnp.exp(
-                jnp.where(at > to, diff, -jnp.inf))
-            made.append((i, q[here], k[here], v[here], col_g[here],
-                         col_b[here], a_qk, M, M.T))
+        AT = jnp.stack([side_by_side(x[-1].T) for x in made])
+        shape = AT.shape                                  # (·, s, P)
+        row_of = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 2) % s
+        T = jnp.zeros(shape, f32)
+        for r in range(s):
+            here = lane == r
+            col = jnp.where(here, AT, 0.0)
+            if r:
+                col = pltpu.roll(col, P - r, 2)
+            reach = 1
+            while reach < s:
+                col = col + pltpu.roll(col, reach, 2)
+                reach *= 2
+            above = jnp.sum(jnp.where(row_of < r, col, 0.0) * T, axis=1,
+                            keepdims=True)
+            T = jnp.where(row_of == r, jnp.where(here, 1.0, 0.0) - above, T)
 
-    # Rows inside a sub-block of `s`, every sub-block of the step at once:
-    # row r of a sub-block's inverse is e_r − Σ_{j<r} A[r, j]·row j.
-    nb = len(made) * C // s
-    MT3 = jnp.concatenate([x[-1] for x in made], axis=0).reshape(nb, s, C)
-    shape = (nb, s, C)
-    row_of = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 2) \
-        - jax.lax.broadcasted_iota(jnp.int32, shape, 0) % (C // s) * s
-    T = jnp.zeros(shape, f32)
-    for r in range(s):
-        here = lane == r
-        col = jnp.sum(jnp.where(here, MT3, 0.0), axis=2, keepdims=True)
-        above = jnp.sum(jnp.where(row_of < r, col, 0.0) * T, axis=1,
-                        keepdims=True)
-        T = jnp.where(row_of == r, jnp.where(here, 1.0, 0.0) - above, T)
-    T = T.reshape(len(made) * C, C)
+        # Then the chunks in sequence, four products each on the state.
+        o = [[] for _ in range(live)]
+        for n, (i, q, k, v, gam, beta, a_qk, M) in enumerate(made):
+            # back where the sub-blocks lie in (P, P), then the chunks' T
+            # from their sub-blocks' (ops/_delta_rule.py)
+            Tg = merge_rows(
+                jnp.where(same_block,
+                          jnp.concatenate([T[n]] * (P // s), axis=0), 0.0),
+                M, s, C, rows, cols, same_chunk)
+            for c0 in range(0, P, C):
+                here = slice(c0, c0 + C)
+                o[i].append(step(i, Tg[here, here], a_qk[here, here],
+                                 *(x[here] for x in (q, k, v, gam, beta))))
+        o_ref[0, :, :live * dv] = jnp.concatenate(
+            [jnp.concatenate(x, axis=0) for x in o], axis=1)
 
-    # Then the chunks in sequence, four products each on the state.
-    o = [[] for _ in range(heads)]
-    for n, (i, q, k, v, gam, beta, a_qk, M, _) in enumerate(made):
-        Tc = merge_blocks(T[n * C:(n + 1) * C], M, s, C, at, to)
+    def step(i, Tc, a_qk, q, k, v, gam, beta):
+        """Head i's chunk from its state and on it; `Tc`, `a_qk` (C, C):
+        the chunk's own block of its group's T and A_qk."""
         S = st_ref[i]                                         # (d_k, d_v)
         into = jnp.exp(gam)
-        kqs = mm(jnp.concatenate([k * into, q * into], axis=0), S)
-        U = mm(Tc, beta * (v - kqs[:C]))
+        kqs = jnp.concatenate([into, into], axis=0) * mm_parts(
+            jnp.concatenate([k, q], axis=0), S)               # (2C, d_v)
+        U = mm(Tc, beta * (v.astype(f32) - kqs[:C]))
         end = gam[C - 1:]
-        st_ref[i] = jnp.exp(end) * S + mm(k * jnp.exp(end - gam), U,
-                                          ((0,), (0,)))
-        o[i].append(kqs[C:] + mm(a_qk, U))
-    o_ref[0] = jnp.concatenate([jnp.concatenate(x, axis=0) for x in o],
-                               axis=1)
+        st_ref[i] = jnp.exp(end) * S + mm_parts(
+            k, jnp.exp(end - gam) * U, ((0,), (0,)))
+        return kqs[C:] + mm(a_qk, U)
+
+    # the last group's heads past the array's edge do not exist
+    rest = total_heads % heads
+    if rest:
+        last = pl.num_programs(1) - 1
+        pl.when(group < last)(lambda: walk(heads))
+        pl.when(group == last)(lambda: walk(rest))
+    else:
+        walk(heads)
 
     @pl.when(run == pl.num_programs(2) - 1)
     def _leave():
@@ -205,7 +269,10 @@ def _gdn_call(q, k, v, g, beta, S0, *, chunk: int, interpret: bool):
     B, L, H = beta.shape
     dk, dv = q.shape[-1] // H, v.shape[-1] // H
     heads = _heads_a_step(H, dk, dv, interpret)
+    # a run is whole groups of `wide` chunks, a group at most 128 rows
     chunks = min(RUN_CHUNKS, -(-L // chunk))
+    wide = min(chunks, max(1, 128 // chunk))
+    chunks = -(-chunks // wide) * wide
     R = chunks * chunk
     pad = (-L) % R
     with jax.named_scope("pt.layout"):
@@ -223,7 +290,7 @@ def _gdn_call(q, k, v, g, beta, S0, *, chunk: int, interpret: bool):
     with jax.named_scope("pt.kernel"):
         o, S = pl.pallas_call(
             functools.partial(_gdn_kernel, chunk=chunk, chunks=chunks,
-                              heads=heads, total_heads=H),
+                              wide=wide, heads=heads, total_heads=H),
             out_shape=(jax.ShapeDtypeStruct((B, L + pad, H * dv),
                                             jnp.float32),
                        jax.ShapeDtypeStruct((B, H, dk, dv), jnp.float32)),

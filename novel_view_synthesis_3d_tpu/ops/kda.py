@@ -77,8 +77,10 @@ head width; compiled, THIS kernel's head must be whole 128-lane blocks
 packs narrower heads into lane blocks, and ops/gdn.py's kernel slices
 heads of 96 and 192 lanes out of them). What the two delta-rule kernels
 share —
-the float32 product, a block's placement among zeros, the upper levels of
-the triangular inverse — is ops/_delta_rule.py's.
+the float32 product (and its form at fewer passes for operands that arrive
+in bfloat16, which only the scalar decay's kernel has), a block's placement
+among zeros, the upper levels of the triangular inverse — is
+ops/_delta_rule.py's.
 
 `kda_chunked` and `_kda_call` stamp `pt.kernel` around the `kda_fwd` call
 and nothing else, `pt.layout` around what feeds it and hands its result

@@ -2,17 +2,19 @@
 `gated_delta_chunked`) against the recurrence written out token by token,
 in float32 on the CPU — keys narrower than values, β across (0, 2), from a
 state that is not zero, over lengths that are not whole chunks, at the
-fastest decay the public draw allows —; the short convolution in front of
-it at heads that are no whole lane blocks (96 and 192 lanes: several heads
-share a group of lane blocks); and what the delta-rule operators share
-(ops/_delta_rule.py)."""
+fastest decay the public draw allows, with float32 operands and with the
+bfloat16 ones the convolution hands it (which take fewer MXU passes for the
+same float32: held to a float64 recurrence, where a part too few shows) —;
+the short convolution in front of it at heads that are no whole lane
+blocks (96 and 192 lanes: several heads share a group of lane blocks); and
+what the delta-rule operators share (ops/_delta_rule.py)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from novel_view_synthesis_3d_tpu.ops import _delta_rule, kda, short_conv
+from novel_view_synthesis_3d_tpu.ops import _delta_rule, gdn, kda, short_conv
 from novel_view_synthesis_3d_tpu.ops.gdn import CHUNK, gated_delta_chunked
 
 TOL = 2e-5
@@ -62,21 +64,83 @@ def close(got, want):
         assert float(jnp.max(jnp.abs(a - b))) < TOL * max(scale, 1.0)
 
 
-@pytest.mark.parametrize("B,L,H,dk,dv,chunk", [
-    (2, 100, 3, 8, 16, 16),      # values twice the keys, a ragged length
-    (1, 200, 2, 96, 192, CHUNK),  # the source's head, three chunks and a bit
-    (2, 40, 2, 8, 4, 8),         # keys WIDER than values, a chunk under 16
-    (1, 64, 1, 16, 16, 64),      # one whole chunk
-    (1, 70, 6, 96, 192, CHUNK),  # four heads a grid step: the second
-                                 # step's block hangs over the sixth head
-    (2, 300, 3, 32, 64, CHUNK),  # two runs of four chunks, heads 4 a step
-], ids=["8on16", "96on192", "8on4", "one_chunk", "edge_group", "two_runs"])
-def test_chunked_matches_the_recurrence(B, L, H, dk, dv, chunk):
-    args = operands(B, L, H, dk, dv)
+def narrowed(args, dtype):
+    """q, k, v as the convolution hands them over in that compute type."""
+    return [x.astype(dtype) for x in args[:3]] + list(args[3:])
+
+
+def widened(args):
+    return [x.astype(jnp.float32) for x in args]
+
+
+@pytest.mark.parametrize("B,L,H,dk,dv,chunk,dtype", [
+    (2, 100, 3, 8, 16, 16, "float32"),   # values twice the keys, a ragged
+                                         # length
+    (1, 200, 2, 96, 192, CHUNK, "float32"),  # the source's head, three
+                                             # chunks and a bit
+    (1, 200, 2, 96, 192, CHUNK, "bfloat16"),
+    (2, 40, 2, 8, 4, 8, "float32"),      # keys WIDER than values, a chunk
+                                         # under 16
+    (1, 64, 1, 16, 16, 64, "float32"),   # one whole chunk
+    (1, 70, 6, 96, 192, CHUNK, "float32"),   # four heads a grid step: the
+                                             # second step walks two
+    (1, 70, 6, 96, 192, CHUNK, "bfloat16"),
+    (2, 300, 3, 32, 64, CHUNK, "float32"),   # two runs of four chunks,
+                                             # heads 3 a step
+], ids=["8on16", "96on192", "96on192_bf16", "8on4", "one_chunk",
+        "edge_group", "edge_group_bf16", "two_runs"])
+def test_chunked_matches_the_recurrence(B, L, H, dk, dv, chunk, dtype):
+    args = narrowed(operands(B, L, H, dk, dv), dtype)
     got = gated_delta_chunked(*args, chunk=chunk)
     assert got[0].shape == (B, L, H * dv) and got[0].dtype == jnp.float32
     assert got[1].shape == (B, H, dk, dv) and got[1].dtype == jnp.float32
-    close(got, recurrence(*args))
+    close(got, recurrence(*widened(args)))
+
+
+def recurrence64(q, k, v, g, beta, S0):
+    """`recurrence` in numpy's float64: what float32 means to approach."""
+    q, k, v, g, beta, S = (np.asarray(x, np.float64)
+                           for x in (q, k, v, g, beta, S0))
+    B, L, H = beta.shape
+    q, k, v = (x.reshape(B, L, H, -1) for x in (q, k, v))
+    o = np.zeros(v.shape)
+    for t in range(L):
+        S = np.exp(g[:, t])[..., None, None] * S
+        u = beta[:, t][..., None] * (
+            v[:, t] - np.einsum("bhkv,bhk->bhv", S, k[:, t]))
+        S = S + k[:, t][..., :, None] * u[..., None, :]
+        o[:, t] = np.einsum("bhkv,bhk->bhv", S, q[:, t])
+    return o.reshape(B, L, -1), S
+
+
+def worst(got, want):
+    """The largest error of o and of the state, each as a share of the
+    largest value."""
+    return max(float(np.max(np.abs(np.asarray(a, np.float64) - b))
+                     / np.max(np.abs(b))) for a, b in zip(got, want))
+
+
+@pytest.fixture
+def retraced():
+    """`_gdn_call` is jitted: what a test plants in the product is traced
+    in only by a fresh trace, and must not stay traced in behind it."""
+    yield
+    gdn._gdn_call.clear_cache()
+
+
+def test_bfloat16_operands_keep_every_bit_of_the_float32_scan(
+        monkeypatch, retraced):
+    """bfloat16 q, k, v take fewer passes (`_delta_rule.mm_parts`), not
+    fewer bits: against the float64 recurrence the scan stays within 3e-6
+    of the largest value — and the state or U cut to TWO bfloat16 parts
+    (16 of a float32's 24 bits), planted here, does not: no later edit
+    trades passes for bits unseen."""
+    args = narrowed(operands(1, 256, 2, 96, 192, seed=11), "bfloat16")
+    want = recurrence64(*widened(args))
+    assert worst(gated_delta_chunked(*args), want) < 3e-6
+    monkeypatch.setattr(_delta_rule, "PARTS", 2)
+    gdn._gdn_call.clear_cache()
+    assert worst(gated_delta_chunked(*args), want) > 3e-6
 
 
 @pytest.mark.parametrize("beta", [0.0, 0.05, 1.0, 1.95, 2.0])
@@ -163,10 +227,8 @@ def test_near_parallel_keys_under_beta_two(C):
 def test_both_delta_rule_operators_share_one_product_and_one_merge():
     assert kda._mm is _delta_rule.mm and kda._placed is _delta_rule.placed
     assert kda.merge_blocks is _delta_rule.merge_blocks
-    from novel_view_synthesis_3d_tpu.ops import gdn
-
-    assert gdn.mm is _delta_rule.mm
-    assert gdn.merge_blocks is _delta_rule.merge_blocks
+    assert gdn.mm is _delta_rule.mm and gdn.mm_parts is _delta_rule.mm_parts
+    assert gdn.merge_rows is _delta_rule.merge_rows
     a = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
     b = jnp.ones((4, 5), jnp.float32)
     np.testing.assert_allclose(_delta_rule.mm(a, b), a @ b)
@@ -175,6 +237,72 @@ def test_both_delta_rule_operators_share_one_product_and_one_merge():
     x = jnp.ones((2, 3))
     np.testing.assert_array_equal(
         _delta_rule.placed(x, 1, 4), jnp.pad(x, ((1, 1), (0, 0))))
+
+
+@pytest.mark.parametrize("contract", [((1,), (0,)), ((1,), (1,)),
+                                      ((0,), (0,))],
+                         ids=["ab", "abT", "aTb"])
+@pytest.mark.parametrize("types", ["bfloat16·bfloat16", "bfloat16·float32",
+                                   "float32·bfloat16", "float32·float32"])
+def test_mm_parts_is_the_float32_product_whatever_the_passes(types, contract):
+    """`mm_parts` against numpy's float64 product, at every contraction
+    `mm` takes: a bfloat16 operand is one part, the float32 one opposite
+    it three — the WHOLE float32 product, to the accumulator's rounding —,
+    two float32 operands are `mm`; two bfloat16 operands and a float32
+    result are exact but for the sum's order."""
+    rng = np.random.default_rng(5)
+    (ca,), (cb,) = contract
+    a = rng.normal(size=(24, 40) if ca else (40, 24))
+    b = rng.normal(size=(40, 16) if not cb else (16, 40))
+    a, b = (jnp.asarray(x, jnp.float32).astype(t)
+            for x, t in zip((a, b), types.split("·")))
+    got = _delta_rule.mm_parts(a, b, contract)
+    assert got.dtype == jnp.float32 and got.shape == (24, 16)
+    a64, b64 = (np.asarray(x.astype(jnp.float32), np.float64)
+                for x in (a, b))
+    want = (a64 if ca else a64.T) @ (b64.T if cb else b64)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+    if types == "float32·float32":
+        np.testing.assert_array_equal(got, _delta_rule.mm(a, b, contract))
+
+
+@pytest.mark.parametrize("P,C,size", [(128, 64, 16), (64, 64, 16),
+                                      (32, 8, 4), (128, 64, 32)])
+def test_merge_rows_is_merge_blocks_at_half_the_rows(P, C, size):
+    """Both merges from the same sub-block inverses, for P // C chunks
+    block-diagonally in one (P, P): the same T, which is (I + M)⁻¹ of
+    each chunk."""
+    rng = np.random.default_rng(P + size)
+    rows, cols = np.indices((P, P))
+    same = rows // C == cols // C
+    M = np.where(same & (rows > cols), rng.normal(size=(P, P)), 0.0)
+    T0 = np.zeros((P, P))
+    for at in range(0, P, size):
+        block = slice(at, at + size)
+        T0[block, block] = np.linalg.inv(np.eye(size) + M[block, block])
+    args = (jnp.asarray(T0, jnp.float32), jnp.asarray(M, jnp.float32), size,
+            C, jnp.asarray(rows % C), jnp.asarray(cols % C),
+            jnp.asarray(same))
+    got = _delta_rule.merge_rows(*args)
+    np.testing.assert_allclose(got, _delta_rule.merge_blocks(*args),
+                               rtol=0, atol=1e-5)
+    want = np.zeros((P, P))
+    for at in range(0, P, C):
+        block = slice(at, at + C)
+        want[block, block] = np.linalg.inv(np.eye(C) + M[block, block])
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2e-5 * np.abs(want).max())
+
+
+def test_bfloat16_parts_hold_every_bit():
+    x = jnp.asarray(np.random.default_rng(6).normal(size=(8, 128))
+                    * 10.0 ** np.arange(-4, 4)[:, None], jnp.float32)
+    parts = _delta_rule.bfloat16_parts(x)
+    assert len(parts) == 3 and all(p.dtype == jnp.bfloat16 for p in parts)
+    np.testing.assert_array_equal(
+        sum(np.asarray(p.astype(jnp.float32), np.float64) for p in parts),
+        np.asarray(x, np.float64))
 
 
 # ---------------------------------------------------------------------------

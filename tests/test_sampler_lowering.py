@@ -138,14 +138,18 @@ DIGESTS = {
         "05f8f63c7fa473cb754df607ae529763c219dde26b375809c49a704be6f2f054",
     ("p4f_denoiser256", "v5e"):
         "8d3815a1662ead5a40d76159f15dcc8e09008623a3a645cd0baedb5df00a31eb",
-    # PR 42's tree, these two: both delta-rule layers hand their scan's o
-    # and the gate's projection to `head_norm_fwd` (ops/head_norm.py) where
-    # they held the norm on a (B, L, H, d) view; the six above are PR 41's
-    # (CHANGES.md, PR 42).
+    # PR 46's tree, these two: `gdn_fwd` multiplies q and k as the bfloat16
+    # they arrive in and S and U in three parts, two chunks side by side
+    # wherever an operand is (C, C), T's merges on the rows they change,
+    # the heads past the edge not walked;
+    # until then they were PR 42's (both delta-rule layers hand their
+    # scan's o and the gate's projection to `head_norm_fwd`,
+    # ops/head_norm.py), as `kl48`'s scan still is. The other twelve are
+    # the parent's (CHANGES.md, PR 46).
     ("oh7_denoiser256", "cpu"):
-        "cb2f8f0dade55f98ceb4c906475321c4ab09c7d6f05e5830750ed2f2f13439b8",
+        "4bb1c0b6a74ddc4e79b8f4e0be563228d07365bf92feec93933edf7bdeb055a6",
     ("oh7_denoiser256", "v5e"):
-        "9b558d7285dee20601aff53a5019b4a0caabfbdc05fd28fa9b433037e6be6e29",
+        "99aae2d5daaa435d323e6d30be69c63f1a74afd3bae51b44716e0a643fec28ca",
     # PR 44's tree, pinned by PR 45 (whose tree lowers the same text: the
     # banded form takes no second operand)
     ("st21_denoiser256", "cpu"):

@@ -766,6 +766,56 @@ def test_kda_compiles_and_fits_for_v5e(name, v5e, monkeypatch):
     assert mem.temp_size_in_bytes < TEMP_LIMITS[name], mem.temp_size_in_bytes
 
 
+def _kernel_products(text):
+    """The operand types of every `tpu.matmul` in the one Mosaic kernel of
+    a lowered program, read off the kernel's own module (the custom call's
+    serialized body)."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    (config,) = re.findall(r'backend_config = "([^"]*)"', text)
+    body = json.loads(re.sub(
+        r"\\([0-9A-Fa-f]{2})", lambda m: chr(int(m.group(1), 16)),
+        config))["custom_call_config"]["body"]
+    with jax_mlir.make_ir_context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+    return re.findall(r"tpu\.matmul.* : \(vector<[0-9x]+x(\w+)>, "
+                      r"vector<[0-9x]+x(\w+)>, ", asm)
+
+
+def test_gdn_products_take_the_passes_their_operands_leave(v5e, monkeypatch):
+    """`gdn_fwd` at its cell's shape, lowered for the described v5e: which
+    products run on bfloat16 operands is decided by dtype when the kernel
+    is traced, so its module is where it shows. A step's kernel holds the
+    walk of four heads and, for the last group, of the two that exist: 6
+    heads × (2 pairs of chunks × [both; two merge levels of 2 products]
+    + 4 chunks × [kqs, U, the state's, O's]) = 156 products. With bfloat16
+    q, k, v the 12 `both`, the 24 `kqs` and the 24 state products take
+    bfloat16 operands (one pass; three stacked along the contraction), the
+    48 merge products — each on the 64 rows of 128 its level changes — and
+    the 48 on T and A_qk keep two float32 operands (six passes); with
+    float32 q, k, v none takes bfloat16."""
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    fn, arg_specs = CASES["gdn_chunked_2x4096_h30_k96_v192"]
+
+    def products(dtype):
+        return _kernel_products(jax.jit(fn).lower(*[
+            jax.ShapeDtypeStruct(shape, dtype if was == BF16 else was,
+                                 sharding=v5e)
+            for shape, was in arg_specs]).as_text())
+
+    narrow = products(BF16)
+    assert len(narrow) == 156
+    assert narrow.count(("bf16", "bf16")) == 60
+    assert narrow.count(("f32", "f32")) == 96
+    assert products(F32) == [("f32", "f32")] * 156
+
+
 def test_lcf_sampler_fits_the_described_v5e(v5e, monkeypatch):
     """`make_sampler` of `lcf_denoiser256` at its cell's size (1 view, 8
     steps, guidance 3) compiled for the described chip: the arguments are
