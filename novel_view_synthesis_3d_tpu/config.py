@@ -414,11 +414,114 @@ class LongcatFlashTrunkConfig:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
+_LAGUNA_PERIOD = ("full_attention",) + ("sliding_attention",) * 3
+
+
+@dataclasses.dataclass(frozen=True)
+class LagunaRope:
+    """One entry of a `laguna` config.json's `rope_parameters`: the rotary
+    law of ONE kind of layer. `rope_type` "default" is plain θ^(−2i/d);
+    "yarn" reads the four keys after it and scales cos and sin by
+    `attention_factor`. The first `partial_rotary_factor` of a head's
+    lanes rotate, the rest pass."""
+
+    rope_type: str = "default"
+    rope_theta: float = 10000
+    partial_rotary_factor: float = 1
+    factor: float = 1
+    original_max_position_embeddings: int = 8192
+    beta_fast: float = 32
+    beta_slow: float = 1
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LagunaRopeParameters:
+    """`rope_parameters` of a `laguna` config.json: keyed BY LAYER KIND."""
+
+    full_attention: LagunaRope = LagunaRope(
+        rope_type="yarn", rope_theta=500000, partial_rotary_factor=0.5,
+        factor=128, attention_factor=1.4852030263919618)
+    sliding_attention: LagunaRope = LagunaRope()
+
+
+@dataclasses.dataclass(frozen=True)
+class LagunaTrunkConfig:
+    """The token denoiser's seventh trunk: Laguna-S-2.1's decoder stack
+    under the key names of its `laguna` `config.json` — grouped-query
+    attention whose QUERY-HEAD COUNT depends on the layer
+    (`num_attention_heads_per_layer`: 48 in a "full_attention" layer, 72 in
+    a "sliding_attention" layer, `layer_types`) on the same
+    `num_key_value_heads` keys and values; a rotary law a layer kind
+    (`rope_parameters`: yarn on half of a head's lanes in full layers,
+    plain on all of them under the one-sided `sliding_window`); a sigmoid
+    gate a head on the attention's output (`gating` "per-head"); by
+    `mlp_layer_types` a dense gated-SiLU MLP (the leading layer) or a
+    softmax router over `num_experts`, top-k renormalised and scaled,
+    beside one shared expert. The three per-layer tuples keep their
+    published 48 entries; the program reads the first `num_hidden_layers`.
+    The defaults are the published values (`num_attention_heads` 48 is the
+    full layers' count and is read by no layer); a preset sets the depth
+    and the experts this chip holds."""
+
+    hidden_size: int = 3072
+    intermediate_size: int = 12288
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 48
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    attention_bias: bool = False
+    rms_norm_eps: float = 1e-6
+    num_experts: int = 256
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 1024
+    shared_expert_intermediate_size: int = 1024
+    norm_topk_prob: bool = True
+    decoder_sparse_step: int = 1
+    mlp_only_layers: Tuple[int, ...] = (0,)
+    gating: str = "per-head"
+    sliding_window: int = 512
+    rope_parameters: LagunaRopeParameters = LagunaRopeParameters()
+    layer_types: Tuple[str, ...] = _LAGUNA_PERIOD * 12
+    mlp_layer_types: Tuple[str, ...] = ("dense",) + ("sparse",) * 47
+    num_attention_heads_per_layer: Tuple[int, ...] = (48, 72, 72, 72) * 12
+    moe_apply_router_weight_on_input: bool = False
+    moe_routed_scaling_factor: float = 2.5
+    moe_router_logit_softcapping: float = 0
+    # As TokenTrunkConfig's: the (first, count) experts this chip holds,
+    # and the patch adapter.
+    held_experts: Tuple[int, int] = (0, 256)
+    patch_size: int = 4
+
+    # The expert layer's names for the same things. The source names no
+    # score function: its keys are the Qwen2-MoE family's, whose router is
+    # a float32 softmax (the configuration file's `assumed`).
+    expert_activation = "silu"
+    router_activation = "softmax"
+
+    @property
+    def n_routed_experts(self) -> int:
+        return self.num_experts
+
+    @property
+    def routed_scaling_factor(self) -> float:
+        return self.moe_routed_scaling_factor
+
+    def is_window(self, i: int) -> bool:
+        """Layer i (0-based) attends under `sliding_window`."""
+        return self.layer_types[i] == "sliding_attention"
+
+    def is_dense(self, i: int) -> bool:
+        """Layer i carries the dense MLP, not experts."""
+        return self.mlp_layer_types[i] == "dense"
+
+
 # The trunks `ModelConfig.tokens` may hold; a serialized config says
 # which by its keys (they share only sizes every trunk has).
 TOKEN_TRUNKS = (TokenTrunkConfig, SmallThinkerTrunkConfig,
                 KimiLinearTrunkConfig, Phi4FlashTrunkConfig,
-                OlmoHybridTrunkConfig, LongcatFlashTrunkConfig)
+                OlmoHybridTrunkConfig, LongcatFlashTrunkConfig,
+                LagunaTrunkConfig)
 
 
 def _trunk_of_keys(keys) -> type:
@@ -2207,6 +2310,59 @@ def _longcat_flash_errors(k: LongcatFlashTrunkConfig) -> list:
     return errors
 
 
+def _laguna_errors(k: LagunaTrunkConfig) -> list:
+    """What layers that differ by index in THREE tuples, and two rotary
+    laws, need of the settings."""
+    errors = []
+    N = k.num_hidden_layers
+    tuples = {n: getattr(k, n) for n in (
+        "layer_types", "num_attention_heads_per_layer", "mlp_layer_types")}
+    if len({len(t) for t in tuples.values()}) != 1 \
+            or len(k.layer_types) < N:
+        return [f"model.tokens: {', '.join(tuples)} must have one length, "
+                f"an entry a layer for {N} layers (got "
+                f"{[len(t) for t in tuples.values()]})"]
+    if not set(k.layer_types[:N]) <= {"full_attention", "sliding_attention"}:
+        errors.append("model.tokens.layer_types: 'full_attention' or "
+                      "'sliding_attention'")
+    elif k.sliding_window < 1 and "sliding_attention" in k.layer_types[:N]:
+        errors.append("model.tokens.layer_types names sliding_attention "
+                      f"layers under sliding_window={k.sliding_window}")
+    dense = tuple(i for i in range(N) if k.mlp_layer_types[i] == "dense")
+    if not set(k.mlp_layer_types[:N]) <= {"dense", "sparse"} \
+            or dense != tuple(i for i in k.mlp_only_layers if i < N) \
+            or k.decoder_sparse_step != 1:
+        errors.append(
+            "model.tokens.mlp_layer_types ('dense' or 'sparse') must name "
+            "as dense exactly mlp_only_layers, at decoder_sparse_step=1")
+    bad = [n for n in k.num_attention_heads_per_layer[:N]
+           if n < 1 or n % k.num_key_value_heads]
+    if bad:
+        errors.append(
+            f"model.tokens.num_attention_heads_per_layer: {sorted(set(bad))}"
+            f" is not a multiple of num_key_value_heads="
+            f"{k.num_key_value_heads}")
+    for kind in ("full_attention", "sliding_attention"):
+        rope = getattr(k.rope_parameters, kind)
+        dim = k.head_dim * rope.partial_rotary_factor
+        if dim != int(dim) or int(dim) % 2 or not 0 < dim <= k.head_dim:
+            errors.append(
+                f"model.tokens.rope_parameters.{kind}: head_dim × "
+                f"partial_rotary_factor = {dim:g} must be an even number "
+                "of lanes of a head (rotary pairs)")
+        if rope.rope_type not in ("default", "yarn"):
+            errors.append(f"model.tokens.rope_parameters.{kind}.rope_type="
+                          f"{rope.rope_type!r}: 'default' or 'yarn'")
+    if k.attention_bias or k.gating != "per-head" \
+            or k.moe_apply_router_weight_on_input \
+            or k.moe_router_logit_softcapping:
+        errors.append(
+            "model.tokens: attention_bias=True, gating other than "
+            "'per-head', moe_apply_router_weight_on_input=True and a "
+            "router logit soft cap are not carried")
+    return errors
+
+
 def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
     """What a `family: tokens` model needs of its settings."""
     k = m.tokens
@@ -2230,6 +2386,8 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
         elif not 1 <= k.num_experts_per_tok <= k.n_routed_experts:
             errors.append("model.tokens.num_experts_per_tok must be in "
                           "[1, n_routed_experts]")
+        if isinstance(k, LagunaTrunkConfig):
+            errors += _laguna_errors(k)
     if d.img_sidelength % k.patch_size:
         errors.append(
             f"data.img_sidelength={d.img_sidelength} is not a multiple of "
@@ -2281,7 +2439,8 @@ def _token_family_errors(m: ModelConfig, d: DataConfig) -> list:
 # ----------------------------------------------------------------------
 PRESET_NAMES = ("reference", "tiny64", "base128", "paper256", "pod64",
                 "ms4_denoiser128", "st21_denoiser256", "kl48_denoiser256",
-                "p4f_denoiser256", "oh7_denoiser256", "lcf_denoiser256")
+                "p4f_denoiser256", "oh7_denoiser256", "lcf_denoiser256",
+                "lgs_denoiser256")
 
 
 def get_preset(name: str) -> Config:
@@ -2459,6 +2618,26 @@ def get_preset(name: str) -> Config:
                 dropout=0.0,
                 tokens=LongcatFlashTrunkConfig(num_layers=4,
                                                held_experts=(0, 16))),
+            data=DataConfig(img_sidelength=256),
+            diffusion=DiffusionConfig(sample_timesteps=256),
+        )
+    if name == "lgs_denoiser256":
+        # A token denoiser whose trunk is Laguna-S-2.1's decoder stack at
+        # its published widths (LagunaTrunkConfig's defaults), cut to chip
+        # 0 of stage 0 of a deployment in which 2 chips share each layer
+        # by expert parallelism (128 of 256 routed experts a chip;
+        # attention, the dense layer and the shared expert replicated):
+        # layers 0-4 of 48 — the leading dense layer under 48-head full
+        # attention, then one whole period of [72-head window 512 x 3,
+        # 48-head full] with experts 0-127 held (the router keeps its 256
+        # outputs and top-10). bfloat16 parameters: 5.29 B = 10.58 GB.
+        # 256 px, 4096 tokens a frame: the window is an eighth of a frame.
+        return Config(
+            model=ModelConfig(
+                family="tokens", dtype="bfloat16", param_dtype="bfloat16",
+                dropout=0.0,
+                tokens=LagunaTrunkConfig(num_hidden_layers=5,
+                                         held_experts=(0, 128))),
             data=DataConfig(img_sidelength=256),
             diffusion=DiffusionConfig(sample_timesteps=256),
         )

@@ -16,7 +16,7 @@
     blocks named by `op_groups(config)` of the family's module.
 
 `model.family` selects: "xunet" (models/xunet.XUNet, the default) or
-"tokens" (models/token_denoiser.TokenDenoiser). The token family has six
+"tokens" (models/token_denoiser.TokenDenoiser). The token family has seven
 trunks behind that one class — `model.tokens` is one of
 config.TOKEN_TRUNKS and names the layers: Mistral-Small-4's (latent
 attention, a shared expert; its cache entry a latent), SmallThinker's
@@ -29,7 +29,11 @@ keys narrower than values — or full attention under a QK norm, by index;
 every sublayer's OUTPUT normalised inside the residual; no expert layer),
 LongCat-Flash's shortcut-connected double layer (two latent attentions and
 two dense MLPs a layer, one expert branch across them over a router whose
-last outputs are identity experts; its cache entry TWO latents), or
+last outputs are identity experts; its cache entry TWO latents), Laguna's
+stack (grouped-query heads whose COUNT depends on the layer, 48 full and
+72 under a window on the same 8 keys and values, a rotary law a layer
+kind, a sigmoid gate a head on the attention's output; a window layer's
+cache entry the window's tail), or
 Phi-4-mini-flash's whole stack (Mamba selective-scan layers, differential
 attention under a window and full, then gated memory units and cross
 layers that READ what two earlier layers publish in the same pass — one

@@ -1,7 +1,7 @@
 """A token denoiser: patch tokens of both frames through a decoder trunk
 of a published language model, ε̂ of the target frame out.
 
-**Six trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
+**Seven trunks, one frame.** `config.tokens` is one of config.TOKEN_TRUNKS
 and names the layers; the frame asks the layer object for layer i's
 parameter tree and takes back layer i's cache entry — or None, from a layer
 that keeps nothing of a frame — so a trunk's layers may differ by index,
@@ -83,6 +83,18 @@ same pass (one trunk's; the others' calls do not take it):
     renormalised — a token's twelve choices hold 0 to 12 real experts.
     Its cache of a frame is TWO latents a layer.
 
+  - `LagunaLayer` (config.LagunaTrunkConfig; Laguna-S-2.1): grouped-query
+    attention whose query-head COUNT depends on the layer — 48 heads in a
+    full layer, 72 under a one-sided 512 window, on the same 8 keys and
+    values of 128 — with a rotary law a layer kind (yarn on a head's first
+    64 lanes, cos and sin scaled, in full layers; plain on all 128 under
+    the window) and a sigmoid gate a head, read from the layer's
+    normalised input, on the attention's output before W_o; a dense
+    gated-SiLU MLP in the leading layer, then a softmax router over 256,
+    top-10 renormalised × 2.5, beside one shared expert. Its cache of a
+    frame is the keys (rotated by the layer's law) and values — under a
+    window their last 511 rows alone.
+
 `route` and `held_expert_part` are one function each for all that route
 (the scoring function, the choice's bias, top-k, the renormalisation and
 the activation come from the trunk's config and its router's parameters),
@@ -104,7 +116,7 @@ repo's and not a source's is the frame around the trunk:
 **The once-a-call pass.** Because of that mask, everything a step needs of
 the conditioning frame is its per-layer cache. `precompute` runs the
 conditioning frame once (prefill) through the layers UP TO THE LAST THAT
-KEEPS A CACHE ENTRY — all of them in five trunks, layers 0–17 of the
+KEEPS A CACHE ENTRY — all of them in six trunks, layers 0–17 of the
 fourth's 32: nothing its cross-decoder computes of that frame is ever
 read, and the pass is built without it, not left to the compiler to cut —
 and every denoise step runs the target's tokens alone against [cache ;
@@ -140,9 +152,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from novel_view_synthesis_3d_tpu.config import (
-    KimiLinearTrunkConfig, LongcatFlashTrunkConfig, ModelConfig,
-    OlmoHybridTrunkConfig, Phi4FlashTrunkConfig, SmallThinkerTrunkConfig,
-    TokenTrunkConfig)
+    KimiLinearTrunkConfig, LagunaTrunkConfig, LongcatFlashTrunkConfig,
+    ModelConfig, OlmoHybridTrunkConfig, Phi4FlashTrunkConfig,
+    SmallThinkerTrunkConfig, TokenTrunkConfig)
 from novel_view_synthesis_3d_tpu.models.rays import camera_rays
 from novel_view_synthesis_3d_tpu.ops.expert_combine import combine
 from novel_view_synthesis_3d_tpu.ops.flash_attention import (
@@ -243,7 +255,7 @@ def op_groups(cfg: ModelConfig):
 
 def param_shapes(cfg: ModelConfig) -> dict:
     """The parameter tree as ShapeDtypeStructs. 2-D kernels are (in, out);
-    an expert stack is (held, in, out). Five trunks have no bias at all
+    an expert stack is (held, in, out). Six trunks have no bias at all
     (a router's correction bias apart); Phi4FlashLayer's has LayerNorm
     weights AND biases, and biases on its attention projections, its
     convolution and its step projection."""
@@ -724,6 +736,31 @@ def gated_mlp(x, p):
                   p["down"])
 
 
+def feed_forward(cfg, p, h, dense: bool):
+    """The second half of a pre-norm layer whose parameters `p` hold
+    `mlp_norm` and either `mlp` (a dense gated-SiLU MLP: `dense`) or
+    `router`, `experts` and `shared`: h + MLP(RMSNorm(h)), or h + the held
+    experts' part (`route`, `held_expert_part`) + the shared expert of it.
+    → (h, (tokens per held expert, each token's chosen experts (B, L, k)) —
+    (None, None) from the dense form)."""
+    k = cfg.tokens
+    dt, eps = jnp.dtype(cfg.dtype), k.rms_norm_eps
+    B, L, _ = h.shape
+    if dense:
+        with jax.named_scope("lk.dense_mlp"):
+            b = rms_norm(h, p["mlp_norm"]["scale"], eps).astype(dt)
+            return h + gated_mlp(b, p["mlp"]), (None, None)
+    with jax.named_scope("lk.moe_route"):
+        b32 = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(B * L, -1)
+        top_p, top_i = route(b32, p["router"], k)
+        b = b32.astype(dt)
+    routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
+    with jax.named_scope("lk.moe_shared"):
+        shared = gated_mlp(b, p["shared"])
+        h = h + (shared + routed).reshape(B, L, -1)
+    return h, (counts, top_i.reshape(B, L, -1))
+
+
 def conv_qkv(p, qkv, tail, heads: int, scale: float):
     """A delta-rule layer's q, k, v projections each through its own taps
     (`p["q_conv"]`, …), SiLU, q's and k's head-wise L2 norm (q's with the
@@ -811,7 +848,6 @@ class Mistral4Layer:
         cfg, k = self.config, self.config.tokens
         dt = jnp.dtype(cfg.dtype)
         eps = k.rms_norm_eps
-        B, L, _ = h.shape
         cos, sin, qscale = tables
         with jax.named_scope("lk.mla_proj"):
             a = rms_norm(h, p["attn_norm"]["scale"], eps).astype(dt)
@@ -821,15 +857,8 @@ class Mistral4Layer:
                     lambda x: x * jnp.asarray(qscale, dt)[None, :, None], q)
         h, own = latent_attention(cfg, p, h, a, q, cache, softmax_scale(k),
                                   rope=(cos, sin))
-        with jax.named_scope("lk.moe_route"):
-            b32 = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(B * L, -1)
-            top_p, top_i = route(b32, p["router"], k)
-            b = b32.astype(dt)
-        routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
-        with jax.named_scope("lk.moe_shared"):
-            shared = gated_mlp(b, p["shared"])
-            h = h + (shared + routed).reshape(B, L, -1)
-        return h, own, (counts, top_i.reshape(B, L, -1))
+        h, routed = feed_forward(cfg, p, h, dense=False)
+        return h, own, routed
 
     def key_columns(self, L: int):
         """No layer of this trunk has a window."""
@@ -1055,28 +1084,28 @@ class KimiLinearLayer:
     def __call__(self, i, p, h, tables, cache):
         del tables
         k = self.config.tokens
-        dt, eps = jnp.dtype(self.config.dtype), k.rms_norm_eps
-        B, L, _ = h.shape
         mix = self._mla if k.is_full_attention(i) else self._kda
         h, own = mix(p, h, cache)
-        if k.is_dense(i):
-            with jax.named_scope("lk.dense_mlp"):
-                b = rms_norm(h, p["mlp_norm"]["scale"], eps).astype(dt)
-                h = h + gated_mlp(b, p["mlp"])
-            return h, own, (None, None)
-        with jax.named_scope("lk.moe_route"):
-            b32 = rms_norm(h, p["mlp_norm"]["scale"], eps).reshape(B * L, -1)
-            top_p, top_i = route(b32, p["router"], k)
-            b = b32.astype(dt)
-        routed, counts = held_expert_part(b, top_p, top_i, p["experts"], k)
-        with jax.named_scope("lk.moe_shared"):
-            shared = gated_mlp(b, p["shared"])
-            h = h + (shared + routed).reshape(B, L, -1)
-        return h, own, (counts, top_i.reshape(B, L, -1))
+        h, routed = feed_forward(self.config, p, h, k.is_dense(i))
+        return h, own, routed
 
     def key_columns(self, L: int):
         """No layer of this trunk has a window."""
         return 0, 0
+
+
+def tail_key_columns(layer, L: int):
+    """`key_columns` of a trunk whose window layers keep their window's
+    TAIL of a frame (`layer.window(i)`, the config's `sliding_window`):
+    (visited, visible) key columns of L target queries against [tail ;
+    own], summed over the layers whose window binds there
+    (ops/flash_attention.band_key_columns)."""
+    k = layer.config.tokens
+    tail = min(L, k.sliding_window - 1)
+    per_layer = [band_key_columns(L, tail + L, layer.window(i), tail)
+                 for i in range(k.num_hidden_layers)
+                 if window_binds(L, layer.window(i), tail)]
+    return tuple(sum(c) for c in zip(*per_layer)) if per_layer else (0, 0)
 
 
 class Phi4FlashLayer:
@@ -1285,12 +1314,7 @@ class Phi4FlashLayer:
         """(visited, visible) key columns of one map's L target queries
         against [the window's tail ; own], summed over the window layers
         whose window binds there."""
-        k = self.config.tokens
-        tail = min(L, k.sliding_window - 1)
-        per_layer = [band_key_columns(L, tail + L, self.window(i), tail)
-                     for i in range(k.num_hidden_layers)
-                     if window_binds(L, self.window(i), tail)]
-        return tuple(sum(c) for c in zip(*per_layer)) if per_layer else (0, 0)
+        return tail_key_columns(self, L)
 
 
 class OlmoHybridLayer:
@@ -1540,12 +1564,138 @@ class LongcatFlashLayer:
         return 0, 0
 
 
+def partial_rope_tables(positions, rope, head_dim: int):
+    """cos, sin (L, dim/2) float32 of one of `LagunaRopeParameters`' laws
+    over the `partial_rotary_factor` · head_dim lanes it rotates: plain
+    θ^(−2i/dim), or yarn's blended frequencies with cos and sin times
+    `attention_factor`."""
+    dim = int(head_dim * rope.partial_rotary_factor)
+    if rope.rope_type != "yarn":
+        return plain_rope_tables(positions, dim, rope.rope_theta)
+    ang = np.asarray(positions, np.float64)[:, None] \
+        * yarn_inv_freq(rope, dim)[None]
+    factor = float(rope.attention_factor)
+    return ((np.cos(ang) * factor).astype(np.float32),
+            (np.sin(ang) * factor).astype(np.float32))
+
+
+def apply_rope_prefix(x, cos, sin):
+    """`apply_rope` (halves) on the first 2 · cos.shape[-1] lanes of every
+    head of x (B, L, heads, D); the lanes past them pass as they are."""
+    dim = 2 * cos.shape[-1]
+    if dim == x.shape[-1]:
+        return apply_rope(x, cos, sin, False)
+    return jnp.concatenate([apply_rope(x[..., :dim], cos, sin, False),
+                            x[..., dim:]], axis=-1)
+
+
+class LagunaLayer:
+    """Laguna's layers: grouped-query attention with a query-head count, a
+    rotary law and a window or none BY INDEX (`num_attention_heads_per_layer`,
+    `layer_types`) on one set of key/value heads, a sigmoid gate a head on
+    the attention's output, and by index (`mlp_layer_types`) a dense MLP or
+    softmax-routed experts beside one shared expert. A full layer's cache
+    entry is the frame's rotated keys and its values; a window layer's is
+    their last `sliding_window` − 1 rows (no later query sees an earlier
+    one)."""
+
+    cache_name = "layer_cache"
+    has_experts, publishes = True, False
+
+    def __init__(self, config: ModelConfig):
+        self.config = config
+
+    def cache_kind(self, i):
+        return "window_tail" if self.config.tokens.is_window(i) \
+            else "keys_values"
+
+    def param_shapes(self, w, i):
+        k = self.config.tokens
+        H, D = k.hidden_size, k.head_dim
+        N, NKV = k.num_attention_heads_per_layer[i], k.num_key_value_heads
+        if k.is_dense(i):
+            ffn = {"mlp": _mlp_shapes(w, H, k.intermediate_size)}
+        else:
+            ffn = {"router": {"kernel": w(H, k.num_experts)},
+                   "shared": _mlp_shapes(
+                       w, H, k.shared_expert_intermediate_size),
+                   "experts": _mlp_shapes(w, H, k.moe_intermediate_size,
+                                          k.held_experts[1])}
+        return {"attn_norm": {"scale": w(H)},
+                "q": {"kernel": w(H, N * D)},
+                "k": {"kernel": w(H, NKV * D)},
+                "v": {"kernel": w(H, NKV * D)},
+                "head_gate": {"kernel": w(H, N)},
+                "o": {"kernel": w(N * D, H)},
+                "mlp_norm": {"scale": w(H)}, **ffn}
+
+    def tables(self, positions):
+        """{layer kind: (cos, sin)}: the pair of laws, a layer takes its
+        kind's."""
+        k = self.config.tokens
+        return {kind: partial_rope_tables(
+            positions, getattr(k.rope_parameters, kind), k.head_dim)
+            for kind in ("full_attention", "sliding_attention")}
+
+    def window(self, i):
+        """Layer i's window, or None where it sees every key."""
+        k = self.config.tokens
+        return k.sliding_window if k.is_window(i) else None
+
+    def __call__(self, i, p, h, tables, cache):
+        """`cache` is the (keys, values) (B, L', kv heads, head_dim) of the
+        frames before this one — under a window their tail —, keys rotated
+        by this layer's law."""
+        cfg, k = self.config, self.config.tokens
+        dt, f32 = jnp.dtype(cfg.dtype), jnp.float32
+        eps = k.rms_norm_eps
+        B, L, _ = h.shape
+        N, NKV, D = k.num_attention_heads_per_layer[i], \
+            k.num_key_value_heads, k.head_dim
+        window = self.window(i)
+        rope = tables[k.layer_types[i]]
+        with jax.named_scope("lk.gqa_proj"):
+            a = rms_norm(h, p["attn_norm"]["scale"], eps).astype(dt)
+            q = apply_rope_prefix(
+                _dense(a, p["q"]).reshape(B, L, N, D), *rope)
+            keys = apply_rope_prefix(
+                _dense(a, p["k"]).reshape(B, L, NKV, D), *rope)
+            values = _dense(a, p["v"]).reshape(B, L, NKV, D)
+            gate = _dense(a, p["head_gate"])         # before its sigmoid
+            own = (keys, values)
+            if window is not None and L >= window:
+                # no query of a later frame sees an earlier row
+                own = (keys[:, L - window + 1:], values[:, L - window + 1:])
+            if cache is not None:
+                keys = jnp.concatenate([cache[0].astype(dt), keys], axis=1)
+                values = jnp.concatenate([cache[1].astype(dt), values],
+                                         axis=1)
+        binds = window_binds(L, window, keys.shape[1] - L)
+        with jax.named_scope("lk.attn_window" if binds else "lk.attn_full"):
+            o = _attention(q, keys, values, D ** -0.5,
+                           resolve_flash(cfg.use_flash_attention), window)
+        with jax.named_scope("lk.attn_gate"):
+            o = (o.astype(f32)
+                 * jax.nn.sigmoid(gate.astype(f32))[..., None]).astype(dt)
+        with jax.named_scope("lk.gqa_proj"):
+            h = h + _dense(o.reshape(B, L, N * D), p["o"])
+        h, routed = feed_forward(cfg, p, h, k.is_dense(i))
+        return h, own, routed
+
+    def key_columns(self, L: int):
+        """(visited, visible) key columns of one head's L target queries
+        against [the window's tail ; own], summed over the window layers
+        whose window binds there."""
+        return tail_key_columns(self, L)
+
+
 TRUNK_LAYERS = {TokenTrunkConfig: Mistral4Layer,
                 SmallThinkerTrunkConfig: SmallThinkerLayer,
                 KimiLinearTrunkConfig: KimiLinearLayer,
                 Phi4FlashTrunkConfig: Phi4FlashLayer,
                 OlmoHybridTrunkConfig: OlmoHybridLayer,
-                LongcatFlashTrunkConfig: LongcatFlashLayer}
+                LongcatFlashTrunkConfig: LongcatFlashLayer,
+                LagunaTrunkConfig: LagunaLayer}
 
 
 def laid_over(p: dict, d: dict) -> dict:

@@ -83,6 +83,18 @@ GDN_TOKEN_LAYER_KINDS = ("gdn_proj", "gdn_conv", "gdn_core", "gqa_proj",
 SCMOE_TOKEN_LAYER_KINDS = ("mla_proj", "mla_core", "dense_mlp", "moe_route",
                            "moe_experts", "moe_zero", "patch", "emb", "pose",
                            "update")
+# The token family's seventh trunk (Laguna's stack): grouped-query attention
+# stamps as the second trunk's — the norm, the q, k, v, gate and o products
+# and both rotary laws as `gqa_proj`, the kernel by what the layer's mask
+# is, `attn_window` or `attn_full`, whatever its head count — and the
+# sigmoid gate a head on the attention's output, with its product and the
+# cast, as `attn_gate`: the one elementwise pass between the kernel and
+# W_o, so a trace says what it costs. The leading layer's MLP is
+# `dense_mlp`, the expert layers stamp as the first trunk's.
+HEADMIX_TOKEN_LAYER_KINDS = ("gqa_proj", "attn_window", "attn_full",
+                             "attn_gate", "dense_mlp", "moe_route",
+                             "moe_experts", "moe_shared", "patch", "emb",
+                             "pose", "update")
 # Every kind a `jax.named_scope("lk.<kind>")` may stamp. The stamps sit
 # where the work happens (models/layers.py, models/xunet.py,
 # models/token_denoiser.py, sample/ddpm.py); these tuples and layer_of are
@@ -90,7 +102,8 @@ SCMOE_TOKEN_LAYER_KINDS = ("mla_proj", "mla_core", "dense_mlp", "moe_route",
 LAYER_KINDS = tuple(dict.fromkeys(
     XUNET_LAYER_KINDS + TOKEN_LAYER_KINDS + GQA_TOKEN_LAYER_KINDS
     + KDA_TOKEN_LAYER_KINDS + SSM_TOKEN_LAYER_KINDS
-    + GDN_TOKEN_LAYER_KINDS + SCMOE_TOKEN_LAYER_KINDS))
+    + GDN_TOKEN_LAYER_KINDS + SCMOE_TOKEN_LAYER_KINDS
+    + HEADMIX_TOKEN_LAYER_KINDS))
 # Every part a `jax.named_scope("pt.<part>")` may stamp inside a kind
 # (ops/flash_attention.py, ops/grouped_matmul.py, ops/kda.py, ops/gdn.py,
 # ops/ssm.py, ops/short_conv.py, ops/head_norm.py, ops/expert_combine.py,

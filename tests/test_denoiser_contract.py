@@ -113,12 +113,28 @@ TINY_SCMOE_TOKENS = {
     "model.dtype": "float32", "model.param_dtype": "float32",
     "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
 }
+# The seventh token trunk (Laguna's stack) at toy sizes: 2 and 4 query
+# heads by layer on 2 key/value heads, a window of 8 on 16 tokens, a
+# leading dense layer, 8 experts top-2 of which 4 are held.
+TINY_HEADMIX_TOKENS = {
+    "model.tokens.hidden_size": 32, "model.tokens.num_hidden_layers": 3,
+    "model.tokens.num_key_value_heads": 2, "model.tokens.head_dim": 8,
+    "model.tokens.num_attention_heads_per_layer": [2, 4, 4, 4] * 12,
+    "model.tokens.sliding_window": 8, "model.tokens.intermediate_size": 48,
+    "model.tokens.num_experts": 8, "model.tokens.num_experts_per_tok": 2,
+    "model.tokens.moe_intermediate_size": 16,
+    "model.tokens.shared_expert_intermediate_size": 16,
+    "model.tokens.held_experts": [0, 4], "data.img_sidelength": 16,
+    "model.dtype": "float32", "model.param_dtype": "float32",
+    "diffusion.timesteps": 8, "diffusion.sample_timesteps": 2,
+}
 TINY_BY_PRESET = {"ms4_denoiser128": TINY_TOKENS,
                   "st21_denoiser256": TINY_GQA_TOKENS,
                   "kl48_denoiser256": TINY_KDA_TOKENS,
                   "p4f_denoiser256": TINY_SSM_TOKENS,
                   "oh7_denoiser256": TINY_GDN_TOKENS,
-                  "lcf_denoiser256": TINY_SCMOE_TOKENS}
+                  "lcf_denoiser256": TINY_SCMOE_TOKENS,
+                  "lgs_denoiser256": TINY_HEADMIX_TOKENS}
 
 
 def token_cfg(**over) -> Config:

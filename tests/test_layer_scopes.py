@@ -342,14 +342,15 @@ def test_each_module_call_is_stamped_once(sampler_paths):
 
 
 # The compiled toy sampler of every denoiser the benchmark has a cell of:
-# the X-UNet above, and the token family's six trunks at the sizes their
+# the X-UNet above, and the token family's seven trunks at the sizes their
 # cells rehearse at (benchmarks/traffic/<traffic>.json, `rehearse`).
 TRUNKS = {"ms4_denoiser128": "sample_scan_tokens",
           "st21_denoiser256": "sample_scan_swa",
           "kl48_denoiser256": "sample_scan_kda",
           "p4f_denoiser256": "sample_scan_ssm",
           "oh7_denoiser256": "sample_scan_gdn",
-          "lcf_denoiser256": "sample_scan_scmoe"}
+          "lcf_denoiser256": "sample_scan_scmoe",
+          "lgs_denoiser256": "sample_scan_headmix"}
 KERNELS = ("flash_fwd", "gmm", "kda_fwd", "ssm_fwd", "short_conv_fwd",
            "gdn_fwd", "head_norm_fwd")
 # The parts each compiled sampler must show (it may show more: the
@@ -395,6 +396,12 @@ PARTS_SEEN = {
         "mla_core.kernel", "mla_core.layout", "mla_proj.matmul",
         "dense_mlp.matmul", "moe_route.matmul", "moe_route.gather",
         "moe_experts.kernel", "moe_experts.gather", "patch.matmul",
+        "emb.matmul"},
+    "lgs_denoiser256": {
+        "attn_window.kernel", "attn_window.layout", "attn_full.kernel",
+        "attn_full.layout", "gqa_proj.matmul", "dense_mlp.matmul",
+        "moe_route.matmul", "moe_route.gather", "moe_experts.kernel",
+        "moe_experts.gather", "moe_shared.matmul", "patch.matmul",
         "emb.matmul"},
 }
 # What the X-UNet's op loop does between modules stays `other` (the frame

@@ -114,6 +114,19 @@ TOY = {
         "model.tokens.zero_expert_num": 8, "model.tokens.moe_topk": 4,
         "model.tokens.held_experts": [0, 4], "data.img_sidelength": 16,
         "model.use_flash_attention": True},
+    # 4 and 6 query heads on 2 key/value heads, a window of 8 on 16 tokens
+    "lgs_denoiser256": {
+        "model.tokens.hidden_size": 64, "model.tokens.num_hidden_layers": 5,
+        "model.tokens.num_key_value_heads": 2, "model.tokens.head_dim": 16,
+        "model.tokens.num_attention_heads_per_layer": [4, 6, 6, 6] * 12,
+        "model.tokens.sliding_window": 8,
+        "model.tokens.intermediate_size": 96,
+        "model.tokens.num_experts": 16,
+        "model.tokens.num_experts_per_tok": 4,
+        "model.tokens.moe_intermediate_size": 32,
+        "model.tokens.shared_expert_intermediate_size": 32,
+        "model.tokens.held_experts": [0, 8], "data.img_sidelength": 16,
+        "model.use_flash_attention": True},
 }
 # (preset, "cpu" | "v5e") → sha256 of the lowered text, from the tree of
 # the PR that last meant to change it.
@@ -171,6 +184,13 @@ DIGESTS = {
         "bd7ff69c8c69e00a33700e588bfd09f436a4f777630984e4a262ef8d0ba34f15",
     ("lcf_denoiser256", "v5e"):
         "08af418bf9ade7147e962d60aeea843c45b7ed1a3ca3baf4247468c58aa53af9",
+    # PR 47's tree: the seventh trunk, pinned as it landed (the sixteen
+    # above are the parent's: `LagunaLayer` is its own class and the shared
+    # functions it calls were not touched)
+    ("lgs_denoiser256", "cpu"):
+        "f71e7d6b713bc12775b6504672bcee4a8e1ef00ec5b512f47aadd3918a1e4b42",
+    ("lgs_denoiser256", "v5e"):
+        "c23db595955e06a26a5a08f8c7560b07dfd3978ea6145cd6307eb1e8406200c3",
 }
 
 

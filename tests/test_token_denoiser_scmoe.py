@@ -597,7 +597,8 @@ def test_preset_is_the_published_config_cut_as_the_file_says():
 
 
 def test_token_trunks_are_six_and_read_back_by_their_keys():
-    assert len(TOKEN_TRUNKS) == 6 and TOKEN_TRUNKS[-1] is \
+    # the sixth of them (a seventh came with PR 47, behind it)
+    assert len(TOKEN_TRUNKS) >= 6 and TOKEN_TRUNKS[5] is \
         LongcatFlashTrunkConfig
     seen = set()
     for name in PRESET_NAMES:

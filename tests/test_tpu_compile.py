@@ -864,6 +864,57 @@ def test_lcf_sampler_fits_the_described_v5e(v5e, monkeypatch):
             >= least, name
 
 
+def test_lgs_sampler_fits_the_described_v5e(v5e, monkeypatch):
+    """`make_sampler` of `lgs_denoiser256` at its cell's size (1 view, 16
+    steps, guidance 3) compiled for the described chip: the arguments are
+    the program's own tree (5.29 B bfloat16 parameters, 10.58 GB), the
+    temporaries — the expert buffer of a step's 81 920 choices at hidden
+    3072, a window layer's float32 queries of 72 heads — stay under 2 GB,
+    and all of it inside the chip's 16 GB; grouped heads of both counts
+    and the band compile as `flash_fwd`."""
+    from novel_view_synthesis_3d_tpu.config import get_preset
+    from novel_view_synthesis_3d_tpu.diffusion.schedules import (
+        sampling_schedule)
+    from novel_view_synthesis_3d_tpu.models import build_denoiser
+    from novel_view_synthesis_3d_tpu.sample.ddpm import make_sampler
+
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
+    cfg = get_preset("lgs_denoiser256").override(**{
+        "diffusion.sample_timesteps": 16, "diffusion.guidance_weight": 3.0,
+        "diffusion.sampler": "ddpm"}).validate()
+    model = build_denoiser(cfg.model)
+    side = cfg.data.img_sidelength
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, F32, sharding=v5e)
+
+    cond = {"x": spec(1, side, side, 3), "R1": spec(1, 3, 3),
+            "t1": spec(1, 3), "R2": spec(1, 3, 3), "t2": spec(1, 3),
+            "K": spec(1, 3, 3)}
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=v5e),
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)})["params"]))
+    sampler = make_sampler(model, sampling_schedule(cfg.diffusion, 16),
+                           cfg.diffusion, trajectory_every=1)
+    compiled = jax.jit(sampler).lower(
+        params, jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e),
+        cond).compile()
+    mem = compiled.memory_analysis()
+    assert 10.58e9 < mem.argument_size_in_bytes < 10.59e9
+    assert mem.temp_size_in_bytes < 2.0e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes < 15.0e9
+    text = compiled.as_text()
+    # a step: 2 full layers one kernel each, 3 window layers four (one a
+    # query block of 1024); the once-a-call pass the same less the last
+    # layer's; three grouped products and a combine an expert layer
+    for name, least in (("flash_fwd", 2 + 12 + 1 + 12), ("gmm", 3),
+                        ("moe_combine", 1)):
+        assert len(re.findall(r"^\s*%%%s\S* = " % name, text, re.M)) \
+            >= least, name
+
+
 _LAYER_TEXTS = {}   # a preset's delta-rule layer is compiled once a run
 
 
